@@ -23,10 +23,9 @@ def _build_llm():
     s = get_settings()
     backend = s.llm_backend.lower()
     if backend == "inprocess":
-        import jax
-
         from githubrepostorag_tpu.llm import InProcessLLM
         from githubrepostorag_tpu.models.hf_loader import load_qwen2
+        from githubrepostorag_tpu.runtime import on_tpu
         from githubrepostorag_tpu.serving import Engine
         from githubrepostorag_tpu.serving.async_engine import AsyncEngine
         from githubrepostorag_tpu.serving.tokenizer import make_tokenizer
@@ -48,7 +47,7 @@ def _build_llm():
             prefill_chunk=s.prefill_chunk,
             prefill_widths=s.prefill_widths,
             kv_quant=s.kv_quant,
-            use_pallas=jax.default_backend() == "tpu",
+            use_pallas=on_tpu(),
             preempt=s.preempt,
             preempt_headroom_pages=s.preempt_headroom_pages,
             default_priority=s.priority_default_class,
@@ -62,7 +61,9 @@ def _build_llm():
 
 async def serve(host: str, port: int, use_redis: bool, run_worker: bool = True) -> None:
     from githubrepostorag_tpu.api.app import RagApi
+    from githubrepostorag_tpu.runtime import enable_compile_cache
 
+    enable_compile_cache()
     if use_redis:
         from githubrepostorag_tpu.events.redis import RedisBus, RedisCancelFlags, RedisJobQueue
 

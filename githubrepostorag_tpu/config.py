@@ -259,8 +259,6 @@ class Settings:
     # config at engine construction (dense approximation, good to ~5%)
     model_flops_per_token: float = field(
         default_factory=lambda: _env_float("MODEL_FLOPS_PER_TOKEN", 0.0))
-    # peak per-chip TFLOPs for the MFU denominator (v5e bf16 = 197)
-    chip_peak_tflops: float = field(default_factory=lambda: _env_float("CHIP_PEAK_TFLOPS", 197.0))
 
     # --- Priority classes & preempt-to-host scheduling ---
     # SLO class stamped on requests that arrive unlabeled (API job

@@ -44,6 +44,9 @@ def main(argv: list[str] | None = None) -> int:
                 return 0
 
     from githubrepostorag_tpu.ingest.controller import ingest_component, ingest_many
+    from githubrepostorag_tpu.runtime import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.watch:
         if not args.local:

@@ -377,8 +377,27 @@ def make_dense_cache(cfg: Qwen2Config, batch: int, max_len: int, dtype=jnp.bfloa
     return jnp.zeros(shape, dtype=dtype), jnp.zeros(shape, dtype=dtype)
 
 
+def _attend_per_tp_shard(attn_fn, mesh, quant: bool):
+    """Run the Pallas paged-attention dispatcher as a shard_map island over
+    the mesh: a Mosaic kernel has no GSPMD partitioning rule (the TPU
+    compiler refuses one in a multi-device program), and attention needs no
+    collective across heads, so each tp shard runs the kernel on its own
+    q/kv heads.  Same island as the decode burst's staged kernel."""
+    from jax.sharding import PartitionSpec as P
+
+    heads = P(None, None, "tp", None)  # q / out [B, S, n_q, hd]
+    pool = P("tp", None, None, None)  # one layer's pages [n_kv, P, ps, hd]
+    in_specs = [heads, pool, pool, P(None, None), P(None), P(None)]
+    if quant:
+        in_specs += [P("tp", None)] * 2  # [n_kv, P] page scales
+    return jax.shard_map(
+        attn_fn, mesh=mesh, in_specs=tuple(in_specs), out_specs=heads,
+        check_vma=False,
+    )
+
+
 @partial(
-    jax.jit, static_argnames=("cfg", "use_pallas", "int4_kernel"),
+    jax.jit, static_argnames=("cfg", "use_pallas", "int4_kernel", "mesh"),
     donate_argnums=(4, 5),
 )
 def forward_paged(
@@ -398,6 +417,8 @@ def forward_paged(
     v_scales: jnp.ndarray | None = None,  # int8 (kv_quant) pool scales
     int4_kernel: bool = True,  # False under TP-sharded int4 weights
     # (pallas_call has no GSPMD partitioning rule — see quant.Layered4XLA)
+    mesh=None,  # the engine's jax.sharding.Mesh: with use_pallas the
+    # attention kernel then runs per tp shard (_attend_per_tp_shard)
 ):
     """Prefill-chunk or decode step over the paged KV cache.
 
@@ -425,7 +446,7 @@ def forward_paged(
         params, cfg, input_ids, positions, k_pages, v_pages,
         slot_mapping, block_tables, cached_lens, new_lens, use_pallas,
         logits_at=logits_at, k_scales=k_scales, v_scales=v_scales,
-        int4_kernel=int4_kernel,
+        int4_kernel=int4_kernel, mesh=mesh,
     )
 
 
@@ -445,6 +466,7 @@ def forward_paged_impl(
     k_scales: jnp.ndarray | None = None,
     v_scales: jnp.ndarray | None = None,
     int4_kernel: bool = True,
+    mesh=None,
 ):
     """Unjitted body of ``forward_paged`` so larger fused programs (the
     multi-step decode burst in serving/decode_burst.py) can inline it inside
@@ -461,6 +483,8 @@ def forward_paged_impl(
         from githubrepostorag_tpu.ops.fused_decode import (
             fused_paged_attention as attn_fn,
         )
+        if mesh is not None:
+            attn_fn = _attend_per_tp_shard(attn_fn, mesh, quant)
     else:
         attn_fn = paged_attention_ref
 

@@ -21,8 +21,8 @@ Three concerns, all driven from the engine driver thread
   while serving means live traffic just paid an XLA compile the warmup
   ladder failed to predict: ``rag_xla_compiles_total`` increments and
   every registered in-flight span gets an ``xla_compile`` event, so the
-  one request that stalled 30 s on a TPU compile tunnel says so in its
-  own timeline.
+  one request that stalled for the length of a TPU compile says so in
+  its own timeline.
 """
 
 from __future__ import annotations
@@ -124,6 +124,7 @@ class EngineStepProfiler:
         self._lock = threading.Lock()
         self._live: dict[int, "Span"] = {}
         self._last_step_end: float | None = None
+        self.live_compiles = 0  # programs compiled under live traffic
 
     # ----------------------------------------------------- live requests --
 
@@ -157,6 +158,7 @@ class EngineStepProfiler:
 
         delta = self.watchdog.sample()
         if delta > 0:
+            self.live_compiles += delta  # driver thread only; GIL-atomic read
             XLA_COMPILES.labels(replica=self.replica).inc(delta)
             with self._lock:
                 live = list(self._live.values())

@@ -95,11 +95,13 @@ class TokenLedger:
 
     def __init__(self, replica: str = "r0", *,
                  flops_per_tok: float = 0.0,
-                 peak_flops: float = 0.0,
+                 peak_flops: float | None = None,
                  window_s: float = 60.0) -> None:
         self.replica = replica
         self.flops_per_tok = float(flops_per_tok)
-        self.peak_flops = float(peak_flops)
+        # None = the device kind has no entry in runtime.CHIP_PEAK_FLOPS:
+        # MFU is then reported as null, never against a guessed peak
+        self.peak_flops = peak_flops
         self.window_s = float(window_s)
         self._lock = threading.Lock()
         self._prev: dict[str, float] | None = None
@@ -250,17 +252,23 @@ class TokenLedger:
             return "stall"
         return "none"
 
+    def _mfu_locked(self, elapsed: float) -> float | None:
+        if not self.peak_flops:
+            return None
+        if not (elapsed and self.flops_per_tok):
+            return 0.0
+        work = (self._sums.get("committed", 0.0)
+                + self._sums.get("prefill_tokens", 0.0)) * self.flops_per_tok
+        return work / (elapsed * self.peak_flops)
+
     def _publish_locked(self, now: float) -> None:
         elapsed = self._elapsed(now)
         goodput = self._sums.get("committed", 0.0) / elapsed if elapsed else 0.0
-        mfu = 0.0
-        if elapsed and self.flops_per_tok and self.peak_flops:
-            work = (self._sums.get("committed", 0.0)
-                    + self._sums.get("prefill_tokens", 0.0)) * self.flops_per_tok
-            mfu = work / (elapsed * self.peak_flops)
+        mfu = self._mfu_locked(elapsed)
         limiter = self._limiter_locked(now)
         self._m_goodput.set(goodput)
-        self._m_mfu.set(mfu)
+        if mfu is not None:
+            self._m_mfu.set(mfu)
         steps = self._sums.get("steps", 0.0)
         self._m_dispatches.set(
             self._sums.get("dispatches", 0.0) / steps if steps else 0.0)
@@ -316,11 +324,7 @@ class TokenLedger:
             elapsed = self._elapsed(now)
             s = self._sums
             goodput = s.get("committed", 0.0) / elapsed if elapsed else 0.0
-            mfu = 0.0
-            if elapsed and self.flops_per_tok and self.peak_flops:
-                work = (s.get("committed", 0.0)
-                        + s.get("prefill_tokens", 0.0)) * self.flops_per_tok
-                mfu = work / (elapsed * self.peak_flops)
+            mfu = self._mfu_locked(elapsed)
             committed = s.get("committed", 0.0)
             wasted = s.get("spec_rejected", 0.0) + s.get("deadline_reaped", 0.0)
             return {
@@ -329,7 +333,7 @@ class TokenLedger:
                 "elapsed_s": round(elapsed, 6),
                 "steps": int(s.get("steps", 0.0)),
                 "goodput_tok_s": round(goodput, 3),
-                "mfu": round(mfu, 6),
+                "mfu": None if mfu is None else round(mfu, 6),
                 "limiter": self._limiter_locked(now),
                 "tokens": {
                     "committed": int(committed),

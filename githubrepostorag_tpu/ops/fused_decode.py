@@ -41,11 +41,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from githubrepostorag_tpu.ops.packed_prefill import _segment_scatter_indices
+from githubrepostorag_tpu.runtime import on_tpu
 
 NEG_INF = -1e30
-
-# JAX renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 
 def _fused_window_kernel(
@@ -233,7 +231,7 @@ def fused_window_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_kv, group, s_w, hd), q_win.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -252,7 +250,7 @@ def fused_paged_attention(q, k_pages, v_pages, block_tables, cached_lens,
     on the kernel's exact compute graph."""
     return fused_window_attention(
         q, k_pages, v_pages, block_tables, cached_lens, new_lens,
-        k_scales, v_scales, interpret=jax.default_backend() != "tpu",
+        k_scales, v_scales, interpret=not on_tpu(),
     )
 
 
@@ -285,7 +283,7 @@ def fused_packed_attention(
     )
     out_seg = fused_window_attention(
         q_seg, k_pages, v_pages, block_tables, cached_lens, new_lens,
-        k_scales, v_scales, interpret=jax.default_backend() != "tpu",
+        k_scales, v_scales, interpret=not on_tpu(),
     )
     # gather back to packed order; padding tokens read a clamped garbage
     # row (finite — never committed to KV, never projected to logits)

@@ -51,11 +51,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from githubrepostorag_tpu.ops.attention import dense_attention
 from githubrepostorag_tpu.ops.paged_attention import gather_kv
+from githubrepostorag_tpu.runtime import on_tpu
 
 NEG_INF = -1e30
-
-# JAX renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 
 def _segment_scatter_indices(seg_ids, positions, cached_lens, tq):
@@ -199,7 +197,7 @@ def packed_prefill_attention_seg(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, n_kv, group, tq, hd), q_seg.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -250,7 +248,7 @@ def packed_prefill_attention(
         .reshape(r, tq, n_q, hd)
     )
     if use_pallas and not quant:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
         out_seg = packed_prefill_attention_seg(
             q_seg, k_pages, v_pages, block_tables, cached_lens, new_lens,
             interpret=interpret,

@@ -33,9 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# JAX renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _pick_tile(total: int, unit: int, target: int) -> int:
     """Largest multiple of ``unit`` that divides ``total``, is <= target,
@@ -306,7 +303,7 @@ def _w4a8_matmul(x, q, s, zs, layer, out_dtype, interpret: bool):
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m_padded, out), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -419,7 +416,7 @@ def int4_matmul(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m_padded, out), out_dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

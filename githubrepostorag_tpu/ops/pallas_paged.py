@@ -30,10 +30,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from githubrepostorag_tpu.runtime import on_tpu
 
-# JAX renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+NEG_INF = -1e30
 
 
 def _decode_kernel(
@@ -151,7 +150,7 @@ def paged_attention_decode(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_kv, group, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -396,7 +395,7 @@ def paged_attention_decode_staged(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_kv, group, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -411,7 +410,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, cached_lens, new_lens):
     from githubrepostorag_tpu.ops.paged_attention import paged_attention_ref
 
     if q.shape[1] == 1:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
         return paged_attention_decode(
             q, k_pages, v_pages, block_tables, cached_lens, new_lens, interpret=interpret
         )

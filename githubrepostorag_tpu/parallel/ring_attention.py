@@ -143,18 +143,16 @@ def make_ring_attend(
 
         return lambda q, k, v: dense_attention(q, k, v, causal=causal, q_offset=0)
 
-    from githubrepostorag_tpu.parallel.compat import shard_map
-
     if segmented:
         seg_spec = P(b_ax, axis_name)
-        return shard_map(
+        return jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(spec, spec, spec, seg_spec),
             out_specs=spec,
             check_vma=False,
         )
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec, spec, spec),

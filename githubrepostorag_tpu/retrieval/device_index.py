@@ -136,8 +136,6 @@ class DeviceIndexedStore(VectorStore):
 
         from jax.sharding import PartitionSpec as P
 
-        from githubrepostorag_tpu.parallel.compat import shard_map
-
         def sharded(corpus, queries, mask, k: int):
             local_n = corpus.shape[1] // dp                 # corpus [dim, cap]
             kk = min(k, local_n)
@@ -156,7 +154,7 @@ class DeviceIndexedStore(VectorStore):
                 vv, pos = jax.lax.top_k(v_all, k)
                 return vv, jnp.take_along_axis(i_all, pos, axis=1)
 
-            return shard_map(
+            return jax.shard_map(
                 body,
                 mesh=mesh,
                 in_specs=(P(None, "dp"), P(), P(None, "dp")),

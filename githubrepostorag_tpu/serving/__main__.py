@@ -25,6 +25,7 @@ async def serve(host: str, port: int) -> None:
     import ml_dtypes
 
     from githubrepostorag_tpu.models.hf_loader import load_qwen2
+    from githubrepostorag_tpu.runtime import enable_compile_cache, on_tpu
     from githubrepostorag_tpu.serving.async_engine import AsyncEngine
     from githubrepostorag_tpu.serving.engine import Engine
     from githubrepostorag_tpu.serving.openai_api import OpenAIServer
@@ -37,7 +38,9 @@ async def serve(host: str, port: int) -> None:
         plan_for_devices,
     )
 
+    enable_compile_cache()
     maybe_initialize_distributed()  # multi-host pod -> global device list
+    pallas = on_tpu()  # raises if JAX fell back to a CPU nobody asked for
     s = get_settings()
     if not s.model_weights_path:
         raise SystemExit("model server requires MODEL_WEIGHTS_PATH (a local HF checkpoint dir)")
@@ -134,7 +137,7 @@ async def serve(host: str, port: int) -> None:
             prefill_chunk=s.prefill_chunk,
             prefill_widths=s.prefill_widths,
             prefill_token_budget=s.prefill_token_budget or None,
-            use_pallas=jax.default_backend() == "tpu",
+            use_pallas=pallas,
             kv_quant=s.kv_quant,
             mesh=mesh,
             prefix_caching=s.prefix_caching,
@@ -227,7 +230,8 @@ async def serve(host: str, port: int) -> None:
         controller = FleetController(async_engine, restore=restore)
         await controller.start()
         logger.info("fleet controller up (tick %.2fs)", controller.tick_s)
-    logger.info("model server up on %s:%d (backend=%s)", host, bound, jax.default_backend())
+    logger.info("model server up on %s:%d (backend=%s pallas=%s)",
+                host, bound, jax.default_backend(), pallas)
     while True:  # serve until the pod is killed
         await asyncio.sleep(3600)
 

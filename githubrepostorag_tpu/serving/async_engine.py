@@ -65,6 +65,7 @@ class AsyncEngine:
         from githubrepostorag_tpu.config import get_settings
         from githubrepostorag_tpu.obs.ledger import TokenLedger, flops_per_token
         from githubrepostorag_tpu.obs.slo import SLOMonitor, get_slo_plane
+        from githubrepostorag_tpu.runtime import chip_peak_flops
 
         s = get_settings()
         fpt = s.model_flops_per_token or (
@@ -72,7 +73,7 @@ class AsyncEngine:
         )
         self.ledger = TokenLedger(
             replica, flops_per_tok=fpt,
-            peak_flops=s.chip_peak_tflops * 1e12,
+            peak_flops=chip_peak_flops(),
             window_s=s.slo_ledger_window_s,
         )
         self.slo = SLOMonitor(replica)
@@ -527,6 +528,7 @@ class AsyncEngine:
     def stats(self) -> dict[str, Any]:
         from githubrepostorag_tpu.config import get_settings
         from githubrepostorag_tpu.resilience.policy import Deadline
+        from githubrepostorag_tpu.runtime import device_facts
 
         # bounded collection: a wedged driver holds the lock for seconds;
         # /debug/fleet must render the last good row with its age instead
@@ -584,9 +586,15 @@ class AsyncEngine:
                     self.engine, "resume_recomputed_tokens", 0),
                 "resume_recomputed_prompt_tokens": getattr(
                     self.engine, "resume_recomputed_prompt_tokens", 0),
+                "pallas": bool(getattr(self.engine, "use_pallas", False)),
+                "live_compiles": self.profiler.live_compiles,
             }
         finally:
             self._lock.release()
+        # where it runs: a CPU fallback or a gather-path engine must be
+        # visible on /health, not only in the start-up log (read outside
+        # the driver lock — it asks the runtime, not the engine)
+        out.update(device_facts())
         self._last_stats = out
         self._last_stats_t = time.monotonic()
         return dict(out)

@@ -39,12 +39,9 @@ def _device_index_enabled(s) -> bool:
         return True
     if mode not in {"auto", ""}:
         return False
-    try:
-        import jax
+    from githubrepostorag_tpu.runtime import on_tpu
 
-        return jax.default_backend() == "tpu"
-    except Exception:  # noqa: BLE001 - no jax -> host store
-        return False
+    return on_tpu()
 
 
 def _build() -> VectorStore:

@@ -215,9 +215,7 @@ def make_pp_train_step(
     # layers: leading (stage) axis over pp, plus Megatron column/row tp
     # shards when tp>1; head params replicated; microbatches replicated
     # over pp/tp, batch-dim over dp
-    from githubrepostorag_tpu.parallel.compat import shard_map
-
-    shard_body = shard_map(
+    shard_body = jax.shard_map(
         pp_loss,
         mesh=mesh,
         in_specs=(pp_layer_specs(tp), P(), P(), P(), mb_spec, mb_spec, mb_spec),

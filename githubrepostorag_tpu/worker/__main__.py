@@ -46,7 +46,9 @@ async def serve() -> None:
     from githubrepostorag_tpu.metrics import MeteredLLM
     from githubrepostorag_tpu.worker.worker import RagWorker
     from githubrepostorag_tpu.api.__main__ import _build_llm
+    from githubrepostorag_tpu.runtime import enable_compile_cache
 
+    enable_compile_cache()
     s = get_settings()
     await _start_metrics_server(s.metrics_port)
     raw_llm = _build_llm()

@@ -31,8 +31,12 @@ model-server
   command: ['sh', '-c', 'until nc -z {{ .host }} {{ .port }}; do echo waiting for {{ .name }}; sleep 3; done']
 {{- end -}}
 
-{{/* Env block shared by api / worker / ingest pods */}}
+{{/* Env block shared by api / worker / ingest pods.  These pods request no
+     TPU and say so with JAX_PLATFORMS=cpu: runtime.on_tpu() reads an unpinned
+     CPU backend as a chip that went missing and refuses to start. */}}
 {{- define "rag.commonEnv" -}}
+- name: JAX_PLATFORMS
+  value: "cpu"
 - name: REDIS_URL
   value: "redis://{{ include "rag.redisHost" . }}:6379/0"
 - name: CASSANDRA_HOST

@@ -13,9 +13,9 @@ import sys
 import time
 
 sys.path.insert(0, ".")
-import _jax_cache
+from githubrepostorag_tpu.runtime import enable_compile_cache
 
-_jax_cache.enable_persistent_cache()
+enable_compile_cache()
 
 import jax
 import jax.numpy as jnp

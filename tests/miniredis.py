@@ -17,6 +17,7 @@ class MiniRedis:
         self.subscribers: dict[str, list[asyncio.StreamWriter]] = defaultdict(list)
         self.server: asyncio.AbstractServer | None = None
         self.port: int | None = None
+        self._handlers: set[asyncio.Task] = set()
 
     async def start(self) -> int:
         self.server = await asyncio.start_server(self._handle, "127.0.0.1", 0)
@@ -26,7 +27,19 @@ class MiniRedis:
     async def stop(self) -> None:
         if self.server:
             self.server.close()
-            await self.server.wait_closed()
+            # Python 3.12 made wait_closed() wait for every open connection:
+            # a client that never hangs up (a test's leftover handle) would
+            # hold it forever, so drop the connections like a dying server.
+            # Looping catches a handler whose task had not started running
+            # (and so had not registered) when the first sweep went by.
+            while True:
+                for task in list(self._handlers):
+                    task.cancel()
+                try:
+                    await asyncio.wait_for(self.server.wait_closed(), timeout=0.1)
+                    return
+                except asyncio.TimeoutError:
+                    continue
 
     async def _read_command(self, reader: asyncio.StreamReader) -> list[str] | None:
         line = await reader.readline()
@@ -79,6 +92,7 @@ class MiniRedis:
         return val
 
     async def _handle(self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter) -> None:
+        self._handlers.add(asyncio.current_task())
         try:
             while True:
                 args = await self._read_command(reader)
@@ -133,6 +147,7 @@ class MiniRedis:
         except (ConnectionError, asyncio.IncompleteReadError, OSError):
             pass
         finally:
+            self._handlers.discard(asyncio.current_task())
             for subs in self.subscribers.values():
                 if writer in subs:
                     subs.remove(writer)
