@@ -231,9 +231,9 @@ class JaxBertTextEncoder:
 
                 ids_d = jax.device_put(ids_d, self._batch_sharding)
                 mask_d = jax.device_put(mask_d, self._batch_sharding)
-            with annotate("encoder.embed_batch"):
-                vecs = embed(self.params, self.cfg, ids_d, mask_d)
-            out[idx] = np.asarray(vecs)[: len(idx)]
+            with annotate("embed.batch", texts=len(idx)):  # dispatch to vectors on host
+                vecs = np.asarray(embed(self.params, self.cfg, ids_d, mask_d))
+            out[idx] = vecs[: len(idx)]
         return out
 
 

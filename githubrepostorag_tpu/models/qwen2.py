@@ -186,34 +186,40 @@ def _block(cfg: Qwen2Config, h, p, cos, sin, attend, reduce=None):
     if reduce is None:
         reduce = lambda x: x
 
-    hn = rms_norm(h, p["ln1"], cfg.rms_norm_eps)
-    if "wqkv" in p:  # fused single-chip serving layout (quant.fuse_projections)
-        qkv = qmatmul(hn, p["wqkv"]) + p["bqkv"]
-        q, k, v = jnp.split(qkv, [nq * hd, (nq + nkv) * hd], axis=-1)
-        q = q.reshape(b, s, nq, hd)
-        k = k.reshape(b, s, nkv, hd)
-        v = v.reshape(b, s, nkv, hd)
-    else:
-        q = (qmatmul(hn, p["wq"]) + p["bq"]).reshape(b, s, nq, hd)
-        k = (qmatmul(hn, p["wk"]) + p["bk"]).reshape(b, s, nkv, hd)
-        v = (qmatmul(hn, p["wv"]) + p["bv"]).reshape(b, s, nkv, hd)
-    q, k = apply_rope(q, k, cos, sin)
+    # named scopes (attn_proj, mlp; the callers' attend adds kv_write and
+    # paged_attention, the head adds sample): the names a device trace's
+    # ops carry in every step program, whatever a refactor renames
+    with jax.named_scope("attn_proj"):
+        hn = rms_norm(h, p["ln1"], cfg.rms_norm_eps)
+        if "wqkv" in p:  # fused single-chip serving layout (quant.fuse_projections)
+            qkv = qmatmul(hn, p["wqkv"]) + p["bqkv"]
+            q, k, v = jnp.split(qkv, [nq * hd, (nq + nkv) * hd], axis=-1)
+            q = q.reshape(b, s, nq, hd)
+            k = k.reshape(b, s, nkv, hd)
+            v = v.reshape(b, s, nkv, hd)
+        else:
+            q = (qmatmul(hn, p["wq"]) + p["bq"]).reshape(b, s, nq, hd)
+            k = (qmatmul(hn, p["wk"]) + p["bk"]).reshape(b, s, nkv, hd)
+            v = (qmatmul(hn, p["wv"]) + p["bv"]).reshape(b, s, nkv, hd)
+        q, k = apply_rope(q, k, cos, sin)
 
     attn, cache_info = attend(q, k, v)
-    h = h + reduce(qmatmul(attn.reshape(b, s, nq * hd), p["wo"]))
+    with jax.named_scope("attn_proj"):
+        h = h + reduce(qmatmul(attn.reshape(b, s, nq * hd), p["wo"]))
 
-    hn = rms_norm(h, p["ln2"], cfg.rms_norm_eps)
-    if "router" in p:  # sparse MoE MLP (Qwen2-MoE family, models/moe.py)
-        from githubrepostorag_tpu.models.moe import moe_mlp
+    with jax.named_scope("mlp"):
+        hn = rms_norm(h, p["ln2"], cfg.rms_norm_eps)
+        if "router" in p:  # sparse MoE MLP (Qwen2-MoE family, models/moe.py)
+            from githubrepostorag_tpu.models.moe import moe_mlp
 
-        h = h + moe_mlp(cfg, p, hn)
-    elif "wgu" in p:  # fused gate|up (quant.fuse_projections)
-        g, u = jnp.split(qmatmul(hn, p["wgu"]), 2, axis=-1)
-        h = h + reduce(qmatmul(jax.nn.silu(g) * u, p["wd"]))
-    else:
-        h = h + reduce(
-            qmatmul(jax.nn.silu(qmatmul(hn, p["wg"])) * qmatmul(hn, p["wu"]), p["wd"])
-        )
+            h = h + moe_mlp(cfg, p, hn)
+        elif "wgu" in p:  # fused gate|up (quant.fuse_projections)
+            g, u = jnp.split(qmatmul(hn, p["wgu"]), 2, axis=-1)
+            h = h + reduce(qmatmul(jax.nn.silu(g) * u, p["wd"]))
+        else:
+            h = h + reduce(
+                qmatmul(jax.nn.silu(qmatmul(hn, p["wg"])) * qmatmul(hn, p["wu"]), p["wd"])
+            )
     return h, cache_info
 
 
@@ -523,18 +529,20 @@ def forward_paged_impl(
             # commit_paged is THE shared pool-commit rule (cast for bf16
             # pools; per-page first-write scales for int8 — same semantics
             # as the burst and ring-prefill commits)
-            new_kp, new_ks = commit_paged(
-                kp, k_t, flat_slots, ks if quant else None, page_size
-            )
-            new_vp, new_vs = commit_paged(
-                vp, v_t, flat_slots, vs if quant else None, page_size
-            )
-            if quant:
-                attn = attn_fn(q, new_kp, new_vp, block_tables, cached_lens,
-                               new_lens, new_ks, new_vs)
-                return attn, (new_kp, new_vp, new_ks, new_vs)
-            attn = attn_fn(q, new_kp, new_vp, block_tables, cached_lens, new_lens)
-            return attn, (new_kp, new_vp)
+            with jax.named_scope("kv_write"):
+                new_kp, new_ks = commit_paged(
+                    kp, k_t, flat_slots, ks if quant else None, page_size
+                )
+                new_vp, new_vs = commit_paged(
+                    vp, v_t, flat_slots, vs if quant else None, page_size
+                )
+            with jax.named_scope("paged_attention"):
+                if quant:
+                    attn = attn_fn(q, new_kp, new_vp, block_tables, cached_lens,
+                                   new_lens, new_ks, new_vs)
+                    return attn, (new_kp, new_vp, new_ks, new_vs)
+                attn = attn_fn(q, new_kp, new_vp, block_tables, cached_lens, new_lens)
+                return attn, (new_kp, new_vp)
 
         h, cache = _block(cfg, h, p, cos, sin, attend)
         return (h, li + 1), cache
@@ -548,13 +556,14 @@ def forward_paged_impl(
         (h, _), (k_pages, v_pages) = jax.lax.scan(
             body, (h, 0), (scan_layers, k_pages, v_pages)
         )
-    h = rms_norm(h, params["norm"], cfg.rms_norm_eps)
-    if logits_at is not None:
-        h = jnp.take_along_axis(h, logits_at[:, None, None], axis=1)  # [B, 1, d]
-    # w4a8=False: prefill/spec-verify logits keep the exact bf16-dequant
-    # contract, like the projections above (the prompt's first sampled
-    # token and every verify accept/reject come from these)
-    logits = _logits(params, h, int4_kernel=int4_kernel, w4a8=False)
+    with jax.named_scope("sample"):  # the head; the first token's draw is the engine's
+        h = rms_norm(h, params["norm"], cfg.rms_norm_eps)
+        if logits_at is not None:
+            h = jnp.take_along_axis(h, logits_at[:, None, None], axis=1)  # [B, 1, d]
+        # w4a8=False: prefill/spec-verify logits keep the exact bf16-dequant
+        # contract, like the projections above (the prompt's first sampled
+        # token and every verify accept/reject come from these)
+        logits = _logits(params, h, int4_kernel=int4_kernel, w4a8=False)
     if quant:
         return logits, k_pages, v_pages, k_scales, v_scales
     return logits, k_pages, v_pages

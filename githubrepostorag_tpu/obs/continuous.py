@@ -52,6 +52,9 @@ class ContinuousProfiler:
         self._captured = 0
         self._lock = threading.Lock()
         self._samples: deque[dict] = deque(maxlen=self.ring)
+        # the replica's finished-request records (AsyncEngine.request_ring):
+        # hung here by its owner so readers find it through profilers()
+        self.request_ring: deque[dict] | None = None
         self._m_samples = metrics.PROFILE_SAMPLES.labels(replica=replica)
 
     def on_step(self, now: float, rec: dict | None,

@@ -3,12 +3,16 @@
 Three concerns, all driven from the engine driver thread
 (``AsyncEngine._drive``) so the event loop never pays for them:
 
-* **Per-request phase attribution** — the engine stamps monotonic
-  timestamps as a request moves waiting -> prefilling -> first token ->
-  done (``GenerationResult.timings``); ``record_engine_spans`` turns
-  those into retroactive ``engine.queue_wait`` / ``engine.prefill`` /
-  ``engine.decode`` spans under the request's trace, so a flight-recorder
-  dump shows exactly where a slow TTFT went.
+* **Per-request phase attribution** — the serving path stamps monotonic
+  timestamps as a request moves received -> enqueued -> waiting ->
+  prefilling -> first token -> first emit -> done
+  (``GenerationResult.timings``); ``record_engine_spans`` turns those
+  into retroactive spans under the request's trace.  Time to first token
+  is the six in a row ``server.tokenize``, ``server.submit_wait``,
+  ``engine.queue_wait``, ``engine.prefill_dispatch``,
+  ``engine.first_token_lag``, ``server.emit_lag``; ``engine.prefill``
+  (admission to first token) and ``engine.decode`` are as before, so a
+  flight-recorder dump shows exactly where a slow TTFT went.
 
 * **Scheduler-stall gauge + TPOT histogram** — the gap between
   consecutive steps while work exists is scheduler stall (vLLM's
@@ -181,20 +185,36 @@ class EngineStepProfiler:
         SCHED_STALL.labels(replica=self.replica).set(0.0)
 
 
+# time to first token, received to emitted, as (span, from stamp, to stamp):
+# consecutive, so the six sum to first_emit_t - recv_t exactly
+TTFT_PARTS = (
+    ("server.tokenize", "recv_t", "enqueue_t"),
+    ("server.submit_wait", "enqueue_t", "submit_t"),
+    ("engine.queue_wait", "submit_t", "prefill_start_t"),
+    ("engine.prefill_dispatch", "prefill_start_t", "prefill_end_t"),
+    ("engine.first_token_lag", "prefill_end_t", "first_token_t"),
+    ("server.emit_lag", "first_token_t", "first_emit_t"),
+)
+
+
 def record_engine_spans(result: Any, parent: TraceContext | None) -> None:
-    """Turn a ``GenerationResult``'s monotonic phase stamps into
-    queue-wait / prefill / decode spans under ``parent``.  Tolerates
-    partial timings (errored or reaped requests may never prefill)."""
+    """Turn a ``GenerationResult``'s monotonic phase stamps into spans
+    under ``parent``: the six parts of time to first token, and prefill /
+    decode.  Tolerates partial timings (errored or reaped requests may
+    never prefill; a caller that left before the first token never saw
+    one emitted)."""
     timings = getattr(result, "timings", None)
     if not timings or parent is None or not parent.sampled:
         return
-    submit = timings.get("submit_t")
     pstart = timings.get("prefill_start_t")
     ftok = timings.get("first_token_t")
     done = timings.get("done_t", time.monotonic())
     attrs = {"request_id": getattr(result, "request_id", "")}
-    if submit is not None and pstart is not None:
-        record_span("engine.queue_wait", submit, pstart, parent=parent, attrs=attrs)
+    for name, a, b in TTFT_PARTS:
+        t0, t1 = timings.get(a), timings.get(b)
+        # (a resumed request's second admission lies after its first token)
+        if t0 is not None and t1 is not None and t1 >= t0:
+            record_span(name, t0, t1, parent=parent, attrs=attrs)
     if pstart is not None and ftok is not None:
         psp = record_span("engine.prefill", pstart, ftok, parent=parent, attrs={
             **attrs, "prompt_tokens": len(getattr(result, "prompt_tokens", ()) or ()),

@@ -49,6 +49,7 @@ from githubrepostorag_tpu.store.base import (
 )
 from githubrepostorag_tpu.utils import next_bucket
 from githubrepostorag_tpu.utils.logging import get_logger
+from githubrepostorag_tpu.utils.profiling import annotate
 
 logger = get_logger(__name__)
 
@@ -522,8 +523,9 @@ class DeviceIndexedStore(VectorStore):
         if self.mesh is not None and self._dp > 1:
             q = jax.device_put(q, self._sharding(P()))
             m = jax.device_put(m, self._sharding(P(None, "dp")))
-        vals, idx = self._search_jit(corpus, q, m, k=k)
-        return np.asarray(vals), np.asarray(idx)
+        with annotate("index.search", queries=int(q.shape[0]), k=k):
+            vals, idx = self._search_jit(corpus, q, m, k=k)
+            return np.asarray(vals), np.asarray(idx)  # dispatch to result on host
 
     def search_batch(
         self,

@@ -14,6 +14,7 @@ from __future__ import annotations
 import asyncio
 import threading
 import time
+from collections import deque
 from dataclasses import dataclass
 from typing import Any, AsyncIterator
 
@@ -22,8 +23,11 @@ from githubrepostorag_tpu.serving.engine import Engine, GenerationResult
 from githubrepostorag_tpu.serving.routing import ReplicaDigest
 from githubrepostorag_tpu.serving.sampling_params import SamplingParams
 from githubrepostorag_tpu.utils.logging import get_logger
+from githubrepostorag_tpu.utils.profiling import annotate
 
 logger = get_logger(__name__)
+
+REQUEST_RING = 4096  # finished requests kept with their stamps
 
 # replica lifecycle states (serving/multi_engine.py drives transitions;
 # gauge encoding matches metrics.FLEET_LIFECYCLE)
@@ -95,6 +99,12 @@ class AsyncEngine:
         get_hbm_plane().register(replica, self.page_obs)
         self.continuous = ContinuousProfiler(replica)
         register_profiler(replica, self.continuous)
+        # the request record: every finished request's id, token counts and
+        # ``timings`` (the dict itself: stream() adds first_emit_t to it on
+        # the event loop).  Appended by the driver outside its lock; readers
+        # reach it through the profiler registry (continuous.profilers()).
+        self.request_ring: deque[dict] = deque(maxlen=REQUEST_RING)
+        self.continuous.request_ring = self.request_ring
         # lifecycle is event-loop state: MultiAsyncEngine transitions it and
         # its _pick reads it, both on the loop; other threads only render it
         self.lifecycle = "active"
@@ -325,75 +335,93 @@ class AsyncEngine:
                     self.engine.set_class_pressure(self.slo.class_states())
                     pressure_next = time.monotonic() + 0.25
                 has_work = self.engine.has_work()
-                finished = self.engine.step() if has_work else []
-                parked = (self.engine.drain_park_events()
-                          if hasattr(self.engine, "drain_park_events") else [])
-                m_running.set(self.engine.num_running)
-                m_waiting.set(self.engine.num_waiting)
-                export_counters()
-                snap = engine_snapshot(self.engine) if has_work else None
-                # queue/pool depths for the continuous profiler, read under
-                # the driver lock so a sample is internally consistent
-                q_depths = (self.engine.num_running, self.engine.num_waiting,
-                            getattr(self.engine, "num_parked", 0))
-                pool_alloc = self.engine._allocator
-                pool_depths = (pool_alloc.free_count,
-                               getattr(pool_alloc, "host_pages", 0))
-                # rate-limited chain-digest rebuild for the fleet router —
-                # allocator maps are driver-lock state, so build here and
-                # publish the frozen view through the digest's own lock
-                now = time.monotonic()
-                if now >= digest_next:
-                    alloc = self.engine._allocator
-                    res_fn = getattr(alloc, "resident_chain_hashes", None)
-                    host_fn = getattr(alloc, "host_chain_hashes", None)
-                    if res_fn is not None or host_fn is not None:
-                        resident = res_fn() if res_fn else frozenset()
-                        host = host_fn() if host_fn else frozenset()
-                        self.digest.publish(
-                            resident, host, time.monotonic() - now)
-                    digest_next = now + digest_interval
-            if has_work:
-                step_end = time.monotonic()
-                compiles = self.profiler.on_step(step_start, step_end)
-                self.ledger.on_step(snap, step_start, step_end,
-                                    compiles=compiles)
-                # always-on sampled anatomy: every Nth step lands in the
-                # continuous ring (PROFILE_SAMPLE_EVERY); off the lock, so
-                # a flush can never stretch the locked section
-                self.continuous.on_step(step_end, self.ledger.last_rec or {},
-                                        queue=q_depths, pool=pool_depths)
-            else:
-                self.profiler.idle()
-                self.ledger.idle()
-            for rid in parked:
-                # advisory event: the request is parked (KV in the host
-                # tier) and will resume token-identically.  Disagg decode
-                # consumers use it to fall back fused pre-first-token;
-                # ordinary consumers just keep waiting for tokens.
-                self._emit(rid, StreamEvent(type="parked"))
-            for res in finished:
-                m_tokens.inc(len(res.output_tokens))
-                if res.ttft_s is not None:
-                    m_ttft.observe(res.ttft_s)
-                decoded = len(res.output_tokens) - 1  # first token is prefill's
-                tpot = None
-                if decoded > 0 and res.decode_time_s > 0:
-                    tpot = res.decode_time_s / decoded
-                    m_tpot.observe(tpot)
-                if res.spec_proposed > 0:
-                    m_saccept.observe(res.spec_accepted / res.spec_proposed)
-                self.slo.observe(
-                    self._priority.pop(res.request_id, None) or "interactive",
-                    ttft_s=res.ttft_s, tpot_s=tpot,
-                    deadline_missed=res.finish_reason == "deadline",
-                )
-                self._emit(res.request_id, StreamEvent(type="final", result=res))
-            # keep burn rates decaying while no requests finish (recovery
-            # back to ok must not wait for the next completion)
-            self.slo.maybe_refresh()
+                finished = []
+                if has_work:
+                    # mono_ns anchors every time.monotonic() stamp of the
+                    # program (request timings, obs/) to the trace's clock:
+                    # trace time = this event's start + (stamp - mono_ns)
+                    with annotate("driver.step", mono_ns=time.monotonic_ns()):
+                        finished = self.engine.step()
+                with annotate("driver.export", work=int(has_work)):
+                    parked = (self.engine.drain_park_events()
+                              if hasattr(self.engine, "drain_park_events") else [])
+                    m_running.set(self.engine.num_running)
+                    m_waiting.set(self.engine.num_waiting)
+                    export_counters()
+                    snap = engine_snapshot(self.engine) if has_work else None
+                    # queue/pool depths for the continuous profiler, read under
+                    # the driver lock so a sample is internally consistent
+                    q_depths = (self.engine.num_running, self.engine.num_waiting,
+                                getattr(self.engine, "num_parked", 0))
+                    pool_alloc = self.engine._allocator
+                    pool_depths = (pool_alloc.free_count,
+                                   getattr(pool_alloc, "host_pages", 0))
+                    # rate-limited chain-digest rebuild for the fleet router —
+                    # allocator maps are driver-lock state, so build here and
+                    # publish the frozen view through the digest's own lock
+                    now = time.monotonic()
+                    if now >= digest_next:
+                        alloc = self.engine._allocator
+                        res_fn = getattr(alloc, "resident_chain_hashes", None)
+                        host_fn = getattr(alloc, "host_chain_hashes", None)
+                        if res_fn is not None or host_fn is not None:
+                            resident = res_fn() if res_fn else frozenset()
+                            host = host_fn() if host_fn else frozenset()
+                            self.digest.publish(
+                                resident, host, time.monotonic() - now)
+                        digest_next = now + digest_interval
+            with annotate("driver.export", work=int(has_work)):
+                if has_work:
+                    step_end = time.monotonic()
+                    compiles = self.profiler.on_step(step_start, step_end)
+                    self.ledger.on_step(snap, step_start, step_end,
+                                        compiles=compiles)
+                    # always-on sampled anatomy: every Nth step lands in the
+                    # continuous ring (PROFILE_SAMPLE_EVERY); off the lock, so
+                    # a flush can never stretch the locked section
+                    self.continuous.on_step(step_end, self.ledger.last_rec or {},
+                                            queue=q_depths, pool=pool_depths)
+                else:
+                    self.profiler.idle()
+                    self.ledger.idle()
+            with annotate("driver.emit", finished=len(finished)):
+                for rid in parked:
+                    # advisory event: the request is parked (KV in the host
+                    # tier) and will resume token-identically.  Disagg decode
+                    # consumers use it to fall back fused pre-first-token;
+                    # ordinary consumers just keep waiting for tokens.
+                    self._emit(rid, StreamEvent(type="parked"))
+                for res in finished:
+                    m_tokens.inc(len(res.output_tokens))
+                    if res.ttft_s is not None:
+                        m_ttft.observe(res.ttft_s)
+                    decoded = len(res.output_tokens) - 1  # first token is prefill's
+                    tpot = None
+                    if decoded > 0 and res.decode_time_s > 0:
+                        tpot = res.decode_time_s / decoded
+                        m_tpot.observe(tpot)
+                    if res.spec_proposed > 0:
+                        m_saccept.observe(res.spec_accepted / res.spec_proposed)
+                    self.slo.observe(
+                        self._priority.pop(res.request_id, None) or "interactive",
+                        ttft_s=res.ttft_s, tpot_s=tpot,
+                        deadline_missed=res.finish_reason == "deadline",
+                    )
+                    self.request_ring.append({
+                        "request_id": res.request_id,
+                        "prompt_tokens": len(res.prompt_tokens),
+                        "cached_tokens": res.cached_tokens,
+                        "output_tokens": len(res.output_tokens),
+                        "reason": res.finish_reason,
+                        "timings": res.timings,
+                    })
+                    self._emit(res.request_id, StreamEvent(type="final", result=res))
+                # keep burn rates decaying while no requests finish (recovery
+                # back to ok must not wait for the next completion)
+                self.slo.maybe_refresh()
             if not has_work:
-                self._wake.wait(timeout=0.02)
+                with annotate("driver.wait"):
+                    self._wake.wait(timeout=0.02)
                 self._wake.clear()
 
     def driver_alive(self) -> bool:
@@ -439,6 +467,7 @@ class AsyncEngine:
         deadline_s: float | None = None,
         priority: str | None = None,
         on_admit=None,
+        recv_t: float | None = None,
     ) -> AsyncIterator[StreamEvent]:
         """Submit a request and yield token events then the final event.
         ``deadline_s`` (absolute time.monotonic()) lets the engine reap the
@@ -447,7 +476,12 @@ class AsyncEngine:
         events count against (obs/slo.py).  ``on_admit(rid)`` fires on the
         event loop the moment the request is queued on the engine — the
         router uses it to retire its pending-admission claim exactly when
-        the load becomes visible in num_running/num_waiting."""
+        the load becomes visible in num_running/num_waiting.  ``recv_t`` is
+        when the caller received the request (an HTTP handler's first line);
+        without one, this call's entry.  The final result's ``timings``
+        carry it with ``enqueue_t`` and ``first_emit_t`` from here."""
+        if recv_t is None:
+            recv_t = time.monotonic()
         await self.start()
         q: asyncio.Queue[StreamEvent] = asyncio.Queue()
 
@@ -462,19 +496,34 @@ class AsyncEngine:
                 priority = "longctx"
         priority = priority or getattr(
             self.engine, "default_priority", "interactive")
-        with self._lock:
+        # the driver holds this lock through all of engine.step(): the wait
+        # for it stalls the whole event loop, so it has a stamp and a name
+        enqueue_t = time.monotonic()
+        with annotate("server.submit_wait"):
+            self._lock.acquire()
+        try:
             rid = self.engine.add_request(
                 prompt_ids, sampling, on_token=on_token, request_id=request_id,
                 deadline_s=deadline_s, priority=priority,
+                recv_t=recv_t, enqueue_t=enqueue_t,
             )
             self._queues[rid] = q
             self._priority[rid] = priority
+        finally:
+            self._lock.release()
         if on_admit is not None:
             on_admit(rid)
         self._wake.set()
+        first_emit_t = None
         try:
             while True:
                 event = await q.get()
+                if event.type == "token":
+                    if first_emit_t is None:
+                        first_emit_t = time.monotonic()
+                elif event.type == "final":
+                    if event.result.timings is not None:
+                        event.result.timings["first_emit_t"] = first_emit_t
                 yield event
                 if event.type == "final":
                     return

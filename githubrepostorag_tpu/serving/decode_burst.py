@@ -185,10 +185,11 @@ def decode_burst(
         # write avoids.
         def stage_at(sk_all, sv_all, li, k_new, v_new):
             """k_new/v_new: [B, 1, n_kv, hd] -> write at [li, :, :, step]."""
-            k_t = k_new.swapaxes(1, 2).astype(kv_dtype)[None, :, :, :]
-            v_t = v_new.swapaxes(1, 2).astype(kv_dtype)[None, :, :, :]
-            sk_all = jax.lax.dynamic_update_slice(sk_all, k_t, (li, 0, 0, step, 0))
-            sv_all = jax.lax.dynamic_update_slice(sv_all, v_t, (li, 0, 0, step, 0))
+            with jax.named_scope("kv_write"):
+                k_t = k_new.swapaxes(1, 2).astype(kv_dtype)[None, :, :, :]
+                v_t = v_new.swapaxes(1, 2).astype(kv_dtype)[None, :, :, :]
+                sk_all = jax.lax.dynamic_update_slice(sk_all, k_t, (li, 0, 0, step, 0))
+                sv_all = jax.lax.dynamic_update_slice(sv_all, v_t, (li, 0, 0, step, 0))
             return sk_all, sv_all
 
         if use_pallas:
@@ -205,6 +206,12 @@ def decode_burst(
             def make_attend(kp, vp, li, sk_all, sv_all):
                 def attend(q, k_new, v_new):
                     sk2, sv2 = stage_at(sk_all, sv_all, li, k_new, v_new)
+                    # NOT under named_scope("paged_attention") like the gather
+                    # path below: XLA names a custom call after its innermost
+                    # scope, so the kernel's instruction `closed_call.N`
+                    # would become `paged_attention.N` in the device trace,
+                    # and BENCHMARK.json's paged_attn_hbm_frac finds it by
+                    # the old name (PERF.md, Findings, PR 24)
                     out = kernel(
                         q, kp, vp, block_tables, start_lens,
                         jax.lax.dynamic_index_in_dim(sk2, li, 0, keepdims=False),
@@ -231,15 +238,17 @@ def decode_burst(
 
                 def attend(q, k_new, v_new):
                     sk2, sv2 = stage_at(sk_all, sv_all, li, k_new, v_new)
-                    sk = jax.lax.dynamic_index_in_dim(sk2, li, 0, keepdims=False)
-                    sv = jax.lax.dynamic_index_in_dim(sv2, li, 0, keepdims=False)
-                    k_all = jnp.concatenate([pool_k, sk.swapaxes(1, 2)], axis=1)
-                    v_all = jnp.concatenate([pool_v, sv.swapaxes(1, 2)], axis=1)
-                    valid = jnp.concatenate(
-                        [pool_valid, jnp.broadcast_to(staged_valid, (b, n_steps))],
-                        axis=1,
-                    )
-                    out = dense_attention(q, k_all, v_all, causal=False, kv_valid=valid)
+                    with jax.named_scope("paged_attention"):
+                        sk = jax.lax.dynamic_index_in_dim(sk2, li, 0, keepdims=False)
+                        sv = jax.lax.dynamic_index_in_dim(sv2, li, 0, keepdims=False)
+                        k_all = jnp.concatenate([pool_k, sk.swapaxes(1, 2)], axis=1)
+                        v_all = jnp.concatenate([pool_v, sv.swapaxes(1, 2)], axis=1)
+                        valid = jnp.concatenate(
+                            [pool_valid, jnp.broadcast_to(staged_valid, (b, n_steps))],
+                            axis=1,
+                        )
+                        out = dense_attention(q, k_all, v_all, causal=False,
+                                              kv_valid=valid)
                     return out, (sk2, sv2)
 
                 return attend
@@ -280,19 +289,19 @@ def decode_burst(
             layer_body, (h, staged_k, staged_v, 0), layer_xs,
             unroll=min(max(1, layer_unroll), L),
         )
-        logits = _logits(params, h, int4_kernel=int4_kernel)
-
-        if filter_sampling:
-            toks = sample_tokens_capped(
-                logits[:, 0], step_rng, temperature, top_p, top_k,
-                repetition_penalty, pres,
-            )
-        else:
-            # no running row filters: Gumbel-argmax over the full vocab,
-            # skipping the candidate sort (ops/sampling.py)
-            toks = sample_tokens_nofilter(
-                logits[:, 0], step_rng, temperature, repetition_penalty, pres,
-            )
+        with jax.named_scope("sample"):
+            logits = _logits(params, h, int4_kernel=int4_kernel)
+            if filter_sampling:
+                toks = sample_tokens_capped(
+                    logits[:, 0], step_rng, temperature, top_p, top_k,
+                    repetition_penalty, pres,
+                )
+            else:
+                # no running row filters: Gumbel-argmax over the full vocab,
+                # skipping the candidate sort (ops/sampling.py)
+                toks = sample_tokens_nofilter(
+                    logits[:, 0], step_rng, temperature, repetition_penalty, pres,
+                )
         toks = jnp.where(act, toks, last)
         pres = pres.at[rows, toks].max(act)
         lens = lens + act.astype(jnp.int32)
@@ -323,8 +332,9 @@ def decode_burst(
         vals = staged.swapaxes(1, 2).reshape(L, n_kv, b * n_steps, hd)
         return commit_paged(pools, vals, flat_slots, scales, page_size)
 
-    k_pages, k_scales = commit(k_pages, staged_k, k_scales)
-    v_pages, v_scales = commit(v_pages, staged_v, v_scales)
+    with jax.named_scope("kv_write"):
+        k_pages, k_scales = commit(k_pages, staged_k, k_scales)
+        v_pages, v_scales = commit(v_pages, staged_v, v_scales)
     if quant:
         return packed, valid, k_pages, v_pages, presence, out_lens, k_scales, v_scales
     return packed, valid, k_pages, v_pages, presence, out_lens

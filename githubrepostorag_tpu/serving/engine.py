@@ -77,9 +77,11 @@ class GenerationResult:
     ttft_s: float | None = None
     decode_time_s: float = 0.0
     error: str | None = None
-    # monotonic phase stamps (submit_t / prefill_start_t / first_token_t /
-    # done_t) for trace attribution — obs/engine_profile.record_engine_spans
-    # turns these into queue-wait / prefill / decode spans
+    # monotonic phase stamps, in order: recv_t (handler entry), enqueue_t
+    # (before the driver lock), submit_t, prefill_start_t, prefill_end_t
+    # (completing chunk dispatched), first_token_t, first_emit_t (first
+    # token off the stream queue; AsyncEngine.stream adds it), done_t —
+    # obs/engine_profile.record_engine_spans turns them into spans
     timings: dict | None = None
     # draft-model speculation accounting: tokens the draft proposed for
     # this request, tokens the target accepted, and the sticky fallback
@@ -94,6 +96,8 @@ class GenerationResult:
     # times this request was preempt-parked to the host tier and resumed
     # (0 = never preempted; output is token-identical either way)
     preempted: int = 0
+    # prompt tokens served from the prefix cache at (the last) admission
+    cached_tokens: int = 0
 
 
 @dataclass
@@ -115,7 +119,10 @@ class _Request:
     error: str | None = None
     submit_t: float = field(default_factory=time.monotonic)
     prefill_start_t: float | None = None  # admission: waiting -> prefilling
+    prefill_end_t: float | None = None  # completing chunk dispatched (host)
     first_token_t: float | None = None
+    recv_t: float | None = None  # caller's stamps (AsyncEngine.stream)
+    enqueue_t: float | None = None
     # absolute time.monotonic() budget; past it the request is reaped at
     # the next step boundary (pages freed) instead of decoding on for a
     # caller that stopped waiting
@@ -661,6 +668,24 @@ class Engine:
 
         # advisory page observatory (obs/hbm.py) — request-attribution seams
         self._page_obs = None
+        # the open host-phase annotation (utils/profiling.annotate)
+        self._phase_ann = None
+
+    # ------------------------------------------------------- host phases --
+
+    def _phase(self, name: str | None, **meta):
+        """Name the host work from here to the next ``_phase`` call: one
+        profiler annotation, opened after closing the one before it, so the
+        phases of a step never overlap whatever calls what (``None`` closes
+        without opening).  Returns the annotation (``set_metadata`` adds
+        counts known only at its end).  With no trace being taken this is
+        two C++ calls and no formatting."""
+        if self._phase_ann is not None:
+            self._phase_ann.__exit__(None, None, None)
+        self._phase_ann = ann = annotate(name, **meta) if name else None
+        if ann is not None:
+            ann.__enter__()
+        return ann
 
     # ------------------------------------------------- page observability --
 
@@ -697,12 +722,15 @@ class Engine:
         request_id: str | None = None,
         deadline_s: float | None = None,
         priority: str | None = None,
+        recv_t: float | None = None,
+        enqueue_t: float | None = None,
     ) -> str:
         rid = request_id or f"req-{next(self._ids)}"
         sampling = sampling or SamplingParams()
         req = _Request(request_id=rid, prompt=list(prompt_ids), sampling=sampling,
                        on_token=on_token, deadline_ts=deadline_s,
-                       priority=priority or self.default_priority)
+                       priority=priority or self.default_priority,
+                       recv_t=recv_t, enqueue_t=enqueue_t)
         req.orig_prompt_len = len(req.prompt)
         if len(req.prompt) + sampling.max_tokens > self.max_seq_len:
             req.sampling = sampling.clamped(self.max_seq_len - len(req.prompt))
@@ -781,6 +809,7 @@ class Engine:
         never stalls running streams: each of its prefill steps rides along
         with a full decode burst.  Returns requests finished this step."""
         finished: list[GenerationResult] = []
+        self._phase("engine.admit")
         for req in self._rejected:
             res = self._result(req, "error")
             res.error = req.error
@@ -853,6 +882,7 @@ class Engine:
             # nothing left running: land any in-flight burst (its tokens
             # belong to already-finished rows) and recycle deferred pages
             self._drain_chain(finished)
+        self._phase(None)
         return finished
 
     def _reap_expired(self) -> None:
@@ -959,6 +989,7 @@ class Engine:
                 # land the burst first: its commits may finish the victim
                 # we'd otherwise park, and deferred pages may be enough
                 self._drain_chain(finished)
+                self._phase("engine.admit")
                 continue
             victim = self._pick_victim()
             if victim is None:
@@ -1457,6 +1488,8 @@ class Engine:
             if not can_free and self._admission_feasible():
                 self._drain_chain(finished)
         # admit as many waiting requests as rows + pages allow
+        admit = self._phase("engine.admit")
+        admitted = 0
         cached_admits: list[_Request] = []  # batched presence marking below
         while self._waiting and self._free_rows:
             req = self._waiting[0]
@@ -1493,6 +1526,7 @@ class Engine:
                 self._allocator.release(shared)
                 break  # wait for running requests to finish
             self._waiting.pop(0)
+            admitted += 1
             row = self._free_rows.pop()
             req.row, req.pages, req.state = row, pages, "prefilling"
             req.prefill_start_t = time.monotonic()
@@ -1565,11 +1599,13 @@ class Engine:
                 jnp.asarray(lens),
                 self.cfg.vocab_size,
             )
+        admit.set_metadata(admitted=admitted, waiting=len(self._waiting))
         prefilling = [r for r in self._row_req.values() if r.state == "prefilling"]
         if not prefilling:
             return False
         long_reqs = [r for r in prefilling if self._sp_eligible(r) and r.prefill_pos == 0]
         if long_reqs:
+            self._phase("engine.prefill_batch")  # ring passes annotate inside
             if self.sp_ring_pack:
                 # segment-packed: every waiting long prompt that fits the
                 # ring token budget shares ONE pass; the rest keep their
@@ -1600,10 +1636,13 @@ class Engine:
         fetched — the wave is queued on device and commits with the next
         burst, so admissions never stall running streams on a host sync."""
         if self.prefill_token_budget is not None:
+            # the packed dispatch keeps its own annotation inside the phase
+            self._phase("engine.prefill_batch")
             self._prefill_batch_packed(reqs, finished)
             return
         others_running = any(r.state == "running" for r in self._row_req.values())
         n = len(reqs)
+        wave_ann = self._phase("engine.prefill_batch")
         # Shape discipline: row count buckets to powers of two, width comes
         # from the fixed prefill_width_buckets set (a single value —
         # prefill_chunk — unless prefill_widths > 1).  Every distinct device
@@ -1631,6 +1670,14 @@ class Engine:
             bt[i] = self._block_tables[req.row]
             cached[i] = start
             new_lens[i] = valid
+        # the wave's real work, for whoever reads the trace: tokens already in
+        # the cache, tokens this chunk adds, the (query, key) pairs they
+        # attend, and the prompts this chunk completes
+        starts = [int(c) for c in cached[:n]]
+        wave_ann.set_metadata(
+            rows=n, new_tokens=sum(valids), cached_tokens=sum(starts),
+            pairs=sum(v * c + v * (v + 1) // 2 for v, c in zip(valids, starts)),
+            completes=sum(c + v >= len(r.prompt) for v, c, r in zip(valids, starts, reqs)))
 
         # logits only at each row's last valid position: full-position
         # prefill logits are [rb, width, V] float32 — GBs at 64 rows
@@ -1642,22 +1689,21 @@ class Engine:
         cached_d, new_lens_d = jnp.asarray(cached), jnp.asarray(new_lens)
         last_idx_d = jnp.asarray(last_idx)
         self.step_dispatches_total += 1
-        with annotate("engine.prefill_batch"):
-            out = forward_paged(
-                self.params, self.cfg,
-                ids_d, pos_d,
-                self._k_pages, self._v_pages,
-                slots_d, bt_d,
-                cached_d, new_lens_d,
-                use_pallas=self.use_pallas, logits_at=last_idx_d,
-                k_scales=self._k_scales, v_scales=self._v_scales,
-                int4_kernel=self._int4_kernel, mesh=self.mesh,
-            )
-            if self.kv_quant:
-                (logits, self._k_pages, self._v_pages,
-                 self._k_scales, self._v_scales) = out
-            else:
-                logits, self._k_pages, self._v_pages = out
+        out = forward_paged(
+            self.params, self.cfg,
+            ids_d, pos_d,
+            self._k_pages, self._v_pages,
+            slots_d, bt_d,
+            cached_d, new_lens_d,
+            use_pallas=self.use_pallas, logits_at=last_idx_d,
+            k_scales=self._k_scales, v_scales=self._v_scales,
+            int4_kernel=self._int4_kernel, mesh=self.mesh,
+        )
+        if self.kv_quant:
+            (logits, self._k_pages, self._v_pages,
+             self._k_scales, self._v_scales) = out
+        else:
+            logits, self._k_pages, self._v_pages = out
         if self._draft_enabled:
             # the draft model prefills the SAME chunk into its own pools
             # (same slots/block tables — the pools are position-aligned by
@@ -1717,17 +1763,8 @@ class Engine:
         )
         safe = jnp.where(jnp.asarray(done_mask), tokens_d, self.cfg.vocab_size)
         self._presence = _mark_presence_rows(self._presence, row_d, safe)
-        wave = [(reqs[i], i) for i in done_idx]
-        for req, _ in wave:
-            req.state = "running"
-        if self._commit_first_now(others_running):
-            # engine idle (nothing to overlap the sync with) or speculative
-            # mode (synchronous by design): commit immediately (best TTFT)
-            tokens = np.asarray(tokens_d)
-            for req, i in wave:
-                self._commit_token(req, int(tokens[i]), finished)
-        else:
-            self._pending_first.append((tokens_d, wave))
+        self._first_wave(tokens_d, [(reqs[i], i) for i in done_idx],
+                         others_running, finished)
 
     def _prefill_batch_packed(
         self, reqs: list[_Request], finished: list[GenerationResult]
@@ -1911,15 +1948,8 @@ class Engine:
         )
         safe = jnp.where(jnp.asarray(done_mask), tokens_d, self.cfg.vocab_size)
         self._presence = _mark_presence_rows(self._presence, row_d, safe)
-        wave = [(packed[i][0], i) for i in done_idx]
-        for req, _ in wave:
-            req.state = "running"
-        if self._commit_first_now(others_running):
-            tokens = np.asarray(tokens_d)
-            for req, i in wave:
-                self._commit_token(req, int(tokens[i]), finished)
-        else:
-            self._pending_first.append((tokens_d, wave))
+        self._first_wave(tokens_d, [(packed[i][0], i) for i in done_idx],
+                         others_running, finished)
 
     def _sp_prefill(self, req: _Request, finished: list[GenerationResult]) -> None:
         """Whole-prompt sequence-parallel prefill: one ring-attention program
@@ -1974,14 +2004,8 @@ class Engine:
             self._rep_pen_d[row_d], self._presence[row_d],
         )
         self._presence = _mark_presence_rows(self._presence, row_d, tokens_d)
-        req.state = "running"
-        others_running = any(
-            r.state == "running" and r is not req for r in self._row_req.values()
-        )
-        if self._commit_first_now(others_running):
-            self._commit_token(req, int(np.asarray(tokens_d)[0]), finished)
-        else:
-            self._pending_first.append((tokens_d, [(req, 0)]))
+        others_running = any(r.state == "running" for r in self._row_req.values())
+        self._first_wave(tokens_d, [(req, 0)], others_running, finished)
 
     def _sp_prefill_packed(
         self, reqs: list[_Request], finished: list[GenerationResult]
@@ -2080,15 +2104,8 @@ class Engine:
         live[: len(packed)] = True
         safe = jnp.where(jnp.asarray(live), tokens_d, self.cfg.vocab_size)
         self._presence = _mark_presence_rows(self._presence, row_d, safe)
-        wave = [(req, i) for i, req in enumerate(packed)]
-        for req in packed:
-            req.state = "running"
-        if self._commit_first_now(others_running):
-            tokens = np.asarray(tokens_d)
-            for req, i in wave:
-                self._commit_token(req, int(tokens[i]), finished)
-        else:
-            self._pending_first.append((tokens_d, wave))
+        self._first_wave(tokens_d, [(req, i) for i, req in enumerate(packed)],
+                         others_running, finished)
         return packed
 
     def _decode_step(self, finished: list[GenerationResult]) -> None:
@@ -2103,14 +2120,18 @@ class Engine:
         burst references them (``_drain_chain``)."""
         from githubrepostorag_tpu.serving.decode_burst import decode_burst
 
+        self._phase("engine.burst_prepare")
         b = self.max_num_seqs
         active = np.zeros((b,), dtype=bool)
         remaining = 1
+        live_rows = kv_tokens = 0  # running rows and their cached tokens, for the trace
         for row, req in self._row_req.items():
             active[row] = req.state == "running"  # mid-prefill rows sit out
             if req.state == "running":  # mid-prefill budgets don't hold the
                 # drain shortcut open: they can't consume burst tokens yet
                 remaining = max(remaining, req.sampling.max_tokens - len(req.output))
+                live_rows += 1
+                kv_tokens += req.seq_len
         # ONE compiled burst shape: always decode_burst steps.  Overshoot
         # past a row's max_tokens is discarded at commit — with continuous
         # batching the "wasted" steps still serve every other running row,
@@ -2159,36 +2180,37 @@ class Engine:
         self._rng, key = jax.random.split(self._rng)
 
         self.step_dispatches_total += 1
-        with annotate("engine.decode_burst"):
-            out = decode_burst(
-                self.params, self.cfg,
-                last_d, lens_d,
-                self._k_pages, self._v_pages, self._presence,
-                jnp.asarray(active), jnp.asarray(self._row_limits),
-                jnp.asarray(self._block_tables), key,
-                self._temp_d, self._top_p_d, self._top_k_d, self._rep_pen_d,
-                n_steps=n_steps, use_pallas=self.use_pallas, mesh=self.mesh,
-                layer_unroll=self.layer_unroll,
-                # sort-free sampling whenever no SAMPLING row filters —
-                # greedy rows (temp <= 0) take the exact argmax regardless
-                # of their top_p/top_k, so an all-greedy batch (e.g. the
-                # ingest extractors) skips the candidate sort even at the
-                # default top_p=0.9.  Free rows are reset at release, so
-                # this is exactly the running set.
-                filter_sampling=bool(
-                    np.any(
-                        (self._temp > 0.0)
-                        & ((self._top_p < 1.0) | (self._top_k > 0))
-                    )
-                ),
-                k_scales=self._k_scales, v_scales=self._v_scales,
-            )
-            if self.kv_quant:
-                (toks, valid, self._k_pages, self._v_pages, self._presence,
-                 out_lens, self._k_scales, self._v_scales) = out
-            else:
-                (toks, valid, self._k_pages, self._v_pages, self._presence,
-                 out_lens) = out
+        self._phase("engine.decode_burst", rows=live_rows, kv_tokens=kv_tokens,
+                    steps=n_steps)
+        out = decode_burst(
+            self.params, self.cfg,
+            last_d, lens_d,
+            self._k_pages, self._v_pages, self._presence,
+            jnp.asarray(active), jnp.asarray(self._row_limits),
+            jnp.asarray(self._block_tables), key,
+            self._temp_d, self._top_p_d, self._top_k_d, self._rep_pen_d,
+            n_steps=n_steps, use_pallas=self.use_pallas, mesh=self.mesh,
+            layer_unroll=self.layer_unroll,
+            # sort-free sampling whenever no SAMPLING row filters —
+            # greedy rows (temp <= 0) take the exact argmax regardless
+            # of their top_p/top_k, so an all-greedy batch (e.g. the
+            # ingest extractors) skips the candidate sort even at the
+            # default top_p=0.9.  Free rows are reset at release, so
+            # this is exactly the running set.
+            filter_sampling=bool(
+                np.any(
+                    (self._temp > 0.0)
+                    & ((self._top_p < 1.0) | (self._top_k > 0))
+                )
+            ),
+            k_scales=self._k_scales, v_scales=self._v_scales,
+        )
+        if self.kv_quant:
+            (toks, valid, self._k_pages, self._v_pages, self._presence,
+             out_lens, self._k_scales, self._v_scales) = out
+        else:
+            (toks, valid, self._k_pages, self._v_pages, self._presence,
+             out_lens) = out
         prev = self._chain
         self._chain = {
             "last": toks[:, -1], "lens": out_lens, "pending": toks,
@@ -2205,6 +2227,7 @@ class Engine:
         on the packed tokens, like _commit_burst."""
         from githubrepostorag_tpu.serving.spec_burst import spec_decode_burst
 
+        self._phase("engine.burst_prepare")
         k = self.spec_ngram_k
         running = [r for r in self._row_req.values() if r.state == "running"]
         rb = _bucket(len(running), self.max_num_seqs, minimum=1)
@@ -2240,8 +2263,10 @@ class Engine:
              self._k_scales, self._v_scales) = out
         else:
             toks_d, prop_d, self._k_pages, self._v_pages = out
+        self._phase("engine.commit_fetch")
         toks = np.asarray(toks_d)  # [rb, iters, k+1], -1 padded
         prop = np.asarray(prop_d)  # [rb, iters]
+        self._phase("engine.commit_host")
         for i, req in enumerate(running):
             for it in range(toks.shape[1]):
                 if req.state != "running":
@@ -2273,6 +2298,7 @@ class Engine:
         finishing prefill join the NEXT step's burst."""
         from githubrepostorag_tpu.serving.fused_step import fused_step_burst
 
+        self._phase("engine.burst_prepare")
         k = self.spec_ngram_k
         running = [r for r in self._row_req.values() if r.state == "running"]
         rb = _bucket(len(running), self.max_num_seqs, minimum=1)
@@ -2349,8 +2375,10 @@ class Engine:
             # tokens (spec modes commit first tokens synchronously —
             # _commit_first_now is True whenever spec_ngram_k > 0)
             self._finish_packed_wave(pf_wave, pf_logits, finished, True)
+        self._phase("engine.commit_fetch")
         toks = np.asarray(toks_d)  # [rb, iters, k+1], -1 padded
         prop = np.asarray(prop_d)  # [rb, iters] — 0 on sampled rows
+        self._phase("engine.commit_host")
         for i, req in enumerate(running):
             for it in range(toks.shape[1]):
                 if req.state != "running":
@@ -2431,6 +2459,7 @@ class Engine:
             # pipeline) is in flight: land it so the history/lens snapshot
             # below sees every committed token
             self._drain_chain(finished)
+        self._phase("engine.burst_prepare")
         running = [r for r in self._row_req.values() if r.state == "running"]
         if not running:
             return
@@ -2474,8 +2503,10 @@ class Engine:
         # ONE [rb, iters, k+1] fetch per dispatch; every acceptance-rate
         # read below is host numpy (no per-iteration device round trips —
         # the tpulint TPU007 hazard this step was designed around)
+        self._phase("engine.commit_fetch")
         toks = np.asarray(toks_d)
         prop = np.asarray(prop_d)
+        self._phase("engine.commit_host")
         for i, req in enumerate(running):
             proposed = accepted = 0
             for it in range(toks.shape[1]):
@@ -2523,6 +2554,7 @@ class Engine:
         trade-off against pipelined bursts."""
         from githubrepostorag_tpu.serving.spec_decode import ngram_propose
 
+        self._phase("engine.burst_prepare")
         k = self.spec_ngram_k
         width = k + 1
         running = [r for r in self._row_req.values() if r.state == "running"]
@@ -2584,6 +2616,7 @@ class Engine:
         row_idx = np.zeros((rb,), dtype=np.int32)
         row_idx[: len(running)] = [r.row for r in running]
         row_d = jnp.asarray(row_idx)
+        self._phase("engine.commit_fetch")
         greedy_toks = np.asarray(jnp.argmax(logits, axis=-1))  # [rb, width]
         sampled0 = None
         if not all(plain_greedy):
@@ -2596,6 +2629,7 @@ class Engine:
             ))
 
         # sentinel-padded committed-token matrix -> one batched presence mark
+        self._phase("engine.commit_host")
         committed = np.full((rb, width), self.cfg.vocab_size, dtype=np.int32)
         counts = np.zeros((rb,), dtype=np.int32)
         for i, req in enumerate(running):
@@ -2623,6 +2657,29 @@ class Engine:
             jnp.asarray(counts), self.cfg.vocab_size,
         )
 
+    def _first_wave(
+        self,
+        tokens_d: jnp.ndarray,
+        wave: list[tuple[_Request, int]],
+        others_running: bool,
+        finished: list[GenerationResult],
+    ) -> None:
+        """The chunk that completed these prompts is dispatched and their
+        first tokens are sampled on device: the rows join the running set.
+        With the engine otherwise idle (nothing to overlap the sync with) or
+        in speculative mode (synchronous by design) the tokens commit now
+        (best TTFT); else the wave stays on device and commits with the
+        next burst's fetch, so admissions never stall running streams."""
+        now = time.monotonic()
+        for req, _ in wave:
+            req.state = "running"
+            if req.prefill_end_t is None:  # a resumed request keeps its first
+                req.prefill_end_t = now
+        if self._commit_first_now(others_running):
+            self._commit_first_tokens([(tokens_d, wave)], finished)
+        else:
+            self._pending_first.append((tokens_d, wave))
+
     def _commit_first_tokens(
         self,
         waves: list[tuple[jnp.ndarray, list[tuple[_Request, int]]]],
@@ -2630,12 +2687,14 @@ class Engine:
     ) -> None:
         """Fetch + commit deferred prefill first-token waves."""
         for tokens_d, wave in waves:
-            tokens = None
-            for req, i in wave:
-                if req.state != "running" or req.output:
-                    continue  # cancelled/released, or already committed
-                if tokens is None:
-                    tokens = np.asarray(tokens_d)
+            live = [(req, i) for req, i in wave
+                    if req.state == "running" and not req.output]
+            if not live:
+                continue  # cancelled/released, or already committed
+            self._phase("engine.commit_fetch")
+            tokens = np.asarray(tokens_d)
+            self._phase("engine.commit_host", tokens=len(live))
+            for req, i in live:
                 self._commit_token(req, int(tokens[i]), finished)
 
     def _commit_burst(self, entry: dict, finished: list[GenerationResult]) -> None:
@@ -2646,7 +2705,9 @@ class Engine:
         (row, i) holds -1 where the row was inactive; rows already released
         ignore their tokens."""
         self._commit_first_tokens(entry.get("first", []), finished)
+        self._phase("engine.commit_fetch")
         toks = np.asarray(entry["pending"])  # [B, n_steps]
+        self._phase("engine.commit_host", tokens=int((toks >= 0).sum()))
         for i in range(toks.shape[1]):
             for row in sorted(self._row_req):
                 req = self._row_req.get(row)
@@ -2766,11 +2827,15 @@ class Engine:
             ttft_s=ttft,
             decode_time_s=(done_t - req.first_token_t) if req.first_token_t else 0.0,
             timings={
+                "recv_t": req.recv_t,
+                "enqueue_t": req.enqueue_t,
                 "submit_t": req.submit_t,
                 "prefill_start_t": req.prefill_start_t,
+                "prefill_end_t": req.prefill_end_t,
                 "first_token_t": req.first_token_t,
                 "done_t": done_t,
             },
+            cached_tokens=req.cached_tokens,
             spec_proposed=req.spec_proposed_req,
             spec_accepted=req.spec_accepted_req,
             spec_fallback=req.spec_fallback,

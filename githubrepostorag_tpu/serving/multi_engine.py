@@ -492,7 +492,11 @@ class MultiAsyncEngine:
         request_id: str | None = None,
         deadline_s: float | None = None,
         priority: str | None = None,
+        recv_t: float | None = None,
     ) -> AsyncIterator[StreamEvent]:
+        # ``recv_t`` (the caller's receipt stamp) rides to the serving
+        # replica's request record; a disaggregated request's two legs
+        # each stamp their own entry instead.
         # engines generate per-engine "req-N" ids that would collide across
         # replicas; mint a process-unique id when the caller didn't
         rid = request_id or f"mreq-{next(self._ids)}"
@@ -504,7 +508,7 @@ class MultiAsyncEngine:
         else:
             target, granted = self._pick(prompt_ids)
             events = self._stream_on(target, granted, prompt_ids, sampling,
-                                     rid, deadline_s, priority)
+                                     rid, deadline_s, priority, recv_t=recv_t)
         async for event in events:
             yield event
 
@@ -517,6 +521,7 @@ class MultiAsyncEngine:
         rid: str,
         deadline_s: float | None,
         priority: str,
+        recv_t: float | None = None,
     ) -> AsyncIterator[StreamEvent]:
         """Run ``rid`` on the already-picked ``target``, owning the route
         map, pending-claim, and breaker bookkeeping end to end."""
@@ -535,7 +540,7 @@ class MultiAsyncEngine:
         try:
             async for event in target.stream(
                 prompt_ids, sampling, request_id=rid, deadline_s=deadline_s,
-                priority=priority, on_admit=on_admit,
+                priority=priority, on_admit=on_admit, recv_t=recv_t,
             ):
                 if event.type == "final":
                     # settle breaker + route eagerly at the final token, not

@@ -21,6 +21,8 @@ import uuid
 
 from aiohttp import web
 
+from githubrepostorag_tpu.obs.engine_profile import record_engine_spans
+from githubrepostorag_tpu.obs.trace import current_context, root_span
 from githubrepostorag_tpu.serving.async_engine import AsyncEngine
 from githubrepostorag_tpu.serving.sampling_params import SamplingParams
 from githubrepostorag_tpu.serving.tokenizer import StreamingDetokenizer, Tokenizer
@@ -167,32 +169,37 @@ class OpenAIServer:
         )
 
     async def chat_completions(self, request: web.Request) -> web.StreamResponse:
-        try:
-            body = await request.json()
-            messages = body["messages"]
-        except (json.JSONDecodeError, KeyError) as exc:
-            return _error_response(f"invalid request body: {exc}", status=400)
-        if hasattr(self.tokenizer, "encode_chat"):
-            prompt_ids = self.tokenizer.encode_chat(messages)
-        else:  # pragma: no cover - all in-tree tokenizers have encode_chat
-            prompt_ids = self.tokenizer.encode(
-                self.tokenizer.apply_chat_template(messages)
-            )
-        return await self._serve(request, body, prompt_ids, chat=True)
+        recv_t = time.monotonic()  # the request record's first stamp
+        with _request_span(request):
+            try:
+                body = await request.json()
+                messages = body["messages"]
+            except (json.JSONDecodeError, KeyError) as exc:
+                return _error_response(f"invalid request body: {exc}", status=400)
+            if hasattr(self.tokenizer, "encode_chat"):
+                prompt_ids = self.tokenizer.encode_chat(messages)
+            else:  # pragma: no cover - all in-tree tokenizers have encode_chat
+                prompt_ids = self.tokenizer.encode(
+                    self.tokenizer.apply_chat_template(messages)
+                )
+            return await self._serve(request, body, prompt_ids, chat=True, recv_t=recv_t)
 
     async def completions(self, request: web.Request) -> web.StreamResponse:
-        try:
-            body = await request.json()
-            prompt = body["prompt"]
-        except (json.JSONDecodeError, KeyError) as exc:
-            return _error_response(f"invalid request body: {exc}", status=400)
-        prompt_ids = self.tokenizer.encode(prompt)
-        return await self._serve(request, body, prompt_ids, chat=False)
+        recv_t = time.monotonic()
+        with _request_span(request):
+            try:
+                body = await request.json()
+                prompt = body["prompt"]
+            except (json.JSONDecodeError, KeyError) as exc:
+                return _error_response(f"invalid request body: {exc}", status=400)
+            prompt_ids = self.tokenizer.encode(prompt)
+            return await self._serve(request, body, prompt_ids, chat=False, recv_t=recv_t)
 
     # ------------------------------------------------------------- core
 
     async def _serve(
-        self, request: web.Request, body: dict, prompt_ids: list[int], chat: bool
+        self, request: web.Request, body: dict, prompt_ids: list[int], chat: bool,
+        recv_t: float,
     ) -> web.StreamResponse:
         sampling = _sampling_from_request(body, self.tokenizer, self.default_max_tokens)
         rid = f"chatcmpl-{uuid.uuid4().hex}" if chat else f"cmpl-{uuid.uuid4().hex}"
@@ -204,14 +211,14 @@ class OpenAIServer:
             body.get("priority") or get_settings().priority_default_class)
         if body.get("stream"):
             return await self._serve_stream(request, sampling, prompt_ids, rid, chat,
-                                            priority=priority)
+                                            priority=priority, recv_t=recv_t)
 
         detok = StreamingDetokenizer(self.tokenizer)
         text_parts: list[str] = []
         result = None
         stopped_on_string = False
         async for event in self.engine.stream(prompt_ids, sampling, request_id=rid,
-                                              priority=priority):
+                                              priority=priority, recv_t=recv_t):
             if event.type == "token":
                 text_parts.append(detok.push(event.token_id))
                 full = "".join(text_parts)
@@ -222,6 +229,7 @@ class OpenAIServer:
                     stopped_on_string = True
             elif event.type == "final":
                 result = event.result
+                record_engine_spans(result, parent=current_context())
             # "parked" (preempt-to-host) is advisory: the request resumes
             # token-identically, so just keep waiting
         text_parts.append("" if stopped_on_string else detok.flush())
@@ -268,6 +276,7 @@ class OpenAIServer:
         rid: str,
         chat: bool,
         priority: str = "interactive",
+        recv_t: float | None = None,
     ) -> web.StreamResponse:
         resp = web.StreamResponse(
             status=200,
@@ -287,7 +296,7 @@ class OpenAIServer:
         finish = None
         try:
             async for event in self.engine.stream(prompt_ids, sampling, request_id=rid,
-                                                  priority=priority):
+                                                  priority=priority, recv_t=recv_t):
                 if event.type == "token":
                     delta = detok.push(event.token_id)
                     emitted += delta
@@ -304,6 +313,7 @@ class OpenAIServer:
                     if delta and finish is None:
                         await send(self._chunk(rid, chat, delta, None))
                 elif event.type == "final":
+                    record_engine_spans(event.result, parent=current_context())
                     if finish is None:
                         tail = detok.flush()
                         if tail:
@@ -338,6 +348,14 @@ class OpenAIServer:
             "model": self.model_name,
             "choices": [{"index": 0, "text": content or "", "finish_reason": finish}],
         }
+
+
+def _request_span(request: web.Request):
+    """Root span per generation request, as the API pod opens one per /rag
+    request (api/app.py): an incoming ``traceparent`` is continued, and
+    TRACE_SAMPLE decides whether the flight recorder keeps it."""
+    return root_span(f"http {request.method} {request.path}",
+                     wire=request.headers.get("traceparent"))
 
 
 def _find_stop(text: str, stops: tuple[str, ...]) -> int | None:

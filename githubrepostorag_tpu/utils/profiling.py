@@ -1,10 +1,33 @@
 """jax.profiler integration (SURVEY.md §5.1).
 
 Two layers:
-  - ``annotate(name)`` — a TraceAnnotation context manager marking the hot
-    host-side regions (prefill dispatch, decode burst, embed batch, ingest
-    stages) so device traces carry semantic names.  Degrades to a no-op on
-    backends/builds without profiler support.
+  - ``annotate(name, **meta)`` — a TraceAnnotation context manager: a host
+    span on the device trace's own clock.  ``meta`` (ints, floats, short
+    strings) rides as the event's stats; it is encoded only while a trace
+    is being taken, so with tracing off an annotation is one C++ object
+    and no formatting.  The names that exist (PERF.md §3 has the metric
+    that reads each):
+
+      driver.step        the locked section of AsyncEngine._drive with
+                         work; ``mono_ns`` anchors time.monotonic() stamps
+                         (GenerationResult.timings, obs/) to the trace
+      driver.export      counters, gauges, ledger, rings (under the
+                         lock, then after it)
+      driver.emit        parked/final events to the event loop, SLO monitor
+      driver.wait        the driver asleep with no work
+      server.submit_wait the event loop waiting for the driver's lock
+      engine.admit       reaping, preemption, admission, page allocation
+      engine.prefill_batch (+ _draft, prefill_packed, sp_prefill...)
+                         one prefill wave: host arrays, dispatch, sampling
+      engine.burst_prepare  masks, first-wave overlay, sampling push, RNG
+      engine.decode_burst (+ spec_burst, fused_step, draft_spec_burst,
+                         spec_decode) the dispatch call
+      engine.commit_fetch   the blocking device->host fetch of a burst
+      engine.commit_host    per-token bookkeeping, callbacks, results
+      embed.batch        one encoder batch, dispatch to vectors on host
+      index.search       one device-index wave, dispatch to hits on host
+      encoder.warmup, ingest.<stage>
+
   - ``maybe_trace()`` — env-gated whole-run capture: when
     ``JAX_PROFILE_DIR`` is set, wraps the block in
     jax.profiler.start_trace/stop_trace, producing a TensorBoard-loadable
@@ -14,7 +37,7 @@ Two layers:
 from __future__ import annotations
 
 import os
-from contextlib import contextmanager, nullcontext
+from contextlib import contextmanager
 
 from githubrepostorag_tpu.utils.logging import get_logger
 
@@ -23,14 +46,32 @@ logger = get_logger(__name__)
 PROFILE_DIR_ENV = "JAX_PROFILE_DIR"
 
 
-def annotate(name: str):
-    """TraceAnnotation for the named region; no-op if unsupported."""
-    try:
-        import jax
+try:  # resolved once: annotate() sits on the engine's step path
+    from jax.profiler import TraceAnnotation as _Annotation
+except Exception:  # noqa: BLE001 - a build without profiler support
+    _Annotation = None
 
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # noqa: BLE001 - profiling must never break the path
-        return nullcontext()
+
+class _NoAnnotation:
+    """What annotate() returns on a build without a profiler."""
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def set_metadata(self, **meta) -> None:
+        pass
+
+
+def annotate(name: str, **meta):
+    """TraceAnnotation for the named region, ``meta`` as its stats (more can
+    follow through ``set_metadata`` before it closes); a no-op context if
+    this build has no profiler.  Never raises."""
+    if _Annotation is None:
+        return _NoAnnotation()
+    return _Annotation(name, **meta)
 
 
 @contextmanager
