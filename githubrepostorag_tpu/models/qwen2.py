@@ -392,10 +392,10 @@ def _attend_per_tp_shard(attn_fn, mesh, quant: bool):
     from jax.sharding import PartitionSpec as P
 
     heads = P(None, None, "tp", None)  # q / out [B, S, n_q, hd]
-    pool = P("tp", None, None, None)  # one layer's pages [n_kv, P, ps, hd]
-    in_specs = [heads, pool, pool, P(None, None), P(None), P(None)]
+    pool = P(None, "tp", None, None, None)  # whole pools [L, n_kv, P, ps, hd]
+    in_specs = [heads, pool, pool, P(None, None), P(None), P(None), P()]
     if quant:
-        in_specs += [P("tp", None)] * 2  # [n_kv, P] page scales
+        in_specs += [P(None, "tp", None)] * 2  # [L, n_kv, P] page scales
     return jax.shard_map(
         attn_fn, mesh=mesh, in_specs=tuple(in_specs), out_specs=heads,
         check_vma=False,
@@ -486,9 +486,12 @@ def forward_paged_impl(
         # run ops/fused_decode's flash window kernel — the old dispatcher
         # routed S > 1 and quantized pools to the materialized gather_kv
         # fallback, a full [B, mp*ps, n_kv, hd] HBM copy per layer.
-        from githubrepostorag_tpu.ops.fused_decode import (
-            fused_paged_attention as attn_fn,
-        )
+        from githubrepostorag_tpu.ops.fused_decode import fused_paged_attention
+
+        def attn_fn(q, kp, vp, bt, cached, new, layer, *scales):
+            return fused_paged_attention(q, kp, vp, bt, cached, new, *scales,
+                                         layer=layer)
+
         if mesh is not None:
             attn_fn = _attend_per_tp_shard(attn_fn, mesh, quant)
     else:
@@ -509,13 +512,15 @@ def forward_paged_impl(
 
     scan_layers, q4_stacks = _split_q4(params["layers"])
 
-    def body(carry, layer_xs):
-        h, li = carry
-        if quant:
-            p, kp, vp, ks, vs = layer_xs
-        else:
-            p, kp, vp = layer_xs
-            ks = vs = None
+    # The pools (and a quantized pool's page scales) ride the layer scan as
+    # CARRY, whole: layer li is committed by index (kv_cache.commit_paged's
+    # carried form) and read through the kernel's index map.  As scan xs/ys
+    # every layer's slab is sliced out, re-laid-out around its scatter and
+    # written into a second stacked pool that two whole-pool copies then
+    # reconcile with the donated one: 44% of a 512-token chunk's device
+    # time at Qwen2-7B widths (PERF.md, Findings, PR 25).
+    def body(carry, p):
+        h, li, kp, vp, ks, vs = carry
         # prefill / spec-verify chunks pin w4a8=False: prompt processing
         # keeps the exact bf16-dequant contract even when the chunk is
         # decode-sized (the auto gate must never catch a prefill batch)
@@ -530,32 +535,27 @@ def forward_paged_impl(
             # pools; per-page first-write scales for int8 — same semantics
             # as the burst and ring-prefill commits)
             with jax.named_scope("kv_write"):
-                new_kp, new_ks = commit_paged(
-                    kp, k_t, flat_slots, ks if quant else None, page_size
-                )
-                new_vp, new_vs = commit_paged(
-                    vp, v_t, flat_slots, vs if quant else None, page_size
-                )
+                new_kp, new_ks = commit_paged(kp, k_t, flat_slots, ks, page_size, layer=li)
+                new_vp, new_vs = commit_paged(vp, v_t, flat_slots, vs, page_size, layer=li)
             with jax.named_scope("paged_attention"):
-                if quant:
+                scales = (new_ks, new_vs) if quant else ()
+                if use_pallas:
                     attn = attn_fn(q, new_kp, new_vp, block_tables, cached_lens,
-                                   new_lens, new_ks, new_vs)
-                    return attn, (new_kp, new_vp, new_ks, new_vs)
-                attn = attn_fn(q, new_kp, new_vp, block_tables, cached_lens, new_lens)
-                return attn, (new_kp, new_vp)
+                                   new_lens, li, *scales)
+                else:
+                    # the CPU/oracle path reads one layer's slab
+                    attn = attn_fn(
+                        q, new_kp[li], new_vp[li], block_tables, cached_lens,
+                        new_lens, *(sc[li] for sc in scales),
+                    )
+            return attn, (new_kp, new_vp, new_ks, new_vs)
 
         h, cache = _block(cfg, h, p, cos, sin, attend)
-        return (h, li + 1), cache
+        return (h, li + 1, *cache), None
 
-    if quant:
-        xs = (scan_layers, k_pages, v_pages, k_scales, v_scales)
-        (h, _), (k_pages, v_pages, k_scales, v_scales) = jax.lax.scan(
-            body, (h, 0), xs
-        )
-    else:
-        (h, _), (k_pages, v_pages) = jax.lax.scan(
-            body, (h, 0), (scan_layers, k_pages, v_pages)
-        )
+    (h, _, k_pages, v_pages, k_scales, v_scales), _ = jax.lax.scan(
+        body, (h, 0, k_pages, v_pages, k_scales, v_scales), scan_layers
+    )
     with jax.named_scope("sample"):  # the head; the first token's draw is the engine's
         h = rms_norm(h, params["norm"], cfg.rms_norm_eps)
         if logits_at is not None:

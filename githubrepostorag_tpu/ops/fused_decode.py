@@ -47,18 +47,18 @@ NEG_INF = -1e30
 
 
 def _fused_window_kernel(
-    # scalar prefetch (quant == 0 omits the two scale refs)
+    # scalar prefetch: block tables, cached and total lens, then the two
+    # scale refs (quant != 0), then the layer index (rank-5 pools; only the
+    # index maps read it), then blocks and scratch
     *refs,
     page_size: int,
     scale: float,
     quant: int,  # 0 = full precision, 8 = int8 pages, 4 = int4 nibble pages
 ):
+    block_tables_ref, cached_lens_ref, total_lens_ref = refs[:3]
     if quant:
-        (block_tables_ref, cached_lens_ref, total_lens_ref, ks_ref, vs_ref,
-         q_ref, k_ref, v_ref, out_ref, m_ref, l_ref, acc_ref) = refs
-    else:
-        (block_tables_ref, cached_lens_ref, total_lens_ref,
-         q_ref, k_ref, v_ref, out_ref, m_ref, l_ref, acc_ref) = refs
+        ks_ref, vs_ref = refs[3:5]
+    q_ref, k_ref, v_ref, out_ref, m_ref, l_ref, acc_ref = refs[-7:]
 
     bi = pl.program_id(0)
     hi = pl.program_id(1)
@@ -168,20 +168,29 @@ def _fused_window_kernel(
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def fused_window_attention(
     q_win: jnp.ndarray,  # [B, S, n_q, hd] — per-row windows based at cached_lens
-    k_pages: jnp.ndarray,  # [n_kv, P, page_size, hd] (or [.., hd//2] uint8 int4)
+    k_pages: jnp.ndarray,  # [(L,) n_kv, P, page_size, hd] (or [.., hd//2] uint8 int4)
     v_pages: jnp.ndarray,
     block_tables: jnp.ndarray,  # [B, max_pages]
     cached_lens: jnp.ndarray,  # [B]
     new_lens: jnp.ndarray,  # [B] valid new tokens (<= S) — already committed
-    k_scales: jnp.ndarray | None = None,  # [n_kv, P] f32 per-page (quant pools)
+    k_scales: jnp.ndarray | None = None,  # [(L,) n_kv, P] f32 per-page (quant pools)
     v_scales: jnp.ndarray | None = None,
+    layer: jnp.ndarray | None = None,  # [] / [1] int32, REQUIRED for rank-5
     interpret: bool = False,
 ) -> jnp.ndarray:
     """ONE Pallas launch for every row's S-token window: grid
     (B, n_kv, max_pages), one page slab in VMEM per step.  Same contract
-    as ``paged_attention_ref`` (its oracle)."""
+    as ``paged_attention_ref`` (its oracle).
+
+    Rank-5 pools + ``layer``: the WHOLE [L, n_kv, P, ps, hd] pool and the
+    layer index as one more prefetched scalar, so the index map addresses
+    (layer, head, page) and no layer of the pool is sliced out — the same
+    form as pallas_paged.paged_attention_decode_staged."""
     b, s_w, n_q, hd = q_win.shape
-    n_kv, _, page_size, hd_store = k_pages.shape
+    layered = k_pages.ndim == 5
+    if layered:
+        assert layer is not None, "rank-5 pools need the layer index"
+    n_kv, _, page_size, hd_store = k_pages.shape[-4:]
     group = n_q // n_kv
     max_pages = block_tables.shape[1]
     scale = 1.0 / (hd ** 0.5)
@@ -202,15 +211,32 @@ def fused_window_attention(
         # Clamp the walk to allocated pages: beyond the row's length the
         # kernel skips compute, so any valid page id works — page 0.
         page = jax.lax.select(pi * page_size < tl[bi], bt[bi, pi], 0)
+        if layered:  # the layer index is the LAST prefetched scalar
+            return (scalars[-1][0], hi, page, 0, 0)
         return (hi, page, 0, 0)
 
+    # the kernel body reads its page as k_ref[0, 0]: squeeze the layer axis
+    kv_block = (None,) * layered + (1, 1, page_size, hd_store)
+    prefetch = [block_tables.astype(jnp.int32), cached_lens.astype(jnp.int32),
+                total_lens]
+    if quant:
+        # per-page scales ride the scalar-prefetch channel as one layer's
+        # [n_kv, P]: a slice of KBs, not of a pool
+        for sc in (k_scales, v_scales):
+            if layered:
+                sc = jax.lax.dynamic_index_in_dim(
+                    sc, jnp.reshape(layer, ()), 0, keepdims=False)
+            prefetch.append(sc.astype(jnp.float32))
+    if layered:
+        prefetch.append(jnp.reshape(layer, (1,)).astype(jnp.int32))
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5 if quant else 3,
+        num_scalar_prefetch=len(prefetch),
         grid=(b, n_kv, max_pages),
         in_specs=[
             pl.BlockSpec((1, 1, group, s_w, hd), q_map),
-            pl.BlockSpec((1, 1, page_size, hd_store), kv_map),
-            pl.BlockSpec((1, 1, page_size, hd_store), kv_map),
+            pl.BlockSpec(kv_block, kv_map),
+            pl.BlockSpec(kv_block, kv_map),
         ],
         out_specs=pl.BlockSpec((1, 1, group, s_w, hd), q_map),
         scratch_shapes=[
@@ -223,10 +249,6 @@ def fused_window_attention(
     kernel = functools.partial(
         _fused_window_kernel, page_size=page_size, scale=scale, quant=quant
     )
-    scalars = [block_tables.astype(jnp.int32), cached_lens.astype(jnp.int32),
-               total_lens]
-    if quant:
-        scalars += [k_scales.astype(jnp.float32), v_scales.astype(jnp.float32)]
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -235,14 +257,14 @@ def fused_window_attention(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(*scalars, q_r, k_pages, v_pages)
+    )(*prefetch, q_r, k_pages, v_pages)
 
     # [B, n_kv, group, S, hd] -> [B, S, n_q, hd]
     return out.transpose(0, 3, 1, 2, 4).reshape(b, s_w, n_q, hd)
 
 
 def fused_paged_attention(q, k_pages, v_pages, block_tables, cached_lens,
-                          new_lens, k_scales=None, v_scales=None):
+                          new_lens, k_scales=None, v_scales=None, layer=None):
     """Drop-in for ``paged_attention_ref``/``pallas_paged.paged_attention``
     at the forward_paged seam: spec-verify windows (S = k+1), plain decode
     (S = 1), and quantized pools all hit the SAME kernel instead of the
@@ -250,7 +272,7 @@ def fused_paged_attention(q, k_pages, v_pages, block_tables, cached_lens,
     on the kernel's exact compute graph."""
     return fused_window_attention(
         q, k_pages, v_pages, block_tables, cached_lens, new_lens,
-        k_scales, v_scales, interpret=not on_tpu(),
+        k_scales, v_scales, layer, interpret=not on_tpu(),
     )
 
 

@@ -17,6 +17,15 @@ burst: new K/V go to a tiny [L, B, n_kv, N, hd] staging buffer (~MBs),
 attention per step covers (frozen pool prefix) + (staged tail so far), and
 the staged tokens are scattered into the pools ONCE at burst end.
 
+That one scatter (kv_cache.commit_paged) writes a [hd] row per (layer,
+head, slot) index, in the layout the pools arrive in.  Written as one
+window over all layers and heads per slot it is fewer, fatter writes, but
+the v5e compiler then transposes each whole pool into the layout that makes
+the window contiguous and back out again — four 1.4 GB copies around a
+30 us scatter, 17 ms of a 120 ms burst at Qwen2-7B widths, donation
+notwithstanding (PERF.md, Findings, PR 25; tests/test_tpu_compile.py holds
+the compiled program to "no copy of a pool").
+
 Attention inside the burst has two implementations (``use_pallas``):
   - the Pallas flash-decode kernel extended with a staged-tail operand
     (ops/pallas_paged.py::paged_attention_decode_staged) — walks the block

@@ -199,6 +199,8 @@ def commit_paged(
     flat_slots: jnp.ndarray,  # [N] int32 flat slots; out-of-range = dropped
     scales: jnp.ndarray | None,  # [..., P] f32 per-page (int8 pools) or None
     page_size: int,
+    layer: jnp.ndarray | None = None,  # [] int32: pools/scales keep their
+    # leading [L] axis, vals (and the write) are that one layer's
 ):
     """Scatter new K or V vectors into flat pool slots — THE pool-commit
     rule, shared by the chunked-prefill (models/qwen2.forward_paged),
@@ -208,19 +210,35 @@ def commit_paged(
     cast to the pool dtype); else quantized pools with each page's scale
     fixed by its first write (quantize_kv_paged) — int8 when the pool
     dtype is int8, nibble-packed int4 (pack_int4) when it is uint8.
-    Returns (pools, scales)."""
+
+    The scatter writes ONE [hd] row per (leading index..., slot): every
+    leading axis is indexed, none is a window.  A window over the leading
+    axes (``flat.at[:, slots]``: all layers and heads of a slot) makes the
+    TPU compiler re-lay the whole pool out so that the window is
+    contiguous, and back again after: two transposes of the pool around a
+    scatter of a few MB (PERF.md, Findings, PR 25).  Rows leave the pool
+    in the layout it lives in; the kv-head axis stays an axis of its own,
+    so pools sharded over kv heads (tp) scatter shard-locally.
+
+    ``layer`` is the carried form: the caller keeps the whole
+    [L, ..., P, ps, hd] pool (a scan carry, never sliced) and commits one
+    layer's ``vals`` [..., N, hd] at that index.  Returns (pools, scales)."""
     p, ps, hd = pools.shape[-3:]  # hd is the STORED payload width
-    if scales is None:
-        vals = vals.astype(pools.dtype)
-    elif pools.dtype == jnp.uint8:
-        vals, scales = quantize_kv_paged(vals, flat_slots, scales, page_size, qmax=7)
-        vals = pack_int4(vals)  # [..., N, hd] -> [..., N, hd//2] == pool hd
-    else:
-        vals, scales = quantize_kv_paged(vals, flat_slots, scales, page_size)
-    flat = pools.reshape(-1, p * ps, hd)
-    flat = flat.at[:, flat_slots].set(
-        vals.reshape(-1, vals.shape[-2], hd), mode="drop"
-    )
+    if scales is not None:
+        qmax = 7 if pools.dtype == jnp.uint8 else 127
+        page_scales = scales if layer is None else scales[layer]
+        vals, page_scales = quantize_kv_paged(
+            vals, flat_slots, page_scales, page_size, qmax=qmax
+        )
+        scales = page_scales if layer is None else scales.at[layer].set(page_scales)
+        if qmax == 7:
+            vals = pack_int4(vals)  # [..., N, hd] -> [..., N, hd//2] == pool hd
+    # open mesh over vals' leading axes and the slots: one index per row
+    index = jnp.ix_(*(jnp.arange(d) for d in vals.shape[:-2]), flat_slots)
+    if layer is not None:
+        index = (layer, *index)
+    flat = pools.reshape(*pools.shape[:-3], p * ps, hd)
+    flat = flat.at[index].set(vals.astype(pools.dtype), mode="drop")
     return flat.reshape(pools.shape), scales
 
 

@@ -7,10 +7,17 @@ kernel may use.  These cases hand each kernel the shapes the serving engine
 gives it for Qwen2-1.5B and Qwen2-7B (head 128, page size 128) and ask the
 real TPU compiler.  Nothing executes, so they say nothing about results or
 speed; a pass here is not a chip run.
+
+The second half compiles the two step programs of the benchmark's cells
+whole (decode burst, paged prefill; Qwen2-7B int8 weights, 384 pages) and
+reads the optimized HLO: nothing in it may copy, transpose or slice a K/V
+page pool, or a layer of one (PERF.md, Findings, PR 25).
 """
 
 import functools
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs under /tmp
 
@@ -33,24 +40,39 @@ KV = {"fp": (jnp.bfloat16, 1), "int8": (jnp.int8, 1), "int4": (jnp.uint8, 2)}
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """Sharding on one described v5e chip; the persistent compile cache is
-    off around these compiles (an entry written for a described chip cannot
-    be read back without one, and the retry warns)."""
+def topo():
+    """A described v5e host (2x2); the persistent compile cache is off
+    around these compiles (an entry written for a described chip cannot be
+    read back without one, and the retry warns)."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as exc:  # noqa: BLE001 - no TPU compiler in this installation
         pytest.skip(f"cannot describe a v5e topology here: {exc}")
     was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield desc
     jax.config.update("jax_enable_compilation_cache", was_on)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """Sharding on one described v5e chip."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def tp4(topo):
+    """The four described chips as the engine's MeshPlan(tp=4) mesh."""
+    from githubrepostorag_tpu.parallel.mesh import MeshPlan, make_mesh
+
+    return make_mesh(MeshPlan(tp=4), devices=topo.devices)
 
 
 def _staged(model: str, kv: str):
@@ -116,3 +138,140 @@ def test_kernel_compiles_for_v5e(chip, build):
     shapes = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip) for shape, dtype in args]
     compiled = fn.lower(*shapes).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# ---- the step programs keep the K/V page pools where they are -------------
+
+CELL_PAGES, CELL_ROWS, CELL_ROW_PAGES = 384, 32, 16  # benchmarks/configs/qwen2-7b-int8.json
+_INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = \(?\w+\[([\d,]+)\]\S* ([\w\-]+)\(")
+MOVERS = ("copy", "copy-start", "transpose", "dynamic-slice", "dynamic-update-slice",
+          "all-gather", "all-gather-start", "all-to-all", "collective-permute")
+
+
+def pool_movers(hlo: str, pool_shape: tuple) -> list:
+    """Instructions of the optimized HLO, fused computations included (so a
+    fusion of a copy counts), that copy, transpose, slice, update-slice or
+    gather across chips into a result holding a whole pool (as one device
+    holds it, or all of it) or a whole layer of one, however its
+    leading axes are merged: [28,4,384,128,128], [1,4,384,128,128],
+    [4,49152,128], [112,49152,128], [5505024,128], ..."""
+    sizes = (math.prod(pool_shape[:-1]), math.prod(pool_shape[1:-1]))
+    found = []
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        if m and m.group(3) in MOVERS:
+            *lead, width = map(int, m.group(2).split(","))
+            if width == pool_shape[-1] and math.prod(lead) in sizes:
+                found.append(f"{m.group(1)} {m.group(3)} [{m.group(2)}]")
+    return found
+
+
+@pytest.fixture()
+def as_on_chip(monkeypatch):
+    """The program asks runtime.on_tpu() whether its kernels run compiled or
+    interpreted; a compile for a described chip answers for it here."""
+    import githubrepostorag_tpu.models.quant as quant
+    import githubrepostorag_tpu.ops.fused_decode as fused_decode
+    import githubrepostorag_tpu.serving.decode_burst as decode_burst
+
+    for mod in (quant, fused_decode, decode_burst):
+        monkeypatch.setattr(mod, "on_tpu", lambda: True)
+
+
+def _cell_program(where, program: str, kv: str, variant):
+    """decode_burst (variant: filter_sampling) or forward_paged (variant:
+    rows of 512 new tokens) lowered at the cell's shapes -> (lowered, shape
+    of the pool a device holds).  ``where`` is one described chip's sharding
+    (the cell's own int8 weights) or a tp mesh (bf16 weights sharded as the
+    engine shards them, the pools over kv heads, everything else replicated)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from githubrepostorag_tpu.models.quant import init_params_quantized
+    from githubrepostorag_tpu.models.qwen2 import Qwen2Config, forward_paged, init_params
+    from githubrepostorag_tpu.parallel.sharding import qwen2_param_specs
+    from githubrepostorag_tpu.serving.decode_burst import decode_burst
+
+    cfg = Qwen2Config.qwen2_7b()
+    dtype, pack = KV[kv]
+    lead = (cfg.num_layers, cfg.num_kv_heads, CELL_PAGES)
+    pool_shape = (*lead, PAGE, cfg.head_dim // pack)
+    mesh = where if isinstance(where, Mesh) else None
+    if mesh is None:
+        params = jax.eval_shape(lambda: init_params_quantized(cfg, 0, bits=8, fuse=True))
+        params = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=where), params)
+        everywhere = over_heads = where
+        held = pool_shape
+    else:
+        params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16))
+        params = jax.tree.map(
+            lambda x, spec: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=NamedSharding(mesh, spec)),
+            params, qwen2_param_specs(cfg, mesh, params))
+        everywhere = NamedSharding(mesh, P())
+        over_heads = NamedSharding(mesh, P(None, "tp"))
+        held = (lead[0], lead[1] // mesh.shape["tp"], *pool_shape[2:])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=everywhere)
+
+    pool = jax.ShapeDtypeStruct(pool_shape, dtype, sharding=over_heads)
+    scale = jax.ShapeDtypeStruct(lead, jnp.float32, sharding=over_heads)
+    scales = {} if kv == "fp" else {"k_scales": scale, "v_scales": scale}
+    b, i32, f32 = CELL_ROWS, jnp.int32, jnp.float32
+    if program == "burst":
+        lowered = decode_burst.lower(
+            params, cfg, sds((b,), i32), sds((b,), i32), pool, pool,
+            sds((b, cfg.vocab_size), jnp.bool_), sds((b,), jnp.bool_), sds((b,), i32),
+            sds((b, CELL_ROW_PAGES), i32), sds((2,), jnp.uint32), sds((b,), f32),
+            sds((b,), f32), sds((b,), i32), sds((b,), f32),
+            n_steps=8, use_pallas=True, filter_sampling=variant, mesh=mesh, **scales,
+        )
+    else:
+        chunk = (variant, 512)
+        lowered = forward_paged.lower(
+            params, cfg, sds(chunk, i32), sds(chunk, i32), pool, pool, sds(chunk, i32),
+            sds((variant, CELL_ROW_PAGES), i32), sds((variant,), i32), sds((variant,), i32),
+            use_pallas=True, logits_at=sds((variant,), i32), mesh=mesh, **scales,
+        )
+    return lowered, held
+
+
+# int4 pages are 64 bytes wide, half a lane tile: the compiler keeps them
+# in layouts of its own, and the burst reads them through the gather path
+_INT4 = pytest.mark.xfail(strict=True, reason=(
+    "int4 pools: forward_paged's scatter wants u8[5505024,64]{0,1} and the "
+    "compiler copies each pool in and out of it (copy u8[28,4,384,128,64]); "
+    "the burst has no nibble kernel and dynamic-slices each layer for gather_kv"))
+POOL_CASES = [
+    pytest.param("burst", "fp", True, id="burst-filter-fp"),
+    pytest.param("burst", "fp", False, id="burst-nofilter-fp"),
+    pytest.param("prefill", "fp", 1, id="prefill-1x512-fp"),
+    pytest.param("prefill", "fp", 2, id="prefill-2x512-fp"),
+    pytest.param("burst", "int8", False, id="burst-nofilter-int8"),
+    pytest.param("prefill", "int8", 1, id="prefill-1x512-int8"),
+    pytest.param("burst", "int4", False, id="burst-nofilter-int4", marks=_INT4),
+    pytest.param("prefill", "int4", 1, id="prefill-1x512-int4", marks=_INT4),
+]
+
+
+@pytest.mark.parametrize("program,kv,variant", POOL_CASES)
+def test_step_program_leaves_the_pools_in_place(chip, as_on_chip, program, kv, variant):
+    lowered, pool_shape = _cell_program(chip, program, kv, variant)
+    hlo = lowered.compile().as_text()
+    assert "tpu_custom_call" in hlo  # the attention kernel is in the program
+    assert pool_movers(hlo, pool_shape) == []
+
+
+@pytest.mark.parametrize("program,variant", [("burst", False), ("prefill", 1)],
+                         ids=["burst-nofilter-fp", "prefill-1x512-fp"])
+def test_step_program_leaves_sharded_pools_in_place(tp4, as_on_chip, program, variant):
+    """MeshPlan(tp=4): each chip commits into its own kv head's pages.  The
+    window commit made GSPMD all-gather both pools in every burst (PR 22 read
+    18% of device time there); the row commit indexes the head axis, which
+    stays sharded."""
+    lowered, held = _cell_program(tp4, program, "fp", variant)
+    hlo = lowered.compile().as_text()
+    assert "tpu_custom_call" in hlo
+    whole = (held[0], held[1] * tp4.shape["tp"], *held[2:])
+    assert pool_movers(hlo, held) + pool_movers(hlo, whole) == []
