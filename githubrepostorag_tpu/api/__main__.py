@@ -36,7 +36,6 @@ def _build_llm():
 
         params, cfg = load_qwen2(
             s.model_weights_path, dtype=ml_dtypes.bfloat16, quantize=s.quantize_weights,
-            moe_capacity_factor=s.moe_capacity_factor,
         )
         engine = Engine(
             params, cfg,

@@ -504,13 +504,6 @@ class Settings:
     kv_migrate_burst: int = field(
         default_factory=lambda: _env_int("KV_MIGRATE_BURST", 8)
     )
-    # MoE serving expert capacity = ceil(K*T/E * factor); overflow
-    # assignments drop that expert's contribution (models/moe.py; set
-    # MOE_DROP_STATS=1 to count drops).  0 = exact no-drop dispatch —
-    # HF-parity math with [T, E, T] dispatch tensors, test scale only.
-    moe_capacity_factor: float = field(
-        default_factory=lambda: _env_float("MOE_CAPACITY_FACTOR", 2.0)
-    )
 
     @property
     def scope_tables(self) -> dict[str, str]:

@@ -493,14 +493,17 @@ INDEX_OPS_APPLIED = Counter(
     ["table", "kind"],
     registry=REGISTRY,
 )
-MOE_ASSIGNMENTS = Counter(
-    "rag_moe_expert_assignments_total",
-    "MoE router token->expert assignments offered (MOE_DROP_STATS=1)",
+MOE_EXPERTS_HIT = Counter(
+    "rag_moe_experts_hit_total",
+    "Held experts that received a token, summed over expert layers and steps "
+    "(each streams its weights once); read back with each burst's tokens",
+    ["program"],
     registry=REGISTRY,
 )
-MOE_DROPPED = Counter(
-    "rag_moe_dropped_assignments_total",
-    "MoE assignments dropped by expert capacity (MOE_DROP_STATS=1)",
+MOE_EXPERT_TOKENS = Counter(
+    "rag_moe_expert_tokens_total",
+    "(token, expert) pairs routed to an expert held on this chip; none is dropped",
+    ["program"],
     registry=REGISTRY,
 )
 

@@ -29,11 +29,9 @@ def _np(t) -> np.ndarray:
     return t.detach().to("cpu").float().numpy()
 
 
-def config_from_hf(hf_cfg: dict, moe_capacity_factor: float = 2.0) -> Qwen2Config:
-    """Pure parser: HF config dict -> Qwen2Config.  ``moe_capacity_factor``
-    is caller-supplied (the serving entrypoint threads
-    Settings.moe_capacity_factor through load_qwen2) so parsing the same
-    config.json never depends on process env."""
+def config_from_hf(hf_cfg: dict) -> Qwen2Config:
+    """Pure parser: HF config dict -> Qwen2Config (parsing the same
+    config.json never depends on process env)."""
     num_heads = hf_cfg["num_attention_heads"]
     moe: dict = {}
     if hf_cfg.get("num_experts", 0):  # Qwen2MoeConfig (model_type qwen2_moe)
@@ -49,10 +47,6 @@ def config_from_hf(hf_cfg: dict, moe_capacity_factor: float = 2.0) -> Qwen2Confi
             moe_intermediate_size=hf_cfg["moe_intermediate_size"],
             shared_expert_intermediate_size=hf_cfg["shared_expert_intermediate_size"],
             norm_topk_prob=hf_cfg.get("norm_topk_prob", False),
-            # bounded-capacity dispatch (MOE_DROP_STATS=1 counts drops).
-            # The exact no-drop mode (factor 0) builds [T, E, T] dispatch
-            # tensors — parity-test scale only.
-            capacity_factor=moe_capacity_factor,
         )
     return Qwen2Config(
         vocab_size=hf_cfg["vocab_size"],
@@ -135,7 +129,6 @@ def load_qwen2(
     checkpoint_dir: str,
     dtype=np.float32,
     quantize: bool | int = False,
-    moe_capacity_factor: float = 2.0,
     fuse: bool = False,
 ) -> tuple[dict, Qwen2Config]:
     """Load config.json + *.safetensors from a local directory.
@@ -155,7 +148,7 @@ def load_qwen2(
 
     root = Path(checkpoint_dir)
     hf_cfg = json.loads((root / "config.json").read_text())
-    cfg = config_from_hf(hf_cfg, moe_capacity_factor=moe_capacity_factor)
+    cfg = config_from_hf(hf_cfg)
 
     state: dict[str, np.ndarray] = {}
     for shard in sorted(root.glob("*.safetensors")):

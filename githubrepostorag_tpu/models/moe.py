@@ -1,23 +1,22 @@
-"""Sparse Mixture-of-Experts MLP (Qwen2-MoE family) with expert-parallel
-sharding over the ``ep`` mesh axis.
+"""Sparse Mixture-of-Experts layers: routers, and one dropless dispatch
+that is told which experts it holds.
 
-The TPU formulation: no scatters, no per-expert Python — routing becomes
-dense one-hot dispatch/combine tensors and the expert FFN is ONE batched
-einsum over stacked expert weights [E, ...] (GShard/Switch style).  With
-the expert axis of the weights sharded P("ep", ...), GSPMD turns the
-dispatch/combine einsums into the all-to-alls of classic expert
-parallelism — no hand-written collectives, same recipe as the rest of the
-mesh fabric (SURVEY.md §2.3: the mesh was designed so EP "can slot in";
-this fills the slot).
+Dispatch (``dropless_experts``): the (token, expert) pairs routed to an
+expert held here are sorted by expert and cut into tiles of at most ``tile``
+rows, each tile of one expert; a scan visits the tiles that exist and a
+``lax.cond`` skips the rest, so an expert that received no token streams no
+weights, no token is ever dropped, and nothing of shape [T, E, C] is built.
+Pairs routed to experts that are not held (``lo <= id < lo + n`` fails) add
+nothing: they are another chip's share, and the exchange that would bring
+their result here is not built (one chip runs without it).  The scan has a
+static length (the most tiles the shapes allow), so the layer differentiates
+and shards like any other; with the expert axis sharded P("ep", ...) GSPMD
+resolves the per-tile expert index itself.
 
-Math matches HF ``Qwen2MoeSparseMoeBlock`` (softmax router in float32,
-top-k, optional top-k renorm, plus an always-on shared expert scaled by a
-sigmoid gate), so HF-parity tests hold token-exact when capacity is
-no-drop.  Capacity: ``cfg.capacity_factor == 0`` gives exact no-drop
-dispatch (capacity = T; dispatch tensors are [T, E, T] — parity/test
-scale); real serving sets a factor so capacity = ceil(K*T/E * factor) and
-overflow tokens simply lose that expert's contribution (standard
-token-dropping semantics).
+Routers: ``moe_mlp`` is HF ``Qwen2MoeSparseMoeBlock`` (float32 softmax,
+top-k, optional renormalisation, a shared expert behind a sigmoid gate);
+``route_noaux_tc`` is DeepSeek-V3's (sigmoid scores, a selection-only bias,
+group-limited top-k, weights from the unbiased scores).
 """
 
 from __future__ import annotations
@@ -25,33 +24,82 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from githubrepostorag_tpu.models.quant import dequant_weight, qmatmul
-
-# Host-side routing-drop accumulator (ADVICE r02: bounded-capacity dispatch
-# silently loses expert contributions under router imbalance — make the
-# drop rate observable).  MOE_DROP_STATS=1 enables a per-layer
-# jax.debug.callback that adds (assignments, dropped) here and to the
-# Prometheus counters; off by default because the callback forces a
-# host round trip per MoE layer.
-DROP_STATS = {"assignments": 0, "dropped": 0}
+from githubrepostorag_tpu.models.quant import qmatmul
 
 
-def _drop_stats_enabled() -> bool:
-    from githubrepostorag_tpu.config import _env_bool
+def route_noaux_tc(scores: jnp.ndarray, bias: jnp.ndarray, top_k: int, n_group: int,
+                   topk_group: int, norm_topk_prob: bool = True,
+                   routed_scaling_factor: float = 1.0):
+    """DeepSeek-V3's ``noaux_tc`` selection.  ``scores`` [T, E] float32 are
+    the sigmoid affinities, ``bias`` [E] the ``e_score_correction_bias`` that
+    enters the choice and not the weight.  A group's score is the sum of its
+    two largest biased scores; the best ``topk_group`` groups stay, and the
+    ``top_k`` largest biased scores inside them are chosen.  Returns (ids
+    [T, K] int32, weights [T, K] float32: the unbiased scores of the chosen,
+    normalised to sum 1 and scaled)."""
+    t, e = scores.shape
+    biased = scores + bias[None, :].astype(scores.dtype)
+    grouped = biased.reshape(t, n_group, e // n_group)
+    group_score = jax.lax.top_k(grouped, 2)[0].sum(axis=-1)  # [T, G]
+    _, best = jax.lax.top_k(group_score, topk_group)
+    keep = jnp.zeros((t, n_group), bool).at[jnp.arange(t)[:, None], best].set(True)
+    masked = jnp.where(jnp.repeat(keep, e // n_group, axis=1), biased, -jnp.inf)
+    _, ids = jax.lax.top_k(masked, top_k)
+    w = jnp.take_along_axis(scores, ids, axis=1)
+    if norm_topk_prob:
+        w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
+    return ids.astype(jnp.int32), w * routed_scaling_factor
 
-    return _env_bool("MOE_DROP_STATS", False)
+
+EXPERT_TILE = 128  # rows of one expert a dispatch tile holds, at most
 
 
-def _record_drops(assignments, dropped) -> None:
-    DROP_STATS["assignments"] += int(assignments)
-    DROP_STATS["dropped"] += int(dropped)
-    try:
-        from githubrepostorag_tpu.metrics import MOE_ASSIGNMENTS, MOE_DROPPED
+def dropless_experts(x: jnp.ndarray, top_i: jnp.ndarray, top_w: jnp.ndarray, expert_ffn,
+                     n_held: int, lo=0):
+    """Sum over the held experts of w * E(x) for the pairs routed to them.
 
-        MOE_ASSIGNMENTS.inc(int(assignments))
-        MOE_DROPPED.inc(int(dropped))
-    except Exception:  # pragma: no cover - metrics registry optional in tools
-        pass
+    ``x`` [T, d]; ``top_i`` [T, K] expert ids over ALL experts; ``top_w``
+    [T, K] float32; ``expert_ffn(e, rows [M, d]) -> [M, d]`` runs held expert
+    ``e`` (0-based among the held, a traced int32); the held are the ids
+    ``lo .. lo + n_held - 1``.  Returns (y [T, d] float32, counts [n_held]
+    int32: pairs each held expert received)."""
+    t, d = x.shape
+    k = top_i.shape[1]
+    m = min(EXPERT_TILE, -(-t // 8) * 8)  # rows a tile holds
+    held = (top_i >= lo) & (top_i < lo + n_held)
+    e = jnp.where(held, top_i - lo, n_held).reshape(-1)  # [T*K]; n_held = not here
+    order = jnp.argsort(e, stable=True)  # pairs by expert, the absent last
+    counts = (e[:, None] == jnp.arange(n_held)[None, :]).sum(axis=0).astype(jnp.int32)
+    tiles_of = (counts + m - 1) // m
+    tile_end = jnp.cumsum(tiles_of)
+    tile_start = tile_end - tiles_of
+    group_start = jnp.cumsum(counts) - counts  # an expert's first pair in sorted order
+    # ceil(c_e / m) summed is at most floor(pairs / m) + n_held, and an expert
+    # receives a token at most once
+    n_tiles = min(t * k // m + n_held, n_held * -(-t // m))
+    flat_w = top_w.reshape(-1).astype(jnp.float32)
+
+    def run(i, y):
+        ex = jnp.minimum(jnp.searchsorted(tile_end, i, side="right"), n_held - 1)
+        off = (i - tile_start[ex]) * m
+        idx = group_start[ex] + off + jnp.arange(m)
+        valid = jnp.arange(m) < counts[ex] - off
+        pair = order[jnp.clip(idx, 0, t * k - 1)]
+        tok = pair // k
+        w = jnp.where(valid, flat_w[pair], 0.0)
+        yt = expert_ffn(ex.astype(jnp.int32), x[tok]).astype(jnp.float32)
+        return y.at[tok].add(yt * w[:, None])  # rows past the group add zero
+
+    def body(y, i):
+        return jax.lax.cond(i < tile_end[-1], lambda y: run(i, y), lambda y: y, y), None
+
+    y, _ = jax.lax.scan(body, jnp.zeros((t, d), jnp.float32), jnp.arange(n_tiles))
+    return y, counts
+
+
+def _expert(w, e):
+    """Expert ``e`` of a stacked [E, ...] weight, plain or quantized."""
+    return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, e, 0, keepdims=False), w)
 
 
 def moe_mlp(cfg, p: dict, x: jnp.ndarray) -> jnp.ndarray:
@@ -62,53 +110,26 @@ def moe_mlp(cfg, p: dict, x: jnp.ndarray) -> jnp.ndarray:
     ``s_gate`` [d, 1].
     """
     b, s, d = x.shape
-    E, K = cfg.num_experts, cfg.num_experts_per_tok
-    T = b * s
-    xf = x.reshape(T, d)
+    xf = x.reshape(b * s, d)
 
-    # --- router: float32 softmax over experts, top-k (HF parity) ----------
-    logits = jnp.einsum("td,de->te", xf.astype(jnp.float32), p["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)  # [T, E]
-    top_p, top_i = jax.lax.top_k(probs, K)  # [T, K]
-    if cfg.norm_topk_prob:
-        top_p = top_p / jnp.maximum(top_p.sum(axis=-1, keepdims=True), 1e-20)
+    with jax.named_scope("moe_route"):  # float32 softmax over experts, top-k (HF parity)
+        logits = jnp.einsum("td,de->te", xf.astype(jnp.float32),
+                            p["router"].astype(jnp.float32))
+        top_p, top_i = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.num_experts_per_tok)
+        if cfg.norm_topk_prob:
+            top_p = top_p / jnp.maximum(top_p.sum(axis=-1, keepdims=True), 1e-20)
 
-    # --- dispatch/combine tensors (one-hot + in-expert position) ----------
-    if cfg.capacity_factor > 0:
-        C = max(1, int(-(-K * T * cfg.capacity_factor // E)))
-    else:
-        C = T  # no-drop: an expert can at most receive every token once
-    oh = jax.nn.one_hot(top_i, E, dtype=jnp.float32)  # [T, K, E]
-    oh_flat = oh.reshape(T * K, E)
-    # arrival order: token-major, then k — position of each assignment in
-    # its expert's queue decides who fits under the capacity
-    pos = jnp.cumsum(oh_flat, axis=0) - oh_flat
-    slot = (pos * oh_flat).sum(-1)  # [T*K] this assignment's queue position
-    keep = slot < C
-    if cfg.capacity_factor > 0 and _drop_stats_enabled():
-        jax.debug.callback(
-            _record_drops, jnp.asarray(T * K), (~(slot < C)).sum()
-        )
-    slot_oh = (jax.nn.one_hot(slot, C, dtype=jnp.float32) * keep[:, None]).reshape(T, K, C)
-    # contract k inside the einsums: a materialized [T, K, E, C] would be
-    # K times the memory of the [T, E, C] tensors actually needed
-    dispatch = jnp.einsum("tke,tkc->tec", oh, slot_oh)  # [T, E, C] 0/1
-    combine = jnp.einsum("tke,tkc,tk->tec", oh, slot_oh, top_p)
+    def expert_ffn(e, rows):
+        h = jax.nn.silu(qmatmul(rows, _expert(p["e_wg"], e))) * qmatmul(rows, _expert(p["e_wu"], e))
+        return qmatmul(h, _expert(p["e_wd"], e))
 
-    # --- expert FFN: one batched einsum per projection --------------------
-    cdt = x.dtype
-    xs = jnp.einsum("td,tec->ecd", xf, dispatch.astype(cdt))  # [E, C, d]
-    h1 = jnp.einsum("ecd,edf->ecf", xs, dequant_weight(p["e_wg"], cdt))
-    h2 = jnp.einsum("ecd,edf->ecf", xs, dequant_weight(p["e_wu"], cdt))
-    ys = jnp.einsum(
-        "ecf,efd->ecd", jax.nn.silu(h1) * h2, dequant_weight(p["e_wd"], cdt)
-    )
-    y = jnp.einsum("ecd,tec->td", ys, combine.astype(cdt))
+    with jax.named_scope("moe_experts"):
+        y, _ = dropless_experts(xf, top_i, top_p, expert_ffn, cfg.num_experts)
 
-    # --- always-on shared expert with sigmoid gate ------------------------
-    sh = jax.nn.silu(qmatmul(xf, p["s_wg"])) * qmatmul(xf, p["s_wu"])
-    sh = qmatmul(sh, p["s_wd"]) * jax.nn.sigmoid(qmatmul(xf, p["s_gate"]))
-    return (y + sh).reshape(b, s, d)
+    with jax.named_scope("moe_shared"):  # always on, behind a sigmoid gate
+        sh = jax.nn.silu(qmatmul(xf, p["s_wg"])) * qmatmul(xf, p["s_wu"])
+        sh = qmatmul(sh, p["s_wd"]) * jax.nn.sigmoid(qmatmul(xf, p["s_gate"]))
+    return (y.astype(x.dtype) + sh).reshape(b, s, d)
 
 
 def init_moe_layer_params(cfg, key: jax.Array, dtype=jnp.float32) -> dict:

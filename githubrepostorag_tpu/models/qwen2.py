@@ -56,9 +56,6 @@ class Qwen2Config:
     moe_intermediate_size: int = 0
     shared_expert_intermediate_size: int = 0
     norm_topk_prob: bool = False
-    # expert capacity = ceil(K*T/E * factor); 0.0 = exact no-drop dispatch
-    # (capacity T — HF-parity math, quadratic dispatch tensors: test scale)
-    capacity_factor: float = 0.0
 
     # ---- presets (HF config.json values for the eval-config model family) --
 
@@ -99,7 +96,6 @@ class Qwen2Config:
             tie_word_embeddings=False,
             num_experts=60, num_experts_per_tok=4, moe_intermediate_size=1408,
             shared_expert_intermediate_size=5632, norm_topk_prob=False,
-            capacity_factor=2.0,
         )
 
     @classmethod

@@ -55,6 +55,7 @@ DEFAULT_JIT_MODULES = (
     "githubrepostorag_tpu.serving.draft_spec",
     "githubrepostorag_tpu.serving.long_prefill",
     "githubrepostorag_tpu.models.qwen2",
+    "githubrepostorag_tpu.models.deepseek_v3",
     "githubrepostorag_tpu.ops.sampling",
     "githubrepostorag_tpu.ops.packed_prefill",
     "githubrepostorag_tpu.ops.fused_decode",
@@ -89,30 +90,36 @@ class CompileWatchdog:
         # or a resync racing a sample mis-attributes warmup compiles to
         # live traffic
         self._lock = threading.Lock()
-        self._last = self.cache_size()
+        self.grown: list[str] = []
+        self._last = self.sizes()
+
+    def sizes(self) -> dict[str, int]:
+        out = {}
+        for name, obj in self._jits:
+            try:
+                out[name] = int(obj._cache_size())
+            except Exception:  # noqa: BLE001 - a torn-down jit reads as 0
+                out[name] = 0
+        return out
 
     def cache_size(self) -> int:
-        total = 0
-        for _, obj in self._jits:
-            try:
-                total += int(obj._cache_size())
-            except Exception:  # noqa: BLE001 - a torn-down jit reads as 0
-                pass
-        return total
+        return sum(self.sizes().values())
 
     def resync(self) -> None:
         """Rebaseline — called at serve start so warmup's own compiles
         (expected, pre-traffic) never count as live-traffic compiles."""
-        size = self.cache_size()
+        sizes = self.sizes()
         with self._lock:
-            self._last = size
+            self._last = sizes
 
     def sample(self) -> int:
-        """New programs compiled since the previous sample (>= 0)."""
-        size = self.cache_size()
+        """New programs compiled since the previous sample (>= 0); ``grown``
+        names the jits that gained one."""
+        sizes = self.sizes()
         with self._lock:
-            delta = size - self._last
-            self._last = size
+            delta = sum(sizes.values()) - sum(self._last.values())
+            self.grown = [n for n, v in sizes.items() if v > self._last.get(n, 0)]
+            self._last = sizes
         return max(0, delta)
 
 
@@ -170,9 +177,9 @@ class EngineStepProfiler:
                 sp.add_event("xla_compile", new_programs=delta,
                              step_s=round(step_end - step_start, 6))
             logger.warning(
-                "xla compile during live traffic: %d new program(s) in a %.3fs step "
+                "xla compile during live traffic: %d new program(s) in a %.3fs step, of %s "
                 "(warmup should have predicted this shape)",
-                delta, step_end - step_start,
+                delta, step_end - step_start, ", ".join(self.watchdog.grown),
             )
         return delta
 

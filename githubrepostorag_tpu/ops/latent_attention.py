@@ -1,0 +1,369 @@
+"""Attention over a latent (MLA) page pool: one row ``[c_kv | k_rope | pad]``
+a token and layer (``rank + rope`` columns used, zeros up to whole lane
+tiles), no V pool.
+
+Two paths over the same pool, because the two shapes of work want opposite
+things (DeepSeek-V2, arXiv:2405.04434, section 2.1):
+
+* **decode** (``latent_decode_attention``): the absorbed form.  The
+  up-projection of the keys is folded into the query (``q' = q_nope W_uk^T``)
+  and that of the values is applied after the weighted sum, so every head
+  attends the 576-wide latent row directly: multi-query attention with 128
+  query heads over one shared key of width 576 whose first 512 columns are
+  also the value.  A token of context costs one row read (1,152 B in
+  bfloat16) and 2 * H * (576 + 512) FLOPs.  On the chip a Pallas kernel walks
+  the block table page by page (flash-decoding, as ops/pallas_paged.py) and
+  folds in the burst's staged tail; elsewhere a gather and dense products
+  stand in for it and are its oracle.
+
+* **prefill of a chunk** (``latent_prefill_attention``): the materialised
+  form.  For S new tokens against a long cached prefix the absorbed form
+  costs about twice the FLOPs, so ``k_nope`` and ``v`` are rebuilt from the
+  cached latents a page at a time (a whole 8k prefix would be 537 MB a row
+  and layer) under an online softmax.  On the chip a Pallas kernel does it
+  per (row, head), K, V and the scores living in VMEM only; elsewhere an XLA
+  loop over tiles of pages is its oracle (on the chip its [H, S, tile]
+  float32 scores crossed HBM several times a tile: 5% of the FLOP peak,
+  PERF.md, Findings, PR 27).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from githubrepostorag_tpu.runtime import on_tpu
+
+NEG_INF = -1e30
+
+
+def einsum_f32(eq: str, a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """einsum accumulated and returned in float32.  On the chip the operands
+    stay as stored (bfloat16 on the MXU); XLA's CPU runtime has no
+    bf16 x bf16 -> f32 product inside a loop body, so off the chip (tests,
+    the rehearsal) they are widened first."""
+    if on_tpu():
+        return jnp.einsum(eq, a, b, preferred_element_type=jnp.float32)
+    return jnp.einsum(eq, a.astype(jnp.float32), b.astype(jnp.float32))
+
+
+def _softmax_step(s, values, m_ref, l_ref, acc_ref):
+    """One online-softmax update in VMEM: scores ``s`` [M, T] float32 over
+    ``values`` [T, v]; (m, l) live in column 0 of their [M, 128] scratch."""
+    m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_ref[:, :1] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+        p.astype(values.dtype), values, preferred_element_type=jnp.float32)
+    m_ref[:, :1] = m_new
+
+
+PAGES_PER_STEP = 8  # my chip runs, PR 27, one layer at the cell's shapes: the prefill kernel
+# (1 x 512 queries over 8,704 rows) 20.6 / 9.7 / 6.6 / 5.6 ms at 1 / 2 / 4 / 8 pages a step, the
+# decode kernel (22 live rows x 8,700) 1.63 / 1.04 / 0.82 / 0.75 ms
+
+
+TILE_PAGES = 4  # pages of K and V the XLA oracle of the prefill path rebuilds at a time
+
+
+def _pages_per_step(max_pages: int) -> int:
+    """Pages one grid step reads: the pool is handed to the kernel that many
+    times, each operand's index map picking its own page of the block table,
+    so a step's products are that many pages wide and its fixed cost (~0.35
+    us, and small products that leave the MXU idle) is paid that much less
+    often.  Must divide the table's width."""
+    return next(n for n in (PAGES_PER_STEP, 4, 2, 1) if max_pages % n == 0)
+
+
+# ------------------------------------------------------------------ decode --
+
+def _decode_kernel(*refs, page_size: int, rank: int, rope: int, pps: int):
+    """Grid (B, max_pages / pps + 1): the first steps walk the row's block
+    table ``pps`` pages at a time (steps past ``lens`` skip compute and re-use
+    page 0's block, so no DMA is issued for them), the last folds in the
+    staged tail and writes the normalised output.  Products take bfloat16
+    operands and accumulate in float32: at 242 FLOP per byte the kernel sits
+    on the v5e's ridge, and a float32 product would put it far on the wrong
+    side.
+
+    Refs: scalar prefetch [block tables, pool lens, staged len, layer], blocks
+    [q_lat (1, H, rank), q_rope (1, H, rope), ``pps`` pages of the pool, staged
+    (1, n_steps, width)], out (1, H, rank), scratch [m, l (H, 128), acc]."""
+    bt_ref, lens_ref, slen_ref, layer_ref, ql_ref, qr_ref = refs[:6]
+    k_refs = refs[6:6 + pps]
+    st_ref, out_ref, m_ref, l_ref, acc_ref = refs[6 + pps:]
+    bi, pi = pl.program_id(0), pl.program_id(1)
+    num_pi = pl.num_programs(1)
+
+    @pl.when(pi == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    total = lens_ref[bi]
+    start = pi * pps * page_size
+    nt = (((1,), (1,)), ((), ()))  # a [m, k] . b [n, k] -> [m, n]
+
+    def scores(c_kv, k_rope):
+        return jax.lax.dot_general(ql_ref[0], c_kv, nt, preferred_element_type=jnp.float32) \
+            + jax.lax.dot_general(qr_ref[0], k_rope, nt, preferred_element_type=jnp.float32)
+
+    @pl.when((pi < num_pi - 1) & (start < total))
+    def _():
+        rows = [k[0, 0, 0] for k in k_refs]  # pps x [page_size, width]
+        tile = rows[0] if pps == 1 else jnp.concatenate(rows, axis=0)
+        c_kv = tile[:, :rank]
+        s = scores(c_kv, tile[:, rank:rank + rope])
+        kv_pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        _softmax_step(jnp.where(kv_pos < total, s, NEG_INF), c_kv, m_ref, l_ref, acc_ref)
+
+    @pl.when(pi == num_pi - 1)
+    def _():
+        c_kv = st_ref[0, :, :rank]  # [n_steps, rank]
+        s = scores(c_kv, st_ref[0, :, rank:rank + rope])
+        idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        _softmax_step(jnp.where(idx < slen_ref[0], s, NEG_INF), c_kv, m_ref, l_ref, acc_ref)
+        l = l_ref[:, :1]  # staged_len >= 1, so l > 0 for padding rows too
+        out_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(out_ref.dtype)
+
+
+def _decode_pallas(q_lat, q_rope, pool, layer, block_tables, pool_lens, staged, staged_len,
+                   interpret: bool):
+    b, h, rank = q_lat.shape
+    rope = q_rope.shape[-1]
+    page_size, width = pool.shape[3], pool.shape[4]
+    max_pages, n_steps = block_tables.shape[1], staged.shape[1]
+    pps = _pages_per_step(max_pages)
+    walk = max_pages // pps
+
+    def row_map(bi, pi, *refs):
+        return (bi, 0, 0)
+
+    def page_map(j):
+        def index(bi, pi, bt, lens, slen, li):
+            at = jnp.minimum(pi, walk - 1) * pps + j
+            page = jax.lax.select((pi < walk) & (at * page_size < lens[bi]), bt[bi, at], 0)
+            return (li[0], 0, page, 0, 0)
+        return index
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(b, walk + 1),
+        in_specs=[pl.BlockSpec((1, h, rank), row_map), pl.BlockSpec((1, h, rope), row_map)]
+        + [pl.BlockSpec((1, 1, 1, page_size, width), page_map(j)) for j in range(pps)]
+        + [pl.BlockSpec((1, n_steps, width), row_map)],
+        out_specs=pl.BlockSpec((1, h, rank), row_map),
+        scratch_shapes=[pltpu.VMEM((h, 128), jnp.float32), pltpu.VMEM((h, 128), jnp.float32),
+                        pltpu.VMEM((h, rank), jnp.float32)],
+    )
+    # tpulint: disable=SHP003 -- built at trace time only: the one caller is the jitted decode burst (models/deepseek_v3.py), reached through a closure the linter does not follow
+    return pl.pallas_call(
+        functools.partial(_decode_kernel, page_size=page_size, rank=rank, rope=rope, pps=pps),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, rank), q_lat.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(block_tables.astype(jnp.int32), pool_lens.astype(jnp.int32),
+      jnp.reshape(staged_len, (1,)).astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
+      q_lat, q_rope, *([pool] * pps), staged)
+
+
+def _decode_gather(q_lat, q_rope, pool, layer, block_tables, pool_lens, staged, staged_len):
+    """The oracle: every page of every row gathered, dense products."""
+    b, h, rank = q_lat.shape
+    ps, used = pool.shape[3], rank + q_rope.shape[-1]
+    rows = pool[layer, 0, block_tables].reshape(b, -1, pool.shape[-1])  # [B, mp*ps, W]
+    kv = jnp.concatenate([rows, staged], axis=1)[..., :used].astype(jnp.float32)
+    valid = jnp.concatenate([
+        jnp.arange(block_tables.shape[1] * ps)[None, :] < pool_lens[:, None],
+        jnp.broadcast_to(jnp.arange(staged.shape[1])[None, :] < staged_len, (b, staged.shape[1])),
+    ], axis=1)
+    q = jnp.concatenate([q_lat, q_rope], axis=-1).astype(jnp.float32)
+    s = jnp.einsum("bhw,btw->bht", q, kv)
+    s = jnp.where(valid[:, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bht,btc->bhc", p, kv[..., :rank]).astype(q_lat.dtype)
+
+
+def latent_decode_attention(
+    q_lat: jnp.ndarray,  # [B, H, rank] absorbed queries, softmax scale applied
+    q_rope: jnp.ndarray,  # [B, H, rope] rotated, scale applied
+    pool: jnp.ndarray,  # [L, 1, P, page_size, >= rank + rope], whole
+    layer: jnp.ndarray,  # [] int32
+    block_tables: jnp.ndarray,  # [B, max_pages]
+    pool_lens: jnp.ndarray,  # [B] rows of the pool that are valid for each row
+    staged: jnp.ndarray,  # [B, n_steps, pool width] this burst's rows so far
+    staged_len: jnp.ndarray,  # [] int32, how many of them are valid (>= 1)
+    use_pallas: bool = False,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """softmax(q . [c_kv | k_rope]) c_kv over [pool prefix | staged tail]
+    -> [B, H, rank]; the caller applies W_uv and W_o."""
+    if use_pallas:
+        return _decode_pallas(q_lat, q_rope, pool, layer, block_tables, pool_lens, staged,
+                              staged_len, interpret)
+    return _decode_gather(q_lat, q_rope, pool, layer, block_tables, pool_lens, staged,
+                          staged_len)
+
+
+# ----------------------------------------------------------------- prefill --
+
+def _prefill_kernel(*refs, page_size: int, rank: int, rope: int, pps: int):
+    """Grid (B, H, max_pages / pps): one head of one row walks the row's block
+    table ``pps`` pages at a time; K and V of those pages are rebuilt from
+    their latent rows in VMEM (c_kv W_uk,h^T and c_kv W_uv,h), scores never
+    leave VMEM, and an online softmax carries (m, l, acc) over the walk.
+    Steps past the row's last key skip compute and re-use page 0's block.
+
+    Refs: scalar prefetch [block tables, cached lens, kv lens, layer], blocks
+    [q_nope (1, 1, S, nope), q_rope (1, 1, S, rope), ``pps`` pages of the pool,
+    W_uk,h^T (1, rank, nope), W_uv,h (1, rank, v)], out (1, 1, S, v), scratch
+    [m, l (S, 128), acc (S, v)]."""
+    bt_ref, cached_ref, lens_ref, layer_ref, qn_ref, qr_ref = refs[:6]
+    k_refs = refs[6:6 + pps]
+    wuk_ref, wuv_ref, out_ref, m_ref, l_ref, acc_ref = refs[6 + pps:]
+    bi, pi = pl.program_id(0), pl.program_id(2)
+    num_pi = pl.num_programs(2)
+
+    @pl.when(pi == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    kv_len, cached = lens_ref[bi], cached_ref[bi]
+    start = pi * pps * page_size
+    nt = (((1,), (1,)), ((), ()))  # a [m, k] . b [n, k] -> [m, n]
+
+    @pl.when(start < kv_len)
+    def _():
+        rows = [k[0, 0, 0] for k in k_refs]  # pps x [page_size, width]
+        tile = rows[0] if pps == 1 else jnp.concatenate(rows, axis=0)
+        c_kv, k_rope = tile[:, :rank], tile[:, rank:rank + rope]
+        k_nope = jnp.dot(c_kv, wuk_ref[0], preferred_element_type=jnp.float32).astype(c_kv.dtype)
+        v = jnp.dot(c_kv, wuv_ref[0], preferred_element_type=jnp.float32).astype(c_kv.dtype)
+        s = jax.lax.dot_general(qn_ref[0, 0], k_nope, nt, preferred_element_type=jnp.float32) \
+            + jax.lax.dot_general(qr_ref[0, 0], k_rope, nt, preferred_element_type=jnp.float32)
+        kv_pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        q_pos = cached + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        s = jnp.where((kv_pos <= q_pos) & (kv_pos < kv_len), s, NEG_INF)
+        _softmax_step(s, v, m_ref, l_ref, acc_ref)
+
+    @pl.when(pi == num_pi - 1)
+    def _():
+        l = l_ref[:, :1]  # a padding row walks no page: l == 0
+        out_ref[0, 0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(out_ref.dtype)
+
+
+def _prefill_pallas(qn, qr, pool, layer, block_tables, cached_lens, kv_lens, w_uk, w_uv,
+                    interpret: bool):
+    """qn [B, H, S, nope], qr [B, H, S, rope] (scaled) -> [B, H, S, v]."""
+    b, h, s, nope = qn.shape
+    rope, rank, vd = qr.shape[-1], w_uk.shape[-1], w_uv.shape[-1]
+    page_size, width = pool.shape[3], pool.shape[4]
+    max_pages = block_tables.shape[1]
+    pps = _pages_per_step(max_pages)
+
+    def q_map(bi, hi, pi, *refs):
+        return (bi, hi, 0, 0)
+
+    def w_map(bi, hi, pi, *refs):
+        return (hi, 0, 0)
+
+    def page_map(j):
+        def index(bi, hi, pi, bt, cached, lens, li):
+            at = pi * pps + j
+            return (li[0], 0, jax.lax.select(at * page_size < lens[bi], bt[bi, at], 0), 0, 0)
+        return index
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(b, h, max_pages // pps),
+        in_specs=[pl.BlockSpec((1, 1, s, nope), q_map), pl.BlockSpec((1, 1, s, rope), q_map)]
+        + [pl.BlockSpec((1, 1, 1, page_size, width), page_map(j)) for j in range(pps)]
+        + [pl.BlockSpec((1, rank, nope), w_map), pl.BlockSpec((1, rank, vd), w_map)],
+        out_specs=pl.BlockSpec((1, 1, s, vd), q_map),
+        scratch_shapes=[pltpu.VMEM((s, 128), jnp.float32), pltpu.VMEM((s, 128), jnp.float32),
+                        pltpu.VMEM((s, vd), jnp.float32)],
+    )
+    # tpulint: disable=SHP003 -- built at trace time only: the one caller is the jitted prefill chunk (models/deepseek_v3.py), reached through a closure the linter does not follow
+    return pl.pallas_call(
+        functools.partial(_prefill_kernel, page_size=page_size, rank=rank, rope=rope, pps=pps),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, s, vd), qn.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(block_tables.astype(jnp.int32), cached_lens.astype(jnp.int32), kv_lens.astype(jnp.int32),
+      jnp.reshape(layer, (1,)).astype(jnp.int32), qn, qr, *([pool] * pps),
+      w_uk.swapaxes(1, 2), w_uv)
+
+
+def latent_prefill_attention(
+    q_nope: jnp.ndarray,  # [B, S, H, nope]
+    q_rope: jnp.ndarray,  # [B, S, H, rope] rotated
+    pool: jnp.ndarray,  # [L, 1, P, page_size, >= rank + rope], the chunk's rows already written
+    layer: jnp.ndarray,  # [] int32
+    block_tables: jnp.ndarray,  # [B, max_pages]
+    cached_lens: jnp.ndarray,  # [B] rows cached before this chunk
+    new_lens: jnp.ndarray,  # [B] valid new tokens
+    w_uk: jnp.ndarray,  # [H, nope, rank]
+    w_uv: jnp.ndarray,  # [H, rank, v]
+    scale: float,
+    use_pallas: bool = False,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Causal attention of a chunk over its row's cached prefix and itself,
+    K and V rebuilt from the latent rows a page (kernel) or ``TILE_PAGES``
+    pages (XLA, the oracle) at a time; pages past the longest row are not
+    visited.  -> [B, S, H, v]."""
+    b, s, h, _ = q_nope.shape
+    kv_lens = cached_lens + new_lens
+    qn = (q_nope.astype(jnp.float32) * scale).astype(q_nope.dtype)
+    qr = (q_rope.astype(jnp.float32) * scale).astype(q_rope.dtype)
+    if use_pallas:
+        out = _prefill_pallas(qn.swapaxes(1, 2), qr.swapaxes(1, 2), pool, layer, block_tables,
+                              cached_lens, kv_lens, w_uk, w_uv, interpret)
+        return out.swapaxes(1, 2)
+    ps, width = pool.shape[3], pool.shape[4]
+    rank, vd, rope = w_uk.shape[-1], w_uv.shape[-1], q_rope.shape[-1]
+    max_pages = block_tables.shape[1]
+    tp = min(TILE_PAGES, max_pages)
+    tile = tp * ps
+    n_tiles = (jnp.max(kv_lens) + tile - 1) // tile
+    q_pos = cached_lens[:, None] + jnp.arange(s)[None, :]  # [B, S]
+    # the table padded so that the last tile's slice never runs off its end
+    pad = (-max_pages) % tp
+    table = jnp.pad(block_tables, ((0, 0), (0, pad)))
+
+    def body(t, carry):
+        m, l, acc = carry
+        pages = jax.lax.dynamic_slice(table, (0, t * tp), (b, tp))
+        rows = pool[layer, 0, pages].reshape(b, tile, width)  # one gather from the pool
+        c_kv, k_rope = rows[..., :rank], rows[..., rank:rank + rope]
+        k_nope = jnp.einsum("btc,hnc->bthn", c_kv, w_uk)
+        v = jnp.einsum("btc,hcv->bthv", c_kv, w_uv)
+        sc = einsum_f32("bshn,bthn->bhst", qn, k_nope) + einsum_f32("bshr,btr->bhst", qr, k_rope)
+        kv_pos = t * tile + jnp.arange(tile)
+        ok = (kv_pos[None, None, :] <= q_pos[:, :, None]) \
+            & (kv_pos[None, None, :] < kv_lens[:, None, None])  # [B, S, T]
+        sc = jnp.where(ok[:, None], sc, NEG_INF)
+        m_new = jnp.maximum(m, sc.max(axis=-1))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(sc - m_new[..., None])
+        l = l * alpha + p.sum(axis=-1)
+        acc = acc * alpha[..., None] + einsum_f32("bhst,bthv->bhsv", p.astype(v.dtype), v)
+        return m_new, l, acc
+
+    m0 = jnp.full((b, h, s), NEG_INF, jnp.float32)
+    carry = (m0, jnp.zeros((b, h, s), jnp.float32), jnp.zeros((b, h, s, vd), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(0, n_tiles, body, carry)
+    out = acc / jnp.where(l == 0.0, 1.0, l)[..., None]  # padding rows: l == 0
+    return out.swapaxes(1, 2).astype(q_nope.dtype)  # [B, S, H, v]

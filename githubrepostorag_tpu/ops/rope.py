@@ -6,12 +6,43 @@ position 30k+ keep full precision, then applied in the activation dtype.
 
 from __future__ import annotations
 
+import math
+
 import jax.numpy as jnp
 
 
-def rope_cos_sin(positions: jnp.ndarray, head_dim: int, theta: float = 10000.0):
-    """positions [B, S] (int32) -> cos, sin each [B, S, head_dim]."""
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def yarn_inv_freq(head_dim: int, theta: float, factor: float, original_max: int,
+                  beta_fast: float = 32.0, beta_slow: float = 1.0) -> jnp.ndarray:
+    """YaRN inverse frequencies [head_dim/2] (DeepSeek-V2/V3's rotary
+    embedding): dimensions that turn more than ``beta_fast`` times inside the
+    original context keep their frequency, those that turn less than
+    ``beta_slow`` times are interpolated (divided by ``factor``), and a linear
+    ramp blends the two between the correction dimensions."""
+    def correction_dim(rotations: float) -> float:
+        return head_dim * math.log(original_max / (rotations * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), head_dim - 1)
+    exponents = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
+    extrapolated = 1.0 / theta ** exponents
+    interpolated = extrapolated / factor
+    ramp = (jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / max(high - low, 0.001)
+    keep = 1.0 - jnp.clip(ramp, 0.0, 1.0)  # 1 where the frequency is extrapolated
+    return interpolated * (1.0 - keep) + extrapolated * keep
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature: 0.1 * mscale * ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_cos_sin(positions: jnp.ndarray, head_dim: int, theta: float = 10000.0,
+                 inv_freq: jnp.ndarray | None = None):
+    """positions [B, S] (int32) -> cos, sin each [B, S, head_dim].
+    ``inv_freq`` [head_dim/2] replaces the plain theta ladder (YaRN)."""
+    if inv_freq is None:
+        inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # [B, S, hd/2]
     angles = jnp.concatenate([angles, angles], axis=-1)  # [B, S, hd]
     return jnp.cos(angles), jnp.sin(angles)
@@ -29,3 +60,8 @@ def apply_rope(q: jnp.ndarray, k: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarra
     q_out = q * cos + _rotate_half(q) * sin
     k_out = k * cos + _rotate_half(k) * sin
     return q_out, k_out
+
+
+def rope_rotate(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
+    """One tensor [..., hd] with cos/sin already broadcastable to it."""
+    return x * cos.astype(x.dtype) + _rotate_half(x) * sin.astype(x.dtype)

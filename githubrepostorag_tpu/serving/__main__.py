@@ -62,9 +62,7 @@ async def serve(host: str, port: int) -> None:
     from githubrepostorag_tpu.models.hf_loader import config_from_hf
 
     cfg = config_from_hf(
-        _json.loads((_Path(s.model_weights_path) / "config.json").read_text()),
-        moe_capacity_factor=s.moe_capacity_factor,
-    )
+        _json.loads((_Path(s.model_weights_path) / "config.json").read_text()))
     if s.mesh_shape:
         from githubrepostorag_tpu.parallel import plan_from_string
 
@@ -92,7 +90,6 @@ async def serve(host: str, port: int) -> None:
 
     params, cfg = load_qwen2(
         s.model_weights_path, dtype=ml_dtypes.bfloat16, quantize=s.quantize_weights,
-        moe_capacity_factor=s.moe_capacity_factor,
         fuse=plan.n_devices == 1,  # mesh=None below iff the plan is one chip
     )
 
@@ -108,10 +105,7 @@ async def serve(host: str, port: int) -> None:
                 "a serving pod runs one speculation strategy"
             )
         logger.info("loading draft model from %s", s.spec_draft_model)
-        draft_params, draft_cfg = load_qwen2(
-            s.spec_draft_model, dtype=ml_dtypes.bfloat16,
-            moe_capacity_factor=s.moe_capacity_factor,
-        )
+        draft_params, draft_cfg = load_qwen2(s.spec_draft_model, dtype=ml_dtypes.bfloat16)
 
     # tokenizer first: a broken tokenizer config must fail fast, not after
     # minutes of XLA warmup compiles

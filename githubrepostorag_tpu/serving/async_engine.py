@@ -28,6 +28,7 @@ from githubrepostorag_tpu.utils.profiling import annotate
 logger = get_logger(__name__)
 
 REQUEST_RING = 4096  # finished requests kept with their stamps
+SLOW_STEP_S = 2.0  # a step, or a gap between two steps with work, worth a warning
 
 # replica lifecycle states (serving/multi_engine.py drives transitions;
 # gauge encoding matches metrics.FLEET_LIFECYCLE)
@@ -376,6 +377,19 @@ class AsyncEngine:
                     compiles = self.profiler.on_step(step_start, step_end)
                     self.ledger.on_step(snap, step_start, step_end,
                                         compiles=compiles)
+                    rec = self.ledger.last_rec or {}
+                    if rec.get("wall", 0.0) + rec.get("sched_stall", 0.0) > SLOW_STEP_S:
+                        # a stall names itself: which phase held the step, or
+                        # that the driver did not step at all (sched_stall)
+                        logger.warning(
+                            "slow engine step: wall %.2f s (prefill %.2f, decode %.2f, "
+                            "compiles %d), %.2f s since the step before; running %d, "
+                            "waiting %d, free pages %d; host phases %s", rec.get("wall", 0.0),
+                            rec.get("prefill", 0.0), rec.get("decode", 0.0),
+                            int(rec.get("compiles", 0)), rec.get("sched_stall", 0.0),
+                            q_depths[0], q_depths[1], pool_depths[0],
+                            {k: round(v, 3) for k, v in
+                             getattr(self.engine, "step_phase_s", {}).items()})
                     # always-on sampled anatomy: every Nth step lands in the
                     # continuous ring (PROFILE_SAMPLE_EVERY); off the lock, so
                     # a flush can never stretch the locked section

@@ -324,6 +324,37 @@ class Engine:
         # preemption act FOR; its requests are never victims
     ) -> None:
         self.mesh = mesh
+        # which model runs is read from the configuration object: a latent
+        # (MLA) model brings its own two step programs under qwen2's
+        # contracts, one page pool and no V pool
+        self._latent = bool(getattr(cfg, "latent_kv", False))
+        self._forward_paged = forward_paged
+        if self._latent:
+            from githubrepostorag_tpu.models import deepseek_v3
+
+            unsupported = {
+                "mesh": mesh is not None, "kv_quant": bool(quant_bits(kv_quant)),
+                "prefill_token_budget": prefill_token_budget is not None,
+                "spec_ngram_k": spec_ngram_k > 0, "draft_params": draft_params is not None,
+                "fused_step": fused_step, "sp_prefill_threshold": sp_prefill_threshold is not None,
+                "kv_tier": kv_tier == "on" or kv_host_pool_pages > 0,
+            }
+            if any(unsupported.values()):
+                raise ValueError(
+                    "not built for a latent page pool: "
+                    + ", ".join(k for k, v in unsupported.items() if v))
+            self._forward_paged = deepseek_v3.forward_paged
+            self._decode_burst_fn = deepseek_v3.decode_burst
+            # experts hit / pairs routed to held experts / expert slots
+            # offered, per step program, cumulative; a dispatch's counts are
+            # read back once a later burst's tokens prove the device is past it
+            self.moe_stats = {"burst": [0, 0, 0], "prefill": [0, 0, 0]}
+            self._moe_pending: list[tuple[int, str, jnp.ndarray, int]] = []
+            self._dispatch_seq = 0
+        else:
+            from githubrepostorag_tpu.serving import decode_burst as burst_program
+
+            self._decode_burst_fn = burst_program.decode_burst
         if mesh is not None:
             from githubrepostorag_tpu.parallel.sharding import (
                 qwen2_param_specs,
@@ -341,7 +372,7 @@ class Engine:
                     "..., num_heads=..., num_kv_heads=..., role='serve')"
                 )
             params = shard_params(params, mesh, qwen2_param_specs(cfg, mesh, params))
-        else:
+        elif not self._latent:
             from githubrepostorag_tpu.models.quant import fuse_projections
 
             # single-chip: fuse wq|wk|wv and wg|wu so each layer runs 4
@@ -674,8 +705,23 @@ class Engine:
         self._page_obs = None
         # the open host-phase annotation (utils/profiling.annotate)
         self._phase_ann = None
+        # host seconds of the current step by phase name: a slow step's
+        # warning (serving/async_engine.py) says which phase held it
+        self._phase_name, self._phase_t0 = None, 0.0
+        self.step_phase_s: dict[str, float] = {}
 
     # ------------------------------------------------------- host phases --
+
+    @property
+    def page_pool(self) -> jnp.ndarray:
+        """The pool the step programs commit into: the K pool, or a latent
+        model's one pool.  Settable, for a caller that runs a step program
+        itself on the engine's pool (the benchmark's correctness sample)."""
+        return self._k_pages
+
+    @page_pool.setter
+    def page_pool(self, pool: jnp.ndarray) -> None:
+        self._k_pages = pool
 
     def _phase(self, name: str | None, **meta):
         """Name the host work from here to the next ``_phase`` call: one
@@ -684,8 +730,12 @@ class Engine:
         without opening).  Returns the annotation (``set_metadata`` adds
         counts known only at its end).  With no trace being taken this is
         two C++ calls and no formatting."""
+        now = time.monotonic()
         if self._phase_ann is not None:
             self._phase_ann.__exit__(None, None, None)
+            held = self.step_phase_s
+            held[self._phase_name] = held.get(self._phase_name, 0.0) + now - self._phase_t0
+        self._phase_name, self._phase_t0 = name, now
         self._phase_ann = ann = annotate(name, **meta) if name else None
         if ann is not None:
             ann.__enter__()
@@ -813,6 +863,7 @@ class Engine:
         never stalls running streams: each of its prefill steps rides along
         with a full decode burst.  Returns requests finished this step."""
         finished: list[GenerationResult] = []
+        self.step_phase_s = {}
         self._phase("engine.admit")
         for req in self._rejected:
             res = self._result(req, "error")
@@ -1625,7 +1676,11 @@ class Engine:
             for req in long_reqs:
                 prefilling.remove(req)
         if prefilling:
-            self._prefill_batch(prefilling, finished)
+            # a latent model caps the rows of one wave at the largest row
+            # bucket its prefill program is warmed for; the rest, admitted
+            # later, ride the next step's wave: step() re-enters here
+            cap = self.cfg.prefill_rows_cap if self._latent else len(prefilling)
+            self._prefill_batch(prefilling[:cap], finished)
         return True
 
     # ------------------------------------------------------------ compute --
@@ -1693,7 +1748,7 @@ class Engine:
         cached_d, new_lens_d = jnp.asarray(cached), jnp.asarray(new_lens)
         last_idx_d = jnp.asarray(last_idx)
         self.step_dispatches_total += 1
-        out = forward_paged(
+        out = self._forward_paged(
             self.params, self.cfg,
             ids_d, pos_d,
             self._k_pages, self._v_pages,
@@ -1706,6 +1761,10 @@ class Engine:
         if self.kv_quant:
             (logits, self._k_pages, self._v_pages,
              self._k_scales, self._v_scales) = out
+        elif self._latent:
+            logits, self._k_pages, self._v_pages, moe = out
+            self._moe_dispatched("prefill", moe, 1)
+            wave_ann.set_metadata(**self._moe_meta("prefill"))
         else:
             logits, self._k_pages, self._v_pages = out
         if self._draft_enabled:
@@ -2122,8 +2181,6 @@ class Engine:
         device by one burst; tokens a row produced past its stop are
         discarded at commit, and its pages are recycled once no in-flight
         burst references them (``_drain_chain``)."""
-        from githubrepostorag_tpu.serving.decode_burst import decode_burst
-
         self._phase("engine.burst_prepare")
         b = self.max_num_seqs
         active = np.zeros((b,), dtype=bool)
@@ -2185,8 +2242,8 @@ class Engine:
 
         self.step_dispatches_total += 1
         self._phase("engine.decode_burst", rows=live_rows, kv_tokens=kv_tokens,
-                    steps=n_steps)
-        out = decode_burst(
+                    steps=n_steps, **(self._moe_meta("burst") if self._latent else {}))
+        out = self._decode_burst_fn(
             self.params, self.cfg,
             last_d, lens_d,
             self._k_pages, self._v_pages, self._presence,
@@ -2212,6 +2269,10 @@ class Engine:
         if self.kv_quant:
             (toks, valid, self._k_pages, self._v_pages, self._presence,
              out_lens, self._k_scales, self._v_scales) = out
+        elif self._latent:
+            (toks, valid, self._k_pages, self._v_pages, self._presence,
+             out_lens, moe) = out
+            self._moe_dispatched("burst", moe, n_steps)
         else:
             (toks, valid, self._k_pages, self._v_pages, self._presence,
              out_lens) = out
@@ -2220,6 +2281,8 @@ class Engine:
             "last": toks[:, -1], "lens": out_lens, "pending": toks,
             "first": first_waves,
         }
+        if self._latent:
+            self._chain["seq"] = self._dispatch_seq
         if prev is not None:
             self._commit_burst(prev, finished)
 
@@ -2711,6 +2774,8 @@ class Engine:
         self._commit_first_tokens(entry.get("first", []), finished)
         self._phase("engine.commit_fetch")
         toks = np.asarray(entry["pending"])  # [B, n_steps]
+        if "seq" in entry:
+            self._moe_read_back(entry["seq"])
         self._phase("engine.commit_host", tokens=int((toks >= 0).sum()))
         for i in range(toks.shape[1]):
             for row in sorted(self._row_req):
@@ -2720,6 +2785,33 @@ class Engine:
                 req.seq_len += 1
                 self._seq_lens[row] = req.seq_len
                 self._commit_token(req, int(toks[row, i]), finished)
+
+    def _moe_dispatched(self, program: str, counts: jnp.ndarray, steps: int) -> None:
+        """A step program that ran expert layers was dispatched: keep its
+        device-side [experts hit, expert tokens] until they can be read
+        without waiting, and book the expert slots it offered."""
+        self._dispatch_seq += 1
+        cfg = self.cfg
+        slots = cfg.n_held * (cfg.num_layers - cfg.first_k_dense) * steps
+        self._moe_pending.append((self._dispatch_seq, program, counts, slots))
+
+    def _moe_read_back(self, upto: int) -> None:
+        """Add the counts of every dispatch up to ``upto`` (a burst whose
+        tokens were just fetched, so the device is past all of them)."""
+        from githubrepostorag_tpu.metrics import MOE_EXPERT_TOKENS, MOE_EXPERTS_HIT
+
+        while self._moe_pending and self._moe_pending[0][0] <= upto:
+            _, program, counts, slots = self._moe_pending.pop(0)
+            hit, tokens = (int(x) for x in np.asarray(counts))
+            acc = self.moe_stats[program]
+            acc[0], acc[1], acc[2] = acc[0] + hit, acc[1] + tokens, acc[2] + slots
+            MOE_EXPERTS_HIT.labels(program=program).inc(hit)
+            MOE_EXPERT_TOKENS.labels(program=program).inc(tokens)
+
+    def _moe_meta(self, program: str) -> dict:
+        """The cumulative counts, as an annotation's stats."""
+        hit, tokens, slots = self.moe_stats[program]
+        return {"experts_hit": hit, "expert_tokens": tokens, "expert_slots": slots}
 
     def _drain_chain(self, finished: list[GenerationResult]) -> None:
         """Land the in-flight burst (if any), commit its tokens and any

@@ -35,10 +35,15 @@ class PagePools:
     ``ks``/``vs``: per-PAGE dequant scales [L, n_kv, P] f32
     when the pools are int8 (``kv_quant`` engines — each cached token
     vector is symmetric int8 with its own scale: no calibration, and the
-    scale read is 1/hd of the payload); None for full-precision pools."""
+    scale read is 1/hd of the payload); None for full-precision pools.
+
+    A latent (MLA) model has ONE pool: ``k`` holds a row ``[c_kv | k_rope]``
+    a token and layer ([L, 1, P, page_size, kv_lora_rank + rope padded to
+    whole lane tiles]: ``cfg.head_dim``) and ``v`` is None.  Allocator, prefix cache and block tables are per token
+    position and do not change."""
 
     k: jnp.ndarray  # [L, n_kv, P, page_size, hd]
-    v: jnp.ndarray
+    v: jnp.ndarray | None
     ks: jnp.ndarray | None = None  # [L, n_kv, P] f32 (per-page)
     vs: jnp.ndarray | None = None
 
@@ -77,6 +82,10 @@ def make_page_pools(
 ) -> PagePools:
     shape = (cfg.num_layers, cfg.num_kv_heads, num_pages, page_size, cfg.head_dim)
     bits = quant_bits(quant)
+    if getattr(cfg, "latent_kv", False):
+        if bits:
+            raise ValueError("a latent page pool has no quantized form")
+        return PagePools(k=jnp.zeros(shape, dtype=dtype), v=None)
     if bits == 4:
         # int4: two head components share a byte (pack_int4's nibble
         # planes), so the payload axis is hd//2 uint8 — the dtype is the

@@ -63,10 +63,13 @@ def test_quantize_weights_typo_raises(monkeypatch):
     reload_settings()
 
 
-def test_moe_capacity_factor_env(monkeypatch):
+def test_no_setting_bounds_an_experts_capacity(monkeypatch):
+    """The dispatch is dropless (models/moe.dropless_experts): the knobs of
+    the dropping one are gone, and setting their variables changes nothing."""
     from githubrepostorag_tpu.config import reload_settings
 
     monkeypatch.setenv("MOE_CAPACITY_FACTOR", "1.25")
-    assert reload_settings().moe_capacity_factor == 1.25
-    monkeypatch.delenv("MOE_CAPACITY_FACTOR")
-    assert reload_settings().moe_capacity_factor == 2.0
+    monkeypatch.setenv("MOE_DROP_STATS", "1")
+    s = reload_settings()
+    assert not hasattr(s, "moe_capacity_factor")
+    assert not any("moe" in name for name in vars(s))

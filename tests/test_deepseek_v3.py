@@ -1,0 +1,281 @@
+"""DeepSeek-V3 at a small size on the CPU, seeded weights, against the plain
+reference (benchmarks/reference_deepseek_v3.py: float32, not absorbed, a loop
+over experts, no cache): logits, not tokens."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks import reference_deepseek_v3 as ref
+from githubrepostorag_tpu.models import deepseek_v3 as ds
+from githubrepostorag_tpu.models import moe as moe_ops
+from githubrepostorag_tpu.models.moe import dropless_experts, route_noaux_tc
+from githubrepostorag_tpu.ops import latent_attention as latent_ops
+from githubrepostorag_tpu.ops.latent_attention import (
+    latent_decode_attention,
+    latent_prefill_attention,
+)
+from githubrepostorag_tpu.serving import Engine, SamplingParams
+
+PAGE, PAGES = 8, 48
+YARN = dict(factor=40, original_max_position_embeddings=64, beta_fast=32, beta_slow=1,
+            mscale=1.0, mscale_all_dim=1.0, type="yarn")
+
+
+def model_of(cfg: ds.DeepseekV3Config) -> dict:
+    """The reference's description of ``cfg`` (HF config.json keys)."""
+    return dict(
+        hidden_size=cfg.hidden_size, num_attention_heads=cfg.num_heads,
+        q_lora_rank=cfg.q_lora_rank, kv_lora_rank=cfg.kv_lora_rank,
+        qk_nope_head_dim=cfg.qk_nope_head_dim, qk_rope_head_dim=cfg.qk_rope_head_dim,
+        v_head_dim=cfg.v_head_dim, intermediate_size=cfg.intermediate_size,
+        moe_intermediate_size=cfg.moe_intermediate_size, n_shared_experts=cfg.n_shared_experts,
+        n_routed_experts=cfg.n_routed_experts, experts_held=list(cfg.experts_held),
+        first_k_dense_replace=cfg.first_k_dense, num_hidden_layers=cfg.num_layers,
+        vocab_size=cfg.vocab_size, num_experts_per_tok=cfg.num_experts_per_tok,
+        n_group=cfg.n_group, topk_group=cfg.topk_group,
+        routed_scaling_factor=cfg.routed_scaling_factor, rms_norm_eps=cfg.rms_norm_eps,
+        rope_theta=cfg.rope_theta, rope_scaling=YARN)
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    cfg = ds.DeepseekV3Config.tiny(experts_held=(4, 12))
+    return cfg, ds.init_params(cfg, seed=11)
+
+
+def prefill(cfg, params, pool, ids, start, bt, all_positions=True):
+    """One chunk ``ids`` [B, S] at position ``start`` through forward_paged."""
+    b, s = ids.shape
+    pos = np.broadcast_to(start + np.arange(s, dtype=np.int32), (b, s))
+    slots = np.stack([bt[i, pos[i] // PAGE] * PAGE + pos[i] % PAGE for i in range(b)])
+    logits, pool, _, stats = ds.forward_paged(
+        params, cfg, jnp.asarray(ids), jnp.asarray(pos), pool, None, jnp.asarray(slots),
+        jnp.asarray(bt), jnp.full((b,), start, jnp.int32), jnp.full((b,), s, jnp.int32),
+        logits_at=None if all_positions else jnp.full((b,), s - 1, jnp.int32))
+    return np.asarray(logits, np.float32), pool, stats
+
+
+def rel_rms(got, want):
+    return float(np.sqrt(np.mean((got - want) ** 2)) / np.sqrt(np.mean(want ** 2)))
+
+
+def test_yarn_frequencies_match_the_reference():
+    from githubrepostorag_tpu.ops.rope import yarn_inv_freq
+
+    for dim, orig in ((8, 64), (64, 4096)):
+        got = np.asarray(yarn_inv_freq(dim, 10000.0, 40.0, orig, 32.0, 1.0))
+        np.testing.assert_allclose(got, ref.yarn_inv_freq(dim, 10000.0, {**YARN,
+                                   "original_max_position_embeddings": orig}), rtol=1e-6)
+    cfg = ds.DeepseekV3Config()
+    assert cfg.head_dim == 640 and cfg.num_kv_heads == 1  # 576 used, whole lane tiles
+    assert abs(cfg.softmax_scale - 192 ** -0.5 * 1.3689 ** 2) < 1e-4
+    assert abs(ref.softmax_scale(model_of(cfg) | {"rope_scaling": {**YARN}}) - cfg.softmax_scale) < 1e-9
+
+
+def test_prefill_then_decode_through_the_latent_cache_matches_the_reference(tiny):
+    """Two prompts prefilled in chunks of 16 into the paged latent pool, then
+    eight greedy tokens through decode bursts: the prefill logits at every
+    position, and the logits the reference gives the engine's tokens."""
+    cfg, params = tiny
+    rng = np.random.default_rng(3)
+    ids = rng.integers(2, cfg.vocab_size, size=(2, 32)).astype(np.int32)
+    bt = np.arange(2 * 6, dtype=np.int32).reshape(2, 6)
+    pool = jnp.zeros((cfg.num_layers, 1, PAGES, PAGE, cfg.head_dim), jnp.bfloat16)
+    got = []
+    for start in (0, 16):
+        logits, pool, _ = prefill(cfg, params, pool, ids[:, start:start + 16], start, bt)
+        got.append(logits)
+    got = np.concatenate(got, axis=1)  # [2, 32, V]
+    want = ref.logits_at(model_of(cfg), 11, [list(r) for r in ids], [list(range(32))] * 2)
+    assert rel_rms(got, np.stack(want)) < 0.02  # bfloat16 activations against float32
+
+    eng = Engine(params, cfg, max_num_seqs=4, num_pages=PAGES, page_size=PAGE, max_seq_len=64,
+                 prefill_chunk=16, decode_burst=4, rng_seed=0)
+    sp = SamplingParams(max_tokens=8, temperature=0.0, stop_token_ids=())
+    outs = [list(r.output_tokens) for r in eng.generate([list(map(int, r)) for r in ids], sp)]
+    full = [list(map(int, r)) + o[:-1] for r, o in zip(ids, outs)]
+    rows = ref.logits_at(model_of(cfg), 11, full, [list(range(31, 39))] * 2)
+    for row, toks in zip(rows, outs):
+        gaps = [(r.max() - r[t]) / r.std() for r, t in zip(row, toks)]
+        assert np.mean(gaps) < 0.02  # the engine picks the reference's best, or a near-tie
+    assert eng.moe_stats["burst"][2] == 2 * 2 * 4 * cfg.n_held  # 2 bursts x 2 layers x 4 steps
+
+
+def test_a_prefill_wave_stays_inside_the_warmed_row_buckets(tiny):
+    """Eleven prompts admitted in one step: a wave carries at most
+    ``prefill_rows_cap`` rows (the largest bucket a server warms), the rest
+    ride the next step's wave, and every prompt gets the tokens it gets when
+    it is served alone."""
+    cfg, params = tiny
+    assert cfg.prefill_rows_cap == 8
+    rng = np.random.default_rng(7)
+    prompts = [list(map(int, rng.integers(2, cfg.vocab_size, size=20))) for _ in range(11)]
+    sp = SamplingParams(max_tokens=3, temperature=0.0, stop_token_ids=())
+
+    def engine():
+        return Engine(params, cfg, max_num_seqs=16, num_pages=PAGES, page_size=PAGE,
+                      max_seq_len=64, prefill_chunk=32, decode_burst=4, rng_seed=0)
+
+    eng = engine()
+    waves, inner = [], eng._prefill_batch
+    eng._prefill_batch = lambda reqs, finished: (waves.append(len(reqs)), inner(reqs, finished))[1]
+    together = [list(r.output_tokens) for r in eng.generate(prompts, sp)]
+    assert waves == [8, 3]
+    alone = engine()
+    assert together == [list(alone.generate([p], sp)[0].output_tokens) for p in prompts]
+
+
+def test_a_chunk_against_a_cached_prefix_equals_a_cold_prefill(tiny):
+    """The prefix-cache hit path: 16 new tokens after 32 cached give the
+    logits a cold 48-token prefill gives at the same positions."""
+    cfg, params = tiny
+    ids = np.random.default_rng(5).integers(2, cfg.vocab_size, size=(1, 48)).astype(np.int32)
+    bt = np.arange(6, dtype=np.int32).reshape(1, 6)
+    zeros = lambda: jnp.zeros((cfg.num_layers, 1, PAGES, PAGE, cfg.head_dim), jnp.bfloat16)  # noqa: E731
+    cold, _, _ = prefill(cfg, params, zeros(), ids, 0, bt)
+    _, pool, _ = prefill(cfg, params, zeros(), ids[:, :32], 0, bt)
+    warm, pool, _ = prefill(cfg, params, pool, ids[:, 32:], 32, bt)
+    assert rel_rms(warm, cold[:, 32:]) < 5e-3
+    want = ref.logits_at(model_of(cfg), 11, [list(ids[0])], [list(range(32, 48))])[0]
+    assert rel_rms(warm[0], want) < 0.02
+
+
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["gather", "kernel-interpreted"])
+def test_absorbed_attention_equals_materialised_attention(use_pallas, monkeypatch):
+    """One new token over a paged latent prefix, float32: the decode path
+    (W_uk folded into the query, W_uv applied after, rows read as stored)
+    against the prefill path (K and V rebuilt from the latents tile by tile)."""
+    monkeypatch.setattr(latent_ops, "TILE_PAGES", 2)  # two tiles over the four pages
+    h, nope, rope, rank, vd, ps, width = 4, 16, 8, 16, 16, 8, 128
+    k = jax.random.split(jax.random.PRNGKey(0), 6)
+    lens = jnp.asarray([13, 30], jnp.int32)
+    bt = jnp.asarray([[0, 1, 2, 3], [4, 5, 6, 7]], jnp.int32)
+    pool = jnp.zeros((2, 1, 8, ps, width)).at[..., :rank + rope].set(
+        jax.random.normal(k[0], (2, 1, 8, ps, rank + rope)))
+    new = jnp.zeros((2, 1, width)).at[..., :rank + rope].set(
+        jax.random.normal(k[1], (2, 1, rank + rope)))
+    q_nope, q_rope = jax.random.normal(k[2], (2, 1, h, nope)), jax.random.normal(k[3], (2, 1, h, rope))
+    w_uk, w_uv = jax.random.normal(k[4], (h, nope, rank)), jax.random.normal(k[5], (h, rank, vd))
+    layer, scale = jnp.int32(1), 0.2
+    staged = jnp.zeros((2, 4, width)).at[:, :1].set(new)
+    q_lat = jnp.einsum("bhn,hnc->bhc", q_nope[:, 0], w_uk)
+    out = latent_decode_attention(q_lat * scale, q_rope[:, 0] * scale, pool, layer, bt, lens,
+                                  staged, jnp.int32(1), use_pallas=use_pallas, interpret=True)
+    absorbed = jnp.einsum("bhc,hcv->bhv", out, w_uv)
+    # the materialised path reads the new row from the pool, where the engine commits it
+    slot = bt[jnp.arange(2), lens // ps] * ps + lens % ps
+    flat = pool.reshape(2, 1, -1, width).at[layer, 0, slot].set(new[:, 0]).reshape(pool.shape)
+    mat = latent_prefill_attention(q_nope, q_rope, flat, layer, bt, lens, jnp.ones((2,), jnp.int32),
+                                   w_uk, w_uv, scale)
+    np.testing.assert_allclose(np.asarray(absorbed), np.asarray(mat[:, 0]), rtol=2e-4, atol=2e-4)
+
+
+def test_prefill_kernel_equals_the_tiled_oracle(monkeypatch):
+    """A 16-token chunk over cached prefixes of 13 and 30 rows (and a padding
+    row that walks no page): the Pallas kernel, interpreted, against the XLA
+    path that materialises K and V a tile at a time."""
+    monkeypatch.setattr(latent_ops, "TILE_PAGES", 2)
+    h, nope, rope, rank, vd, ps, width, s = 4, 16, 8, 16, 16, 8, 128, 16
+    k = jax.random.split(jax.random.PRNGKey(9), 5)
+    cached = jnp.asarray([13, 30, 0], jnp.int32)
+    new = jnp.asarray([16, 9, 0], jnp.int32)
+    bt = jnp.asarray([[0, 1, 2, 3, 8, 9], [4, 5, 6, 7, 10, 11], [0, 0, 0, 0, 0, 0]], jnp.int32)
+    pool = jnp.zeros((2, 1, 12, ps, width)).at[..., :rank + rope].set(
+        jax.random.normal(k[0], (2, 1, 12, ps, rank + rope)))
+    q_nope, q_rope = jax.random.normal(k[1], (3, s, h, nope)), jax.random.normal(k[2], (3, s, h, rope))
+    w_uk, w_uv = jax.random.normal(k[3], (h, nope, rank)), jax.random.normal(k[4], (h, rank, vd))
+    args = (q_nope, q_rope, pool, jnp.int32(1), bt, cached, new, w_uk, w_uv, 0.2)
+    want = latent_prefill_attention(*args)
+    got = latent_prefill_attention(*args, use_pallas=True, interpret=True)
+    for row, n in enumerate([16, 9]):  # padded queries attend what their position allows: unused
+        np.testing.assert_allclose(np.asarray(got[row, :n]), np.asarray(want[row, :n]),
+                                   rtol=2e-4, atol=2e-4)
+    assert np.isfinite(np.asarray(got)).all() and not np.asarray(got[2]).any()
+
+
+def test_router_is_the_group_limited_top_k_and_the_bias_only_selects():
+    """Against the reference's hand-written selection, on seeded scores with
+    no ties; the bias changes which experts are chosen and never a weight."""
+    t, e, k, groups, keep = 64, 32, 4, 8, 3
+    scores = jax.nn.sigmoid(2 * jax.random.normal(jax.random.PRNGKey(1), (t, e)))
+    bias = 0.3 * jax.random.normal(jax.random.PRNGKey(2), (e,))
+    ids, w = route_noaux_tc(scores, bias, k, groups, keep, True, 2.5)
+    dense = np.zeros((t, e), np.float32)
+    np.put_along_axis(dense, np.asarray(ids), np.asarray(w), axis=1)
+    np.testing.assert_allclose(dense, np.asarray(ref.route(scores, bias, k, groups, keep, 2.5)),
+                               rtol=1e-5, atol=1e-7)
+    np.testing.assert_allclose(np.asarray(w).sum(axis=1), 2.5, rtol=1e-5)
+    plain, _ = route_noaux_tc(scores, jnp.zeros((e,)), k, groups, keep, True, 2.5)
+    differ = [set(map(int, a)) != set(map(int, b)) for a, b in zip(np.asarray(ids), np.asarray(plain))]
+    assert sum(differ) > t // 4  # s + b and s pick differently
+    # hand check of one token: groups by the sum of their two best biased scores
+    biased = np.asarray(scores + bias)[0].reshape(groups, -1)
+    best = np.argsort(-np.sort(biased, axis=1)[:, -2:].sum(axis=1))[:keep]
+    allowed = {g * (e // groups) + j for g in best for j in range(e // groups)}
+    order = sorted(allowed, key=lambda j: -np.asarray(scores + bias)[0, j])[:k]
+    assert set(order) == set(map(int, np.asarray(ids)[0]))
+
+
+def test_every_share_of_the_experts_adds_up_to_the_uncut_layer():
+    """The share test (model-configs guide, section 4): over all 16 ranges of
+    the experts, the parts the program's expert layer computes for the
+    experts it holds, with the shared expert counted once, sum to what the
+    uncut reference gives for the whole layer."""
+    cfg = ds.DeepseekV3Config.tiny(n_routed_experts=32, n_group=4, topk_group=2,
+                                   num_experts_per_tok=6, experts_held=(0, 32))
+    model = model_of(cfg)
+    d, ffe, e = cfg.hidden_size, cfg.moe_intermediate_size, cfg.n_routed_experts
+    k = jax.random.split(jax.random.PRNGKey(4), 7)
+    x = jax.random.normal(k[0], (2, 9, d))
+    p = {"router": 0.4 * jax.random.normal(k[1], (d, e)),
+         "e_bias": 0.2 * jax.random.normal(k[2], (e,)),
+         "s_wgu": 0.1 * jax.random.normal(k[5], (d, 2 * ffe)),
+         "s_wd": 0.1 * jax.random.normal(k[6], (ffe, d))}
+    e_wgu = 0.1 * jax.random.normal(k[3], (1, e, d, 2 * ffe))
+    e_wd = 0.1 * jax.random.normal(k[4], (1, e, ffe, d))
+    live = jnp.ones((2, 9), bool)
+    shared = ds._swiglu(x, p["s_wgu"], p["s_wd"])
+    total, pairs = shared, 0
+    for lo in range(0, e, 2):  # 16 chips, 2 experts each
+        share = dataclasses.replace(cfg, experts_held=(lo, lo + 2))
+        y, stats = ds._moe_ffn(share, p, {"e_wgu": e_wgu[:, lo:lo + 2], "e_wd": e_wd[:, lo:lo + 2]},
+                               jnp.int32(0), x, live)
+        total = total + (y - shared)  # every chip computes the shared expert alike: once
+        pairs += int(stats[1])
+    assert pairs == 2 * 9 * cfg.num_experts_per_tok  # every pair computed by exactly one share
+    want = ref.moe_layer(model, x.reshape(-1, d), p["router"], p["e_bias"],
+                         lambda i: (e_wgu[0, i], e_wd[0, i]), (p["s_wgu"], p["s_wd"]))
+    np.testing.assert_allclose(np.asarray(total).reshape(-1, d), np.asarray(want),
+                               rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("tokens,tile", [(40, 8), (5, 128), (64, 16)])
+def test_dropless_dispatch_loses_nothing_when_every_token_picks_one_expert(tokens, tile,
+                                                                           monkeypatch):
+    """The case a capacity would drop from: all tokens route to one held
+    expert (and to one that is not held, which adds nothing)."""
+    d, n = 16, 4
+    k = jax.random.split(jax.random.PRNGKey(7), 3)
+    x = jax.random.normal(k[0], (tokens, d))
+    w = jax.random.normal(k[1], (n, d, d))
+    top_i = jnp.stack([jnp.full((tokens,), 10 + 2), jnp.full((tokens,), 3)], axis=1)
+    top_w = jax.random.uniform(k[2], (tokens, 2))
+    monkeypatch.setattr(moe_ops, "EXPERT_TILE", tile)
+    y, counts = dropless_experts(x, top_i, top_w, lambda e, rows: rows @ w[e], n, lo=10)
+    np.testing.assert_allclose(np.asarray(y), np.asarray((x @ w[2]) * top_w[:, :1]),
+                               rtol=1e-5, atol=1e-5)
+    assert list(map(int, counts)) == [0, 0, tokens, 0]
+
+
+def test_engine_refuses_what_is_not_built_for_a_latent_pool(tiny):
+    cfg, params = tiny
+    for kw in ({"kv_quant": 8}, {"spec_ngram_k": 2}, {"prefill_token_budget": 64},
+               {"kv_tier": "on"}):
+        with pytest.raises(ValueError, match="latent page pool"):
+            Engine(params, cfg, max_num_seqs=2, num_pages=16, page_size=PAGE, max_seq_len=64, **kw)
+    eng = Engine(params, cfg, max_num_seqs=2, num_pages=16, page_size=PAGE, max_seq_len=64)
+    assert eng._v_pages is None and eng.page_pool.shape == (cfg.num_layers, 1, 16, PAGE, 128)

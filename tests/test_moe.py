@@ -1,5 +1,5 @@
 """Qwen2-MoE model family (models/moe.py): HF logits/generation parity,
-expert-parallel sharding parity on the CPU mesh, capacity-drop semantics,
+expert-parallel sharding parity on the CPU mesh, the dropless dispatch,
 and the full serving engine over a MoE checkpoint.
 """
 
@@ -35,12 +35,9 @@ def tiny_moe():
         decoder_sparse_step=1, mlp_only_layers=[],
         output_router_logits=False,
     )
-    import dataclasses
-
     torch.manual_seed(0)
     model = transformers.Qwen2MoeForCausalLM(hf_cfg).eval()
-    # exact no-drop dispatch for HF parity (serving default is bounded)
-    cfg = dataclasses.replace(config_from_hf(hf_cfg.to_dict()), capacity_factor=0.0)
+    cfg = config_from_hf(hf_cfg.to_dict())  # the served dispatch is the exact one
     params = params_from_state_dict(model.state_dict(), cfg)
     return model, params, cfg
 
@@ -54,10 +51,9 @@ def test_config_from_hf_maps_moe_fields(tiny_moe):
     assert cfg.moe_intermediate_size == 48
     assert cfg.shared_expert_intermediate_size == 96
     assert cfg.norm_topk_prob is True
-    assert cfg.capacity_factor == 0.0  # fixture overrode it for parity
-    # the LOAD default is bounded capacity: no-drop dispatch is quadratic
+    # nothing bounds an expert's capacity any more: what is loaded is what parity ran
     loaded = config_from_hf(transformers.Qwen2MoeConfig(num_experts=4).to_dict())
-    assert loaded.capacity_factor == 2.0
+    assert not hasattr(loaded, "capacity_factor") and loaded.num_experts == 4
 
 
 def test_nonuniform_sparsity_rejected():
@@ -141,21 +137,26 @@ def test_ep_sharded_engine_token_identical(tiny_moe):
         raise AssertionError("ep-sharded engine decode diverged on 2 seeds")
 
 
-def test_capacity_drops_are_bounded_not_catastrophic():
-    """With a finite capacity factor, overflow tokens lose expert
-    contributions but the shared expert keeps outputs finite and close."""
-    cfg_exact = Qwen2Config.tiny_moe()
-    cfg_cap = Qwen2Config(**{**cfg_exact.__dict__, "capacity_factor": 1.5})
-    params = init_params(cfg_exact, jax.random.PRNGKey(0))
-    rng = np.random.default_rng(3)
-    ids = jnp.asarray(rng.integers(0, cfg_exact.vocab_size, (2, 32), dtype=np.int32))
-    pos = jnp.broadcast_to(jnp.arange(32, dtype=jnp.int32), (2, 32))
-    exact = np.asarray(forward_with_attend(params, cfg_exact, ids, pos))
-    capped = np.asarray(forward_with_attend(params, cfg_cap, ids, pos))
-    assert np.all(np.isfinite(capped))
-    # most tokens fit under capacity, so most logits agree with no-drop
-    frac_same = np.mean(np.abs(capped - exact) < 1e-4)
-    assert frac_same > 0.5, f"only {frac_same:.0%} of logits survived capacity"
+def test_uneven_routing_equals_a_loop_over_experts(monkeypatch):
+    """Where a capacity of 1.5 used to drop: routing skewed hard towards one
+    expert still gives, token for token, what a dense loop over the experts
+    gives (tiles of 8 rows, so the busy expert spans several)."""
+    from githubrepostorag_tpu.models import moe
+    from githubrepostorag_tpu.models.moe import dropless_experts
+
+    monkeypatch.setattr(moe, "EXPERT_TILE", 8)
+    t, d, e, k = 64, 16, 4, 2
+    keys = jax.random.split(jax.random.PRNGKey(3), 3)
+    x = jax.random.normal(keys[0], (t, d))
+    w = jax.random.normal(keys[1], (e, d, d))
+    logits = jax.random.normal(keys[2], (t, e)).at[:, 1].add(4.0)  # expert 1 takes nearly all
+    top_w, top_i = jax.lax.top_k(jax.nn.softmax(logits), k)
+    y, counts = dropless_experts(x, top_i, top_w, lambda ex, rows: rows @ w[ex], e)
+    want = sum(jnp.where((top_i == ex).any(axis=1, keepdims=True),
+                         (x @ w[ex]) * jnp.where(top_i == ex, top_w, 0).sum(axis=1, keepdims=True), 0)
+               for ex in range(e))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=1e-5, atol=1e-5)
+    assert int(counts.sum()) == t * k and int(counts[1]) > t * 0.9
 
 
 def test_moe_int8_quantization(tiny_moe):
@@ -231,32 +232,34 @@ def test_moe_sharded_train_step(tiny_moe):
     assert np.abs(after - before).sum() > 0, "expert weights did not update"
 
 
-def test_moe_drop_stats_counter(tiny_moe, monkeypatch):
-    """MOE_DROP_STATS=1 makes bounded-capacity dispatch observable: a
-    router forced to send every token to one expert under a tight capacity
-    must report drops (ADVICE r02 — silent contribution loss)."""
-    import dataclasses
-
+def test_every_token_to_one_expert_loses_nothing(tiny_moe):
+    """The case the drop counter used to report: a router forced to send
+    every token to expert 0.  The layer's output is the dense formula's, for
+    every token, and no [T, E, C] tensor is built on the way."""
     from githubrepostorag_tpu.models import moe
 
     _, params, cfg = tiny_moe
-    cfg = dataclasses.replace(cfg, capacity_factor=0.5)
-    # all tokens to expert 0: bias the router column hard
     lay = dict(params["layers"])
     router = np.asarray(lay["router"]).copy()
     router[:, :, 0] += 100.0
     lay["router"] = jnp.asarray(router)
-    monkeypatch.setenv("MOE_DROP_STATS", "1")
-    moe.DROP_STATS["assignments"] = moe.DROP_STATS["dropped"] = 0
-    x = jnp.asarray(np.random.default_rng(0).normal(size=(2, 8, cfg.hidden_size)),
-                    dtype=jnp.float32)
+    x = jnp.asarray(np.abs(np.random.default_rng(0).normal(size=(2, 8, cfg.hidden_size))),
+                    dtype=jnp.float32)  # positive, so the biased column wins for every token
     p0 = jax.tree.map(lambda l: l[0], lay)
-    jax.block_until_ready(moe.moe_mlp(cfg, p0, x))
-    assert moe.DROP_STATS["assignments"] == 2 * 8 * cfg.num_experts_per_tok
-    assert moe.DROP_STATS["dropped"] > 0
-
-    # disabled -> no callback, counters untouched
-    monkeypatch.delenv("MOE_DROP_STATS")
-    moe.DROP_STATS["assignments"] = moe.DROP_STATS["dropped"] = 0
-    jax.block_until_ready(moe.moe_mlp(cfg, p0, x))
-    assert moe.DROP_STATS == {"assignments": 0, "dropped": 0}
+    got = moe.moe_mlp(cfg, p0, x)
+    xf = x.reshape(-1, cfg.hidden_size)
+    probs = jax.nn.softmax(xf @ p0["router"], axis=-1)
+    top_p, top_i = jax.lax.top_k(probs, cfg.num_experts_per_tok)
+    assert bool((top_i[:, 0] == 0).all())
+    top_p = top_p / top_p.sum(axis=-1, keepdims=True)
+    want = jax.nn.silu(xf @ p0["s_wg"]) * (xf @ p0["s_wu"]) @ p0["s_wd"] \
+        * jax.nn.sigmoid(xf @ p0["s_gate"])
+    for j in range(cfg.num_experts_per_tok):
+        for ex in range(cfg.num_experts):
+            h = jax.nn.silu(xf @ p0["e_wg"][ex]) * (xf @ p0["e_wu"][ex]) @ p0["e_wd"][ex]
+            want = want + jnp.where((top_i[:, j] == ex)[:, None], h * top_p[:, j:j + 1], 0)
+    np.testing.assert_allclose(np.asarray(got).reshape(-1, cfg.hidden_size), np.asarray(want),
+                               rtol=2e-4, atol=2e-5)
+    shapes = {tuple(v.aval.shape) for eqn in jax.make_jaxpr(
+        lambda x: moe.moe_mlp(cfg, p0, x))(x).jaxpr.eqns for v in eqn.outvars}
+    assert not any(len(sh) == 3 and sh[0] == 16 and sh[1] == cfg.num_experts for sh in shapes)

@@ -361,8 +361,8 @@ def test_compile_watchdog_detects_a_genuine_recompile():
     f(jnp.zeros((2,), jnp.float32))
     assert dog.sample() == 0  # cache hit is not a compile
     f(jnp.zeros((3,), jnp.float32))  # fresh shape -> real XLA compile
-    assert dog.sample() == 1
-    assert dog.sample() == 0  # delta, not level
+    assert dog.sample() == 1 and dog.grown == ["test.f"]
+    assert dog.sample() == 0 and dog.grown == []  # delta, not level
 
 
 def test_discover_jits_finds_the_serving_programs():
@@ -371,6 +371,10 @@ def test_discover_jits_finds_the_serving_programs():
     jits = discover_jits()
     assert jits, "no jitted callables found in the serving/model modules"
     assert all(callable(obj._cache_size) for _, obj in jits)
+    names = {name for name, _ in jits}  # both model families' step programs are watched
+    assert {"githubrepostorag_tpu.serving.decode_burst.decode_burst",
+            "githubrepostorag_tpu.models.deepseek_v3.decode_burst",
+            "githubrepostorag_tpu.models.deepseek_v3.forward_paged"} <= names
 
 
 # ------------------------------------------------- full stack over a bus ---

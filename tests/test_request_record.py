@@ -125,6 +125,24 @@ async def test_in_process_stream_stamps_its_own_receipt():
     assert aeng.request_ring[-1]["timings"] is t
 
 
+async def test_a_slow_step_names_the_phase_that_held_it(monkeypatch, caplog):
+    """Above ``SLOW_STEP_S`` the driver warns with the step's ledger record and
+    the engine's host seconds by phase (a stall names itself in any run)."""
+    from githubrepostorag_tpu.serving import async_engine
+
+    monkeypatch.setattr(async_engine, "SLOW_STEP_S", 0.0)
+    aeng = AsyncEngine(_engine())
+    try:
+        with caplog.at_level("WARNING", logger=async_engine.logger.name):
+            await aeng.generate(list(range(3, 40)))
+    finally:
+        await aeng.stop()
+    slow = [r.getMessage() for r in caplog.records if "slow engine step" in r.getMessage()]
+    assert slow and "host phases {'engine.admit'" in slow[0] and "free pages" in slow[0]
+    assert any("'engine.decode_burst'" in m for m in slow)
+    assert set(aeng.engine.step_phase_s) <= set(DRIVER_PHASES)
+
+
 def test_add_request_keeps_its_call_shape_for_wrappers():
     """benchmarks/system.Probe wraps add_request(prompt_ids, sampling=None, *a,
     **kw), _decode_step(finished), _prefill_batch(reqs, finished) and
