@@ -1,15 +1,36 @@
-"""Pallas TPU paged-attention decode kernel (flash-decoding over the block
+"""Pallas TPU paged-attention decode kernels (flash-decoding over the block
 table).
 
 In-tree replacement for the PagedAttention CUDA kernel vLLM brings to the
-reference deployment (helm/templates/qwen-deployment.yaml).  One grid step
-processes one (sequence, kv-head, page) triple: the page's K/V slab is
-DMA'd into VMEM by the Pallas pipeline (double-buffered automatically via
-the BlockSpec index map, which reads the *scalar-prefetched* block table),
-scores for the kv-head's query group hit the MXU, and an online-softmax
-accumulator in VMEM scratch carries (m, l, acc) across the page walk.
-Nothing is ever materialized in HBM — the gather-based reference path
+reference deployment (helm/templates/qwen-deployment.yaml).  Nothing is ever
+materialized in HBM — the gather-based reference path
 (ops/paged_attention.py) exists only as the correctness oracle.
+
+Two kernels:
+
+* ``paged_attention_decode`` — one decode step outside a burst (no cell
+  runs it).  A dense grid (sequence, kv-head, page): the page's K/V slab is
+  DMA'd into VMEM by the Pallas pipeline through a BlockSpec index map that
+  reads the *scalar-prefetched* block table, and an online-softmax
+  accumulator in VMEM scratch carries (m, l, acc) across the page walk.
+
+* ``paged_attention_decode_staged`` — the decode burst's kernel
+  (serving/decode_burst.py), over [pool prefix | the burst's staged tail].
+  Its work follows the pages that live rows hold, not the table: the pools
+  stay in HBM (``memory_space=pl.ANY``) and the kernel copies a row's own
+  ``ceil(pool_len / page_size)`` pages itself, in waves of N pages with the
+  next wave's DMAs in flight (the row's own, or the next live row's first)
+  while the current one is folded into the softmax as one product N pages
+  wide.  A dead row (pool length 0) is one small product over the staged
+  tail: no page, no DMA, no grid step of its own (a grid step takes
+  ``ROWS_PER_STEP`` row slots).  N is ``_wave_pages``: what fits
+  ``WAVE_VMEM_BYTES`` given the kv heads, the page, the head and the pool's
+  dtype — 4 pages for Qwen2-7B's bfloat16 pools, 8 for 1.5B's, 16 for one
+  kv head of a tp shard.  One layer's call at Qwen2-7B widths, 32 row slots
+  and tables of 16 pages, on a v5e (PERF.md, Findings, PR 28): 24 us at 7
+  live rows of ~350 tokens, 77 us at 13 of ~1.5k, 188 us with every row at
+  2,048 (87% of the HBM peak); the dense (row, page) grid this replaced took
+  102 / 176 / 338 us.
 
 Contract matches paged_attention_ref for the decode shape S == 1:
   q            [B, 1, n_q, hd]
@@ -159,78 +180,111 @@ def paged_attention_decode(
     return out.reshape(b, 1, n_q, hd)
 
 
-def _decode_staged_kernel(
+WAVE_VMEM_BYTES = 4 * 1024 * 1024  # what one wave of the burst kernel may hold in VMEM
+ROWS_PER_STEP = 8  # row slots one grid step of the burst kernel takes
+
+
+def _wave_pages(n_kv: int, page_size: int, hd: int, itemsize: int, max_pages: int) -> int:
+    """Pages the burst kernel reads and folds at a time: the largest power
+    of two whose K and V tiles (every kv head; two DMA slots in the pool's
+    dtype plus the float32 copies the products run on) fit
+    ``WAVE_VMEM_BYTES``, and no more than a row's table holds."""
+    per_page = n_kv * page_size * hd * (2 * 2 * itemsize + 2 * 4)
+    fit = max(1, WAVE_VMEM_BYTES // per_page)
+    return min(1 << (fit.bit_length() - 1), max_pages)
+
+
+def _burst_kernel(
     *refs,
     page_size: int,
     scale: float,
+    wave: int,
     layered: bool = False,
     kv_quant: bool = False,
 ):
-    """Decode-burst attention: online softmax over [pool-prefix pages |
-    staged tail].  Grid (B, max_pages + 1): the first max_pages steps walk
-    the row's block table for ALL kv heads at once (skipping pages past
-    ``pool_lens``); the final step folds in the burst's staged K/V
-    (positions < ``staged_len``) and writes the normalized output.  One
-    grid step per (row, page) — not per (row, head, page) — keeps the
-    kernel's fixed per-step cost off the decode critical path.
+    """Decode-burst attention: online softmax over [the pages the row holds
+    | staged tail].  Grid (B / R,): a step takes R row slots, one after the
+    other.  A row walks its own ``ceil(pool_len / page_size)`` pages in waves
+    of ``wave`` pages, ALL kv heads at once: each page is one DMA from the
+    pool in HBM into a VMEM slot, a wave is one [n_kv, group, wave *
+    page_size] product, and while a wave is folded in the next one's DMAs are
+    in flight: the row's own next wave, or after its last the first wave of
+    the next live row.  The row then folds in the burst's staged K/V
+    (positions < ``staged_len``) and writes its normalized output, so a dead
+    row (pool length 0) costs that one small product: no page, no DMA, no
+    grid step.
 
     Refs, in order: scalar prefetch [block_tables (B, max_pages) SMEM,
     pool_lens (B), staged_len (1), + layer (1) when ``layered``, + k/v
-    per-PAGE scales (n_kv, P) f32 when ``kv_quant``], blocks
-    [q (1, n_kv, group, hd) VMEM, k/v (one pool page, every kv head —
-    leading extra 1 for the layer axis when ``layered``), staged k/v
-    (1, n_kv, n_steps, hd)], out (1, n_kv, group, hd), scratch [m, l
-    (n_kv, group, 128) f32, acc (n_kv, group, hd) f32].  ``kv_quant``:
-    pool tiles are int8; each page's scale is read per kv head from the
-    SMEM scalar channel (zero extra operand DMAs — per-token scale tiles
-    measured 5-18x slower, r04) and dequant happens here in VMEM, right
-    before the dots."""
+    per-PAGE scales (n_kv, P) f32 when ``kv_quant``], q (R, n_kv, group,
+    hd) VMEM, the k and v pools WHOLE in HBM ([L,] n_kv, P, page_size, hd),
+    staged k/v (R, n_kv, n_steps, hd), out (R, n_kv, group, hd), scratch
+    [k and v slots (2, n_kv, wave * page_size, hd) in the pool's dtype, DMA
+    semaphores (2, 2), the slot the next first wave was sent to (1,) SMEM,
+    m, l (n_kv, group, 128) f32, acc (n_kv, group, hd) f32].  ``kv_quant``:
+    pool pages are int8; each page's scale is read per kv head from the SMEM
+    scalar channel (zero extra DMAs: per-token scale tiles measured 5-18x
+    slower, r04) and dequant happens here in VMEM, right before the dots."""
     n_scalars = (4 if layered else 3) + (2 if kv_quant else 0)
     scalar_refs = refs[:n_scalars]
     block_tables_ref, pool_lens_ref, staged_len_ref = scalar_refs[:3]
-    blocks = refs[n_scalars : n_scalars + 5]
-    q_ref, k_ref, v_ref, sk_ref, sv_ref = blocks
-    out_ref, m_ref, l_ref, acc_ref = refs[n_scalars + 5 :]
+    q_ref, k_hbm, v_hbm, sk_ref, sv_ref, out_ref = refs[n_scalars : n_scalars + 6]
+    k_buf, v_buf, sems, slot_ref, m_ref, l_ref, acc_ref = refs[n_scalars + 6 :]
     if layered:
-        raw_k = lambda: k_ref[0, :, 0]  # [n_kv, page_size, hd]
-        raw_v = lambda: v_ref[0, :, 0]
-    else:
-        raw_k = lambda: k_ref[:, 0]
-        raw_v = lambda: v_ref[:, 0]
-    bi = pl.program_id(0)
-    pi = pl.program_id(1)
-    num_pi = pl.num_programs(1)
-    if kv_quant:
-        # per-PAGE scales ride the SCALAR-PREFETCH channel ([n_kv, P] f32
-        # in SMEM, already layer-sliced by the wrapper) and are read as
-        # per-head scalars — the r03 per-token scale TILES added two tiny
-        # operand DMAs to every (row, page) grid step and measured 5-18x
-        # slower than bf16 pools; int8 pages with SMEM scales run at bf16
-        # speed + halved KV HBM (r04 isolation)
-        ks_ref, vs_ref = scalar_refs[-2:]
-        n_kv_heads = k_ref.shape[1] if layered else k_ref.shape[0]
-        page = block_tables_ref[bi, jnp.minimum(pi, num_pi - 2)]
+        k_hbm, v_hbm = k_hbm.at[scalar_refs[3][0]], v_hbm.at[scalar_refs[3][0]]
+    ks_ref, vs_ref = scalar_refs[-2:] if kv_quant else (None, None)
+    n_kv_heads = k_buf.shape[1]
+    rows, max_pages = block_tables_ref.shape
+    block_rows = q_ref.shape[0]
 
-        def dequant(raw, ref):
-            # per-head scalar-from-SMEM x [ps, hd] plane, restacked on the
-            # leading axis (a [n_kv] vector reshaped to [n_kv,1,1] is an
-            # unsupported Mosaic shape cast; scalar broadcasts are free)
-            x = raw().astype(jnp.float32)
-            return jnp.stack([x[h] * ref[h, page] for h in range(n_kv_heads)])
+    def pages_of(row):
+        return (pool_lens_ref[row] + page_size - 1) // page_size
 
-        k_page = lambda: dequant(raw_k, ks_ref)
-        v_page = lambda: dequant(raw_v, vs_ref)
-    else:
-        k_page, v_page = raw_k, raw_v
+    def page_at(row, w, j):
+        return block_tables_ref[row, jnp.minimum(w * wave + j, max_pages - 1)]
 
-    @pl.when(pi == 0)
-    def _():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def page_dmas(row, w, slot, go):
+        """``go`` (start or wait) on the DMA of every page ``row`` holds in
+        its wave ``w``: page -> rows [j * page_size, (j + 1) * page_size) of
+        slot ``slot``, every kv head in one strided copy."""
+        held = pages_of(row)
+        for j in range(wave):
+            @pl.when(w * wave + j < held)
+            def _():
+                page, at = page_at(row, w, j), pl.ds(j * page_size, page_size)
+                for which, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                    go(pltpu.make_async_copy(
+                        hbm.at[:, page], buf.at[slot, :, at], sems.at[which, slot]))
 
-    total = pool_lens_ref[bi]
-    page_start = pi * page_size
+    def start_first_wave(after, slot):
+        """Start the first wave of the next live row at or after ``after``,
+        if there is one, so that it lands while the rows before it work."""
+        row = jax.lax.while_loop(
+            lambda r: (r < rows) & (pool_lens_ref[jnp.minimum(r, rows - 1)] == 0),
+            lambda r: r + 1, after)
+
+        @pl.when(row < rows)
+        def _():
+            page_dmas(row, 0, slot, lambda dma: dma.start())
+
+    def tile(buf, scales_ref, row, w, slot):
+        """Wave ``w`` of ``row`` as float32 [n_kv, wave * page_size, hd]."""
+        x = buf[slot].astype(jnp.float32)
+        if not kv_quant:
+            return x
+        # per-PAGE scales ride the SCALAR-PREFETCH channel ([n_kv, P] f32 in
+        # SMEM, already layer-sliced by the wrapper) and are read as per-head
+        # scalars x [ps, hd] planes, restacked (a [n_kv] vector reshaped to
+        # [n_kv,1,1] is an unsupported Mosaic shape cast; scalar broadcasts
+        # are free): int8 pages with SMEM scales run at bf16 speed + halved
+        # KV HBM (r04 isolation)
+        return jnp.stack([
+            jnp.concatenate([
+                x[h, j * page_size : (j + 1) * page_size] * scales_ref[h, page_at(row, w, j)]
+                for j in range(wave)
+            ], axis=0)
+            for h in range(n_kv_heads)
+        ])
 
     # batched-over-heads dot: [n_kv, g, hd] x [n_kv, T, hd] -> [n_kv, g, T]
     bdot = lambda a, b: jax.lax.dot_general(
@@ -253,28 +307,57 @@ def _decode_staged_kernel(
         acc_ref[...] = acc_ref[...] * alpha + pdot(p, vals)
         m_ref[:, :, :1] = m_new
 
-    @pl.when((pi < num_pi - 1) & (page_start < total))
-    def _():
-        q = q_ref[0].astype(jnp.float32)  # [n_kv, group, hd]
-        k = k_page().astype(jnp.float32)  # [n_kv, page_size, hd]
-        s = bdot(q, k) * scale  # [n_kv, group, page_size]
-        kv_pos = page_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(kv_pos < total, s, NEG_INF)
-        accumulate(s, v_page().astype(jnp.float32))
+    # read out here: the interpreter has no program_id inside a loop's body
+    first_row = pl.program_id(0) * block_rows
 
-    @pl.when(pi == num_pi - 1)
+    @pl.when(first_row == 0)
     def _():
-        q = q_ref[0].astype(jnp.float32)
-        sk = sk_ref[0].astype(jnp.float32)  # [n_kv, n_steps, hd]
-        s = bdot(q, sk) * scale  # [n_kv, group, n_steps]
+        # a row's last wave fills only the pages the row holds; what the
+        # rest of the V slot holds meets a weight of exactly 0 and must be
+        # finite for that (K's leftovers are masked after the product)
+        v_buf[...] = jnp.zeros_like(v_buf)
+        slot_ref[0] = 0
+        start_first_wave(0, 0)
+
+    def one_row(r, carry):
+        bi = first_row + r
+        total = pool_lens_ref[bi]
+        n_waves = (pages_of(bi) + wave - 1) // wave
+        first_slot = slot_ref[0]  # where this row's first wave was sent
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        q = q_ref[r].astype(jnp.float32)  # [n_kv, group, hd]
+
+        def fold_wave(w, carry):
+            slot = (first_slot + w) % 2
+
+            @pl.when(w + 1 < n_waves)
+            def _():
+                page_dmas(bi, w + 1, 1 - slot, lambda dma: dma.start())
+
+            @pl.when(w + 1 == n_waves)
+            def _():
+                start_first_wave(bi + 1, 1 - slot)
+
+            page_dmas(bi, w, slot, lambda dma: dma.wait())
+            s = bdot(q, tile(k_buf, ks_ref, bi, w, slot)) * scale  # [n_kv, group, wave * ps]
+            kv_pos = w * wave * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+            accumulate(jnp.where(kv_pos < total, s, NEG_INF), tile(v_buf, vs_ref, bi, w, slot))
+            return carry
+
+        jax.lax.fori_loop(0, n_waves, fold_wave, 0)
+        slot_ref[0] = (first_slot + n_waves) % 2
+
+        s = bdot(q, sk_ref[r].astype(jnp.float32)) * scale  # [n_kv, group, n_steps]
         idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(idx < staged_len_ref[0], s, NEG_INF)
-        accumulate(s, sv_ref[0].astype(jnp.float32))
-
-        # staged_len >= 1 always, so l > 0 for every row incl. padding rows
+        accumulate(jnp.where(idx < staged_len_ref[0], s, NEG_INF), sv_ref[r].astype(jnp.float32))
+        # staged_len >= 1 always, so l > 0 for every row incl. dead ones
         l = l_ref[:, :, :1]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        out_ref[0] = (acc_ref[...] / safe_l).astype(out_ref.dtype)
+        out_ref[r] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(out_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, block_rows, one_row, 0)
 
 
 def paged_attention_decode_staged(
@@ -294,62 +377,33 @@ def paged_attention_decode_staged(
     """Burst-decode attention over [pool prefix | staged tail] without ever
     materializing the gathered KV in HBM (replaces gather_kv+dense in
     serving/decode_burst.py).  Not jitted — always called inside the burst's
-    compiled program.
+    compiled program.  Its cost follows the pages that live rows hold
+    (``_burst_kernel``), not the block table's size.
 
     Rank-5 pools + ``layer``: the burst's layer loop passes the WHOLE
     [L, n_kv, P, ps, hd] pool and the current layer index as a prefetched
-    scalar — the BlockSpec index map addresses (layer, head, page)
-    directly, so no per-layer pool slice is ever materialized.  Device
+    scalar; the pools stay in HBM and the kernel's DMAs address (layer,
+    page) directly, so no per-layer pool slice is ever materialized.  Device
     profiling showed the sliced form costing ~0.5 ms/step at 0.5B/bs8
     (2 x 4 MB x 24 layers of dynamic-slice copy traffic per decode step).
 
-    ``k_scales``/``v_scales`` mark int8 (kv_quant) pools: page tiles
-    arrive int8 and dequantize in VMEM right before the dots with their
-    per-PAGE scale read from the scalar-prefetch SMEM channel — KV HBM
-    reads halve at zero extra operand DMAs (per-token scale tiles
-    measured 5-18x slower, r04); the staged tail stays full precision."""
+    ``k_scales``/``v_scales`` mark int8 (kv_quant) pools: pages arrive int8
+    and dequantize in VMEM right before the dots with their per-PAGE scale
+    read from the scalar-prefetch SMEM channel — KV HBM reads halve at zero
+    extra DMAs (per-token scale tiles measured 5-18x slower, r04); the
+    staged tail stays full precision."""
     b, s, n_q, hd = q.shape
     assert s == 1, "staged kernel is the decode path (S == 1)"
     layered = k_pages.ndim == 5
     kv_quant = k_scales is not None
     if layered:
         assert layer is not None, "rank-5 pools need the layer index"
-        n_kv, num_pages, page_size, _ = k_pages.shape[1:]
-    else:
-        n_kv, num_pages, page_size, _ = k_pages.shape
+    n_kv, _, page_size, _ = k_pages.shape[-4:]
     group = n_q // n_kv
-    max_pages = block_tables.shape[1]
-    scale = 1.0 / (hd ** 0.5)
+    n_steps = staged_k.shape[2]
+    wave = _wave_pages(n_kv, page_size, hd, k_pages.dtype.itemsize, block_tables.shape[1])
     q_r = q.reshape(b, n_kv, group, hd)
 
-    grid = (b, max_pages + 1)
-
-    def q_map(bi, pi, *refs):
-        return (bi, 0, 0, 0)
-
-    def clamp_page(bi, pi, bt, pool):
-        # Clamp the walk to allocated pages; the staged grid step and pages
-        # past the row's prefix skip compute, so any valid page id works.
-        pp = jnp.minimum(pi, max_pages - 1)
-        return jax.lax.select(
-            (pi < max_pages) & (pi * page_size < pool[bi]), bt[bi, pp], 0
-        )
-
-    if layered:
-        def kv_map(bi, pi, bt, pool, sl, *rest):
-            return (rest[0][0], 0, clamp_page(bi, pi, bt, pool), 0, 0)
-
-        kv_block = (1, n_kv, 1, page_size, hd)
-    else:
-        def kv_map(bi, pi, bt, pool, sl, *rest):
-            return (0, clamp_page(bi, pi, bt, pool), 0, 0)
-
-        kv_block = (n_kv, 1, page_size, hd)
-
-    def staged_map(bi, pi, *refs):
-        return (bi, 0, 0, 0)
-
-    n_steps = staged_k.shape[2]
     scalars = [
         block_tables.astype(jnp.int32),
         pool_lens.astype(jnp.int32),
@@ -359,28 +413,41 @@ def paged_attention_decode_staged(
         scalars.append(jnp.reshape(layer, (1,)).astype(jnp.int32))
     if kv_quant:
         # per-page scales [n_kv, P] join the SCALAR-PREFETCH channel (SMEM,
-        # like the block tables): zero extra per-grid-step operand DMAs.
-        # Layer-sliced here — a [n_kv, P] f32 slice is ~KBs, not a pool copy
+        # like the block tables): zero extra DMAs.  Layer-sliced here — a
+        # [n_kv, P] f32 slice is ~KBs, not a pool copy
         ks, vs = k_scales, v_scales
         if layered:
             li = jnp.reshape(layer, ()).astype(jnp.int32)
             ks = jax.lax.dynamic_index_in_dim(ks, li, 0, keepdims=False)
             vs = jax.lax.dynamic_index_in_dim(vs, li, 0, keepdims=False)
         scalars += [ks.astype(jnp.float32), vs.astype(jnp.float32)]
-    in_specs = [
-        pl.BlockSpec((1, n_kv, group, hd), q_map),
-        pl.BlockSpec(kv_block, kv_map),
-        pl.BlockSpec(kv_block, kv_map),
-        pl.BlockSpec((1, n_kv, n_steps, hd), staged_map),
-        pl.BlockSpec((1, n_kv, n_steps, hd), staged_map),
-    ]
-    operands = [q_r, k_pages, v_pages, staged_k, staged_v]
+
+    # rows a grid step takes, the largest divisor of B up to ROWS_PER_STEP: a
+    # step's own cost and its three small operand DMAs are paid once for them,
+    # dead rows included (26.1 -> 24.2 us a call at 7 live rows of 32; PR 28)
+    block_rows = next(r for r in range(min(b, ROWS_PER_STEP), 0, -1) if b % r == 0)
+
+    def row_map(gi, *refs):
+        return (gi, 0, 0, 0)
+
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    slot = pltpu.VMEM((2, n_kv, wave * page_size, hd), k_pages.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, n_kv, group, hd), q_map),
+        grid=(b // block_rows,),
+        in_specs=[
+            pl.BlockSpec((block_rows, n_kv, group, hd), row_map),
+            in_hbm,
+            in_hbm,
+            pl.BlockSpec((block_rows, n_kv, n_steps, hd), row_map),
+            pl.BlockSpec((block_rows, n_kv, n_steps, hd), row_map),
+        ],
+        out_specs=pl.BlockSpec((block_rows, n_kv, group, hd), row_map),
         scratch_shapes=[
+            slot,
+            slot,
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
             pltpu.VMEM((n_kv, group, 128), jnp.float32),
             pltpu.VMEM((n_kv, group, 128), jnp.float32),
             pltpu.VMEM((n_kv, group, hd), jnp.float32),
@@ -388,18 +455,18 @@ def paged_attention_decode_staged(
     )
 
     kernel = functools.partial(
-        _decode_staged_kernel, page_size=page_size, scale=scale,
+        _burst_kernel, page_size=page_size, scale=1.0 / (hd ** 0.5), wave=wave,
         layered=layered, kv_quant=kv_quant,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_kv, group, hd), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
+        # rows in order on one core: the first step zeroes the V slots, and
+        # each live row starts the first DMAs of the next
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(*scalars, *operands)
+    )(*scalars, q_r, k_pages, v_pages, staged_k, staged_v)
 
     return out.reshape(b, 1, n_q, hd)
 

@@ -27,9 +27,17 @@ notwithstanding (PERF.md, Findings, PR 25; tests/test_tpu_compile.py holds
 the compiled program to "no copy of a pool").
 
 Attention inside the burst has two implementations (``use_pallas``):
-  - the Pallas flash-decode kernel extended with a staged-tail operand
-    (ops/pallas_paged.py::paged_attention_decode_staged) — walks the block
-    table page by page in VMEM, nothing materialized in HBM.  The TPU path.
+  - the Pallas flash-decode kernel with a staged-tail operand
+    (ops/pallas_paged.py::paged_attention_decode_staged) — nothing
+    materialized in HBM.  The TPU path.  It walks only the pages live rows
+    hold: a row's own ceil(start_len / page_size) pages, copied from the
+    pools (which stay whole in HBM) several pages a wave — as many as a
+    fixed VMEM budget holds for the pool's heads, page and dtype, 4 at
+    Qwen2-7B widths — with the next wave in flight while this one is folded
+    in; a row slot with nothing in the pool costs one small product over
+    the staged tail.  ``block_tables`` and ``start_lens`` do not change
+    inside a burst, so every one of its n_steps x num_layers calls walks the
+    same pages.
   - gather_kv + dense attention over the materialized copy — the CPU test
     path and the kernel's correctness oracle.
 
@@ -156,6 +164,10 @@ def decode_burst(
     num_pages, page_size = k_pages.shape[2], k_pages.shape[3]
     rows = jnp.arange(b)
     start_lens = seq_lens  # pool validity is frozen for the whole burst
+    # what the kernel walks: a row that sits the whole burst out (mid-prefill,
+    # finished and still in the chained lens, at its limit) has its result
+    # thrown away, so it is handed over as holding nothing
+    walk_lens = jnp.where(active & (seq_lens < row_limits), start_lens, 0)
     quant = k_scales is not None
     # int4 pools (uint8, kv_cache.pack_int4): the staged kernel reads int8
     # pages natively but has no nibble path — bursts over int4 pages take
@@ -222,7 +234,7 @@ def decode_burst(
                     # and BENCHMARK.json's paged_attn_hbm_frac finds it by
                     # the old name (PERF.md, Findings, PR 24)
                     out = kernel(
-                        q, kp, vp, block_tables, start_lens,
+                        q, kp, vp, block_tables, walk_lens,
                         jax.lax.dynamic_index_in_dim(sk2, li, 0, keepdims=False),
                         jax.lax.dynamic_index_in_dim(sv2, li, 0, keepdims=False),
                         jnp.reshape(step + 1, (1,)),

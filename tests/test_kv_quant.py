@@ -144,14 +144,29 @@ def test_kv_quant_composes_with_sp_ring_prefill(tiny):
     assert len(res.output_tokens) == 6
 
 
-def test_staged_kernel_int8_matches_dequant_reference(tiny):
+@pytest.mark.parametrize("lens,row_pages,wave", [
+    pytest.param([9, 5], 3, None, id="one-wave"),
+    # the page walk's own cases (tests/test_pallas_paged.py), pages of 4
+    pytest.param([0, 30, 0, 0, 3, 0], 8, 2, id="dead-rows-between-live"),
+    pytest.param([1, 3, 4, 5], 8, 2, id="one-token-and-around-a-page"),
+    pytest.param([7, 8, 9, 0], 8, 2, id="around-a-wave"),
+    pytest.param([16, 17, 31, 32], 8, 2, id="waves-plus-one-to-the-full-table"),
+    pytest.param([0, 0, 0, 13, 0, 0], 8, 2, id="one-live-row-of-many"),
+    pytest.param([32, 32, 32], 8, 4, id="every-row-full"),
+    pytest.param([1, 15, 16, 17, 31, 32, 0], 8, 4, id="around-waves-of-4"),
+])
+def test_staged_kernel_int8_matches_dequant_reference(tiny, monkeypatch, lens, row_pages, wave):
     """The Pallas staged kernel's in-VMEM dequant (interpret mode) must
     match attention over the explicitly dequantized pool."""
     from githubrepostorag_tpu.ops.attention import dense_attention
     from githubrepostorag_tpu.ops.pallas_paged import paged_attention_decode_staged
+    from tests.test_pallas_paged import set_wave
 
     rng = np.random.default_rng(1)
-    L, B, n_kv, group, hd, P, ps, n_steps = 3, 2, 2, 2, 16, 8, 4, 4
+    L, B, n_kv, group, hd, ps, n_steps = 3, len(lens), 2, 2, 16, 4, 4
+    P = B * row_pages + 2
+    if wave:
+        set_wave(monkeypatch, wave, n_kv, ps, hd, itemsize=1)
     q = jnp.asarray(rng.normal(size=(B, 1, n_kv * group, hd)), dtype=jnp.float32)
     kf = rng.normal(size=(L, n_kv, P, ps, hd)).astype(np.float32)
     vf = rng.normal(size=(L, n_kv, P, ps, hd)).astype(np.float32)
@@ -162,8 +177,8 @@ def test_staged_kernel_int8_matches_dequant_reference(tiny):
 
     kq, ks = quant_per_page(kf)
     vq, vs = quant_per_page(vf)
-    bt = jnp.asarray(rng.permutation(P)[: B * 3].reshape(B, 3), dtype=jnp.int32)
-    pool_lens = jnp.asarray([9, 5], dtype=jnp.int32)
+    bt = jnp.asarray(rng.permutation(P)[: B * row_pages].reshape(B, row_pages), dtype=jnp.int32)
+    pool_lens = jnp.asarray(lens, dtype=jnp.int32)
     sk = jnp.asarray(rng.normal(size=(B, n_kv, n_steps, hd)), dtype=jnp.float32)
     sv = jnp.asarray(rng.normal(size=(B, n_kv, n_steps, hd)), dtype=jnp.float32)
     sl = jnp.asarray([2], dtype=jnp.int32)
@@ -186,7 +201,7 @@ def test_staged_kernel_int8_matches_dequant_reference(tiny):
         n_pool = int(pool_lens[b])
         valid = np.zeros((k_all.shape[1],), dtype=bool)
         valid[:n_pool] = True
-        valid[3 * ps : 3 * ps + int(sl[0])] = True
+        valid[row_pages * ps : row_pages * ps + int(sl[0])] = True
         out = dense_attention(
             q[b : b + 1],
             jnp.asarray(k_all.transpose(1, 0, 2))[None],

@@ -90,11 +90,15 @@ def test_engine_with_pallas_path_matches_hf():
 
 
 def _staged_case(seed, b, n_q, n_kv, hd, ps, num_pages, max_pages, pool_lens,
-                 n_steps, staged_len):
+                 n_steps, staged_len, layers=0):
+    """``layers`` > 0: rank-5 pools [layers, n_kv, P, ps, hd], every layer
+    drawn apart, so that a kernel reading another layer than it was told
+    cannot match."""
     rng = np.random.default_rng(seed)
     q = rng.normal(size=(b, 1, n_q, hd)).astype(np.float32)
-    k_pages = rng.normal(size=(n_kv, num_pages, ps, hd)).astype(np.float32)
-    v_pages = rng.normal(size=(n_kv, num_pages, ps, hd)).astype(np.float32)
+    lead = (layers,) if layers else ()
+    k_pages = rng.normal(size=(*lead, n_kv, num_pages, ps, hd)).astype(np.float32)
+    v_pages = rng.normal(size=(*lead, n_kv, num_pages, ps, hd)).astype(np.float32)
     staged_k = rng.normal(size=(b, n_kv, n_steps, hd)).astype(np.float32)
     staged_v = rng.normal(size=(b, n_kv, n_steps, hd)).astype(np.float32)
     perm = rng.permutation(num_pages)
@@ -130,18 +134,100 @@ def _staged_oracle(q, k_pages, v_pages, block_tables, pool_lens, staged_k,
     return dense_attention(q, k_all, v_all, causal=False, kv_valid=valid)
 
 
-@pytest.mark.parametrize("pool_lens,staged_len", [
-    ([50, 7, 0, 33], 3),   # ragged pools incl. empty, mid-burst
-    ([0, 0, 0, 0], 1),     # burst step 0 right after prefill-free start
-    ([64, 64, 64, 64], 8), # full pools, full staged tail
-])
-def test_staged_kernel_matches_oracle(pool_lens, staged_len):
+def set_wave(monkeypatch, pages, n_kv, ps, hd, itemsize=4):
+    """Make the burst kernel's waves ``pages`` pages wide at these sizes: the
+    width comes from a VMEM budget (ops/pallas_paged.py::_wave_pages), which
+    at test sizes would hold a whole table."""
+    from githubrepostorag_tpu.ops import pallas_paged
+
+    per_page = n_kv * ps * hd * (2 * 2 * itemsize + 2 * 4)
+    monkeypatch.setattr(pallas_paged, "WAVE_VMEM_BYTES", pages * per_page)
+    assert pallas_paged._wave_pages(n_kv, ps, hd, itemsize, 1 << 20) == pages
+
+
+def _walk(pool_lens, staged_len, wave=2, max_pages=8, num_pages=40, **kw):
+    """A case of the page walk: pages of 16, tables of ``max_pages``, waves
+    of ``wave`` pages (None: the budget's own, a whole table here)."""
+    return dict(pool_lens=pool_lens, staged_len=staged_len, wave=wave,
+                max_pages=max_pages, num_pages=num_pages, **kw)
+
+
+STAGED_CASES = [
+    # the dense-grid kernel's cases: tables of 4 pages, one wave holds them
+    pytest.param(_walk([50, 7, 0, 33], 3, None, 4, 32), id="ragged-incl-empty-mid-burst"),
+    pytest.param(_walk([0, 0, 0, 0], 1, None, 4, 32), id="no-pool-first-step"),
+    pytest.param(_walk([64, 64, 64, 64], 8, None, 4, 32), id="full-pools-full-tail"),
+    # what a walk over the rows' own pages can get wrong
+    pytest.param(_walk([0, 300, 0, 0, 17, 0], 3, 4, 20), id="dead-rows-between-live"),
+    pytest.param(_walk([1, 15, 16, 17], 2), id="one-token-and-around-a-page"),
+    pytest.param(_walk([31, 32, 33, 0], 5), id="around-a-wave"),
+    pytest.param(_walk([64, 65, 127, 128], 4), id="waves-plus-one-to-the-full-table"),
+    pytest.param(_walk([0, 0, 0, 0, 0, 77, 0, 0], 6), id="one-live-row-of-many"),
+    pytest.param(_walk([128] * 4, 7), id="every-row-full"),
+    pytest.param(_walk([128] * 4, 7, 8), id="every-row-full-one-wave"),
+    pytest.param(_walk([40, 0, 128, 9], 1), id="staged-len-1"),
+    pytest.param(_walk([40, 0, 128, 9], 8), id="staged-len-n-steps"),
+    pytest.param(_walk([0, 33, 128, 16], 3, 1), id="waves-of-one-page"),
+    pytest.param(_walk([1, 63, 64, 65, 127, 128, 0, 33], 3, 4), id="around-waves-of-4"),
+    pytest.param(_walk([0, 97, 0, 16, 17, 128], 8, 8), id="dead-rows-one-wave-a-table"),
+    pytest.param(_walk([50, 0, 97, 16], 3, n_q=7, n_kv=1), id="one-kv-head-tp-shard"),
+    pytest.param(_walk([0, 33, 128, 16], 3, 4, n_q=7, n_kv=1), id="one-kv-head-waves-of-4"),
+    pytest.param(_walk([50, 7, 0, 33], 3, None, 4, 32, layers=3), id="rank5-one-wave"),
+    pytest.param(_walk([0, 300, 0, 0, 17, 0], 2, 4, 20, layers=2), id="rank5-dead-rows-between-live"),
+    pytest.param(_walk([31, 32, 33, 128], 8, layers=3), id="rank5-around-a-wave"),
+]
+
+
+@pytest.mark.parametrize("case", STAGED_CASES)
+def test_staged_kernel_matches_oracle(monkeypatch, case):
     from githubrepostorag_tpu.ops.pallas_paged import paged_attention_decode_staged
 
-    args = _staged_case(0, 4, 8, 2, 64, 16, 32, 4, pool_lens, 8, staged_len)
-    ref = _staged_oracle(*args)
-    out = paged_attention_decode_staged(*args, interpret=True)
+    case = dict(case)
+    n_q, n_kv, layers = case.pop("n_q", 8), case.pop("n_kv", 2), case.pop("layers", 0)
+    hd, ps, wave = 64, 16, case.pop("wave")
+    if wave:
+        set_wave(monkeypatch, wave, n_kv, ps, hd)
+    args = _staged_case(0, len(case["pool_lens"]), n_q, n_kv, hd, ps, n_steps=8,
+                        layers=layers, **case)
+    q, k_pages, v_pages, *rest = args
+    layer = layers - 2 if layers else None  # neither the first nor the last
+    one = (lambda pool: pool[layer]) if layers else (lambda pool: pool)
+    ref = _staged_oracle(q, one(k_pages), one(v_pages), *rest)
+    out = paged_attention_decode_staged(
+        *args, layer=None if layer is None else jnp.asarray(layer), interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("kv_quant", [False, True], ids=["fp", "int8"])
+def test_staged_kernel_dmas_land_before_they_are_read(monkeypatch, kv_quant):
+    """Plain interpret mode copies at ``start()``; the TPU interpreter runs a
+    DMA only when it is waited for and watches every buffer for races, so a
+    wave folded before its wait, a slot refilled while it is still read (the
+    next row's first wave goes out during this row's last) or a wait that
+    matches no start shows here (the last as a hang, not a failure)."""
+    from jax.experimental.pallas import tpu as pltpu
+    from githubrepostorag_tpu.ops.pallas_paged import paged_attention_decode_staged
+
+    if not hasattr(pltpu, "InterpretParams"):
+        pytest.skip("this jax has no TPU interpreter")
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call as tpu_interpreter
+
+    n_kv, hd, ps = 2, 64, 16
+    set_wave(monkeypatch, 2, n_kv, ps, hd, itemsize=1 if kv_quant else 4)
+    args = _staged_case(5, 6, 8, n_kv, hd, ps, 40, 8, [0, 100, 0, 33, 128, 0], 8, 3)
+    ref = _staged_oracle(*args)
+    q, k_pages, v_pages, *rest = args
+    scales = ()
+    if kv_quant:  # whole numbers under one scale a page: int8 holds them exactly
+        k_pages, v_pages = (jnp.round(x * 20).astype(jnp.int8) for x in (k_pages, v_pages))
+        scales = (jnp.full(k_pages.shape[:2], 0.05, jnp.float32),) * 2
+        ref = _staged_oracle(q, k_pages.astype(jnp.float32) * 0.05,
+                             v_pages.astype(jnp.float32) * 0.05, *rest)
+    out = paged_attention_decode_staged(
+        q, k_pages, v_pages, *rest, None, *scales,
+        interpret=pltpu.InterpretParams(detect_races=True, dma_execution_mode="on_wait"))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5, rtol=1e-5)
+    assert not tpu_interpreter.races.races_found
 
 
 def test_staged_kernel_gqa_group_seven():

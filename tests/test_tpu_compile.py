@@ -75,12 +75,12 @@ def tp4(topo):
     return make_mesh(MeshPlan(tp=4), devices=topo.devices)
 
 
-def _staged(model: str, kv: str):
+def _staged(model: str, kv: str, max_pages: int = MAX_PAGES):
     n_q, n_kv, hd, _, _, b = MODELS[model]
     dtype, _ = KV[kv]
     pool = ((LAYERS, n_kv, PAGES, PAGE, hd), dtype)
     staged = ((b, n_kv, 8, hd), jnp.bfloat16)
-    args = [((b, 1, n_q, hd), jnp.bfloat16), pool, pool, ((b, MAX_PAGES), jnp.int32),
+    args = [((b, 1, n_q, hd), jnp.bfloat16), pool, pool, ((b, max_pages), jnp.int32),
             ((b,), jnp.int32), staged, staged, ((1,), jnp.int32), ((1,), jnp.int32)]
     if kv != "fp":
         args += [((LAYERS, n_kv, PAGES), jnp.float32)] * 2
@@ -129,6 +129,10 @@ CASES = [
            (f"packed-tq512-{model}", functools.partial(_packed, model)),
            (f"int4-matmul-m32-{model}", functools.partial(_int4, model))]
     )
+] + [
+    # the burst kernel at the benchmark cells' own table width (CELL_ROW_PAGES below)
+    pytest.param(functools.partial(_staged, "qwen2-7b", kv, 16), id=f"staged-{kv}-qwen2-7b-table16")
+    for kv in ("fp", "int8")
 ]
 
 
