@@ -1,7 +1,7 @@
 # Developer entrypoints (reference: Makefile — env create + per-component
-# pytest; here one package, one suite, plus native build / bench / deploy).
+# pytest; here one package, one suite, plus native build / deploy).
 
-.PHONY: all native test test-fast bench serve lint lint-diff lint-baseline image deploy clean
+.PHONY: all native test test-fast serve lint lint-diff lint-baseline image deploy clean
 
 all: native test
 
@@ -38,9 +38,6 @@ lint-diff:
 lint-baseline:
 	python -m tools.tpulint githubrepostorag_tpu tests \
 		--exclude tests/lint_fixtures --write-baseline tools/tpulint/baseline.json
-
-bench:
-	python bench.py
 
 serve:
 	python -m githubrepostorag_tpu.api --port 8080

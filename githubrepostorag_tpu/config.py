@@ -200,8 +200,7 @@ class Settings:
 
     # --- Observability ---
     # trace sampling rate [0, 1]; 0 disables root-span creation entirely
-    # (the span() fast path becomes a single contextvar read — bench.py
-    # asserts the overhead budget under this setting)
+    # (the span() fast path becomes a single contextvar read)
     trace_sample: float = field(default_factory=lambda: _env_float("TRACE_SAMPLE", 1.0))
     # flight-recorder ring-buffer bounds: O(traces * spans) memory, period
     trace_max_traces: int = field(default_factory=lambda: _env_int("TRACE_MAX_TRACES", 256))
@@ -368,10 +367,8 @@ class Settings:
     mesh_shape: str = field(default_factory=lambda: os.getenv("MESH_SHAPE", ""))  # e.g. "dp:2,tp:4"
     dtype: str = field(default_factory=lambda: os.getenv("MODEL_DTYPE", "bfloat16"))
     # page_size x num_pages = KV token capacity (default 32k slots).
-    # 128-token pages measured +11-29% conc64 THROUGHPUT over 64-token
-    # pages on 128-token prompts, kv_quant included (BENCH r05,
-    # scripts/probe_conc64_pagesize.py).  Two granularity tradeoffs ride
-    # the same knob: prefix caching shares WHOLE pages, so shared
+    # 128-token pages are what the benchmark's cells run (PERF.md
+    # section 4).  Two granularity tradeoffs ride the same knob: prefix caching shares WHOLE pages, so shared
     # prefixes shorter than one page stop caching; and with KV_QUANT=1 a
     # page's int8 scale is fixed by its first write, so up to
     # page_size-1 later appends clip against it (greedy still tracks
@@ -421,37 +418,24 @@ class Settings:
     sp_prefill_threshold_set: bool = field(
         default_factory=lambda: os.environ.get("SP_PREFILL_THRESHOLD") is not None
     )
-    # segment-packed ring prefill: pack every waiting eligible long prompt
-    # into ONE fixed-budget ring pass with per-token segment ids
-    # (serving/long_prefill.ring_prefill_packed); off = one sequence per
-    # ring pass (the longctx A/B baseline)
-    sp_ring_pack: bool = field(
-        default_factory=lambda: _env_bool("SP_RING_PACK", True)
-    )
     # ring-width buckets kept in the compiled ladder, widest down
     # (Engine.sp_ring_bucket_ladder); 0 = the full power-of-two ladder
     # from the threshold bucket to bucketed context_window
     sp_ring_buckets: int = field(
         default_factory=lambda: _env_int("SP_RING_BUCKETS", 0)
     )
-    # >0: n-gram speculative decoding with drafts of up to k tokens
-    # (serving/spec_decode.py) instead of pipelined decode bursts; a latency
-    # knob for quoting-heavy greedy decodes, 0 (bursts) is the throughput
-    # default
+    # >0: n-gram speculative decoding with drafts of up to k tokens,
+    # SPEC_ITERS draft/verify rounds a device dispatch for all-greedy
+    # batches (serving/spec_burst.py; any other batch decodes plainly)
+    # instead of pipelined decode bursts; a latency knob for quoting-heavy
+    # greedy decodes, 0 (bursts) is the throughput default
     spec_ngram_k: int = field(default_factory=lambda: _env_int("SPEC_NGRAM_K", 0))
-    # >0 with SPEC_NGRAM_K: fuse this many draft/verify iterations into one
-    # device program for all-greedy batches (serving/spec_burst.py) — the
-    # host-dispatched spec path pays a round trip per verify and measured
-    # 0.5x of fused bursts (BENCH r03/r04)
-    spec_burst_iters: int = field(
-        default_factory=lambda: _env_int("SPEC_BURST_ITERS", 0)
-    )
     # one compiled program per engine step (serving/fused_step.py): the
     # packed prefill wave and a MIXED spec/plain decode burst dispatch
     # together, so greedy rows keep their verify windows even when
-    # sampled rows share the batch.  Requires SPEC_NGRAM_K,
-    # SPEC_BURST_ITERS and PREFILL_TOKEN_BUDGET; incompatible with
-    # SPEC_DRAFT_MODEL and PREFILL_PRIORITY.
+    # sampled rows share the batch.  Requires SPEC_NGRAM_K and
+    # PREFILL_TOKEN_BUDGET; incompatible with SPEC_DRAFT_MODEL and
+    # PREFILL_PRIORITY.
     fused_step: bool = field(
         default_factory=lambda: _env_bool("FUSED_STEP", False)
     )
@@ -466,7 +450,8 @@ class Settings:
     # max draft length per round; the adaptive controller walks the
     # power-of-two ladder [1..SPEC_K] on EMA acceptance rate
     spec_k: int = field(default_factory=lambda: _env_int("SPEC_K", 4))
-    # fused draft/verify/accept rounds per device dispatch
+    # fused draft/verify/accept rounds per device dispatch (draft-model
+    # and n-gram speculation alike)
     spec_iters: int = field(default_factory=lambda: _env_int("SPEC_ITERS", 4))
     # a request whose EMA acceptance rate falls below this floor drops to
     # plain decode_burst for the rest of its life (sticky fallback)

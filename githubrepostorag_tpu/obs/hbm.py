@@ -16,8 +16,7 @@ attributes is a page the scheduler lost track of.
 
 Feeding is seam-cheap by construction — every hook is O(1) dict/float
 work under one small lock, and prometheus publishing is rate-limited to
-the ledger's flush cadence (obs/ledger.py _PUBLISH_S) so the observatory
-stays inside the bench's <=2% obs-overhead budget.  Expensive renders
+the ledger's flush cadence (obs/ledger.py _PUBLISH_S).  Expensive renders
 (free-run fragmentation histogram, lifetime percentiles) happen only in
 ``payload()``, i.e. when someone actually GETs /debug/hbm.
 

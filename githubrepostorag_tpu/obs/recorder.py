@@ -12,7 +12,7 @@ validates the same payloads against the committed golden schema, so the
 CI gate checks the real shape, not a copy.  ``phase_summary`` collapses
 a trace into per-phase seconds (queue/plan/retrieve/judge/rewrite/
 synthesize/prefill/decode) — the compact dict attached to each job's
-terminal SSE event and aggregated by bench.py into p50/p95 breakdowns.
+terminal SSE event (the benchmark reads `retrieve_ms_p50` from it).
 """
 
 from __future__ import annotations

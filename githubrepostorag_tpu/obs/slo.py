@@ -36,7 +36,7 @@ DEFAULT_CLASS = "interactive"
 
 # how often the state machine re-evaluates on the driver thread; transitions
 # need no more resolution than the shortest practical window and the driver
-# loop must stay cheap (bench.py's obs-overhead gate)
+# loop must stay cheap
 _REFRESH_S = 0.25
 
 

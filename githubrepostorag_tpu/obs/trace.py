@@ -19,8 +19,7 @@ spans are handed to the flight recorder (obs/recorder.py).
 Cost discipline: with no active scope — TRACE_SAMPLE=0, or simply
 nothing upstream opened a trace — ``span()`` is one contextvar read and
 yields a shared no-op singleton: no allocation, no lock, no recorder
-touch.  bench.py asserts the resulting overhead stays under 2 % of the
-concurrency scenarios.
+touch.
 """
 
 from __future__ import annotations

@@ -74,7 +74,6 @@ class LiveIndexApplier:
         self._ops_applied = 0
         self._compact_runs = 0
         self._reclaimed_rows = 0
-        self._publish_s = 0.0   # host seconds spent on gauge publishing
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
 
@@ -153,7 +152,6 @@ class LiveIndexApplier:
         return False
 
     def _publish(self, ops) -> None:
-        t0 = time.monotonic()
         appended = self.log.watermark()
         with self._lock:
             applied, per_table = self._applied, dict(self._table_applied)
@@ -174,14 +172,6 @@ class LiveIndexApplier:
             counts[key] = counts.get(key, 0) + 1
         for (table, kind), n in counts.items():
             INDEX_OPS_APPLIED.labels(table=table, kind=kind).inc(n)
-        with self._lock:
-            self._publish_s += time.monotonic() - t0
-
-    def publish_seconds(self) -> float:
-        """Cumulative host time spent publishing stream gauges — the
-        stream-apply share of the bench's <=2% observability budget."""
-        with self._lock:
-            return self._publish_s
 
     # ----------------------------------------------------------- compaction
 

@@ -140,10 +140,8 @@ async def serve(host: str, port: int) -> None:
             kv_migrate_burst=s.kv_migrate_burst,
             prefill_priority=s.prefill_priority,
             sp_prefill_threshold=sp_threshold,
-            sp_ring_pack=s.sp_ring_pack,
             sp_ring_buckets=s.sp_ring_buckets,
             spec_ngram_k=s.spec_ngram_k,
-            spec_burst_iters=s.spec_burst_iters,
             fused_step=s.fused_step,
             draft_params=draft_params,
             draft_cfg=draft_cfg,

@@ -205,9 +205,8 @@ class Engine:
         # dispatches at the smallest bucket covering its longest pending
         # chunk.  Short prompts (RAG chat queries are ~100-300 tokens vs
         # a 256-512 chunk) stop paying the full chunk width in prefill
-        # FLOPs — under simultaneous 64-stream arrival that padding was
-        # most of p50 TTFT (BENCH r04: prompt 128, chunk 256 -> half the
-        # 7B prefill wave computed on padding).  warmup() compiles every
+        # FLOPs (prompt 128 at chunk 256: half a prefill wave is computed
+        # on padding; not measured on the chip).  warmup() compiles every
         # (row bucket x width bucket) pair so live traffic stays on
         # warmed shapes.
         prefill_token_budget: int | None = None,  # token-budget PACKED
@@ -259,13 +258,6 @@ class Engine:
         # (admissions never stall running streams).
         sp_prefill_threshold: int | None = None,  # prompts this long prefill
         # sequence-parallel over the mesh's sp axis (serving/long_prefill.py)
-        sp_ring_pack: bool = True,  # segment-packed ring prefill: every
-        # waiting eligible long prompt that fits the ring token budget
-        # rides ONE fixed-budget [1, width] ring pass with per-token
-        # segment ids (serving/long_prefill.ring_prefill_packed) instead
-        # of one program per prompt — ring rotation cost amortizes over
-        # full sp shards.  False = the one-sequence-per-pass path (the
-        # longctx A/B baseline).
         sp_ring_buckets: int = 0,  # SP_RING_BUCKETS: number of ring-width
         # buckets kept in the compiled ladder, counted from the widest
         # down (0 = the full power-of-two ladder from the threshold
@@ -274,19 +266,16 @@ class Engine:
         # sp_ring_bucket_ladder() is the single source of truth warmup
         # and dispatch both read.
         spec_ngram_k: int = 0,  # >0: n-gram speculative decoding with drafts
-        # of up to k tokens (serving/spec_decode.py) instead of decode bursts
-        spec_burst_iters: int = 0,  # >0 (with spec_ngram_k>0): fuse this many
-        # draft->verify->accept iterations into ONE device program
-        # (serving/spec_burst.py) whenever every running row is plain
-        # greedy — removes the per-verify dispatch round trip that made
-        # host-dispatched spec decode a measured loss (BENCH r03/r04)
+        # of up to k tokens: ``spec_iters`` draft->verify->accept rounds in
+        # ONE device program (serving/spec_burst.py) whenever every running
+        # row is plain greedy; any other step decodes plainly
         fused_step: bool = False,  # FUSED_STEP: one compiled program per
         # engine step (serving/fused_step.py) — the packed prefill wave
         # and a MIXED spec/plain decode burst dispatch together, so
         # greedy rows keep their verify windows even when sampled rows
         # share the batch (the unfused all-greedy gate demotes such
         # batches to plain decode).  Requires spec_ngram_k > 0,
-        # spec_burst_iters > 0, prefill_token_budget set, no draft model
+        # prefill_token_budget set, no draft model
         # and no prefill_priority (a skipped decode step would orphan
         # the deferred prefill wave).
         draft_params: dict | None = None,  # DRAFT-MODEL speculation (the
@@ -479,7 +468,6 @@ class Engine:
         self.sp_prefill_threshold = sp_prefill_threshold
         self._sp = mesh.shape.get("sp", 1) if mesh is not None else 1
         self.sp_prefills = 0  # stats: ring-prefill passes dispatched
-        self.sp_ring_pack = sp_ring_pack
         self.sp_ring_bucket_count = max(0, sp_ring_buckets)
         # fixed segment-row count of the packed ring program: per-segment
         # arrays (logits_at, presence rows) always dispatch at this many
@@ -497,30 +485,18 @@ class Engine:
         self.sp_ring_padding = 0  # stats: unused ring-buffer slots
         if self._sp > 1 and sp_prefill_threshold is not None:
             logger.info(
-                "sp prefill: threshold=%d tokens over sp=%d (%s, ladder %s)",
-                sp_prefill_threshold, self._sp,
-                "segment-packed" if sp_ring_pack else "one sequence per pass",
-                self.sp_ring_bucket_ladder(),
+                "sp prefill: threshold=%d tokens over sp=%d (segment-packed, ladder %s)",
+                sp_prefill_threshold, self._sp, self.sp_ring_bucket_ladder(),
             )
         self.spec_ngram_k = spec_ngram_k
-        if spec_burst_iters > 0 and spec_ngram_k <= 0:
-            # fail fast on the inert combo:
-            # the fused burst only engages inside the spec_ngram_k gate
-            raise ValueError(
-                "spec_burst_iters requires spec_ngram_k > 0 "
-                "(SPEC_BURST_ITERS fuses the n-gram spec path; without "
-                "SPEC_NGRAM_K it would silently do nothing)"
-            )
-        self.spec_burst_iters = spec_burst_iters
         if fused_step:
             # fail fast on inert/unsafe combos rather than silently
             # falling back: the fused step IS the serving mode the
             # operator asked for
-            if spec_ngram_k <= 0 or spec_burst_iters <= 0:
+            if spec_ngram_k <= 0:
                 raise ValueError(
-                    "fused_step requires spec_ngram_k > 0 and "
-                    "spec_burst_iters > 0 (FUSED_STEP fuses the n-gram "
-                    "spec burst with packed prefill)"
+                    "fused_step requires spec_ngram_k > 0 (FUSED_STEP fuses "
+                    "the n-gram spec burst with packed prefill)"
                 )
             if prefill_token_budget is None:
                 raise ValueError(
@@ -569,6 +545,8 @@ class Engine:
         self._dk_pages = self._dv_pages = None
         self._force_plain = False  # warmup hook: route through _decode_step
         self._spec_k_ladder: list[int] = []
+        if (self._draft_enabled or spec_ngram_k > 0) and spec_iters < 1:
+            raise ValueError("spec_iters must be >= 1")
         if self._draft_enabled:
             if draft_cfg.vocab_size != cfg.vocab_size:
                 # accept/verify compares token IDs across the two models —
@@ -578,8 +556,8 @@ class Engine:
                     f"draft vocab {draft_cfg.vocab_size} != target vocab "
                     f"{cfg.vocab_size}; draft and target must share a tokenizer"
                 )
-            if spec_k < 1 or spec_iters < 1:
-                raise ValueError("spec_k and spec_iters must be >= 1")
+            if spec_k < 1:
+                raise ValueError("spec_k must be >= 1")
             if mesh is not None:
                 # the draft is small: replicate rather than shard (its
                 # head counts need not divide tp, and replicated weights
@@ -840,8 +818,8 @@ class Engine:
     def is_admitting(self) -> bool:
         """True while a prompt wave is still being admitted — requests are
         queued or mid-prefill.  Drives prefill-priority scheduling and lets
-        callers (bench phase attribution) classify the next step without
-        reaching into engine privates."""
+        callers classify the next step without reaching into engine
+        privates."""
         return bool(self._waiting) or any(
             r.state == "prefilling" for r in self._row_req.values())
 
@@ -895,50 +873,42 @@ class Engine:
             running = []
         if running:
             t_run = time.monotonic()
-            spec_path = True  # flipped off on the plain-decode branches
-            if self._draft_enabled and not self._force_plain:
-                capable = [r for r in running if self._spec_capable(r)]
-                if capable and len(capable) == len(running):
-                    self._draft_spec_step(finished)
-                else:
-                    # mixed batch: one sampling/fallen row demotes the whole
-                    # dispatch to plain decode (the spec burst is greedy-only
-                    # and batch-shaped).  Rows that were individually capable
-                    # stay capable — the mix is per-step, not sticky.
-                    spec_path = False
-                    self._decode_step(finished)
-            elif self.spec_ngram_k > 0:
-                if self.fused_step_on:
-                    # one compiled program for the whole step: the packed
-                    # prefill wave _try_prefill deferred (if any) plus a
-                    # MIXED spec/plain burst — greedy rows keep their
-                    # verify windows even when sampled rows share the
-                    # batch (serving/fused_step.py)
-                    self._fused_step(finished)
-                else:
-                    all_greedy = all(
-                        r.sampling.temperature <= 0.0
-                        and r.sampling.repetition_penalty == 1.0
-                        for r in running
-                    )
-                    if self.spec_burst_iters > 0 and all_greedy:
-                        self._spec_burst_step(finished)
-                    else:
-                        self._spec_decode_step(finished)
-            else:
-                spec_path = False
-                self._decode_step(finished)
+            path = self._decode_path(running)
+            path(finished)
             dt = time.monotonic() - t_run
-            if spec_path:
-                self.spec_verify_seconds_total += dt
-            else:
+            if path == self._decode_step:
                 self.decode_seconds_total += dt
+            else:
+                self.spec_verify_seconds_total += dt
         if not self._row_req:
             # nothing left running: land any in-flight burst (its tokens
             # belong to already-finished rows) and recycle deferred pages
             self._drain_chain(finished)
         self._phase(None)
         return finished
+
+    def _decode_path(self, running: list[_Request]):
+        """The decode program this step's running rows take: the one place
+        the choice is made (``step`` calls what it returns; ``warmup`` and
+        the seconds booked to speculation read it).  Speculation is all or
+        nothing per step: one row that cannot ride the speculative burst
+        (sampled, penalised, fallen back) demotes the whole dispatch to
+        plain decode — the mix is per step, not sticky.  The fused step
+        alone takes mixed batches whole (serving/fused_step.py)."""
+        if self._force_plain:
+            return self._decode_step
+        if self.fused_step_on:
+            return self._fused_step
+        if self._draft_enabled:
+            # every row is asked: a demotion is sticky and counted
+            spec, ok = self._draft_spec_step, [self._spec_capable(r) for r in running]
+        elif self.spec_ngram_k > 0:
+            spec, ok = self._spec_burst_step, [
+                r.sampling.temperature <= 0.0 and r.sampling.repetition_penalty == 1.0
+                for r in running]
+        else:
+            return self._decode_step
+        return spec if all(ok) else self._decode_step
 
     def _reap_expired(self) -> None:
         """Mark past-deadline requests cancelled so the cancel/reap path
@@ -1284,7 +1254,7 @@ class Engine:
 
     def flush_kv_migrations(self) -> None:
         """Run migration boundaries until quiescent — every plannable
-        writeback dispatched AND landed.  Tests/bench use this for a
+        writeback dispatched AND landed.  Tests use this for a
         deterministic host-tier state between traffic phases; the serving
         loop never needs it (step() makes incremental progress)."""
         if not self._kv_tier_on:
@@ -1408,16 +1378,13 @@ class Engine:
         immediate host sync (best TTFT) instead of queueing on device into
         ``_pending_first`` for the next decode dispatch.  The single source
         of truth for all three prefill paths:
-          - n-gram spec modes are synchronous by design -> always commit;
-          - draft-model spec is synchronous too, but a plain-decode chain
-            may be in flight (mixed-batch/fallback steps pipeline) and its
-            stale device state must not race a fresh commit -> commit only
-            when no chain is live;
+          - speculation (n-gram or draft model) is synchronous by design,
+            but a plain-decode chain may be in flight (mixed-batch/fallback
+            steps pipeline) and its stale device state must not race a
+            fresh commit -> commit only when no chain is live;
           - plain decode additionally defers whenever other rows are
             running, so admissions never stall streams on a host sync."""
-        if self.spec_ngram_k > 0:
-            return True
-        if self._draft_enabled:
+        if self.spec_ngram_k > 0 or self._draft_enabled:
             return self._chain is None
         return self._chain is None and not others_running
 
@@ -1474,7 +1441,7 @@ class Engine:
                 break
             w *= 2
         out = list(dict.fromkeys(out))
-        if self.sp_ring_pack and self.sp_ring_bucket_count > 0:
+        if self.sp_ring_bucket_count > 0:
             out = out[-self.sp_ring_bucket_count:]
         return out
 
@@ -1660,16 +1627,13 @@ class Engine:
             return False
         long_reqs = [r for r in prefilling if self._sp_eligible(r) and r.prefill_pos == 0]
         if long_reqs:
-            self._phase("engine.prefill_batch")  # ring passes annotate inside
-            if self.sp_ring_pack:
-                # segment-packed: every waiting long prompt that fits the
-                # ring token budget shares ONE pass; the rest keep their
-                # rows and ride the next step's pass (step() re-enters
-                # _try_prefill every iteration, so nothing starves)
-                self._sp_prefill_packed(long_reqs, finished)
-            else:
-                for req in long_reqs:
-                    self._sp_prefill(req, finished)
+            self._phase("engine.prefill_batch")  # the ring pass annotates inside
+            # segment-packed: every waiting long prompt that fits the ring
+            # token budget shares ONE pass (a prompt alone is a pass of one
+            # segment); the rest keep their rows and ride the next step's
+            # pass (step() re-enters _try_prefill every iteration, so
+            # nothing starves)
+            self._sp_prefill_packed(long_reqs, finished)
             # served or not, ring-bound rows never fall through to the
             # chunked path below — a leftover would lose its from-position-0
             # ring contract the moment a chunk advanced its prefill_pos
@@ -2014,62 +1978,6 @@ class Engine:
         self._first_wave(tokens_d, [(packed[i][0], i) for i in done_idx],
                          others_running, finished)
 
-    def _sp_prefill(self, req: _Request, finished: list[GenerationResult]) -> None:
-        """Whole-prompt sequence-parallel prefill: one ring-attention program
-        over the sp axis computes every position's attention and commits all
-        prompt K/V to this row's pages (serving/long_prefill.py).  The first
-        token samples from the returned last-position logits and joins the
-        decode batch exactly like a chunked-prefill completion."""
-        from githubrepostorag_tpu.serving.long_prefill import ring_prefill
-
-        n = len(req.prompt)
-        width = _bucket(n, self.max_seq_len, minimum=self._sp)
-        width = -(-width // self._sp) * self._sp  # shard_map needs sp | width
-        ids = np.zeros((1, width), dtype=np.int32)
-        ids[0, :n] = req.prompt
-        pos = np.broadcast_to(np.arange(width, dtype=np.int32), (1, width))
-        slots = slot_mapping(
-            self._block_tables[req.row], 0, n, self.page_size, width
-        )[None]
-        self.step_dispatches_total += 1
-        with annotate("engine.sp_prefill"):
-            (logits, self._k_pages, self._v_pages,
-             self._k_scales, self._v_scales) = ring_prefill(
-                self.params, self.cfg,
-                jnp.asarray(ids), jnp.asarray(pos),
-                self._k_pages, self._v_pages,
-                jnp.asarray(slots), jnp.asarray([n - 1], dtype=jnp.int32),
-                self.mesh,
-                k_scales=self._k_scales, v_scales=self._v_scales,
-            )
-        self.sp_prefills += 1
-        self.prefill_tokens += n
-        req.prefill_pos = req.seq_len = n
-        self._seq_lens[req.row] = n
-
-        # whole prompt into the repetition-penalty presence mask (the same
-        # fixed [1, max_seq] program the cached-prefix path uses)
-        ids_full = np.zeros((1, self.max_seq_len), dtype=np.int32)
-        ids_full[0, :n] = req.prompt
-        row_d = jnp.asarray([req.row], dtype=jnp.int32)
-        self._presence = _mark_presence_chunks(
-            self._presence, row_d, jnp.asarray(ids_full),
-            jnp.asarray([n], dtype=jnp.int32), self.cfg.vocab_size,
-        )
-        # can't RESUME from the cache, but others can resume from us
-        self._register_full_pages(req)
-
-        self._push_sampling()
-        self._rng, key = jax.random.split(self._rng)
-        tokens_d = sample_tokens(
-            logits[:, 0], key,
-            self._temp_d[row_d], self._top_p_d[row_d], self._top_k_d[row_d],
-            self._rep_pen_d[row_d], self._presence[row_d],
-        )
-        self._presence = _mark_presence_rows(self._presence, row_d, tokens_d)
-        others_running = any(r.state == "running" for r in self._row_req.values())
-        self._first_wave(tokens_d, [(req, 0)], others_running, finished)
-
     def _sp_prefill_packed(
         self, reqs: list[_Request], finished: list[GenerationResult]
     ) -> list[_Request]:
@@ -2287,16 +2195,22 @@ class Engine:
             self._commit_burst(prev, finished)
 
     def _spec_burst_step(self, finished: list[GenerationResult]) -> None:
-        """``spec_burst_iters`` fused draft/verify/accept iterations in ONE
-        dispatch (serving/spec_burst.py) — the on-device form of
-        _spec_decode_step for all-plain-greedy batches.  One [B, iters,
-        k+1] token fetch per burst; stop/length bookkeeping happens here
-        on the packed tokens, like _commit_burst."""
+        """``spec_iters`` fused n-gram draft/verify/accept iterations in
+        ONE dispatch (serving/spec_burst.py), for all-plain-greedy batches.
+        One [B, iters, k+1] token fetch per burst; stop/length bookkeeping
+        happens here on the packed tokens, like _commit_burst."""
         from githubrepostorag_tpu.serving.spec_burst import spec_decode_burst
 
+        if self._chain is not None or self._pending_first:
+            # a plain-decode chain (a mixed batch's steps pipeline) is in
+            # flight: land it so the history/lens snapshot below sees every
+            # committed token
+            self._drain_chain(finished)
         self._phase("engine.burst_prepare")
         k = self.spec_ngram_k
         running = [r for r in self._row_req.values() if r.state == "running"]
+        if not running:
+            return
         rb = _bucket(len(running), self.max_num_seqs, minimum=1)
         h = self.max_seq_len
         hist = np.zeros((rb, h), dtype=np.int32)
@@ -2316,12 +2230,13 @@ class Engine:
 
         self.step_dispatches_total += 1
         with annotate("engine.spec_burst"):
+            # tpulint: disable=SHP002 -- warmup's greedy waves reach this through the bound method _decode_path returns, an edge the call graph does not follow; tests/test_spec_decode.py holds live traffic to zero compiles after warmup
             out = spec_decode_burst(
                 self.params, self.cfg,
                 jnp.asarray(hist), jnp.asarray(hlens), jnp.asarray(lens),
                 self._k_pages, self._v_pages,
                 jnp.asarray(bt), jnp.asarray(limits), jnp.asarray(active),
-                n_iters=self.spec_burst_iters, k=k,
+                n_iters=self.spec_iters, k=k,
                 use_pallas=self.use_pallas, int4_kernel=self._int4_kernel,
                 k_scales=self._k_scales, v_scales=self._v_scales,
             )
@@ -2355,7 +2270,7 @@ class Engine:
     def _fused_step(self, finished: list[GenerationResult]) -> None:
         """ONE compiled program for the whole step (serving/fused_step.py):
         the packed prefill wave _prefill_batch_packed deferred (if any)
-        runs as phase A, then ``spec_burst_iters`` MIXED decode iterations
+        runs as phase A, then ``spec_iters`` MIXED decode iterations
         — greedy rows draft/verify/accept exactly like _spec_burst_step
         (token-identical by construction), sampled rows draw one on-device
         token per iteration from the same forward instead of demoting the
@@ -2426,7 +2341,7 @@ class Engine:
                 self._temp_d[row_d], self._top_p_d[row_d],
                 self._top_k_d[row_d], self._rep_pen_d[row_d],
                 *pf,
-                n_iters=self.spec_burst_iters, k=k, tq=self.packed_chunk,
+                n_iters=self.spec_iters, k=k, tq=self.packed_chunk,
                 use_pallas=self.use_pallas, int4_kernel=self._int4_kernel,
                 filter_sampling=filter_sampling, has_prefill=has_prefill,
                 k_scales=self._k_scales, v_scales=self._v_scales,
@@ -2439,8 +2354,8 @@ class Engine:
              self._presence) = out
         if has_prefill:
             # deferred-wave bookkeeping: presence marks, advance, first
-            # tokens (spec modes commit first tokens synchronously —
-            # _commit_first_now is True whenever spec_ngram_k > 0)
+            # tokens (the fused step keeps no chain, so _commit_first_now
+            # holds and first tokens commit synchronously)
             self._finish_packed_wave(pf_wave, pf_logits, finished, True)
         self._phase("engine.commit_fetch")
         toks = np.asarray(toks_d)  # [rb, iters, k+1], -1 padded
@@ -2606,123 +2521,6 @@ class Engine:
                     rate if req.spec_accept_ema is None
                     else 0.3 * rate + 0.7 * req.spec_accept_ema
                 )
-
-    def _spec_decode_step(self, finished: list[GenerationResult]) -> None:
-        """One speculative iteration (serving/spec_decode.py): rows on plain
-        greedy (temperature 0, no repetition penalty) get an n-gram draft of
-        up to ``spec_ngram_k`` tokens; ONE paged forward over
-        [last_token, draft...] verifies every row, and each row commits its
-        longest model-agreed prefix plus the model's correction token — up
-        to k+1 tokens per dispatch.  Rows with sampling or penalties commit
-        exactly one token from the standard sampler (their drafts would
-        need evolving-presence rejection sampling for parity; not worth the
-        complexity), so token outputs are identical to the burst path for
-        EVERY config.  Synchronous by design — see the module docstring's
-        trade-off against pipelined bursts."""
-        from githubrepostorag_tpu.serving.spec_decode import ngram_propose
-
-        self._phase("engine.burst_prepare")
-        k = self.spec_ngram_k
-        width = k + 1
-        running = [r for r in self._row_req.values() if r.state == "running"]
-        rb = _bucket(len(running), self.max_num_seqs, minimum=1)
-        ids = np.zeros((rb, width), dtype=np.int32)
-        pos = np.zeros((rb, width), dtype=np.int32)
-        slots = np.full((rb, width), -1, dtype=np.int32)
-        bt = np.zeros((rb, self.max_pages_per_seq), dtype=np.int32)
-        cached = np.zeros((rb,), dtype=np.int32)
-        new_lens = np.zeros((rb,), dtype=np.int32)
-        drafts: list[list[int]] = []
-        plain_greedy: list[bool] = []
-        for i, req in enumerate(running):
-            sp = req.sampling
-            eligible = sp.temperature <= 0.0 and sp.repetition_penalty == 1.0
-            plain_greedy.append(eligible)
-            draft: list[int] = []
-            if eligible:
-                cap = min(
-                    k,
-                    int(self._row_limits[req.row]) - req.seq_len - 1,
-                    sp.max_tokens - len(req.output) - 1,
-                )
-                if cap > 0:
-                    draft = ngram_propose(req.prompt + req.output, cap)
-            drafts.append(draft)
-            self.spec_proposed += len(draft)
-            n_new = 1 + len(draft)
-            ids[i, 0] = req.output[-1] if req.output else req.prompt[-1]
-            ids[i, 1:n_new] = draft
-            pos[i] = np.arange(req.seq_len, req.seq_len + width)
-            slots[i] = slot_mapping(
-                self._block_tables[req.row], req.seq_len, n_new, self.page_size, width
-            )
-            bt[i] = self._block_tables[req.row]
-            cached[i] = req.seq_len
-            new_lens[i] = n_new
-
-        self.step_dispatches_total += 1
-        with annotate("engine.spec_decode"):
-            # full-width logits: [rb, k+1, V] — k is small, and verification
-            # needs every position
-            out = forward_paged(
-                self.params, self.cfg,
-                jnp.asarray(ids), jnp.asarray(pos),
-                self._k_pages, self._v_pages,
-                jnp.asarray(slots), jnp.asarray(bt),
-                jnp.asarray(cached), jnp.asarray(new_lens),
-                use_pallas=self.use_pallas,
-                k_scales=self._k_scales, v_scales=self._v_scales,
-                int4_kernel=self._int4_kernel, mesh=self.mesh,
-            )
-            if self.kv_quant:
-                (logits, self._k_pages, self._v_pages,
-                 self._k_scales, self._v_scales) = out
-            else:
-                logits, self._k_pages, self._v_pages = out
-
-        row_idx = np.zeros((rb,), dtype=np.int32)
-        row_idx[: len(running)] = [r.row for r in running]
-        row_d = jnp.asarray(row_idx)
-        self._phase("engine.commit_fetch")
-        greedy_toks = np.asarray(jnp.argmax(logits, axis=-1))  # [rb, width]
-        sampled0 = None
-        if not all(plain_greedy):
-            self._push_sampling()
-            self._rng, key = jax.random.split(self._rng)
-            sampled0 = np.asarray(sample_tokens(
-                logits[:, 0], key,
-                self._temp_d[row_d], self._top_p_d[row_d], self._top_k_d[row_d],
-                self._rep_pen_d[row_d], self._presence[row_d],
-            ))
-
-        # sentinel-padded committed-token matrix -> one batched presence mark
-        self._phase("engine.commit_host")
-        committed = np.full((rb, width), self.cfg.vocab_size, dtype=np.int32)
-        counts = np.zeros((rb,), dtype=np.int32)
-        for i, req in enumerate(running):
-            if plain_greedy[i]:
-                draft = drafts[i]
-                a = 0
-                while a < len(draft) and greedy_toks[i, a] == draft[a]:
-                    a += 1
-                toks = [int(t) for t in greedy_toks[i, : a + 1]]
-            else:
-                a = 0
-                toks = [int(sampled0[i])]
-            for j, t in enumerate(toks):
-                req.seq_len += 1
-                self._seq_lens[req.row] = req.seq_len
-                committed[i, counts[i]] = t
-                counts[i] += 1
-                if j < a:  # an accepted draft that actually committed
-                    self.spec_accepted += 1
-                self._commit_token(req, t, finished)
-                if req.state != "running":
-                    break
-        self._presence = _mark_presence_chunks(
-            self._presence, row_d, jnp.asarray(committed),
-            jnp.asarray(counts), self.cfg.vocab_size,
-        )
 
     def _first_wave(
         self,
@@ -3000,8 +2798,8 @@ class Engine:
                     plen = min(plen, self.sp_prefill_threshold - 1)
                 if plen <= 0:
                     # Skipping is provably safe, not a warm-coverage gap
-                    # (ADVICE r04 suggested an all-short fallback wave; it
-                    # is unnecessary): plen<=0 via the page budget needs
+                    # (an all-short fallback wave is unnecessary):
+                    # plen<=0 via the page budget needs
                     # num_pages <= (nb-1)*short_pages, i.e. no page left
                     # for an nb-th row — live traffic can never run nb
                     # simultaneous rows either, so (nb, *) is unreachable.
@@ -3032,26 +2830,27 @@ class Engine:
         )
         if self.sp_prefill_threshold is not None and self._sp > 1:
             # precompile the ring-prefill program at every ladder width a
-            # live pass can dispatch at (ADVICE r02: without this, the
-            # first above-threshold prompt — and each new width — pays a
+            # live pass can dispatch at (without this, the first
+            # above-threshold prompt — and each new width — pays a
             # multi-second-to-minutes XLA compile mid-request, violating
             # the warmed-shapes discipline stated in _prefill_batch).
-            # sp_ring_bucket_ladder() is the same list _ring_width (packed)
-            # selects from, and covers the one-sequence path's widths too,
-            # so warmup and dispatch can never desynchronize.  One prompt
-            # per width suffices for the packed program: its per-segment
-            # arrays are fixed at sp_ring_segs rows regardless of how many
+            # sp_ring_bucket_ladder() is the same list _ring_width selects
+            # from, so warmup and dispatch can never desynchronize.  One
+            # prompt per width suffices: the program's per-segment arrays
+            # are fixed at sp_ring_segs rows regardless of how many
             # segments a live pass actually carries.
             for width in self.sp_ring_bucket_ladder():
                 n = min(width, self.max_seq_len - 2)  # room for 2 tokens
                 if n >= self.sp_prefill_threshold:
                     self.generate([[1] * n], sp)
-        if self._draft_enabled:
-            # the plain-decode FALLBACK must be warm before it's ever
-            # needed: an acceptance collapse mid-request must not pay a
-            # decode_burst compile on top of the throughput it is already
-            # losing (the greedy waves above all routed through the spec
-            # path, so the no-filter burst variant is still cold)
+        if self._decode_path([]) not in (self._decode_step, self._fused_step):
+            # a speculative path that demotes a mixed batch: the
+            # plain-decode FALLBACK must be warm before it's ever needed —
+            # a sampled row joining, or an acceptance collapse mid-request,
+            # must not pay a decode_burst compile on top of the throughput
+            # it is already losing (the greedy waves above all routed
+            # through the spec path, so the no-filter burst variant is
+            # still cold)
             wave += 1
             tok = 2 + wave % max(2, self.cfg.vocab_size - 2)
             self._force_plain = True
@@ -3059,6 +2858,7 @@ class Engine:
                 self.generate([[tok] * 3], sp)
             finally:
                 self._force_plain = False
+        if self._draft_enabled:
             # compile the whole (k rung x row bucket) spec-burst ladder the
             # adaptive controller can reach.  All-False ``active`` masks
             # every KV write and commit, so each call is a pure
@@ -3136,7 +2936,7 @@ class Engine:
                             self._temp_d[rows], self._top_p_d[rows],
                             self._top_k_d[rows], self._rep_pen_d[rows],
                             *(pf_warm if has_pf else (None,) * 8),
-                            n_iters=self.spec_burst_iters,
+                            n_iters=self.spec_iters,
                             k=self.spec_ngram_k, tq=self.packed_chunk,
                             use_pallas=self.use_pallas,
                             int4_kernel=self._int4_kernel,
@@ -3207,7 +3007,7 @@ class Engine:
         prompts: list[list[int]],
         sampling: SamplingParams | list[SamplingParams] | None = None,
     ) -> list[GenerationResult]:
-        """Synchronous batch generation (tests, ingest extractors, bench)."""
+        """Synchronous batch generation (tests, ingest extractors)."""
         if isinstance(sampling, list):
             sps = sampling
         else:

@@ -25,8 +25,8 @@ spec_decode_burst has no way to sample.
 
 So a step that used to cost [prefill program] + [decode-or-spec program]
 (+ the gather fallbacks inside each) is ONE dispatch, and a mixed batch
-keeps speculation for its greedy rows — the goodput lever bench.py's
-``fused`` A/B measures.
+keeps speculation for its greedy rows (no benchmark cell runs it: not
+measured on the chip).
 
 Host contract matches spec_burst/decode_burst: stop/max_tokens
 bookkeeping stays host-side on the returned packed [B, n_iters, k+1]

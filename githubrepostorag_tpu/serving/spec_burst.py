@@ -1,37 +1,34 @@
 """Fused speculative decode bursts: draft + verify entirely on-device.
 
-The host-dispatched spec path (serving/spec_decode.py + engine.
-_spec_decode_step) pays one dispatch+fetch round trip per verify, so 16
-spec dispatches for 128 tokens measured 0.48-0.58x of ONE 128-step fused
-burst (BENCH r03/r04, over a slow host link: the comparison measured
-transport latency, not compute; not re-measured on an attached chip).
-This module removes the transport from the equation: ``n_iters``
-draft->verify->accept iterations run inside ONE compiled program
-(``lax.scan``), so a 128-token generation is one dispatch either way and
-the comparison becomes what speculative decoding is actually about — ~16
-verify forwards (each reading the weights once for k+1 positions) versus
-128 sequential single-token forwards.  In the acceptance regime that is a
-direct weight-HBM-read reduction, the decode bottleneck.
+Speculation dispatched from the host would pay one dispatch+fetch round
+trip per verify.  Here ``n_iters`` draft->verify->accept iterations run
+inside ONE compiled program (``lax.scan``), so a 128-token generation is
+one dispatch with or without speculation and the comparison becomes what
+speculative decoding is actually about — ~16 verify forwards (each reading
+the weights once for k+1 positions) versus 128 sequential single-token
+forwards.  In the
+acceptance regime that is a direct weight-HBM-read reduction, the decode
+bottleneck.  No benchmark cell runs it yet: not measured on the chip.
 
 Design, per iteration (all [B]-vectorized, no host control flow):
   1. DRAFT on-device: bigram prompt-lookup over a device-resident token
      history [B, H] — match positions j where history[j:j+2] equals the
-     row's last two tokens, take the EARLIEST (argmax of the match mask —
-     same earliest-occurrence choice as spec_decode.ngram_propose, which
-     measured ~k tokens/dispatch vs ~2 for most-recent), and gather the
-     following k tokens as the draft.
+     row's last two tokens, take the EARLIEST (argmax of the match mask:
+     on repetitive text the most recent match sits just before the suffix
+     and truncates the draft), and gather the following k tokens as the
+     draft.
   2. VERIFY: one ``forward_paged_impl`` call over [last, draft...] (k+1
      positions, causal over the row's pages) — the same body the engine's
      prefill path inlines; rejected positions' K/V are overwritten by the
-     next iteration exactly as in the host spec path.
+     next iteration.
   3. ACCEPT: commit the longest model-agreed draft prefix plus the
      model's correction token (cumprod of the agreement mask), append to
      the history, advance lens.
 
-Greedy-only by design, like the host spec path's eligibility rule: the
-engine engages this program only when every running row is plain greedy
-(temperature 0, no penalties), so outputs are token-identical to the
-plain burst path.  Stop-token / max_tokens bookkeeping stays host-side on
+Greedy-only by design: the engine engages this program only when every
+running row is plain greedy (temperature 0, no penalties; any other step
+decodes plainly, Engine._decode_path), so outputs are token-identical to
+the plain burst path.  Stop-token / max_tokens bookkeeping stays host-side on
 the returned packed tokens — the same contract as decode_burst.
 """
 

@@ -1,50 +1,34 @@
 """jax.profiler integration (SURVEY.md §5.1).
 
-Two layers:
-  - ``annotate(name, **meta)`` — a TraceAnnotation context manager: a host
-    span on the device trace's own clock.  ``meta`` (ints, floats, short
-    strings) rides as the event's stats; it is encoded only while a trace
-    is being taken, so with tracing off an annotation is one C++ object
-    and no formatting.  The names that exist (PERF.md §3 has the metric
-    that reads each):
+``annotate(name, **meta)`` — a TraceAnnotation context manager: a host
+span on the device trace's own clock.  ``meta`` (ints, floats, short
+strings) rides as the event's stats; it is encoded only while a trace
+is being taken, so with tracing off an annotation is one C++ object
+and no formatting.  The names that exist (PERF.md §3 has the metric
+that reads each):
 
-      driver.step        the locked section of AsyncEngine._drive with
-                         work; ``mono_ns`` anchors time.monotonic() stamps
-                         (GenerationResult.timings, obs/) to the trace
-      driver.export      counters, gauges, ledger, rings (under the
-                         lock, then after it)
-      driver.emit        parked/final events to the event loop, SLO monitor
-      driver.wait        the driver asleep with no work
-      server.submit_wait the event loop waiting for the driver's lock
-      engine.admit       reaping, preemption, admission, page allocation
-      engine.prefill_batch (+ _draft, prefill_packed, sp_prefill...)
-                         one prefill wave: host arrays, dispatch, sampling
-      engine.burst_prepare  masks, first-wave overlay, sampling push, RNG
-      engine.decode_burst (+ spec_burst, fused_step, draft_spec_burst,
-                         spec_decode) the dispatch call
-      engine.commit_fetch   the blocking device->host fetch of a burst
-      engine.commit_host    per-token bookkeeping, callbacks, results
-      embed.batch        one encoder batch, dispatch to vectors on host
-      index.search       one device-index wave, dispatch to hits on host
-      encoder.warmup, ingest.<stage>
-
-  - ``maybe_trace()`` — env-gated whole-run capture: when
-    ``JAX_PROFILE_DIR`` is set, wraps the block in
-    jax.profiler.start_trace/stop_trace, producing a TensorBoard-loadable
-    trace (``tensorboard --logdir $JAX_PROFILE_DIR``).
+    driver.step        the locked section of AsyncEngine._drive with
+                       work; ``mono_ns`` anchors time.monotonic() stamps
+                       (GenerationResult.timings, obs/) to the trace
+    driver.export      counters, gauges, ledger, rings (under the
+                       lock, then after it)
+    driver.emit        parked/final events to the event loop, SLO monitor
+    driver.wait        the driver asleep with no work
+    server.submit_wait the event loop waiting for the driver's lock
+    engine.admit       reaping, preemption, admission, page allocation
+    engine.prefill_batch (+ _draft, prefill_packed, sp_prefill_packed)
+                       one prefill wave: host arrays, dispatch, sampling
+    engine.burst_prepare  masks, first-wave overlay, sampling push, RNG
+    engine.decode_burst (+ spec_burst, fused_step, draft_spec_burst)
+                       the dispatch call
+    engine.commit_fetch   the blocking device->host fetch of a burst
+    engine.commit_host    per-token bookkeeping, callbacks, results
+    embed.batch        one encoder batch, dispatch to vectors on host
+    index.search       one device-index wave, dispatch to hits on host
+    encoder.warmup, ingest.<stage>
 """
 
 from __future__ import annotations
-
-import os
-from contextlib import contextmanager
-
-from githubrepostorag_tpu.utils.logging import get_logger
-
-logger = get_logger(__name__)
-
-PROFILE_DIR_ENV = "JAX_PROFILE_DIR"
-
 
 try:  # resolved once: annotate() sits on the engine's step path
     from jax.profiler import TraceAnnotation as _Annotation
@@ -72,22 +56,3 @@ def annotate(name: str, **meta):
     if _Annotation is None:
         return _NoAnnotation()
     return _Annotation(name, **meta)
-
-
-@contextmanager
-def maybe_trace():
-    """Capture a device trace for the enclosed block when JAX_PROFILE_DIR is
-    set (else no-op).  Usage: ``with maybe_trace(): run_workload()``."""
-    out_dir = os.environ.get(PROFILE_DIR_ENV)
-    if not out_dir:
-        yield
-        return
-    import jax
-
-    jax.profiler.start_trace(out_dir)
-    logger.info("jax.profiler trace capture -> %s", out_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-        logger.info("jax.profiler trace written to %s", out_dir)

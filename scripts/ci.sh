@@ -40,84 +40,6 @@ JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python scripts/check_slo_schema.py
 echo "== /debug/timeline + /debug/hbm schema =="
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python scripts/check_timeline_schema.py
 
-echo "== kv-tier oversubscription A/B (CPU-tiny) =="
-# tiered vs device-only pool at equal HBM budget: bench_kv_tier_pair
-# asserts >=1.5x admitted concurrency, token-identical outputs, and zero
-# live-traffic XLA recompiles — a failed gate fails the bench exit code.
-# BENCH_ONLY keeps the run single-scenario and leaves the committed
-# BENCH_SUMMARY.json untouched; the artifact lands in artifacts/.
-BENCH_ONLY=kv_tier JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python bench.py
-
-echo "== fleet-routing A/B (CPU-tiny) =="
-# prefix-affinity vs least-loaded vs round-robin over identical 2-replica
-# fleets: bench_routing_pair asserts affinity wins TTFT p50 against both
-# fallbacks, resident prefix-hit-rate materially above least-loaded,
-# token-identical outputs, and zero live-traffic XLA recompiles with
-# digest publishing active.
-BENCH_ONLY=routing JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python bench.py
-
-echo "== disaggregated-serving A/B (CPU-tiny) =="
-# fused vs disaggregated prefill/decode over identical 3-replica fleets
-# at the same offered load (65% of recalibrated fused capacity, Poisson
-# arrivals): bench_disagg_pair asserts decode TPOT p99 at or under fused
-# in the median of 5 paired back-to-back trials, window goodput within
-# noise, token-identical outputs, zero live-traffic XLA recompiles, and
-# the kv_transfer accounting + wire seconds inside the 2% obs budget.
-BENCH_ONLY=disagg JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python bench.py
-
-echo "== live-index streaming A/B (CPU-tiny) =="
-# idle vs under-streamed-re-index query p95 on the same warmed device
-# index: bench_liveindex_pair asserts doc-id parity before timing, live
-# p95 <= 1.5x idle, zero live XLA compiles on both the search and
-# mutation program caches, no whole-table transpose re-put (full_syncs),
-# and watermark-gauge publishing inside the 2% obs budget.
-BENCH_ONLY=liveindex JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python bench.py
-
-echo "== preemption A/B (CPU-tiny) =="
-# preempt=on vs preempt=off on the same 128-request saturating schedule
-# over identical tiered engines: bench_preempt_pair asserts interactive
-# TTFT p99 with preemption at or under 0.5x FIFO, both paths (and the
-# unloaded reference) token-identical, every victim resumed via host-tier
-# fault-in with zero recomputed prompt tokens, and zero live-traffic XLA
-# recompiles across park/resume.
-BENCH_ONLY=preempt JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python bench.py
-
-echo "== segment-packed ring prefill A/B (CPU-tiny) =="
-# packed vs one-sequence-per-pass ring prefill at equal sp=2 on the same
-# 8-stream mixed-length long-prompt wave: bench_longctx_pair asserts
-# packed aggregate prefill tok/s >= 1.5x the one-seq baseline, both paths
-# (and the unloaded chunked reference) token-identical, zero live-traffic
-# XLA compiles on either ring path, and SLO-plane overhead inside the 2%
-# obs budget.
-BENCH_ONLY=longctx JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python bench.py
-
-echo "== fused-step A/B (CPU-tiny) =="
-# one fused launch per engine step (packed prefill + spec-verify + paged
-# attention + sampling) vs the unfused per-iteration spec path on the
-# same 64-request mixed spec/plain wave over identical engines at equal
-# HBM, plus an int4-KV fused arm: bench_fused_pair asserts fused goodput
-# >= 1.3x unfused, greedy rows token-identical across all three arms,
-# int4 pages >= 1.8x int8 at equal pool bytes, zero live-traffic XLA
-# compiles, and SLO overhead (incl. the dispatch-attribution counters)
-# inside the 2% obs budget.
-BENCH_ONLY=fused JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python bench.py
-
-echo "== self-healing fleet-controller A/B (CPU-tiny) =="
-# controller on vs off against the same mid-run FAULTS replica kill over
-# identical 2-active + 1-warm-spare fleets: bench_controller_pair asserts
-# the controller arm recovers >= 0.8x pre-kill goodput with zero hung
-# requests (the fence fails in-flight work with error frames) and a
-# justification-stamped failover in the action log, while the
-# no-controller arm collapses below the same bar with requests hung to
-# timeout against the corpse.
-BENCH_ONLY=controller JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python bench.py
-
-echo "== bench history vs committed baselines =="
-# noise-tolerant comparison of this run's artifacts against the committed
-# BENCH_*_cpu.json history: warn-by-default (CPU-tiny numbers jitter on
-# shared hosts); export BENCH_STRICT=1 to turn regressions into failures
-python scripts/bench_compare.py artifacts/BENCH_*_cpu.json
-
 echo "== tier-1 tests =="
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m pytest tests/ -q -m 'not slow' \
     --continue-on-collection-errors -p no:cacheprovider -p no:xdist -p no:randomly

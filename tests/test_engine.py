@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from githubrepostorag_tpu.serving import Engine, SamplingParams
+from tests.helpers.step_paths import DECODE_PATHS, count_step_paths
 
 transformers = pytest.importorskip("transformers")
 import torch  # noqa: E402
@@ -416,3 +417,65 @@ def test_prefill_priority_same_outputs(tiny):
         return [r.output_tokens for r in eng.generate(prompts, sp)]
 
     assert run(prefill_priority=True) == run()
+
+
+# ------------------------------------------------ the choice of step path --
+
+_GREEDY = SamplingParams(max_tokens=8, temperature=0.0, stop_token_ids=())
+_SAMPLED = SamplingParams(max_tokens=8, temperature=0.8, stop_token_ids=())
+
+
+@pytest.mark.parametrize("options,sampling,path", [
+    pytest.param({}, [_GREEDY, _SAMPLED], "_decode_step", id="plain"),
+    pytest.param(dict(spec_ngram_k=3), [_GREEDY, _GREEDY], "_spec_burst_step",
+                 id="ngram-all-greedy"),
+    pytest.param(dict(spec_ngram_k=3), [_GREEDY, _SAMPLED], "_decode_step",
+                 id="ngram-sampled-row"),
+    pytest.param(dict(spec_ngram_k=3, fused_step=True, prefill_token_budget=32),
+                 [_GREEDY, _SAMPLED], "_fused_step", id="fused"),
+    pytest.param(dict(draft=True), [_GREEDY, _GREEDY], "_draft_spec_step",
+                 id="draft-all-capable"),
+    pytest.param(dict(draft=True), [_GREEDY, _SAMPLED], "_decode_step",
+                 id="draft-sampled-row"),
+])
+def test_step_takes_the_path_its_options_name(tiny, options, sampling, path):
+    """``Engine._decode_path`` is the one place a step's decode program is
+    chosen: each row of its ladder dispatches the path it names and no
+    other.  Both rows live and die together (same budget, no stop token),
+    so a mixed batch stays mixed for every step."""
+    _, params, cfg = tiny
+    options = dict(options)
+    if options.pop("draft", False):
+        options.update(draft_params=params, draft_cfg=cfg, spec_k=2)
+    eng = _make_engine(params, cfg, spec_iters=2, decode_burst=4, **options)
+    calls = count_step_paths(eng)
+    results = eng.generate([[5, 6, 7, 8] * 3, [9, 1, 2] * 4], sampling)
+    assert [len(r.output_tokens) for r in results] == [8, 8]
+    assert calls[path] > 0
+    assert [n for n in DECODE_PATHS if calls[n]] == [path]
+
+
+@pytest.mark.parametrize("config_name", ["qwen2-7b-int8", "deepseek-v3-ep16-bf16"])
+def test_the_cells_run_one_prefill_and_one_decode_path(config_name):
+    """Every benchmark cell builds its engine through its family's
+    ``build_engine`` with the engine's default options: a mixed batch
+    (greedy and sampled rows, a prompt longer than a chunk) dispatches
+    ``_prefill_batch`` and ``_decode_step`` and none of the other paths.
+    Built at the configuration file's ``rehearse`` widths."""
+    import json
+    from pathlib import Path
+
+    from benchmarks import families
+
+    root = Path(__file__).resolve().parents[1]
+    config = json.loads((root / "benchmarks" / "configs" / f"{config_name}.json").read_text())
+    family = families.load(config)
+    model = family.model_of(config, True)
+    eng = family.build_engine(config, model, config["rehearse"]["engine"], 7)
+    calls = count_step_paths(eng)
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(3, model["vocab_size"], n).tolist()
+               for n in (eng.prefill_chunk + 9, 12, 30)]
+    results = eng.generate(prompts, [_GREEDY, _SAMPLED, _GREEDY])
+    assert [len(r.output_tokens) for r in results] == [8, 8, 8]
+    assert sorted(n for n, c in calls.items() if c) == ["_decode_step", "_prefill_batch"]

@@ -288,7 +288,7 @@ def narrator():
 def _engine(params, cfg, **kw):
     defaults = dict(max_num_seqs=4, num_pages=64, page_size=8, max_seq_len=128,
                     prefill_chunk=16, prefill_token_budget=32,
-                    spec_ngram_k=3, spec_burst_iters=2, decode_burst=4)
+                    spec_ngram_k=3, spec_iters=2, decode_burst=4)
     defaults.update(kw)
     return Engine(dict(params), cfg, **defaults)
 
@@ -297,8 +297,6 @@ def test_fused_step_construction_gates(narrator):
     cfg, params = narrator
     with pytest.raises(ValueError, match="spec_ngram_k"):
         _engine(params, cfg, fused_step=True, spec_ngram_k=0)
-    with pytest.raises(ValueError, match="spec_burst_iters"):
-        _engine(params, cfg, fused_step=True, spec_burst_iters=0)
     with pytest.raises(ValueError, match="prefill_token_budget"):
         _engine(params, cfg, fused_step=True, prefill_token_budget=None)
     with pytest.raises(ValueError, match="SPEC_DRAFT_MODEL"):

@@ -206,13 +206,13 @@ def test_warmup_skips_ring_prefill_when_disabled(tiny):
     assert eng.sp_prefills == 0
 
 
-# ---- segment-packed ring passes (sp_ring_pack, the default) ---------------
+# ---- segment-packed ring passes: several long prompts in one pass ---------
 
 
 def test_packed_ring_multi_segment_token_parity(tiny):
     """Three long prompts admitted together flatten into ONE segment-packed
-    ring pass; every stream's tokens must match the one-sequence-per-pass
-    ring path AND the chunked single-device path run solo."""
+    ring pass; every stream's tokens must match the chunked single-device
+    path run solo."""
     _, params, cfg = tiny
     rng = np.random.default_rng(11)
     lens = (48, 64, 56)  # mixed lengths, all above threshold 40, sum 168
@@ -228,26 +228,21 @@ def test_packed_ring_multi_segment_token_parity(tiny):
     assert packed.sp_ring_segments == 3
     assert got == solo
 
-    seq = _sp_engine(params, cfg, sp_ring_pack=False)
-    got_seq = [r.output_tokens for r in seq.generate(prompts, sp)]
-    assert seq.sp_prefills == 3, "baseline must dispatch one pass per prompt"
-    assert got_seq == solo
 
-
-def test_packed_ring_pool_contents_match_seq(tiny):
+def test_packed_ring_pool_contents_match_chunked(tiny):
     """The packed pass commits every segment's K/V to the same pages with
-    the same bytes as one-sequence-per-pass ring prefill — same admission
-    order, same allocator decisions, same cache content."""
+    the same values as the chunked path — same admission order, same
+    allocator decisions, same cache content."""
     _, params, cfg = tiny
     rng = np.random.default_rng(12)
     prompts = [rng.integers(0, cfg.vocab_size, n).tolist() for n in (56, 48)]
     sp = SamplingParams(max_tokens=1, temperature=0.0, stop_token_ids=())
 
     a = _sp_engine(params, cfg)
-    b = _sp_engine(params, cfg, sp_ring_pack=False)
+    b = _engine(params, cfg)
     a.generate(prompts, sp)
     b.generate(prompts, sp)
-    assert a.sp_prefills == 1 and b.sp_prefills == 2
+    assert a.sp_prefills == 1 and a.sp_ring_segments == 2
     np.testing.assert_allclose(np.asarray(a._k_pages), np.asarray(b._k_pages),
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(a._v_pages), np.asarray(b._v_pages),
@@ -255,17 +250,19 @@ def test_packed_ring_pool_contents_match_seq(tiny):
 
 
 def test_packed_ring_kv_quant_parity(tiny):
-    """kv_quant composes with segment packing: both ring flavors compute
-    the whole prompt full-precision and quantize once at commit with the
-    same first-write-fixes-the-scale rule, so decoded tokens must match
-    exactly and the int8 page bytes within rounding."""
+    """kv_quant composes with segment packing: the pass computes every
+    prompt full-precision and quantizes once at commit with the chunked
+    path's first-write-fixes-the-scale rule (whole pages a chunk here, as
+    in test_ring_prefill_kv_quant_matches_chunked), so decoded tokens must
+    match the chunked engine's exactly and the int8 page bytes within
+    rounding."""
     _, params, cfg = tiny
     rng = np.random.default_rng(13)
     prompts = [rng.integers(0, cfg.vocab_size, n).tolist() for n in (48, 64)]
     sp = SamplingParams(max_tokens=10, temperature=0.0, stop_token_ids=())
 
     a = _sp_engine(params, cfg, kv_quant=True)
-    b = _sp_engine(params, cfg, kv_quant=True, sp_ring_pack=False)
+    b = _engine(params, cfg, kv_quant=True)
     got_a = [r.output_tokens for r in a.generate(prompts, sp)]
     got_b = [r.output_tokens for r in b.generate(prompts, sp)]
     assert a.sp_prefills == 1
