@@ -758,7 +758,8 @@ def child_tp4(seed: int, rehearse: bool) -> None:
             rep((b,), jnp.int32), rep((b, eng.max_pages_per_seq), jnp.int32), rep((2,), jnp.uint32),
             rep((b,), jnp.float32), rep((b,), jnp.float32), rep((b,), jnp.int32), rep((b,), jnp.float32),
             n_steps=eng.decode_burst, use_pallas=eng.use_pallas, mesh=eng.mesh,
-            filter_sampling=False,
+            filter_sampling=False, first_tokens=rep((b,), jnp.int32), fresh=rep((b,), jnp.bool_),
+            fresh_lens=rep((b,), jnp.int32), key_step=rep((), jnp.uint32),
         ).compile().as_text()
 
     # ---- tp:4 ----

@@ -493,6 +493,14 @@ INDEX_OPS_APPLIED = Counter(
     ["table", "kind"],
     registry=REGISTRY,
 )
+BURST_DISPATCH = Counter(
+    "rag_engine_burst_dispatch_total",
+    "Decode bursts dispatched, by whether the device still had work queued "
+    "when the step's programs went out (ahead=1: the host ran ahead) or had "
+    "drained and waited for the host (ahead=0)",
+    ["ahead"],
+    registry=REGISTRY,
+)
 MOE_EXPERTS_HIT = Counter(
     "rag_moe_experts_hit_total",
     "Held experts that received a token, summed over expert layers and steps "

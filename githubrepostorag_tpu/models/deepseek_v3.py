@@ -45,7 +45,11 @@ from githubrepostorag_tpu.ops.latent_attention import (
 )
 from githubrepostorag_tpu.ops.norms import rms_norm
 from githubrepostorag_tpu.ops.rope import rope_cos_sin, rope_rotate, yarn_inv_freq, yarn_mscale
-from githubrepostorag_tpu.ops.sampling import sample_tokens_capped, sample_tokens_nofilter
+from githubrepostorag_tpu.ops.sampling import (
+    first_token_tail,
+    sample_tokens_capped,
+    sample_tokens_nofilter,
+)
 from githubrepostorag_tpu.runtime import on_tpu
 
 ACT = jnp.bfloat16  # products take bfloat16 operands; the residual stream is float32
@@ -343,6 +347,53 @@ def forward_paged(
     """A prefill chunk over the latent cache, qwen2.forward_paged's contract:
     the chunk's latent rows are committed to the pool, then each row attends
     its cached prefix and itself.  Returns (logits, pool, None, stats [2])."""
+    return forward_paged_impl(params, cfg, input_ids, positions, k_pages, slot_mapping,
+                              block_tables, cached_lens, new_lens, use_pallas, logits_at)
+
+
+@partial(jax.jit, static_argnames=("cfg", "use_pallas", "int4_kernel", "mesh"),
+         donate_argnums=(4, 6))
+def forward_paged_wave(
+    params: dict,
+    cfg: DeepseekV3Config,
+    input_ids: jnp.ndarray,
+    positions: jnp.ndarray,
+    k_pages: jnp.ndarray,  # the latent pool (donated)
+    v_pages,  # None
+    presence: jnp.ndarray,  # [rows, V] bool (donated)
+    first_tokens: jnp.ndarray,  # [rows] int32
+    slot_mapping: jnp.ndarray,
+    block_tables: jnp.ndarray,
+    cached_lens: jnp.ndarray,
+    new_lens: jnp.ndarray,
+    logits_at: jnp.ndarray,
+    row_idx: jnp.ndarray,
+    done_mask: jnp.ndarray,
+    rng: jax.Array,
+    key_step: jnp.ndarray,
+    temperature: jnp.ndarray,
+    top_p: jnp.ndarray,
+    top_k: jnp.ndarray,
+    repetition_penalty: jnp.ndarray,
+    use_pallas: bool = False,
+    k_scales=None, v_scales=None, int4_kernel: bool = True, mesh=None,
+):
+    """The engine's prefill wave as one program, qwen2.forward_paged_wave's
+    contract: the chunk, then the first-token tail every family shares.
+    Returns (first_tokens, presence, pool, None, stats [2])."""
+    logits, *cache = forward_paged_impl(params, cfg, input_ids, positions, k_pages,
+                                        slot_mapping, block_tables, cached_lens, new_lens,
+                                        use_pallas, logits_at)
+    with jax.named_scope("sample"):
+        first_tokens, presence = first_token_tail(
+            logits[:, 0], presence, first_tokens, input_ids, new_lens, row_idx, done_mask,
+            jax.random.fold_in(rng, key_step), temperature, top_p, top_k, repetition_penalty)
+    return (first_tokens, presence, *cache)
+
+
+def forward_paged_impl(params, cfg, input_ids, positions, k_pages, slot_mapping, block_tables,
+                       cached_lens, new_lens, use_pallas=False, logits_at=None):
+    """Unjitted body of ``forward_paged``, traced into the wave program too."""
     from githubrepostorag_tpu.serving.kv_cache import commit_paged
 
     num_pages, page_size = k_pages.shape[2], k_pages.shape[3]
@@ -400,16 +451,20 @@ def decode_burst(
     layer_unroll: int = 1,
     filter_sampling: bool = True,
     k_scales=None, v_scales=None,
+    *, first_tokens, fresh, fresh_lens, key_step,
 ):
     """``n_steps`` decode iterations in one program, serving/decode_burst.py's
     contract and structure: the pool is loop-invariant inside the burst, new
     latent rows go to a staged buffer [L, B, n_steps, 640] that attention
     reads as a tail, and one scatter commits them at the end.  Returns
     (packed tokens [B, n_steps], valid, pool, None, presence, seq_lens,
-    stats [2]: experts hit and pairs routed to held experts, summed over
-    layers and steps)."""
+    last_tokens, stats [2]: experts hit and pairs routed to held experts,
+    summed over layers and steps)."""
+    from githubrepostorag_tpu.serving.decode_burst import overlay_fresh
     from githubrepostorag_tpu.serving.kv_cache import commit_paged
 
+    last_tokens, seq_lens, rng = overlay_fresh(
+        last_tokens, seq_lens, rng, first_tokens, fresh, fresh_lens, key_step)
     b, L = last_tokens.shape[0], cfg.num_layers
     num_pages, page_size = k_pages.shape[2], k_pages.shape[3]
     rows = jnp.arange(b)
@@ -459,7 +514,7 @@ def decode_burst(
 
     staged0 = jnp.zeros((L, b, n_steps, k_pages.shape[-1]), k_pages.dtype)
     carry0 = (last_tokens, seq_lens, staged0, presence, active, jnp.zeros((2,), jnp.int32))
-    (_, out_lens, staged, presence, _, stats), (toks, valid) = jax.lax.scan(
+    (last, out_lens, staged, presence, _, stats), (toks, valid) = jax.lax.scan(
         one_step, carry0, (jnp.arange(n_steps), jax.random.split(rng, n_steps)))
     toks, valid = toks.T, valid.T
     packed = jnp.where(valid, toks, -1)
@@ -471,4 +526,4 @@ def decode_burst(
     with jax.named_scope("latent_write"):
         k_pages, _ = commit_paged(k_pages, staged.reshape(L, 1, b * n_steps, -1), slots, None,
                                   page_size)
-    return packed, valid, k_pages, None, presence, out_lens, stats
+    return packed, valid, k_pages, None, presence, out_lens, last, stats

@@ -33,6 +33,7 @@ from githubrepostorag_tpu.models.quant import (
 from githubrepostorag_tpu.ops.attention import dense_attention
 from githubrepostorag_tpu.ops.norms import rms_norm
 from githubrepostorag_tpu.ops.rope import apply_rope, rope_cos_sin
+from githubrepostorag_tpu.ops.sampling import first_token_tail
 
 
 @dataclass(frozen=True)
@@ -450,6 +451,60 @@ def forward_paged(
         logits_at=logits_at, k_scales=k_scales, v_scales=v_scales,
         int4_kernel=int4_kernel, mesh=mesh,
     )
+
+
+@partial(
+    jax.jit, static_argnames=("cfg", "use_pallas", "int4_kernel", "mesh"),
+    donate_argnums=(4, 5, 6),
+)
+def forward_paged_wave(
+    params: dict,
+    cfg: Qwen2Config,
+    input_ids: jnp.ndarray,  # [R, S] the wave's chunk per row (forward_paged's)
+    positions: jnp.ndarray,
+    k_pages: jnp.ndarray,  # donated
+    v_pages: jnp.ndarray,  # donated
+    presence: jnp.ndarray,  # [rows, V] bool, the engine's whole mask (donated)
+    first_tokens: jnp.ndarray,  # [rows] int32, the engine's first-token array
+    slot_mapping: jnp.ndarray,
+    block_tables: jnp.ndarray,
+    cached_lens: jnp.ndarray,
+    new_lens: jnp.ndarray,
+    logits_at: jnp.ndarray,  # [R] each row's last valid position
+    row_idx: jnp.ndarray,  # [R] engine row of each wave row
+    done_mask: jnp.ndarray,  # [R] bool: the chunk completes the row's prompt
+    rng: jax.Array,  # the engine's base key; ``key_step`` is folded in here
+    key_step: jnp.ndarray,  # scalar: the engine's dispatch counter
+    temperature: jnp.ndarray,  # [rows] per engine row, like the burst's
+    top_p: jnp.ndarray,
+    top_k: jnp.ndarray,
+    repetition_penalty: jnp.ndarray,
+    use_pallas: bool = False,
+    k_scales: jnp.ndarray | None = None,
+    v_scales: jnp.ndarray | None = None,
+    int4_kernel: bool = True,
+    mesh=None,
+):
+    """The engine's prefill wave as ONE program: ``forward_paged``'s chunk,
+    then ``ops/sampling.first_token_tail`` on its logits (prompt tokens into
+    ``presence``, the first token of every completed row drawn, marked and
+    scattered into ``first_tokens``).  Every input but the pools, ``presence``,
+    ``first_tokens`` and ``rng`` is a host array, so the host dispatches it
+    and goes on: no value of the wave is touched before the commit fetches
+    ``first_tokens``.  Returns (first_tokens, presence, k_pages, v_pages[,
+    k_scales, v_scales])."""
+    logits, *cache = forward_paged_impl(
+        params, cfg, input_ids, positions, k_pages, v_pages,
+        slot_mapping, block_tables, cached_lens, new_lens, use_pallas,
+        logits_at=logits_at, k_scales=k_scales, v_scales=v_scales,
+        int4_kernel=int4_kernel, mesh=mesh,
+    )
+    with jax.named_scope("sample"):
+        first_tokens, presence = first_token_tail(
+            logits[:, 0], presence, first_tokens, input_ids, new_lens, row_idx,
+            done_mask, jax.random.fold_in(rng, key_step),
+            temperature, top_p, top_k, repetition_penalty)
+    return (first_tokens, presence, *cache)
 
 
 def forward_paged_impl(

@@ -67,6 +67,7 @@ SNAPSHOT_FIELDS = (
     "migration_seconds_total", "fault_in_seconds_total",
     "transfer_seconds_total",
     "fused_steps_total", "step_dispatches_total",
+    "bursts_ahead", "bursts_starved",
 )
 
 
@@ -170,6 +171,10 @@ class TokenLedger:
                 # program served it (serving/fused_step.py)
                 "fused_steps": max(0.0, d["fused_steps_total"]),
                 "dispatches": max(0.0, d["step_dispatches_total"]),
+                # bursts that went out while the device still had work
+                # queued, and bursts it had drained and waited for
+                "bursts_ahead": max(0.0, d["bursts_ahead"]),
+                "bursts_starved": max(0.0, d["bursts_starved"]),
             }
             if compiles > 0:
                 # kv_transfer stays out of ``measured``: it is inter-step
@@ -351,5 +356,7 @@ class TokenLedger:
                     "dispatches_per_step": round(
                         s.get("dispatches", 0.0) / s.get("steps", 1.0)
                         if s.get("steps", 0.0) else 0.0, 6),
+                    "bursts_ahead": int(s.get("bursts_ahead", 0.0)),
+                    "bursts_starved": int(s.get("bursts_starved", 0.0)),
                 },
             }

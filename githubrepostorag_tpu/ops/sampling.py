@@ -209,3 +209,53 @@ def sample_tokens(
     sampled = jax.random.categorical(rng, filtered, axis=-1).astype(jnp.int32)
 
     return jnp.where(temperature <= 0.0, greedy, sampled)
+
+
+def mark_presence_chunks(
+    presence: jnp.ndarray,  # [rows, V] bool
+    row_idx: jnp.ndarray,  # [R] int32
+    ids: jnp.ndarray,  # [R, W] int32 prompt-chunk tokens (right-padded)
+    lens: jnp.ndarray,  # [R] valid tokens per row
+) -> jnp.ndarray:
+    """Batched prompt-token presence marking: padding positions map to an
+    out-of-range sentinel that the drop-mode scatter discards."""
+    valid = jnp.arange(ids.shape[1])[None, :] < lens[:, None]
+    safe_ids = jnp.where(valid, ids, presence.shape[1])
+    return presence.at[row_idx[:, None], safe_ids].set(True, mode="drop")
+
+
+def first_token_tail(
+    logits: jnp.ndarray,  # [R, V] float32: each wave row's last-position logits
+    presence: jnp.ndarray,  # [rows, V] bool, the engine's whole mask
+    first_tokens: jnp.ndarray,  # [rows] int32, the engine's first-token array
+    input_ids: jnp.ndarray,  # [R, W] the wave's chunk tokens (right-padded)
+    new_lens: jnp.ndarray,  # [R] valid tokens per wave row (0 on padding rows)
+    row_idx: jnp.ndarray,  # [R] int32 engine row of each wave row
+    done_mask: jnp.ndarray,  # [R] bool: this chunk completes the row's prompt
+    rng: jax.Array,
+    temperature: jnp.ndarray,  # [rows] per-ENGINE-row sampling parameters,
+    top_p: jnp.ndarray,  # taken by row_idx here
+    top_k: jnp.ndarray,
+    repetition_penalty: jnp.ndarray,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """What follows a prefill wave's forward pass, traced into the wave's own
+    program (both model families): mark the chunk's prompt tokens in
+    ``presence``, draw the first token of every row whose prompt this chunk
+    completes (``sample_tokens`` on the rows' own parameters), mark those
+    tokens too and scatter them by engine row into ``first_tokens``.  A wave
+    that completes no prompt skips the draw on the device (``lax.cond``: the
+    two vocabulary-wide sorts are the cost).  Returns (first_tokens,
+    presence)."""
+    presence = mark_presence_chunks(presence, row_idx, input_ids, new_lens)
+
+    def draw(presence, first_tokens):
+        toks = sample_tokens(
+            logits, rng, temperature[row_idx], top_p[row_idx], top_k[row_idx],
+            repetition_penalty[row_idx], presence[row_idx])
+        # rows that are not done sample too; their scatters are dropped
+        rows = jnp.where(done_mask, row_idx, presence.shape[0])
+        return (first_tokens.at[rows].set(toks, mode="drop"),
+                presence.at[rows, toks].set(True, mode="drop"))
+
+    return jax.lax.cond(jnp.any(done_mask), draw, lambda p, f: (f, p),
+                        presence, first_tokens)

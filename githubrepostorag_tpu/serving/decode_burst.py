@@ -73,6 +73,16 @@ from githubrepostorag_tpu.ops.sampling import (
 from githubrepostorag_tpu.runtime import on_tpu
 
 
+def overlay_fresh(last_tokens, seq_lens, rng, first_tokens, fresh, fresh_lens, key_step):
+    """The head of a burst program (both families'): rows that a prefill wave
+    completed since the last burst take their token from the engine's
+    first-token array and their cache length from the host, and the engine's
+    dispatch counter is folded into its base key."""
+    last_tokens = jnp.where(fresh, first_tokens, last_tokens)
+    seq_lens = jnp.where(fresh, fresh_lens, seq_lens)
+    return last_tokens, seq_lens, jax.random.fold_in(rng, key_step)
+
+
 def _staged_attend_tp(mesh, interpret, quant: bool = False):
     """The Pallas staged kernel wrapped in a shard_map island for tensor
     parallelism: attention is embarrassingly parallel over kv heads, so each
@@ -148,16 +158,30 @@ def decode_burst(
     # engine decides per burst from its host-side sampling mirrors
     k_scales: jnp.ndarray | None = None,  # [L, n_kv, P] f32: int8 (kv_quant)
     v_scales: jnp.ndarray | None = None,  # pools' per-PAGE dequant scales
+    *,
+    first_tokens: jnp.ndarray,  # [B] int32: the engine's first-token array
+    # (models/qwen2.forward_paged_wave scatters into it)
+    fresh: jnp.ndarray,  # [B] bool: rows joining fresh from a prefill wave;
+    # their token is first_tokens' and their length fresh_lens'
+    fresh_lens: jnp.ndarray,  # [B] int32, host-known
+    key_step: jnp.ndarray,  # scalar folded into ``rng`` here
 ):
     """Run ``n_steps`` decode iterations for every active row.
 
     Returns (tokens [B, n_steps] int32, valid [B, n_steps] bool, k_pages,
-    v_pages, presence, seq_lens).  ``tokens`` is PACKED: positions where the
-    row was inactive hold -1, so the host learns tokens and validity from a
-    single [B, n_steps] transfer (one device->host round trip per burst).
-    ``valid`` (= tokens >= 0) stays a device output for in-program
-    consumers and tests.
+    v_pages, presence, seq_lens, last_tokens).  ``tokens`` is PACKED:
+    positions where the row was inactive hold -1, so the host learns tokens
+    and validity from a single [B, n_steps] transfer (one device->host round
+    trip per burst).  ``valid`` (= tokens >= 0) stays a device output for
+    in-program consumers and tests.  ``seq_lens`` and ``last_tokens`` [B] are
+    what the next burst takes in their place, on the device.
+
+    ``fresh`` overlays the rows a prefill wave just completed on the chained
+    state, here and not on the host: one shape whatever the number of waves
+    or of rows joining.
     """
+    last_tokens, seq_lens, rng = overlay_fresh(
+        last_tokens, seq_lens, rng, first_tokens, fresh, fresh_lens, key_step)
     b = last_tokens.shape[0]
     L = cfg.num_layers
     n_kv, hd = cfg.num_kv_heads, cfg.head_dim
@@ -357,5 +381,5 @@ def decode_burst(
         k_pages, k_scales = commit(k_pages, staged_k, k_scales)
         v_pages, v_scales = commit(v_pages, staged_v, v_scales)
     if quant:
-        return packed, valid, k_pages, v_pages, presence, out_lens, k_scales, v_scales
-    return packed, valid, k_pages, v_pages, presence, out_lens
+        return packed, valid, k_pages, v_pages, presence, out_lens, last, k_scales, v_scales
+    return packed, valid, k_pages, v_pages, presence, out_lens, last

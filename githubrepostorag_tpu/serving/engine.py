@@ -24,6 +24,13 @@ Design notes (TPU-first):
   - Sampling runs on-device with per-row parameters so one fused kernel
     serves heterogeneous requests (greedy judge calls batched with
     temperature-0.7 synthesis calls).
+  - Inside ``step`` the host asks the device for two programs and nothing
+    else: a prefill wave (the chunk and its first-token tail) and a decode
+    burst (which overlays the rows fresh from a wave itself).  Both take
+    host arrays and the device state they hand each other (pools, presence
+    mask, first-token array, chained tokens and lengths, a base key), so
+    the host runs ahead of the device and only ``engine.commit_fetch``
+    waits for it; admission's two presence helpers go out before the wave.
 """
 
 from __future__ import annotations
@@ -42,9 +49,10 @@ from githubrepostorag_tpu.models.qwen2 import (
     Qwen2Config,
     forward_paged,
     forward_paged_packed,
+    forward_paged_wave,
 )
 from githubrepostorag_tpu.ops.packed_prefill import ring_segment_layout
-from githubrepostorag_tpu.ops.sampling import sample_tokens
+from githubrepostorag_tpu.ops.sampling import mark_presence_chunks, sample_tokens
 from githubrepostorag_tpu.ops.page_migration import (
     gather_pages,
     migrate_buckets,
@@ -64,6 +72,7 @@ from githubrepostorag_tpu.serving.kv_cache import (
     slot_mapping,
 )
 from githubrepostorag_tpu.serving.sampling_params import SamplingParams
+from githubrepostorag_tpu.metrics import BURST_DISPATCH
 from githubrepostorag_tpu.utils.logging import get_logger
 from githubrepostorag_tpu.utils.profiling import annotate
 
@@ -317,7 +326,7 @@ class Engine:
         # (MLA) model brings its own two step programs under qwen2's
         # contracts, one page pool and no V pool
         self._latent = bool(getattr(cfg, "latent_kv", False))
-        self._forward_paged = forward_paged
+        self._wave_fn = forward_paged_wave
         if self._latent:
             from githubrepostorag_tpu.models import deepseek_v3
 
@@ -332,7 +341,7 @@ class Engine:
                 raise ValueError(
                     "not built for a latent page pool: "
                     + ", ".join(k for k, v in unsupported.items() if v))
-            self._forward_paged = deepseek_v3.forward_paged
+            self._wave_fn = deepseek_v3.forward_paged_wave
             self._decode_burst_fn = deepseek_v3.decode_burst
             # experts hit / pairs routed to held experts / expert slots
             # offered, per step program, cumulative; a dispatch's counts are
@@ -646,10 +655,23 @@ class Engine:
 
         # token-presence mask for repetition penalty [rows, V]
         self._presence = jnp.zeros((max_num_seqs, cfg.vocab_size), dtype=bool)
+        # each row's first token, where the prefill wave that completed its
+        # prompt scattered it: the next burst overlays it on its chained
+        # state and the commit fetches it, the host touches it nowhere else
+        self._first_d = jnp.zeros((max_num_seqs,), dtype=jnp.int32)
+        # the step programs' base key; they fold the dispatch counter in
+        # themselves, so a step splits no key on the host
+        self._rng = jax.random.PRNGKey(rng_seed)
+        self._key_step = 0
         if mesh is not None:
             self._presence = jax.device_put(self._presence, self._replicated)
-
-        self._rng = jax.random.PRNGKey(rng_seed)
+            self._first_d = jax.device_put(self._first_d, self._replicated)
+        # bursts dispatched while the device still had work queued (the host
+        # ran ahead) / after it had drained (the device waited for the host)
+        self.bursts_ahead = 0
+        self.bursts_starved = 0
+        self._step_starved = False
+        self._m_burst = [BURST_DISPATCH.labels(ahead=a) for a in ("0", "1")]
         self._waiting: list[_Request] = []
         self._rejected: list[_Request] = []
         self._requests: dict[str, _Request] = {}
@@ -668,9 +690,11 @@ class Engine:
         # deferred pages never re-enter the allocator while a burst is in
         # flight, so a new request can only receive pages no in-flight
         # computation references.  Prefill waves dispatch between bursts
-        # with no host sync: first tokens stay on device in
-        # ``_pending_first`` waves, get overlaid into the next burst's
-        # chained last/lens state, and commit with that burst's fetch.
+        # with no host sync: first tokens stay on device in the first-token
+        # array, the next burst overlays them on its chained last/lens
+        # state, and they commit with that burst's fetch.  A
+        # ``_pending_first`` entry is (the array as its wave left it, the
+        # wave's (request, row) pairs).
         self._chain: dict | None = None
         # (row, pages, request_id): the rid rides along so the page
         # observatory attributes page-seconds until the TRUE recycle time
@@ -842,6 +866,7 @@ class Engine:
         with a full decode burst.  Returns requests finished this step."""
         finished: list[GenerationResult] = []
         self.step_phase_s = {}
+        self._step_starved = False
         self._phase("engine.admit")
         for req in self._rejected:
             res = self._result(req, "error")
@@ -1654,9 +1679,10 @@ class Engine:
         vLLM-style batched prefill compute rather than one program per
         request.  Rows at different prompt offsets ride the same program via
         per-row positions / cached_lens / slot mappings; rows whose prompt
-        completes this chunk get their first token sampled in one batched
-        on-device call.  When decode is running, the sampled tokens are NOT
-        fetched — the wave is queued on device and commits with the next
+        completes this chunk get their first token sampled in the same
+        program, which scatters it into the first-token array.  This method
+        ends at that dispatch: when decode is running, the sampled tokens are
+        NOT fetched — the wave is queued on device and commits with the next
         burst, so admissions never stall running streams on a host sync."""
         if self.prefill_token_budget is not None:
             # the packed dispatch keeps its own annotation inside the phase
@@ -1682,6 +1708,11 @@ class Engine:
         bt = np.zeros((rb, self.max_pages_per_seq), dtype=np.int32)
         cached = np.zeros((rb,), dtype=np.int32)
         new_lens = np.zeros((rb,), dtype=np.int32)
+        # logits only at each row's last valid position: full-position
+        # prefill logits are [rb, width, V] float32 — GBs at 64 rows
+        last_idx = np.zeros((rb,), dtype=np.int32)
+        row_idx = np.zeros((rb,), dtype=np.int32)  # the engine row of each wave row
+        done_mask = np.zeros((rb,), dtype=bool)  # this chunk completes the prompt
         valids = []
         for i, req in enumerate(reqs):
             start = req.prefill_pos
@@ -1693,6 +1724,9 @@ class Engine:
             bt[i] = self._block_tables[req.row]
             cached[i] = start
             new_lens[i] = valid
+            last_idx[i] = valid - 1
+            row_idx[i] = req.row
+            done_mask[i] = start + valid >= len(req.prompt)
         # the wave's real work, for whoever reads the trace: tokens already in
         # the cache, tokens this chunk adds, the (query, key) pairs they
         # attend, and the prompts this chunk completes
@@ -1700,37 +1734,36 @@ class Engine:
         wave_ann.set_metadata(
             rows=n, new_tokens=sum(valids), cached_tokens=sum(starts),
             pairs=sum(v * c + v * (v + 1) // 2 for v, c in zip(valids, starts)),
-            completes=sum(c + v >= len(r.prompt) for v, c, r in zip(valids, starts, reqs)))
+            completes=int(done_mask.sum()))
 
-        # logits only at each row's last valid position: full-position
-        # prefill logits are [rb, width, V] float32 — GBs at 64 rows
-        last_idx = np.zeros((rb,), dtype=np.int32)
-        for i, v in enumerate(valids):
-            last_idx[i] = v - 1
-        ids_d, pos_d = jnp.asarray(ids), jnp.asarray(pos)
-        slots_d, bt_d = jnp.asarray(slots), jnp.asarray(bt)
-        cached_d, new_lens_d = jnp.asarray(cached), jnp.asarray(new_lens)
-        last_idx_d = jnp.asarray(last_idx)
+        # ONE program: the chunk, then its tail (prompt tokens into the
+        # presence mask, the first token of every completed row drawn, marked
+        # and scattered into the first-token array).  Its inputs are host
+        # arrays and device state the step programs hand each other, so the
+        # host returns from here while the wave is still queued: nothing of
+        # the wave is read before _commit_first_tokens fetches it
         self.step_dispatches_total += 1
-        out = self._forward_paged(
+        self._note_dispatch()
+        self._first_d, self._presence, *cache = self._wave_fn(
             self.params, self.cfg,
-            ids_d, pos_d,
-            self._k_pages, self._v_pages,
-            slots_d, bt_d,
-            cached_d, new_lens_d,
-            use_pallas=self.use_pallas, logits_at=last_idx_d,
+            ids, pos,
+            self._k_pages, self._v_pages, self._presence, self._first_d,
+            slots, bt, cached, new_lens,
+            last_idx, row_idx, done_mask,
+            self._rng, self._next_key_step(),
+            self._temp, self._top_p, self._top_k, self._rep_pen,
+            use_pallas=self.use_pallas,
             k_scales=self._k_scales, v_scales=self._v_scales,
             int4_kernel=self._int4_kernel, mesh=self.mesh,
         )
         if self.kv_quant:
-            (logits, self._k_pages, self._v_pages,
-             self._k_scales, self._v_scales) = out
+            self._k_pages, self._v_pages, self._k_scales, self._v_scales = cache
         elif self._latent:
-            logits, self._k_pages, self._v_pages, moe = out
+            self._k_pages, self._v_pages, moe = cache
             self._moe_dispatched("prefill", moe, 1)
             wave_ann.set_metadata(**self._moe_meta("prefill"))
         else:
-            logits, self._k_pages, self._v_pages = out
+            self._k_pages, self._v_pages = cache
         if self._draft_enabled:
             # the draft model prefills the SAME chunk into its own pools
             # (same slots/block tables — the pools are position-aligned by
@@ -1741,57 +1774,25 @@ class Engine:
             with annotate("engine.prefill_batch_draft"):
                 _, self._dk_pages, self._dv_pages = forward_paged(
                     self.draft_params, self.draft_cfg,
-                    ids_d, pos_d,
+                    ids, pos,
                     self._dk_pages, self._dv_pages,
-                    slots_d, bt_d,
-                    cached_d, new_lens_d,
-                    use_pallas=self.use_pallas, logits_at=last_idx_d,
+                    slots, bt,
+                    cached, new_lens,
+                    use_pallas=self.use_pallas, logits_at=last_idx,
                     int4_kernel=self._int4_kernel,
                 )
 
-        # mark prompt tokens in the presence mask (repetition penalty input);
-        # one batched scatter for the whole padded wave (padding rows have
-        # lens 0, so their scatter drops everything)
-        row_idx = np.zeros((rb,), dtype=np.int32)
-        row_idx[:n] = [r.row for r in reqs]
-        row_d = jnp.asarray(row_idx)
-        self._presence = _mark_presence_chunks(
-            self._presence, row_d, jnp.asarray(ids), jnp.asarray(new_lens),
-            self.cfg.vocab_size,
-        )
-
-        done_idx: list[int] = []
+        done: list[_Request] = []
         for i, req in enumerate(reqs):
             req.prefill_pos += valids[i]
             self.prefill_tokens += int(valids[i])
             req.seq_len = req.prefill_pos
             self._seq_lens[req.row] = req.seq_len
             self._register_full_pages(req)
-            if req.prefill_pos >= len(req.prompt):
-                done_idx.append(i)
-
-        if not done_idx:
-            return  # every row has more chunks to go
-
-        # Prompts fully cached for some rows: sample first tokens.  The
-        # sampling program always sees the full [rb] padded batch (one
-        # compiled shape per row bucket); rows that aren't done sample too
-        # but their tokens are discarded and their presence scatter masked.
-        done_mask = np.zeros((rb,), dtype=bool)
-        done_mask[done_idx] = True
-
-        self._push_sampling()
-        self._rng, key = jax.random.split(self._rng)
-        last_logits = logits[:, 0]  # [rb, V] — logits_at already selected
-        tokens_d = sample_tokens(
-            last_logits, key,
-            self._temp_d[row_d], self._top_p_d[row_d], self._top_k_d[row_d],
-            self._rep_pen_d[row_d], self._presence[row_d],
-        )
-        safe = jnp.where(jnp.asarray(done_mask), tokens_d, self.cfg.vocab_size)
-        self._presence = _mark_presence_rows(self._presence, row_d, safe)
-        self._first_wave(tokens_d, [(reqs[i], i) for i in done_idx],
-                         others_running, finished)
+            if done_mask[i]:
+                done.append(req)
+        if done:
+            self._rows_join(done, others_running, finished)
 
     def _prefill_batch_packed(
         self, reqs: list[_Request], finished: list[GenerationResult]
@@ -2119,45 +2120,47 @@ class Engine:
             last = np.zeros((b,), dtype=np.int32)
             for row, req in self._row_req.items():
                 last[row] = req.output[-1] if req.output else req.prompt[-1]
+            # device arrays like the chained ones: the burst's call signature
+            # (one cache entry, one warm-up) is the same either way
             last_d = jnp.asarray(last)
             lens_d = jnp.asarray(self._seq_lens)
         else:
             last_d = self._chain["last"]
             lens_d = self._chain["lens"]
 
-        # overlay freshly-prefilled rows: their first token lives on device
-        # (uncommitted) and their cache length is the host-known prompt
-        # length — neither is in the chained state from the in-flight burst
+        # freshly-prefilled rows: their first token lives on device
+        # (uncommitted, in the first-token array) and their cache length is
+        # the host-known prompt length — neither is in the chained state from
+        # the in-flight burst.  The burst overlays both itself from two host
+        # masks of fixed shape, whatever the number of waves or rows joining
         first_waves = self._pending_first
         self._pending_first = []
-        for tokens_d, wave in first_waves:
-            # skip requests released/cancelled since their wave was queued:
-            # their row is -1 (or reassigned), and a negative index would
-            # WRAP to the last row and corrupt an unrelated request
-            live = [(req, i) for req, i in wave if req.state == "running" and req.row >= 0]
-            if not live:
-                continue
-            rows = jnp.asarray(np.asarray([req.row for req, _ in live], dtype=np.int32))
-            idxs = jnp.asarray(np.asarray([i for _, i in live], dtype=np.int32))
-            lens = jnp.asarray(
-                np.asarray([self._seq_lens[req.row] for req, _ in live], dtype=np.int32)
-            )
-            last_d = last_d.at[rows].set(tokens_d[idxs])
-            lens_d = lens_d.at[rows].set(lens)
-
-        self._push_sampling()
-        self._rng, key = jax.random.split(self._rng)
+        fresh = np.zeros((b,), dtype=bool)
+        fresh_lens = np.zeros((b,), dtype=np.int32)
+        for _, wave in first_waves:
+            for req, row in wave:
+                # skip requests released/cancelled since their wave was
+                # queued: their row is free (or reassigned), and must not
+                # take a token that is not its occupant's
+                if req.state == "running" and req.row == row:
+                    fresh[row] = True
+                    fresh_lens[row] = self._seq_lens[row]
 
         self.step_dispatches_total += 1
+        self._note_dispatch()
+        ahead = not self._step_starved
+        self.bursts_ahead += ahead
+        self.bursts_starved += not ahead
+        self._m_burst[ahead].inc()
         self._phase("engine.decode_burst", rows=live_rows, kv_tokens=kv_tokens,
-                    steps=n_steps, **(self._moe_meta("burst") if self._latent else {}))
+                    steps=n_steps, ahead=int(ahead),
+                    **(self._moe_meta("burst") if self._latent else {}))
         out = self._decode_burst_fn(
             self.params, self.cfg,
             last_d, lens_d,
             self._k_pages, self._v_pages, self._presence,
-            jnp.asarray(active), jnp.asarray(self._row_limits),
-            jnp.asarray(self._block_tables), key,
-            self._temp_d, self._top_p_d, self._top_k_d, self._rep_pen_d,
+            active, self._row_limits, self._block_tables, self._rng,
+            self._temp, self._top_p, self._top_k, self._rep_pen,
             n_steps=n_steps, use_pallas=self.use_pallas, mesh=self.mesh,
             layer_unroll=self.layer_unroll,
             # sort-free sampling whenever no SAMPLING row filters —
@@ -2173,20 +2176,22 @@ class Engine:
                 )
             ),
             k_scales=self._k_scales, v_scales=self._v_scales,
+            first_tokens=self._first_d, fresh=fresh, fresh_lens=fresh_lens,
+            key_step=self._next_key_step(),
         )
         if self.kv_quant:
-            (toks, valid, self._k_pages, self._v_pages, self._presence,
-             out_lens, self._k_scales, self._v_scales) = out
+            (toks, _, self._k_pages, self._v_pages, self._presence,
+             out_lens, last, self._k_scales, self._v_scales) = out
         elif self._latent:
-            (toks, valid, self._k_pages, self._v_pages, self._presence,
-             out_lens, moe) = out
+            (toks, _, self._k_pages, self._v_pages, self._presence,
+             out_lens, last, moe) = out
             self._moe_dispatched("burst", moe, n_steps)
         else:
-            (toks, valid, self._k_pages, self._v_pages, self._presence,
-             out_lens) = out
+            (toks, _, self._k_pages, self._v_pages, self._presence,
+             out_lens, last) = out
         prev = self._chain
         self._chain = {
-            "last": toks[:, -1], "lens": out_lens, "pending": toks,
+            "last": last, "lens": out_lens, "pending": toks,
             "first": first_waves,
         }
         if self._latent:
@@ -2529,21 +2534,55 @@ class Engine:
         others_running: bool,
         finished: list[GenerationResult],
     ) -> None:
+        """First tokens sampled by a path that draws them outside its prefill
+        program (packed, ring, fused): ``tokens_d[i]`` is ``req``'s for each
+        ``(req, i)`` of ``wave``.  They are scattered by row into the
+        first-token array, where ``_prefill_batch``'s wave program puts its
+        own, and the rows join the running set."""
+        rows = np.full((self.max_num_seqs,), self.max_num_seqs, dtype=np.int32)  # dropped
+        idxs = np.zeros((self.max_num_seqs,), dtype=np.int32)
+        for j, (req, i) in enumerate(wave):
+            rows[j], idxs[j] = req.row, i
+        self._first_d = _scatter_first_tokens(self._first_d, tokens_d, rows, idxs)
+        self._rows_join([req for req, _ in wave], others_running, finished)
+
+    def _rows_join(
+        self,
+        reqs: list[_Request],
+        others_running: bool,
+        finished: list[GenerationResult],
+    ) -> None:
         """The chunk that completed these prompts is dispatched and their
-        first tokens are sampled on device: the rows join the running set.
-        With the engine otherwise idle (nothing to overlap the sync with) or
-        in speculative mode (synchronous by design) the tokens commit now
-        (best TTFT); else the wave stays on device and commits with the
-        next burst's fetch, so admissions never stall running streams."""
+        first tokens are in (this version of) the first-token array: the rows
+        join the running set.  With the engine otherwise idle (nothing to
+        overlap the sync with) or in speculative mode (synchronous by design)
+        the tokens commit now (best TTFT); else the wave stays on device and
+        commits with the next burst's fetch, so admissions never stall
+        running streams."""
         now = time.monotonic()
-        for req, _ in wave:
+        for req in reqs:
             req.state = "running"
             if req.prefill_end_t is None:  # a resumed request keeps its first
                 req.prefill_end_t = now
+        wave = (self._first_d, [(req, req.row) for req in reqs])
         if self._commit_first_now(others_running):
-            self._commit_first_tokens([(tokens_d, wave)], finished)
+            self._commit_first_tokens([wave], finished)
         else:
-            self._pending_first.append((tokens_d, wave))
+            self._pending_first.append(wave)
+
+    def _next_key_step(self) -> np.ndarray:
+        """The dispatch counter a step program folds into the base key."""
+        self._key_step = (self._key_step + 1) % (1 << 31)
+        return np.asarray(self._key_step, dtype=np.uint32)
+
+    def _note_dispatch(self) -> None:
+        """Before a step program goes out: had the device already drained?
+        ``presence`` is an output of the newest program of any kind (wave,
+        burst, the admission helpers); ready, the device has nothing left
+        queued and waits for this dispatch.  Non-blocking.  A step whose
+        wave or burst found the device drained counts its burst as starved."""
+        if self._presence.is_ready():
+            self._step_starved = True
 
     def _commit_first_tokens(
         self,
@@ -2740,8 +2779,9 @@ class Engine:
     # --------------------------------------------------------- convenience --
 
     def warmup(self) -> None:
-        """Precompile every steady-state device program — prefill at each
-        row bucket, the decode burst, first-token sampling — so live traffic
+        """Precompile every steady-state device program — the prefill wave at
+        each row bucket (first-token sampling is part of it), the decode
+        burst in both sampling variants — so live traffic
         never hits a multi-second XLA compile mid-request (vLLM warms up its
         CUDA graphs the same way; a cold shape of a 28-layer step program
         costs tens of seconds).  Runs tiny throwaway requests through
@@ -3031,11 +3071,16 @@ def _mark_presence_chunks(
     lens: jnp.ndarray,  # [R] valid tokens per row
     vocab: int,
 ) -> jnp.ndarray:
-    """Batched prompt-token presence marking: padding positions map to an
-    out-of-range sentinel that the drop-mode scatter discards."""
-    valid = jnp.arange(ids.shape[1])[None, :] < lens[:, None]
-    safe_ids = jnp.where(valid, ids, vocab)
-    return presence.at[row_idx[:, None], safe_ids].set(True, mode="drop")
+    """Batched prompt-token presence marking (cached-prefix admission; a
+    prefill wave marks its own chunk inside its program)."""
+    return mark_presence_chunks(presence, row_idx, ids, lens)
+
+
+@jax.jit
+def _scatter_first_tokens(first: jnp.ndarray, tokens: jnp.ndarray, rows: jnp.ndarray,
+                          idxs: jnp.ndarray) -> jnp.ndarray:
+    """``first[rows[j]] = tokens[idxs[j]]``; rows past the array are dropped."""
+    return first.at[rows].set(tokens[idxs], mode="drop")
 
 
 @jax.jit

@@ -14,13 +14,16 @@ that reads each):
                        lock, then after it)
     driver.emit        parked/final events to the event loop, SLO monitor
     driver.wait        the driver asleep with no work
-    server.submit_wait the event loop waiting for the driver's lock
+    server.submit_wait a submission waiting for the driver's lock, in the
+                       executor: the event loop stays free
     engine.admit       reaping, preemption, admission, page allocation
     engine.prefill_batch (+ _draft, prefill_packed, sp_prefill_packed)
-                       one prefill wave: host arrays, dispatch, sampling
-    engine.burst_prepare  masks, first-wave overlay, sampling push, RNG
+                       one prefill wave: host arrays and the dispatch of
+                       its one program (chunk + first-token tail)
+    engine.burst_prepare  the active mask and the masks of fresh rows
     engine.decode_burst (+ spec_burst, fused_step, draft_spec_burst)
-                       the dispatch call
+                       the dispatch call; ``ahead``: the device still
+                       had work queued when the step's programs went out
     engine.commit_fetch   the blocking device->host fetch of a burst
     engine.commit_host    per-token bookkeeping, callbacks, results
     embed.batch        one encoder batch, dispatch to vectors on host

@@ -129,6 +129,45 @@ def test_a_prefill_wave_stays_inside_the_warmed_row_buckets(tiny):
     assert together == [list(alone.generate([p], sp)[0].output_tokens) for p in prompts]
 
 
+def test_a_latent_step_is_a_wave_and_a_burst_and_no_eager_op(tiny):
+    """The latent engine through the same two programs a step: once warm, a
+    row joining a running row, then eleven at once (a wave of eight, the rest
+    in the next step's wave), apply no primitive eagerly and dispatch at most
+    the wave and the burst; greedy tokens are a request-at-a-time run's."""
+    from tests.helpers.step_programs import assert_two_programs_a_step, run_recorded
+
+    cfg, params = tiny
+    rng = np.random.default_rng(30)
+    prompts = [list(map(int, rng.integers(2, cfg.vocab_size, size=n)))
+               for n in [9, 7] + [20] * 10 + [40]]
+    sp = SamplingParams(max_tokens=14, temperature=0.0, stop_token_ids=())
+
+    def engine():
+        return Engine(params, cfg, max_num_seqs=16, num_pages=2 * PAGES, page_size=PAGE,
+                      max_seq_len=64, prefill_chunk=32, decode_burst=4, rng_seed=0)
+
+    def script(eng, ids):
+        ids.append(eng.add_request(prompts[0], sp))
+        yield
+        yield
+        yield
+        ids.append(eng.add_request(prompts[1], sp))
+        yield
+        yield
+        ids.extend(eng.add_request(p, sp) for p in prompts[2:])
+        yield
+        yield
+
+    rehearsal = engine()
+    run_recorded(rehearsal, script(rehearsal, []), warm=False)
+    eng, ids = engine(), []
+    done, steps = run_recorded(eng, script(eng, ids))
+    assert_two_programs_a_step(steps)
+    alone = engine()
+    assert [done[rid].output_tokens for rid in ids] == [
+        alone.generate([p], sp)[0].output_tokens for p in prompts]
+
+
 def test_a_chunk_against_a_cached_prefix_equals_a_cold_prefill(tiny):
     """The prefix-cache hit path: 16 new tokens after 32 cached give the
     logits a cold 48-token prefill gives at the same positions."""

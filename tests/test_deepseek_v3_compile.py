@@ -19,7 +19,12 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from tests.test_tpu_compile import chip, pool_movers, topo  # noqa: F401 - fixtures
+from tests.test_tpu_compile import (  # noqa: F401 - fixtures
+    assert_wave_keeps_in_place,
+    chip,
+    pool_movers,
+    topo,
+)
 
 PAGES, PAGE, ROWS, ROW_PAGES = 2048, 128, 32, 80
 
@@ -41,7 +46,12 @@ def as_on_chip(monkeypatch):
 
 
 def lowered_program(where, program: str, variant):
-    from githubrepostorag_tpu.models.deepseek_v3 import decode_burst, forward_paged, init_params
+    from githubrepostorag_tpu.models.deepseek_v3 import (
+        decode_burst,
+        forward_paged,
+        forward_paged_wave,
+        init_params,
+    )
 
     cfg = cell_config()
     params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=where),
@@ -58,7 +68,18 @@ def lowered_program(where, program: str, variant):
             params, cfg, sds((b,), i32), sds((b,), i32), pool, None,
             sds((b, cfg.vocab_size), jnp.bool_), sds((b,), jnp.bool_), sds((b,), i32),
             sds((b, ROW_PAGES), i32), sds((2,), jnp.uint32), sds((b,), f32), sds((b,), f32),
-            sds((b,), i32), sds((b,), f32), n_steps=8, use_pallas=True, filter_sampling=variant)
+            sds((b,), i32), sds((b,), f32), n_steps=8, use_pallas=True, filter_sampling=variant,
+            # as the engine calls it: rows fresh from a wave are overlaid inside
+            first_tokens=sds((b,), i32), fresh=sds((b,), jnp.bool_), fresh_lens=sds((b,), i32),
+            key_step=sds((), jnp.uint32))
+    elif program == "wave":  # the engine's prefill wave: the chunk and its first-token tail
+        chunk, rows = (variant, 512), (variant,)
+        lowered = forward_paged_wave.lower(
+            params, cfg, sds(chunk, i32), sds(chunk, i32), pool, None,
+            sds((b, cfg.vocab_size), jnp.bool_), sds((b,), i32), sds(chunk, i32),
+            sds((variant, ROW_PAGES), i32), sds(rows, i32), sds(rows, i32), sds(rows, i32),
+            sds(rows, i32), sds(rows, jnp.bool_), sds((2,), jnp.uint32), sds((), jnp.uint32),
+            sds((b,), f32), sds((b,), f32), sds((b,), i32), sds((b,), f32), use_pallas=True)
     else:
         chunk = (variant, 512)
         lowered = forward_paged.lower(
@@ -72,6 +93,8 @@ def lowered_program(where, program: str, variant):
     pytest.param("burst", False, id="burst-nofilter"),
     pytest.param("prefill", 1, id="prefill-1x512"),
     pytest.param("prefill", 2, id="prefill-2x512"),
+    pytest.param("wave", 1, id="wave-1x512"),
+    pytest.param("wave", 8, id="wave-8x512"),
 ])
 def test_step_program_leaves_latent_pool_and_experts_in_place(chip, as_on_chip, program,
                                                               variant):
@@ -81,6 +104,14 @@ def test_step_program_leaves_latent_pool_and_experts_in_place(chip, as_on_chip, 
     assert pool_movers(hlo, pool_shape) == []
     for name in ("e_wgu", "e_wd"):  # [Lm, n_held, in, out]: no copy of a stack or a layer's slab
         assert pool_movers(hlo, params["moe"][name].shape) == []
+
+
+def test_the_wave_is_one_program_that_donates_the_pool_and_presence(chip, as_on_chip):
+    """The benchmark's readers find the wave by ``forward_paged`` in its
+    module's name; the latent pool and the presence mask come back in place."""
+    lowered, _, _ = lowered_program(chip, "wave", 2)
+    assert_wave_keeps_in_place(lowered.compile().as_text(),
+                               rf"bf16\[5,1,{PAGES},{PAGE},640\]|pred\[{ROWS},16160\]", 2)
 
 
 def test_the_expert_metric_selects_the_products_under_the_moe_experts_scope(chip, as_on_chip):
@@ -111,3 +142,31 @@ def test_the_expert_metric_selects_the_products_under_the_moe_experts_scope(chip
             under_scope.add(name)
     assert len(picked) == 3  # gate|up, down, the combine's scatter-add
     assert picked == under_scope
+
+
+def test_the_latent_burst_has_one_shape_whatever_joins_it():
+    """On the CPU, at a tiny size: bursts that no row, one row and nine rows
+    (two waves: a wave carries eight) join call one program with one set of
+    shapes."""
+    from githubrepostorag_tpu.models import deepseek_v3 as ds
+    from githubrepostorag_tpu.serving import Engine, SamplingParams
+    from tests.helpers.step_programs import burst_call_shapes
+
+    cfg = ds.DeepseekV3Config.tiny(experts_held=(4, 12))
+    eng = Engine(ds.init_params(cfg, seed=11), cfg, max_num_seqs=16, num_pages=96, page_size=8,
+                 max_seq_len=64, prefill_chunk=32, decode_burst=4, rng_seed=0)
+    sp = SamplingParams(max_tokens=20, temperature=0.0, stop_token_ids=())
+    before = ds.decode_burst._cache_size()
+    shapes = burst_call_shapes(eng)
+    joined = []
+    for wave in ([[3, 4, 5]], [[6, 7]], [[8 + i, 9, 10] for i in range(9)]):
+        for prompt in wave:
+            eng.add_request(prompt, sp)
+        for _ in range(3):
+            eng.step()
+            joined.append(sum(len(w) for _, w in eng._chain["first"]) if eng._chain else 0)
+    while eng.has_work():
+        eng.step()
+    assert {0, 1, 8} <= set(joined)
+    assert len(shapes) >= 9 and len(set(shapes)) == 1
+    assert ds.decode_burst._cache_size() - before <= 1

@@ -400,6 +400,123 @@ def test_cancelled_pending_first_wave_does_not_corrupt_others(tiny):
     assert done[r1].output_tokens == solo.output_tokens
 
 
+# ------------------------------------- a step is two device programs --
+
+
+def _join_one_then_several(eng, prompts, sp, ids):
+    """A row decodes with a live chain; one row joins it, then three in one
+    wave, one of them two chunks long (its second chunk is a wave of its
+    own a step later)."""
+    ids.append(eng.add_request(prompts[0], sp))
+    yield
+    yield
+    yield
+    ids.append(eng.add_request(prompts[1], sp))
+    yield
+    yield
+    ids.extend(eng.add_request(p, sp) for p in prompts[2:5])
+    yield
+    yield
+
+
+def _two_waves_before_a_burst(eng, prompts, sp, ids, cancel=False):
+    """Under prefill priority a step whose wave leaves a prompt unfinished
+    skips its burst: the next burst takes the rows of two waves.  With
+    ``cancel`` the first wave's request goes before that burst is built."""
+    ids.append(eng.add_request(prompts[0], sp))
+    yield
+    yield
+    yield
+    ids.extend(eng.add_request(p, sp) for p in prompts[1:3])  # one chunk, two chunks
+    yield
+    assert eng._pending_first, "the first wave did not wait for a burst"
+    if cancel:
+        eng.cancel(ids[1])
+    yield
+    yield
+
+
+@pytest.mark.parametrize("options,script,cancelled", [
+    pytest.param({}, _join_one_then_several, None, id="one-row-then-several"),
+    pytest.param(dict(prefill_priority=True), _two_waves_before_a_burst, None,
+                 id="two-waves-before-one-burst"),
+    pytest.param(dict(prefill_priority=True),
+                 lambda *a: _two_waves_before_a_burst(*a, cancel=True), 1,
+                 id="cancelled-before-the-burst"),
+])
+def test_a_step_is_a_wave_and_a_burst_and_no_eager_op(tiny, options, script, cancelled):
+    """Once warm, steps in which prefill waves join running rows apply no
+    primitive eagerly and dispatch, after admission's presence helpers, at
+    most the wave program and the burst program; greedy tokens are those of
+    a request-at-a-time ``generate``."""
+    from tests.helpers.step_programs import assert_two_programs_a_step, run_recorded
+
+    _, params, cfg = tiny
+    rng = np.random.default_rng(30)
+    lens = [9, 7, 12, 40, 5] if cancelled is None and not options else [9, 7, 40]
+    prompts = [rng.integers(2, cfg.vocab_size, size=n).tolist() for n in lens]
+    sp = SamplingParams(max_tokens=24, temperature=0.0, stop_token_ids=())
+
+    def engine():
+        return _make_engine(params, cfg, decode_burst=4, **options)
+
+    rehearsal = engine()
+    run_recorded(rehearsal, script(rehearsal, prompts, sp, []), warm=False)
+    eng, ids = engine(), []
+    done, steps = run_recorded(eng, script(eng, prompts, sp, ids))
+    assert_two_programs_a_step(steps)
+    alone = engine()
+    for i, (rid, prompt) in enumerate(zip(ids, prompts)):
+        if i == cancelled:
+            assert done[rid].finish_reason == "cancelled"
+        else:
+            assert done[rid].output_tokens == alone.generate([prompt], sp)[0].output_tokens
+
+
+def test_a_burst_says_whether_the_device_had_drained(tiny, monkeypatch):
+    """``ahead`` on the burst's annotation, the engine's two counters and
+    the Prometheus counter: 0 for a burst dispatched after a forced drain
+    (nothing queued on the device), 1 for one dispatched while a program the
+    device has not finished is still ahead of it."""
+    import githubrepostorag_tpu.serving.engine as engine_mod
+    from githubrepostorag_tpu.metrics import BURST_DISPATCH
+
+    _, params, cfg = tiny
+    bursts, real = [], engine_mod.annotate
+
+    def annotate(name, **meta):
+        if name == "engine.decode_burst":
+            bursts.append(meta)
+        return real(name, **meta)
+
+    monkeypatch.setattr(engine_mod, "annotate", annotate)
+    counted = lambda: (eng.bursts_ahead, eng.bursts_starved,  # noqa: E731
+                       BURST_DISPATCH.labels(ahead="1")._value.get(),
+                       BURST_DISPATCH.labels(ahead="0")._value.get())
+    eng = _make_engine(params, cfg, decode_burst=4)
+    eng.add_request([5, 6, 7, 8], SamplingParams(max_tokens=64, temperature=0.0,
+                                                 stop_token_ids=()))
+    eng.step()
+    eng.step()
+    eng._drain_chain([])  # the forced drain: the last burst's tokens are on the host
+    assert eng._presence.is_ready()
+    a1, s0, p1, p0 = counted()
+    eng.step()
+    assert bursts[-1]["ahead"] == 0 and counted() == (a1, s0 + 1, p1, p0 + 1)
+
+    @jax.jit
+    def held_back(presence, x):  # presence, once a long product has run
+        y = jax.lax.fori_loop(0, 400, lambda i, a: jnp.tanh(a @ a), x)
+        return presence ^ (y[0, 0] > 2.0)
+
+    eng._presence = held_back(eng._presence, jnp.full((384, 384), 0.01))
+    assert not eng._presence.is_ready()  # the device is busy: the probe does not wait for it
+    eng.step()
+    assert bursts[-1]["ahead"] == 1 and counted() == (a1 + 1, s0 + 1, p1 + 1, p0 + 1)
+    while eng.has_work():
+        eng.step()
+
+
 def test_prefill_priority_same_outputs(tiny):
     """prefill_priority is a SCHEDULING change only: a wave of requests
     admitted together produces the same tokens as the co-dispatched

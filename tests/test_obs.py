@@ -374,7 +374,10 @@ def test_discover_jits_finds_the_serving_programs():
     names = {name for name, _ in jits}  # both model families' step programs are watched
     assert {"githubrepostorag_tpu.serving.decode_burst.decode_burst",
             "githubrepostorag_tpu.models.deepseek_v3.decode_burst",
-            "githubrepostorag_tpu.models.deepseek_v3.forward_paged"} <= names
+            "githubrepostorag_tpu.models.deepseek_v3.forward_paged",
+            # the two programs a step dispatches: the prefill wave of each family
+            "githubrepostorag_tpu.models.qwen2.forward_paged_wave",
+            "githubrepostorag_tpu.models.deepseek_v3.forward_paged_wave"} <= names
 
 
 # ------------------------------------------------- full stack over a bus ---

@@ -264,7 +264,7 @@ def test_burst_pallas_matches_gather_path():
         rng2 = np.random.default_rng(42)
         k_init = jnp.asarray(rng2.standard_normal(pools.k.shape), dtype=jnp.float32)
         v_init = jnp.asarray(rng2.standard_normal(pools.v.shape), dtype=jnp.float32)
-        toks, valid, k_out, v_out, _, out_lens = decode_burst(
+        toks, valid, k_out, v_out, _, out_lens, _ = decode_burst(
             params, cfg,
             jnp.asarray(last), jnp.asarray(seq_lens),
             k_init, v_init,
@@ -275,6 +275,8 @@ def test_burst_pallas_matches_gather_path():
             jnp.zeros((b,)), jnp.ones((b,)), jnp.zeros((b,), jnp.int32),
             jnp.ones((b,)),
             n_steps=n_steps, use_pallas=use_pallas,
+            first_tokens=jnp.zeros((b,), jnp.int32), fresh=np.zeros((b,), bool),
+            fresh_lens=np.zeros((b,), np.int32), key_step=np.uint32(0),
         )
         outs[use_pallas] = (np.asarray(toks), np.asarray(valid),
                             np.asarray(k_out), np.asarray(v_out),

@@ -125,6 +125,32 @@ async def test_in_process_stream_stamps_its_own_receipt():
     assert aeng.request_ring[-1]["timings"] is t
 
 
+async def test_jobs_that_hold_every_pool_thread_can_still_submit():
+    """Agent jobs run in the loop's default executor and call the engine from
+    their threads (``worker.py``, ``llm/inprocess.py``): a submission that
+    itself needed a thread of that pool would wait for ever once as many jobs
+    run as the pool has threads."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    loop = asyncio.get_running_loop()
+    pool = ThreadPoolExecutor(max_workers=2)
+    loop.set_default_executor(pool)
+    aeng = AsyncEngine(_engine())
+    await aeng.start()
+
+    def job(i):
+        call = asyncio.run_coroutine_threadsafe(aeng.generate(list(range(3 + i, 24 + i))), loop)
+        return call.result(60)
+
+    try:
+        results = await asyncio.wait_for(
+            asyncio.gather(*(loop.run_in_executor(None, job, i) for i in range(2))), 90)
+    finally:
+        await aeng.stop()
+        pool.shutdown(wait=False)
+    assert all(r.output_tokens and r.finish_reason in ("stop", "length") for r in results)
+
+
 async def test_a_slow_step_names_the_phase_that_held_it(monkeypatch, caplog):
     """Above ``SLOW_STEP_S`` the driver warns with the step's ledger record and
     the engine's host seconds by phase (a stall names itself in any run)."""
@@ -236,6 +262,8 @@ def test_dispatch_metadata_equals_what_a_probe_counted(traced_steps):
     bursts = [(ev[3]["rows"], ev[3]["kv_tokens"]) for ev in events
               if ev[0] == "engine.decode_burst"]
     assert bursts and all(ev[3]["steps"] == 8 for ev in events if ev[0] == "engine.decode_burst")
+    # and whether the device still had work queued when the step's programs went out
+    assert all(ev[3]["ahead"] in (0, 1) for ev in events if ev[0] == "engine.decode_burst")
     # the Probe also counts the calls that land the in-flight burst without a
     # dispatch; the annotation is written by dispatches alone
     counted = [(rows, kv) for _, rows, kv in probe.bursts]
@@ -334,7 +362,8 @@ def test_named_scopes_reach_the_step_programs():
         eng.params, eng.cfg, z(b), z(b), eng._k_pages, eng._v_pages, eng._presence,
         jnp.ones((b,), bool), z(b), z(b, eng.max_pages_per_seq), jax.random.PRNGKey(0),
         jnp.ones((b,)), jnp.ones((b,)), z(b), jnp.ones((b,)), n_steps=8,
-        use_pallas=False).as_text(debug_info=True)
+        use_pallas=False, first_tokens=z(b), fresh=jnp.zeros((b,), bool), fresh_lens=z(b),
+        key_step=jnp.uint32(0)).as_text(debug_info=True)
     for scope in ("paged_attention", "mlp", "attn_proj", "sample", "kv_write"):
         assert f"/{scope}/" in prefill or f"{scope}/" in prefill, scope
         assert f"/{scope}/" in burst or f"{scope}/" in burst, scope

@@ -170,6 +170,20 @@ def pool_movers(hlo: str, pool_shape: tuple) -> list:
     return found
 
 
+def assert_wave_keeps_in_place(hlo: str, held: str, count: int) -> None:
+    """The compiled module is ``jit_forward_paged_wave`` and each of its
+    ``count`` entry parameters whose type matches ``held`` (the pools, the
+    presence mask) is aliased to an output: donated, and updated in place."""
+    head = hlo.split("\n", 1)[0]
+    assert re.match(r"HloModule jit_forward_paged_wave\b", head), head
+    aliases = head.split("input_output_alias=")[1].split("entry_computation_layout")[0]
+    aliased = {int(p) for p in re.findall(r"\(\s*(\d+), \{\}", aliases)}
+    params = {int(re.search(r"parameter\((\d+)\)", line).group(1))
+              for line in hlo.split("ENTRY", 1)[1].splitlines()
+              if " parameter(" in line and re.search(held, line)}
+    assert len(params) == count and params <= aliased, (params, aliased)
+
+
 @pytest.fixture()
 def as_on_chip(monkeypatch):
     """The program asks runtime.on_tpu() whether its kernels run compiled or
@@ -183,15 +197,21 @@ def as_on_chip(monkeypatch):
 
 
 def _cell_program(where, program: str, kv: str, variant):
-    """decode_burst (variant: filter_sampling) or forward_paged (variant:
-    rows of 512 new tokens) lowered at the cell's shapes -> (lowered, shape
-    of the pool a device holds).  ``where`` is one described chip's sharding
+    """decode_burst (variant: filter_sampling; with the overlay of fresh rows,
+    as the engine calls it), forward_paged or the engine's forward_paged_wave
+    (variant: rows of 512 new tokens) lowered at the cell's shapes ->
+    (lowered, shape of the pool a device holds).  ``where`` is one described chip's sharding
     (the cell's own int8 weights) or a tp mesh (bf16 weights sharded as the
     engine shards them, the pools over kv heads, everything else replicated)."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from githubrepostorag_tpu.models.quant import init_params_quantized
-    from githubrepostorag_tpu.models.qwen2 import Qwen2Config, forward_paged, init_params
+    from githubrepostorag_tpu.models.qwen2 import (
+        Qwen2Config,
+        forward_paged,
+        forward_paged_wave,
+        init_params,
+    )
     from githubrepostorag_tpu.parallel.sharding import qwen2_param_specs
     from githubrepostorag_tpu.serving.decode_burst import decode_burst
 
@@ -230,6 +250,18 @@ def _cell_program(where, program: str, kv: str, variant):
             sds((b, CELL_ROW_PAGES), i32), sds((2,), jnp.uint32), sds((b,), f32),
             sds((b,), f32), sds((b,), i32), sds((b,), f32),
             n_steps=8, use_pallas=True, filter_sampling=variant, mesh=mesh, **scales,
+            first_tokens=sds((b,), i32), fresh=sds((b,), jnp.bool_),
+            fresh_lens=sds((b,), i32), key_step=sds((), jnp.uint32),
+        )
+    elif program == "wave":
+        chunk, rows = (variant, 512), (variant,)
+        lowered = forward_paged_wave.lower(
+            params, cfg, sds(chunk, i32), sds(chunk, i32), pool, pool,
+            sds((b, cfg.vocab_size), jnp.bool_), sds((b,), i32), sds(chunk, i32),
+            sds((variant, CELL_ROW_PAGES), i32), sds(rows, i32), sds(rows, i32),
+            sds(rows, i32), sds(rows, i32), sds(rows, jnp.bool_), sds((2,), jnp.uint32),
+            sds((), jnp.uint32), sds((b,), f32), sds((b,), f32), sds((b,), i32), sds((b,), f32),
+            use_pallas=True, mesh=mesh, **scales,
         )
     else:
         chunk = (variant, 512)
@@ -252,6 +284,9 @@ POOL_CASES = [
     pytest.param("burst", "fp", False, id="burst-nofilter-fp"),
     pytest.param("prefill", "fp", 1, id="prefill-1x512-fp"),
     pytest.param("prefill", "fp", 2, id="prefill-2x512-fp"),
+    pytest.param("wave", "fp", 1, id="wave-1x512-fp"),
+    pytest.param("wave", "fp", 4, id="wave-4x512-fp"),
+    pytest.param("wave", "int8", 1, id="wave-1x512-int8"),
     pytest.param("burst", "int8", False, id="burst-nofilter-int8"),
     pytest.param("prefill", "int8", 1, id="prefill-1x512-int8"),
     pytest.param("burst", "int4", False, id="burst-nofilter-int4", marks=_INT4),
@@ -267,6 +302,17 @@ def test_step_program_leaves_the_pools_in_place(chip, as_on_chip, program, kv, v
     assert pool_movers(hlo, pool_shape) == []
 
 
+def test_the_wave_is_one_program_that_donates_pools_and_presence(chip, as_on_chip):
+    """The engine's prefill wave, first-token tail included: the readers of
+    the benchmark find it by ``forward_paged`` in its module's name, and the
+    three buffers it is handed to keep (K pool, V pool, the presence mask)
+    come back in place.  (That nothing in it moves a pool is a case of
+    test_step_program_leaves_the_pools_in_place.)"""
+    lowered, _ = _cell_program(chip, "wave", "fp", 2)
+    assert_wave_keeps_in_place(lowered.compile().as_text(),
+                               r"bf16\[28,4,384,128,128\]|pred\[32,152064\]", 3)
+
+
 @pytest.mark.parametrize("program,variant", [("burst", False), ("prefill", 1)],
                          ids=["burst-nofilter-fp", "prefill-1x512-fp"])
 def test_step_program_leaves_sharded_pools_in_place(tp4, as_on_chip, program, variant):
@@ -279,3 +325,33 @@ def test_step_program_leaves_sharded_pools_in_place(tp4, as_on_chip, program, va
     assert "tpu_custom_call" in hlo
     whole = (held[0], held[1] * tp4.shape["tp"], *held[2:])
     assert pool_movers(hlo, held) + pool_movers(hlo, whole) == []
+
+
+def test_the_burst_has_one_shape_whatever_joins_it():
+    """On the CPU, at a tiny size: no row, one row, three rows of one wave and
+    rows of two waves joining a burst call the same program with the same
+    shapes (the masks of fresh rows are [max_num_seqs] wide), so nothing but
+    the wave and the burst is ever compiled for a step."""
+    from githubrepostorag_tpu.models.qwen2 import Qwen2Config, init_params
+    from githubrepostorag_tpu.serving import Engine, SamplingParams
+    from githubrepostorag_tpu.serving.decode_burst import decode_burst
+    from tests.helpers.step_programs import burst_call_shapes
+
+    cfg = Qwen2Config.tiny()
+    eng = Engine(init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32), cfg, max_num_seqs=8,
+                 num_pages=128, page_size=4, max_seq_len=96, prefill_chunk=16,
+                 kv_dtype=jnp.float32, decode_burst=4, prefill_priority=True)
+    sp = SamplingParams(max_tokens=30, temperature=0.0, stop_token_ids=())
+    shapes = burst_call_shapes(eng)
+    joined = []
+    for wave in ([[1, 2, 3]], [[4, 5]], [[6, 7, 8], [9, 10], [11]], [[12, 13], list(range(2, 40))]):
+        for prompt in wave:
+            eng.add_request(prompt, sp)
+        for _ in range(3):
+            eng.step()
+            joined.append(len(eng._chain["first"]) if eng._chain else 0)
+    while eng.has_work():
+        eng.step()
+    assert {0, 1, 2} <= set(joined)  # bursts with no wave, one wave and two waves of rows
+    assert len(shapes) >= 12 and len(set(shapes)) == 1
+    assert decode_burst._cache_size() == 1
