@@ -44,7 +44,6 @@ def _build_llm():
             page_size=s.kv_page_size,
             max_seq_len=s.context_window,
             prefill_chunk=s.prefill_chunk,
-            prefill_widths=s.prefill_widths,
             kv_quant=s.kv_quant,
             use_pallas=on_tpu(),
             preempt=s.preempt,

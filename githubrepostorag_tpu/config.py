@@ -378,17 +378,12 @@ class Settings:
     kv_num_pages: int = field(default_factory=lambda: _env_int("KV_NUM_PAGES", 256))
     max_num_seqs: int = field(default_factory=lambda: _env_int("MAX_NUM_SEQS", 64))
     prefill_chunk: int = field(default_factory=lambda: _env_int("PREFILL_CHUNK", 512))
-    # number of power-of-two prefill dispatch widths (chunk, chunk/2, ...)
-    # warmed and used; >1 stops short prompts paying full-chunk prefill
-    # FLOPs as padding (serving/engine.py prefill_widths)
-    prefill_widths: int = field(
-        default_factory=lambda: _env_int("PREFILL_WIDTHS", 1)
-    )
     # >0: token-budget PACKED prefill — every prefilling row's next chunk
     # packs into one [budget] buffer with segment-ID attention instead of
     # the padded [row_bucket, width] dispatch; prefill FLOPs scale with
-    # real tokens on heterogeneous prompt-heavy waves and PREFILL_WIDTHS
-    # is ignored (serving/engine.py prefill_token_budget).  0 = padded.
+    # real tokens on heterogeneous prompt-heavy waves
+    # (serving/engine.py prefill_token_budget).  0 = padded, whose waves
+    # run at chunk, chunk/2 or chunk/4 columns by their longest row.
     prefill_token_budget: int = field(
         default_factory=lambda: _env_int("PREFILL_TOKEN_BUDGET", 0)
     )

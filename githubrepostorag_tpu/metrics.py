@@ -501,6 +501,14 @@ BURST_DISPATCH = Counter(
     ["ahead"],
     registry=REGISTRY,
 )
+PREFILL_WAVE = Counter(
+    "rag_engine_prefill_wave_total",
+    "Padded prefill waves dispatched, by the columns a row the wave program "
+    "ran at: the narrowest rung of the width ladder that held the wave's "
+    "longest pending chunk",
+    ["width"],
+    registry=REGISTRY,
+)
 MOE_EXPERTS_HIT = Counter(
     "rag_moe_experts_hit_total",
     "Held experts that received a token, summed over expert layers and steps "

@@ -44,6 +44,7 @@ from githubrepostorag_tpu.ops.latent_attention import (
     latent_prefill_attention,
 )
 from githubrepostorag_tpu.ops.norms import rms_norm
+from githubrepostorag_tpu.ops.prefill_width import at_wave_width, layer_weights
 from githubrepostorag_tpu.ops.rope import rope_cos_sin, rope_rotate, yarn_inv_freq, yarn_mscale
 from githubrepostorag_tpu.ops.sampling import (
     first_token_tail,
@@ -290,34 +291,51 @@ def _layer(cfg, p, h, cos, sin, attend, ffn):
     return h + y, carry, stats
 
 
-def _run_layers(cfg, params, h, cos, sin, make_attend, carry, live):
-    """Both stacks in order.  ``make_attend(p, layer index, carry)`` builds a
-    layer's ``attend``; ``carry`` (the pool, or the staged rows) threads
-    through every layer; ``live`` [B, S] marks the real tokens.  Returns (h,
-    carry, [experts hit, expert tokens])."""
+def _run_layers(cfg, params, cols, make_attend, carry, width=None, page_size=0):
+    """Both stacks in order.  ``cols`` = (h, cos, sin, live, ...): what runs
+    along the chunk, ``live`` [B, S] marking the real tokens, anything after
+    it handed on to ``make_attend(p, layer index, carry, ...)``, which builds
+    a layer's ``attend``; ``carry`` (the pool, or the staged rows) threads
+    through every layer.  ``width`` (the prefill wave's) runs every layer at
+    the narrowest rung that holds it (ops/prefill_width.at_wave_width).
+    Returns (h, carry, [experts hit, expert tokens])."""
     zero = jnp.zeros((2,), jnp.int32)
+    h, *cols = cols
 
-    def dense_body(c, p):
-        h, li, carry = c
-        h, carry, _ = _layer(cfg, p, h, cos, sin, make_attend(p, li, carry),
-                             lambda x: (_swiglu(x, p["wgu"], p["wd"]), zero))
-        return (h, li + 1, carry), None
+    def run(stack, ffn_of, c, first):
+        """One stack's scan; its layers index their own weights where a
+        ``width`` puts them in a branch (layer_weights): no xs then."""
+        def body(c, p_xs):
+            h, li, carry, stats = c
 
-    li = jnp.int32(0)
+            def layer(cols, carry):
+                h, cos, sin, live, *rest = cols
+                p = layer_weights(p_xs, stack, li - first)
+                h, carry, st = _layer(cfg, p, h, cos, sin, make_attend(p, li, carry, *rest),
+                                      ffn_of(p, li - first, live))
+                return h, (carry, st)
+
+            h, (carry, st) = at_wave_width(layer, width, page_size, (h, *cols), carry)
+            return (h, li + 1, carry, stats + st), None
+
+        n = jax.tree.leaves(stack)[0].shape[0]
+        xs, n = (stack, None) if width is None else (None, n)
+        return jax.lax.scan(body, c, xs, length=n)[0]
+
+    def dense_ffn(p, li, live):
+        return lambda x: (_swiglu(x, p["wgu"], p["wd"]), zero)
+
+    c = (h, jnp.int32(0), carry, zero)
     if cfg.first_k_dense:
-        (h, li, carry), _ = jax.lax.scan(dense_body, (h, li, carry), params["dense"])
-    stats = zero
+        c = run(params["dense"], dense_ffn, c, 0)
     if cfg.num_layers > cfg.first_k_dense:
         scan_moe, experts = _split_experts(params["moe"])
 
-        def moe_body(c, p):
-            h, li, carry, stats = c
-            h, carry, st = _layer(
-                cfg, p, h, cos, sin, make_attend(p, li, carry),
-                lambda x: _moe_ffn(cfg, p, experts, li - cfg.first_k_dense, x, live))
-            return (h, li + 1, carry, stats + st), None
+        def moe_ffn(p, li, live):
+            return lambda x: _moe_ffn(cfg, p, experts, li, x, live)
 
-        (h, _, carry, stats), _ = jax.lax.scan(moe_body, (h, li, carry, stats), scan_moe)
+        c = run(scan_moe, moe_ffn, c, cfg.first_k_dense)
+    h, _, carry, stats = c
     return h, carry, stats
 
 
@@ -369,6 +387,7 @@ def forward_paged_wave(
     logits_at: jnp.ndarray,
     row_idx: jnp.ndarray,
     done_mask: jnp.ndarray,
+    width: jnp.ndarray,
     rng: jax.Array,
     key_step: jnp.ndarray,
     temperature: jnp.ndarray,
@@ -379,11 +398,12 @@ def forward_paged_wave(
     k_scales=None, v_scales=None, int4_kernel: bool = True, mesh=None,
 ):
     """The engine's prefill wave as one program, qwen2.forward_paged_wave's
-    contract: the chunk, then the first-token tail every family shares.
+    contract: the chunk, every layer at the narrowest width that holds
+    ``width`` columns, then the first-token tail every family shares.
     Returns (first_tokens, presence, pool, None, stats [2])."""
     logits, *cache = forward_paged_impl(params, cfg, input_ids, positions, k_pages,
                                         slot_mapping, block_tables, cached_lens, new_lens,
-                                        use_pallas, logits_at)
+                                        use_pallas, logits_at, width)
     with jax.named_scope("sample"):
         first_tokens, presence = first_token_tail(
             logits[:, 0], presence, first_tokens, input_ids, new_lens, row_idx, done_mask,
@@ -392,21 +412,21 @@ def forward_paged_wave(
 
 
 def forward_paged_impl(params, cfg, input_ids, positions, k_pages, slot_mapping, block_tables,
-                       cached_lens, new_lens, use_pallas=False, logits_at=None):
-    """Unjitted body of ``forward_paged``, traced into the wave program too."""
+                       cached_lens, new_lens, use_pallas=False, logits_at=None, width=None):
+    """Unjitted body of ``forward_paged``, traced into the wave program too
+    (``width``: the wave's, see ops/prefill_width.at_wave_width)."""
     from githubrepostorag_tpu.serving.kv_cache import commit_paged
 
     num_pages, page_size = k_pages.shape[2], k_pages.shape[3]
     h = embedding_lookup(params["embed"], input_ids).astype(jnp.float32)
     cos, sin = _rope_tables(cfg, positions)
-    flat_slots = slot_mapping.reshape(-1)
-    flat_slots = jnp.where(flat_slots < 0, num_pages * page_size, flat_slots)  # dropped
+    slots = jnp.where(slot_mapping < 0, num_pages * page_size, slot_mapping)  # dropped
 
-    def make_attend(p, li, pool):
+    def make_attend(p, li, pool, slots):
         def attend(q_nope, q_rope, latent):
             with jax.named_scope("latent_write"):
                 pool2, _ = commit_paged(pool, latent.reshape(1, -1, latent.shape[-1]),
-                                        flat_slots, None, page_size, layer=li)
+                                        slots.reshape(-1), None, page_size, layer=li)
             with jax.named_scope("latent_prefill_attention"):
                 out = latent_prefill_attention(
                     q_nope, q_rope, pool2, li, block_tables, cached_lens, new_lens,
@@ -416,7 +436,8 @@ def forward_paged_impl(params, cfg, input_ids, positions, k_pages, slot_mapping,
         return attend
 
     live = jnp.arange(input_ids.shape[1])[None, :] < new_lens[:, None]
-    h, k_pages, stats = _run_layers(cfg, params, h, cos, sin, make_attend, k_pages, live)
+    h, k_pages, stats = _run_layers(cfg, params, (h, cos, sin, live, slots), make_attend,
+                                    k_pages, width, page_size)
     with jax.named_scope("sample"):
         h = rms_norm(h, params["norm"], cfg.rms_norm_eps).astype(ACT)
         if logits_at is not None:
@@ -497,8 +518,8 @@ def decode_burst(
                 return o.reshape(b, 1, -1), staged2
             return attend
 
-        h, staged, st = _run_layers(cfg, params, h, cos, sin, make_attend, staged,
-                                    act[:, None])
+        h, staged, st = _run_layers(cfg, params, (h, cos, sin, act[:, None]), make_attend,
+                                    staged)
         with jax.named_scope("sample"):
             logits = _head(params, rms_norm(h, params["norm"], cfg.rms_norm_eps).astype(ACT))
             if filter_sampling:

@@ -32,6 +32,7 @@ from githubrepostorag_tpu.models.quant import (
 )
 from githubrepostorag_tpu.ops.attention import dense_attention
 from githubrepostorag_tpu.ops.norms import rms_norm
+from githubrepostorag_tpu.ops.prefill_width import at_wave_width, layer_weights
 from githubrepostorag_tpu.ops.rope import apply_rope, rope_cos_sin
 from githubrepostorag_tpu.ops.sampling import first_token_tail
 
@@ -473,6 +474,7 @@ def forward_paged_wave(
     logits_at: jnp.ndarray,  # [R] each row's last valid position
     row_idx: jnp.ndarray,  # [R] engine row of each wave row
     done_mask: jnp.ndarray,  # [R] bool: the chunk completes the row's prompt
+    width: jnp.ndarray,  # scalar: columns the wave's longest chunk needs
     rng: jax.Array,  # the engine's base key; ``key_step`` is folded in here
     key_step: jnp.ndarray,  # scalar: the engine's dispatch counter
     temperature: jnp.ndarray,  # [rows] per engine row, like the burst's
@@ -486,7 +488,9 @@ def forward_paged_wave(
     mesh=None,
 ):
     """The engine's prefill wave as ONE program: ``forward_paged``'s chunk,
-    then ``ops/sampling.first_token_tail`` on its logits (prompt tokens into
+    every layer at the narrowest width that holds ``width`` columns
+    (``ops/prefill_width.at_wave_width``), then
+    ``ops/sampling.first_token_tail`` on its logits (prompt tokens into
     ``presence``, the first token of every completed row drawn, marked and
     scattered into ``first_tokens``).  Every input but the pools, ``presence``,
     ``first_tokens`` and ``rng`` is a host array, so the host dispatches it
@@ -497,7 +501,7 @@ def forward_paged_wave(
         params, cfg, input_ids, positions, k_pages, v_pages,
         slot_mapping, block_tables, cached_lens, new_lens, use_pallas,
         logits_at=logits_at, k_scales=k_scales, v_scales=v_scales,
-        int4_kernel=int4_kernel, mesh=mesh,
+        int4_kernel=int4_kernel, mesh=mesh, width=width,
     )
     with jax.named_scope("sample"):
         first_tokens, presence = first_token_tail(
@@ -524,6 +528,7 @@ def forward_paged_impl(
     v_scales: jnp.ndarray | None = None,
     int4_kernel: bool = True,
     mesh=None,
+    width: jnp.ndarray | None = None,  # the wave program's: see at_wave_width
 ):
     """Unjitted body of ``forward_paged`` so larger fused programs (the
     multi-step decode burst in serving/decode_burst.py) can inline it inside
@@ -558,8 +563,7 @@ def forward_paged_impl(
     # Padding slots arrive as -1; JAX scatter *wraps* negative indices (it
     # only drops indices >= size), so map them to an out-of-range positive
     # sentinel that mode="drop" actually drops.
-    flat_slots = slot_mapping.reshape(-1)  # [B*S]
-    flat_slots = jnp.where(flat_slots < 0, total_slots, flat_slots)
+    slots = jnp.where(slot_mapping < 0, total_slots, slot_mapping)  # [B, S]
 
     scan_layers, q4_stacks = _split_q4(params["layers"])
 
@@ -570,42 +574,52 @@ def forward_paged_impl(
     # written into a second stacked pool that two whole-pool copies then
     # reconcile with the donated one: 44% of a 512-token chunk's device
     # time at Qwen2-7B widths (PERF.md, Findings, PR 25).
-    def body(carry, p):
-        h, li, kp, vp, ks, vs = carry
-        # prefill / spec-verify chunks pin w4a8=False: prompt processing
-        # keeps the exact bf16-dequant contract even when the chunk is
-        # decode-sized (the auto gate must never catch a prefill batch)
-        p = _with_layered_q4(p, q4_stacks, li, kernel=int4_kernel, w4a8=False)
+    def body(carry, p_xs):
+        h, li, *pools = carry
 
-        def attend(q, k, v):
-            from githubrepostorag_tpu.serving.kv_cache import commit_paged
+        def layer(cols, pools):
+            h, cos, sin, slots = cols
+            kp, vp, ks, vs = pools
+            flat_slots = slots.reshape(-1)  # [B*S]
+            # prefill / spec-verify chunks pin w4a8=False: prompt processing
+            # keeps the exact bf16-dequant contract even when the chunk is
+            # decode-sized (the auto gate must never catch a prefill batch)
+            p = _with_layered_q4(layer_weights(p_xs, scan_layers, li), q4_stacks, li,
+                                 kernel=int4_kernel, w4a8=False)
 
-            k_t = k.reshape(-1, nkv, hd).swapaxes(0, 1)  # [n_kv, B*S, hd]
-            v_t = v.reshape(-1, nkv, hd).swapaxes(0, 1)
-            # commit_paged is THE shared pool-commit rule (cast for bf16
-            # pools; per-page first-write scales for int8 — same semantics
-            # as the burst and ring-prefill commits)
-            with jax.named_scope("kv_write"):
-                new_kp, new_ks = commit_paged(kp, k_t, flat_slots, ks, page_size, layer=li)
-                new_vp, new_vs = commit_paged(vp, v_t, flat_slots, vs, page_size, layer=li)
-            with jax.named_scope("paged_attention"):
-                scales = (new_ks, new_vs) if quant else ()
-                if use_pallas:
-                    attn = attn_fn(q, new_kp, new_vp, block_tables, cached_lens,
-                                   new_lens, li, *scales)
-                else:
-                    # the CPU/oracle path reads one layer's slab
-                    attn = attn_fn(
-                        q, new_kp[li], new_vp[li], block_tables, cached_lens,
-                        new_lens, *(sc[li] for sc in scales),
-                    )
-            return attn, (new_kp, new_vp, new_ks, new_vs)
+            def attend(q, k, v):
+                from githubrepostorag_tpu.serving.kv_cache import commit_paged
 
-        h, cache = _block(cfg, h, p, cos, sin, attend)
-        return (h, li + 1, *cache), None
+                k_t = k.reshape(-1, nkv, hd).swapaxes(0, 1)  # [n_kv, B*S, hd]
+                v_t = v.reshape(-1, nkv, hd).swapaxes(0, 1)
+                # commit_paged is THE shared pool-commit rule (cast for bf16
+                # pools; per-page first-write scales for int8 — same semantics
+                # as the burst and ring-prefill commits)
+                with jax.named_scope("kv_write"):
+                    new_kp, new_ks = commit_paged(kp, k_t, flat_slots, ks, page_size, layer=li)
+                    new_vp, new_vs = commit_paged(vp, v_t, flat_slots, vs, page_size, layer=li)
+                with jax.named_scope("paged_attention"):
+                    scales = (new_ks, new_vs) if quant else ()
+                    if use_pallas:
+                        attn = attn_fn(q, new_kp, new_vp, block_tables, cached_lens,
+                                       new_lens, li, *scales)
+                    else:
+                        # the CPU/oracle path reads one layer's slab
+                        attn = attn_fn(
+                            q, new_kp[li], new_vp[li], block_tables, cached_lens,
+                            new_lens, *(sc[li] for sc in scales),
+                        )
+                return attn, (new_kp, new_vp, new_ks, new_vs)
 
+            return _block(cfg, h, p, cos, sin, attend)
+
+        h, pools = at_wave_width(layer, width, page_size, (h, cos, sin, slots), tuple(pools))
+        return (h, li + 1, *pools), None
+
+    # the wave's layers index their own weights (layer_weights): no xs
+    xs, n = (scan_layers, None) if width is None else (None, cfg.num_layers)
     (h, _, k_pages, v_pages, k_scales, v_scales), _ = jax.lax.scan(
-        body, (h, 0, k_pages, v_pages, k_scales, v_scales), scan_layers
+        body, (h, 0, k_pages, v_pages, k_scales, v_scales), xs, length=n
     )
     with jax.named_scope("sample"):  # the head; the first token's draw is the engine's
         h = rms_norm(h, params["norm"], cfg.rms_norm_eps)

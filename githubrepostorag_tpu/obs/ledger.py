@@ -68,6 +68,7 @@ SNAPSHOT_FIELDS = (
     "transfer_seconds_total",
     "fused_steps_total", "step_dispatches_total",
     "bursts_ahead", "bursts_starved",
+    "prefill_padded_tokens",
 )
 
 
@@ -175,6 +176,9 @@ class TokenLedger:
                 # queued, and bursts it had drained and waited for
                 "bursts_ahead": max(0.0, d["bursts_ahead"]),
                 "bursts_starved": max(0.0, d["bursts_starved"]),
+                # columns the padded prefill waves multiplied (row bucket x
+                # width), to set against prefill_tokens' real ones
+                "prefill_padded_tokens": max(0.0, d["prefill_padded_tokens"]),
             }
             if compiles > 0:
                 # kv_transfer stays out of ``measured``: it is inter-step
@@ -358,5 +362,7 @@ class TokenLedger:
                         if s.get("steps", 0.0) else 0.0, 6),
                     "bursts_ahead": int(s.get("bursts_ahead", 0.0)),
                     "bursts_starved": int(s.get("bursts_starved", 0.0)),
+                    "prefill_tokens": int(s.get("prefill_tokens", 0.0)),
+                    "prefill_padded_tokens": int(s.get("prefill_padded_tokens", 0.0)),
                 },
             }

@@ -262,9 +262,13 @@ def _prefill_kernel(*refs, page_size: int, rank: int, rope: int, pps: int):
         out_ref[0, 0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(out_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def _prefill_pallas(qn, qr, pool, layer, block_tables, cached_lens, kv_lens, w_uk, w_uv,
                     interpret: bool):
-    """qn [B, H, S, nope], qr [B, H, S, rope] (scaled) -> [B, H, S, v]."""
+    """qn [B, H, S, nope], qr [B, H, S, rope] (scaled) -> [B, H, S, v].
+    Jitted for its trace cache alone (it is only ever called inside a step
+    program): the dense and the expert stack call it with the same shapes,
+    and tracing the call is most of what tracing a layer costs."""
     b, h, s, nope = qn.shape
     rope, rank, vd = qr.shape[-1], w_uk.shape[-1], w_uv.shape[-1]
     page_size, width = pool.shape[3], pool.shape[4]
@@ -293,17 +297,20 @@ def _prefill_pallas(qn, qr, pool, layer, block_tables, cached_lens, kv_lens, w_u
         scratch_shapes=[pltpu.VMEM((s, 128), jnp.float32), pltpu.VMEM((s, 128), jnp.float32),
                         pltpu.VMEM((s, vd), jnp.float32)],
     )
-    # tpulint: disable=SHP003 -- built at trace time only: the one caller is the jitted prefill chunk (models/deepseek_v3.py), reached through a closure the linter does not follow
-    return pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_prefill_kernel, page_size=page_size, rank=rank, rope=rope, pps=pps),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s, vd), qn.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), cached_lens.astype(jnp.int32), kv_lens.astype(jnp.int32),
-      jnp.reshape(layer, (1,)).astype(jnp.int32), qn, qr, *([pool] * pps),
-      w_uk.swapaxes(1, 2), w_uv)
+    )
+    # XLA names the custom call after the innermost scope: the name a trace's
+    # reader finds it by, kept under this function's own jit
+    with jax.named_scope("latent_prefill_attention"):
+        return call(block_tables.astype(jnp.int32), cached_lens.astype(jnp.int32),
+                    kv_lens.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
+                    qn, qr, *([pool] * pps), w_uk.swapaxes(1, 2), w_uv)
 
 
 def latent_prefill_attention(

@@ -129,7 +129,6 @@ async def serve(host: str, port: int) -> None:
             page_size=s.kv_page_size,
             max_seq_len=s.context_window,
             prefill_chunk=s.prefill_chunk,
-            prefill_widths=s.prefill_widths,
             prefill_token_budget=s.prefill_token_budget or None,
             use_pallas=pallas,
             kv_quant=s.kv_quant,

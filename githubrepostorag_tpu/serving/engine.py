@@ -52,6 +52,7 @@ from githubrepostorag_tpu.models.qwen2 import (
     forward_paged_wave,
 )
 from githubrepostorag_tpu.ops.packed_prefill import ring_segment_layout
+from githubrepostorag_tpu.ops.prefill_width import width_ladder
 from githubrepostorag_tpu.ops.sampling import mark_presence_chunks, sample_tokens
 from githubrepostorag_tpu.ops.page_migration import (
     gather_pages,
@@ -72,7 +73,7 @@ from githubrepostorag_tpu.serving.kv_cache import (
     slot_mapping,
 )
 from githubrepostorag_tpu.serving.sampling_params import SamplingParams
-from githubrepostorag_tpu.metrics import BURST_DISPATCH
+from githubrepostorag_tpu.metrics import BURST_DISPATCH, PREFILL_WAVE
 from githubrepostorag_tpu.utils.logging import get_logger
 from githubrepostorag_tpu.utils.profiling import annotate
 
@@ -207,17 +208,6 @@ class Engine:
         page_size: int = 16,
         max_seq_len: int = 2048,
         prefill_chunk: int = 512,
-        prefill_widths: int = 1,  # number of power-of-two prefill dispatch
-        # widths to compile and use: 1 = every chunk dispatches at
-        # prefill_chunk (today's single-shape discipline); k>1 adds the
-        # k-1 next-smaller buckets (chunk/2, chunk/4, ...) and each wave
-        # dispatches at the smallest bucket covering its longest pending
-        # chunk.  Short prompts (RAG chat queries are ~100-300 tokens vs
-        # a 256-512 chunk) stop paying the full chunk width in prefill
-        # FLOPs (prompt 128 at chunk 256: half a prefill wave is computed
-        # on padding; not measured on the chip).  warmup() compiles every
-        # (row bucket x width bucket) pair so live traffic stays on
-        # warmed shapes.
         prefill_token_budget: int | None = None,  # token-budget PACKED
         # prefill: flatten every prefilling row's next chunk into one
         # [budget] buffer with per-token segment IDs instead of the
@@ -226,8 +216,9 @@ class Engine:
         # heterogeneous waves (mixed prompt lengths, tail chunks, short
         # uncached suffixes after prefix-cache hits).  Chunks that don't
         # fit the budget split mid-chunk and resume next step.  One
-        # compiled prefill shape per row bucket (the width-bucket zoo
-        # collapses; ``prefill_widths`` is ignored).  None = padded path.
+        # compiled prefill shape per row bucket, as the padded path has
+        # (whose waves follow their longest chunk down the width ladder
+        # inside that program).  None = padded path.
         kv_dtype=jnp.bfloat16,
         kv_quant: bool | int = False,  # quantized KV pages with per-page
         # scales (kv_cache.quantize_kv_paged; scales ride the decode
@@ -385,15 +376,9 @@ class Engine:
         self.max_seq_len = max_seq_len
         self.max_pages_per_seq = pages_needed(max_seq_len, page_size)
         self.prefill_chunk = prefill_chunk
-        # dispatch-width buckets, largest first: [chunk, chunk/2, ...];
-        # never below the page size (slot mappings stay page-aligned and
-        # the marginal FLOP saving below one page is noise)
-        self.prefill_width_buckets = [prefill_chunk]
-        for _ in range(max(1, prefill_widths) - 1):
-            half = self.prefill_width_buckets[-1] // 2
-            if half < max(page_size, 16):
-                break
-            self.prefill_width_buckets.append(half)
+        # the widths a padded wave runs at, largest first: branches of the
+        # one wave program a row bucket has (ops/prefill_width.py)
+        self.prefill_width_buckets = width_ladder(prefill_chunk, page_size)
         if prefill_token_budget is not None and prefill_token_budget < 1:
             raise ValueError("prefill_token_budget must be >= 1 when set")
         self.prefill_token_budget = prefill_token_budget
@@ -672,6 +657,11 @@ class Engine:
         self.bursts_starved = 0
         self._step_starved = False
         self._m_burst = [BURST_DISPATCH.labels(ahead=a) for a in ("0", "1")]
+        # columns the padded prefill waves multiplied (row bucket x width),
+        # against prefill_tokens' real ones, and the waves by width
+        self.prefill_padded_tokens = 0
+        self._m_wave = {w: PREFILL_WAVE.labels(width=str(w))
+                        for w in self.prefill_width_buckets}
         self._waiting: list[_Request] = []
         self._rejected: list[_Request] = []
         self._requests: dict[str, _Request] = {}
@@ -1413,16 +1403,14 @@ class Engine:
             return self._chain is None
         return self._chain is None and not others_running
 
-    def _dispatch_width(self, longest_chunk: int) -> int:
-        """Prefill dispatch width for a wave whose longest pending chunk is
-        ``longest_chunk``: the smallest warmed width bucket covering it.
-        The ONLY width-selection rule — warmup() predicts shapes with the
-        same call, so the two can never desynchronize."""
-        width = self.prefill_chunk
-        for w in self.prefill_width_buckets:  # largest -> smallest
-            if w >= longest_chunk:
-                width = w
-        return width
+    def _dispatch_width(self, longest_chunk: int, rows: int) -> int:
+        """The width a wave of ``rows`` rows (its row bucket) whose longest
+        pending chunk is ``longest_chunk`` runs at: the narrowest rung of
+        the bucket's ladder that holds it.  The ONLY width-selection rule on
+        the host; the wave program is handed the result and cuts its chunk
+        to that rung."""
+        ladder = width_ladder(self.prefill_chunk, self.page_size, rows)
+        return min(w for w in ladder if w >= longest_chunk)
 
     def packed_prefill_buckets(self) -> list[int]:
         """The exact set of segment-count row buckets the packed prefill
@@ -1692,19 +1680,20 @@ class Engine:
         others_running = any(r.state == "running" for r in self._row_req.values())
         n = len(reqs)
         wave_ann = self._phase("engine.prefill_batch")
-        # Shape discipline: row count buckets to powers of two, width comes
-        # from the fixed prefill_width_buckets set (a single value —
-        # prefill_chunk — unless prefill_widths > 1).  Every distinct device
-        # shape is a multi-second XLA compile; steady-state traffic must
-        # only ever see shapes that warmup() has already compiled.
+        # Shape discipline: row count buckets to powers of two and every
+        # array is prefill_chunk wide, so a row bucket is ONE compiled
+        # program (a distinct device shape is a multi-second XLA compile;
+        # steady-state traffic must only ever see shapes that warmup() has
+        # compiled).  The wave's longest chunk picks a rung of the width
+        # ladder; the program is handed it and runs that many columns.
         rb = _bucket(n, self.max_num_seqs, minimum=1)
+        chunk = self.prefill_chunk
         width = self._dispatch_width(
-            max(min(len(r.prompt) - r.prefill_pos, self.prefill_chunk) for r in reqs)
-        )
+            max(min(len(r.prompt) - r.prefill_pos, chunk) for r in reqs), rb)
 
-        ids = np.zeros((rb, width), dtype=np.int32)
-        pos = np.zeros((rb, width), dtype=np.int32)
-        slots = np.full((rb, width), -1, dtype=np.int32)
+        ids = np.zeros((rb, chunk), dtype=np.int32)
+        pos = np.zeros((rb, chunk), dtype=np.int32)
+        slots = np.full((rb, chunk), -1, dtype=np.int32)
         bt = np.zeros((rb, self.max_pages_per_seq), dtype=np.int32)
         cached = np.zeros((rb,), dtype=np.int32)
         new_lens = np.zeros((rb,), dtype=np.int32)
@@ -1716,11 +1705,11 @@ class Engine:
         valids = []
         for i, req in enumerate(reqs):
             start = req.prefill_pos
-            valid = min(len(req.prompt) - start, self.prefill_chunk)
+            valid = min(len(req.prompt) - start, chunk)
             valids.append(valid)
             ids[i, :valid] = req.prompt[start : start + valid]
-            pos[i] = np.arange(start, start + width)
-            slots[i] = slot_mapping(self._block_tables[req.row], start, valid, self.page_size, width)
+            pos[i] = np.arange(start, start + chunk)
+            slots[i] = slot_mapping(self._block_tables[req.row], start, valid, self.page_size, chunk)
             bt[i] = self._block_tables[req.row]
             cached[i] = start
             new_lens[i] = valid
@@ -1728,13 +1717,16 @@ class Engine:
             row_idx[i] = req.row
             done_mask[i] = start + valid >= len(req.prompt)
         # the wave's real work, for whoever reads the trace: tokens already in
-        # the cache, tokens this chunk adds, the (query, key) pairs they
-        # attend, and the prompts this chunk completes
+        # the cache, tokens this chunk adds (of the row bucket x width the
+        # program multiplies), the (query, key) pairs they attend, and the
+        # prompts this chunk completes
         starts = [int(c) for c in cached[:n]]
         wave_ann.set_metadata(
             rows=n, new_tokens=sum(valids), cached_tokens=sum(starts),
             pairs=sum(v * c + v * (v + 1) // 2 for v, c in zip(valids, starts)),
-            completes=int(done_mask.sum()))
+            completes=int(done_mask.sum()), width=width, padded_tokens=rb * width)
+        self.prefill_padded_tokens += rb * width
+        self._m_wave[width].inc()
 
         # ONE program: the chunk, then its tail (prompt tokens into the
         # presence mask, the first token of every completed row drawn, marked
@@ -1749,7 +1741,7 @@ class Engine:
             ids, pos,
             self._k_pages, self._v_pages, self._presence, self._first_d,
             slots, bt, cached, new_lens,
-            last_idx, row_idx, done_mask,
+            last_idx, row_idx, done_mask, np.int32(width),
             self._rng, self._next_key_step(),
             self._temp, self._top_p, self._top_k, self._rep_pen,
             use_pallas=self.use_pallas,
@@ -1769,7 +1761,8 @@ class Engine:
             # (same slots/block tables — the pools are position-aligned by
             # construction), so decode-time drafting always has the full
             # prompt in its cache.  Logits are discarded; the call exists
-            # for its KV writes.
+            # for its KV writes.  It runs the whole chunk width: one warm
+            # program per row bucket, like the wave.
             self.step_dispatches_total += 1
             with annotate("engine.prefill_batch_draft"):
                 _, self._dk_pages, self._dv_pages = forward_paged(
@@ -2805,58 +2798,40 @@ class Engine:
             # the first dispatch then packs cap segments at exactly the
             # bucket this entry names, and the leftovers re-dispatch at
             # buckets earlier entries already compiled)
-            for nb in self.packed_prefill_buckets():
-                short_pages = pages_needed(3 + sp.max_tokens, self.page_size)
-                long_budget = (
-                    self._allocator.num_pages - (nb - 1) * short_pages
-                ) * self.page_size - sp.max_tokens
-                plen = min(self.prefill_chunk, self.max_seq_len - 3, long_budget)
-                if self.sp_prefill_threshold is not None and self._sp > 1:
-                    plen = min(plen, self.sp_prefill_threshold - 1)
-                if plen <= 0:
-                    continue  # unreachable bucket (see padded-path note)
-                wave += 1
-                tok = 2 + wave % max(2, self.cfg.vocab_size - 2)
-                self.generate([[tok] * plen] + [[tok] * 3] * (nb - 1), sp)
-        seen: set[tuple[int, int]] = set()  # (row bucket, width) dispatched
-        for nb in buckets if self.prefill_token_budget is None else []:
-            for w in self.prefill_width_buckets:
-                # ONE long prompt selects width bucket w; the other nb-1
-                # rows stay short, so the page pool never forces the wave
-                # into a smaller shape than live traffic could hit (a
-                # heterogeneous live wave needs only one long prompt to
-                # dispatch at (nb, w) — warmup must cover exactly that)
-                short_pages = pages_needed(3 + sp.max_tokens, self.page_size)
-                long_budget = (
-                    self._allocator.num_pages - (nb - 1) * short_pages
-                ) * self.page_size - sp.max_tokens
-                plen = min(w, self.max_seq_len - 3, long_budget)
-                if self.sp_prefill_threshold is not None and self._sp > 1:
-                    # stay below the ring-prefill routing threshold — this
-                    # loop warms the CHUNKED shapes; ring widths are warmed
-                    # by the dedicated loop below
-                    plen = min(plen, self.sp_prefill_threshold - 1)
-                if plen <= 0:
-                    # Skipping is provably safe, not a warm-coverage gap
-                    # (an all-short fallback wave is unnecessary):
-                    # plen<=0 via the page budget needs
-                    # num_pages <= (nb-1)*short_pages, i.e. no page left
-                    # for an nb-th row — live traffic can never run nb
-                    # simultaneous rows either, so (nb, *) is unreachable.
-                    # The only other source is an sp_prefill_threshold <= 1
-                    # clamp, where EVERY live prompt routes to ring prefill
-                    # (warmed by the dedicated loop below), never to these
-                    # chunked shapes.
-                    continue
-                # the width this wave will actually dispatch at (page caps
-                # can collapse several w's onto one shape — run it once)
-                dw = self._dispatch_width(min(plen, self.prefill_chunk))
-                if (nb, dw) in seen:
-                    continue
-                seen.add((nb, dw))
-                wave += 1
-                tok = 2 + wave % max(2, self.cfg.vocab_size - 2)
-                self.generate([[tok] * plen] + [[tok] * 3] * (nb - 1), sp)
+            prefill_buckets = self.packed_prefill_buckets()
+        else:
+            # padded prefill: a row bucket's one program holds every rung
+            # of the width ladder, whichever this wave runs
+            prefill_buckets = buckets
+        for nb in prefill_buckets:
+            # ONE prompt of a whole chunk, the other nb-1 rows short, so the
+            # page pool never forces the wave into fewer rows than live
+            # traffic could hit
+            short_pages = pages_needed(3 + sp.max_tokens, self.page_size)
+            long_budget = (
+                self._allocator.num_pages - (nb - 1) * short_pages
+            ) * self.page_size - sp.max_tokens
+            plen = min(self.prefill_chunk, self.max_seq_len - 3, long_budget)
+            if self.sp_prefill_threshold is not None and self._sp > 1:
+                # stay below the ring-prefill routing threshold — this
+                # loop warms the CHUNKED shapes; ring widths are warmed
+                # by the dedicated loop below
+                plen = min(plen, self.sp_prefill_threshold - 1)
+            if plen <= 0:
+                # Skipping is provably safe, not a warm-coverage gap
+                # (an all-short fallback wave is unnecessary):
+                # plen<=0 via the page budget needs
+                # num_pages <= (nb-1)*short_pages, i.e. no page left
+                # for an nb-th row — live traffic can never run nb
+                # simultaneous rows either, so (nb, *) is unreachable.
+                # The only other source is an sp_prefill_threshold <= 1
+                # clamp, where EVERY live prompt routes to ring prefill
+                # (warmed by the dedicated loop below), never to these
+                # chunked shapes.
+                continue
+            wave += 1
+            tok = 2 + wave % max(2, self.cfg.vocab_size - 2)
+            self.generate([[tok] * plen] + [[tok] * 3] * (nb - 1), sp)
         # both burst sampling variants must be warm: the bucket loop above
         # compiled the no-filter (Gumbel-argmax) burst; one filtered request
         # compiles the sample_tokens_capped burst (in-vocab tokens — tiny

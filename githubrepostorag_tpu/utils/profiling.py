@@ -19,7 +19,10 @@ that reads each):
     engine.admit       reaping, preemption, admission, page allocation
     engine.prefill_batch (+ _draft, prefill_packed, sp_prefill_packed)
                        one prefill wave: host arrays and the dispatch of
-                       its one program (chunk + first-token tail)
+                       its one program (chunk + first-token tail);
+                       ``width``: the columns a row it runs at, and
+                       ``padded_tokens`` = row bucket x width, of which
+                       ``new_tokens`` are real
     engine.burst_prepare  the active mask and the masks of fresh rows
     engine.decode_burst (+ spec_burst, fused_step, draft_spec_burst)
                        the dispatch call; ``ahead``: the device still

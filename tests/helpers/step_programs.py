@@ -72,6 +72,40 @@ def assert_two_programs_a_step(steps: list[list[str]]) -> None:
             assert set(calls[calls.index(WAVE) + 1:]) <= {BURST}, calls
 
 
+def recorded_waves(monkeypatch) -> list[dict]:
+    """The list that receives, from here on, the metadata of every
+    ``engine.prefill_batch`` annotation (``rows``, ``new_tokens``, ``width``,
+    ``padded_tokens``, ...), what ``set_metadata`` adds included."""
+    import githubrepostorag_tpu.serving.engine as engine_mod
+
+    waves, real = [], engine_mod.annotate
+
+    class Recorded:
+        def __init__(self, ann, meta):
+            self.ann, self.meta = ann, meta
+
+        def __enter__(self):
+            self.ann.__enter__()
+            return self
+
+        def __exit__(self, *exc):
+            return self.ann.__exit__(*exc)
+
+        def set_metadata(self, **meta):
+            self.meta.update(meta)
+            self.ann.set_metadata(**meta)
+
+    def annotate(name, **meta):
+        ann = real(name, **meta)
+        if name != "engine.prefill_batch":
+            return ann
+        waves.append(meta)
+        return Recorded(ann, meta)
+
+    monkeypatch.setattr(engine_mod, "annotate", annotate)
+    return waves
+
+
 def burst_call_shapes(eng) -> list[tuple]:
     """Wrap the burst program of ``eng``; the returned list receives, for
     every dispatch, the (shape, dtype) of each array it was called with."""

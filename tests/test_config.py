@@ -21,13 +21,13 @@ def test_env_overrides(monkeypatch):
     monkeypatch.setenv("MAX_RAG_ATTEMPTS", "7")
     monkeypatch.setenv("EMBEDDINGS_TABLE", "alt_embeddings")
     monkeypatch.setenv("DEV_MODE", "true")
-    monkeypatch.setenv("PREFILL_WIDTHS", "2")
+    monkeypatch.setenv("PREFILL_WIDTHS", "2")  # gone: the width ladder is derived
     monkeypatch.setenv("PREFILL_TOKEN_BUDGET", "2048")
     s = reload_settings()
     assert s.max_rag_attempts == 7
     assert s.embeddings_table_chunk == "alt_embeddings"
     assert s.dev_force_standalone is True
-    assert s.prefill_widths == 2
+    assert not hasattr(s, "prefill_widths")
     assert s.prefill_token_budget == 2048
 
 

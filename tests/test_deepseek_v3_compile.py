@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import pytest
 
 from tests.test_tpu_compile import (  # noqa: F401 - fixtures
+    assert_wave_holds_every_rung,
     assert_wave_keeps_in_place,
     chip,
     pool_movers,
@@ -78,8 +79,9 @@ def lowered_program(where, program: str, variant):
             params, cfg, sds(chunk, i32), sds(chunk, i32), pool, None,
             sds((b, cfg.vocab_size), jnp.bool_), sds((b,), i32), sds(chunk, i32),
             sds((variant, ROW_PAGES), i32), sds(rows, i32), sds(rows, i32), sds(rows, i32),
-            sds(rows, i32), sds(rows, jnp.bool_), sds((2,), jnp.uint32), sds((), jnp.uint32),
-            sds((b,), f32), sds((b,), f32), sds((b,), i32), sds((b,), f32), use_pallas=True)
+            sds(rows, i32), sds(rows, jnp.bool_), sds((), i32), sds((2,), jnp.uint32),
+            sds((), jnp.uint32), sds((b,), f32), sds((b,), f32), sds((b,), i32), sds((b,), f32),
+            use_pallas=True)
     else:
         chunk = (variant, 512)
         lowered = forward_paged.lower(
@@ -109,9 +111,11 @@ def test_step_program_leaves_latent_pool_and_experts_in_place(chip, as_on_chip, 
 def test_the_wave_is_one_program_that_donates_the_pool_and_presence(chip, as_on_chip):
     """The benchmark's readers find the wave by ``forward_paged`` in its
     module's name; the latent pool and the presence mask come back in place."""
-    lowered, _, _ = lowered_program(chip, "wave", 2)
-    assert_wave_keeps_in_place(lowered.compile().as_text(),
-                               rf"bf16\[5,1,{PAGES},{PAGE},640\]|pred\[{ROWS},16160\]", 2)
+    lowered, pool_shape, _ = lowered_program(chip, "wave", 2)
+    hlo = lowered.compile().as_text()
+    assert_wave_keeps_in_place(hlo, rf"bf16\[5,1,{PAGES},{PAGE},640\]|pred\[{ROWS},16160\]", 2)
+    # the dense stack's scan and the expert stack's: 512, 256, 128 columns in each
+    assert_wave_holds_every_rung(hlo, 3, pool_shape)
 
 
 def test_the_expert_metric_selects_the_products_under_the_moe_experts_scope(chip, as_on_chip):

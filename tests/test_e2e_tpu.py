@@ -54,9 +54,8 @@ def test_config1_e2e_on_tpu(tmp_path, monkeypatch):
     params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16)
     eng = Engine(params, cfg, max_num_seqs=4, num_pages=32, page_size=256,
                  max_seq_len=2048, prefill_chunk=512, use_pallas=True,
-                 decode_burst=32, prefill_widths=2)  # width-bucketed
-    # prefill on real hardware: the agent's mixed prompt lengths hit both
-    # dispatch widths
+                 decode_burst=32)  # prefill on real hardware: the agent's
+    # mixed prompt lengths hit both rungs of the width ladder (512, 256)
     llm = InProcessLLM(AsyncEngine(eng), ByteTokenizer(),
                    default_max_tokens=48, context_window=2048)
 

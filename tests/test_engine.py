@@ -9,6 +9,7 @@ import jax.numpy as jnp
 
 from githubrepostorag_tpu.serving import Engine, SamplingParams
 from tests.helpers.step_paths import DECODE_PATHS, count_step_paths
+from tests.helpers.step_programs import recorded_waves
 
 transformers = pytest.importorskip("transformers")
 import torch  # noqa: E402
@@ -80,23 +81,42 @@ def test_chunked_prefill_long_prompt(tiny):
     assert res.output_tokens == _hf_greedy(model, prompt, 5)
 
 
-def test_width_bucketed_prefill_matches_hf(tiny):
-    """prefill_widths > 1 dispatches short waves at sub-chunk widths (the
-    p50-TTFT fix for eval config #5) — tokens must be identical to the
-    single-width engine and to HF, across short, bucket-boundary, and
-    multi-chunk (resume) prompts, mixed in one batch."""
+def test_width_bucketed_prefill_matches_hf(tiny, monkeypatch):
+    """A wave runs at the narrowest rung of the derived ladder that holds its
+    longest pending chunk (the ``width`` of its annotation): a wave of short
+    rows the narrow rung, a wave with one long row the full one.  Tokens are
+    HF's across short, rung-boundary and multi-chunk (resume) prompts, alone
+    and mixed in one batch."""
+    from githubrepostorag_tpu.metrics import PREFILL_WAVE
+
     model, params, cfg = tiny
     rng = np.random.default_rng(7)
-    # chunk=32 -> buckets [32, 16] (floored at 16): 5 -> 16, 16 -> 16,
-    # 17 -> 32, 70 -> chunks 32+32+6 (the 6-token resume chunk rides a
-    # 16-wide wave)
+    # chunk=32 over pages of 8 -> rungs [32, 16, 8]: 5 -> 8, 16 -> 16,
+    # 17 -> 32, 70 -> chunks 32+32+6 (the 6-token resume chunk rides an
+    # 8-wide wave)
     prompts = [rng.integers(0, cfg.vocab_size, size=n).tolist() for n in (5, 16, 17, 70)]
-    eng = _make_engine(params, cfg, prefill_widths=3)
-    assert eng.prefill_width_buckets == [32, 16]
+    eng = _make_engine(params, cfg, page_size=8)
+    assert eng.prefill_width_buckets == [32, 16, 8]
     eng.warmup()
+    waves = recorded_waves(monkeypatch)
+    counted = lambda: {w: PREFILL_WAVE.labels(width=str(w))._value.get()  # noqa: E731
+                       for w in eng.prefill_width_buckets}
+    before, padded = counted(), eng.prefill_padded_tokens
     sp = SamplingParams(temperature=0.0, max_tokens=8)
+    for prompt in prompts:
+        assert eng.generate([prompt], sp)[0].output_tokens == _hf_greedy(model, prompt, 8)
+    assert [w["width"] for w in waves] == [8, 16, 32, 32, 32, 8]
+    assert [w["padded_tokens"] for w in waves] == [8, 16, 32, 32, 32, 8]  # one row each
+    assert [w["new_tokens"] for w in waves] == [5, 16, 17, 32, 32, 6]
+    assert {w: n - before[w] for w, n in counted().items()} == {32: 3, 16: 1, 8: 2}
+    assert eng.prefill_padded_tokens - padded == 128
+    del waves[:]  # fresh prompts: the prefix cache holds the first four
+    prompts = [rng.integers(0, cfg.vocab_size, size=n).tolist() for n in (5, 16, 17, 70)]
     for prompt, res in zip(prompts, eng.generate(prompts, sp)):
         assert res.output_tokens == _hf_greedy(model, prompt, 8)
+    # four rows (and the long one among them) run whole; then its 6-token remainder
+    assert [(w["rows"], w["width"], w["padded_tokens"]) for w in waves] == [
+        (4, 32, 128), (1, 32, 32), (1, 8, 8)]
 
 
 def test_streaming_callback_order(tiny):

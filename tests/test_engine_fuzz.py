@@ -47,7 +47,7 @@ CONFIGS = [
     dict(prefix_caching=False),
     dict(spec_ngram_k=3),
     dict(decode_burst=1),  # per-token stepping
-    dict(prefill_widths=3),  # width-bucketed prefill dispatches
+    dict(prefill_chunk=32),  # three rungs of prefill width: 32, 16, 8
 ]
 
 
@@ -63,10 +63,8 @@ def test_random_schedule_episode(tiny, extra):
 
     def make():
         return Engine(params, cfg, max_num_seqs=4, num_pages=48, page_size=8,
-                      max_seq_len=128, prefill_chunk=16, kv_dtype=jnp.float32,
-                      decode_burst=extra.get("decode_burst", 4), **{
-                          k: v for k, v in extra.items() if k != "decode_burst"
-                      })
+                      max_seq_len=128, kv_dtype=jnp.float32,
+                      **{"prefill_chunk": 16, "decode_burst": 4, **extra})
 
     # a small pool of prompts, some sharing prefixes (prefix-cache traffic)
     base = rng.integers(0, cfg.vocab_size, 40).tolist()
@@ -134,9 +132,9 @@ def test_random_schedule_episode(tiny, extra):
 
 @pytest.mark.parametrize("extra", [
     dict(spec_ngram_k=3),  # speculative path
-    dict(prefill_widths=3),  # plain bursts: the mixed top_p traffic flips
-    # the filter_sampling burst variant between bursts, over width-bucketed
-    # prefill dispatches
+    dict(prefill_chunk=32),  # plain bursts: the mixed top_p traffic flips
+    # the filter_sampling burst variant between bursts, over prefill waves
+    # at three widths (32, 16, 8)
 ], ids=["spec", "burst-widths"])
 def test_random_schedule_sampled_invariants(tiny, extra):
     """Sampled traffic (temperature > 0, top-p, penalties) under random
@@ -146,8 +144,8 @@ def test_random_schedule_sampled_invariants(tiny, extra):
     params, cfg = tiny
     rng = np.random.default_rng(99)
     eng = Engine(params, cfg, max_num_seqs=4, num_pages=48, page_size=8,
-                 max_seq_len=128, prefill_chunk=16, kv_dtype=jnp.float32,
-                 decode_burst=4, **extra)
+                 max_seq_len=128, kv_dtype=jnp.float32, decode_burst=4,
+                 **{"prefill_chunk": 16, **extra})
     want: dict[str, int] = {}
     done: dict[str, object] = {}
     steps = 0

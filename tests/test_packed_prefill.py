@@ -165,7 +165,7 @@ def test_packed_prefill_matches_padded_and_hf(tiny, use_pallas):
     prompts = [rng.integers(0, cfg.vocab_size, size=n).tolist()
                for n in (5, 16, 17, 70, 33)]
     sp = SamplingParams(temperature=0.0, max_tokens=8)
-    padded = _make_engine(params, cfg, prefill_widths=2)
+    padded = _make_engine(params, cfg)  # its waves run at 32, 16 or 8 columns
     packed = _make_engine(params, cfg, prefill_token_budget=48,
                           use_pallas=use_pallas)
     got_padded = [r.output_tokens for r in padded.generate(prompts, sp)]
@@ -238,10 +238,10 @@ def test_packed_warmup_compiles_exact_shape_set(tiny):
                        label="mixed packed traffic"):
         eng.generate(prompts, sp)
         eng.generate(prompts, sp)  # warm repeat: prefix-cache resume traffic
-    # the collapse claim: packed shapes never exceed the padded engine's
-    # (row bucket x width bucket) grid for the same geometry
-    padded = _make_engine(params, cfg, prefill_widths=2)
+    # the collapse claim: packed shapes never exceed the padded engine's,
+    # one program a row bucket (its width rungs are branches of it)
+    padded = _make_engine(params, cfg)
     row_buckets = {min(b, padded.max_num_seqs)
                    for b in (1, 2, 4, 8) if b <= padded.max_num_seqs}
-    padded_shapes = len(row_buckets) * len(padded.prefill_width_buckets)
-    assert len(eng.packed_prefill_buckets()) <= padded_shapes
+    assert len(padded.prefill_width_buckets) > 1
+    assert len(eng.packed_prefill_buckets()) <= len(row_buckets)

@@ -184,6 +184,21 @@ def assert_wave_keeps_in_place(hlo: str, held: str, count: int) -> None:
     assert len(params) == count and params <= aliased, (params, aliased)
 
 
+def assert_wave_holds_every_rung(hlo: str, rungs: int, pool_shape: tuple) -> None:
+    """Each layer scan of the compiled wave switches among ``rungs`` branches
+    that take the pool and hand it back (ops/prefill_width.at_wave_width),
+    and no branch, like nothing else in the module, copies it."""
+    pool = r"bf16\[" + ",".join(map(str, pool_shape)) + r"\]"
+    switches = [ln for ln in hlo.splitlines()
+                if re.search(r"= \([^=]*" + pool + r"[^=]* conditional\(", ln)]
+    assert switches, "no switch hands the pool on"
+    for ln in switches:
+        assert "while/body" in ln, ln  # inside a layer scan, not around one
+        branches = re.search(r"branch_computations=\{([^}]*)\}", ln).group(1).split(",")
+        assert len(branches) == rungs, ln
+    assert pool_movers(hlo, pool_shape) == []
+
+
 @pytest.fixture()
 def as_on_chip(monkeypatch):
     """The program asks runtime.on_tpu() whether its kernels run compiled or
@@ -259,9 +274,9 @@ def _cell_program(where, program: str, kv: str, variant):
             params, cfg, sds(chunk, i32), sds(chunk, i32), pool, pool,
             sds((b, cfg.vocab_size), jnp.bool_), sds((b,), i32), sds(chunk, i32),
             sds((variant, CELL_ROW_PAGES), i32), sds(rows, i32), sds(rows, i32),
-            sds(rows, i32), sds(rows, i32), sds(rows, jnp.bool_), sds((2,), jnp.uint32),
-            sds((), jnp.uint32), sds((b,), f32), sds((b,), f32), sds((b,), i32), sds((b,), f32),
-            use_pallas=True, mesh=mesh, **scales,
+            sds(rows, i32), sds(rows, i32), sds(rows, jnp.bool_), sds((), i32),
+            sds((2,), jnp.uint32), sds((), jnp.uint32), sds((b,), f32), sds((b,), f32),
+            sds((b,), i32), sds((b,), f32), use_pallas=True, mesh=mesh, **scales,
         )
     else:
         chunk = (variant, 512)
@@ -308,9 +323,10 @@ def test_the_wave_is_one_program_that_donates_pools_and_presence(chip, as_on_chi
     three buffers it is handed to keep (K pool, V pool, the presence mask)
     come back in place.  (That nothing in it moves a pool is a case of
     test_step_program_leaves_the_pools_in_place.)"""
-    lowered, _ = _cell_program(chip, "wave", "fp", 2)
-    assert_wave_keeps_in_place(lowered.compile().as_text(),
-                               r"bf16\[28,4,384,128,128\]|pred\[32,152064\]", 3)
+    lowered, pool_shape = _cell_program(chip, "wave", "fp", 2)
+    hlo = lowered.compile().as_text()
+    assert_wave_keeps_in_place(hlo, r"bf16\[28,4,384,128,128\]|pred\[32,152064\]", 3)
+    assert_wave_holds_every_rung(hlo, 3, pool_shape)  # 512, 256, 128 columns
 
 
 @pytest.mark.parametrize("program,variant", [("burst", False), ("prefill", 1)],
