@@ -522,6 +522,19 @@ MOE_EXPERT_TOKENS = Counter(
     ["program"],
     registry=REGISTRY,
 )
+STATE_SNAPSHOTS = Counter(
+    "rag_state_snapshots_total",
+    "Snapshots of a recurrent model's per-sequence state at page boundaries "
+    "(serving/kv_cache.StateSlots): written by a prefill wave, hit by an "
+    "admission that resumed from one, evicted by LRU or with their page",
+    ["event"],
+    registry=REGISTRY,
+)
+STATE_SLOTS_IN_USE = Gauge(
+    "rag_state_slots_in_use",
+    "Snapshot slots of the state pool that hold a snapshot",
+    registry=REGISTRY,
+)
 
 
 def render() -> bytes:

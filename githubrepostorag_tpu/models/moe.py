@@ -55,14 +55,23 @@ EXPERT_TILE = 128  # rows of one expert a dispatch tile holds, at most
 
 
 def dropless_experts(x: jnp.ndarray, top_i: jnp.ndarray, top_w: jnp.ndarray, expert_ffn,
-                     n_held: int, lo=0):
+                     n_held: int, lo=0, listed: bool = False):
     """Sum over the held experts of w * E(x) for the pairs routed to them.
 
     ``x`` [T, d]; ``top_i`` [T, K] expert ids over ALL experts; ``top_w``
     [T, K] float32; ``expert_ffn(e, rows [M, d]) -> [M, d]`` runs held expert
     ``e`` (0-based among the held, a traced int32); the held are the ids
     ``lo .. lo + n_held - 1``.  Returns (y [T, d] float32, counts [n_held]
-    int32: pairs each held expert received)."""
+    int32: pairs each held expert received).
+
+    ``listed``: the form for MANY SMALL experts (128 held of 6 MB each, ~40
+    hit by a burst's rows: models/qwen3_next.py).  There the scan's own
+    bookkeeping, a dozen scalar ops a tile and a skipped ``cond`` for each of
+    the ~90 tiles that do not exist, costs more than the experts' bytes
+    (PERF.md, Findings, PR 34).  Every tile's expert, rows and weights are
+    listed once, vectorised, before the loop; the loop runs the tiles that
+    exist and no others (a dynamic trip count: serving only, it does not
+    differentiate in reverse)."""
     t, d = x.shape
     k = top_i.shape[1]
     m = min(EXPERT_TILE, -(-t // 8) * 8)  # rows a tile holds
@@ -78,6 +87,41 @@ def dropless_experts(x: jnp.ndarray, top_i: jnp.ndarray, top_w: jnp.ndarray, exp
     # receives a token at most once
     n_tiles = min(t * k // m + n_held, n_held * -(-t // m))
     flat_w = top_w.reshape(-1).astype(jnp.float32)
+
+    if listed and t <= m:
+        # the rows fit one tile: an expert that was hit runs on ALL of them (a
+        # tile is m rows whatever it holds) and its result is weighted by that
+        # expert's column of the dense [T, held] weights, zero where a row did
+        # not choose it: no sort, no gather of rows, no scatter-add a tile
+        local = jnp.where(held, top_i - lo, n_held)  # [T, K]; n_held = not here
+        w_dense = jnp.zeros((t, n_held + 1), jnp.float32).at[
+            jnp.arange(t)[:, None], local].add(top_w.astype(jnp.float32))
+        first_hit = jnp.argsort(counts == 0, stable=True).astype(jnp.int32)  # the hit ones first
+        w_cols = w_dense.T[first_hit]  # [n_held, T]
+
+        def one(i, y):
+            yt = expert_ffn(first_hit[i], x).astype(jnp.float32)
+            return y + yt * jax.lax.dynamic_index_in_dim(w_cols, i, keepdims=False)[:, None]
+
+        y = jax.lax.fori_loop(0, (counts > 0).sum(), one, jnp.zeros((t, d), jnp.float32))
+        return y, counts
+    if listed:
+        tiles = jnp.arange(n_tiles)
+        ex = jnp.minimum(jnp.searchsorted(tile_end, tiles, side="right"), n_held - 1)
+        off = (tiles - tile_start[ex]) * m
+        idx = (group_start[ex] + off)[:, None] + jnp.arange(m)[None, :]
+        valid = jnp.arange(m)[None, :] < (counts[ex] - off)[:, None]
+        pair = order[jnp.clip(idx, 0, t * k - 1)]
+        tok, w = pair // k, jnp.where(valid, flat_w[pair], 0.0)  # [n_tiles, m]
+        ex = ex.astype(jnp.int32)
+
+        def tile(i, y):
+            rows = jax.lax.dynamic_index_in_dim(tok, i, keepdims=False)
+            yt = expert_ffn(ex[i], x[rows]).astype(jnp.float32)
+            return y.at[rows].add(yt * jax.lax.dynamic_index_in_dim(w, i, keepdims=False)[:, None])
+
+        y = jax.lax.fori_loop(0, tile_end[-1], tile, jnp.zeros((t, d), jnp.float32))
+        return y, counts
 
     def run(i, y):
         ex = jnp.minimum(jnp.searchsorted(tile_end, i, side="right"), n_held - 1)
@@ -105,8 +149,9 @@ def _expert(w, e):
 def moe_mlp(cfg, p: dict, x: jnp.ndarray) -> jnp.ndarray:
     """Sparse MoE MLP over normed hidden states ``x`` [B, S, d].
 
-    ``p`` keys: ``router`` [d, E]; ``e_wg``/``e_wu`` [E, d, ff_e],
-    ``e_wd`` [E, ff_e, d]; ``s_wg``/``s_wu`` [d, ff_s], ``s_wd`` [ff_s, d];
+    ``p`` keys: ``router`` [d, E]; ``e_wg``/``e_wu`` [held, d, ff_e],
+    ``e_wd`` [held, ff_e, d] (the experts held here: all E unless
+    ``cfg.experts_held`` names a range); ``s_wg``/``s_wu`` [d, ff_s], ``s_wd`` [ff_s, d];
     ``s_gate`` [d, 1].
     """
     b, s, d = x.shape
@@ -123,8 +168,12 @@ def moe_mlp(cfg, p: dict, x: jnp.ndarray) -> jnp.ndarray:
         h = jax.nn.silu(qmatmul(rows, _expert(p["e_wg"], e))) * qmatmul(rows, _expert(p["e_wu"], e))
         return qmatmul(h, _expert(p["e_wd"], e))
 
+    # the layer is told which experts it holds (``cfg.experts_held``: a
+    # contiguous range of ``num_experts``, all of them where the configuration
+    # does not say); the router above scored every one
+    lo, hi = getattr(cfg, "experts_held", None) or (0, cfg.num_experts)
     with jax.named_scope("moe_experts"):
-        y, _ = dropless_experts(xf, top_i, top_p, expert_ffn, cfg.num_experts)
+        y, _ = dropless_experts(xf, top_i, top_p, expert_ffn, hi - lo, lo=lo)
 
     with jax.named_scope("moe_shared"):  # always on, behind a sigmoid gate
         sh = jax.nn.silu(qmatmul(xf, p["s_wg"])) * qmatmul(xf, p["s_wu"])

@@ -69,6 +69,8 @@ SNAPSHOT_FIELDS = (
     "fused_steps_total", "step_dispatches_total",
     "bursts_ahead", "bursts_starved",
     "prefill_padded_tokens",
+    # a recurrent model's state cache (serving/kv_cache.StateSlots); zero elsewhere
+    "state_restored", "state_snapshots_written", "state_snapshots_evicted",
 )
 
 
@@ -179,6 +181,11 @@ class TokenLedger:
                 # columns the padded prefill waves multiplied (row bucket x
                 # width), to set against prefill_tokens' real ones
                 "prefill_padded_tokens": max(0.0, d["prefill_padded_tokens"]),
+                # prefills resumed from a state snapshot, snapshots written and
+                # evicted (a recurrent model's; zero for every other)
+                "state_restored": max(0.0, d["state_restored"]),
+                "state_snapshots_written": max(0.0, d["state_snapshots_written"]),
+                "state_snapshots_evicted": max(0.0, d["state_snapshots_evicted"]),
             }
             if compiles > 0:
                 # kv_transfer stays out of ``measured``: it is inter-step
@@ -364,5 +371,8 @@ class TokenLedger:
                     "bursts_starved": int(s.get("bursts_starved", 0.0)),
                     "prefill_tokens": int(s.get("prefill_tokens", 0.0)),
                     "prefill_padded_tokens": int(s.get("prefill_padded_tokens", 0.0)),
+                    "state_restored": int(s.get("state_restored", 0.0)),
+                    "state_snapshots_written": int(s.get("state_snapshots_written", 0.0)),
+                    "state_snapshots_evicted": int(s.get("state_snapshots_evicted", 0.0)),
                 },
             }

@@ -1,0 +1,176 @@
+"""Gated DeltaNet (arXiv:2412.06464), the linear-attention layer of
+Qwen3-Next: a fixed-size matrix state a head and sequence in place of keys
+and values, and a short causal convolution in front of it.
+
+Per head and token, with ``S`` [dk, dv] float32, ``q`` and ``k`` L2-normalised
+(``q`` scaled by dk^-1/2), ``g <= 0`` the log of the decay and ``beta`` in
+(0, 1) the write strength:
+
+    S <- exp(g_t) S;  S <- S + k_t (beta_t (v_t - S^T k_t))^T;  o_t = S^T q_t
+
+Two forms of the same recurrence:
+
+* ``gated_delta_step``: one token a row (the decode burst).  The state is
+  read and written once; everything is elementwise or a reduction over it.
+* ``gated_delta_chunked``: a prefill chunk in blocks of ``BLOCK`` tokens
+  (the WY form of the delta rule).  Inside a block the 64 rank-one updates
+  are one triangular system ``(I + A) U = beta V``, ``A`` the strictly lower
+  part of ``(beta K K^T) * decay``; its inverse is built by halving (the
+  inverse of a block-triangular matrix from its blocks' inverses) down to
+  16 x 16 blocks, each the finite Neumann product ``(I - A)(I + A^2)(I +
+  A^4)(I + A^8)`` of a nilpotent matrix: small products, no substitution
+  loop of 64 steps.  Across blocks the
+  state is carried by a scan, so a chunk of 512 columns reads and writes
+  its state 8 times and not 512.  The per-token scan the step form would
+  give over such a chunk moves 4 MB of state a token, row and layer.
+
+Tokens that are not real (the padding of a wave's row, past ``new_lens``)
+arrive with ``k = 0``, ``beta = 0`` and ``g = 0`` (``mask_padding``): they
+multiply the state by one and add zero to it, bit for bit.
+
+``causal_conv`` / ``causal_conv_step`` are the depthwise convolution of
+``taps`` inputs with its carried history (the last ``taps - 1`` inputs of
+the sequence so far), followed by SiLU.
+
+All products here run at ``Precision.HIGHEST`` in float32: they are a few
+GFLOP a chunk (the experts' products are hundreds), and the triangular
+inverse cancels.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+BLOCK = 64  # tokens of one block of the chunked form
+HI = jax.lax.Precision.HIGHEST
+
+
+def l2norm(x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
+    """x / sqrt(sum x^2 + eps) over the last axis (the published kernel's)."""
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+def mask_padding(live: jnp.ndarray, k, g, beta):
+    """``live`` [R, T] marks real tokens; the others leave the state as it is."""
+    return (jnp.where(live[..., None, None], k, 0.0), jnp.where(live[..., None], g, 0.0),
+            jnp.where(live[..., None], beta, 0.0))
+
+
+def gated_delta_step(state, q, k, v, g, beta):
+    """One token a row.  ``state`` [B, H, dk, dv] float32; ``q``, ``k``
+    [B, H, dk]; ``v`` [B, H, dv]; ``g``, ``beta`` [B, H].  Returns
+    (o [B, H, dv], new state).  Written so that the state is read twice and
+    written once: ``S^T k`` and ``S^T q`` come from one pass over the state
+    that came in (``o = exp(g) S^T q + (k . q) delta`` is ``S_new^T q``), the
+    update is the second."""
+    decay = jnp.exp(g)[..., None]
+    kv = decay * jnp.sum(state * k[..., None], axis=-2)
+    qv = decay * jnp.sum(state * q[..., None], axis=-2)
+    delta = beta[..., None] * (v - kv)
+    o = qv + jnp.sum(k * q, axis=-1, keepdims=True) * delta
+    return o, state * decay[..., None] + k[..., None] * delta[..., None, :]
+
+
+NEUMANN_MAX = 16  # the Neumann product's terms grow like binomials of its size
+
+
+def _unit_lower_inverse(a: jnp.ndarray) -> jnp.ndarray:
+    """(I + a)^-1 for strictly lower-triangular ``a`` [..., C, C].  Halved
+    down to ``NEUMANN_MAX`` x ``NEUMANN_MAX``: ``[[P, 0], [X, Q]]^-1 = [[P^-1,
+    0], [-Q^-1 X P^-1, Q^-1]]``; a small block is the Neumann series of a
+    nilpotent matrix, ``(I - a)(I + a^2)(I + a^4)...``.  The series alone over
+    64 columns cancels badly when neighbouring keys are alike (terms up to
+    C(63, k) in size: 4e-3 of error in float32 at cosine 0.9)."""
+    c = a.shape[-1]
+    mm = lambda x, y: jnp.einsum("...ij,...jk->...ik", x, y, precision=HI)  # noqa: E731
+    if c > NEUMANN_MAX:
+        half = c // 2
+        p = _unit_lower_inverse(a[..., :half, :half])
+        q = _unit_lower_inverse(a[..., half:, half:])
+        low = -mm(mm(q, a[..., half:, :half]), p)
+        top = jnp.concatenate([p, jnp.zeros_like(low).swapaxes(-1, -2)], axis=-1)
+        return jnp.concatenate([top, jnp.concatenate([low, q], axis=-1)], axis=-2)
+    m = -a
+    inv = jnp.eye(c, dtype=a.dtype) + m
+    power = 2
+    while power < c:
+        m = mm(m, m)
+        inv = inv + mm(inv, m)
+        power *= 2
+    return inv
+
+
+def gated_delta_chunked(state, q, k, v, g, beta, snap_col=None, block: int = BLOCK):
+    """A chunk of T tokens a row, T a multiple of ``block``.  ``state``
+    [R, H, dk, dv] float32; ``q``, ``k`` [R, T, H, dk]; ``v`` [R, T, H, dv];
+    ``g``, ``beta`` [R, T, H], padding masked (``mask_padding``).  Returns
+    (o [R, T, H, dv], the state after the chunk, the state after
+    ``snap_col`` [R] tokens of it: a multiple of ``block``; the state that
+    came in where it is not positive or not given)."""
+    r, t, h, dk = q.shape
+    n, c = t // block, block
+
+    def blocks(x):  # [R, T, H, ...] -> [N, R, H, C, ...]
+        x = x.reshape(r, n, c, *x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
+
+    q, k, v, g, beta = (blocks(x.astype(jnp.float32)) for x in (q, k, v, g, beta))
+    gc = jnp.cumsum(g, axis=-1)  # [N, R, H, C]: log decay from the block's start
+    lower = jnp.tril(jnp.ones((c, c), bool))
+    decay = jnp.exp(jnp.where(lower, gc[..., :, None] - gc[..., None, :], -jnp.inf))
+    kb, vb = k * beta[..., None], v * beta[..., None]
+    a = jnp.einsum("...ik,...jk->...ij", kb, k, precision=HI) * decay
+    inv = _unit_lower_inverse(jnp.where(jnp.tril(lower, -1), a, 0.0))
+    u = jnp.einsum("...ij,...jv->...iv", inv, vb, precision=HI)
+    w = jnp.einsum("...ij,...jk->...ik", inv, kb * jnp.exp(gc)[..., None], precision=HI)
+    qk = jnp.einsum("...ik,...jk->...ij", q, k, precision=HI) * decay
+    q_in = q * jnp.exp(gc)[..., None]  # the query against the state carried in
+    k_out = k * jnp.exp(gc[..., -1:] - gc)[..., None]  # a key's share of the state carried out
+    snap_col = jnp.zeros((r,), jnp.int32) if snap_col is None else snap_col
+
+    def step(carry, xs):
+        s, snap = carry
+        i, u_i, w_i, qk_i, q_i, k_i, g_end = xs
+        v_new = u_i - jnp.einsum("rhck,rhkv->rhcv", w_i, s, precision=HI)
+        o = jnp.einsum("rhck,rhkv->rhcv", q_i, s, precision=HI) \
+            + jnp.einsum("rhij,rhjv->rhiv", qk_i, v_new, precision=HI)
+        s = s * jnp.exp(g_end)[..., None, None] \
+            + jnp.einsum("rhck,rhcv->rhkv", k_i, v_new, precision=HI)
+        snap = jnp.where((snap_col == (i + 1) * c)[:, None, None, None], s, snap)
+        return (s, snap), o
+
+    (state, snap), o = jax.lax.scan(
+        step, (state, state), (jnp.arange(n), u, w, qk, q_in, k_out, gc[..., -1]))
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)  # [R, N, C, H, dv]
+    return o.reshape(r, t, h, -1), state, snap
+
+
+def causal_conv(x, taps, weight, new_lens, snap_col=None):
+    """Depthwise causal convolution, then SiLU, over a chunk with its carried
+    history.  ``x`` [R, T, C]; ``taps`` [R, K - 1, C]: the K - 1 inputs before
+    the chunk; ``weight`` [C, K] (tap K - 1 multiplies the current input);
+    ``new_lens`` [R] real tokens.  Returns (y [R, T, C] float32, the history
+    after the row's real tokens, the history after ``snap_col`` of them), the
+    histories in ``taps``' type.  A row with no real token keeps its history."""
+    kk = weight.shape[1]
+    ext = jnp.concatenate([taps.astype(x.dtype), x], axis=1)  # [R, K - 1 + T, C]
+    wf = weight.astype(jnp.float32)
+    t = x.shape[1]
+    y = sum(ext[:, j:j + t].astype(jnp.float32) * wf[:, j] for j in range(kk))
+
+    def history(at):  # the K - 1 inputs that end at chunk column ``at``
+        idx = at[:, None] + jnp.arange(kk - 1)[None, :]
+        return jnp.take_along_axis(ext, idx[..., None], axis=1).astype(taps.dtype)
+
+    snap_col = jnp.zeros_like(new_lens) if snap_col is None else snap_col
+    return jax.nn.silu(y), history(new_lens), history(jnp.maximum(snap_col, 0))
+
+
+def causal_conv_step(x, taps, weight):
+    """One token a row: ``x`` [B, C], ``taps`` [B, K - 1, C].  Returns
+    (y [B, C] float32, the history with ``x`` shifted in)."""
+    ext = jnp.concatenate([taps.astype(x.dtype), x[:, None]], axis=1)  # [B, K, C]
+    y = jnp.einsum("bkc,ck->bc", ext.astype(jnp.float32), weight.astype(jnp.float32),
+                   precision=HI)
+    return jax.nn.silu(y), ext[:, 1:].astype(taps.dtype)

@@ -18,3 +18,22 @@ def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-6) -> jnp.ndar
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
     normed = xf * jax.lax.rsqrt(var + eps)
     return normed.astype(orig_dtype) * weight
+
+
+def rms_norm_zero_centered(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
+    """Qwen3-Next's RMSNorm: the stored weight is the scale's distance from
+    one, ``y = x / rms(x) * (1 + w)``, all of it in float32 (the published
+    module multiplies before it casts back)."""
+    xf = x.astype(jnp.float32)
+    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    return (xf * jax.lax.rsqrt(var + eps) * (1.0 + weight.astype(jnp.float32))).astype(x.dtype)
+
+
+def rms_norm_gated(x: jnp.ndarray, gate: jnp.ndarray, weight: jnp.ndarray,
+                   eps: float = 1e-6) -> jnp.ndarray:
+    """The Gated DeltaNet output norm: ``rmsnorm(x) * w * silu(gate)`` over
+    the last axis (one head), norm before gate, plain weight; float32 out."""
+    xf = x.astype(jnp.float32)
+    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    normed = xf * jax.lax.rsqrt(var + eps) * weight.astype(jnp.float32)
+    return normed * jax.nn.silu(gate.astype(jnp.float32))

@@ -65,3 +65,11 @@ def apply_rope(q: jnp.ndarray, k: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarra
 def rope_rotate(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
     """One tensor [..., hd] with cos/sin already broadcastable to it."""
     return x * cos.astype(x.dtype) + _rotate_half(x) * sin.astype(x.dtype)
+
+
+def rope_rotate_leading(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
+    """Rotary position on the leading ``cos.shape[-1]`` columns of ``x``
+    [..., hd] (``partial_rotary_factor``: rotate-half inside that slice), the
+    rest passed through."""
+    r = cos.shape[-1]
+    return jnp.concatenate([rope_rotate(x[..., :r], cos, sin), x[..., r:]], axis=-1)

@@ -64,6 +64,7 @@ from githubrepostorag_tpu.serving.kv_cache import (
     OutOfPages,
     PageAllocator,
     PrefixCachingAllocator,
+    StateSlots,
     TieredPageAllocator,
     make_page_pools,
     packed_slot_mapping,
@@ -73,7 +74,12 @@ from githubrepostorag_tpu.serving.kv_cache import (
     slot_mapping,
 )
 from githubrepostorag_tpu.serving.sampling_params import SamplingParams
-from githubrepostorag_tpu.metrics import BURST_DISPATCH, PREFILL_WAVE
+from githubrepostorag_tpu.metrics import (
+    BURST_DISPATCH,
+    PREFILL_WAVE,
+    STATE_SLOTS_IN_USE,
+    STATE_SNAPSHOTS,
+)
 from githubrepostorag_tpu.utils.logging import get_logger
 from githubrepostorag_tpu.utils.profiling import annotate
 
@@ -166,6 +172,13 @@ class _Request:
     resume_pending: bool = False  # parked->waiting, first re-admission ahead
     orig_prompt_len: int = 0  # original prompt length (0 = never parked path)
     prior_output: list[int] = field(default_factory=list)
+    # a model with recurrent state (serving/kv_cache.StateSlots): the prompt
+    # pages the prefix cache holds (a hit resumes only as deep as a snapshot
+    # lies), the snapshot slot the first wave resumes from (-1: none, zeros),
+    # and the page counts at which this prefill still owes a snapshot
+    page_match: int = 0
+    state_src: int = -1
+    snap_at: list[int] = field(default_factory=list)
 
 
 from githubrepostorag_tpu.utils import next_bucket as _bucket
@@ -311,15 +324,26 @@ class Engine:
         # unlabeled add_request calls (PRIORITY_DEFAULT_CLASS)
         protected_priority: str = "interactive",  # the class headroom and
         # preemption act FOR; its requests are never victims
+        state_snapshots: int | None = None,  # snapshot slots of a recurrent
+        # model's state pool (serving/kv_cache.StateSlots), sized beside
+        # num_pages; None = two a row.  Unused by every other model
     ) -> None:
         self.mesh = mesh
         # which model runs is read from the configuration object: a latent
         # (MLA) model brings its own two step programs under qwen2's
         # contracts, one page pool and no V pool
         self._latent = bool(getattr(cfg, "latent_kv", False))
+        # ... and a hybrid of recurrent and attention layers its two programs,
+        # K/V pools for its attention layers and a state pool beside them.
+        # Either kind brings expert counters; both are "own programs" below
+        self._recurrent = bool(getattr(cfg, "recurrent_state", False))
+        self._own_programs = self._latent or self._recurrent
         self._wave_fn = forward_paged_wave
-        if self._latent:
-            from githubrepostorag_tpu.models import deepseek_v3
+        if self._own_programs:
+            if self._latent:
+                from githubrepostorag_tpu.models import deepseek_v3 as family
+            else:
+                from githubrepostorag_tpu.models import qwen3_next as family
 
             unsupported = {
                 "mesh": mesh is not None, "kv_quant": bool(quant_bits(kv_quant)),
@@ -327,13 +351,16 @@ class Engine:
                 "spec_ngram_k": spec_ngram_k > 0, "draft_params": draft_params is not None,
                 "fused_step": fused_step, "sp_prefill_threshold": sp_prefill_threshold is not None,
                 "kv_tier": kv_tier == "on" or kv_host_pool_pages > 0,
+                "preempt": preempt == "on",  # parks pages in the host tier
+                # a snapshot lies at a page boundary between two blocks of a chunk
+                "prefill_chunk": self._recurrent and prefill_chunk % page_size != 0,
             }
             if any(unsupported.values()):
                 raise ValueError(
-                    "not built for a latent page pool: "
-                    + ", ".join(k for k, v in unsupported.items() if v))
-            self._wave_fn = deepseek_v3.forward_paged_wave
-            self._decode_burst_fn = deepseek_v3.decode_burst
+                    f"not built for a {'latent page' if self._latent else 'recurrent state'} "
+                    "pool: " + ", ".join(k for k, v in unsupported.items() if v))
+            self._wave_fn = family.forward_paged_wave
+            self._decode_burst_fn = family.decode_burst
             # experts hit / pairs routed to held experts / expert slots
             # offered, per step program, cumulative; a dispatch's counts are
             # read back once a later burst's tokens prove the device is past it
@@ -361,7 +388,7 @@ class Engine:
                     "..., num_heads=..., num_kv_heads=..., role='serve')"
                 )
             params = shard_params(params, mesh, qwen2_param_specs(cfg, mesh, params))
-        elif not self._latent:
+        elif not self._own_programs:
             from githubrepostorag_tpu.models.quant import fuse_projections
 
             # single-chip: fuse wq|wk|wv and wg|wu so each layer runs 4
@@ -407,6 +434,17 @@ class Engine:
                                 quant=self.kv_quant)
         self._k_pages, self._v_pages = pools.k, pools.v
         self._k_scales, self._v_scales = pools.ks, pools.vs
+        # a recurrent model's second kind of per-sequence memory: the state
+        # pool on the device and the ledger of its slots on the host
+        self._state = self._state_pools = None
+        if self._recurrent:
+            self._state = StateSlots(
+                max_num_seqs, 2 * max_num_seqs if state_snapshots is None else state_snapshots)
+            self._state_pools = family.make_state_pools(cfg, self._state.total)
+            self._state_published: dict[str, int] = {}  # what the counters have been told
+        self.state_restored = 0  # stats: prefills resumed from a snapshot
+        self.page_hit_tokens = 0  # stats: prompt tokens whose pages the prefix cache held
+        self.state_hit_tokens = 0  # ... and of those, the tokens a snapshot let a prefill skip
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as PS
 
@@ -445,6 +483,9 @@ class Engine:
             self._allocator = PrefixCachingAllocator(num_pages)
         else:
             self._allocator = PageAllocator(num_pages)
+        if self._state is not None and prefix_caching:
+            # a snapshot whose page is evicted goes with it
+            self._allocator.on_evict = self._state.drop
         # in-flight writeback gathers: [(device bufs tuple, hashes)] — the
         # gather + copy_to_host_async dispatch at step N, the np reads (and
         # allocator complete_writeback calls) happen at step N+1, so the
@@ -714,6 +755,29 @@ class Engine:
     @page_pool.setter
     def page_pool(self, pool: jnp.ndarray) -> None:
         self._k_pages = pool
+
+    @property
+    def value_pool(self) -> jnp.ndarray | None:
+        """The V pool beside ``page_pool`` (None for a latent model)."""
+        return self._v_pages
+
+    @value_pool.setter
+    def value_pool(self, pool: jnp.ndarray) -> None:
+        self._v_pages = pool
+
+    @property
+    def state_pools(self) -> dict | None:
+        """A recurrent model's state pools (None for every other model), and
+        ``state_slots`` their ledger: settable like ``page_pool``."""
+        return self._state_pools
+
+    @state_pools.setter
+    def state_pools(self, pools: dict) -> None:
+        self._state_pools = pools
+
+    @property
+    def state_slots(self):
+        return self._state
 
     def _phase(self, name: str | None, **meta):
         """Name the host work from here to the next ``_phase`` call: one
@@ -1485,6 +1549,12 @@ class Engine:
                 req.page_hashes = page_hashes(req.prompt, self.page_size)
             shareable = min(len(req.page_hashes), (len(req.prompt) - 1) // self.page_size)
             hashes = req.page_hashes[:shareable]
+            if self._state is not None:
+                # a hit is only as deep as the deepest matched page boundary
+                # that also has a state snapshot; pages past it are computed
+                # again on pages of the request's own
+                req.page_match = self._allocator.match_len(hashes)
+                hashes = hashes[:self._state.depth(hashes[:req.page_match])]
         return need, hashes
 
     def _admission_feasible(self) -> bool:
@@ -1555,6 +1625,10 @@ class Engine:
                 self._allocator.fault_ins if self._kv_tier_on else 0
             )
             shared = self._allocator.share(hashes) if hashes else []
+            if self._state is not None and shared:
+                deep = self._state.depth(hashes[:len(shared)])
+                self._allocator.release(shared[deep:])
+                shared = shared[:deep]
             try:
                 pages = shared + self._allocator.allocate(need - len(shared))
             except OutOfPages:
@@ -1582,6 +1656,8 @@ class Engine:
             req.pages_registered = len(shared)
             if shared:
                 self._allocator.hit_tokens += req.cached_tokens
+            if self._state is not None:
+                self._admit_state(req, len(shared))
             if req.resume_pending:
                 # a parked victim is back: its folded prompt prefix-shared
                 # the full pages it parked (device hit or host fault-in);
@@ -1656,7 +1732,7 @@ class Engine:
             # a latent model caps the rows of one wave at the largest row
             # bucket its prefill program is warmed for; the rest, admitted
             # later, ride the next step's wave: step() re-enters here
-            cap = self.cfg.prefill_rows_cap if self._latent else len(prefilling)
+            cap = self.cfg.prefill_rows_cap if self._own_programs else len(prefilling)
             self._prefill_batch(prefilling[:cap], finished)
         return True
 
@@ -1727,6 +1803,10 @@ class Engine:
             completes=int(done_mask.sum()), width=width, padded_tokens=rb * width)
         self.prefill_padded_tokens += rb * width
         self._m_wave[width].inc()
+        state_args = {}
+        if self._state is not None:
+            state_args = self._wave_state(reqs, valids, rb)
+            wave_ann.set_metadata(**self._state_meta())
 
         # ONE program: the chunk, then its tail (prompt tokens into the
         # presence mask, the first token of every completed row drawn, marked
@@ -1746,11 +1826,13 @@ class Engine:
             self._temp, self._top_p, self._top_k, self._rep_pen,
             use_pallas=self.use_pallas,
             k_scales=self._k_scales, v_scales=self._v_scales,
-            int4_kernel=self._int4_kernel, mesh=self.mesh,
+            int4_kernel=self._int4_kernel, mesh=self.mesh, **state_args,
         )
         if self.kv_quant:
             self._k_pages, self._v_pages, self._k_scales, self._v_scales = cache
-        elif self._latent:
+        elif self._own_programs:
+            if self._recurrent:
+                self._state_pools = cache.pop()
             self._k_pages, self._v_pages, moe = cache
             self._moe_dispatched("prefill", moe, 1)
             wave_ann.set_metadata(**self._moe_meta("prefill"))
@@ -1786,6 +1868,77 @@ class Engine:
                 done.append(req)
         if done:
             self._rows_join(done, others_running, finished)
+
+    def _admit_state(self, req: _Request, shared_pages: int) -> None:
+        """A recurrent model's admission: the snapshot the first wave resumes
+        from (the one after the last shared page: ``_head_need_hashes`` shared
+        no deeper), and THE SNAPSHOT POLICY: this prefill owes a snapshot at
+        the branch point the page match revealed (pages the cache held deeper
+        than a snapshot lay: the next prompt of that prefix resumes there) and
+        at the prompt's last shareable page boundary (a repeat, or a longer
+        prompt of the same head).  Two slots a cold prompt at most, not one a
+        chunk: a topic's 8,192-token head costs one slot, not sixteen."""
+        ps = self.page_size
+        self.page_hit_tokens += req.page_match * ps
+        self.state_hit_tokens += shared_pages * ps
+        req.state_src = -1
+        if shared_pages:
+            req.state_src = self._state.take(req.page_hashes[shared_pages - 1])
+            self.state_restored += 1
+        last = min(len(req.page_hashes), (len(req.prompt) - 1) // ps)
+        req.snap_at = sorted({d for d in (req.page_match, last) if d > shared_pages})
+
+    def _wave_state(self, reqs: list[_Request], valids: list[int], rb: int) -> dict:
+        """The state arguments of one wave (models/qwen3_next.py): per wave
+        row, the slot its state comes from (the snapshot it resumes from or
+        none on a request's first wave, its own row's after), its own row's
+        slot for the state after the chunk, and where one is owed inside this
+        chunk, a snapshot slot and the column it is caught at (one a row and
+        wave: the shallowest owed, the branch point before the prompt's own
+        last boundary).  Rows of the bucket past the wave write to the slot
+        nobody reads."""
+        st, ps = self._state, self.page_size
+        src = np.full((rb,), -1, dtype=np.int32)
+        dst = np.full((rb,), st.trash, dtype=np.int32)
+        snap = np.full((rb,), st.trash, dtype=np.int32)
+        snap_col = np.zeros((rb,), dtype=np.int32)
+        for i, req in enumerate(reqs):
+            start, end = req.prefill_pos, req.prefill_pos + valids[i]
+            dst[i] = req.row
+            if start == req.cached_tokens:  # the request's first wave
+                src[i] = req.state_src
+                if req.state_src >= 0:
+                    st.unpin(req.state_src)
+                    req.state_src = -1
+            else:
+                src[i] = req.row
+            owed = [d for d in req.snap_at if start < d * ps <= end]
+            if owed:
+                slot = st.reserve(req.page_hashes[owed[0] - 1])
+                if slot is not None:
+                    snap[i], snap_col[i] = slot, owed[0] * ps - start
+                req.snap_at = [d for d in req.snap_at if d * ps > end]
+        for event, total in (("written", st.written), ("hit", st.hits), ("evicted", st.evicted)):
+            counter = STATE_SNAPSHOTS.labels(event=event)
+            counter.inc(total - self._state_published.get(event, 0))
+            self._state_published[event] = total
+        STATE_SLOTS_IN_USE.set(st.in_use)
+        return {"state": self._state_pools, "state_src": src, "state_dst": dst,
+                "state_snap": snap, "snap_col": snap_col}
+
+    @property
+    def state_snapshots_written(self) -> int:
+        return self._state.written if self._state is not None else 0
+
+    @property
+    def state_snapshots_evicted(self) -> int:
+        return self._state.evicted if self._state is not None else 0
+
+    def _state_meta(self) -> dict:
+        """The state cache's cumulative counts, as an annotation's stats."""
+        return {"state_restored": self.state_restored, "state_snapshots": self._state.written,
+                "state_evicted": self._state.evicted, "page_hit_tokens": self.page_hit_tokens,
+                "state_hit_tokens": self.state_hit_tokens}
 
     def _prefill_batch_packed(
         self, reqs: list[_Request], finished: list[GenerationResult]
@@ -2147,12 +2300,15 @@ class Engine:
         self._m_burst[ahead].inc()
         self._phase("engine.decode_burst", rows=live_rows, kv_tokens=kv_tokens,
                     steps=n_steps, ahead=int(ahead),
-                    **(self._moe_meta("burst") if self._latent else {}))
+                    **(self._moe_meta("burst") if self._own_programs else {}))
         out = self._decode_burst_fn(
             self.params, self.cfg,
             last_d, lens_d,
             self._k_pages, self._v_pages, self._presence,
-            active, self._row_limits, self._block_tables, self._rng,
+            # copies: the host rewrites a row of these at the next release or
+            # admission while this burst may still be running, and the CPU
+            # backend hands a numpy argument to the program without copying it
+            active, self._row_limits.copy(), self._block_tables.copy(), self._rng,
             self._temp, self._top_p, self._top_k, self._rep_pen,
             n_steps=n_steps, use_pallas=self.use_pallas, mesh=self.mesh,
             layer_unroll=self.layer_unroll,
@@ -2171,11 +2327,14 @@ class Engine:
             k_scales=self._k_scales, v_scales=self._v_scales,
             first_tokens=self._first_d, fresh=fresh, fresh_lens=fresh_lens,
             key_step=self._next_key_step(),
+            **({"state": self._state_pools} if self._recurrent else {}),
         )
         if self.kv_quant:
             (toks, _, self._k_pages, self._v_pages, self._presence,
              out_lens, last, self._k_scales, self._v_scales) = out
-        elif self._latent:
+        elif self._own_programs:
+            if self._recurrent:
+                *out, self._state_pools = out
             (toks, _, self._k_pages, self._v_pages, self._presence,
              out_lens, last, moe) = out
             self._moe_dispatched("burst", moe, n_steps)
@@ -2187,7 +2346,7 @@ class Engine:
             "last": last, "lens": out_lens, "pending": toks,
             "first": first_waves,
         }
-        if self._latent:
+        if self._own_programs:
             self._chain["seq"] = self._dispatch_seq
         if prev is not None:
             self._commit_burst(prev, finished)
@@ -2691,6 +2850,9 @@ class Engine:
             finished.append(self._result(req, "length"))
 
     def _release(self, req: _Request) -> None:
+        if req.state_src >= 0:  # admitted to resume from a snapshot, never dispatched
+            self._state.unpin(req.state_src)
+            req.state_src = -1
         if req.claimed_hashes:
             # an unfinished prefill abandons its registration promises
             # (reap/cancel mid-prefill) so held followers aren't stranded

@@ -80,7 +80,9 @@ def make_page_pools(
     cfg: Qwen2Config, num_pages: int, page_size: int, dtype=jnp.bfloat16,
     quant=False,
 ) -> PagePools:
-    shape = (cfg.num_layers, cfg.num_kv_heads, num_pages, page_size, cfg.head_dim)
+    # a hybrid model pages keys and values in some of its layers only
+    layers = getattr(cfg, "kv_layers", cfg.num_layers)
+    shape = (layers, cfg.num_kv_heads, num_pages, page_size, cfg.head_dim)
     bits = quant_bits(quant)
     if getattr(cfg, "latent_kv", False):
         if bits:
@@ -359,6 +361,9 @@ class PrefixCachingAllocator(_ObserverSeam):
         # zero-ref cached pages, least-recently-used first (dict = ordered)
         self._lru: dict[int, None] = {}
         self.hit_tokens = 0  # stats: prompt tokens served from cache
+        # called with the chain hash of a cached page as it is evicted: what
+        # else is keyed by that hash (a state snapshot, StateSlots) goes too
+        self.on_evict = None
 
     @property
     def free_count(self) -> int:
@@ -376,6 +381,8 @@ class PrefixCachingAllocator(_ObserverSeam):
                 del self._lru[page]
                 h = self._page_to_hash.pop(page)
                 del self._hash_to_page[h]
+                if self.on_evict is not None:
+                    self.on_evict(h)
             self._rc[page] = 1
             out.append(page)
         self._note_claims(n)
@@ -427,6 +434,16 @@ class PrefixCachingAllocator(_ObserverSeam):
                 parked += 1
         avail = len(self._free) + len(self._lru) - parked + extra_free
         return avail >= need - matched + headroom
+
+    def match_len(self, hashes: list[bytes]) -> int:
+        """Pages ``share(hashes)`` would hand out, claiming nothing."""
+        seen: set[int] = set()
+        for h in hashes:
+            page = self._hash_to_page.get(h)
+            if page is None or page in seen:
+                break
+            seen.add(page)
+        return len(seen)
 
     def share(self, hashes: list[bytes]) -> list[int]:
         """Claim the longest cached run matching ``hashes``: refcounts bump,
@@ -855,6 +872,87 @@ class TieredPageAllocator(PrefixCachingAllocator):
             else:
                 break
         return n
+
+
+class StateSlots:
+    """Host-side ledger of a recurrent model's state pool (models/
+    qwen3_next.py): which slot holds what.  The pool's first ``rows`` slots
+    are the engine's rows' LIVE state (row r = slot r: nothing to allocate);
+    ``snapshots`` SNAPSHOT slots follow, each a copy of a sequence's state at
+    a page boundary, keyed by the chain hash of the page that ends there (the
+    prefix cache's own key: a prefix whose pages match and whose last page's
+    hash has a snapshot can resume there); one more slot takes the writes of
+    wave rows that have nothing to write.  Snapshots leave by LRU within
+    their slots and with their page (``PrefixCachingAllocator.on_evict``).  A
+    slot is pinned from the admission that will resume from it until the
+    wave that reads it is dispatched.  The copies themselves happen on the
+    device, inside the wave program; decisions here are made in dispatch
+    order, which is the order the device runs them in."""
+
+    def __init__(self, rows: int, snapshots: int) -> None:
+        self.rows, self.snapshots = rows, snapshots
+        self.trash = rows + snapshots
+        self._free = list(range(rows + snapshots - 1, rows - 1, -1))
+        self._by_hash: dict[bytes, int] = {}  # least recently used first
+        self._pins: dict[int, int] = {}
+        self.written = self.hits = self.evicted = 0
+
+    @property
+    def total(self) -> int:
+        return self.rows + self.snapshots + 1
+
+    @property
+    def in_use(self) -> int:
+        return len(self._by_hash)
+
+    def depth(self, hashes: list[bytes]) -> int:
+        """The deepest page count d <= len(hashes) with a snapshot after page
+        d (keyed ``hashes[d - 1]``); 0 where there is none."""
+        for d in range(len(hashes), 0, -1):
+            if hashes[d - 1] in self._by_hash:
+                return d
+        return 0
+
+    def take(self, h: bytes) -> int:
+        """The slot of ``h``'s snapshot for an admission that resumes from it:
+        most recently used, counted a hit, pinned until ``unpin``."""
+        slot = self._by_hash.pop(h)
+        self._by_hash[h] = slot
+        self._pins[slot] = self._pins.get(slot, 0) + 1
+        self.hits += 1
+        return slot
+
+    def unpin(self, slot: int) -> None:
+        left = self._pins.get(slot, 0) - 1
+        if left > 0:
+            self._pins[slot] = left
+        else:
+            self._pins.pop(slot, None)
+
+    def reserve(self, h: bytes) -> int | None:
+        """A slot for a snapshot keyed ``h`` that the wave being built will
+        write, evicting the least recently used unpinned one if none is
+        free.  None where ``h`` already has one, or every slot is pinned."""
+        if h in self._by_hash:
+            return None
+        if self._free:
+            slot = self._free.pop()
+        else:
+            old = next((k for k, s in self._by_hash.items() if s not in self._pins), None)
+            if old is None:
+                return None
+            slot = self._by_hash.pop(old)
+            self.evicted += 1
+        self._by_hash[h] = slot
+        self.written += 1
+        return slot
+
+    def drop(self, h: bytes) -> None:
+        """``h``'s page was evicted: its snapshot goes with it."""
+        slot = self._by_hash.pop(h, None)
+        if slot is not None:
+            self._free.append(slot)
+            self.evicted += 1
 
 
 def pages_needed(num_tokens: int, page_size: int) -> int:

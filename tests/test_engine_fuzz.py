@@ -17,6 +17,7 @@ and checks the global invariants after every episode:
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from githubrepostorag_tpu.serving import Engine, SamplingParams
@@ -66,6 +67,38 @@ def test_random_schedule_episode(tiny, extra):
                       max_seq_len=128, kv_dtype=jnp.float32,
                       **{"prefill_chunk": 16, "decode_burst": 4, **extra})
 
+    run_episode(make, cfg, rng)
+
+
+@pytest.mark.parametrize("extra", [dict(), dict(state_snapshots=2, prefill_chunk=32)],
+                         ids=["hybrid", "hybrid-two-snapshots"])
+def test_random_schedule_episode_hybrid(extra, monkeypatch):
+    """The same episode over the hybrid family (models/qwen3_next.py: Gated
+    DeltaNet state beside K/V pages, in float32 so that a resumed prefix and a
+    cold one agree to rounding): prefix hits resume from state snapshots, two
+    snapshot slots make them turn over, and no slot stays pinned."""
+    from githubrepostorag_tpu.models import qwen3_next
+
+    monkeypatch.setattr(qwen3_next, "ACT", jnp.float32)
+    cfg = qwen3_next.Qwen3NextConfig.tiny(num_layers=4, experts_held=(4, 12))
+    params = jax.tree.map(lambda x: x.astype(jnp.float32), qwen3_next.init_params(cfg, seed=3))
+    made = []
+
+    def make():
+        made.append(Engine(params, cfg, max_num_seqs=4, num_pages=48, page_size=8,
+                           max_seq_len=128, kv_dtype=jnp.float32,
+                           **{"prefill_chunk": 16, "decode_burst": 4, **extra}))
+        return made[-1]
+
+    run_episode(make, cfg, np.random.default_rng(34))
+    eng = made[0]  # the episode's engine (solo runs come after it)
+    assert eng.state_restored > 0 and not eng._state._pins
+    assert eng._state.in_use <= eng._state.snapshots
+
+
+def run_episode(make, cfg, rng):
+    """One random schedule over the engine ``make`` builds, checked against
+    solo runs on fresh engines from the same factory."""
     # a small pool of prompts, some sharing prefixes (prefix-cache traffic)
     base = rng.integers(0, cfg.vocab_size, 40).tolist()
     prompts = [

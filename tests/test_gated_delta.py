@@ -1,0 +1,130 @@
+"""The Gated DeltaNet rule in its three forms: the chunked (WY) form of a
+prefill wave, the one-token form of the burst (ops/gated_delta.py) and the
+benchmark's reference recurrence (benchmarks/reference_qwen3_next.py, which
+imports nothing of the program) agree; across block and chunk boundaries,
+from a state that is not zero, with padded columns at each of the wave's
+rungs, and with rows that sit a burst step out.
+
+Tolerances: everything here is float32 on the CPU, and the three forms sum
+the same terms in different orders; 2e-5 absolute on outputs and states of
+order one is a few hundred roundings, not a different formula (a missing
+decay or a transposed state reads 1e-1 and more)."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks import reference_qwen3_next as ref
+from githubrepostorag_tpu.ops import gated_delta as gd
+
+TOL = 2e-5
+
+
+def inputs(rows, t, h=3, dk=16, dv=8, seed=0, alike=0.0):
+    rng = np.random.default_rng(seed)
+    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa: E731
+    base = rng.normal(size=(rows, 1, h, dk))
+    q = gd.l2norm(f(rows, t, h, dk)) * dk ** -0.5
+    k = gd.l2norm(jnp.asarray(alike * base, jnp.float32) + f(rows, t, h, dk))
+    g = -jnp.asarray(rng.uniform(0.001, 0.6, size=(rows, t, h)), jnp.float32)
+    beta = jnp.asarray(rng.uniform(0.05, 1.0, size=(rows, t, h)), jnp.float32)
+    return q, k, f(rows, t, h, dv), g, beta, f(rows, h, dk, dv)
+
+
+def by_steps(q, k, v, g, beta, state, n):
+    outs = []
+    for t in range(n):
+        o, state = gd.gated_delta_step(state, q[:, t], k[:, t], v[:, t], g[:, t], beta[:, t])
+        outs.append(o)
+    return jnp.stack(outs, axis=1), state
+
+
+@pytest.mark.parametrize("alike", [0.0, 3.0], ids=["keys-apart", "keys-alike"])
+def test_chunked_one_token_and_reference_forms_agree_from_a_nonzero_state(alike):
+    """192 tokens are three blocks of 64: the state crosses two block
+    boundaries.  ``keys-alike`` puts neighbouring keys at cosine ~0.9, where a
+    Neumann series over the whole block cancels (4e-3); the halved inverse
+    does not."""
+    q, k, v, g, beta, s0 = inputs(2, 192, alike=alike)
+    o_c, s_c, _ = gd.gated_delta_chunked(s0, q, k, v, g, beta)
+    o_s, s_s = by_steps(q, k, v, g, beta, s0, 192)
+    np.testing.assert_allclose(o_c, o_s, atol=TOL)
+    np.testing.assert_allclose(s_c, s_s, atol=TOL)
+    for r in range(2):
+        o_r, s_r = ref.recurrence(q[r], k[r], v[r], g[r], beta[r], state=s0[r])
+        np.testing.assert_allclose(o_c[r], o_r, atol=TOL)
+        np.testing.assert_allclose(s_c[r], s_r, atol=TOL)
+
+
+def test_a_prompt_cut_into_chunks_is_the_prompt_whole_and_a_snapshot_is_its_state_there():
+    """Two chunks of 128 hand the state on as one of 256 carries it, and the
+    snapshot caught at column 64 / 128 is the state the one-token form has
+    after that many tokens (a row that asks for none gets its state back)."""
+    q, k, v, g, beta, s0 = inputs(3, 256, seed=1)
+    o_w, s_w, _ = gd.gated_delta_chunked(s0, q, k, v, g, beta)
+    cut = lambda x, a, b: x[:, a:b]  # noqa: E731
+    o_1, s_1, snap = gd.gated_delta_chunked(
+        s0, *(cut(x, 0, 128) for x in (q, k, v, g, beta)), snap_col=jnp.asarray([64, 128, 0]))
+    o_2, s_2, _ = gd.gated_delta_chunked(s_1, *(cut(x, 128, 256) for x in (q, k, v, g, beta)))
+    np.testing.assert_allclose(jnp.concatenate([o_1, o_2], axis=1), o_w, atol=TOL)
+    np.testing.assert_allclose(s_2, s_w, atol=TOL)
+    np.testing.assert_allclose(snap[0], by_steps(q, k, v, g, beta, s0, 64)[1][0], atol=TOL)
+    np.testing.assert_allclose(snap[1], s_1[1], atol=TOL)
+    assert bool((snap[2] == s0[2]).all())
+
+
+@pytest.mark.parametrize("width", [128, 256, 512])
+def test_padded_columns_of_a_rung_leave_the_state_bit_identical(width):
+    """A wave's row of 70 real tokens at each rung of the width ladder: the
+    state after it is the state after 70 one-token steps, and a row with no
+    real token keeps its state bit for bit."""
+    q, k, v, g, beta, s0 = inputs(2, width, seed=width, h=2, dk=8, dv=8)
+    live = jnp.arange(width)[None, :] < jnp.asarray([70, 0])[:, None]
+    km, gm, bm = gd.mask_padding(live, k, g, beta)
+    o, s, _ = gd.gated_delta_chunked(s0, q, km, v, gm, bm)
+    o_s, s_s = by_steps(q, k, v, g, beta, s0, 70)
+    np.testing.assert_allclose(o[0, :70], o_s[0], atol=TOL)
+    np.testing.assert_allclose(s[0], s_s[0], atol=TOL)
+    assert bool((s[1] == s0[1]).all())
+
+
+def test_a_small_page_sets_the_block_and_the_snapshot_falls_between_blocks():
+    """Pages of 16 (the rehearsal's): blocks of 16, a snapshot at column 48."""
+    q, k, v, g, beta, s0 = inputs(1, 64, seed=5)
+    o, s, snap = gd.gated_delta_chunked(s0, q, k, v, g, beta, snap_col=jnp.asarray([48]), block=16)
+    o_s, s_s = by_steps(q, k, v, g, beta, s0, 64)
+    np.testing.assert_allclose(o, o_s, atol=TOL)
+    np.testing.assert_allclose(s, s_s, atol=TOL)
+    np.testing.assert_allclose(snap, by_steps(q, k, v, g, beta, s0, 48)[1], atol=TOL)
+
+
+def test_the_convolution_carries_its_history_across_chunks_and_steps():
+    """Chunk + chunk + one-token steps = the reference's plain causal
+    convolution over the whole sequence; the history after a row's real
+    tokens is its last three inputs, and a row with none keeps its history."""
+    rng = np.random.default_rng(2)
+    x = jnp.asarray(rng.normal(size=(2, 40, 6)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(6, 4)), jnp.float32)
+    taps0 = jnp.zeros((2, 3, 6), jnp.float32)
+    want = jnp.stack([ref.causal_conv(x[r], w) for r in range(2)])
+    y1, taps, snap = gd.causal_conv(x[:, :16], taps0, w, jnp.asarray([16, 16]),
+                                    snap_col=jnp.asarray([8, 0]))
+    np.testing.assert_allclose(snap[0], x[0, 5:8], atol=0)
+    np.testing.assert_allclose(snap[1], taps0[1], atol=0)
+    # the second chunk is padded: 20 real tokens of 32 columns, and none in row 1
+    pad = jnp.concatenate([x[:, 16:36], jnp.ones((2, 12, 6))], axis=1)
+    y2, taps2, _ = gd.causal_conv(pad, taps, w, jnp.asarray([20, 0]))
+    np.testing.assert_allclose(taps2[0], x[0, 33:36], atol=0)
+    assert bool((taps2[1] == taps[1]).all())
+    got, hist = [y1[0], y2[0, :20]], taps2[:1]
+    for t in range(36, 40):
+        y, hist = gd.causal_conv_step(x[:1, t], hist, w)
+        got.append(y)
+    np.testing.assert_allclose(jnp.concatenate(got), want[0], atol=TOL)
+
+
+def test_l2norm_is_the_published_kernels():
+    x = jnp.asarray([[3.0, 4.0]])
+    np.testing.assert_allclose(gd.l2norm(x), x / np.sqrt(25.0 + 1e-6), rtol=1e-6)
+    assert jax.numpy.isfinite(gd.l2norm(jnp.zeros((1, 4)))).all()

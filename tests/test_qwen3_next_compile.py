@@ -1,0 +1,194 @@
+"""Qwen3-Next's two step programs compiled whole for a TPU v5e that is
+described, not attached, at the shapes of the benchmark's cell
+(``qwen3-next-80b-a3b-ep4-bf16``: published widths, two periods, 128 experts
+held, 2,048 pages of 128 tokens for the 2 attention layers, 32 live + 64
+snapshot + 1 slots of state for the 6 Gated DeltaNet layers): both paged
+kernels pass the chip's compiler at head size 256 with a group of 8; nothing
+in the optimized HLO copies, transposes or slices a K/V pool, the state pool
+or an expert stack (the state pool is WRITTEN in place, a row's slot at a
+time, and by nothing else); and the ops that the benchmark's metrics pick out
+of a trace by their shapes are the ops under the scopes they are meant to
+read.  Nothing executes; a pass here is not a chip run.
+"""
+
+import functools
+import os
+import re
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from tests.test_tpu_compile import (  # noqa: F401 - fixtures
+    assert_wave_keeps_in_place,
+    chip,
+    pool_movers,
+    topo,
+)
+
+PAGES, PAGE, ROWS, ROW_PAGES, SLOTS = 2048, 128, 32, 80, 97
+SCOPES = ("gdn_proj", "gdn_conv", "gdn_chunked", "gdn_recurrent", "gdn_gate_norm", "attn_gate",
+          "state_read", "state_write", "paged_attention", "kv_write", "moe_route", "moe_experts",
+          "moe_shared", "sample")
+
+
+def cell_config():
+    from githubrepostorag_tpu.models.qwen3_next import Qwen3NextConfig
+
+    return Qwen3NextConfig(vocab_size=37984, num_layers=8, experts_held=(0, 128))
+
+
+@pytest.fixture()
+def as_on_chip(monkeypatch):
+    import githubrepostorag_tpu.models.qwen3_next as model
+    import githubrepostorag_tpu.ops.fused_decode as fused_decode
+    import githubrepostorag_tpu.ops.latent_attention as latent
+
+    for mod in (model, fused_decode, latent):
+        monkeypatch.setattr(mod, "on_tpu", lambda: True)
+
+
+@functools.lru_cache(maxsize=None)
+def compiled(where, program: str, rows: int):
+    """(optimized HLO, the shapes of what must stay in place) of the burst or
+    of the wave at a row bucket.  Compiled once a module: a wave of one row
+    holds three rungs of eight layers and takes a minute."""
+    from githubrepostorag_tpu.models.qwen3_next import (
+        decode_burst,
+        forward_paged_wave,
+        init_params,
+        make_state_pools,
+    )
+
+    cfg = cell_config()
+    shaped = lambda t: jax.tree.map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=where), t)
+    params = shaped(jax.eval_shape(lambda: init_params(cfg, 0)))
+    state = shaped(jax.eval_shape(lambda: make_state_pools(cfg, SLOTS)))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=where)
+
+    kv_shape = (cfg.kv_layers, cfg.num_kv_heads, PAGES, PAGE, cfg.head_dim)
+    kp, vp = sds(kv_shape, jnp.bfloat16), sds(kv_shape, jnp.bfloat16)
+    b, i32, f32 = ROWS, jnp.int32, jnp.float32
+    if program == "burst":
+        lowered = decode_burst.lower(
+            params, cfg, sds((b,), i32), sds((b,), i32), kp, vp,
+            sds((b, cfg.vocab_size), jnp.bool_), sds((b,), jnp.bool_), sds((b,), i32),
+            sds((b, ROW_PAGES), i32), sds((2,), jnp.uint32), sds((b,), f32), sds((b,), f32),
+            sds((b,), i32), sds((b,), f32), n_steps=8, use_pallas=True, filter_sampling=False,
+            first_tokens=sds((b,), i32), fresh=sds((b,), jnp.bool_), fresh_lens=sds((b,), i32),
+            key_step=sds((), jnp.uint32), state=state)
+    else:
+        chunk, row = (rows, 512), (rows,)
+        lowered = forward_paged_wave.lower(
+            params, cfg, sds(chunk, i32), sds(chunk, i32), kp, vp,
+            sds((b, cfg.vocab_size), jnp.bool_), sds((b,), i32), sds(chunk, i32),
+            sds((rows, ROW_PAGES), i32), sds(row, i32), sds(row, i32), sds(row, i32),
+            sds(row, i32), sds(row, jnp.bool_), sds((), i32), sds((2,), jnp.uint32),
+            sds((), jnp.uint32), sds((b,), f32), sds((b,), f32), sds((b,), i32), sds((b,), f32),
+            use_pallas=True, state=state, state_src=sds(row, i32), state_dst=sds(row, i32),
+            state_snap=sds(row, i32), snap_col=sds(row, i32))
+    pools = {"kv": kv_shape, "s": state["s"].shape, "conv": state["conv"].shape,
+             "e_wgu": params["moe"]["e_wgu"].shape, "e_wd": params["moe"]["e_wd"].shape}
+    return lowered.compile().as_text(), pools
+
+
+def timed_ops(hlo: str):
+    """(name as a trace shows it, the scope it was traced under or '') of what
+    a trace times: fusions, copies, slices and custom calls of the entry and
+    loop computations, not the instructions fused into them."""
+    from benchmarks.trace import short_name
+
+    fused = False
+    for line in hlo.splitlines():
+        head = re.match(r"^(ENTRY )?%?(\S+) \(.*\) -> .* \{$", line)
+        if head:
+            fused = head.group(2).startswith("fused_")
+        line = line.strip().removeprefix("ROOT ")
+        if fused or not re.search(
+                r" (fusion|copy|custom-call|dynamic-slice|dynamic-update-slice)\(", line):
+            continue
+        path = re.search(r'op_name="([^"]*)"', line)
+        scope = next((s for s in SCOPES if path and f"/{s}/" in path.group(1) + "/"), "")
+        yield short_name(line)[0], scope
+
+
+@pytest.mark.parametrize("program,rows,writes", [
+    pytest.param("burst", 0, 6, id="burst"),        # a Gated DeltaNet layer: one write of its rows
+    pytest.param("wave", 1, 6, id="wave-1x512"),    # a row: its state after the chunk, its snapshot
+    pytest.param("wave", 8, 48, id="wave-8x512"),
+])
+def test_step_program_leaves_pools_and_experts_in_place(chip, as_on_chip, program, rows, writes):
+    hlo, pools = compiled(chip, program, rows)
+    assert "tpu_custom_call" in hlo  # the paged kernel of the burst, or of the prefill
+    for name in ("kv", "e_wgu", "e_wd"):
+        assert pool_movers(hlo, pools[name]) == [], name
+    for name in ("s", "conv"):  # written in place, a slot (the burst: its rows) at a time
+        movers = pool_movers(hlo, pools[name])
+        assert all(m.startswith("dynamic_update_slice") for m in movers), (name, movers)
+        assert len(movers) == writes, (name, movers)
+
+
+def test_the_wave_is_one_program_that_donates_every_pool_and_keeps_them_out_of_its_switches(
+        chip, as_on_chip):
+    hlo, _ = compiled(chip, "wave", 1)
+    held = (rf"bf16\[2,2,{PAGES},{PAGE},256\]|f32\[6,{SLOTS},32,128,128\]|bf16\[6,{SLOTS},24576\]"
+            rf"|pred\[{ROWS},37984\]")
+    assert_wave_keeps_in_place(hlo, held, 5)
+    switches = [ln for ln in hlo.splitlines() if re.search(r" conditional\(", ln)
+                and len(re.search(r"branch_computations=\{([^}]*)\}", ln).group(1).split(",")) == 3]
+    assert len(switches) >= 5  # a layer at 512 / 256 / 128 columns: 3 + 2 switches a period
+    for ln in switches:  # a pool a branch is handed is copied into it and out of it
+        assert not re.search(r"\[(2,2,2048,128,256|6,97,32,128,128|6,97,24576)\]", ln), ln[:200]
+
+
+def _picked(hlo, pattern):
+    by_scope = {}
+    for name, scope in timed_ops(hlo):
+        if pattern.search(name):
+            by_scope.setdefault(scope, set()).add(name)
+    return by_scope
+
+
+def test_the_metrics_select_the_ops_under_their_scopes(chip, as_on_chip):
+    """A trace's device plane names instructions, not scopes, so the metrics
+    find their ops by name and output shape; the compiled programs' own
+    metadata says which scope each came from."""
+    from benchmarks import manifest
+    from benchmarks.families import qwen3_next as family
+
+    cell = manifest.load_cell("qwen3-next-80b-a3b-ep4-bf16.repo-sessions")
+    model = family.model_of(cell.config, rehearse=False)
+    burst, _ = compiled(chip, "burst", 0)
+    wave, _ = compiled(chip, "wave", 1)
+    spec = lambda name: manifest.metric_spec(name)["args"]  # noqa: E731
+
+    experts = re.compile(spec("moe_experts_hbm_frac")["op"].format(
+        **family.expert_op_sizes(model, cell.config)))
+    got = _picked(burst, experts)
+    assert set(got) == {"moe_experts"} and len(got["moe_experts"]) == 16  # 2 products x 8 layers
+    # the shared expert is 512 wide too: its products keep three axes and are not picked
+    assert any(re.search(r"_bf16_32_1_1024_$", n) for n, s in timed_ops(burst) if s == "moe_shared")
+
+    decode = _picked(burst, re.compile(spec("gdn_decode_roofline_frac")["op"]))
+    assert set(decode) == {"gdn_recurrent"} and len(decode["gdn_recurrent"]) == 12  # 2 passes x 6 layers
+    assert _picked(wave, re.compile(spec("gdn_decode_roofline_frac")["op"])) == {}
+
+    chunked = re.compile(spec("gdn_prefill_roofline_frac")["op"].format(
+        **family.state_op_sizes(model, cell.config)))
+    got = _picked(wave, chunked)
+    assert "gdn_chunked" in got and set(got) <= {"gdn_chunked", "state_read", ""}
+    assert _picked(burst, chunked) == {}
+
+    moves = re.compile(spec("state_pool_move_share")["pattern"])
+    assert set(_picked(wave, moves)) <= {"state_write"}  # the in-place row writes, nothing else
+    assert set(_picked(burst, moves)) <= {"gdn_recurrent", "gdn_conv", ""}
+
+    # the burst's attention kernel is named for its scope, where the accepted metric looks
+    paged = re.compile(manifest.metric_spec("paged_attn_hbm_frac")["args"]["op"])
+    names = {n for n, _ in timed_ops(burst) if paged.search(n)}
+    assert names and all(n.startswith("paged_attention") for n in names)
