@@ -218,13 +218,36 @@ def decay_ladder(cfg: Qwen3NextConfig) -> jnp.ndarray:
     return jnp.linspace(math.log(1e-3), 0.0, hv, dtype=jnp.float32)
 
 
+def _by_kind(cfg, w_qkvz, w_ba):
+    """The Gated DeltaNet projections' columns from the published order,
+    grouped by key head (``q | k | v (r value heads) | z (r)`` and ``b (r) |
+    a (r)``, a key head after another), into the order ``_gdn_inputs`` reads:
+    ``q | k | v | z`` and ``b | a``, each of every head, the value heads in
+    ``_gdn_heads``' order (a key head's r side by side).  Read as published,
+    the product's columns are regrouped after it, and the v5e compiler folds
+    that into the weight: it transposed the 302 MB stack once a burst and a
+    wave and wrote all six layers of it out again every step (PERF.md,
+    Findings, PR 35)."""
+    hk, r = cfg.linear_num_key_heads, cfg.linear_num_value_heads // cfg.linear_num_key_heads
+    dk, rdv = cfg.linear_key_head_dim, r * cfg.linear_value_head_dim
+
+    def regroup(w, widths):
+        heads = w.reshape(*w.shape[:-1], hk, sum(widths))
+        kinds = jnp.split(heads, [sum(widths[:i]) for i in range(1, len(widths))], axis=-1)
+        return jnp.concatenate([k.reshape(*w.shape[:-1], -1) for k in kinds], axis=-1)
+
+    return regroup(w_qkvz, (dk, dk, rdv, rdv)), regroup(w_ba, (r, r))
+
+
 def init_params(cfg: Qwen3NextConfig, seed: int = 0) -> dict:
     """Weights made on the device from the seed, leaf by leaf, in bfloat16
     (models/quant._devrand), as DeepSeek-V3's are.  Zero-centred norms at
     zero (a scale of one), the Gated DeltaNet output norm at one, ``dt_bias``
     at one, ``A_log`` the ``decay_ladder``.  The expert stacks hold the
     ``experts_held`` range only.  ``wq | wk | wv`` are then laid side by side
-    as the one product the attention layer runs."""
+    as the one product the attention layer runs, and the Gated DeltaNet
+    projections' columns, drawn in the published order, are put once into
+    the order their products are read in (``_by_kind``)."""
     salt = jnp.uint32(seed * 40503 + 12345)
     params: dict = {"norm": jnp.zeros((cfg.hidden_size,), jnp.bfloat16)}
     draw = jax.jit(_devrand, static_argnums=(0, 2))
@@ -239,10 +262,12 @@ def init_params(cfg: Qwen3NextConfig, seed: int = 0) -> dict:
         node[path[-1]] = leaf
     attn = params["attn"]
     attn["wqkv"] = jnp.concatenate([attn.pop("wq"), attn.pop("wk"), attn.pop("wv")], axis=-1)
+    gdn = params["gdn"]
+    gdn["w_qkvz"], gdn["w_ba"] = jax.jit(partial(_by_kind, cfg))(gdn["w_qkvz"], gdn["w_ba"])
     L, P, G = cfg.num_layers, cfg.periods, cfg.gdn_layers
     attn.update(q_norm=jnp.zeros((P, cfg.head_dim), jnp.bfloat16),
                 k_norm=jnp.zeros((P, cfg.head_dim), jnp.bfloat16))
-    params["gdn"].update(
+    gdn.update(
         A_log=jnp.tile(decay_ladder(cfg)[None], (G, 1)),
         dt_bias=jnp.ones((G, cfg.linear_num_value_heads), jnp.float32),
         o_norm=jnp.ones((G, cfg.linear_value_head_dim), jnp.bfloat16))
@@ -266,20 +291,28 @@ def _swiglu(x, wgu, wd):
 def _gdn_inputs(cfg, p, x):
     """x [B, S, d] normed -> (the convolution's input [B, S, C]: q | k | v of
     the linear heads, in bfloat16 as the history keeps it; z [B, S, Hv, dv];
-    beta, g [B, S, Hv]).  The projections are laid out as published: grouped
-    by key head, ``q | k | v (r heads) | z (r heads)`` and ``b (r) | a (r)``."""
+    beta, g [B, S, Hv]).  The projections' columns lie as they are read
+    (``_by_kind``): ``q | k | v | z`` and ``b | a``.
+
+    The burst (one token a row) runs ``w_qkvz`` as one product and cuts its
+    columns after it; a chunk runs two products, each on its own columns of
+    the leaf.  Either program, compiled for a v5e, then reads the stack where
+    it lies, and neither form serves the other: cut before the product, the
+    burst transposes the whole stack once a burst; cut after it, a wave of
+    eight rows never came back from the chip (PERF.md, Findings, PR 35)."""
     b, s, _ = x.shape
-    hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
-    dk, dv, r = cfg.linear_key_head_dim, cfg.linear_value_head_dim, hv // hk
+    hv, dv, c = cfg.linear_num_value_heads, cfg.linear_value_head_dim, cfg.conv_channels
     with jax.named_scope("gdn_proj"):
-        qkvz = einsum_f32("bsd,de->bse", x, p["w_qkvz"]).reshape(b, s, hk, 2 * dk + 2 * r * dv)
-        parts = (qkvz[..., :dk], qkvz[..., dk:2 * dk], qkvz[..., 2 * dk:2 * dk + r * dv])
-        mixed = jnp.concatenate([t.reshape(b, s, -1) for t in parts], axis=-1).astype(ACT)
-        z = qkvz[..., 2 * dk + r * dv:].reshape(b, s, hv, dv)
-        ba = einsum_f32("bsd,de->bse", x, p["w_ba"]).reshape(b, s, hk, 2 * r)
-        beta = jax.nn.sigmoid(ba[..., :r].reshape(b, s, hv))
-        g = -jnp.exp(p["A_log"]) * jax.nn.softplus(ba[..., r:].reshape(b, s, hv) + p["dt_bias"])
-    return mixed, z, beta, g
+        if s == 1:
+            qkvz = einsum_f32("bsd,de->bse", x, p["w_qkvz"])
+            mixed, z = qkvz[..., :c], qkvz[..., c:]
+        else:
+            mixed, z = (einsum_f32("bsd,de->bse", x, w)
+                        for w in (p["w_qkvz"][:, :c], p["w_qkvz"][:, c:]))
+        ba = einsum_f32("bsd,de->bse", x, p["w_ba"])
+        beta = jax.nn.sigmoid(ba[..., :hv])
+        g = -jnp.exp(p["A_log"]) * jax.nn.softplus(ba[..., hv:] + p["dt_bias"])
+    return mixed.astype(ACT), z.reshape(b, s, hv, dv), beta, g
 
 
 def _gdn_heads(cfg, y):

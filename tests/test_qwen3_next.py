@@ -185,3 +185,139 @@ def test_zero_centred_and_gated_norms_and_the_partial_rotary_slice():
     np.testing.assert_allclose(y[..., 4:], x[None, :1, 4:])  # the rest passes through
     np.testing.assert_allclose(y[0, 0, :4], ref._rope(x[:1, None, :], pos[0], 4, 1e7)[0, 0, :4],
                                rtol=1e-5)
+
+
+# ---- the Gated DeltaNet projections as stored (PR 35): drawn as published,
+# their columns put once into the order the product is read in
+
+def published_split(cfg, p, x):
+    """``_gdn_inputs`` as the published model writes it, on the projections as
+    DRAWN: the product's columns grouped by key head, ``q | k | v (r value
+    heads) | z (r)`` and ``b (r) | a (r)``, split and laid side by side."""
+    from githubrepostorag_tpu.ops.latent_attention import einsum_f32
+
+    b, s, _ = x.shape
+    hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    dk, dv, r = cfg.linear_key_head_dim, cfg.linear_value_head_dim, hv // hk
+    f32 = lambda w: einsum_f32("bsd,de->bse", x, w)  # noqa: E731 - the program's own product
+    qkvz = f32(p["w_qkvz"]).reshape(b, s, hk, 2 * dk + 2 * r * dv)
+    parts = (qkvz[..., :dk], qkvz[..., dk:2 * dk], qkvz[..., 2 * dk:2 * dk + r * dv])
+    mixed = jnp.concatenate([t.reshape(b, s, -1) for t in parts], axis=-1).astype(model.ACT)
+    z = qkvz[..., 2 * dk + r * dv:].reshape(b, s, hv, dv)
+    ba = f32(p["w_ba"]).reshape(b, s, hk, 2 * r)
+    beta = jax.nn.sigmoid(ba[..., :r].reshape(b, s, hv))
+    g = -jnp.exp(p["A_log"]) * jax.nn.softplus(ba[..., r:].reshape(b, s, hv) + p["dt_bias"])
+    return mixed, z, beta, g
+
+
+def drawn(cfg, name):
+    """A leaf as ``leaf_order`` names it: the reference's own draw of it
+    (benchmarks/reference_qwen3_next.py), which knows nothing of the stored order."""
+    spec = dict(MODEL, linear_num_key_heads=cfg.linear_num_key_heads,
+                linear_num_value_heads=cfg.linear_num_value_heads)
+    w = ref.Weights(spec, SEED)
+    return np.stack([np.asarray(w.at(name, g)) for g in range(cfg.gdn_layers)])
+
+
+def published_columns(cfg, widths):
+    """For each stored column, the published column it holds: kind after kind
+    (``widths`` a key head), and inside a kind key head after key head."""
+    hk, group = cfg.linear_num_key_heads, sum(widths)
+    starts = np.cumsum([0, *widths[:-1]])
+    return np.concatenate([head * group + start + np.arange(width)
+                           for start, width in zip(starts, widths) for head in range(hk)])
+
+
+@pytest.mark.parametrize("value_heads", [2, 4], ids=["one-value-head-a-key-head", "two"])
+def test_stored_projections_are_the_published_draw_with_its_columns_permuted(value_heads):
+    cfg = model.Qwen3NextConfig.tiny(experts_held=(4, 12), linear_num_value_heads=value_heads)
+    gdn = model.init_params(cfg, seed=SEED)["gdn"]
+    dk, dv, r = cfg.linear_key_head_dim, cfg.linear_value_head_dim, value_heads // 2
+    for name, widths in (("w_qkvz", (dk, dk, r * dv, r * dv)), ("w_ba", (r, r))):
+        want, stored = drawn(cfg, f"gdn.{name}"), np.asarray(gdn[name], np.float32)
+        cols = published_columns(cfg, widths)
+        assert sorted(cols) == list(range(want.shape[-1]))  # a permutation, nothing else
+        assert stored.shape == want.shape and (stored == want[..., cols]).all(), name
+    if r > 1:  # grouped by key head is another order than kind after kind
+        assert not (np.asarray(gdn["w_qkvz"], np.float32) == drawn(cfg, "gdn.w_qkvz")).all()
+
+
+@pytest.mark.parametrize("value_heads", [2, 4], ids=["one-value-head-a-key-head", "two"])
+def test_gdn_inputs_on_the_stored_leaf_is_the_published_split_on_the_drawn_leaf(value_heads):
+    cfg = model.Qwen3NextConfig.tiny(experts_held=(4, 12), linear_num_value_heads=value_heads)
+    stored = jax.tree.map(lambda w: w[1], model.init_params(cfg, seed=SEED)["gdn"])
+    as_drawn = dict(stored, **{name: jnp.asarray(drawn(cfg, f"gdn.{name}")[1], jnp.bfloat16)
+                               for name in ("w_qkvz", "w_ba")})
+    for shape in ((2, 7), (3, 1)):  # a chunk's two products, the burst's one (one token a row)
+        x = jnp.asarray(np.random.default_rng(5).normal(size=(*shape, cfg.hidden_size)), model.ACT)
+        got, want = model._gdn_inputs(cfg, stored, x), published_split(cfg, as_drawn, x)
+        for name, a, b in zip(("mixed", "z", "beta", "g"), got, want):
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            assert a.shape == b.shape and (a == b).all(), (shape, name)  # bit for bit
+    # and the value heads lie where ``_gdn_heads``' repeat of q and k expects them:
+    # value head h of the convolution's input belongs to key head h // r
+    q, k, v = model._gdn_heads(cfg, got[0].astype(jnp.float32))
+    q_pub, k_pub, v_pub = model._gdn_heads(cfg, want[0].astype(jnp.float32))
+    assert all((np.asarray(a) == np.asarray(b)).all()
+               for a, b in ((q, q_pub), (k, k_pub), (v, v_pub)))
+
+
+def run_wave(act):
+    """(first token, the state and the keys after it) of ONE wave of one row:
+    the first 40 prompt tokens in a chunk of 64 columns, at the rung of 64 /
+    32 / 16 that holds them, a snapshot after 32."""
+    cfg = model.Qwen3NextConfig.tiny(experts_held=(4, 12))
+    params = jax.tree.map(lambda x: x.astype(act), model.init_params(cfg, seed=SEED))
+    kp = jnp.zeros((cfg.kv_layers, cfg.num_kv_heads, PAGES, PAGE, cfg.head_dim), act)
+    valid, row = 40, lambda v, t=jnp.int32: jnp.asarray([v], t)  # noqa: E731
+    ids = np.zeros((1, CHUNK), np.int32)
+    ids[0, :valid] = PROMPT[:valid]
+    slots = np.full((1, CHUNK), -1, np.int32)
+    slots[0, :valid] = np.arange(valid)
+    per_row = (jnp.zeros((ROWS,)), jnp.ones((ROWS,)), jnp.zeros((ROWS,), jnp.int32),
+               jnp.ones((ROWS,)))  # temperature 0: the first token is the best logit
+    first, _, kp, _, _, state = model.forward_paged_wave(
+        params, cfg, jnp.asarray(ids), jnp.arange(CHUNK, dtype=jnp.int32)[None], kp,
+        jnp.zeros_like(kp), jnp.zeros((ROWS, cfg.vocab_size), bool),
+        jnp.zeros((ROWS,), jnp.int32), jnp.asarray(slots),
+        jnp.arange(16, dtype=jnp.int32)[None], row(0), row(valid), row(valid - 1), row(0),
+        row(True, bool), jnp.int32(valid), jax.random.PRNGKey(0), jnp.uint32(1), *per_row,
+        state=model.make_state_pools(cfg, ROWS + 3), state_src=row(-1), state_dst=row(0),
+        state_snap=row(ROWS), snap_col=row(32))
+    return int(first[0]), {k: np.asarray(v, np.float32) for k, v in state.items()}, \
+        np.asarray(kp, np.float32)
+
+
+@pytest.fixture()
+def as_published(monkeypatch):
+    """The program as it was before PR 35: the projections stored as drawn and
+    split after the product.  The jitted step programs are traced anew on
+    both sides of it (they would otherwise be found in jit's cache)."""
+    traced = []
+    monkeypatch.setattr(model, "_by_kind", lambda cfg, w_qkvz, w_ba: (w_qkvz, w_ba))
+    monkeypatch.setattr(model, "_gdn_inputs",
+                        lambda *a: traced.append(1) or published_split(*a))
+    jax.clear_caches()
+    yield traced
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("program", ["wave", "burst"])
+def test_a_wave_and_a_burst_give_what_they_gave_with_the_projections_as_published(
+        program, request):
+    """Bit for bit in bfloat16 as served: a column of the product is the same
+    contraction wherever it lies."""
+    run = {"wave": lambda: run_wave(jnp.bfloat16), "burst": lambda: run_program(jnp.bfloat16)}
+    stored = run[program]()
+    traced = request.getfixturevalue("as_published")
+    published = run[program]()
+    assert traced  # the published split is what ran
+    if program == "wave":
+        assert stored[0] == published[0]
+        assert stored[1]["s"][:, ROWS].any() and stored[1]["conv"][:, 0].any()  # snapshot, state
+        for name in ("s", "conv"):
+            assert (stored[1][name] == published[1][name]).all(), name
+        assert (stored[2] == published[2]).all()
+    else:  # chunk by chunk through forward_paged, then a burst of four steps
+        assert (stored[0] == published[0]).all() and stored[1] == published[1]
+        assert (stored[2] == published[2]).all()

@@ -4,14 +4,17 @@ described, not attached, at the shapes of the benchmark's cell
 held, 2,048 pages of 128 tokens for the 2 attention layers, 32 live + 64
 snapshot + 1 slots of state for the 6 Gated DeltaNet layers): both paged
 kernels pass the chip's compiler at head size 256 with a group of 8; nothing
-in the optimized HLO copies, transposes or slices a K/V pool, the state pool
-or an expert stack (the state pool is WRITTEN in place, a row's slot at a
-time, and by nothing else); and the ops that the benchmark's metrics pick out
-of a trace by their shapes are the ops under the scopes they are meant to
-read.  Nothing executes; a pass here is not a chip run.
+in the optimized HLO copies, transposes or slices a K/V pool, the state pool,
+an expert stack or a Gated DeltaNet weight stack (the state pool is WRITTEN
+in place, a row's slot at a time, and by nothing else; the burst slices a
+layer of a weight stack inside the product that reads it, and nowhere else);
+and the ops that the benchmark's metrics pick out of a trace by their shapes
+are the ops under the scopes they are meant to read.  Nothing executes; a
+pass here is not a chip run.
 """
 
 import functools
+import math
 import os
 import re
 
@@ -97,24 +100,69 @@ def compiled(where, program: str, rows: int):
     return lowered.compile().as_text(), pools
 
 
-def timed_ops(hlo: str):
-    """(name as a trace shows it, the scope it was traced under or '') of what
-    a trace times: fusions, copies, slices and custom calls of the entry and
-    loop computations, not the instructions fused into them."""
-    from benchmarks.trace import short_name
+TIMED = ("fusion", "copy", "custom-call", "dynamic-slice", "dynamic-update-slice")
+_HEAD = re.compile(r"^(ENTRY )?%?(\S+) \(.*\) -> .* \{$")
 
+
+def timed_lines(hlo: str, opcodes=TIMED):
+    """The instructions a trace times: fusions, copies, slices and custom
+    calls of the entry and loop computations, not the instructions fused
+    into them."""
     fused = False
     for line in hlo.splitlines():
-        head = re.match(r"^(ENTRY )?%?(\S+) \(.*\) -> .* \{$", line)
+        head = _HEAD.match(line)
         if head:
             fused = head.group(2).startswith("fused_")
-        line = line.strip().removeprefix("ROOT ")
-        if fused or not re.search(
-                r" (fusion|copy|custom-call|dynamic-slice|dynamic-update-slice)\(", line):
-            continue
+        if not fused and re.search(rf" ({'|'.join(opcodes)})\(", line):
+            yield line.strip().removeprefix("ROOT ")
+
+
+def timed_ops(hlo: str):
+    """(name as a trace shows it, the scope it was traced under or '') of what
+    a trace times."""
+    from benchmarks.trace import short_name
+
+    for line in timed_lines(hlo):
         path = re.search(r'op_name="([^"]*)"', line)
         scope = next((s for s in SCOPES if path and f"/{s}/" in path.group(1) + "/"), "")
         yield short_name(line)[0], scope
+
+
+def written_out(hlo: str) -> str:
+    """The optimized HLO less the fused computations that another fusion calls:
+    what is left writes its result out.  A layer's slice of a weight stack
+    inside the fusion of the product that reads it is how every stack is read
+    in place; the same slice as a fusion of its own is 50 MB written a layer."""
+    name, bodies = None, {}
+    for line in hlo.splitlines():
+        head = _HEAD.match(line)
+        if head:
+            name = head.group(2)
+        bodies.setdefault(name, []).append(line)
+    inner = {callee for name, lines in bodies.items() if name and name.startswith("fused_")
+             for line in lines for callee in re.findall(r"calls=%?([\w.\-]+)", line)}
+    return "\n".join(line for name, lines in bodies.items() if name not in inner for line in lines)
+
+
+def timed_ops_rewriting(hlo: str, leaf) -> list:
+    """Timed ops (plain slices too) that take the weight stack ``leaf`` or a
+    layer of it and give the stack or a layer of it, as their result or as an
+    element of a tuple result.  Not memory-space assignment's prefetches of a
+    stack into VMEM (``ConcatBitcast`` over ``slice-done``): they read what
+    the product is about to read."""
+    dtype = {"bfloat16": "bf16", "float32": "f32"}[str(leaf.dtype)]
+    forms = {",".join(map(str, d)) for d in (leaf.shape, (1, *leaf.shape[1:]), leaf.shape[1:])}
+    holds = lambda types: any(  # noqa: E731
+        d in forms for d in re.findall(rf"\b{dtype}\[([\d,]+)\]", types))
+    instr = re.compile(r"\s*(?:ROOT )?(%[\w.\-]+) = (.*?) [a-z][a-z\-]*\(")
+    typed = {m.group(1): m.group(2) for m in map(instr.match, hlo.splitlines()) if m}
+    found, opcodes = [], (*TIMED, "slice")
+    for line in timed_lines(hlo, opcodes):
+        operands = re.search(rf" (?:{'|'.join(opcodes)})\(([^)]*)\)", line).group(1)
+        if "ConcatBitcast" not in line and holds(typed[line.split(" = ", 1)[0]]) and holds(
+                " ".join(typed.get(name, "") for name in re.findall(r"%[\w.\-]+", operands))):
+            found.append(line[:160])
+    return found
 
 
 @pytest.mark.parametrize("program,rows,writes", [
@@ -131,6 +179,46 @@ def test_step_program_leaves_pools_and_experts_in_place(chip, as_on_chip, progra
         movers = pool_movers(hlo, pools[name])
         assert all(m.startswith("dynamic_update_slice") for m in movers), (name, movers)
         assert len(movers) == writes, (name, movers)
+
+
+@pytest.mark.parametrize("program,rows,layers_written", [
+    pytest.param("burst", 0, 0, id="burst"),
+    # the chunk's two products share one slice of the leaf: ONE fusion a period writes its three
+    # layers out (50 MB each), where PR 34's wave also transposed the whole stack once a wave
+    pytest.param("wave", 8, 1, id="wave-8x512"),
+    # with rungs (one and two rows) every branch still wants its layer transposed: the stack is
+    # copied once a wave and a layer written out a rung, as in PR 34's program.  The form that
+    # reads it in place (one product, cut after it) hangs the chip (PERF.md, Findings, PR 35)
+    pytest.param("wave", 1, 0, id="wave-1x512", marks=pytest.mark.xfail(
+        strict=True, reason="the one-row wave still copies w_qkvz: PERF.md section 7")),
+])
+def test_step_program_reads_the_gated_deltanet_stacks_where_they_lie(chip, as_on_chip, program,
+                                                                     rows, layers_written):
+    """``w_qkvz`` (302 MB) and ``w_out`` (101 MB) are stored as their products
+    read them.  With ``w_qkvz``'s columns as published (grouped by key head,
+    regrouped after the product) the compiler wanted the contraction axis
+    minor: it transposed the stack once a burst and a wave in ``main``
+    (``copy.547``, ``copy.2207``), and wrote every layer's 50 MB out again
+    inside the loops: all six a step of the burst, as one fusion with a tuple of
+    six results (``fusion.1225``), and a ``constant_dynamic-slice_fusion`` a
+    layer of the wave (PERF.md, Findings, PR 35)."""
+    from githubrepostorag_tpu.models.qwen3_next import init_params
+
+    hlo, _ = compiled(chip, program, rows)
+    gdn = jax.eval_shape(lambda: init_params(cell_config(), 0))["gdn"]
+    # a stack a layer of which is over 1 MB; ``w_ba`` (1.6 MB the stack) is not one
+    stacks = {k: v for k, v in gdn.items()
+              if math.prod(v.shape[1:]) * v.dtype.itemsize > 2 ** 20}
+    assert set(stacks) == {"w_qkvz", "w_out"}
+    assert timed_ops_rewriting(hlo, stacks["w_out"]) == []
+    rewriting = timed_ops_rewriting(hlo, stacks["w_qkvz"])
+    whole = " = bf16[" + ",".join(map(str, stacks["w_qkvz"].shape)) + "]"  # a copy of the stack
+    assert [op for op in rewriting if whole in op] == []
+    assert len(rewriting) == layers_written, rewriting
+    if not layers_written:
+        # 12,288 columns are no activation's: every merged form of the stack counts.
+        # (``w_out``'s layer is 4,096 x 2,048, which eight rows of 512 columns are too.)
+        assert pool_movers(written_out(hlo), stacks["w_qkvz"].shape) == []
 
 
 def test_the_wave_is_one_program_that_donates_every_pool_and_keeps_them_out_of_its_switches(
