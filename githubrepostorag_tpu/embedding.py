@@ -27,6 +27,7 @@ import numpy as np
 from githubrepostorag_tpu.config import get_settings
 from githubrepostorag_tpu.utils import next_bucket
 from githubrepostorag_tpu.utils.logging import get_logger
+from githubrepostorag_tpu.obs import startup
 from githubrepostorag_tpu.utils.profiling import annotate
 
 logger = get_logger(__name__)
@@ -117,6 +118,7 @@ class JaxBertTextEncoder:
             self._batch_sharding = None
 
     @classmethod
+    @startup.records("startup.encoder")
     def from_pretrained(cls, model_dir: str, **kw) -> "JaxBertTextEncoder":
         import json
         from pathlib import Path
@@ -166,6 +168,7 @@ class JaxBertTextEncoder:
         return sorted({self._dp_rows(next_bucket(n, self.batch_size, minimum=8))
                        for n in range(1, self.batch_size + 1)})
 
+    @startup.records("startup.encoder")
     def warmup(self) -> int:
         """Precompile ``embed`` over the full (rows x length) bucket ladder
         so no live ``encode`` ever pays an XLA compile — the same

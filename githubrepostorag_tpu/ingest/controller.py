@@ -61,12 +61,15 @@ def _push_stage_gauge(stage: str, seconds: float, grouping: dict[str, str]) -> N
 @contextmanager
 def stage_timer(stage: str, grouping: dict[str, str], timings: dict[str, float],
                 on_stage: StageCallback | None = None):
+    from githubrepostorag_tpu.obs.startup import phase as startup_phase
     from githubrepostorag_tpu.utils.profiling import annotate
 
     start = time.monotonic()
     logger.info("stage %s: start", stage)
     try:
-        with annotate(f"ingest.{stage}"):
+        # (the start-up record takes an ingest that runs before ready, and
+        # none after it)
+        with annotate(f"ingest.{stage}"), startup_phase(f"startup.ingest.{stage}"):
             yield
     finally:
         elapsed = time.monotonic() - start

@@ -194,9 +194,25 @@ ADMISSION_FAILOPEN = Counter(
 )
 XLA_COMPILES = Counter(
     "rag_xla_compiles_total",
-    "Fresh XLA compilations observed during live engine stepping "
-    "(warmup should make this zero; see obs/engine_profile.py)",
+    "Step programs that went through the back end (compiled, or read from "
+    "the cache) during live engine stepping (warmup should make this zero; "
+    "see obs/engine_profile.py)",
     ["replica"],
+    registry=REGISTRY,
+)
+XLA_COMPILE_SECONDS = Counter(
+    "rag_xla_compile_seconds_total",
+    "Seconds JAX spent tracing, lowering and back-end compiling (a cache hit's "
+    "seconds are the read), before mark_warm() (startup) and after it (live): "
+    "the compile ledger of obs/engine_profile.py",
+    ["when"],
+    registry=REGISTRY,
+)
+STARTUP_SECONDS = Gauge(
+    "rag_startup_seconds",
+    "Seconds from process start to ready by phase of the start-up record "
+    "(obs/startup.py), with phase=\"total\" the whole; set once, at mark_warm()",
+    ["phase"],
     registry=REGISTRY,
 )
 TPOT = Histogram(

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 
 from githubrepostorag_tpu.models.moe import dropless_experts, route_noaux_tc
 from githubrepostorag_tpu.models.quant import _devrand, embedding_lookup
+from githubrepostorag_tpu.obs import startup
 from githubrepostorag_tpu.ops.latent_attention import (
     einsum_f32,
     latent_decode_attention,
@@ -171,6 +172,7 @@ def leaf_order(cfg: DeepseekV3Config) -> list:
     return leaves
 
 
+@startup.records("startup.weights", settle=True)
 def init_params(cfg: DeepseekV3Config, seed: int = 0) -> dict:
     """Weights made on the device from the seed, leaf by leaf, in bfloat16
     (models/quant._devrand: a Knuth-hashed iota, std ~0.02), norms at one.

@@ -20,6 +20,7 @@ from pathlib import Path
 import numpy as np
 
 from githubrepostorag_tpu.models.qwen2 import Qwen2Config
+from githubrepostorag_tpu.obs import startup
 
 
 def _np(t) -> np.ndarray:
@@ -125,6 +126,7 @@ def params_from_state_dict(state_dict: dict, cfg: Qwen2Config, dtype=np.float32)
     return params
 
 
+@startup.records("startup.weights", settle=True)
 def load_qwen2(
     checkpoint_dir: str,
     dtype=np.float32,

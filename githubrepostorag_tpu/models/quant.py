@@ -30,6 +30,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from githubrepostorag_tpu.obs import startup
 from githubrepostorag_tpu.runtime import on_tpu
 
 
@@ -495,6 +496,7 @@ def _devrand(shape: tuple, salt: jnp.ndarray, kind: str) -> jnp.ndarray:
     )
 
 
+@startup.records("startup.weights", settle=True)
 def init_params_quantized(cfg, seed: int = 0, bits: int = 8,
                           group_size: int = 64, fuse: bool = False) -> dict:
     """Random quantized Qwen2 params (int8 or AWQ-class int4), generated

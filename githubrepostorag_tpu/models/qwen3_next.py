@@ -59,6 +59,7 @@ from githubrepostorag_tpu.ops.gated_delta import (
     l2norm,
     mask_padding,
 )
+from githubrepostorag_tpu.obs import startup
 from githubrepostorag_tpu.ops.latent_attention import einsum_f32
 from githubrepostorag_tpu.ops.norms import rms_norm_gated, rms_norm_zero_centered
 from githubrepostorag_tpu.ops.prefill_width import at_wave_width
@@ -239,6 +240,7 @@ def _by_kind(cfg, w_qkvz, w_ba):
     return regroup(w_qkvz, (dk, dk, rdv, rdv)), regroup(w_ba, (r, r))
 
 
+@startup.records("startup.weights", settle=True)
 def init_params(cfg: Qwen3NextConfig, seed: int = 0) -> dict:
     """Weights made on the device from the seed, leaf by leaf, in bfloat16
     (models/quant._devrand), as DeepSeek-V3's are.  Zero-centred norms at

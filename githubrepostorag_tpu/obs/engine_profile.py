@@ -20,22 +20,46 @@ Three concerns, all driven from the engine driver thread
   histograms); TPOT is decode seconds per generated token after the
   first.
 
-* **XLA compile watchdog** — sums ``_cache_size()`` over every jitted
-  callable in the serving/model modules each step.  A positive delta
-  while serving means live traffic just paid an XLA compile the warmup
-  ladder failed to predict: ``rag_xla_compiles_total`` increments and
-  every registered in-flight span gets an ``xla_compile`` event, so the
-  one request that stalled for the length of a TPU compile says so in
-  its own timeline.
+* **The compile ledger** — one process-wide listener on the events JAX
+  itself announces for every trace, lowering and back-end compile
+  (``jax.monitoring``: ``jaxpr_trace_duration``,
+  ``jaxpr_to_mlir_module_duration``, ``backend_compile_duration``, each
+  with its seconds and ``fun_name``; the compilation cache's
+  ``cache_hits``).  ``CompileLedger`` keeps, per function: programs that
+  went through the back end, seconds of tracing, lowering and back-end
+  compile (on a cache hit that is the read), cache hits; and every event
+  with its ``time.monotonic()`` stamp, the clock every other stamp in
+  ``obs/`` uses.  ``mark_warm()`` draws the line: before it is start-up
+  (``obs/startup.py`` has the phases; one log line, ``rag_startup_seconds``
+  and ``rag_xla_compile_seconds_total{when}`` tell an operator where the
+  seconds to ready went), after it is live traffic.  A back-end compile
+  after the line, cache hit or not, of one of the engine's STEP PROGRAMS
+  (the callables ``Engine.step_programs()`` hands over at construction,
+  whatever module defines them) means live traffic just stalled for a
+  program the warm-up failed to predict: ``rag_xla_compiles_total``
+  increments, ``live_compiles`` rises, every registered in-flight span
+  gets an ``xla_compile`` event and the warning names the function and
+  its seconds.  Every other event after the line (an eager scatter of a
+  new row count, an encoder batch of a new shape) is kept under
+  ``when="live"`` and raises nothing.  ``CompileWatchdog`` reads the
+  ledger as deltas: no list of modules, no ``_cache_size()`` on a step.
 """
 
 from __future__ import annotations
 
-import importlib
+import re
 import threading
 import time
-from typing import TYPE_CHECKING, Any, Iterable
+from collections import deque
+from typing import TYPE_CHECKING, Any, Iterable, NamedTuple
 
+from githubrepostorag_tpu.metrics import (
+    SCHED_STALL,
+    STARTUP_SECONDS,
+    XLA_COMPILE_SECONDS,
+    XLA_COMPILES,
+)
+from githubrepostorag_tpu.obs.startup import PROCESS_START, startup_record
 from githubrepostorag_tpu.obs.trace import TraceContext, record_span
 from githubrepostorag_tpu.utils.logging import get_logger
 
@@ -44,83 +68,227 @@ if TYPE_CHECKING:  # pragma: no cover - typing only
 
 logger = get_logger(__name__)
 
-# every module that defines top-level jit objects the engine dispatches;
-# importing lazily and tolerantly — a module missing its accelerator dep
-# simply contributes no jits
-DEFAULT_JIT_MODULES = (
-    "githubrepostorag_tpu.serving.engine",
-    "githubrepostorag_tpu.serving.decode_burst",
-    "githubrepostorag_tpu.serving.spec_burst",
-    "githubrepostorag_tpu.serving.fused_step",
-    "githubrepostorag_tpu.serving.draft_spec",
-    "githubrepostorag_tpu.serving.long_prefill",
-    "githubrepostorag_tpu.models.qwen2",
-    "githubrepostorag_tpu.models.deepseek_v3",
-    "githubrepostorag_tpu.ops.sampling",
-    "githubrepostorag_tpu.ops.packed_prefill",
-    "githubrepostorag_tpu.ops.fused_decode",
-    "githubrepostorag_tpu.ops.page_migration",
-)
+TRACE, LOWER, COMPILE = "trace", "lower", "compile"
+_KINDS = {"/jax/core/compile/jaxpr_trace_duration": TRACE,
+          "/jax/core/compile/jaxpr_to_mlir_module_duration": LOWER,
+          "/jax/core/compile/backend_compile_duration": COMPILE}
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_COLUMN = {TRACE: 2, LOWER: 3, COMPILE: 4}  # of a by_fun / totals row
+_WRAPPED = re.compile(r"^\w+\((.*)\)$")  # lowering and compile say "jit(name)", tracing "name"
+TAIL = 4096  # events kept for readers of what arrived lately
+STARTUP_EVENTS = 1 << 17  # ... and of a start-up (a job that never serves never draws the line)
 
 
-def discover_jits(module_names: Iterable[str] = DEFAULT_JIT_MODULES) -> list[tuple[str, Any]]:
-    """Find every module-level object exposing jit's ``_cache_size`` in the
-    serving/model modules — the complete set of programs live traffic can
-    trigger a compile through."""
-    jits: list[tuple[str, Any]] = []
-    for name in module_names:
-        try:
-            mod = importlib.import_module(name)
-        except Exception:  # noqa: BLE001 - optional accelerator deps
-            continue
-        for attr, obj in vars(mod).items():
-            if callable(getattr(obj, "_cache_size", None)):
-                jits.append((f"{name}.{attr}", obj))
-    return jits
+class CompileEvent(NamedTuple):
+    t: float        # time.monotonic() when the event arrived: its end
+    seconds: float  # its own: what nested traces took is theirs
+    kind: str       # TRACE | LOWER | COMPILE
+    fun: str
+    hit: bool       # COMPILE: the program was read from the persistent cache
+    step: bool      # the function is one of the engines' step programs
+    wall: float     # seconds from its start to ``t``, nested work included
+
+
+def program_name(fn: Any) -> str:
+    """The name JAX's events give a jitted callable."""
+    return getattr(fn, "__name__", None) or repr(fn)
+
+
+class CompileLedger:
+    """What JAX traced, lowered and compiled in this process, by function and
+    by when.  Written by the listener on whichever thread compiles; read from
+    any thread."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._local = threading.local()  # a cache hit waiting for its compile event; open traces
+        self.seq = 0  # events so far
+        self.tail: deque[CompileEvent] = deque(maxlen=TAIL)
+        self.startup_events: list[CompileEvent] = []  # every event before mark_warm()
+        self.warm_t: float | None = None
+        self.step_programs: set[str] = set()
+        self.step_compiles = 0  # back-end compiles of step programs, ever
+        # fun -> [programs, cache hits, trace s, lower s, compile s]
+        self.by_fun: dict[str, list] = {}
+        # when -> the same five over every function
+        self.totals = {"startup": [0, 0, 0.0, 0.0, 0.0], "live": [0, 0, 0.0, 0.0, 0.0]}
+
+    def watch(self, programs: Iterable[Any]) -> None:
+        """``programs``: the jitted callables an engine dispatches from."""
+        names = {program_name(p) for p in programs}
+        with self._lock:
+            self.step_programs |= names
+
+    # ---------------------------------------------------------- listener --
+
+    def on_cache_hit(self) -> None:
+        self._local.hit = True  # inside the compile event that follows on this thread
+
+    def on_duration(self, kind: str, fun_name: str, seconds: float) -> None:
+        now = time.monotonic()
+        local = self._local
+        own, hit = seconds, False
+        if kind == TRACE:
+            # an inner jit is traced inside its caller's trace and announced
+            # before it: what this event covers of earlier ones is theirs
+            opened = local.__dict__.setdefault("traces", [])
+            start = now - seconds
+            while opened and opened[-1][0] >= start:
+                own -= opened.pop()[1]
+            opened.append((now, seconds))
+            own = max(0.0, own)
+        else:
+            local.traces = []  # a program's tracing is over once it is lowered
+            if kind == COMPILE:
+                hit, local.hit = getattr(local, "hit", False), False
+        m = _WRAPPED.match(fun_name)
+        fun = m.group(1) if m else fun_name
+        col = _COLUMN[kind]
+        with self._lock:
+            when = "startup" if self.warm_t is None else "live"
+            ev = CompileEvent(now, own, kind, fun, hit, fun in self.step_programs, seconds)
+            self.seq += 1
+            self.tail.append(ev)
+            if when == "startup" and len(self.startup_events) < STARTUP_EVENTS:
+                self.startup_events.append(ev)
+            for row in (self.by_fun.setdefault(fun, [0, 0, 0.0, 0.0, 0.0]), self.totals[when]):
+                row[col] += own
+                if kind == COMPILE:
+                    row[0] += 1
+                    row[1] += hit
+            if kind == COMPILE and ev.step:
+                self.step_compiles += 1
+        XLA_COMPILE_SECONDS.labels(when=when).inc(own)
+        if when == "startup":
+            startup_record().poll(now)
+
+    # ----------------------------------------------------------- readers --
+
+    def since(self, seq: int) -> tuple[int, list[CompileEvent]]:
+        """(events so far, the events after the first ``seq`` of them that
+        the tail still holds)."""
+        with self._lock:
+            n = min(self.seq - seq, len(self.tail))
+            return self.seq, (list(self.tail)[-n:] if n > 0 else [])
+
+    def programs_of(self, names: Iterable[str]) -> int:
+        with self._lock:
+            return sum(self.by_fun[n][0] for n in names if n in self.by_fun)
+
+    def snapshot(self) -> dict:
+        """Plain data for a reader: the start-up's events whole, then the
+        tail of what came after the line."""
+        with self._lock:
+            events = self.startup_events + [e for e in self.tail
+                                            if self.warm_t is not None and e.t >= self.warm_t]
+            return {"warm_t": self.warm_t, "events": [list(e) for e in events]}
+
+    def mark_warm(self) -> None:
+        """Draw the line between start-up and live traffic (the first call
+        does; a relaunch's is a no-op) and tell the operator once where the
+        seconds to ready went."""
+        now = time.monotonic()
+        with self._lock:
+            if self.warm_t is not None:
+                return
+            self.warm_t = now
+            programs, hits, trace_s, lower_s, compile_s = self.totals["startup"]
+            traced = sum(1 for e in self.startup_events if e.kind == TRACE)
+            slowest = sorted(((sum(row[2:]), fun) for fun, row in self.by_fun.items()),
+                             reverse=True)[:5]
+        record = startup_record()
+        record.mark_warm(now)
+        phases = record.seconds_by_phase()
+        STARTUP_SECONDS.labels(phase="total").set(now - PROCESS_START)
+        for name, seconds in phases.items():
+            STARTUP_SECONDS.labels(phase=name).set(seconds)
+        logger.info(
+            "ready %.1f s after process start; phases %s; jax traced %d function(s) %.1f s, "
+            "lowered %.1f s, back end %d program(s) %.1f s (%d read from the cache); slowest %s",
+            now - PROCESS_START, {k: round(v, 2) for k, v in phases.items()}, traced, trace_s,
+            lower_s, programs, compile_s, hits,
+            ", ".join(f"{fun} {s:.1f} s" for s, fun in slowest))
+
+
+_ledger = CompileLedger()
+
+
+def _on_duration(event: str, seconds: float, fun_name: str = "", **_kw) -> None:
+    kind = _KINDS.get(event)
+    if kind is not None:
+        _ledger.on_duration(kind, fun_name, seconds)
+
+
+def _on_event(event: str, **_kw) -> None:
+    if event == _CACHE_HIT:
+        _ledger.on_cache_hit()
+
+
+try:  # once, at the first import: set-up compiles before any engine exists
+    from jax import monitoring as _monitoring
+
+    _monitoring.register_event_duration_secs_listener(_on_duration)
+    _monitoring.register_event_listener(_on_event)
+except Exception:  # noqa: BLE001 - a build without jax compiles nothing
+    pass
+
+
+def compile_ledger() -> CompileLedger:
+    """The process's ledger (whatever module imports this one first has
+    registered its listener)."""
+    return _ledger
+
+
+def reset_compile_ledger() -> CompileLedger:
+    """A fresh ledger behind the same listener (tests)."""
+    global _ledger
+    _ledger = CompileLedger()
+    return _ledger
 
 
 class CompileWatchdog:
-    """Tracks the total jit program count and reports fresh compiles as
-    deltas between samples."""
+    """The ledger's back-end compiles read as deltas between samples: of the
+    engines' step programs, or of ``programs`` (jitted callables) alone."""
 
-    def __init__(self, jits: list[tuple[str, Any]] | None = None) -> None:
-        self._jits = discover_jits() if jits is None else list(jits)
+    def __init__(self, programs: Iterable[Any] | None = None) -> None:
+        self._names = None if programs is None else {program_name(p) for p in programs}
         # resync() runs on the event loop (serve start / mark_warm) while
-        # sample() runs on the driver thread every step; _last needs a lock
-        # or a resync racing a sample mis-attributes warmup compiles to
-        # live traffic
+        # sample() runs on the driver thread every step; the cursor needs a
+        # lock or a resync racing a sample books warm-up's compiles to live
+        # traffic
         self._lock = threading.Lock()
-        self.grown: list[str] = []
-        self._last = self.sizes()
+        self.grown: list[str] = []  # "function 1.234 s" of the last sample's compiles
+        self._seq = compile_ledger().seq
 
-    def sizes(self) -> dict[str, int]:
-        out = {}
-        for name, obj in self._jits:
-            try:
-                out[name] = int(obj._cache_size())
-            except Exception:  # noqa: BLE001 - a torn-down jit reads as 0
-                out[name] = 0
-        return out
+    def _mine(self, ev: CompileEvent) -> bool:
+        return ev.kind == COMPILE and (ev.step if self._names is None else ev.fun in self._names)
 
     def cache_size(self) -> int:
-        return sum(self.sizes().values())
+        """Programs of the watched functions that went through the back end
+        so far, cache hits included."""
+        ledger = compile_ledger()
+        return ledger.step_compiles if self._names is None else ledger.programs_of(self._names)
 
     def resync(self) -> None:
         """Rebaseline — called at serve start so warmup's own compiles
         (expected, pre-traffic) never count as live-traffic compiles."""
-        sizes = self.sizes()
         with self._lock:
-            self._last = sizes
+            self._seq = compile_ledger().seq
 
     def sample(self) -> int:
-        """New programs compiled since the previous sample (>= 0); ``grown``
-        names the jits that gained one."""
-        sizes = self.sizes()
+        """Watched programs compiled since the previous sample (>= 0);
+        ``grown`` names them with their seconds.  No new event: one int
+        compared."""
+        ledger = compile_ledger()
         with self._lock:
-            delta = sum(sizes.values()) - sum(self._last.values())
-            self.grown = [n for n, v in sizes.items() if v > self._last.get(n, 0)]
-            self._last = sizes
-        return max(0, delta)
+            if ledger.seq == self._seq:
+                self.grown = []
+                return 0
+            self._seq, events = ledger.since(self._seq)
+            mine = [ev for ev in events if self._mine(ev)]
+            self.grown = [f"{ev.fun} {ev.seconds:.3f} s" + (" (read from the cache)" if ev.hit
+                                                           else "") for ev in mine]
+        return len(mine)
 
 
 class EngineStepProfiler:
@@ -135,7 +303,7 @@ class EngineStepProfiler:
         self._lock = threading.Lock()
         self._live: dict[int, "Span"] = {}
         self._last_step_end: float | None = None
-        self.live_compiles = 0  # programs compiled under live traffic
+        self.live_compiles = 0  # step programs through the back end under live traffic
 
     # ----------------------------------------------------- live requests --
 
@@ -151,6 +319,7 @@ class EngineStepProfiler:
         """Declare warmup finished: compiles observed after this are
         live-traffic compiles."""
         self.watchdog.resync()
+        compile_ledger().mark_warm()
         with self._lock:
             self._last_step_end = None
 
@@ -159,8 +328,6 @@ class EngineStepProfiler:
     def on_step(self, step_start: float, step_end: float) -> int:
         """Record stall + compile telemetry for one completed engine step.
         Returns the number of fresh compiles observed (for tests)."""
-        from githubrepostorag_tpu.metrics import SCHED_STALL, XLA_COMPILES
-
         with self._lock:
             prev = self._last_step_end
             self._last_step_end = step_end
@@ -171,15 +338,16 @@ class EngineStepProfiler:
         if delta > 0:
             self.live_compiles += delta  # driver thread only; GIL-atomic read
             XLA_COMPILES.labels(replica=self.replica).inc(delta)
+            grown = ", ".join(self.watchdog.grown)
             with self._lock:
                 live = list(self._live.values())
             for sp in live:
-                sp.add_event("xla_compile", new_programs=delta,
+                sp.add_event("xla_compile", new_programs=delta, programs=grown,
                              step_s=round(step_end - step_start, 6))
             logger.warning(
-                "xla compile during live traffic: %d new program(s) in a %.3fs step, of %s "
+                "xla compile during live traffic: %d new program(s) in a %.3fs step: %s "
                 "(warmup should have predicted this shape)",
-                delta, step_end - step_start, ", ".join(self.watchdog.grown),
+                delta, step_end - step_start, grown,
             )
         return delta
 
@@ -187,8 +355,6 @@ class EngineStepProfiler:
         """The driver found no work — the next gap is idleness, not stall."""
         with self._lock:
             self._last_step_end = None
-        from githubrepostorag_tpu.metrics import SCHED_STALL
-
         SCHED_STALL.labels(replica=self.replica).set(0.0)
 
 

@@ -49,6 +49,7 @@ from githubrepostorag_tpu.store.base import (
 )
 from githubrepostorag_tpu.utils import next_bucket
 from githubrepostorag_tpu.utils.logging import get_logger
+from githubrepostorag_tpu.obs.startup import phase as startup_phase
 from githubrepostorag_tpu.utils.profiling import annotate
 
 logger = get_logger(__name__)
@@ -429,9 +430,11 @@ class DeviceIndexedStore(VectorStore):
         if t.corpus_dev is None or t.full_sync:
             # device copy is the TRANSPOSE of the host mirror ([dim, cap]):
             # see _build_search — row r lives in column r
-            sh = self._sharding(P(None, "dp"))
-            arr = jnp.asarray(np.ascontiguousarray(t.host.T))
-            t.corpus_dev = jax.device_put(arr, sh) if sh else jax.device_put(arr)
+            with startup_phase("startup.index_build") as ph:  # the upload to size
+                sh = self._sharding(P(None, "dp"))
+                arr = jnp.asarray(np.ascontiguousarray(t.host.T))
+                t.corpus_dev = jax.device_put(arr, sh) if sh else jax.device_put(arr)
+                ph.settles(t.corpus_dev)
             t.dirty_rows, t.full_sync = set(), False
             t.full_syncs += 1
             INDEX_FULL_SYNCS.labels(table=t.name).inc()

@@ -19,6 +19,7 @@ from dataclasses import dataclass
 from typing import Any, AsyncIterator
 
 from githubrepostorag_tpu.obs.engine_profile import EngineStepProfiler
+from githubrepostorag_tpu.obs.startup import startup_record
 from githubrepostorag_tpu.serving.engine import Engine, GenerationResult
 from githubrepostorag_tpu.serving.routing import ReplicaDigest
 from githubrepostorag_tpu.serving.sampling_params import SamplingParams
@@ -62,9 +63,11 @@ class AsyncEngine:
                           "fb": {}, "kv_fault": 0, "kv_wb": 0,
                           "kv_dedup": 0, "kv_hold": 0, "kv_mig_s": 0.0,
                           "xfer_s": 0.0, "preempts": 0, "resumes": 0}
-        # step profiler: scheduler-stall gauge + XLA compile watchdog,
-        # sampled once per step on the driver thread (obs/engine_profile)
+        # step profiler: scheduler-stall gauge + what the compile ledger saw
+        # since the last step, on the driver thread (obs/engine_profile)
         self.profiler = EngineStepProfiler(replica=replica)
+        # startup.serve: from here to mark_warm() in start() (obs/startup.py)
+        self._serve_phase = startup_record().begin("startup.serve")
         # SLO plane: token ledger + burn-rate monitor, registered under this
         # replica id so MultiAsyncEngine fleets federate per-replica
         from githubrepostorag_tpu.config import get_settings
@@ -162,6 +165,7 @@ class AsyncEngine:
         # rebaseline the compile watchdog: programs compiled before serve
         # start (warmup, imports) are expected — only compiles during live
         # stepping should count
+        startup_record().finish(self._serve_phase)
         self.profiler.mark_warm()
         # tpulint: disable=WPA002 -- written before Thread.start() below; the thread launch is the happens-before edge that publishes it to the driver
         self._loop = asyncio.get_running_loop()
@@ -383,10 +387,12 @@ class AsyncEngine:
                         # that the driver did not step at all (sched_stall)
                         logger.warning(
                             "slow engine step: wall %.2f s (prefill %.2f, decode %.2f, "
-                            "compiles %d), %.2f s since the step before; running %d, "
+                            "compiles %d%s), %.2f s since the step before; running %d, "
                             "waiting %d, free pages %d; host phases %s", rec.get("wall", 0.0),
                             rec.get("prefill", 0.0), rec.get("decode", 0.0),
-                            int(rec.get("compiles", 0)), rec.get("sched_stall", 0.0),
+                            int(rec.get("compiles", 0)),
+                            ": " + ", ".join(self.profiler.watchdog.grown) if compiles else "",
+                            rec.get("sched_stall", 0.0),
                             q_depths[0], q_depths[1], pool_depths[0],
                             {k: round(v, 3) for k, v in
                              getattr(self.engine, "step_phase_s", {}).items()})

@@ -80,6 +80,8 @@ from githubrepostorag_tpu.metrics import (
     STATE_SLOTS_IN_USE,
     STATE_SNAPSHOTS,
 )
+from githubrepostorag_tpu.obs.engine_profile import compile_ledger
+from githubrepostorag_tpu.obs.startup import records as startup_records, startup_record
 from githubrepostorag_tpu.utils.logging import get_logger
 from githubrepostorag_tpu.utils.profiling import annotate
 
@@ -328,6 +330,8 @@ class Engine:
         # model's state pool (serving/kv_cache.StateSlots), sized beside
         # num_pages; None = two a row.  Unused by every other model
     ) -> None:
+        # startup.engine_init: pools, allocator, state slots (obs/startup.py)
+        init_phase = startup_record().begin("startup.engine_init")
         self.mesh = mesh
         # which model runs is read from the configuration object: a latent
         # (MLA) model brings its own two step programs under qwen2's
@@ -742,6 +746,36 @@ class Engine:
         # warning (serving/async_engine.py) says which phase held it
         self._phase_name, self._phase_t0 = None, 0.0
         self.step_phase_s: dict[str, float] = {}
+        # the compile ledger (obs/engine_profile.py) learns which functions
+        # are step programs from the engine that dispatches them
+        compile_ledger().watch(self.step_programs())
+        init_phase.settles(self._presence)  # the last array made: the pools are before it
+        startup_record().finish(init_phase)
+
+    def step_programs(self) -> list:
+        """Every jitted callable a step of THIS engine can dispatch: the
+        jits this module imports and defines, the family's wave and burst,
+        and the programs of the modes it was built with.  A back-end compile
+        of one of them under traffic is a stall the warm-up owed."""
+        programs = [v for v in globals().values() if callable(getattr(v, "_cache_size", None))]
+        programs += [self._wave_fn, self._decode_burst_fn]
+        if self._sp > 1 and self.sp_prefill_threshold is not None:
+            from githubrepostorag_tpu.serving.long_prefill import ring_prefill_packed
+
+            programs.append(ring_prefill_packed)
+        if self.fused_step_on:
+            from githubrepostorag_tpu.serving.fused_step import fused_step_burst
+
+            programs.append(fused_step_burst)
+        elif self.spec_ngram_k > 0:
+            from githubrepostorag_tpu.serving.spec_burst import spec_decode_burst
+
+            programs.append(spec_decode_burst)
+        if self._draft_enabled:
+            from githubrepostorag_tpu.serving.draft_spec import draft_spec_burst
+
+            programs.append(draft_spec_burst)
+        return programs
 
     # ------------------------------------------------------- host phases --
 
@@ -2933,6 +2967,7 @@ class Engine:
 
     # --------------------------------------------------------- convenience --
 
+    @startup_records("startup.warmup")
     def warmup(self) -> None:
         """Precompile every steady-state device program — the prefill wave at
         each row bucket (first-token sampling is part of it), the decode

@@ -13,6 +13,8 @@ from __future__ import annotations
 
 from typing import Protocol, Sequence
 
+from githubrepostorag_tpu.obs import startup
+
 
 class Tokenizer(Protocol):
     eos_token_id: int
@@ -91,6 +93,7 @@ class HFTokenizer:
         )
 
 
+@startup.records("startup.tokenizer")
 def make_tokenizer(model_dir: str, backend: str | None = None) -> "Tokenizer":
     """Tokenizer for a local checkpoint dir: the in-tree C++/Python BPE when
     ``tokenizer.json`` is a byte-level BPE (no transformers import at all),

@@ -32,6 +32,20 @@ that reads each):
     embed.batch        one encoder batch, dispatch to vectors on host
     index.search       one device-index wave, dispatch to hits on host
     encoder.warmup, ingest.<stage>
+
+Start-up (obs/startup.py: each is also a phase of the start-up record,
+stamped by the function that does the work, until ``mark_warm()``; the
+annotation is there for a start-up traced by hand):
+
+    startup.tokenizer      serving/tokenizer.make_tokenizer
+    startup.weights        the families' initialisers, hf_loader.load_qwen2
+    startup.engine_init    Engine.__init__: pools, allocator, state slots
+    startup.warmup         Engine.warmup: the pod's ladder
+    startup.encoder        JaxBertTextEncoder.from_pretrained and .warmup
+    startup.ingest.<stage> ingest/controller.stage_timer
+    startup.index_build    the device index's first upload of a table
+    (``startup.serve``, AsyncEngine's construction to ``mark_warm()``, spans
+    two functions and is in the record alone)
 """
 
 from __future__ import annotations

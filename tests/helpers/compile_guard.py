@@ -14,7 +14,8 @@ plumbing live in exactly one place:
 
 ``counter`` is any zero-arg callable returning the current cumulative
 program count.  For engine-wide checks, ``watchdog_counter()`` wraps a
-``CompileWatchdog`` over every discovered module-global jit.
+``CompileWatchdog`` over the compile ledger: every back-end compile of a
+function some engine of this process named as its step program.
 """
 
 from __future__ import annotations
@@ -60,8 +61,8 @@ def compile_guard(
 
 
 def watchdog_counter() -> Callable[[], int]:
-    """Engine-wide counter: total program count across every discovered
-    module-global jit (same discovery the serving watchdog uses)."""
+    """Engine-wide counter: step programs that went through the back end so
+    far, as the compile ledger counts them (what the serving watchdog reads)."""
     from githubrepostorag_tpu.obs.engine_profile import CompileWatchdog
 
     return CompileWatchdog().cache_size

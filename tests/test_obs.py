@@ -354,30 +354,51 @@ def test_compile_watchdog_detects_a_genuine_recompile():
 
     from githubrepostorag_tpu.obs.engine_profile import CompileWatchdog
 
-    f = jax.jit(lambda x: x + 1)
+    def watchdog_probe(x):
+        return x + 1
+
+    f = jax.jit(watchdog_probe)
     f(jnp.zeros((2,), jnp.float32))
-    dog = CompileWatchdog(jits=[("test.f", f)])
+    dog = CompileWatchdog(programs=[f])
     assert dog.sample() == 0  # warm shape, no new programs
     f(jnp.zeros((2,), jnp.float32))
     assert dog.sample() == 0  # cache hit is not a compile
     f(jnp.zeros((3,), jnp.float32))  # fresh shape -> real XLA compile
-    assert dog.sample() == 1 and dog.grown == ["test.f"]
+    assert dog.sample() == 1 and dog.grown[0].startswith("watchdog_probe ")
     assert dog.sample() == 0 and dog.grown == []  # delta, not level
 
 
-def test_discover_jits_finds_the_serving_programs():
-    from githubrepostorag_tpu.obs.engine_profile import discover_jits
+@pytest.mark.parametrize("family", ["qwen2", "deepseek_v3", "qwen3_next"])
+def test_an_engine_names_its_step_programs_to_the_ledger(family):
+    """No list of modules: what the watchdog watches is what the engine of
+    each family says it dispatches."""
+    import importlib
 
-    jits = discover_jits()
-    assert jits, "no jitted callables found in the serving/model modules"
-    assert all(callable(obj._cache_size) for _, obj in jits)
-    names = {name for name, _ in jits}  # both model families' step programs are watched
-    assert {"githubrepostorag_tpu.serving.decode_burst.decode_burst",
-            "githubrepostorag_tpu.models.deepseek_v3.decode_burst",
-            "githubrepostorag_tpu.models.deepseek_v3.forward_paged",
-            # the two programs a step dispatches: the prefill wave of each family
-            "githubrepostorag_tpu.models.qwen2.forward_paged_wave",
-            "githubrepostorag_tpu.models.deepseek_v3.forward_paged_wave"} <= names
+    import jax
+    import jax.numpy as jnp
+
+    from githubrepostorag_tpu.obs.engine_profile import compile_ledger, program_name
+    from githubrepostorag_tpu.serving import Engine
+
+    model = importlib.import_module(f"githubrepostorag_tpu.models.{family}")
+    kw = dict(max_num_seqs=2, num_pages=32, page_size=16, max_seq_len=128, prefill_chunk=64,
+              kv_dtype=jnp.float32)
+    if family == "qwen2":
+        cfg = model.Qwen2Config.tiny()
+        params = model.init_params(cfg, jax.random.PRNGKey(0))
+    else:
+        cfg = {"deepseek_v3": "DeepseekV3Config", "qwen3_next": "Qwen3NextConfig"}[family]
+        cfg = getattr(model, cfg).tiny()
+        params = model.init_params(cfg, seed=0)
+    eng = Engine(params, cfg, **kw)
+    programs = eng.step_programs()
+    assert all(callable(p._cache_size) for p in programs)
+    # the two programs a step dispatches are the family's own objects
+    assert model.forward_paged_wave in programs
+    assert (model.decode_burst if family != "qwen2" else eng._decode_burst_fn) in programs
+    names = {program_name(p) for p in programs}
+    assert {"forward_paged_wave", "decode_burst", "_mark_presence_chunks"} <= names
+    assert names <= compile_ledger().step_programs
 
 
 # ------------------------------------------------- full stack over a bus ---
@@ -469,15 +490,23 @@ async def _collect_events(session, base, job_id, timeout=120):
     return events
 
 
-async def test_one_connected_trace_api_to_engine_decode(sampled):
+async def test_one_connected_trace_api_to_engine_decode(sampled, monkeypatch):
     """The acceptance trace: root API span -> worker continuation -> agent
     phase spans -> engine prefill/decode spans, all one trace_id, the full
     tree retrievable from /debug/traces/{trace_id}, and the compact phase
     summary on the terminal SSE event."""
     import aiohttp
 
+    from githubrepostorag_tpu.config import reload_settings
     from githubrepostorag_tpu.llm import FakeLLM
 
+    # the warm call below compiles on the CPU: where nothing in the process
+    # compiled such programs before, or the host is loaded, its first token
+    # or its token gap misses the objectives, one bad request of one burns
+    # critically, and the API sheds the job this test posts
+    monkeypatch.setenv("SLO_TTFT_P99_MS", "600000")
+    monkeypatch.setenv("SLO_TPOT_MS", "600000")
+    reload_settings()
     real = _tiny_llm()
     real.complete("warm the engine compile cache")  # compiles outside the job
     api, worker = _stack(_HybridLLM(FakeLLM(script=AGENT_SCRIPT), real))
@@ -543,6 +572,8 @@ async def test_one_connected_trace_api_to_engine_decode(sampled):
         worker_task.cancel()
         await api.stop()
         real.close()
+        monkeypatch.undo()
+        reload_settings()
 
 
 async def test_post_warmup_recompile_fires_watchdog(sampled):
@@ -554,12 +585,15 @@ async def test_post_warmup_recompile_fires_watchdog(sampled):
 
     from githubrepostorag_tpu.obs.engine_profile import CompileWatchdog
 
-    f = jax.jit(lambda x: x * 2)
+    def watchdog_sentinel(x):
+        return x * 2
+
+    f = jax.jit(watchdog_sentinel)
     f(jnp.zeros((2,), jnp.float32))  # pre-warm shape A
     llm = _tiny_llm()
     # watch our sentinel jit: its recompile below is a genuine XLA compile,
-    # observed by the real per-step sampling on the engine driver thread
-    llm.engine.profiler.watchdog = CompileWatchdog(jits=[("test.sentinel", f)])
+    # found by the real per-step sampling on the engine driver thread
+    llm.engine.profiler.watchdog = CompileWatchdog(programs=[f])
     try:
         llm.complete("warm")  # AsyncEngine.start() -> profiler.mark_warm()
         before = counter_value(XLA_COMPILES)
