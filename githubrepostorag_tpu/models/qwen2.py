@@ -426,11 +426,14 @@ def forward_paged(
 ):
     """Prefill-chunk or decode step over the paged KV cache.
 
-    New K/V are scattered into the page pools at ``slot_mapping`` (padding
-    slots are -1 and dropped), then attention runs over each row's block
-    table.  Returns (logits, k_pages, v_pages[, k_scales, v_scales]) — the
-    pools are donated so XLA updates them in place (scale pools are small
-    enough that their copy is noise).
+    New K/V are committed to the page pools at ``slot_mapping`` (padding
+    slots are -1 and dropped; a row's columns are consecutive positions, so
+    kv_cache.commit_paged writes them a page-sized window at a time where
+    the pools are full precision, a row per slot where they are quantized),
+    then attention runs over each row's block table.  Returns (logits,
+    k_pages, v_pages[, k_scales, v_scales]) — the pools are donated so XLA
+    updates them in place (scale pools are small enough that their copy is
+    noise).
 
     ``k_scales``/``v_scales`` mark int8 kv_quant pools: new K/V quantize
     per PAGE at the scatter (kv_cache.quantize_kv_paged: the first write
@@ -596,8 +599,11 @@ def forward_paged_impl(
                 # pools; per-page first-write scales for int8 — same semantics
                 # as the burst and ring-prefill commits)
                 with jax.named_scope("kv_write"):
-                    new_kp, new_ks = commit_paged(kp, k_t, flat_slots, ks, page_size, layer=li)
-                    new_vp, new_vs = commit_paged(vp, v_t, flat_slots, vs, page_size, layer=li)
+                    run = slots.shape[1]  # a row's columns are consecutive positions
+                    new_kp, new_ks = commit_paged(kp, k_t, flat_slots, ks, page_size,
+                                                  layer=li, run=run)
+                    new_vp, new_vs = commit_paged(vp, v_t, flat_slots, vs, page_size,
+                                                  layer=li, run=run)
                 with jax.named_scope("paged_attention"):
                     scales = (new_ks, new_vs) if quant else ()
                     if use_pallas:

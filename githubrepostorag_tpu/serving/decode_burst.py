@@ -17,14 +17,20 @@ burst: new K/V go to a tiny [L, B, n_kv, N, hd] staging buffer (~MBs),
 attention per step covers (frozen pool prefix) + (staged tail so far), and
 the staged tokens are scattered into the pools ONCE at burst end.
 
-That one scatter (kv_cache.commit_paged) writes a [hd] row per (layer,
-head, slot) index, in the layout the pools arrive in.  Written as one
-window over all layers and heads per slot it is fewer, fatter writes, but
-the v5e compiler then transposes each whole pool into the layout that makes
-the window contiguous and back out again — four 1.4 GB copies around a
-30 us scatter, 17 ms of a 120 ms burst at Qwen2-7B widths, donation
-notwithstanding (PERF.md, Findings, PR 25; tests/test_tpu_compile.py holds
-the compiled program to "no copy of a pool").
+That one commit (kv_cache.commit_paged, told that a row's ``n_steps`` slots
+are one run) writes each row's steps as the one or two 16-slot windows they
+fall in, over all layers and heads at once: 64 update-slices of 448 KB a
+pool, in place in the layout the pools arrive in, 0.5 ms a burst at
+Qwen2-7B widths where a scatter of one [hd] row per (layer, head, slot)
+index took 3.9-4.2 ms for its 28,672 indices, dead row slots included
+(PERF.md, Findings, PR 38; quantized pools keep that scatter).  As ONE
+scatter with a window over all layers and heads per slot, the v5e compiler
+transposes each whole pool into the layout that makes the window contiguous
+and back out again — four 1.4 GB copies, 17 ms of a 120 ms burst, donation
+notwithstanding (PERF.md, Findings, PR 25); the update-slices do not tempt
+it because the attention kernel reads the same buffers as they arrive
+(tests/test_tpu_compile.py holds the compiled program to "no copy of a
+pool" and "no row scatter into a full-precision pool").
 
 Attention inside the burst has two implementations (``use_pallas``):
   - the Pallas flash-decode kernel with a staged-tail operand
@@ -360,7 +366,8 @@ def decode_burst(
     toks, valid = toks.T, valid.T  # [B, n_steps]
     packed = jnp.where(valid, toks, -1)
 
-    # one scatter commits the whole burst's staged K/V into the pools
+    # one commit writes the whole burst's staged K/V into the pools: a row's
+    # n_steps slots are consecutive positions (a run, for commit_paged)
     total_slots = num_pages * page_size
     pos = start_lens[:, None] + staged_idx[None, :]  # [B, n_steps]
     page_idx = jnp.clip(pos // page_size, 0, block_tables.shape[1] - 1)
@@ -375,7 +382,7 @@ def decode_burst(
         # order; commit_paged is THE shared pool-commit rule (per-page
         # first-write scales when quantized)
         vals = staged.swapaxes(1, 2).reshape(L, n_kv, b * n_steps, hd)
-        return commit_paged(pools, vals, flat_slots, scales, page_size)
+        return commit_paged(pools, vals, flat_slots, scales, page_size, run=n_steps)
 
     with jax.named_scope("kv_write"):
         k_pages, k_scales = commit(k_pages, staged_k, k_scales)

@@ -14,11 +14,14 @@ Design notes (TPU-first):
     compiles a handful of programs total, once.
   - The page pools are donated through every step and never moved by
     one: donation only lets a program reuse the buffer, it is the commit
-    (kv_cache.commit_paged: one [hd] row per scatter index, in the layout
-    the pool lives in) and the pools riding each layer scan as carry that
-    keep the compiler from transposing, slicing or copying them
-    (tests/test_tpu_compile.py).  Block tables / slot mappings are tiny
-    host-computed int32 arrays shipped per step.
+    (kv_cache.commit_paged: a burst row's steps and a wave row's chunk
+    written as the few aligned windows of slots they fall in, each an
+    update-slice in the layout the pool lives in; one [hd] row per scatter
+    index where the slots are not runs or the pool is quantized) and the
+    pools riding each layer scan as carry that keep the compiler from
+    transposing, slicing or copying them (tests/test_tpu_compile.py).
+    Block tables / slot mappings are tiny host-computed int32 arrays
+    shipped per step.
   - Scheduling (which request prefills, who decodes, page allocation) is
     host-side Python — control flow stays off the device; compute stays on.
   - Sampling runs on-device with per-row parameters so one fused kernel

@@ -3,9 +3,10 @@
 The vLLM idea (PagedAttention) rebuilt for TPU/XLA: K/V live in fixed page
 pools ``[L, num_pages, page_size, n_kv, hd]`` so sequences grow without
 reallocation or copy; a sequence's pages are an indirection table
-(``block_table``).  Writes are flat scatters with out-of-bounds drop
-semantics (padding tokens get slot -1), which XLA lowers to an efficient
-in-place scatter when the pools are donated into the step function.
+(``block_table``).  Writes name flat slots with out-of-bounds drop
+semantics (padding tokens get slot -1) and go through ``commit_paged``,
+which updates the donated pools in place: runs of consecutive slots as
+windows of slots, anything else a row per slot.
 
 Host side, the ``PageAllocator`` is plain Python — allocation decisions are
 control flow, not compute, and belong off-device (SURVEY.md §7 stage 2).
@@ -14,11 +15,14 @@ control flow, not compute, and belong off-device (SURVEY.md §7 stage 2).
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from githubrepostorag_tpu.models.qwen2 import Qwen2Config
+from githubrepostorag_tpu.ops.prefill_width import MAX_RUNG_ROWS
 from githubrepostorag_tpu.serving.chain_hash import chain_hashes
 
 
@@ -28,9 +32,9 @@ class PagePools:
 
     Layout [L, n_kv, P, page_size, hd] keeps each page's (page_size, hd)
     slab contiguous in the trailing two axes — the natural (sublane, lane)
-    tile for the Pallas kernel's page DMAs — and lets the KV scatter index a
-    flat [n_kv, P*page_size, hd] view with one slot vector shared by all
-    heads.
+    tile for the Pallas kernel's page DMAs — and lets the KV commit index a
+    flat [n_kv, P*page_size, hd] view (or its windows of whole tiles) with
+    one slot vector shared by all heads.
 
     ``ks``/``vs``: per-PAGE dequant scales [L, n_kv, P] f32
     when the pools are int8 (``kv_quant`` engines — each cached token
@@ -204,6 +208,109 @@ def unpack_int4(b: jnp.ndarray) -> jnp.ndarray:
     return jnp.concatenate([lo, hi], axis=-1).astype(jnp.int8)
 
 
+def _run_window(pools: jnp.ndarray, run: int | None) -> int | None:
+    """Slots in one window of the run form for runs of ``run`` slots into
+    ``pools``: whole (8, 128) tiles of 32-bit words (16 rows of bfloat16),
+    doubled until one holds a run or is a page; None where the pool keeps
+    the row form (no run length given, a row that is not whole lanes, a
+    page that does not hold whole windows)."""
+    if run is None:
+        return None
+    ps, hd = pools.shape[-2:]
+    win = 32 // pools.dtype.itemsize
+    while win < min(run, ps):
+        win *= 2
+    return win if hd % 128 == 0 and ps % win == 0 else None
+
+
+def _commit_runs(pools, vals, flat_slots, layer, run: int, win: int):
+    """``commit_paged``'s run form.  The pool is read as windows of ``win``
+    slots ([..., P * ps / win, win, hd]: a window never leaves its page); a
+    run of ``run`` consecutive positions falls in at most ``n_win`` of them,
+    wherever it starts and whatever page boundaries it crosses.  Each is
+    read over all leading axes at once, the run's kept rows are laid over
+    it and it is written back where it was: an update-slice of the donated
+    pool, in place (5 windows of 128 KB a layer where a 512-column chunk was
+    2,048 rows; 64 windows of 448 KB where a burst was 28,672 rows).  A slot
+    is written only by the row that names it, so every other slot keeps its
+    bits: dropped rows (padding, steps past a row's limit, dead row slots,
+    pages the row does not hold) write nothing, and a window no kept row
+    falls in is window 0 written back as it was read.
+
+    Many runs (a burst's row slots, a wave of many rows) are a loop over
+    the runs with the pool as its carry; the one or two runs of a wave that
+    has rungs (``MAX_RUNG_ROWS``) are written inline, because with the pool
+    as the carry of a loop INSIDE a branch of the wave's ``lax.switch`` the
+    v5e compiler copies it into and out of every iteration (2 of the 3 rungs
+    of a one-row wave, PR 38), and inline everywhere a start-up traces,
+    lowers and loads 320 windows a layer for the 32-row bucket (+1.5 s to
+    ready).  What keeps the compiler from re-laying the pool out so that a
+    window over the leading axes is contiguous (PR 25) is the attention
+    kernel, which reads the same buffer in the layout it arrives in:
+    tests/test_tpu_compile.py holds the step programs to all three."""
+    p, ps, hd = pools.shape[-3:]
+    total, lead = p * ps, vals.shape[:-2]
+    n_runs = flat_slots.shape[0] // run
+    first, widx, write = _plan_windows(flat_slots, total, run, win)
+    vals = vals.astype(pools.dtype).reshape(*lead, n_runs, run, hd)
+    view = pools.reshape(*pools.shape[:-3], total // win, win, hd)
+    lay = lambda r, view: _lay_run(view, vals, layer, r, first, widx, write)  # noqa: E731
+    if n_runs <= MAX_RUNG_ROWS:
+        for r in range(n_runs):
+            view = lay(r, view)
+    else:
+        view = jax.lax.fori_loop(0, n_runs, lay, view)
+    return view.reshape(pools.shape)
+
+
+@partial(jax.jit, static_argnames=("total", "run", "win"))
+def _plan_windows(flat_slots, total: int, run: int, win: int):
+    """For each run r of ``flat_slots`` and each of the K windows it can fall
+    in: ``first`` [R, K] the run's column that lies on the window's row 0
+    (negative: the run starts inside the window), ``widx`` [R, K] the
+    window's index in the pool's [P * ps / win] windows (0 where no kept slot
+    falls in it) and ``write`` [R, K, win] the window's rows that a kept slot
+    of the run names.  Jitted so that the K and the V commit of a layer, which
+    hand in the same slots, trace it once."""
+    n_win = (run + win - 2) // win + 1
+    slots = flat_slots.reshape(-1, run)
+    kept = (slots >= 0) & (slots < total)
+    # where in its window a run's column 0 lies, from the first slot it keeps
+    j0 = jnp.argmax(kept, axis=1)
+    off = (jnp.take_along_axis(slots, j0[:, None], axis=1)[:, 0] - j0) % win  # [R]
+    first = jnp.arange(n_win)[None, :] * win - off[:, None]
+    cols = first[..., None] + jnp.arange(win)  # [R, K, win]
+    at_col = jnp.take_along_axis(
+        jnp.where(kept, slots, -1)[:, None, :], jnp.clip(cols, 0, run - 1), axis=2)
+    at_col = jnp.where((cols >= 0) & (cols < run), at_col, -1)  # slot of each window row, -1: none
+    widx = jnp.max(at_col, axis=2, initial=0) // win
+    return first, widx, at_col == widx[..., None] * win + jnp.arange(win)
+
+
+@jax.jit
+def _lay_run(view, vals, layer, r, first, widx, write):
+    """Run ``r`` of ``_commit_runs``: for each of its K windows, rows
+    ``first[r, k] .. + win - 1`` of ``vals[..., r, :, :]`` laid over window
+    ``widx[r, k]`` of ``view`` [(L,) ..., W, win, hd] where ``write[r, k]``
+    says so.  A function of its own so that a commit of many runs is traced
+    once and called, not traced window by window (seconds to ready: the
+    wave of a 32-row bucket has 320 windows a layer)."""
+    win, lead = view.shape[-2], vals.shape[:-3]
+    rows = jax.lax.dynamic_index_in_dim(vals, r, len(lead), keepdims=False)
+    rows = jnp.pad(rows, [(0, 0)] * len(lead) + [(win, win), (0, 0)])
+    first, widx, write = (jax.lax.dynamic_index_in_dim(x, r, keepdims=False)
+                          for x in (first, widx, write))
+    origin, shape = (0,) * len(lead), (*lead, 1, *view.shape[-2:])  # every leading index
+    if layer is not None:
+        origin, shape = (layer, *origin), (1, *shape)
+    for k in range(first.shape[0]):
+        at = (*origin, widx[k], 0, 0)
+        new = jax.lax.dynamic_slice_in_dim(rows, first[k] + win, win, axis=-2).reshape(shape)
+        new = jnp.where(write[k][:, None], new, jax.lax.dynamic_slice(view, at, shape))
+        view = jax.lax.dynamic_update_slice(view, new, at)
+    return view
+
+
 def commit_paged(
     pools: jnp.ndarray,  # [..., P, page_size, hd]
     vals: jnp.ndarray,  # [..., N, hd] new K or V vectors, leading dims match
@@ -212,29 +319,48 @@ def commit_paged(
     page_size: int,
     layer: jnp.ndarray | None = None,  # [] int32: pools/scales keep their
     # leading [L] axis, vals (and the write) are that one layer's
+    run: int | None = None,  # static: the slots are N // run runs, see below
 ):
-    """Scatter new K or V vectors into flat pool slots — THE pool-commit
+    """Write new K or V vectors into flat pool slots — THE pool-commit
     rule, shared by the chunked-prefill (models/qwen2.forward_paged),
     decode-burst (serving/decode_burst), and ring-prefill
-    (serving/long_prefill) paths so the quantization/scatter semantics can
+    (serving/long_prefill) paths so the quantization/write semantics can
     never drift apart.  ``scales is None`` = full-precision pools (vals
     cast to the pool dtype); else quantized pools with each page's scale
     fixed by its first write (quantize_kv_paged) — int8 when the pool
     dtype is int8, nibble-packed int4 (pack_int4) when it is uint8.
 
-    The scatter writes ONE [hd] row per (leading index..., slot): every
-    leading axis is indexed, none is a window.  A window over the leading
-    axes (``flat.at[:, slots]``: all layers and heads of a slot) makes the
-    TPU compiler re-lay the whole pool out so that the window is
-    contiguous, and back again after: two transposes of the pool around a
-    scatter of a few MB (PERF.md, Findings, PR 25).  Rows leave the pool
-    in the layout it lives in; the kv-head axis stays an axis of its own,
-    so pools sharded over kv heads (tp) scatter shard-locally.
+    Two forms, one result to the bit, chosen from the static shapes and
+    dtype handed in:
+
+    * the RUN form, where the caller says its slots come as runs
+      (``run``: every ``run`` consecutive entries of ``flat_slots`` are
+      consecutive positions of one sequence, any of them dropped — a burst
+      row's ``n_steps`` tokens, a wave row's chunk) — ``_commit_runs``
+      moves each run as the few aligned windows of slots it falls in;
+    * the ROW form, the general case: a scatter that writes ONE [hd] row
+      per (leading index..., slot), 68-74 ns a row on a v5e whatever it
+      holds (PR 25).  Every leading axis is indexed, none is a window: a
+      scatter window over the leading axes (``flat.at[:, slots]``: all
+      layers and heads of a slot) makes the TPU compiler re-lay the whole
+      pool out so that the window is contiguous, and back again after: two
+      transposes of the pool around a scatter of a few MB (PERF.md,
+      Findings, PR 25).  Quantized pools keep it (a page's scale is found
+      from all rows of the commit, not a run at a time), and so do pools
+      whose row is not whole 128-lane tiles or whose page does not hold
+      whole windows (``_run_window``); no benchmark cell runs either with
+      ``run`` given.
+
+    In both the kv-head axis stays an axis of its own, so pools sharded
+    over kv heads (tp) commit shard-locally.
 
     ``layer`` is the carried form: the caller keeps the whole
     [L, ..., P, ps, hd] pool (a scan carry, never sliced) and commits one
     layer's ``vals`` [..., N, hd] at that index.  Returns (pools, scales)."""
     p, ps, hd = pools.shape[-3:]  # hd is the STORED payload width
+    win = _run_window(pools, run) if scales is None else None
+    if win is not None:
+        return _commit_runs(pools, vals, flat_slots, layer, run, win), None
     if scales is not None:
         qmax = 7 if pools.dtype == jnp.uint8 else 127
         page_scales = scales if layer is None else scales[layer]

@@ -32,19 +32,47 @@ from githubrepostorag_tpu.serving.kv_cache import (
     quantize_kv_paged,
 )
 
-L, N_KV, P, PS, HD = 3, 2, 6, 8, 16
+L, N_KV, P, PS, HD = 3, 2, 6, 32, 128  # a row of whole lanes, a page of whole tiles
 TOTAL = P * PS
 KINDS = {"bf16": (jnp.bfloat16, 0), "int8": (jnp.int8, 127), "int4": (jnp.uint8, 7)}
 
-# what a commit may be handed: [N] flat slots, TOTAL marks a dropped token
+
+def _at(pages, start, n, pad_to=None):
+    """Flat slots of positions start .. start + n - 1 of a sequence whose
+    page i is ``pages[i]`` (P: a page the row does not hold), padded with the
+    dropped sentinel to ``pad_to`` entries."""
+    pos = np.arange(start, start + n)
+    slots = np.asarray(pages)[pos // PS] * PS + pos % PS
+    return [*np.minimum(slots, TOTAL), *[TOTAL] * ((pad_to or n) - n)]
+
+
+# what a commit may be handed: ([N] flat slots, TOTAL marks a dropped token;
+# the length of the runs the caller says they come in, None: says nothing)
 SLOTS = {
     # a page opened at its first slot, then an append to another page
-    "duplicate-free": [8, 9, 10, 11, 12, 40, 41, 3],
+    "duplicate-free": ([32, 33, 34, 35, 36, 160, 161, 3], None),
     # padding and inactive rows arrive as the out-of-range sentinel
-    "dropped-sentinels": [16, TOTAL, 17, TOTAL, TOTAL, 18, TOTAL, 19],
+    "dropped-sentinels": ([64, TOTAL, 65, TOTAL, TOTAL, 66, TOTAL, 67], None),
     # one run of tokens that leaves page 2 and opens page 3
-    "crosses-a-page": [20, 21, 22, 23, 24, 25, 26, 27],
-    "all-dropped": [TOTAL] * 8,
+    "crosses-a-page": (_at([0, 0, 2, 3], 92, 8), None),
+    "all-dropped": ([TOTAL] * 8, None),
+    # ---- a burst: a row's 8 steps are 8 consecutive positions
+    "burst-inside-a-page": (_at([1], 5, 8) + _at([4, 3], 40, 8), 8),
+    # ... the second page anywhere in the pool, not behind the first
+    "burst-across-a-page": (_at([4, 0], 28, 8) + _at([5, 2], 31, 8), 8),
+    # a row at its limit after 3 steps: the other 5 are dropped
+    "burst-cut-short": (_at([3], 6, 3, pad_to=8) + _at([1, 2], 30, 1, pad_to=8), 8),
+    "burst-dead-row": ([TOTAL] * 8 + _at([0, 5], 27, 8) + [TOTAL] * 8, 8),
+    "burst-all-dropped": ([TOTAL] * 16, 8),
+    # ---- a wave: a row's chunk (64 columns, two pages) is consecutive positions
+    "chunk-ends-mid-page": (_at([1, 4], 0, 40, pad_to=64), 64),
+    # the table's sentinel where the second page would be
+    "chunk-page-not-held": (_at([2, P], 0, 64), 64),
+    "chunk-starts-mid-page": (_at([3, 5, 0], 10, 64), 64),
+    "chunk-rows-of-unequal-length": (
+        _at([1, 2], 0, 64) + _at([0, 5], 35, 7, pad_to=64) + [TOTAL] * 64, 64),
+    # a spec-verify window of k + 1 tokens: a run shorter than a tile
+    "window-of-5": (_at([2, 4], 30, 5) + _at([1], 0, 5), 5),
 }
 
 
@@ -100,14 +128,16 @@ def _fresh(kind: str, lead: tuple, seed: int):
 @pytest.mark.parametrize("lead", [(L, N_KV), (N_KV,)], ids=["layers-heads", "heads"])
 @pytest.mark.parametrize("kind", KINDS)
 def test_commit_matches_a_plain_loop(kind, lead, slots):
+    """The whole pool, untouched slots included, equal to the loop's; where
+    the caller names a run length, equal to what the row form gives too."""
     pools, scales, qmax = _fresh(kind, lead, seed=len(lead))
-    slot_list = SLOTS[slots]
+    slot_list, run = SLOTS[slots]
     vals = np.random.default_rng(7).normal(size=(*lead, len(slot_list), HD)).astype(np.float32)
     want_pools, want_scales = _commit_loop(
         np.asarray(pools, np.float32) if not qmax else pools, vals, slot_list, scales, qmax)
-    got_pools, got_scales = commit_paged(
-        jnp.asarray(pools), jnp.asarray(vals), jnp.asarray(slot_list, jnp.int32),
-        None if scales is None else jnp.asarray(scales), PS)
+    args = (jnp.asarray(pools), jnp.asarray(vals), jnp.asarray(slot_list, jnp.int32),
+            None if scales is None else jnp.asarray(scales), PS)
+    got_pools, got_scales = commit_paged(*args, run=run)
     assert got_pools.dtype == KINDS[kind][0] and got_pools.shape == pools.shape
     if qmax:
         np.testing.assert_array_equal(np.asarray(got_pools), want_pools)
@@ -117,22 +147,28 @@ def test_commit_matches_a_plain_loop(kind, lead, slots):
         want = np.asarray(jnp.asarray(want_pools, jnp.bfloat16), np.float32)
         np.testing.assert_array_equal(np.asarray(got_pools, np.float32), want)
         assert got_scales is None
+    if run is not None:
+        row_pools, _ = commit_paged(*args)
+        np.testing.assert_array_equal(np.asarray(got_pools.astype(jnp.float32)),
+                                      np.asarray(row_pools.astype(jnp.float32)))
 
 
+@pytest.mark.parametrize("slots", ["crosses-a-page", "burst-across-a-page",
+                                   "chunk-starts-mid-page"])
 @pytest.mark.parametrize("layer", [0, L - 1], ids=["first-layer", "last-layer"])
 @pytest.mark.parametrize("kind", KINDS)
-def test_commit_of_one_layer_into_the_carried_pool(kind, layer):
+def test_commit_of_one_layer_into_the_carried_pool(kind, layer, slots):
     """The carried form: the whole [L, ...] pool, one layer's values, the
     layer as a traced index.  Equal to committing that layer's slab alone,
     with every other layer (and its scales) untouched."""
     pools, scales, qmax = _fresh(kind, (L, N_KV), seed=11)
-    slot_list = SLOTS["crosses-a-page"]
+    slot_list, run = SLOTS[slots]
     vals = np.random.default_rng(3).normal(size=(N_KV, len(slot_list), HD)).astype(np.float32)
     slots = jnp.asarray(slot_list, jnp.int32)
     pools_j = jnp.asarray(pools)
     scales_j = None if scales is None else jnp.asarray(scales)
 
-    carried = jax.jit(lambda p, v, s, sc, li: commit_paged(p, v, s, sc, PS, layer=li))
+    carried = jax.jit(lambda p, v, s, sc, li: commit_paged(p, v, s, sc, PS, layer=li, run=run))
     got_pools, got_scales = carried(pools_j, jnp.asarray(vals), slots, scales_j,
                                     jnp.asarray(layer, jnp.int32))
     slab, slab_scales = commit_paged(
@@ -145,6 +181,39 @@ def test_commit_of_one_layer_into_the_carried_pool(kind, layer):
         want_scales = np.array(scales)
         want_scales[layer] = np.asarray(slab_scales)
         np.testing.assert_array_equal(np.asarray(got_scales), want_scales)
+
+
+# pool [1, P, page, width] of a dtype, scales or none, the run handed in -> the form
+FORMS = {
+    "bf16-told-runs": ((PS, HD), jnp.bfloat16, False, 8, "run"),
+    "bf16-chunk-of-pages": ((PS, HD), jnp.bfloat16, False, 2 * PS, "run"),
+    "float32-page-of-8": ((8, HD), jnp.float32, False, 8, "run"),
+    "told-nothing": ((PS, HD), jnp.bfloat16, False, None, "row"),
+    # DeepSeek-V3's latent row [c_kv | k_rope]: 4.5 lane tiles
+    "latent-576-wide": ((PS, 576), jnp.bfloat16, False, 8, "row"),
+    "page-of-8-bf16": ((8, HD), jnp.bfloat16, False, 8, "row"),  # half a tile of 16 rows
+    "int8-scales": ((PS, HD), jnp.int8, True, 8, "row"),
+    "int4-scales": ((PS, HD // 2), jnp.uint8, True, 8, "row"),
+}
+
+
+@pytest.mark.parametrize("case", FORMS)
+def test_which_form_a_pool_takes(case):
+    """Told that the slots come as runs, a full-precision pool whose row is
+    whole lanes and whose page is whole tiles moves windows (update-slices,
+    no scatter); every other pool keeps the row scatter."""
+    (ps, width), dtype, quant, run, form = FORMS[case]
+    pools = jnp.zeros((1, P, ps, width), dtype)
+    n = max(16, run or 0)
+    vals = jnp.ones((1, n, HD if quant else width), jnp.float32)
+    scales = jnp.ones((1, P), jnp.float32) if quant else None
+    slots = jnp.arange(n, dtype=jnp.int32)
+    text = str(jax.make_jaxpr(lambda p, v, s, sc: commit_paged(p, v, s, sc, ps, run=run))(
+        pools, vals, slots, scales))
+    assert ("dynamic_update_slice" in text, "scatter" in text) == (
+        (True, False) if form == "run" else (False, True))
+    got, _ = commit_paged(pools, vals, slots, scales, ps, run=run)
+    assert float(jnp.abs(got[0, 0, :, 0].astype(jnp.float32)).sum()) > 0  # and it wrote
 
 
 # ---- forward_paged with carried pools against the form it replaced --------
@@ -246,3 +315,69 @@ def test_forward_paged_with_carried_pools_equals_the_sliced_form(kind, use_palla
                                               np.asarray(ref.astype(jnp.float32)))
         cached = cached + new
     assert float(jnp.abs(kp.astype(jnp.float32)).sum()) > 0  # something was written
+
+
+# ---- the two step programs with the run commit against the row commit -----
+
+
+def test_wave_and_burst_commit_runs_as_they_committed_rows(monkeypatch):
+    """A config whose pools take the run form (head of 128, pages of 16
+    bfloat16 rows) through the prefill chunk (a row that starts on a page, one
+    that starts mid-page, a padded one) and the decode burst (a row that
+    crosses a page, one at its limit after 3 steps, a dead one): logits,
+    tokens and both whole pools equal to the same programs traced with
+    commit_paged held to its row form."""
+    import githubrepostorag_tpu.serving.kv_cache as kv_cache
+    from githubrepostorag_tpu.models.qwen2 import forward_paged_impl
+    from githubrepostorag_tpu.serving.decode_burst import decode_burst
+
+    cfg = Qwen2Config(vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
+                      num_heads=2, num_kv_heads=2, head_dim=128, rope_theta=10_000.0,
+                      tie_word_embeddings=True, max_position_embeddings=512)
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    pages, ps, b, s, steps = 12, 16, 3, 24, 8
+    bt = jnp.asarray([[1, 4, 6, 9], [2, 5, 7, 10], [3, 8, 11, 0]], jnp.int32)
+    rng = np.random.default_rng(5)
+    chunks = []
+    cached = np.zeros((b,), np.int32)
+    for new in ([24, 9, 0], [7, 24, 16]):
+        new = np.asarray(new, np.int32)
+        offs = np.arange(s)[None, :]
+        pos = cached[:, None] + offs
+        slot = np.asarray(bt)[np.arange(b)[:, None], pos // ps] * ps + pos % ps
+        slot = np.where(offs < new[:, None], slot, -1).astype(np.int32)
+        chunks.append((jnp.asarray(rng.integers(0, cfg.vocab_size, (b, s)), jnp.int32),
+                       jnp.asarray(pos, jnp.int32), jnp.asarray(slot), jnp.asarray(cached),
+                       jnp.asarray(new)))
+        cached = cached + new
+    lens = jnp.asarray(cached)  # 31, 33, 16: row 0 crosses a page in the burst
+    limits = jnp.asarray([64, 36, 64], jnp.int32)  # row 1 is at its limit after 3 steps
+    active = jnp.asarray([True, True, False])
+
+    def run_both():
+        shape = (cfg.num_layers, cfg.num_kv_heads, pages, ps, cfg.head_dim)
+        kp, vp = jnp.zeros(shape, jnp.bfloat16), jnp.ones(shape, jnp.bfloat16)
+        wave = jax.jit(lambda kp, vp, *a: forward_paged_impl(params, cfg, a[0], a[1], kp, vp,
+                                                             a[2], bt, a[3], a[4]))
+        out = []
+        for chunk in chunks:
+            logits, kp, vp = wave(kp, vp, *chunk)
+            out.append(logits)
+        ones, zeros = jnp.ones((b,), jnp.float32), jnp.zeros((b,), jnp.int32)
+        burst = jax.jit(lambda kp, vp: decode_burst.__wrapped__(
+            params, cfg, zeros + 7, lens, kp, vp, jnp.zeros((b, cfg.vocab_size), bool), active,
+            limits, bt, jax.random.PRNGKey(1), 0 * ones, ones, zeros, ones, n_steps=steps,
+            first_tokens=zeros, fresh=jnp.zeros((b,), bool), fresh_lens=zeros,
+            key_step=jnp.uint32(0)))
+        toks, valid, kp, vp, *_ = burst(kp, vp)
+        return [*out, toks, valid, kp.astype(jnp.float32), vp.astype(jnp.float32)]
+
+    assert kv_cache._run_window(jnp.zeros((1, ps, 128), jnp.bfloat16), steps) == 16
+    got = run_both()
+    monkeypatch.setattr(kv_cache, "_run_window", lambda pools, run: None)
+    want = run_both()
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    valid = np.asarray(got[-3])
+    assert valid[0].all() and valid[1].sum() == 3 and not valid[2].any()
+    assert not np.array_equal(got[-2], np.zeros_like(got[-2]))

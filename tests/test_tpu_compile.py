@@ -11,7 +11,8 @@ speed; a pass here is not a chip run.
 The second half compiles the two step programs of the benchmark's cells
 whole (decode burst, paged prefill; Qwen2-7B int8 weights, 384 pages) and
 reads the optimized HLO: nothing in it may copy, transpose or slice a K/V
-page pool, or a layer of one (PERF.md, Findings, PR 25).
+page pool, or a layer of one (PERF.md, Findings, PR 25), nor scatter single
+rows into a full-precision one (PR 38: windows of slots, written in place).
 """
 
 import functools
@@ -152,21 +153,43 @@ MOVERS = ("copy", "copy-start", "transpose", "dynamic-slice", "dynamic-update-sl
           "all-gather", "all-gather-start", "all-to-all", "collective-permute")
 
 
-def pool_movers(hlo: str, pool_shape: tuple) -> list:
+_UPDATE = re.compile(r" dynamic-update-slice\(%?[\w.\-]+, %?([\w.\-]+)[,)]")
+
+
+def pool_movers(hlo: str, pool_shape: tuple, ops: tuple = MOVERS, windows: bool = True) -> list:
     """Instructions of the optimized HLO, fused computations included (so a
     fusion of a copy counts), that copy, transpose, slice, update-slice or
     gather across chips into a result holding a whole pool (as one device
     holds it, or all of it) or a whole layer of one, however its
     leading axes are merged: [28,4,384,128,128], [1,4,384,128,128],
-    [4,49152,128], [112,49152,128], [5505024,128], ..."""
+    [4,49152,128], [112,49152,128], [5505024,128], [28,4,3072,16,128], ...
+
+    ``windows=False`` leaves out the update-slices that write in place: an
+    update-slice writes its update (its second operand) into the buffer of
+    its first, and where the compiler cannot reuse that buffer it says so
+    with a ``copy`` before it, which is found under its own name.  So an
+    update-slice moves a pool only when the update itself holds a layer of
+    one or more (a scan stacking its ys: PR 25), not when it is a window of
+    slots (``commit_paged``'s run form, PR 38; a state pool's rows, PR 34,
+    whose test counts them).  ``ops`` names other instructions to look for
+    instead: ("scatter",) finds the row form's scatters into a pool."""
     sizes = (math.prod(pool_shape[:-1]), math.prod(pool_shape[1:-1]))
-    found = []
+    elements, found = {}, []
     for line in hlo.splitlines():
         m = _INSTR.match(line)
-        if m and m.group(3) in MOVERS:
+        if m:
+            elements[m.group(1)] = math.prod(map(int, m.group(2).split(",")))
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        if m and m.group(3) in ops:
             *lead, width = map(int, m.group(2).split(","))
-            if width == pool_shape[-1] and math.prod(lead) in sizes:
-                found.append(f"{m.group(1)} {m.group(3)} [{m.group(2)}]")
+            if width != pool_shape[-1] or math.prod(lead) not in sizes:
+                continue
+            update = _UPDATE.search(line)
+            if (not windows and update
+                    and elements.get(update.group(1), math.inf) < math.prod(pool_shape[1:])):
+                continue
+            found.append(f"{m.group(1)} {m.group(3)} [{m.group(2)}]")
     return found
 
 
@@ -196,7 +219,7 @@ def assert_wave_holds_every_rung(hlo: str, rungs: int, pool_shape: tuple) -> Non
         assert "while/body" in ln, ln  # inside a layer scan, not around one
         branches = re.search(r"branch_computations=\{([^}]*)\}", ln).group(1).split(",")
         assert len(branches) == rungs, ln
-    assert pool_movers(hlo, pool_shape) == []
+    assert pool_movers(hlo, pool_shape, windows=False) == []
 
 
 @pytest.fixture()
@@ -314,7 +337,12 @@ def test_step_program_leaves_the_pools_in_place(chip, as_on_chip, program, kv, v
     lowered, pool_shape = _cell_program(chip, program, kv, variant)
     hlo = lowered.compile().as_text()
     assert "tpu_custom_call" in hlo  # the attention kernel is in the program
-    assert pool_movers(hlo, pool_shape) == []
+    assert pool_movers(hlo, pool_shape, windows=False) == []
+    # full-precision pools are committed a window of slots at a time, in every
+    # rung of the wave: no scatter of single rows into a pool is left
+    rows = pool_movers(hlo, pool_shape, ("scatter",))
+    windows = pool_movers(hlo, pool_shape, ("dynamic-update-slice",))
+    assert (rows == [] and windows) if kv == "fp" else rows, (rows, windows)
 
 
 def test_the_wave_is_one_program_that_donates_pools_and_presence(chip, as_on_chip):
@@ -340,7 +368,7 @@ def test_step_program_leaves_sharded_pools_in_place(tp4, as_on_chip, program, va
     hlo = lowered.compile().as_text()
     assert "tpu_custom_call" in hlo
     whole = (held[0], held[1] * tp4.shape["tp"], *held[2:])
-    assert pool_movers(hlo, held) + pool_movers(hlo, whole) == []
+    assert pool_movers(hlo, held, windows=False) + pool_movers(hlo, whole, windows=False) == []
 
 
 def test_the_burst_has_one_shape_whatever_joins_it():
