@@ -89,9 +89,12 @@ class DeepseekV3Config:
     max_position_embeddings: int = 163840
     experts_held: tuple = (0, 256)  # [first, past the last) of n_routed_experts
 
-    # what the serving engine and kv_cache.make_page_pools ask of a model:
-    # one "kv head" whose row is the latent, and no V pool
+    # what the serving engine and kv_cache.make_page_pools ask of a model: the
+    # module whose step programs serve it, one "kv head" whose row is the
+    # latent and no V pool, and expert counters from its programs
+    step_programs = "githubrepostorag_tpu.models.deepseek_v3"
     latent_kv = True
+    expert_counters = True
     # and the most rows one prefill wave carries (the rest ride the next
     # step's wave).  The prefill program is compiled once a row bucket (1, 2,
     # 4, 8, ...), whole, kernel included; a server warms the buckets up to

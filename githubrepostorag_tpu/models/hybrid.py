@@ -1,0 +1,473 @@
+"""The step skeleton of a hybrid of Gated DeltaNet and paged full-attention
+layers: what models/qwen3_next.py and models/olmo_hybrid.py share.
+
+One PERIOD is ``cfg.gdn_per_period`` Gated DeltaNet layers, then one
+full-attention layer.  The prefill wave is a scan over periods, the burst
+unrolls them.  Two kinds of per-sequence memory ride them as carries, never
+sliced:
+
+* K/V page pools ``[periods, n_kv, P, page_size, head_dim]`` for the
+  full-attention layers alone (``cfg.kv_layers``), committed by
+  ``kv_cache.commit_paged`` at a traced layer index, read by the paged
+  kernels qwen2 uses;
+* a STATE pool for the Gated DeltaNet layers (``cfg.state_shapes()``,
+  ``kv_cache.make_state_pools``): ``s`` ``[gdn layers, slots, Hv, dk, dv]``
+  float32 (the recurrence's matrix) and ``conv`` ``[gdn layers, slots, (taps -
+  1) * channels]`` bfloat16 (the convolution's history).  A slot is one
+  sequence's state in every layer.  The engine's rows own the first
+  ``max_num_seqs`` slots (row r = slot r), snapshot slots follow, and the
+  last slot takes the writes of rows that have nothing to write.
+
+``wave`` and ``burst`` keep qwen2's contracts (``forward_paged`` /
+``forward_paged_wave`` / ``decode_burst``) and add the state beside the
+pools: a wave is told, a row, which slot its state comes from (``-1``: a
+fresh sequence, zeros; its own slot: the next chunk of a prompt; a snapshot
+slot: a prefix hit resumes there), which slot takes the state after the
+chunk, and which slot takes a SNAPSHOT of the state after ``snap_col`` of
+the chunk's tokens (a page boundary; ops/gated_delta.py catches it between
+two blocks of the chunked form).  All of it is device copies inside the
+program.  The burst steps rows 0 .. B-1 in place; a row that sits a step
+out keeps its state and history bit for bit.
+
+What a model brings (``m``, an object of functions; the skeleton never asks
+which model it runs):
+
+* ``weights(params) -> w``: whatever its own functions index, opaque here;
+  ``gdn_weights(w, g)`` / ``attn_weights(w, pi)``: one layer's mixer weights;
+* ``embed(params, ids)`` (the residual stream, float32), ``position_cols(cfg,
+  positions)`` (what runs along the chunk beside it: rotary tables, or
+  nothing), ``final(cfg, params, h)`` and ``head(params, h)``;
+* the block's wiring: ``mixer_input(cfg, w, li, h)`` (a pre-norm, or a cast)
+  and ``after_mixer(cfg, w, li, h, y, live) -> (h, counts or None)`` (the
+  residual add, the feed-forward or expert layer and its add);
+* ``gdn_inputs(cfg, p, x)`` / ``gdn_out(cfg, p, o, z)`` around the shared
+  convolution and recurrence, ``attn_project(cfg, p, x, *position_cols) ->
+  (q, k, v, more)`` / ``attn_out(p, attn, *more)`` around the shared pages
+  and kernels;
+* ``attn_window``: columns of a prefill chunk one call of the attention
+  kernel takes.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+from githubrepostorag_tpu.ops.gated_delta import (
+    BLOCK,
+    causal_conv,
+    causal_conv_step,
+    gated_delta_chunked,
+    gated_delta_step,
+    l2norm,
+    mask_padding,
+)
+from githubrepostorag_tpu.ops.latent_attention import einsum_f32
+from githubrepostorag_tpu.ops.norms import rms_norm_gated
+from githubrepostorag_tpu.ops.prefill_width import at_wave_width
+from githubrepostorag_tpu.ops.sampling import sample_tokens_capped, sample_tokens_nofilter
+from githubrepostorag_tpu.runtime import _pinned_to_cpu, on_tpu
+
+
+def tpu_compiler_options(options: dict) -> dict | None:
+    """``options`` for ``jax.jit(compiler_options=...)`` of a step program: the
+    TPU compiler's own keys, which the CPU compiler refuses ("No such compile
+    option"), so a process pinned to the CPU backend (tests, rehearsals) gets
+    None.  Read when the module is imported; reading the pin starts no backend.
+    A test that compiles for a described chip passes the options to
+    ``.compile()`` itself."""
+    return None if _pinned_to_cpu() else dict(options)
+
+
+def at(tree, index):
+    """Layer ``index`` of every stacked leaf of ``tree``: static in the burst
+    (a view), traced in the wave (a slice the product reads through)."""
+    if isinstance(index, int):
+        return jax.tree.map(lambda x: x[index], tree)
+    return jax.tree.map(lambda x: jax.lax.dynamic_index_in_dim(x, index, keepdims=False), tree)
+
+
+def state_read(pool, layer, slots):
+    """Rows' slots of one layer of a state pool, read where they lie; zeros
+    where the slot is negative (a fresh sequence)."""
+    rows = [jax.lax.dynamic_slice(pool, (layer, jnp.maximum(slots[r], 0)) + (0,) * (pool.ndim - 2),
+                                  (1, 1, *pool.shape[2:]))[0, 0]
+            for r in range(slots.shape[0])]
+    keep = (slots >= 0).reshape(-1, *(1,) * (pool.ndim - 2))
+    return jnp.where(keep, jnp.stack(rows), 0)
+
+
+def state_write(pool, layer, slots, vals):
+    """Rows' values into their slots of one layer, in place, a row at a time."""
+    for r in range(slots.shape[0]):
+        pool = jax.lax.dynamic_update_slice(
+            pool, vals[r][None, None].astype(pool.dtype),
+            (layer, slots[r]) + (0,) * (pool.ndim - 2))
+    return pool
+
+
+def lane_padded(width: int) -> int:
+    """``width`` up to a multiple of the 128-lane tile: what a state pool's
+    last axis is stored at.  A float32 pool ``[.., 96, 192]`` is given, on a
+    v5e, a layout with the SLOTS on the lanes (192 would pad to 256, 97 slots
+    to 128: the compiler takes the smaller), and every program then either
+    copies the 1.7 GB pool into row-major order and back or reads all 97
+    slots to step 32; stored ``[.., 96, 256]`` it stays row-major, and the
+    programs cut the padding off what they read and put zeros back."""
+    return -(-width // 128) * 128
+
+
+def _cut(x, width: int):
+    return x if x.shape[-1] == width else x[..., :width]
+
+
+def _fill(x, width: int):
+    if x.shape[-1] == width:
+        return x
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, width - x.shape[-1]),))
+
+
+def swiglu(x, wgu, wd):
+    """SwiGLU (gate | up side by side in ``wgu``) with the down-projection
+    accumulated and returned in float32."""
+    g, u = jnp.split(x @ wgu, 2, axis=-1)
+    return einsum_f32("...f,fd->...d", jax.nn.silu(g) * u, wd)
+
+
+# ------------------------------------------------- the Gated DeltaNet mixer --
+
+def gdn_inputs(cfg, p, x, act, beta_max: float = 1.0):
+    """x [B, S, d] -> (the convolution's input [B, S, C]: q | k | v of the
+    linear heads, in ``act`` as the history keeps it; z [B, S, Hv, dv]; beta,
+    g [B, S, Hv]).  The projections' columns lie as they are read: ``q | k |
+    v | z`` and ``b | a``.  ``beta = beta_max * sigmoid(b)``: 2 where the
+    published model allows ``I - beta k k^T`` a negative eigenvalue.
+
+    The burst (one token a row) runs ``w_qkvz`` as one product and cuts its
+    columns after it; a chunk runs two products, each on its own columns of
+    the leaf.  Either program, compiled for a v5e, then reads the stack where
+    it lies, and neither form serves the other: cut before the product, the
+    burst transposes the whole stack once a burst; cut after it, a wave of
+    eight rows never came back from the chip (PERF.md, Findings, PR 35)."""
+    b, s, _ = x.shape
+    hv, dv, c = cfg.linear_num_value_heads, cfg.linear_value_head_dim, cfg.conv_channels
+    with jax.named_scope("gdn_proj"):
+        if s == 1:
+            qkvz = einsum_f32("bsd,de->bse", x, p["w_qkvz"])
+            mixed, z = qkvz[..., :c], qkvz[..., c:]
+        else:
+            mixed, z = (einsum_f32("bsd,de->bse", x, w)
+                        for w in (p["w_qkvz"][:, :c], p["w_qkvz"][:, c:]))
+        ba = einsum_f32("bsd,de->bse", x, p["w_ba"])
+        beta = jax.nn.sigmoid(ba[..., :hv])
+        if beta_max != 1.0:
+            beta = beta_max * beta
+        g = -jnp.exp(p["A_log"]) * jax.nn.softplus(ba[..., hv:] + p["dt_bias"])
+    return mixed.astype(act), z.reshape(b, s, hv, dv), beta, g
+
+
+def gdn_heads(cfg, y):
+    """The convolution's output [B, S, C] float32 -> (q, k [B, S, Hv, dk]
+    L2-normalised, q scaled; v [B, S, Hv, dv]); a key head serves r value heads."""
+    b, s, _ = y.shape
+    hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    q = l2norm(y[..., :hk * dk].reshape(b, s, hk, dk)) * dk ** -0.5
+    k = l2norm(y[..., hk * dk:2 * hk * dk].reshape(b, s, hk, dk))
+    v = y[..., 2 * hk * dk:].reshape(b, s, hv, dv)
+    if hv == hk:
+        return q, k, v
+    return jnp.repeat(q, hv // hk, axis=2), jnp.repeat(k, hv // hk, axis=2), v
+
+
+def gdn_out(cfg, p, o, z, act):
+    with jax.named_scope("gdn_gate_norm"):
+        y = rms_norm_gated(o, z, p["o_norm"], cfg.rms_norm_eps)
+    return einsum_f32("bse,ed->bsd", y.reshape(*y.shape[:2], -1).astype(act), p["w_out"])
+
+
+# ----------------------------------------------------------- step programs --
+
+def wave(m, params, cfg, input_ids, positions, k_pages, v_pages, slot_mapping, block_tables,
+         cached_lens, new_lens, state, state_src, state_dst, state_snap, snap_col,
+         use_pallas=False, logits_at=None, width=None):
+    """A prefill chunk, qwen2.forward_paged's contract with the state beside
+    the pools (module docstring), traced into the wave program too
+    (``width``: the wave's, see ops/prefill_width.at_wave_width).  Returns
+    (logits, k_pages, v_pages, the layers' counts [2], state)."""
+    from githubrepostorag_tpu.ops.paged_attention import paged_attention_ref
+    from githubrepostorag_tpu.serving.kv_cache import commit_paged
+
+    num_pages, page_size = k_pages.shape[2], k_pages.shape[3]
+    nkv, hd = cfg.num_kv_heads, cfg.head_dim
+    block = math.gcd(BLOCK, page_size)  # a snapshot's column lies between two blocks
+    h = m.embed(params, input_ids)
+    along = m.position_cols(cfg, positions)
+    slots = jnp.where(slot_mapping < 0, num_pages * page_size, slot_mapping)  # dropped
+    live = jnp.arange(input_ids.shape[1])[None, :] < new_lens[:, None]
+    w = m.weights(params)
+    gpp, dv = cfg.gdn_per_period, cfg.linear_value_head_dim
+
+    chunk = input_ids.shape[1]
+    cols = (h, *along, live)
+
+    def padded(x):  # a rung's columns back to the chunk's: every rung returns the same shapes
+        return jnp.pad(x, ((0, 0), (0, chunk - x.shape[1])) + ((0, 0),) * (x.ndim - 2))
+
+    def add(stats, counts):
+        return stats if counts is None else stats + counts
+
+    # THE POOLS NEVER ENTER A SWITCH (at_wave_width).  A branch that writes a
+    # pool it was handed, or only hands it on, makes the v5e compiler copy the
+    # pool into the branch and out of it (1.2 GB of state, 0.5 GB of keys, a
+    # layer: this program, unlike qwen2's, has four switches a scan body).  A
+    # layer's rows of state are read before its switch and written after it;
+    # the attention layer's switch ends at q, k, v, its pages are committed
+    # and attended outside, and a second switch takes the rest of the layer.
+    def gdn_layer(pi, j, h, st_pools, stats):
+        s_pool, c_pool = st_pools
+        g, li = pi * gpp + j, pi * (gpp + 1) + j
+        with jax.named_scope("state_read"):
+            s0 = _cut(state_read(s_pool, g, state_src), dv).astype(jnp.float32)
+            taps0 = state_read(c_pool, g, state_src)
+
+        def layer(cols, came_in):
+            h, live = cols[0], cols[len(along) + 1]
+            s0, taps0 = came_in
+            x, pg = m.mixer_input(cfg, w, li, h), m.gdn_weights(w, g)
+            mixed, z, beta, gate = m.gdn_inputs(cfg, pg, x)
+            with jax.named_scope("gdn_conv"):
+                y, taps, taps_snap = causal_conv(
+                    mixed, taps0.reshape(h.shape[0], -1, mixed.shape[-1]), pg["conv_w"],
+                    new_lens, snap_col)
+                taps, taps_snap = (t.reshape(t.shape[0], -1) for t in (taps, taps_snap))
+            q, k, v = gdn_heads(cfg, y)
+            k, gate, beta = mask_padding(live, k, gate, beta)
+            with jax.named_scope("gdn_chunked"):
+                o, s_new, s_snap = gated_delta_chunked(
+                    s0, q, k, v, gate, beta, snap_col, block=block,
+                    beta_max=getattr(cfg, "beta_max", 1.0))
+            h, st = m.after_mixer(cfg, w, li, h, m.gdn_out(cfg, pg, o, z), live)
+            return h, (s_new, s_snap, taps, taps_snap, st)
+
+        h, (s_new, s_snap, taps, taps_snap, st) = at_wave_width(
+            layer, width, page_size, (h, *cols[1:]), (s0, taps0))
+        with jax.named_scope("state_write"):
+            s_new, s_snap = (_fill(x, s_pool.shape[-1]) for x in (s_new, s_snap))
+            s_pool = state_write(state_write(s_pool, g, state_dst, s_new), g, state_snap, s_snap)
+            c_pool = state_write(state_write(c_pool, g, state_dst, taps), g, state_snap,
+                                 taps_snap)
+        return h, (s_pool, c_pool), add(stats, st)
+
+    def attn_layer(pi, h, kv_pools, stats):
+        kp, vp = kv_pools
+        li = pi * (gpp + 1) + gpp
+
+        def project(cols, _):
+            h = cols[0]
+            q, k, v, more = m.attn_project(cfg, m.attn_weights(w, pi),
+                                           m.mixer_input(cfg, w, li, h), *cols[1:len(along) + 1])
+            return h, tuple(padded(t) for t in (q, *more, k, v))
+
+        _, (q, *more, k, v) = at_wave_width(project, width, page_size, (h, *cols[1:]), ())
+        with jax.named_scope("kv_write"):
+            flat = slots.reshape(-1)
+            kp, _ = commit_paged(kp, k.reshape(-1, nkv, hd).swapaxes(0, 1), flat, None,
+                                 page_size, layer=pi)
+            vp, _ = commit_paged(vp, v.reshape(-1, nkv, hd).swapaxes(0, 1), flat, None,
+                                 page_size, layer=pi)
+        with jax.named_scope("paged_attention"):
+            if use_pallas:
+                from githubrepostorag_tpu.ops.fused_decode import fused_paged_attention
+
+                # the kernel keeps a window's queries, accumulator and softmax
+                # state for a kv head's whole group in VMEM: 8 heads of 256 over
+                # 512 columns are 16.8 MB, past what a v5e kernel may hold, so a
+                # chunk goes through in windows of ``m.attn_window`` columns (the
+                # keys of the whole chunk are committed: a later window attends the
+                # earlier ones as cache); a window past the wave's width is skipped
+                span = m.attn_window
+
+                def window(c):
+                    qw = q[:, c:c + span]
+                    run = lambda: fused_paged_attention(  # noqa: E731
+                        qw, kp, vp, block_tables, cached_lens + jnp.minimum(new_lens, c),
+                        jnp.clip(new_lens - c, 0, span), layer=pi)
+                    if c == 0 or width is None:
+                        return run()
+                    return jax.lax.cond(width > c, run, lambda: jnp.zeros_like(qw))
+
+                attn = jnp.concatenate([window(c) for c in range(0, chunk, span)], axis=1)
+            else:
+                attn = paged_attention_ref(q, kp[pi], vp[pi], block_tables, cached_lens,
+                                           new_lens)
+
+        def rest(cols, came_in):
+            h, live, attn, *more = cols[0], *cols[len(along) + 1:]
+            return m.after_mixer(cfg, w, li, h, m.attn_out(m.attn_weights(w, pi), attn, *more),
+                                 live)
+
+        h, st = at_wave_width(rest, width, page_size, (h, *cols[1:], attn, *more), ())
+        return h, (kp, vp), add(stats, st)
+
+    def body(carry, _):
+        h, pi, kv_pools, st_pools, stats = carry
+        for j in range(gpp):
+            h, st_pools, stats = gdn_layer(pi, j, h, st_pools, stats)
+        h, kv_pools, stats = attn_layer(pi, h, kv_pools, stats)
+        return (h, pi + 1, kv_pools, st_pools, stats), None
+
+    (h, _, (k_pages, v_pages), st_pools, stats), _ = jax.lax.scan(
+        body, (h, jnp.int32(0), (k_pages, v_pages), (state["s"], state["conv"]),
+               jnp.zeros((2,), jnp.int32)), None, length=cfg.periods)
+    with jax.named_scope("sample"):
+        h = m.final(cfg, params, h)
+        if logits_at is not None:
+            h = jnp.take_along_axis(h, logits_at[:, None, None], axis=1)
+        logits = m.head(params, h)
+    return logits, k_pages, v_pages, stats, {"s": st_pools[0], "conv": st_pools[1]}
+
+
+def burst(m, params, cfg, last_tokens, seq_lens, k_pages, v_pages, presence, active, row_limits,
+          block_tables, rng, temperature, top_p, top_k, repetition_penalty, n_steps,
+          use_pallas, filter_sampling, first_tokens, fresh, fresh_lens, key_step, state):
+    """``n_steps`` decode iterations in one program, serving/decode_burst.py's
+    contract and structure: the K/V pools are loop-invariant inside the burst
+    (new keys and values go to a staged buffer the kernel reads as a tail, one
+    scatter commits them at the end); the state pool is stepped in place,
+    rows 0 .. B-1, a row that sits a step out keeping what it has.  Returns
+    (packed tokens [B, n_steps], valid, k_pages, v_pages, presence, seq_lens,
+    last_tokens, the layers' counts [2], state)."""
+    from githubrepostorag_tpu.ops.attention import dense_attention
+    from githubrepostorag_tpu.ops.paged_attention import gather_kv
+    from githubrepostorag_tpu.ops.pallas_paged import paged_attention_decode_staged
+    from githubrepostorag_tpu.serving.decode_burst import overlay_fresh
+    from githubrepostorag_tpu.serving.kv_cache import commit_paged
+
+    last_tokens, seq_lens, rng = overlay_fresh(
+        last_tokens, seq_lens, rng, first_tokens, fresh, fresh_lens, key_step)
+    b, P, gpp = last_tokens.shape[0], cfg.periods, cfg.gdn_per_period
+    nkv, hd, dv = cfg.num_kv_heads, cfg.head_dim, cfg.linear_value_head_dim
+    num_pages, page_size = k_pages.shape[2], k_pages.shape[3]
+    rows = jnp.arange(b)
+    start_lens = seq_lens
+    walk_lens = jnp.where(active & (seq_lens < row_limits), start_lens, 0)
+    interpret = not on_tpu()
+    w = m.weights(params)
+
+    def rows_of(pool, g):  # the engine's rows are the pool's first slots
+        return jax.lax.dynamic_slice(pool, (g,) + (0,) * (pool.ndim - 1),
+                                     (1, b, *pool.shape[2:]))[0]
+
+    def put_rows(pool, g, vals):
+        return jax.lax.dynamic_update_slice(pool, vals[None].astype(pool.dtype),
+                                            (g,) + (0,) * (pool.ndim - 1))
+
+    def one_step(carry, step_xs):
+        last, lens, staged, st_pools, pres, act, stats = carry
+        step, step_rng = step_xs
+        act = act & (lens < row_limits)
+        h = m.embed(params, jnp.maximum(last, 0)[:, None])
+        along = m.position_cols(cfg, lens[:, None])
+
+        def gdn_mixer(p, g, x, st_pools):
+            s_pool, c_pool = st_pools
+            mixed, z, beta, gate = m.gdn_inputs(cfg, p, x)
+            taps_old, s_old = rows_of(c_pool, g), _cut(rows_of(s_pool, g), dv)
+            with jax.named_scope("gdn_conv"):
+                y, taps = causal_conv_step(mixed[:, 0], taps_old.reshape(b, -1, mixed.shape[-1]),
+                                           p["conv_w"])
+                taps = taps.reshape(b, -1)
+            q, k, v = gdn_heads(cfg, y[:, None])
+            with jax.named_scope("gdn_recurrent"):
+                o, s_new = gated_delta_step(s_old.astype(jnp.float32), q[:, 0], k[:, 0], v[:, 0],
+                                            gate[:, 0], beta[:, 0])
+                s_new = jnp.where(act[:, None, None, None], s_new.astype(s_pool.dtype), s_old)
+                s_pool = put_rows(s_pool, g, _fill(s_new, s_pool.shape[-1]))
+            c_pool = put_rows(c_pool, g, jnp.where(act[:, None], taps, taps_old))
+            return m.gdn_out(cfg, p, o[:, None], z), (s_pool, c_pool)
+
+        def attn_mixer(p, pi, x, staged):
+            sk, sv = staged
+            q, k, v, more = m.attn_project(cfg, p, x, *along)
+            with jax.named_scope("kv_write"):
+                sk = jax.lax.dynamic_update_slice(
+                    sk, k.swapaxes(1, 2).astype(sk.dtype)[None], (pi, 0, 0, step, 0))
+                sv = jax.lax.dynamic_update_slice(
+                    sv, v.swapaxes(1, 2).astype(sv.dtype)[None], (pi, 0, 0, step, 0))
+            sk_l = jax.lax.dynamic_index_in_dim(sk, pi, 0, keepdims=False)
+            sv_l = jax.lax.dynamic_index_in_dim(sv, pi, 0, keepdims=False)
+            # under the scope: the kernel's instruction is named for it in the
+            # device trace, where the accepted metric looks for it
+            with jax.named_scope("paged_attention"):
+                if use_pallas:
+                    attn = paged_attention_decode_staged(
+                        q, k_pages, v_pages, block_tables, walk_lens, sk_l, sv_l,
+                        jnp.reshape(step + 1, (1,)), jnp.reshape(pi, (1,)), interpret=interpret)
+                else:
+                    pool_k, pool_v = gather_kv(k_pages[pi], v_pages[pi], block_tables)
+                    valid = jnp.concatenate(
+                        [jnp.arange(pool_k.shape[1])[None, :] < start_lens[:, None],
+                         jnp.broadcast_to((jnp.arange(n_steps) <= step)[None, :], (b, n_steps))],
+                        axis=1)
+                    attn = dense_attention(
+                        q, jnp.concatenate([pool_k, sk_l.swapaxes(1, 2)], axis=1),
+                        jnp.concatenate([pool_v, sv_l.swapaxes(1, 2)], axis=1),
+                        causal=False, kv_valid=valid)
+            return m.attn_out(p, attn, *more), (sk, sv)
+
+        def body(c):
+            h, pi, staged, st_pools, stats = c
+            for j in range(gpp + 1):
+                li = pi * (gpp + 1) + j
+                x = m.mixer_input(cfg, w, li, h)
+                if j < gpp:
+                    y, st_pools = gdn_mixer(m.gdn_weights(w, pi * gpp + j), pi * gpp + j, x,
+                                            st_pools)
+                else:
+                    y, staged = attn_mixer(m.attn_weights(w, pi), pi, x, staged)
+                h, st = m.after_mixer(cfg, w, li, h, y, act[:, None])
+                if st is not None:
+                    stats = stats + st
+            return h, pi + 1, staged, st_pools, stats
+
+        # the periods are unrolled, not scanned: with a layer's index static its
+        # weights are views of the stacks, and a burst of 8 layers still
+        # compiles in seconds
+        c = (h, 0, staged, st_pools, stats)
+        for _ in range(P):
+            c = body(c)
+        h, _, staged, st_pools, stats = c
+        with jax.named_scope("sample"):
+            logits = m.head(params, m.final(cfg, params, h))
+            if filter_sampling:
+                toks = sample_tokens_capped(logits[:, 0], step_rng, temperature, top_p, top_k,
+                                            repetition_penalty, pres)
+            else:
+                toks = sample_tokens_nofilter(logits[:, 0], step_rng, temperature,
+                                              repetition_penalty, pres)
+        toks = jnp.where(act, toks, last)
+        pres = pres.at[rows, toks].max(act)
+        lens = lens + act.astype(jnp.int32)
+        return (toks, lens, staged, st_pools, pres, act, stats), (toks, act)
+
+    staged0 = tuple(jnp.zeros((P, b, nkv, n_steps, hd), k_pages.dtype) for _ in range(2))
+    carry0 = (last_tokens, seq_lens, staged0, (state["s"], state["conv"]), presence, active,
+              jnp.zeros((2,), jnp.int32))
+    (last, out_lens, staged, st_pools, presence, _, stats), (toks, valid) = jax.lax.scan(
+        one_step, carry0, (jnp.arange(n_steps), jax.random.split(rng, n_steps)))
+    toks, valid = toks.T, valid.T
+    packed = jnp.where(valid, toks, -1)
+
+    pos = start_lens[:, None] + jnp.arange(n_steps)[None, :]
+    page_idx = jnp.clip(pos // page_size, 0, block_tables.shape[1] - 1)
+    slots = jnp.take_along_axis(block_tables, page_idx, axis=1) * page_size + pos % page_size
+    slots = jnp.where(valid, slots, num_pages * page_size).reshape(-1)  # sentinel: dropped
+    with jax.named_scope("kv_write"):
+        commit = lambda pool, st: commit_paged(  # noqa: E731
+            pool, st.swapaxes(1, 2).reshape(P, nkv, b * n_steps, hd), slots, None, page_size)[0]
+        k_pages, v_pages = commit(k_pages, staged[0]), commit(v_pages, staged[1])
+    return (packed, valid, k_pages, v_pages, presence, out_lens, last, stats,
+            {"s": st_pools[0], "conv": st_pools[1]})

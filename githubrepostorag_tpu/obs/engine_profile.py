@@ -204,10 +204,11 @@ class CompileLedger:
             STARTUP_SECONDS.labels(phase=name).set(seconds)
         logger.info(
             "ready %.1f s after process start; phases %s; jax traced %d function(s) %.1f s, "
-            "lowered %.1f s, back end %d program(s) %.1f s (%d read from the cache); slowest %s",
+            "lowered %.1f s, back end %d program(s) %.1f s (%d read from the cache); slowest %s; "
+            "cache pools hold %s bytes",
             now - PROCESS_START, {k: round(v, 2) for k, v in phases.items()}, traced, trace_s,
             lower_s, programs, compile_s, hits,
-            ", ".join(f"{fun} {s:.1f} s" for s, fun in slowest))
+            ", ".join(f"{fun} {s:.1f} s" for s, fun in slowest), record.notes.get("pool_bytes"))
 
 
 _ledger = CompileLedger()

@@ -115,6 +115,13 @@ class StartupRecord:
         self._lock = threading.Lock()
         self.phases: list[Phase] = []
         self.warm_t: float | None = None
+        self.notes: dict[str, Any] = {}  # sizes a phase settled on, by name
+
+    def note(self, name: str, value: Any) -> None:
+        """A fact of the start-up beside its seconds (the bytes each cache pool
+        holds, written by ``startup.engine_init``); the last one of a name stands."""
+        with self._lock:
+            self.notes[name] = value
 
     def begin(self, name: str) -> Phase:
         """Open a phase that no ``with`` block spans (``finish`` closes it).
@@ -181,7 +188,7 @@ class StartupRecord:
 
     def snapshot(self) -> dict:
         return {"process_start": PROCESS_START, "warm_t": self.warm_t,
-                "phases": [ph.as_dict() for ph in list(self.phases)]}
+                "phases": [ph.as_dict() for ph in list(self.phases)], "notes": dict(self.notes)}
 
 
 _record = StartupRecord()

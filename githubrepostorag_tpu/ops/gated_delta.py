@@ -1,10 +1,12 @@
-"""Gated DeltaNet (arXiv:2412.06464), the linear-attention layer of
-Qwen3-Next: a fixed-size matrix state a head and sequence in place of keys
-and values, and a short causal convolution in front of it.
+"""Gated DeltaNet (arXiv:2412.06464), the linear-attention layer of the
+hybrid models (models/hybrid.py): a fixed-size matrix state a head and
+sequence in place of keys and values, and a short causal convolution in
+front of it.
 
-Per head and token, with ``S`` [dk, dv] float32, ``q`` and ``k`` L2-normalised
-(``q`` scaled by dk^-1/2), ``g <= 0`` the log of the decay and ``beta`` in
-(0, 1) the write strength:
+Per head and token, with ``S`` [dk, dv] float32 (``dk`` and ``dv`` need not be
+equal), ``q`` and ``k`` L2-normalised (``q`` scaled by dk^-1/2), ``g <= 0`` the
+log of the decay and ``beta`` the write strength, in (0, 1) or, where a model
+allows ``I - beta k k^T`` a negative eigenvalue, in (0, 2):
 
     S <- exp(g_t) S;  S <- S + k_t (beta_t (v_t - S^T k_t))^T;  o_t = S^T q_t
 
@@ -17,9 +19,10 @@ Two forms of the same recurrence:
   are one triangular system ``(I + A) U = beta V``, ``A`` the strictly lower
   part of ``(beta K K^T) * decay``; its inverse is built by halving (the
   inverse of a block-triangular matrix from its blocks' inverses) down to
-  16 x 16 blocks, each the finite Neumann product ``(I - A)(I + A^2)(I +
-  A^4)(I + A^8)`` of a nilpotent matrix: small products, no substitution
-  loop of 64 steps.  Across blocks the
+  small blocks (``neumann_size``: 16 x 16 at ``beta <= 1``, 8 x 8 at ``beta
+  <= 2``), each the finite Neumann product ``(I - A)(I + A^2)(I + A^4)...``
+  of a nilpotent matrix: small products, no substitution loop of 64 steps.
+  Across blocks the
   state is carried by a scan, so a chunk of 512 columns reads and writes
   its state 8 times and not 512.  The per-token scan the step form would
   give over such a chunk moves 4 MB of state a token, row and layer.
@@ -72,22 +75,42 @@ def gated_delta_step(state, q, k, v, g, beta):
     return o, state * decay[..., None] + k[..., None] * delta[..., None, :]
 
 
-NEUMANN_MAX = 16  # the Neumann product's terms grow like binomials of its size
+# The Neumann product of an n x n block sums the powers (-a)^k, k < n, whose
+# entries reach beta^k C(n - 1, k) where neighbouring keys are alike, while the
+# inverse they cancel to stays of order one: terms up to (1 + beta)^(n - 1), of
+# which float32 keeps 2^-24.  2^15 at n = 16 and beta <= 1 (held to 2e-5 at
+# cosine 0.9 by tests/test_gated_delta.py); 3^15 at n = 16 and beta <= 2 read
+# 0.2 - 0.9 of error (128 alike tokens, beta 1.9 - 2, no decay), so beta up to 2
+# takes n = 8 (3^7).  ``NEUMANN_TERMS`` is the bound either keeps.  On a v5e n = 8
+# is no slower at either model's shapes (PERF.md, PR 39: 0 - 3% faster); 16 stays
+# at beta <= 1 for the compiled wave and the metric pattern that are pinned to it.
+NEUMANN_MAX = 16
+NEUMANN_TERMS = 2.0 ** 15
 
 
-def _unit_lower_inverse(a: jnp.ndarray) -> jnp.ndarray:
+def neumann_size(beta_max: float = 1.0) -> int:
+    """The largest power of two n, ``NEUMANN_MAX`` at the most, whose Neumann
+    product's terms stay inside ``NEUMANN_TERMS`` at write strengths up to
+    ``beta_max``: 16 at 1, 8 at 2."""
+    n = NEUMANN_MAX
+    while n > 2 and (1.0 + beta_max) ** (n - 1) > NEUMANN_TERMS:
+        n //= 2
+    return n
+
+
+def _unit_lower_inverse(a: jnp.ndarray, neumann: int = NEUMANN_MAX) -> jnp.ndarray:
     """(I + a)^-1 for strictly lower-triangular ``a`` [..., C, C].  Halved
-    down to ``NEUMANN_MAX`` x ``NEUMANN_MAX``: ``[[P, 0], [X, Q]]^-1 = [[P^-1,
+    down to ``neumann`` x ``neumann``: ``[[P, 0], [X, Q]]^-1 = [[P^-1,
     0], [-Q^-1 X P^-1, Q^-1]]``; a small block is the Neumann series of a
     nilpotent matrix, ``(I - a)(I + a^2)(I + a^4)...``.  The series alone over
     64 columns cancels badly when neighbouring keys are alike (terms up to
-    C(63, k) in size: 4e-3 of error in float32 at cosine 0.9)."""
+    C(63, k) in size: 4e-3 of error in float32 at cosine 0.9, beta <= 1)."""
     c = a.shape[-1]
     mm = lambda x, y: jnp.einsum("...ij,...jk->...ik", x, y, precision=HI)  # noqa: E731
-    if c > NEUMANN_MAX:
+    if c > neumann:
         half = c // 2
-        p = _unit_lower_inverse(a[..., :half, :half])
-        q = _unit_lower_inverse(a[..., half:, half:])
+        p = _unit_lower_inverse(a[..., :half, :half], neumann)
+        q = _unit_lower_inverse(a[..., half:, half:], neumann)
         low = -mm(mm(q, a[..., half:, :half]), p)
         top = jnp.concatenate([p, jnp.zeros_like(low).swapaxes(-1, -2)], axis=-1)
         return jnp.concatenate([top, jnp.concatenate([low, q], axis=-1)], axis=-2)
@@ -101,10 +124,12 @@ def _unit_lower_inverse(a: jnp.ndarray) -> jnp.ndarray:
     return inv
 
 
-def gated_delta_chunked(state, q, k, v, g, beta, snap_col=None, block: int = BLOCK):
+def gated_delta_chunked(state, q, k, v, g, beta, snap_col=None, block: int = BLOCK,
+                        beta_max: float = 1.0):
     """A chunk of T tokens a row, T a multiple of ``block``.  ``state``
     [R, H, dk, dv] float32; ``q``, ``k`` [R, T, H, dk]; ``v`` [R, T, H, dv];
-    ``g``, ``beta`` [R, T, H], padding masked (``mask_padding``).  Returns
+    ``g``, ``beta`` [R, T, H], padding masked (``mask_padding``); ``beta_max``
+    the most ``beta`` can be (it sets the Neumann block, ``neumann_size``).  Returns
     (o [R, T, H, dv], the state after the chunk, the state after
     ``snap_col`` [R] tokens of it: a multiple of ``block``; the state that
     came in where it is not positive or not given)."""
@@ -121,7 +146,7 @@ def gated_delta_chunked(state, q, k, v, g, beta, snap_col=None, block: int = BLO
     decay = jnp.exp(jnp.where(lower, gc[..., :, None] - gc[..., None, :], -jnp.inf))
     kb, vb = k * beta[..., None], v * beta[..., None]
     a = jnp.einsum("...ik,...jk->...ij", kb, k, precision=HI) * decay
-    inv = _unit_lower_inverse(jnp.where(jnp.tril(lower, -1), a, 0.0))
+    inv = _unit_lower_inverse(jnp.where(jnp.tril(lower, -1), a, 0.0), neumann_size(beta_max))
     u = jnp.einsum("...ij,...jv->...iv", inv, vb, precision=HI)
     w = jnp.einsum("...ij,...jk->...ik", inv, kb * jnp.exp(gc)[..., None], precision=HI)
     qk = jnp.einsum("...ik,...jk->...ij", q, k, precision=HI) * decay

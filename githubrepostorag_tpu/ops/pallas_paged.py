@@ -26,7 +26,12 @@ Two kernels:
   ``ROWS_PER_STEP`` row slots).  N is ``_wave_pages``: what fits
   ``WAVE_VMEM_BYTES`` given the kv heads, the page, the head and the pool's
   dtype — 4 pages for Qwen2-7B's bfloat16 pools, 8 for 1.5B's, 16 for one
-  kv head of a tp shard.  One layer's call at Qwen2-7B widths, 32 row slots
+  kv head of a tp shard.  Where not even ``WAVE_MIN_PAGES`` pages of every kv
+  head fit, a wave takes a SLICE of the kv heads (``_wave_heads``: the most
+  heads, a divisor of their number, of which that many pages fit) and the
+  rows are walked once a slice: 30 kv heads of 128 (multi-head attention, a
+  page of every head is 7.9 MB against the 4 MB budget) go through as 5
+  slices of 6 heads, 2 pages a wave.  One layer's call at Qwen2-7B widths, 32 row slots
   and tables of 16 pages, on a v5e (PERF.md, Findings, PR 28): 24 us at 7
   live rows of ~350 tokens, 77 us at 13 of ~1.5k, 188 us with every row at
   2,048 (87% of the HBM peak); the dense (row, page) grid this replaced took
@@ -184,13 +189,33 @@ WAVE_VMEM_BYTES = 4 * 1024 * 1024  # what one wave of the burst kernel may hold 
 ROWS_PER_STEP = 8  # row slots one grid step of the burst kernel takes
 
 
+WAVE_MIN_PAGES = 2  # pages a wave holds at the least: one folded, its successor in flight
+
+
+def _head_page_bytes(page_size: int, hd: int, itemsize: int) -> int:
+    """One page of one kv head in a wave: K and V, two DMA slots in the pool's
+    dtype plus the float32 copies the products run on."""
+    return page_size * hd * (2 * 2 * itemsize + 2 * 4)
+
+
+def _wave_heads(n_kv: int, page_size: int, hd: int, itemsize: int) -> int:
+    """kv heads one wave of the burst kernel holds: all of them where a page
+    of every head fits ``WAVE_VMEM_BYTES``; else the largest divisor of
+    ``n_kv`` of which ``WAVE_MIN_PAGES`` pages fit (one head at the least)."""
+    fit = max(1, WAVE_VMEM_BYTES // _head_page_bytes(page_size, hd, itemsize))
+    if n_kv <= fit:
+        return n_kv
+    return max(d for d in range(1, n_kv + 1)
+               if n_kv % d == 0 and (d * WAVE_MIN_PAGES <= fit or d == 1))
+
+
 def _wave_pages(n_kv: int, page_size: int, hd: int, itemsize: int, max_pages: int) -> int:
     """Pages the burst kernel reads and folds at a time: the largest power
-    of two whose K and V tiles (every kv head; two DMA slots in the pool's
-    dtype plus the float32 copies the products run on) fit
+    of two whose K and V tiles (the wave's kv heads; two DMA slots in the
+    pool's dtype plus the float32 copies the products run on) fit
     ``WAVE_VMEM_BYTES``, and no more than a row's table holds."""
-    per_page = n_kv * page_size * hd * (2 * 2 * itemsize + 2 * 4)
-    fit = max(1, WAVE_VMEM_BYTES // per_page)
+    heads = _wave_heads(n_kv, page_size, hd, itemsize)
+    fit = max(1, WAVE_VMEM_BYTES // (heads * _head_page_bytes(page_size, hd, itemsize)))
     return min(1 << (fit.bit_length() - 1), max_pages)
 
 
@@ -201,10 +226,15 @@ def _burst_kernel(
     wave: int,
     layered: bool = False,
     kv_quant: bool = False,
+    head_slices: int = 1,
 ):
     """Decode-burst attention: online softmax over [the pages the row holds
     | staged tail].  Grid (B / R,): a step takes R row slots, one after the
-    other.  A row walks its own ``ceil(pool_len / page_size)`` pages in waves
+    other (``head_slices`` > 1: the kv heads go through a slice at a time,
+    grid (slices * B / R,), and a "row" below is a row of one slice: row r of
+    slice s is walked as virtual row s * B + r, so the first wave of a
+    slice's first live row is in flight while the slice before ends).  A row
+    walks its own ``ceil(pool_len / page_size)`` pages in waves
     of ``wave`` pages, ALL kv heads at once: each page is one DMA from the
     pool in HBM into a VMEM slot, a wave is one [n_kv, group, wave *
     page_size] product, and while a wave is folded in the next one's DMAs are
@@ -233,15 +263,25 @@ def _burst_kernel(
     if layered:
         k_hbm, v_hbm = k_hbm.at[scalar_refs[3][0]], v_hbm.at[scalar_refs[3][0]]
     ks_ref, vs_ref = scalar_refs[-2:] if kv_quant else (None, None)
-    n_kv_heads = k_buf.shape[1]
+    n_kv_heads = k_buf.shape[1]  # of one wave: all of them, or a slice
     rows, max_pages = block_tables_ref.shape
     block_rows = q_ref.shape[0]
+    walked = rows * head_slices  # virtual rows: every row once a slice of heads
 
-    def pages_of(row):
-        return (pool_lens_ref[row] + page_size - 1) // page_size
+    def row_of(vr):
+        return vr if head_slices == 1 else vr % rows
 
-    def page_at(row, w, j):
-        return block_tables_ref[row, jnp.minimum(w * wave + j, max_pages - 1)]
+    def pages_of(vr):
+        return (pool_lens_ref[row_of(vr)] + page_size - 1) // page_size
+
+    def page_at(vr, w, j):
+        return block_tables_ref[row_of(vr), jnp.minimum(w * wave + j, max_pages - 1)]
+
+    def heads_of(hbm, vr):
+        """The pool's kv heads that virtual row ``vr``'s slice takes."""
+        if head_slices == 1:
+            return hbm
+        return hbm.at[pl.ds((vr // rows) * n_kv_heads, n_kv_heads)]
 
     def page_dmas(row, w, slot, go):
         """``go`` (start or wait) on the DMA of every page ``row`` holds in
@@ -254,16 +294,17 @@ def _burst_kernel(
                 page, at = page_at(row, w, j), pl.ds(j * page_size, page_size)
                 for which, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
                     go(pltpu.make_async_copy(
-                        hbm.at[:, page], buf.at[slot, :, at], sems.at[which, slot]))
+                        heads_of(hbm, row).at[:, page], buf.at[slot, :, at],
+                        sems.at[which, slot]))
 
     def start_first_wave(after, slot):
         """Start the first wave of the next live row at or after ``after``,
         if there is one, so that it lands while the rows before it work."""
         row = jax.lax.while_loop(
-            lambda r: (r < rows) & (pool_lens_ref[jnp.minimum(r, rows - 1)] == 0),
+            lambda r: (r < walked) & (pool_lens_ref[row_of(jnp.minimum(r, walked - 1))] == 0),
             lambda r: r + 1, after)
 
-        @pl.when(row < rows)
+        @pl.when(row < walked)
         def _():
             page_dmas(row, 0, slot, lambda dma: dma.start())
 
@@ -278,9 +319,10 @@ def _burst_kernel(
         # [n_kv,1,1] is an unsupported Mosaic shape cast; scalar broadcasts
         # are free): int8 pages with SMEM scales run at bf16 speed + halved
         # KV HBM (r04 isolation)
+        h0 = 0 if head_slices == 1 else (row // rows) * n_kv_heads
         return jnp.stack([
             jnp.concatenate([
-                x[h, j * page_size : (j + 1) * page_size] * scales_ref[h, page_at(row, w, j)]
+                x[h, j * page_size : (j + 1) * page_size] * scales_ref[h0 + h, page_at(row, w, j)]
                 for j in range(wave)
             ], axis=0)
             for h in range(n_kv_heads)
@@ -321,7 +363,7 @@ def _burst_kernel(
 
     def one_row(r, carry):
         bi = first_row + r
-        total = pool_lens_ref[bi]
+        total = pool_lens_ref[row_of(bi)]
         n_waves = (pages_of(bi) + wave - 1) // wave
         first_slot = slot_ref[0]  # where this row's first wave was sent
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
@@ -401,7 +443,10 @@ def paged_attention_decode_staged(
     n_kv, _, page_size, _ = k_pages.shape[-4:]
     group = n_q // n_kv
     n_steps = staged_k.shape[2]
-    wave = _wave_pages(n_kv, page_size, hd, k_pages.dtype.itemsize, block_tables.shape[1])
+    itemsize = k_pages.dtype.itemsize
+    wave = _wave_pages(n_kv, page_size, hd, itemsize, block_tables.shape[1])
+    heads = _wave_heads(n_kv, page_size, hd, itemsize)
+    head_slices = n_kv // heads
     q_r = q.reshape(b, n_kv, group, hd)
 
     scalars = [
@@ -427,36 +472,40 @@ def paged_attention_decode_staged(
     # dead rows included (26.1 -> 24.2 us a call at 7 live rows of 32; PR 28)
     block_rows = next(r for r in range(min(b, ROWS_PER_STEP), 0, -1) if b % r == 0)
 
+    steps = b // block_rows  # grid steps of one slice of the kv heads
+
     def row_map(gi, *refs):
-        return (gi, 0, 0, 0)
+        if head_slices == 1:
+            return (gi, 0, 0, 0)
+        return (gi % steps, gi // steps, 0, 0)  # slice after slice, each over every row
 
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
-    slot = pltpu.VMEM((2, n_kv, wave * page_size, hd), k_pages.dtype)
+    slot = pltpu.VMEM((2, heads, wave * page_size, hd), k_pages.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(b // block_rows,),
+        grid=(head_slices * steps,),
         in_specs=[
-            pl.BlockSpec((block_rows, n_kv, group, hd), row_map),
+            pl.BlockSpec((block_rows, heads, group, hd), row_map),
             in_hbm,
             in_hbm,
-            pl.BlockSpec((block_rows, n_kv, n_steps, hd), row_map),
-            pl.BlockSpec((block_rows, n_kv, n_steps, hd), row_map),
+            pl.BlockSpec((block_rows, heads, n_steps, hd), row_map),
+            pl.BlockSpec((block_rows, heads, n_steps, hd), row_map),
         ],
-        out_specs=pl.BlockSpec((block_rows, n_kv, group, hd), row_map),
+        out_specs=pl.BlockSpec((block_rows, heads, group, hd), row_map),
         scratch_shapes=[
             slot,
             slot,
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),
-            pltpu.VMEM((n_kv, group, 128), jnp.float32),
-            pltpu.VMEM((n_kv, group, 128), jnp.float32),
-            pltpu.VMEM((n_kv, group, hd), jnp.float32),
+            pltpu.VMEM((heads, group, 128), jnp.float32),
+            pltpu.VMEM((heads, group, 128), jnp.float32),
+            pltpu.VMEM((heads, group, hd), jnp.float32),
         ],
     )
 
     kernel = functools.partial(
         _burst_kernel, page_size=page_size, scale=1.0 / (hd ** 0.5), wave=wave,
-        layered=layered, kv_quant=kv_quant,
+        layered=layered, kv_quant=kv_quant, head_slices=head_slices,
     )
     out = pl.pallas_call(
         kernel,

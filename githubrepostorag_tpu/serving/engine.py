@@ -38,6 +38,7 @@ Design notes (TPU-first):
 
 from __future__ import annotations
 
+import importlib
 import itertools
 import time
 from dataclasses import dataclass, field, replace
@@ -70,6 +71,7 @@ from githubrepostorag_tpu.serving.kv_cache import (
     StateSlots,
     TieredPageAllocator,
     make_page_pools,
+    make_state_pools,
     packed_slot_mapping,
     page_hashes,
     pages_needed,
@@ -336,21 +338,22 @@ class Engine:
         # startup.engine_init: pools, allocator, state slots (obs/startup.py)
         init_phase = startup_record().begin("startup.engine_init")
         self.mesh = mesh
-        # which model runs is read from the configuration object: a latent
-        # (MLA) model brings its own two step programs under qwen2's
-        # contracts, one page pool and no V pool
+        # which model runs is read from the configuration object, never from a
+        # model's name: ``step_programs`` names the module whose two step
+        # programs (qwen2's contracts) serve it, ``latent_kv`` says its pages
+        # are one latent pool with no V pool, ``recurrent_state`` that it keeps
+        # a state pool beside its K/V pools (``kv_layers``, ``state_layers``,
+        # ``state_shapes()``), ``expert_counters`` that its programs return the
+        # expert layers' counts.  Without ``step_programs`` the model is served
+        # by qwen2's programs
+        programs = getattr(cfg, "step_programs", None)
+        self._own_programs = programs is not None
         self._latent = bool(getattr(cfg, "latent_kv", False))
-        # ... and a hybrid of recurrent and attention layers its two programs,
-        # K/V pools for its attention layers and a state pool beside them.
-        # Either kind brings expert counters; both are "own programs" below
         self._recurrent = bool(getattr(cfg, "recurrent_state", False))
-        self._own_programs = self._latent or self._recurrent
+        self._expert_counters = bool(getattr(cfg, "expert_counters", False))
         self._wave_fn = forward_paged_wave
         if self._own_programs:
-            if self._latent:
-                from githubrepostorag_tpu.models import deepseek_v3 as family
-            else:
-                from githubrepostorag_tpu.models import qwen3_next as family
+            family = importlib.import_module(programs)
 
             unsupported = {
                 "mesh": mesh is not None, "kv_quant": bool(quant_bits(kv_quant)),
@@ -368,12 +371,6 @@ class Engine:
                     "pool: " + ", ".join(k for k, v in unsupported.items() if v))
             self._wave_fn = family.forward_paged_wave
             self._decode_burst_fn = family.decode_burst
-            # experts hit / pairs routed to held experts / expert slots
-            # offered, per step program, cumulative; a dispatch's counts are
-            # read back once a later burst's tokens prove the device is past it
-            self.moe_stats = {"burst": [0, 0, 0], "prefill": [0, 0, 0]}
-            self._moe_pending: list[tuple[int, str, jnp.ndarray, int]] = []
-            self._dispatch_seq = 0
         else:
             from githubrepostorag_tpu.serving import decode_burst as burst_program
 
@@ -403,6 +400,13 @@ class Engine:
             # cost per quantized matmul measured at 7B shapes); sharded
             # meshes keep per-projection leaves — see fuse_projections
             params = fuse_projections(params)
+        if self._expert_counters:
+            # experts hit / pairs routed to held experts / expert slots
+            # offered, per step program, cumulative; a dispatch's counts are
+            # read back once a later burst's tokens prove the device is past it
+            self.moe_stats = {"burst": [0, 0, 0], "prefill": [0, 0, 0]}
+            self._moe_pending: list[tuple[int, str, jnp.ndarray, int]] = []
+            self._dispatch_seq = 0
         self.params = params
         self.cfg = cfg
         self.max_num_seqs = max_num_seqs
@@ -447,7 +451,7 @@ class Engine:
         if self._recurrent:
             self._state = StateSlots(
                 max_num_seqs, 2 * max_num_seqs if state_snapshots is None else state_snapshots)
-            self._state_pools = family.make_state_pools(cfg, self._state.total)
+            self._state_pools = make_state_pools(cfg, self._state.total)
             self._state_published: dict[str, int] = {}  # what the counters have been told
         self.state_restored = 0  # stats: prefills resumed from a snapshot
         self.page_hit_tokens = 0  # stats: prompt tokens whose pages the prefix cache held
@@ -754,6 +758,11 @@ class Engine:
         compile_ledger().watch(self.step_programs())
         init_phase.settles(self._presence)  # the last array made: the pools are before it
         startup_record().finish(init_phase)
+        # what each of the two caches holds, beside the phase that made them
+        held = lambda tree: sum(int(x.nbytes) for x in jax.tree.leaves(tree))  # noqa: E731
+        startup_record().note("pool_bytes", {
+            "pages": held((self._k_pages, self._v_pages, self._k_scales, self._v_scales)),
+            "state": held(self._state_pools)})
 
     def step_programs(self) -> list:
         """Every jitted callable a step of THIS engine can dispatch: the
@@ -1867,13 +1876,14 @@ class Engine:
         )
         if self.kv_quant:
             self._k_pages, self._v_pages, self._k_scales, self._v_scales = cache
-        elif self._own_programs:
+        else:
+            # a model's own programs hand back, after the pools, the expert
+            # layers' counts and the state pool, where it has them
             if self._recurrent:
                 self._state_pools = cache.pop()
-            self._k_pages, self._v_pages, moe = cache
-            self._moe_dispatched("prefill", moe, 1)
-            wave_ann.set_metadata(**self._moe_meta("prefill"))
-        else:
+            if self._expert_counters:
+                self._moe_dispatched("prefill", cache.pop(), 1)
+                wave_ann.set_metadata(**self._moe_meta("prefill"))
             self._k_pages, self._v_pages = cache
         if self._draft_enabled:
             # the draft model prefills the SAME chunk into its own pools
@@ -1926,7 +1936,7 @@ class Engine:
         req.snap_at = sorted({d for d in (req.page_match, last) if d > shared_pages})
 
     def _wave_state(self, reqs: list[_Request], valids: list[int], rb: int) -> dict:
-        """The state arguments of one wave (models/qwen3_next.py): per wave
+        """The state arguments of one wave (models/hybrid.py): per wave
         row, the slot its state comes from (the snapshot it resumes from or
         none on a request's first wave, its own row's after), its own row's
         slot for the state after the chunk, and where one is owed inside this
@@ -2337,7 +2347,7 @@ class Engine:
         self._m_burst[ahead].inc()
         self._phase("engine.decode_burst", rows=live_rows, kv_tokens=kv_tokens,
                     steps=n_steps, ahead=int(ahead),
-                    **(self._moe_meta("burst") if self._own_programs else {}))
+                    **(self._moe_meta("burst") if self._expert_counters else {}))
         out = self._decode_burst_fn(
             self.params, self.cfg,
             last_d, lens_d,
@@ -2369,13 +2379,12 @@ class Engine:
         if self.kv_quant:
             (toks, _, self._k_pages, self._v_pages, self._presence,
              out_lens, last, self._k_scales, self._v_scales) = out
-        elif self._own_programs:
+        else:
             if self._recurrent:
                 *out, self._state_pools = out
-            (toks, _, self._k_pages, self._v_pages, self._presence,
-             out_lens, last, moe) = out
-            self._moe_dispatched("burst", moe, n_steps)
-        else:
+            if self._expert_counters:
+                *out, moe = out
+                self._moe_dispatched("burst", moe, n_steps)
             (toks, _, self._k_pages, self._v_pages, self._presence,
              out_lens, last) = out
         prev = self._chain
@@ -2383,7 +2392,7 @@ class Engine:
             "last": last, "lens": out_lens, "pending": toks,
             "first": first_waves,
         }
-        if self._own_programs:
+        if self._expert_counters:
             self._chain["seq"] = self._dispatch_seq
         if prev is not None:
             self._commit_burst(prev, finished)

@@ -119,6 +119,15 @@ def make_page_pools(
     return PagePools(k=jnp.zeros(shape, dtype=dtype), v=jnp.zeros(shape, dtype=dtype))
 
 
+def make_state_pools(cfg, slots: int) -> dict:
+    """Zeroed state pools of ``slots`` slots for a model with recurrent state,
+    from what its configuration object states: ``state_layers`` layers, each
+    slot the arrays of ``state_shapes()`` ((shape, dtype) by name).  The
+    engine adds the slot that takes dropped writes itself (``StateSlots``)."""
+    return {name: jnp.zeros((cfg.state_layers, slots, *shape), dtype)
+            for name, (shape, dtype) in cfg.state_shapes().items()}
+
+
 def quantize_kv(x: jnp.ndarray):
     """Per-token-vector symmetric int8: ``x`` [..., hd] ->
     (q int8 [..., hd], scale f32 [...]).  Kept as the reference recipe for

@@ -21,14 +21,14 @@ from githubrepostorag_tpu.ops import gated_delta as gd
 TOL = 2e-5
 
 
-def inputs(rows, t, h=3, dk=16, dv=8, seed=0, alike=0.0):
+def inputs(rows, t, h=3, dk=16, dv=8, seed=0, alike=0.0, beta=(0.05, 1.0), g=(0.001, 0.6)):
     rng = np.random.default_rng(seed)
     f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa: E731
     base = rng.normal(size=(rows, 1, h, dk))
     q = gd.l2norm(f(rows, t, h, dk)) * dk ** -0.5
     k = gd.l2norm(jnp.asarray(alike * base, jnp.float32) + f(rows, t, h, dk))
-    g = -jnp.asarray(rng.uniform(0.001, 0.6, size=(rows, t, h)), jnp.float32)
-    beta = jnp.asarray(rng.uniform(0.05, 1.0, size=(rows, t, h)), jnp.float32)
+    g = -jnp.asarray(rng.uniform(*g, size=(rows, t, h)), jnp.float32)
+    beta = jnp.asarray(rng.uniform(*beta, size=(rows, t, h)), jnp.float32)
     return q, k, f(rows, t, h, dv), g, beta, f(rows, h, dk, dv)
 
 
@@ -55,6 +55,37 @@ def test_chunked_one_token_and_reference_forms_agree_from_a_nonzero_state(alike)
         o_r, s_r = ref.recurrence(q[r], k[r], v[r], g[r], beta[r], state=s0[r])
         np.testing.assert_allclose(o_c[r], o_r, atol=TOL)
         np.testing.assert_allclose(s_c[r], s_r, atol=TOL)
+
+
+@pytest.mark.parametrize("beta", [(0.05, 2.0), (1.9, 2.0)], ids=["beta-to-2", "beta-near-2"])
+@pytest.mark.parametrize("alike", [0.0, 3.0], ids=["keys-apart", "keys-alike"])
+def test_chunked_and_one_token_forms_agree_at_write_strengths_up_to_two(alike, beta):
+    """A model that allows ``I - beta k k^T`` a negative eigenvalue writes with
+    ``beta`` in (0, 2): 30 heads (no power of two), keys of 96 (three quarters
+    of a lane tile) against values of 192, two blocks from a state that is not
+    zero, with the block of the Neumann product ``beta_max`` = 2 asks for.
+    Keys alike (cosine 0.9 between neighbours) at ``beta`` near 2 with little
+    decay are the worst case of the triangular inverse: its terms reach
+    ``3^7`` there and float32 keeps 1e-4 of them (ops/gated_delta.py), so that
+    case alone is held to 2e-4 on outputs of order one; at 16 x 16 it reads
+    4e-3 and more."""
+    q, k, v, g, beta, s0 = inputs(2, 128, h=30, dk=96, dv=192, seed=7, alike=alike, beta=beta,
+                                  g=(0.0, 0.05))
+    o_c, s_c, _ = gd.gated_delta_chunked(s0, q, k, v, g, beta, beta_max=2.0)
+    o_s, s_s = by_steps(q, k, v, g, beta, s0, 128)
+    tol = 2e-4 if alike else TOL
+    np.testing.assert_allclose(o_c, o_s, atol=tol)
+    np.testing.assert_allclose(s_c, s_s, atol=10 * tol)  # states of order ten here
+    if alike and beta[0, 0, 0] > 1.9:
+        o_16, _, _ = gd.gated_delta_chunked(s0, q, k, v, g, beta)  # the block beta <= 1 takes
+        assert float(jnp.abs(o_16 - o_s).max()) > 10 * tol
+
+
+def test_the_neumann_block_follows_the_write_strength():
+    assert gd.neumann_size() == gd.neumann_size(1.0) == 16 == gd.NEUMANN_MAX
+    assert gd.neumann_size(2.0) == 8
+    for beta_max in (1.0, 2.0):
+        assert (1 + beta_max) ** (gd.neumann_size(beta_max) - 1) <= gd.NEUMANN_TERMS
 
 
 def test_a_prompt_cut_into_chunks_is_the_prompt_whole_and_a_snapshot_is_its_state_there():

@@ -239,6 +239,96 @@ def test_staged_kernel_gqa_group_seven():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5, rtol=1e-5)
 
 
+def set_slices(monkeypatch, heads, pages, ps, hd, itemsize=4):
+    """Make the burst kernel's waves ``heads`` kv heads by ``pages`` pages at
+    these sizes (ops/pallas_paged.py::_wave_heads): a budget that holds that
+    many head-pages and not a page of every head."""
+    from githubrepostorag_tpu.ops import pallas_paged
+
+    monkeypatch.setattr(pallas_paged, "WAVE_VMEM_BYTES",
+                        heads * pages * pallas_paged._head_page_bytes(ps, hd, itemsize))
+
+
+HEAD_SLICE_CASES = [
+    # multi-head attention, one query row a kv head: 30 / 30 as 5 slices of 6 heads, 2 pages
+    pytest.param(dict(n_q=30, n_kv=30, heads=6, pages=2, pool_lens=[0, 100, 0, 33, 128, 17]),
+                 id="mha-30-heads-5-slices"),
+    pytest.param(dict(n_q=30, n_kv=30, heads=6, pages=2, pool_lens=[0, 0, 0, 0]),
+                 id="mha-30-heads-no-pool"),
+    pytest.param(dict(n_q=30, n_kv=30, heads=10, pages=2, pool_lens=[64, 0, 1, 127], layers=3),
+                 id="mha-30-heads-3-slices-rank5"),
+    # Qwen2-7B's 28 / 4 whole (what it runs as) and a head at a time
+    pytest.param(dict(n_q=28, n_kv=4, heads=4, pages=2, pool_lens=[80, 42, 0, 128]),
+                 id="gqa-28-4-whole"),
+    pytest.param(dict(n_q=28, n_kv=4, heads=1, pages=2, pool_lens=[80, 42, 0, 128]),
+                 id="gqa-28-4-4-slices"),
+]
+
+
+@pytest.mark.parametrize("case", HEAD_SLICE_CASES)
+def test_staged_kernel_takes_the_kv_heads_a_slice_at_a_time(monkeypatch, case):
+    """Where not even two pages of every kv head fit the VMEM budget the kernel
+    walks the rows once a slice of the kv heads; group size 1 (kv heads =
+    query heads) is the multi-head case that needs it."""
+    from githubrepostorag_tpu.ops import pallas_paged
+
+    hd, ps, layers = 64, 16, case.get("layers", 0)
+    set_slices(monkeypatch, case["heads"], case["pages"], ps, hd)
+    assert pallas_paged._wave_heads(case["n_kv"], ps, hd, 4) == case["heads"]
+    assert pallas_paged._wave_pages(case["n_kv"], ps, hd, 4, 8) == case["pages"]
+    args = _staged_case(11, len(case["pool_lens"]), case["n_q"], case["n_kv"], hd, ps, 40, 8,
+                        case["pool_lens"], 8, 3, layers=layers)
+    q, k_pages, v_pages, *rest = args
+    one = (lambda pool: pool[1]) if layers else (lambda pool: pool)
+    ref = _staged_oracle(q, one(k_pages), one(v_pages), *rest)
+    out = pallas_paged.paged_attention_decode_staged(
+        *args, layer=jnp.asarray(1) if layers else None, interpret=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("kv_quant", [False, True], ids=["fp", "int8"])
+def test_head_slices_dmas_land_before_they_are_read(monkeypatch, kv_quant):
+    """The race detector over a walk of three slices of two heads: the first
+    wave of a slice's first live row goes out during the last row of the
+    slice before."""
+    from jax.experimental.pallas import tpu as pltpu
+    from githubrepostorag_tpu.ops.pallas_paged import paged_attention_decode_staged
+
+    if not hasattr(pltpu, "InterpretParams"):
+        pytest.skip("this jax has no TPU interpreter")
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call as tpu_interpreter
+
+    n_kv, hd, ps = 6, 64, 16
+    set_slices(monkeypatch, 2, 2, ps, hd, itemsize=1 if kv_quant else 4)
+    args = _staged_case(5, 6, 6, n_kv, hd, ps, 40, 8, [0, 100, 0, 33, 128, 0], 8, 3)
+    ref = _staged_oracle(*args)
+    q, k_pages, v_pages, *rest = args
+    scales = ()
+    if kv_quant:  # a scale a head and page, so a slice reading another's scales cannot match
+        k_pages, v_pages = (jnp.round(x * 20).astype(jnp.int8) for x in (k_pages, v_pages))
+        by_head = 0.05 * (1.0 + jnp.arange(n_kv, dtype=jnp.float32))[:, None]
+        scales = (jnp.broadcast_to(by_head, k_pages.shape[:2]),) * 2
+        ref = _staged_oracle(q, k_pages.astype(jnp.float32) * by_head[..., None, None],
+                             v_pages.astype(jnp.float32) * by_head[..., None, None], *rest)
+    out = paged_attention_decode_staged(
+        q, k_pages, v_pages, *rest, None, *scales,
+        interpret=pltpu.InterpretParams(detect_races=True, dma_execution_mode="on_wait"))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5, rtol=1e-5)
+    assert not tpu_interpreter.races.races_found
+
+
+def test_wave_tiles_of_the_served_models():
+    """(kv heads, pages) of a wave at the pools the benchmark's models serve:
+    the three the header lists, unchanged, and 30 kv heads of 128."""
+    from githubrepostorag_tpu.ops.pallas_paged import _wave_heads, _wave_pages
+
+    tile = lambda n_kv, hd, size: (_wave_heads(n_kv, 128, hd, size),  # noqa: E731
+                                   _wave_pages(n_kv, 128, hd, size, 80))
+    assert tile(4, 128, 2) == (4, 4) and tile(2, 128, 2) == (2, 8) and tile(1, 128, 2) == (1, 16)
+    assert tile(2, 256, 2) == (2, 4) and tile(4, 128, 1) == (4, 4)
+    assert tile(30, 128, 2) == (6, 2)  # 12 head-pages of 262,144 B: 3.1 MB of the 4 MB budget
+
+
 def test_burst_pallas_matches_gather_path():
     """decode_burst(use_pallas=True) must be token-identical to the gather
     oracle path on the same inputs (greedy, so no sampling noise)."""

@@ -21,6 +21,7 @@ import pytest
 
 from benchmarks import reference_qwen3_next as ref
 from githubrepostorag_tpu.models import qwen3_next as model
+from githubrepostorag_tpu.serving.kv_cache import make_state_pools
 
 MODEL = dict(hidden_size=64, num_hidden_layers=8, full_attention_interval=4,
              num_attention_heads=4, num_key_value_heads=2, head_dim=32,
@@ -45,7 +46,7 @@ def run_program(act, state_dtype="float32"):
     params = jax.tree.map(lambda x: x.astype(act), model.init_params(cfg, seed=SEED))
     kp = jnp.zeros((cfg.kv_layers, cfg.num_kv_heads, PAGES, PAGE, cfg.head_dim), act)
     vp = jnp.zeros_like(kp)
-    state = model.make_state_pools(cfg, ROWS + 3)
+    state = make_state_pools(cfg, ROWS + 3)
     trash = ROWS + 2
     bt = np.zeros((1, 16), np.int32)
     bt[0, :12] = np.arange(12)
@@ -254,10 +255,10 @@ def test_gdn_inputs_on_the_stored_leaf_is_the_published_split_on_the_drawn_leaf(
         for name, a, b in zip(("mixed", "z", "beta", "g"), got, want):
             a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
             assert a.shape == b.shape and (a == b).all(), (shape, name)  # bit for bit
-    # and the value heads lie where ``_gdn_heads``' repeat of q and k expects them:
+    # and the value heads lie where ``hybrid.gdn_heads``' repeat of q and k expects them:
     # value head h of the convolution's input belongs to key head h // r
-    q, k, v = model._gdn_heads(cfg, got[0].astype(jnp.float32))
-    q_pub, k_pub, v_pub = model._gdn_heads(cfg, want[0].astype(jnp.float32))
+    q, k, v = model.hybrid.gdn_heads(cfg, got[0].astype(jnp.float32))
+    q_pub, k_pub, v_pub = model.hybrid.gdn_heads(cfg, want[0].astype(jnp.float32))
     assert all((np.asarray(a) == np.asarray(b)).all()
                for a, b in ((q, q_pub), (k, k_pub), (v, v_pub)))
 
@@ -282,7 +283,7 @@ def run_wave(act):
         jnp.zeros((ROWS,), jnp.int32), jnp.asarray(slots),
         jnp.arange(16, dtype=jnp.int32)[None], row(0), row(valid), row(valid - 1), row(0),
         row(True, bool), jnp.int32(valid), jax.random.PRNGKey(0), jnp.uint32(1), *per_row,
-        state=model.make_state_pools(cfg, ROWS + 3), state_src=row(-1), state_dst=row(0),
+        state=make_state_pools(cfg, ROWS + 3), state_src=row(-1), state_dst=row(0),
         state_snap=row(ROWS), snap_col=row(32))
     return int(first[0]), {k: np.asarray(v, np.float32) for k, v in state.items()}, \
         np.asarray(kp, np.float32)

@@ -45,11 +45,11 @@ def cell_config():
 
 @pytest.fixture()
 def as_on_chip(monkeypatch):
-    import githubrepostorag_tpu.models.qwen3_next as model
+    import githubrepostorag_tpu.models.hybrid as hybrid
     import githubrepostorag_tpu.ops.fused_decode as fused_decode
     import githubrepostorag_tpu.ops.latent_attention as latent
 
-    for mod in (model, fused_decode, latent):
+    for mod in (hybrid, fused_decode, latent):
         monkeypatch.setattr(mod, "on_tpu", lambda: True)
 
 
@@ -62,8 +62,8 @@ def compiled(where, program: str, rows: int):
         decode_burst,
         forward_paged_wave,
         init_params,
-        make_state_pools,
     )
+    from githubrepostorag_tpu.serving.kv_cache import make_state_pools
 
     cfg = cell_config()
     shaped = lambda t: jax.tree.map(  # noqa: E731
