@@ -114,8 +114,12 @@ def lane_padded(width: int) -> int:
     v5e, a layout with the SLOTS on the lanes (192 would pad to 256, 97 slots
     to 128: the compiler takes the smaller), and every program then either
     copies the 1.7 GB pool into row-major order and back or reads all 97
-    slots to step 32; stored ``[.., 96, 256]`` it stays row-major, and the
-    programs cut the padding off what they read and put zeros back."""
+    slots to step 32; stored ``[.., 96, 256]`` it stays row-major.  The
+    padding lanes are zero in every slot, always.  The WAVES cut the padding
+    off what they read and put zeros back (``_cut`` / ``_fill``); the BURST
+    steps its rows at the stored width (``gated_delta_step`` with a state
+    wider than ``v``), where a padding lane comes out as ``0 * decay + k * 0``:
+    zero again, with nobody writing it."""
     return -(-width // 128) * 128
 
 
@@ -337,7 +341,10 @@ def burst(m, params, cfg, last_tokens, seq_lens, k_pages, v_pages, presence, act
     contract and structure: the K/V pools are loop-invariant inside the burst
     (new keys and values go to a staged buffer the kernel reads as a tail, one
     scatter commits them at the end); the state pool is stepped in place,
-    rows 0 .. B-1, a row that sits a step out keeping what it has.  Returns
+    rows 0 .. B-1, a row that sits a step out keeping what it has.  A layer's
+    rows of state cross HBM twice a step: read out of the pool once, at the
+    width the pool stores them (``lane_padded``), and written into it once, by
+    the update itself; nothing pads, cuts or copies them in between.  Returns
     (packed tokens [B, n_steps], valid, k_pages, v_pages, presence, seq_lens,
     last_tokens, the layers' counts [2], state)."""
     from githubrepostorag_tpu.ops.attention import dense_attention
@@ -375,7 +382,15 @@ def burst(m, params, cfg, last_tokens, seq_lens, k_pages, v_pages, presence, act
         def gdn_mixer(p, g, x, st_pools):
             s_pool, c_pool = st_pools
             mixed, z, beta, gate = m.gdn_inputs(cfg, p, x)
-            taps_old, s_old = rows_of(c_pool, g), _cut(rows_of(s_pool, g), dv)
+            taps_old, s_old = rows_of(c_pool, g), rows_of(s_pool, g)
+            if s_old.shape[-1] != dv:
+                # a pool stored wider than its values (lane_padded).  Behind the barrier the
+                # compiler keeps ONE copy of the rows (on a v5e: in VMEM) for the reductions
+                # and for the update; without it the update slices the pool a second time,
+                # 94 MB more a layer and step at Olmo-Hybrid's shapes.  Held by
+                # tests/test_olmo_hybrid_compile.py (the update's operands); at equal widths
+                # (Qwen3-Next) the program is what it was, tests/test_qwen3_next_compile.py
+                s_old = jax.lax.optimization_barrier(s_old)
             with jax.named_scope("gdn_conv"):
                 y, taps = causal_conv_step(mixed[:, 0], taps_old.reshape(b, -1, mixed.shape[-1]),
                                            p["conv_w"])
@@ -385,7 +400,7 @@ def burst(m, params, cfg, last_tokens, seq_lens, k_pages, v_pages, presence, act
                 o, s_new = gated_delta_step(s_old.astype(jnp.float32), q[:, 0], k[:, 0], v[:, 0],
                                             gate[:, 0], beta[:, 0])
                 s_new = jnp.where(act[:, None, None, None], s_new.astype(s_pool.dtype), s_old)
-                s_pool = put_rows(s_pool, g, _fill(s_new, s_pool.shape[-1]))
+                s_pool = put_rows(s_pool, g, s_new)
             c_pool = put_rows(c_pool, g, jnp.where(act[:, None], taps, taps_old))
             return m.gdn_out(cfg, p, o[:, None], z), (s_pool, c_pool)
 

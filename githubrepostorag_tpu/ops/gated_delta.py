@@ -66,12 +66,25 @@ def gated_delta_step(state, q, k, v, g, beta):
     (o [B, H, dv], new state).  Written so that the state is read twice and
     written once: ``S^T k`` and ``S^T q`` come from one pass over the state
     that came in (``o = exp(g) S^T q + (k . q) delta`` is ``S_new^T q``), the
-    update is the second."""
+    update is the second.
+
+    ``state`` may be WIDER than ``v`` along its last axis: a pool stores its
+    rows at a whole number of lane tiles (models/hybrid.lane_padded), lanes
+    ``dv:`` zero.  The reductions then read lanes ``:dv`` alone, ``delta`` is
+    padded with zeros, and the update is made, and returned, at the stored
+    width: a padding lane comes out as ``0 * decay + k * 0``, zero bit for
+    bit, so nobody cuts the padding off or writes it back, and the caller
+    lays what it gets straight into the pool.  At equal widths this traces
+    to what it always did."""
+    dv = v.shape[-1]
+    read = state[..., :dv]  # the whole state at equal widths: traces to nothing
     decay = jnp.exp(g)[..., None]
-    kv = decay * jnp.sum(state * k[..., None], axis=-2)
-    qv = decay * jnp.sum(state * q[..., None], axis=-2)
+    kv = decay * jnp.sum(read * k[..., None], axis=-2)
+    qv = decay * jnp.sum(read * q[..., None], axis=-2)
     delta = beta[..., None] * (v - kv)
     o = qv + jnp.sum(k * q, axis=-1, keepdims=True) * delta
+    if state.shape[-1] != dv:
+        delta = jnp.pad(delta, ((0, 0),) * (delta.ndim - 1) + ((0, state.shape[-1] - dv),))
     return o, state * decay[..., None] + k[..., None] * delta[..., None, :]
 
 

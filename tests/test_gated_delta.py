@@ -120,6 +120,70 @@ def test_padded_columns_of_a_rung_leave_the_state_bit_identical(width):
     assert bool((s[1] == s0[1]).all())
 
 
+def bits(x):
+    return np.asarray(x).view(np.uint32)
+
+
+@pytest.mark.parametrize("dk,dv,stored", [(96, 192, 256), (128, 128, 128)],
+                         ids=["96x192-in-256", "128x128-in-128"])
+def test_the_rule_at_the_stored_width_is_the_rule_and_its_padding_stays_zero(dk, dv, stored):
+    """The burst's use of the rule (models/hybrid.py:burst), 64 steps over rows
+    that sit steps out: the state handed in as the pool stores it (``stored``
+    lanes, lanes ``dv:`` zero) against the state at ``dv``.  Lanes ``:dv`` are
+    the narrow rule's after every step (the same operations; 1e-6 where the
+    backend sums a wider row in another order), lanes ``dv:`` are zero bit for
+    bit with nobody writing zeros, and a row that sits a step out keeps every
+    bit of its slot."""
+    rows, steps = 4, 64
+    q, k, v, g, beta, s0 = inputs(rows, steps, h=2, dk=dk, dv=dv, seed=dv, beta=(0.05, 2.0))
+    act = jnp.asarray(np.random.default_rng(7).uniform(size=(steps, rows)) < 0.6)
+    act = act.at[:, 3].set(False)  # one row never steps
+
+    @jax.jit
+    def step(state, t):
+        args = (q[:, t], k[:, t], v[:, t], g[:, t], beta[:, t])
+        o, new = gd.gated_delta_step(state, *args)
+        return o, jnp.where(act[t][:, None, None, None], new, state)
+
+    wide0 = jnp.pad(s0, ((0, 0),) * 3 + ((0, stored - dv),))
+    wide, narrow = wide0, s0
+    for t in range(steps):
+        before = bits(wide)
+        o_w, wide = step(wide, t)
+        o_n, narrow = step(narrow, t)
+        assert wide.shape == (rows, 2, dk, stored) and o_w.shape == (rows, 2, dv)
+        np.testing.assert_allclose(o_w, o_n, atol=1e-6, rtol=0)
+        np.testing.assert_allclose(wide[..., :dv], narrow, atol=1e-6, rtol=0)
+        assert not bits(wide[..., dv:]).any(), t  # not even a negative zero
+        idle = ~np.asarray(act[t])
+        assert (bits(wide)[idle] == before[idle]).all(), t
+    assert (bits(wide)[3] == bits(wide0)[3]).all()
+    assert float(jnp.abs(wide[:3, ..., :dv] - s0[:3]).max()) > 0.1  # the others did step
+
+
+def test_at_equal_widths_the_rule_traces_to_what_it_always_did():
+    """Qwen3-Next's pool stores 128 lanes for values of 128: its burst, and the
+    metric that finds its two passes by name, must see the same program.  The
+    rule as it stood before a state could be wider than ``v``, traced beside
+    the rule as it is: the same equations, no pad, no slice."""
+    def as_it_was(state, q, k, v, g, beta):
+        decay = jnp.exp(g)[..., None]
+        kv = decay * jnp.sum(state * k[..., None], axis=-2)
+        qv = decay * jnp.sum(state * q[..., None], axis=-2)
+        delta = beta[..., None] * (v - kv)
+        o = qv + jnp.sum(k * q, axis=-1, keepdims=True) * delta
+        return o, state * decay[..., None] + k[..., None] * delta[..., None, :]
+
+    q, k, v, g, beta, s0 = inputs(2, 1, h=3, dk=16, dv=8)
+    args = (s0, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+    now = str(jax.make_jaxpr(gd.gated_delta_step)(*args))
+    assert now == str(jax.make_jaxpr(as_it_was)(*args))
+    assert " pad[" not in now and " slice[" not in now
+    wide = str(jax.make_jaxpr(gd.gated_delta_step)(jnp.pad(s0, ((0, 0),) * 3 + ((0, 120),)),
+                                                   *args[1:]))
+    assert wide.count(" pad[") == 1 and wide.count(" slice[") == 1  # delta; one cut both sums read
+
+
 def test_a_small_page_sets_the_block_and_the_snapshot_falls_between_blocks():
     """Pages of 16 (the rehearsal's): blocks of 16, a snapshot at column 48."""
     q, k, v, g, beta, s0 = inputs(1, 64, seed=5)

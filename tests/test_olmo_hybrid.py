@@ -194,6 +194,14 @@ def run(eng, prompt):
     return res.cached_tokens, list(res.output_tokens)
 
 
+def padding_lanes(eng):
+    """(bits set in the state pool's lanes past the value width: the padding
+    that ``hybrid.lane_padded`` stores; whether a lane of values is non-zero)."""
+    dv, s = eng.cfg.linear_value_head_dim, np.asarray(eng.state_pools["s"])
+    assert s.shape[-1] > dv and s.dtype == np.float32
+    return int(np.count_nonzero(s[..., dv:].view(np.uint32))), bool(s[..., :dv].any())
+
+
 def test_engine_prefill_decode_and_a_restored_snapshot_are_the_references(in_float32):
     """The engine's own path in float32: a cold prompt through waves and bursts,
     a second prompt of the same head that leaves the branch-point snapshot, and
@@ -212,6 +220,9 @@ def test_engine_prefill_decode_and_a_restored_snapshot_are_the_references(in_flo
     cached, again_b = run(eng, B)  # from the branch-point snapshot
     assert cached == 96 and again_b == out_b and max(decode_gaps(B, again_b)) < 1e-3
     assert (eng.page_hit_tokens, eng.state_hit_tokens) == (96 + 144 + 112, 144 + 96)
+    # the bursts update the pool at its stored width and nobody writes the padding: after waves,
+    # bursts, snapshots and restores every padding lane of every slot is still +0.0
+    assert padding_lanes(eng) == (0, True)
     assert max(decode_gaps(B, again_b, control="fp8")) > 1e-3  # held to the control, it fails
 
 
@@ -224,7 +235,7 @@ def test_engine_in_bfloat16_stays_inside_the_decode_tolerance():
     _, cold = run(eng, A)
     run(eng, B)
     cached, again_b = run(eng, B)
-    assert cached == 96
+    assert cached == 96 and padding_lanes(eng) == (0, True)
     assert np.mean(decode_gaps(A, cold)) < 0.05 and np.mean(decode_gaps(B, again_b)) < 0.05
 
 

@@ -140,13 +140,41 @@ def test_this_cells_metrics_select_the_ops_under_their_scopes(chip, as_on_chip):
     wave, _ = compiled(chip, "wave", 1)
     spec = lambda name: manifest.metric_spec(name)["args"]  # noqa: E731
 
-    # all four passes a layer makes over its rows of state: read out of the pool (a fused slice that
-    # keeps no scope), S^T k | S^T q, the update, and the pad-and-write back into the 256-lane pool
+    # the passes a layer makes over its rows of state: read out of the pool ONCE, at the 256 lanes
+    # it stores (a fused slice that keeps no scope, held in VMEM), S^T k | S^T q over lanes :192 of
+    # that copy, and ONE update written into the pool in place from the same copy (PR 40; until
+    # then the rule gave rows of 192 lanes and a pad-and-write laid them into the pool: two writes)
     decode = _picked(burst, re.compile(spec("olmo_gdn_decode_roofline_frac")["op"]))
     assert set(decode) == {"gdn_recurrent", ""}
-    assert len(decode["gdn_recurrent"]) == 18 and len(decode[""]) == 6
+    assert len(decode["gdn_recurrent"]) == 12 and len(decode[""]) == 6
     assert all(n.startswith("slice_bitcast_fusion") for n in decode[""])
-    assert sum(n.startswith("bitcast_dynamic-update-slice_fusion") for n in decode["gdn_recurrent"]) == 6
+    for prefix in ("bitcast_dynamic-update-slice_fusion", "multiply_reduce_fusion"):
+        assert sum(n.startswith(prefix) for n in decode["gdn_recurrent"]) == 6, prefix
+    timed = list(timed_lines(burst))
+    assert not [ln for ln in timed if "/gdn_recurrent/" in ln and "add_select_fusion" in ln]
+    assert not [ln for ln in timed if " = f32[32,30,96,192]" in ln]  # no row at 192 lanes
+    # what the rule reads or writes at state size is in the metric's seconds: the other timed ops
+    # of the scope are per-head vectors ([32, 30]), a thousandth of a pass
+    rest = {n for n, scope in timed_ops(burst) if scope == "gdn_recurrent"} - decode["gdn_recurrent"]
+    assert all(n.endswith("_f32_32_30_") for n in rest), rest
+    # a layer: one op whose result is the pool; its operands are the pool it updates in place and
+    # the rows' slice, which lives in VMEM (S(1)) and is the only read of the state it makes: the
+    # ``optimization_barrier`` in models/hybrid.py:burst buys that (without it the update slices
+    # the pool again: 283 MB a layer and step for 189)
+    results = dict(ln.split(" fusion(")[0].split(" = ", 1) for ln in timed)  # name -> type
+    slices = {name for name, typ in results.items() if typ.startswith("f32[32,30,96,256]")}
+    assert len(slices) == 6
+    assert all("slice_bitcast_fusion" in name and "S(1)" in results[name] for name in slices)
+    writes = [ln for ln in timed if " = f32[6,97,30,96,256]" in ln]
+    assert len(writes) == 6 and all("/gdn_recurrent/" in ln for ln in writes)
+    for ln in writes:
+        operands = re.findall(r"%[\w.\-]+", re.search(r" fusion\(([^)]*)\)", ln).group(1))
+        assert sum(op in slices for op in operands) == 1, ln[:300]
+        body = re.search(r"calls=(%[\w.\-]+)", ln).group(1)
+        fused = burst.split(f"\n{body} (", 1)[1].split("\n}", 1)[0].split("{\n", 1)[1]
+        # inside the fusion the pool (parameter 0) is the update-slice's target and nothing else
+        assert len(re.findall(r"%param_0\.\d+\b", fused)) == 2, fused[:2000]
+        assert " dynamic-slice(" not in fused and " slice(" not in fused
     # XLA names a fusion after its ops and its result, so a wave's slot writes into the same pool
     # read like the burst's rows: the metric's seconds hold them too (one slot a row and a snapshot,
     # against 32 rows a layer and step: they can only lower the reading), and nothing else of a wave

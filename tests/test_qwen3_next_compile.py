@@ -13,9 +13,12 @@ are the ops under the scopes they are meant to read.  Nothing executes; a
 pass here is not a chip run.
 """
 
+import collections
 import functools
+import json
 import math
 import os
+import pathlib
 import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -280,3 +283,21 @@ def test_the_metrics_select_the_ops_under_their_scopes(chip, as_on_chip):
     paged = re.compile(manifest.metric_spec("paged_attn_hbm_frac")["args"]["op"])
     names = {n for n, _ in timed_ops(burst) if paged.search(n)}
     assert names and all(n.startswith("paged_attention") for n in names)
+
+
+def test_the_burst_keeps_the_timed_ops_it_has(chip, as_on_chip):
+    """The burst's timed ops by the name a trace shows (less XLA's running
+    number), counted: the parent commit's of PR 40, whose burst this model shares
+    with Olmo-Hybrid (models/hybrid.py:burst, ops/gated_delta.gated_delta_step).
+    The accepted ``gdn_decode_roofline_frac`` and ``moe_experts_hbm_frac`` find
+    their ops by these names, so an edit made for the other hybrid that renames
+    one here reads null on the chip.  A change that MEANS to move this program
+    writes the file again: ``{name: count}`` of ``timed_ops(burst)``, sorted."""
+    golden = pathlib.Path(__file__).parent / "golden" / "qwen3_next_burst_timed_ops.json"
+    want = collections.Counter(json.loads(golden.read_text()))
+    burst, _ = compiled(chip, "burst", 0)
+    got = collections.Counter(re.sub(r"\.\d+", "", name) for name, _ in timed_ops(burst))
+    assert got == want, {"gone": dict(want - got), "new": dict(got - want)}
+    # the recurrence: two passes a layer, the second the update written into the pool in place
+    assert want["fusion_f32_32_32_128_"] == 6
+    assert want["select_dynamic-update-slice_fusion_f32_6_97_32_128_128_"] == 6
