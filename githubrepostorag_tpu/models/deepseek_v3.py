@@ -121,6 +121,11 @@ class DeepseekV3Config:
         return self.experts_held[1] - self.experts_held[0]
 
     @property
+    def expert_layers(self) -> int:
+        """Layers with an expert layer (what the engine's expert counters span)."""
+        return self.num_layers - self.first_k_dense
+
+    @property
     def softmax_scale(self) -> float:
         m = yarn_mscale(self.rope_factor, self.rope_mscale_all_dim)
         return (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5 * m * m

@@ -1,19 +1,26 @@
-"""The step skeleton of a hybrid of Gated DeltaNet and paged full-attention
-layers: what models/qwen3_next.py and models/olmo_hybrid.py share.
+"""The step skeleton of a hybrid of recurrent-state layers and paged
+full-attention layers: what models/qwen3_next.py, models/olmo_hybrid.py
+(Gated DeltaNet) and models/nemotron_h.py (Mamba-2) share.
 
-One PERIOD is ``cfg.gdn_per_period`` Gated DeltaNet layers, then one
-full-attention layer.  The prefill wave is a scan over periods, the burst
-unrolls them.  Two kinds of per-sequence memory ride them as carries, never
-sliced:
+A model is a LAYER PATTERN, ``cfg.layer_segments``: a tuple of ``(kinds,
+repeats)``, ``kinds`` a string of one letter a layer: ``STATE`` ("R": a layer
+that keeps a slot of recurrent state a sequence), ``ATTN`` ("A": a layer that
+pages keys and values), ``PLAIN`` ("F": a layer that keeps nothing, a
+feed-forward or expert block of its own).  Three Gated DeltaNet layers then
+one attention layer, twice, is ``(("RRRA", 2),)``; ``segments()`` below cuts
+any string into such runs.  The prefill wave scans a segment's repeats (one
+traced copy of ``kinds`` however often it repeats: the compile is the
+pattern's, not the depth's), the burst unrolls them.  Two kinds of
+per-sequence memory ride them as carries, never sliced:
 
-* K/V page pools ``[periods, n_kv, P, page_size, head_dim]`` for the
-  full-attention layers alone (``cfg.kv_layers``), committed by
-  ``kv_cache.commit_paged`` at a traced layer index, read by the paged
-  kernels qwen2 uses;
-* a STATE pool for the Gated DeltaNet layers (``cfg.state_shapes()``,
-  ``kv_cache.make_state_pools``): ``s`` ``[gdn layers, slots, Hv, dk, dv]``
-  float32 (the recurrence's matrix) and ``conv`` ``[gdn layers, slots, (taps -
-  1) * channels]`` bfloat16 (the convolution's history).  A slot is one
+* K/V page pools ``[attention layers, n_kv, P, page_size, head_dim]``
+  (``cfg.kv_layers``), committed by ``kv_cache.commit_paged`` at a traced
+  layer index, read by the paged kernels qwen2 uses;
+* a STATE pool for the recurrent layers (``cfg.state_shapes()``,
+  ``kv_cache.make_state_pools``): ``s`` ``[state layers, slots, ...]`` float32
+  (the recurrence's matrix: ``[Hv, dk, dv]`` a Gated DeltaNet layer, ``[H, P,
+  N]`` a Mamba-2 one) and ``conv`` ``[state layers, slots, (taps - 1) *
+  channels]`` bfloat16 (the convolution's history).  A slot is one
   sequence's state in every layer.  The engine's rows own the first
   ``max_num_seqs`` slots (row r = slot r), snapshot slots follow, and the
   last slot takes the writes of rows that have nothing to write.
@@ -24,26 +31,33 @@ pools: a wave is told, a row, which slot its state comes from (``-1``: a
 fresh sequence, zeros; its own slot: the next chunk of a prompt; a snapshot
 slot: a prefix hit resumes there), which slot takes the state after the
 chunk, and which slot takes a SNAPSHOT of the state after ``snap_col`` of
-the chunk's tokens (a page boundary; ops/gated_delta.py catches it between
-two blocks of the chunked form).  All of it is device copies inside the
-program.  The burst steps rows 0 .. B-1 in place; a row that sits a step
-out keeps its state and history bit for bit.
+the chunk's tokens (a page boundary; the chunked rules catch it between two
+of their blocks).  All of it is device copies inside the program.  The burst
+steps rows 0 .. B-1 in place; a row that sits a step out keeps its state and
+history bit for bit.
 
 What a model brings (``m``, an object of functions; the skeleton never asks
 which model it runs):
 
 * ``weights(params) -> w``: whatever its own functions index, opaque here;
-  ``gdn_weights(w, g)`` / ``attn_weights(w, pi)``: one layer's mixer weights;
+  ``state_weights(w, n)`` / ``attn_weights(w, n)``: the mixer weights of the
+  n-th layer of that kind;
 * ``embed(params, ids)`` (the residual stream, float32), ``position_cols(cfg,
   positions)`` (what runs along the chunk beside it: rotary tables, or
   nothing), ``final(cfg, params, h)`` and ``head(params, h)``;
 * the block's wiring: ``mixer_input(cfg, w, li, h)`` (a pre-norm, or a cast)
   and ``after_mixer(cfg, w, li, h, y, live) -> (h, counts or None)`` (the
-  residual add, the feed-forward or expert layer and its add);
-* ``gdn_inputs(cfg, p, x)`` / ``gdn_out(cfg, p, o, z)`` around the shared
-  convolution and recurrence, ``attn_project(cfg, p, x, *position_cols) ->
-  (q, k, v, more)`` / ``attn_out(p, attn, *more)`` around the shared pages
-  and kernels;
+  residual add and, where a block has one, the feed-forward or expert layer
+  and its add); for a ``PLAIN`` layer, ``plain_layer(cfg, w, n, li, h, live) ->
+  (h, counts or None)``, the whole block;
+* the recurrent mixer, both forms: ``state_chunk(cfg, p, x, s0, taps0, live,
+  new_lens, snap_col, page_size) -> (y, s, s_snap, taps, taps_snap)`` and
+  ``state_step(cfg, p, x, s_old, taps_old) -> (y, s, taps)``, ``s`` at the
+  width the pool stores (``gdn_chunk`` / ``gdn_step`` below are the Gated
+  DeltaNet's, over the model's ``gdn_inputs`` / ``gdn_out``); ``cfg.state_cols``
+  is the width the chunked rule works at (``lane_padded``);
+* ``attn_project(cfg, p, x, *position_cols) -> (q, k, v, more)`` /
+  ``attn_out(p, attn, *more)`` around the shared pages and kernels;
 * ``attn_window``: columns of a prefill chunk one call of the attention
   kernel takes.
 """
@@ -69,6 +83,67 @@ from githubrepostorag_tpu.ops.norms import rms_norm_gated
 from githubrepostorag_tpu.ops.prefill_width import at_wave_width
 from githubrepostorag_tpu.ops.sampling import sample_tokens_capped, sample_tokens_nofilter
 from githubrepostorag_tpu.runtime import _pinned_to_cpu, on_tpu
+
+
+STATE, ATTN, PLAIN = "R", "A", "F"  # the letters of ``cfg.layer_segments``
+
+
+def segments(kinds: str) -> tuple:
+    """``kinds`` (one letter a layer) as runs of ``(body, repeats)``: at each
+    position the repeating body that covers the most layers, else the layers up
+    to the next one that repeats, once.  ``MEMEM*EMEMEM*EMEME`` is ``MEMEM*E``
+    twice, then ``ME`` twice."""
+    out, i, n = [], 0, len(kinds)
+
+    def best(i):
+        found = (0, 0, 0)
+        for p in range(1, (n - i) // 2 + 1):
+            r = 1
+            while kinds[i + r * p:i + (r + 1) * p] == kinds[i:i + p]:
+                r += 1
+            if r > 1 and p * r > found[0]:
+                found = (p * r, p, r)
+        return found
+
+    while i < n:
+        covered, p, r = best(i)
+        if covered:
+            out.append((kinds[i:i + p], r))
+            i += covered
+            continue
+        j = i + 1
+        while j < n and not best(j)[0]:
+            j += 1
+        out.append((kinds[i:j], 1))
+        i = j
+    return tuple(out)
+
+
+def _walk(layer_segments):
+    """(kinds, repeats, layers of each kind and of any before the segment)."""
+    before = {STATE: 0, ATTN: 0, PLAIN: 0, "all": 0}
+    for kinds, reps in layer_segments:
+        yield kinds, reps, dict(before)
+        for k in (STATE, ATTN, PLAIN):
+            before[k] += kinds.count(k) * reps
+        before["all"] += len(kinds) * reps
+
+
+def _index(base: int, rep, per: int, off: int):
+    """Layer ``off`` of its kind in repeat ``rep`` (traced in the wave) of a
+    segment that holds ``per`` of them a repeat and follows ``base``."""
+    i = rep if per == 1 else rep * per
+    return i + (base + off) if base + off else i
+
+
+def _layers(kinds: str, before: dict, rep):
+    """(kind, index among the layers of its kind, index among all layers) of
+    each layer of repeat ``rep`` of a segment ``_walk`` gave."""
+    seen = dict.fromkeys(before, 0)
+    for j, kind in enumerate(kinds):
+        yield (kind, _index(before[kind], rep, kinds.count(kind), seen[kind]),
+               _index(before["all"], rep, len(kinds), j))
+        seen[kind] += 1
 
 
 def tpu_compiler_options(options: dict) -> dict | None:
@@ -192,6 +267,41 @@ def gdn_out(cfg, p, o, z, act):
     return einsum_f32("bse,ed->bsd", y.reshape(*y.shape[:2], -1).astype(act), p["w_out"])
 
 
+def gdn_chunk(m, cfg, p, x, s0, taps0, live, new_lens, snap_col, page_size):
+    """A Gated DeltaNet mixer over a chunk, ``m.state_chunk`` of the models
+    that have one: ``m.gdn_inputs``, the convolution with its history, the
+    chunked rule (a snapshot's column lies between two of its blocks),
+    ``m.gdn_out``."""
+    mixed, z, beta, gate = m.gdn_inputs(cfg, p, x)
+    with jax.named_scope("gdn_conv"):
+        y, taps, taps_snap = causal_conv(
+            mixed, taps0.reshape(x.shape[0], -1, mixed.shape[-1]), p["conv_w"], new_lens, snap_col)
+        taps, taps_snap = (t.reshape(t.shape[0], -1) for t in (taps, taps_snap))
+    q, k, v = gdn_heads(cfg, y)
+    k, gate, beta = mask_padding(live, k, gate, beta)
+    with jax.named_scope("gdn_chunked"):
+        o, s_new, s_snap = gated_delta_chunked(
+            s0, q, k, v, gate, beta, snap_col, block=math.gcd(BLOCK, page_size),
+            beta_max=getattr(cfg, "beta_max", 1.0))
+    return m.gdn_out(cfg, p, o, z), s_new, s_snap, taps, taps_snap
+
+
+def gdn_step(m, cfg, p, x, s_old, taps_old):
+    """A Gated DeltaNet mixer over one token a row, ``m.state_step``: the
+    state at the width the pool stores it (``gated_delta_step``)."""
+    b = x.shape[0]
+    mixed, z, beta, gate = m.gdn_inputs(cfg, p, x)
+    with jax.named_scope("gdn_conv"):
+        y, taps = causal_conv_step(mixed[:, 0], taps_old.reshape(b, -1, mixed.shape[-1]),
+                                   p["conv_w"])
+        taps = taps.reshape(b, -1)
+    q, k, v = gdn_heads(cfg, y[:, None])
+    with jax.named_scope("gdn_recurrent"):
+        o, s_new = gated_delta_step(s_old.astype(jnp.float32), q[:, 0], k[:, 0], v[:, 0],
+                                    gate[:, 0], beta[:, 0])
+    return m.gdn_out(cfg, p, o[:, None], z), s_new, taps
+
+
 # ----------------------------------------------------------- step programs --
 
 def wave(m, params, cfg, input_ids, positions, k_pages, v_pages, slot_mapping, block_tables,
@@ -206,13 +316,12 @@ def wave(m, params, cfg, input_ids, positions, k_pages, v_pages, slot_mapping, b
 
     num_pages, page_size = k_pages.shape[2], k_pages.shape[3]
     nkv, hd = cfg.num_kv_heads, cfg.head_dim
-    block = math.gcd(BLOCK, page_size)  # a snapshot's column lies between two blocks
     h = m.embed(params, input_ids)
     along = m.position_cols(cfg, positions)
     slots = jnp.where(slot_mapping < 0, num_pages * page_size, slot_mapping)  # dropped
     live = jnp.arange(input_ids.shape[1])[None, :] < new_lens[:, None]
     w = m.weights(params)
-    gpp, dv = cfg.gdn_per_period, cfg.linear_value_head_dim
+    cut = cfg.state_cols
 
     chunk = input_ids.shape[1]
     cols = (h, *along, live)
@@ -230,30 +339,19 @@ def wave(m, params, cfg, input_ids, positions, k_pages, v_pages, slot_mapping, b
     # layer's rows of state are read before its switch and written after it;
     # the attention layer's switch ends at q, k, v, its pages are committed
     # and attended outside, and a second switch takes the rest of the layer.
-    def gdn_layer(pi, j, h, st_pools, stats):
+    def state_layer(g, li, h, st_pools, stats):
         s_pool, c_pool = st_pools
-        g, li = pi * gpp + j, pi * (gpp + 1) + j
         with jax.named_scope("state_read"):
-            s0 = _cut(state_read(s_pool, g, state_src), dv).astype(jnp.float32)
+            s0 = _cut(state_read(s_pool, g, state_src), cut).astype(jnp.float32)
             taps0 = state_read(c_pool, g, state_src)
 
         def layer(cols, came_in):
             h, live = cols[0], cols[len(along) + 1]
             s0, taps0 = came_in
-            x, pg = m.mixer_input(cfg, w, li, h), m.gdn_weights(w, g)
-            mixed, z, beta, gate = m.gdn_inputs(cfg, pg, x)
-            with jax.named_scope("gdn_conv"):
-                y, taps, taps_snap = causal_conv(
-                    mixed, taps0.reshape(h.shape[0], -1, mixed.shape[-1]), pg["conv_w"],
-                    new_lens, snap_col)
-                taps, taps_snap = (t.reshape(t.shape[0], -1) for t in (taps, taps_snap))
-            q, k, v = gdn_heads(cfg, y)
-            k, gate, beta = mask_padding(live, k, gate, beta)
-            with jax.named_scope("gdn_chunked"):
-                o, s_new, s_snap = gated_delta_chunked(
-                    s0, q, k, v, gate, beta, snap_col, block=block,
-                    beta_max=getattr(cfg, "beta_max", 1.0))
-            h, st = m.after_mixer(cfg, w, li, h, m.gdn_out(cfg, pg, o, z), live)
+            x, pg = m.mixer_input(cfg, w, li, h), m.state_weights(w, g)
+            y, s_new, s_snap, taps, taps_snap = m.state_chunk(
+                cfg, pg, x, s0, taps0, live, new_lens, snap_col, page_size)
+            h, st = m.after_mixer(cfg, w, li, h, y, live)
             return h, (s_new, s_snap, taps, taps_snap, st)
 
         h, (s_new, s_snap, taps, taps_snap, st) = at_wave_width(
@@ -265,9 +363,8 @@ def wave(m, params, cfg, input_ids, positions, k_pages, v_pages, slot_mapping, b
                                  taps_snap)
         return h, (s_pool, c_pool), add(stats, st)
 
-    def attn_layer(pi, h, kv_pools, stats):
+    def attn_layer(pi, li, h, kv_pools, stats):
         kp, vp = kv_pools
-        li = pi * (gpp + 1) + gpp
 
         def project(cols, _):
             h = cols[0]
@@ -316,16 +413,30 @@ def wave(m, params, cfg, input_ids, positions, k_pages, v_pages, slot_mapping, b
         h, st = at_wave_width(rest, width, page_size, (h, *cols[1:], attn, *more), ())
         return h, (kp, vp), add(stats, st)
 
-    def body(carry, _):
-        h, pi, kv_pools, st_pools, stats = carry
-        for j in range(gpp):
-            h, st_pools, stats = gdn_layer(pi, j, h, st_pools, stats)
-        h, kv_pools, stats = attn_layer(pi, h, kv_pools, stats)
-        return (h, pi + 1, kv_pools, st_pools, stats), None
+    def plain_layer(n, li, h, stats):
+        def layer(cols, _):
+            return m.plain_layer(cfg, w, n, li, cols[0], cols[len(along) + 1])
 
-    (h, _, (k_pages, v_pages), st_pools, stats), _ = jax.lax.scan(
-        body, (h, jnp.int32(0), (k_pages, v_pages), (state["s"], state["conv"]),
-               jnp.zeros((2,), jnp.int32)), None, length=cfg.periods)
+        h, st = at_wave_width(layer, width, page_size, (h, *cols[1:]), ())
+        return h, add(stats, st)
+
+    kv_pools, st_pools = (k_pages, v_pages), (state["s"], state["conv"])
+    stats = jnp.zeros((2,), jnp.int32)
+    for kinds, reps, before in _walk(cfg.layer_segments):
+        def body(carry, _, kinds=kinds, before=before):
+            h, rep, kv_pools, st_pools, stats = carry
+            for kind, n, li in _layers(kinds, before, rep):
+                if kind == STATE:
+                    h, st_pools, stats = state_layer(n, li, h, st_pools, stats)
+                elif kind == ATTN:
+                    h, kv_pools, stats = attn_layer(n, li, h, kv_pools, stats)
+                else:
+                    h, stats = plain_layer(n, li, h, stats)
+            return (h, rep + 1, kv_pools, st_pools, stats), None
+
+        (h, _, kv_pools, st_pools, stats), _ = jax.lax.scan(
+            body, (h, jnp.int32(0), kv_pools, st_pools, stats), None, length=reps)
+    k_pages, v_pages = kv_pools
     with jax.named_scope("sample"):
         h = m.final(cfg, params, h)
         if logits_at is not None:
@@ -355,8 +466,8 @@ def burst(m, params, cfg, last_tokens, seq_lens, k_pages, v_pages, presence, act
 
     last_tokens, seq_lens, rng = overlay_fresh(
         last_tokens, seq_lens, rng, first_tokens, fresh, fresh_lens, key_step)
-    b, P, gpp = last_tokens.shape[0], cfg.periods, cfg.gdn_per_period
-    nkv, hd, dv = cfg.num_kv_heads, cfg.head_dim, cfg.linear_value_head_dim
+    b, P = last_tokens.shape[0], cfg.kv_layers
+    nkv, hd = cfg.num_kv_heads, cfg.head_dim
     num_pages, page_size = k_pages.shape[2], k_pages.shape[3]
     rows = jnp.arange(b)
     start_lens = seq_lens
@@ -379,11 +490,10 @@ def burst(m, params, cfg, last_tokens, seq_lens, k_pages, v_pages, presence, act
         h = m.embed(params, jnp.maximum(last, 0)[:, None])
         along = m.position_cols(cfg, lens[:, None])
 
-        def gdn_mixer(p, g, x, st_pools):
+        def state_mixer(p, g, x, st_pools):
             s_pool, c_pool = st_pools
-            mixed, z, beta, gate = m.gdn_inputs(cfg, p, x)
             taps_old, s_old = rows_of(c_pool, g), rows_of(s_pool, g)
-            if s_old.shape[-1] != dv:
+            if s_old.shape[-1] != cfg.state_cols:
                 # a pool stored wider than its values (lane_padded).  Behind the barrier the
                 # compiler keeps ONE copy of the rows (on a v5e: in VMEM) for the reductions
                 # and for the update; without it the update slices the pool a second time,
@@ -391,18 +501,12 @@ def burst(m, params, cfg, last_tokens, seq_lens, k_pages, v_pages, presence, act
                 # tests/test_olmo_hybrid_compile.py (the update's operands); at equal widths
                 # (Qwen3-Next) the program is what it was, tests/test_qwen3_next_compile.py
                 s_old = jax.lax.optimization_barrier(s_old)
-            with jax.named_scope("gdn_conv"):
-                y, taps = causal_conv_step(mixed[:, 0], taps_old.reshape(b, -1, mixed.shape[-1]),
-                                           p["conv_w"])
-                taps = taps.reshape(b, -1)
-            q, k, v = gdn_heads(cfg, y[:, None])
-            with jax.named_scope("gdn_recurrent"):
-                o, s_new = gated_delta_step(s_old.astype(jnp.float32), q[:, 0], k[:, 0], v[:, 0],
-                                            gate[:, 0], beta[:, 0])
+            y, s_new, taps = m.state_step(cfg, p, x, s_old, taps_old)
+            with jax.named_scope(m.step_scope):  # the update, into the pool, under the rule's name
                 s_new = jnp.where(act[:, None, None, None], s_new.astype(s_pool.dtype), s_old)
                 s_pool = put_rows(s_pool, g, s_new)
             c_pool = put_rows(c_pool, g, jnp.where(act[:, None], taps, taps_old))
-            return m.gdn_out(cfg, p, o[:, None], z), (s_pool, c_pool)
+            return y, (s_pool, c_pool)
 
         def attn_mixer(p, pi, x, staged):
             sk, sv = staged
@@ -433,28 +537,24 @@ def burst(m, params, cfg, last_tokens, seq_lens, k_pages, v_pages, presence, act
                         causal=False, kv_valid=valid)
             return m.attn_out(p, attn, *more), (sk, sv)
 
-        def body(c):
-            h, pi, staged, st_pools, stats = c
-            for j in range(gpp + 1):
-                li = pi * (gpp + 1) + j
-                x = m.mixer_input(cfg, w, li, h)
-                if j < gpp:
-                    y, st_pools = gdn_mixer(m.gdn_weights(w, pi * gpp + j), pi * gpp + j, x,
-                                            st_pools)
-                else:
-                    y, staged = attn_mixer(m.attn_weights(w, pi), pi, x, staged)
-                h, st = m.after_mixer(cfg, w, li, h, y, act[:, None])
-                if st is not None:
-                    stats = stats + st
-            return h, pi + 1, staged, st_pools, stats
-
-        # the periods are unrolled, not scanned: with a layer's index static its
+        # the layers are unrolled, not scanned: with a layer's index static its
         # weights are views of the stacks, and a burst of 8 layers still
         # compiles in seconds
-        c = (h, 0, staged, st_pools, stats)
-        for _ in range(P):
-            c = body(c)
-        h, _, staged, st_pools, stats = c
+        live = act[:, None]
+        for kinds, reps, before in _walk(cfg.layer_segments):
+            for rep in range(reps):
+                for kind, n, li in _layers(kinds, before, rep):
+                    if kind == PLAIN:
+                        h, st = m.plain_layer(cfg, w, n, li, h, live)
+                    else:
+                        x = m.mixer_input(cfg, w, li, h)
+                        if kind == STATE:
+                            y, st_pools = state_mixer(m.state_weights(w, n), n, x, st_pools)
+                        else:
+                            y, staged = attn_mixer(m.attn_weights(w, n), n, x, staged)
+                        h, st = m.after_mixer(cfg, w, li, h, y, live)
+                    if st is not None:
+                        stats = stats + st
         with jax.named_scope("sample"):
             logits = m.head(params, m.final(cfg, params, h))
             if filter_sampling:

@@ -34,16 +34,19 @@ def route_noaux_tc(scores: jnp.ndarray, bias: jnp.ndarray, top_k: int, n_group: 
     the sigmoid affinities, ``bias`` [E] the ``e_score_correction_bias`` that
     enters the choice and not the weight.  A group's score is the sum of its
     two largest biased scores; the best ``topk_group`` groups stay, and the
-    ``top_k`` largest biased scores inside them are chosen.  Returns (ids
-    [T, K] int32, weights [T, K] float32: the unbiased scores of the chosen,
-    normalised to sum 1 and scaled)."""
+    ``top_k`` largest biased scores inside them are chosen; with every group
+    kept (``n_group`` 1, Nemotron-H's) there is no limit to build and the
+    choice is the plain biased top k.  Returns (ids [T, K] int32, weights
+    [T, K] float32: the unbiased scores of the chosen, normalised to sum 1 and
+    scaled)."""
     t, e = scores.shape
-    biased = scores + bias[None, :].astype(scores.dtype)
-    grouped = biased.reshape(t, n_group, e // n_group)
-    group_score = jax.lax.top_k(grouped, 2)[0].sum(axis=-1)  # [T, G]
-    _, best = jax.lax.top_k(group_score, topk_group)
-    keep = jnp.zeros((t, n_group), bool).at[jnp.arange(t)[:, None], best].set(True)
-    masked = jnp.where(jnp.repeat(keep, e // n_group, axis=1), biased, -jnp.inf)
+    masked = scores + bias[None, :].astype(scores.dtype)
+    if topk_group < n_group:
+        grouped = masked.reshape(t, n_group, e // n_group)
+        group_score = jax.lax.top_k(grouped, 2)[0].sum(axis=-1)  # [T, G]
+        _, best = jax.lax.top_k(group_score, topk_group)
+        keep = jnp.zeros((t, n_group), bool).at[jnp.arange(t)[:, None], best].set(True)
+        masked = jnp.where(jnp.repeat(keep, e // n_group, axis=1), masked, -jnp.inf)
     _, ids = jax.lax.top_k(masked, top_k)
     w = jnp.take_along_axis(scores, ids, axis=1)
     if norm_topk_prob:

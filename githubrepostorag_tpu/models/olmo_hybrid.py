@@ -85,6 +85,11 @@ class OlmoHybridConfig:
         return self.full_attention_interval - 1
 
     @property
+    def layer_segments(self) -> tuple:
+        """The layer pattern models/hybrid.py walks: a period, ``periods`` times."""
+        return ((hybrid.STATE * self.gdn_per_period + hybrid.ATTN, self.periods),)
+
+    @property
     def kv_layers(self) -> int:
         """Layers that page keys and values: one a period."""
         return self.periods
@@ -107,6 +112,11 @@ class OlmoHybridConfig:
     @property
     def beta_max(self) -> float:
         return 2.0 if self.linear_allow_neg_eigval else 1.0
+
+    @property
+    def state_cols(self) -> int:
+        """Columns of a slot's matrix the chunked rule works at (the pool may store more)."""
+        return self.linear_value_head_dim
 
     def state_shapes(self) -> dict:
         """One slot of one Gated DeltaNet layer: (shape, dtype) by name.  The
@@ -252,7 +262,10 @@ class _Layers:
 
     attn_window = ATTN_WINDOW
     weights = staticmethod(lambda params: params)
-    gdn_weights = staticmethod(lambda w, g: hybrid.at(w["gdn"], g))
+    step_scope = "gdn_recurrent"
+    state_weights = staticmethod(lambda w, g: hybrid.at(w["gdn"], g))
+    state_chunk = staticmethod(lambda *a: hybrid.gdn_chunk(_Layers, *a))
+    state_step = staticmethod(lambda *a: hybrid.gdn_step(_Layers, *a))
     attn_weights = staticmethod(lambda w, pi: hybrid.at(w["attn"], pi))
     gdn_inputs = staticmethod(lambda cfg, p, x: hybrid.gdn_inputs(cfg, p, x, ACT, cfg.beta_max))
     gdn_out = staticmethod(lambda cfg, p, o, z: hybrid.gdn_out(cfg, p, o, z, ACT))

@@ -69,12 +69,11 @@ class Qwen3NextConfig:
 
     # what the serving engine asks of a model: the module whose step programs
     # serve it, per-sequence state beside the pages (serving/kv_cache.StateSlots),
-    # expert counters with no dense lead-in, and the most rows one prefill wave
+    # expert counters over ``expert_layers``, and the most rows one prefill wave
     # carries (see DeepseekV3Config.prefill_rows_cap)
     step_programs = "githubrepostorag_tpu.models.qwen3_next"
     recurrent_state = True
     expert_counters = True
-    first_k_dense = 0
     prefill_rows_cap = 8
 
     @property
@@ -84,6 +83,11 @@ class Qwen3NextConfig:
     @property
     def gdn_per_period(self) -> int:
         return self.full_attention_interval - 1
+
+    @property
+    def layer_segments(self) -> tuple:
+        """The layer pattern models/hybrid.py walks: a period, ``periods`` times."""
+        return ((hybrid.STATE * self.gdn_per_period + hybrid.ATTN, self.periods),)
 
     @property
     def kv_layers(self) -> int:
@@ -104,6 +108,11 @@ class Qwen3NextConfig:
         return self.experts_held[1] - self.experts_held[0]
 
     @property
+    def expert_layers(self) -> int:
+        """Every layer ends in an expert layer."""
+        return self.num_layers
+
+    @property
     def rotary_dim(self) -> int:
         return int(self.head_dim * self.partial_rotary_factor)
 
@@ -112,6 +121,11 @@ class Qwen3NextConfig:
         """The convolution runs over [q | k | v] of the linear heads."""
         return (2 * self.linear_num_key_heads * self.linear_key_head_dim
                 + self.linear_num_value_heads * self.linear_value_head_dim)
+
+    @property
+    def state_cols(self) -> int:
+        """Columns of a slot's matrix the chunked rule works at (the pool may store more)."""
+        return self.linear_value_head_dim
 
     def state_shapes(self) -> dict:
         """One slot of one Gated DeltaNet layer: (shape, dtype) by name."""
@@ -343,7 +357,10 @@ class _Layers:
 
     attn_window = ATTN_WINDOW
     weights = staticmethod(lambda params: _split(params))
-    gdn_weights = staticmethod(lambda w, g: hybrid.at(w[0]["gdn"], g))
+    step_scope = "gdn_recurrent"
+    state_weights = staticmethod(lambda w, g: hybrid.at(w[0]["gdn"], g))
+    state_chunk = staticmethod(lambda *a: hybrid.gdn_chunk(_Layers, *a))
+    state_step = staticmethod(lambda *a: hybrid.gdn_step(_Layers, *a))
     attn_weights = staticmethod(lambda w, pi: hybrid.at(w[0]["attn"], pi))
     gdn_inputs = staticmethod(lambda cfg, p, x: _gdn_inputs(cfg, p, x))
     gdn_out = staticmethod(lambda cfg, p, o, z: hybrid.gdn_out(cfg, p, o, z, ACT))

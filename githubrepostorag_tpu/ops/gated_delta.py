@@ -184,18 +184,21 @@ def gated_delta_chunked(state, q, k, v, g, beta, snap_col=None, block: int = BLO
     return o.reshape(r, t, h, -1), state, snap
 
 
-def causal_conv(x, taps, weight, new_lens, snap_col=None):
+def causal_conv(x, taps, weight, new_lens, snap_col=None, bias=None):
     """Depthwise causal convolution, then SiLU, over a chunk with its carried
     history.  ``x`` [R, T, C]; ``taps`` [R, K - 1, C]: the K - 1 inputs before
     the chunk; ``weight`` [C, K] (tap K - 1 multiplies the current input);
-    ``new_lens`` [R] real tokens.  Returns (y [R, T, C] float32, the history
-    after the row's real tokens, the history after ``snap_col`` of them), the
-    histories in ``taps``' type.  A row with no real token keeps its history."""
+    ``bias`` [C] or None, added before the SiLU (Mamba-2's has one, the Gated
+    DeltaNets' none); ``new_lens`` [R] real tokens.  Returns (y [R, T, C]
+    float32, the history after the row's real tokens, the history after
+    ``snap_col`` of them), the histories in ``taps``' type.  A row with no real token keeps its history."""
     kk = weight.shape[1]
     ext = jnp.concatenate([taps.astype(x.dtype), x], axis=1)  # [R, K - 1 + T, C]
     wf = weight.astype(jnp.float32)
     t = x.shape[1]
     y = sum(ext[:, j:j + t].astype(jnp.float32) * wf[:, j] for j in range(kk))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
 
     def history(at):  # the K - 1 inputs that end at chunk column ``at``
         idx = at[:, None] + jnp.arange(kk - 1)[None, :]
@@ -205,10 +208,13 @@ def causal_conv(x, taps, weight, new_lens, snap_col=None):
     return jax.nn.silu(y), history(new_lens), history(jnp.maximum(snap_col, 0))
 
 
-def causal_conv_step(x, taps, weight):
-    """One token a row: ``x`` [B, C], ``taps`` [B, K - 1, C].  Returns
-    (y [B, C] float32, the history with ``x`` shifted in)."""
+def causal_conv_step(x, taps, weight, bias=None):
+    """One token a row: ``x`` [B, C], ``taps`` [B, K - 1, C]; ``bias`` as
+    ``causal_conv``'s.  Returns (y [B, C] float32, the history with ``x``
+    shifted in)."""
     ext = jnp.concatenate([taps.astype(x.dtype), x[:, None]], axis=1)  # [B, K, C]
     y = jnp.einsum("bkc,ck->bc", ext.astype(jnp.float32), weight.astype(jnp.float32),
                    precision=HI)
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return jax.nn.silu(y), ext[:, 1:].astype(taps.dtype)

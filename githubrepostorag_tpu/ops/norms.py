@@ -37,3 +37,15 @@ def rms_norm_gated(x: jnp.ndarray, gate: jnp.ndarray, weight: jnp.ndarray,
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
     normed = xf * jax.lax.rsqrt(var + eps) * weight.astype(jnp.float32)
     return normed * jax.nn.silu(gate.astype(jnp.float32))
+
+
+def rms_norm_gate_first(x: jnp.ndarray, gate: jnp.ndarray, weight: jnp.ndarray, groups: int,
+                        eps: float = 1e-6) -> jnp.ndarray:
+    """Mamba-2's output norm as Nemotron-H publishes it (``norm_before_gate=False``,
+    ``group_size = width / groups``): GATE FIRST, then the norm BY GROUP,
+    ``rmsnorm_group(x * silu(gate)) * w`` over each of ``groups`` runs of the last
+    axis; float32 out.  ``rms_norm_gated`` above is norm-first over one head."""
+    y = x.astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
+    yg = y.reshape(*y.shape[:-1], groups, y.shape[-1] // groups)
+    yg = yg * jax.lax.rsqrt(jnp.mean(yg * yg, axis=-1, keepdims=True) + eps)
+    return yg.reshape(y.shape) * weight.astype(jnp.float32)

@@ -2827,7 +2827,7 @@ class Engine:
         without waiting, and book the expert slots it offered."""
         self._dispatch_seq += 1
         cfg = self.cfg
-        slots = cfg.n_held * (cfg.num_layers - cfg.first_k_dense) * steps
+        slots = cfg.n_held * cfg.expert_layers * steps
         self._moe_pending.append((self._dispatch_seq, program, counts, slots))
 
     def _moe_read_back(self, upto: int) -> None:

@@ -159,6 +159,51 @@ def test_uneven_routing_equals_a_loop_over_experts(monkeypatch):
     assert int(counts.sum()) == t * k and int(counts[1]) > t * 0.9
 
 
+def test_noaux_tc_at_one_group_is_the_plain_biased_top_k():
+    """Nemotron-H's ``n_group`` 1 / ``topk_group`` 1: a group of all the
+    experts limits nothing, so the choice is the top k of score + bias and the
+    weights are the unbiased scores of the chosen, normalised and scaled."""
+    from githubrepostorag_tpu.models.moe import route_noaux_tc
+
+    rng = np.random.default_rng(5)
+    t, e, k = 50, 128, 6
+    scores = jax.nn.sigmoid(jnp.asarray(rng.normal(size=(t, e)) * 2.0, jnp.float32))
+    bias = jnp.asarray(rng.normal(size=(e,)) * 0.05, jnp.float32)
+    ids, w = route_noaux_tc(scores, bias, k, 1, 1, True, 2.5)
+    want_ids = np.argsort(-np.asarray(scores + bias[None]), axis=1)[:, :k]
+    assert (np.sort(np.asarray(ids), axis=1) == np.sort(want_ids, axis=1)).all()
+    assert (np.asarray(ids) != np.argsort(-np.asarray(scores), axis=1)[:, :k]).any()  # the bias chose
+    picked = np.take_along_axis(np.asarray(scores), np.asarray(ids), axis=1)
+    np.testing.assert_allclose(w, 2.5 * picked / picked.sum(axis=1, keepdims=True), rtol=1e-6)
+    # a limit that limits (8 groups, 4 kept) chooses otherwise: the short cut is for one group alone
+    limited, _ = route_noaux_tc(scores, bias, k, 8, 4, True, 2.5)
+    assert (np.sort(np.asarray(limited), axis=1) != np.sort(want_ids, axis=1)).any()
+
+
+@pytest.mark.parametrize("listed", [False, True], ids=["scan", "listed"])
+@pytest.mark.parametrize("tokens", [24, 200], ids=["one-tile", "many-tiles"])
+def test_two_product_relu2_experts_through_the_dropless_dispatch(listed, tokens):
+    """An expert that is NOT gated, ``W_down relu(W_up x)^2`` (Nemotron-H's):
+    the dispatch takes any ``expert_ffn``; both forms, rows inside one tile and
+    over several, a held range in the middle of the router's width."""
+    from githubrepostorag_tpu.models.moe import dropless_experts
+    from githubrepostorag_tpu.models.nemotron_h import relu2_ffn
+
+    rng = np.random.default_rng(6)
+    d, f, e, k, lo, held = 16, 24, 16, 4, 4, 8
+    x = jnp.asarray(rng.normal(size=(tokens, d)), jnp.float32)
+    wu = jnp.asarray(rng.normal(size=(held, f, d)) * 0.3, jnp.float32)
+    wd = jnp.asarray(rng.normal(size=(held, f, d)) * 0.3, jnp.float32)
+    top_w, top_i = jax.lax.top_k(jax.nn.sigmoid(jnp.asarray(rng.normal(size=(tokens, e)),
+                                                            jnp.float32)), k)
+    y, counts = dropless_experts(x, top_i, top_w, lambda ex, rows: relu2_ffn(rows, wu[ex], wd[ex]),
+                                 held, lo=lo, listed=listed)
+    want = sum(jnp.where(top_i == lo + ex, top_w, 0).sum(axis=1, keepdims=True)
+               * (jnp.square(jax.nn.relu(x @ wu[ex].T)) @ wd[ex]) for ex in range(held))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=2e-5, atol=2e-5)
+    assert int(counts.sum()) == int(((top_i >= lo) & (top_i < lo + held)).sum())
+
+
 def test_moe_int8_quantization(tiny_moe):
     """Weight-only int8 MoE: experts/shared-expert carry stacked per-expert
     scales, router and gate stay full precision, and logits track the bf16
