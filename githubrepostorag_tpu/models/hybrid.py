@@ -55,7 +55,12 @@ which model it runs):
   ``state_step(cfg, p, x, s_old, taps_old) -> (y, s, taps)``, ``s`` at the
   width the pool stores (``gdn_chunk`` / ``gdn_step`` below are the Gated
   DeltaNet's, over the model's ``gdn_inputs`` / ``gdn_out``); ``cfg.state_cols``
-  is the width the chunked rule works at (``lane_padded``);
+  is the width the chunked rule works at (``lane_padded``).  A layer type
+  whose one-token rule has a kernel that works on the pool itself also brings
+  ``state_step_in_pool(cfg, p, x, s_pool, n, taps_old, act, interpret) -> (y,
+  s_pool, taps)``: the burst then hands it the whole pool, the layer's index
+  and the step's live mask, and neither slices the pool nor selects the old
+  rows itself (``use_pallas``; ``state_step`` stays the array form);
 * ``attn_project(cfg, p, x, *position_cols) -> (q, k, v, more)`` /
   ``attn_out(p, attn, *more)`` around the shared pages and kernels;
 * ``attn_window``: columns of a prefill chunk one call of the attention
@@ -474,6 +479,9 @@ def burst(m, params, cfg, last_tokens, seq_lens, k_pages, v_pages, presence, act
     walk_lens = jnp.where(active & (seq_lens < row_limits), start_lens, 0)
     interpret = not on_tpu()
     w = m.weights(params)
+    # a layer type whose rule steps the pool where it lies (a kernel over the live rows,
+    # ops/pallas_state.py) brings it; the others' rows are read out, stepped and put back
+    step_in_pool = getattr(m, "state_step_in_pool", None) if use_pallas else None
 
     def rows_of(pool, g):  # the engine's rows are the pool's first slots
         return jax.lax.dynamic_slice(pool, (g,) + (0,) * (pool.ndim - 1),
@@ -492,7 +500,11 @@ def burst(m, params, cfg, last_tokens, seq_lens, k_pages, v_pages, presence, act
 
         def state_mixer(p, g, x, st_pools):
             s_pool, c_pool = st_pools
-            taps_old, s_old = rows_of(c_pool, g), rows_of(s_pool, g)
+            taps_old = rows_of(c_pool, g)
+            if step_in_pool is not None:
+                y, s_pool, taps = step_in_pool(cfg, p, x, s_pool, g, taps_old, act, interpret)
+                return y, (s_pool, put_rows(c_pool, g, jnp.where(act[:, None], taps, taps_old)))
+            s_old = rows_of(s_pool, g)
             if s_old.shape[-1] != cfg.state_cols:
                 # a pool stored wider than its values (lane_padded).  Behind the barrier the
                 # compiler keeps ONE copy of the rows (on a v5e: in VMEM) for the reductions

@@ -43,6 +43,7 @@ from githubrepostorag_tpu.obs import startup
 from githubrepostorag_tpu.ops.gated_delta import causal_conv, causal_conv_step
 from githubrepostorag_tpu.ops.latent_attention import einsum_f32
 from githubrepostorag_tpu.ops.norms import rms_norm, rms_norm_gate_first
+from githubrepostorag_tpu.ops.pallas_state import ssd_step_in_place
 from githubrepostorag_tpu.ops.sampling import first_token_tail
 from githubrepostorag_tpu.ops.ssd import BLOCK, mask_padding, ssd_chunked, ssd_step
 
@@ -295,8 +296,9 @@ def _ssm_chunk(cfg, p, x, s0, taps0, live, new_lens, snap_col, page_size):
     return _ssm_out(cfg, p, o, z), s_new, s_snap, taps, taps_snap
 
 
-def _ssm_step(cfg, p, x, s_old, taps_old):
-    """A Mamba-2 mixer over one token a row (``state_step``)."""
+def _ssm_token(cfg, p, x, taps_old):
+    """One token a row up to the rule: (x [B, H, P]; dt [B, H]; B, C [B, G, N];
+    the gate z; the history after the token)."""
     bsz = x.shape[0]
     mixed, z, dt = _ssm_inputs(cfg, p, x)
     with jax.named_scope("ssm_conv"):
@@ -304,10 +306,36 @@ def _ssm_step(cfg, p, x, s_old, taps_old):
                                    p["conv_w"], bias=p["conv_b"])
         taps = taps.reshape(bsz, -1)
     xs, b, c = _ssm_heads(cfg, y[:, None])
+    return xs[:, 0], dt[:, 0], b[:, 0], c[:, 0], z, taps
+
+
+def _ssm_step(cfg, p, x, s_old, taps_old):
+    """A Mamba-2 mixer over one token a row (``state_step``), as array code:
+    the CPU's path, and what the kernel below is held to."""
+    xs, dt, b, c, z, taps = _ssm_token(cfg, p, x, taps_old)
     with jax.named_scope("ssm_recurrent"):
-        o, s_new = ssd_step(s_old.astype(jnp.float32), xs[:, 0], dt[:, 0], -jnp.exp(p["A_log"]),
-                            b[:, 0], c[:, 0], p["D"])
+        o, s_new = ssd_step(s_old.astype(jnp.float32), xs, dt, -jnp.exp(p["A_log"]), b, c, p["D"])
     return _ssm_out(cfg, p, o[:, None], z), s_new, taps
+
+
+@partial(jax.jit, static_argnames=("interpret",))
+def _rule_in_pool(s_pool, n, act, xs, dt, a, b, c, d, interpret):
+    """ops/pallas_state.ssd_step_in_place under the rule's scope: the call is
+    named for it in a device trace, where the rule's roofline looks.  Jitted so
+    that the burst traces the kernel's body (64 heads unrolled: 1.6 s) once and
+    not once a layer: the layer's index is an operand."""
+    with jax.named_scope("ssm_recurrent"):
+        return ssd_step_in_place(s_pool, n, act, xs, dt, a, b, c, d, interpret=interpret)
+
+
+def _ssm_step_in_pool(cfg, p, x, s_pool, n, taps_old, act, interpret):
+    """The same mixer with the rule as a kernel on the state pool itself
+    (``state_step_in_pool``): layer ``n``'s rows that are ``act`` are read once
+    and written once where they lie, the others are not touched."""
+    xs, dt, b, c, z, taps = _ssm_token(cfg, p, x, taps_old)
+    o, s_pool = _rule_in_pool(s_pool, jnp.int32(n), act, xs, dt, -jnp.exp(p["A_log"]), b, c,
+                              p["D"], interpret=interpret)
+    return _ssm_out(cfg, p, o[:, None], z), s_pool, taps
 
 
 def _attn_project(cfg, p, x):
@@ -391,6 +419,7 @@ class _Layers:
     attn_weights = staticmethod(lambda w, n: hybrid.at(w[0]["attn"], n))
     state_chunk = staticmethod(lambda *a: _ssm_chunk(*a))
     state_step = staticmethod(lambda *a: _ssm_step(*a))
+    state_step_in_pool = staticmethod(lambda *a: _ssm_step_in_pool(*a))
     attn_project = staticmethod(lambda cfg, p, x: _attn_project(cfg, p, x))
     attn_out = staticmethod(lambda p, attn: _attn_out(p, attn))
     position_cols = staticmethod(lambda cfg, positions: ())  # positions do not enter

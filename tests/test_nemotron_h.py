@@ -295,6 +295,27 @@ def test_engine_prefill_decode_and_a_restored_snapshot_are_the_references(in_flo
     assert burst[1] > 0 and burst[2] % (eng.cfg.n_held * eng.cfg.expert_layers) == 0
 
 
+def test_engine_with_the_kernel_on_the_pool_gives_the_array_forms_tokens(in_float32):
+    """The burst's one-token rule as ops/pallas_state.py's kernel (``use_pallas``,
+    interpreted here) against ``ssd_step`` (the engine's path on the CPU): the
+    same prompts through waves, bursts, snapshots and restores give the same
+    tokens; a snapshot's slot keeps the bits it was written with through every
+    burst that follows (the kernel addresses live rows' slots alone), so what a
+    restore reads is what was saved."""
+    plain, kernel = build_engine(jnp.float32), build_engine(jnp.float32, use_pallas=True)
+    saved = {}
+    for prompt in (A, B, A, B, A + B[100:]):
+        want, got = run(plain, prompt), run(kernel, prompt)
+        assert got == want and max(decode_gaps(prompt, got[1])) < 1e-3
+        pool = np.asarray(kernel.state_pools["s"]).view(np.uint32)
+        for h, slot in kernel._state._by_hash.items():
+            bits = saved.setdefault(h, pool[:, slot])  # first seen: as the wave wrote it
+            assert (pool[:, slot] == bits).all()
+    assert kernel.state_restored == plain.state_restored == 3 and len(saved) >= 3
+    # the rows that sat every burst out (one request at a time: rows 1 .. 3) hold what they held
+    assert not pool[:, 1:4].any()
+
+
 def test_engine_in_bfloat16_stays_inside_the_decode_tolerance():
     """As served (bfloat16 weights, products and pages, float32 state): the
     tokens of a cold and of a resumed prompt lie 0.05 of a row's spread below

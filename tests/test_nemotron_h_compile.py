@@ -132,9 +132,11 @@ def _dims(shape):
 
 
 @pytest.mark.parametrize("program,rows,writes", [
-    pytest.param("burst", 0, 8, id="burst"),      # a Mamba-2 layer: one write of its rows
-    pytest.param("wave", 1, 8, id="wave-1x512"),  # a row and traced layer: its state, its snapshot
-    pytest.param("wave", 8, 64, id="wave-8x512"),  # (4 M layers are traced: 3 + 1 of the two scans)
+    # a Mamba-2 layer: one write of its rows of history; the STATE is the kernel's alone
+    pytest.param("burst", 0, {"s": 0, "conv": 8}, id="burst"),
+    # a row and traced layer: its state, its snapshot (4 M layers are traced: 3 + 1 of the two scans)
+    pytest.param("wave", 1, {"s": 8, "conv": 8}, id="wave-1x512"),
+    pytest.param("wave", 8, {"s": 64, "conv": 64}, id="wave-8x512"),
 ])
 def test_step_program_leaves_both_caches_and_the_expert_stacks_in_place(
         chip, as_on_chip, program, rows, writes):
@@ -145,7 +147,20 @@ def test_step_program_leaves_both_caches_and_the_expert_stacks_in_place(
     for name in ("s", "conv"):  # written in place, a slot (the burst: its rows) at a time
         movers = pool_movers(hlo, pools[name])
         assert all(m.startswith("dynamic_update_slice") for m in movers), (name, movers)
-        assert len(movers) == writes, (name, movers)
+        assert len(movers) == writes[name], (name, movers)
+    if program == "burst":
+        # a Mamba-2 layer's rule is ONE call: the state pool goes in whole and comes out as the
+        # same buffer (ops/pallas_state.py), and no array of all 32 rows' states exists anywhere
+        pool = f"f32[{_dims(pools['s'])}]"
+        calls = [ln for ln in timed_lines(hlo, ("custom-call",)) if "/ssm_recurrent/" in ln]
+        assert len(calls) == 8, [c[:120] for c in calls]
+        for call in calls:
+            result, operands = call.split(" custom-call(", 1)
+            layouts = operands.split("operand_layout_constraints={", 1)[1].split("}, output_to", 1)[0]
+            assert result.count(pool) == 1 and layouts.count(pool) == 1, call[:400]
+            at = len(re.findall(r"[a-z0-9]+\[[0-9,]*\]\{", layouts.split(pool)[0]))  # its operand
+            assert f"output_to_operand_aliasing={{{{1}}: ({at}, {{}})}}" in call, call[:1200]
+        assert f"f32[{ROWS},64,64,128]" not in hlo
     # every array lies as the program is handed it: row-major, the last axis on the lanes
     layout = hlo.split("entry_computation_layout={(", 1)[1].split(")->", 1)[0]
     assert pools["e_wu"] == pools["e_wd"] == (8, 32, 1856, 2688)  # [f, d]: 2,688 = 21 lane tiles
@@ -179,22 +194,29 @@ def test_this_cells_metrics_select_the_ops_under_their_scopes(chip, as_on_chip):
     waves = [compiled(chip, "wave", rows)[0] for rows in (1, 8)]
     spec = lambda name: manifest.metric_spec(name)["args"]  # noqa: E731
 
-    # the one-token rule's passes over its rows of state, a layer and step: S C read off the pool's
-    # rows, and the update written into the pool in place (which reads them again); B and C
-    # broadcast to the heads and dt x beside them.  All of them, and nothing else of either program
+    # the one-token rule, a layer and step: ONE call of the kernel, named for its scope and for its
+    # FIRST result (y, [32, 64, 64]: the pool is its second), and the copies that turn a row's
+    # [heads, width] on its side for it and back (x on its way in, y on its way out, and the gate
+    # beside y: XLA files the first under no scope and the last under the projection that made
+    # it).  All of them, and nothing else of either program
     rule = re.compile(spec("ssm_decode_roofline_frac")["op"])
     decode = _picked(burst, rule)
-    assert set(decode) == {"ssm_recurrent"}
+    assert set(decode) == {"ssm_recurrent", "ssm_proj", ""}
+    for scope in ("ssm_proj", ""):
+        assert len(decode[scope]) == 8 and all(n.startswith("copy.") for n in decode[scope])
     names = [re.sub(r"\.\d+", "", n) for n in decode["ssm_recurrent"]]
-    for kind, count in (("multiply_reduce_fusion_f32_32_64_64_", 8),
-                        ("select_dynamic-update-slice_fusion_f32_8_96_64_64_128_", 8)):
-        assert names.count(kind) == count, (kind, names)
-    rest = {n for n, scope in timed_ops(burst) if scope == "ssm_recurrent"} - decode["ssm_recurrent"]
-    # what the scope holds besides is a head's scalars ([32, 64], [32, 8, 8], [32, 8]): no pass
-    assert all(re.search(r"_f32_(32_64|32_8_8|32_8|1_64)_$", n) for n in rest), rest
+    assert names.count("ssm_recurrent_f32_32_64_64_") == 8, names
+    # what XLA stepped every row slot with is gone: S C read off the pool, the update in place
+    assert not any(n.startswith(("multiply_reduce_fusion_f32_32_64_64_", "select_dynamic-update-slice"))
+                   for n in names), names
+    in_scope = {n for n, scope in timed_ops(burst) if scope == "ssm_recurrent"}
+    rest = in_scope - decode["ssm_recurrent"]
+    # what the scope holds besides is a head's or a group's scalars ([32, 64] and stacks of it,
+    # [32, 8], [64]): every op of a row's size ([32, 64, 64]) or more is in the seconds
+    assert all(re.search(r"_f32_(32_64|32_[14]_64|32_8|1_64|64)_$", n) for n in rest), rest
     for wave in waves:
         assert _picked(wave, rule) == {}
-    # a kernel named after the scope would be read too (it is a custom call in a trace)
+    # a kernel named after the scope is read (it is a custom call in a trace), an unnamed one is not
     assert rule.search("ssm_recurrent.3") and not rule.search("custom-call.2_f32_32_64_64_128_")
 
     chunked = re.compile(spec("ssm_prefill_roofline_frac")["op"].format(
@@ -220,7 +242,11 @@ def test_this_cells_metrics_select_the_ops_under_their_scopes(chip, as_on_chip):
     moves = re.compile(spec("ssm_state_pool_move_share")["pattern"])
     for wave in waves:
         assert set(_picked(wave, moves)) == {"state_write"}  # the in-place row writes, nothing else
-    assert _picked(burst, moves) == {}  # the burst's own updates compute: not moves
+    # the kernel computes, it does not move the pool: its name ends in S C's shape, not the pool's
+    # (had the pool been its first result, ``ssm_recurrent.N_f32_8_96_64_64_128_`` would be a move)
+    assert _picked(burst, moves) == {}
+    assert moves.search("ssm_recurrent.80_f32_8_96_64_64_128_")
+    assert not any(moves.search(n) for n in in_scope)
 
     # the accepted experts' metric finds the burst's two products a layer, and only them
     experts = re.compile(manifest.metric_spec("moe_experts_hbm_frac")["args"]["op"].format(

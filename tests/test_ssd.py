@@ -13,6 +13,8 @@ terms in different orders; 2e-5 on outputs and states of order one is a few
 hundred roundings, not a different formula (a missing decay, a head reading
 the wrong group or a dropped skip reads 1e-1 and more)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -119,6 +121,121 @@ def test_one_token_step_at_a_state_stored_wider_than_it_is():
     np.testing.assert_allclose(y_w, y, atol=1e-6)
     np.testing.assert_allclose(s_w[..., :16], s, atol=1e-6)
     assert not np.asarray(s_w[..., 16:]).view(np.uint32).any()
+
+
+# ----------------------------------- the one-token rule as a kernel on the pool --
+
+ROWS = 32  # the engine's rows: the pool's first slots; 3 more stand for snapshots and the spare
+
+
+def _pool_case(live_steps, limits=None, seed=4, layers=3, rows=ROWS, h=8, p=8, g=2, n=16):
+    """A pool [layers, rows + 3, H, P, 128] (N = 16 stored at a lane tile, the
+    padding zero) and, a step, the inputs of ``ssd_step`` and the rows that
+    step: ``live_steps`` [steps][rows] bools, or ``limits`` (``row_limits``
+    against lengths that start at 5 and grow with every step a row takes, as
+    models/hybrid.burst masks them)."""
+    steps = len(live_steps) if limits is None else 4
+    x, dt, a, b, c, d, _ = inputs(rows, steps, h, p, g, n, seed)
+    rng = np.random.default_rng(seed)
+    pool = np.zeros((layers, rows + 3, h, p, 128), np.float32)
+    pool[..., :n] = rng.normal(size=pool.shape[:-1] + (n,))
+    if limits is not None:
+        lens, live_steps = np.full(rows, 5), []
+        for _ in range(steps):
+            live_steps.append(lens < np.asarray(limits))
+            lens = lens + live_steps[-1]
+    return jnp.asarray(pool), (x, dt, a, b, c, d), [jnp.asarray(m, bool) for m in live_steps]
+
+
+BETWEEN = np.isin(np.arange(ROWS), [1, 2, 7, 19, 30])  # live rows between dead ones
+
+POOL_CASES = [
+    pytest.param(dict(live_steps=[np.zeros(ROWS, bool)] * 2), id="no-live-row"),
+    pytest.param(dict(live_steps=[np.arange(ROWS) == 13] * 3), id="one-live-row"),
+    pytest.param(dict(live_steps=[BETWEEN, BETWEEN, ~BETWEEN, BETWEEN]),
+                 id="live-rows-between-dead-ones"),
+    pytest.param(dict(live_steps=[np.ones(ROWS, bool)] * 3), id="all-32"),
+    pytest.param(dict(live_steps=None, limits=[7, 5, 6, 99] * 8),
+                 id="rows-that-reach-their-row-limits"),
+]
+
+
+def _chain(pool, ins, live_steps, layer, step_fn):
+    """Every step's (y, pool) from ``step_fn`` beside the array form's."""
+    x, dt, a, b, c, d = ins
+    rows = x.shape[0]
+    want = pool
+    for t, act in enumerate(live_steps):
+        before = pool
+        y, pool = step_fn(pool, layer, act, x[:, t], dt[:, t], a, b[:, t], c[:, t], d)
+        y_w, s_w = ssd.ssd_step(want[layer, :rows], x[:, t], dt[:, t], a, b[:, t], c[:, t], d)
+        want = want.at[layer, :rows].set(
+            jnp.where(act[:, None, None, None], s_w, want[layer, :rows]))
+        yield np.asarray(act), np.asarray(before), (y, pool), (y_w, want)
+
+
+def heads_a_block(monkeypatch, heads, p=8):
+    """``heads`` heads of [p, 128] float32 a block of the kernel's walk."""
+    from githubrepostorag_tpu.ops import pallas_state
+
+    monkeypatch.setattr(pallas_state, "BLOCK_BYTES", heads * p * 128 * 4)
+
+
+@pytest.mark.parametrize("case", POOL_CASES)
+def test_the_kernel_steps_live_rows_as_ssd_step_and_touches_nothing_else(monkeypatch, case):
+    """ops/pallas_state.ssd_step_in_place under the interpreter, chained over
+    steps: a live row's state and output are ``ssd_step``'s to rounding (the
+    elementwise operations are the same; the 128 lanes of ``S C`` may be summed
+    in another order); a dead row's slot, every slot past the rows and every
+    other layer are the bits that were there; the padding lanes stay zero."""
+    from githubrepostorag_tpu.ops.pallas_state import ssd_step_in_place
+
+    pool, ins, live_steps = _pool_case(**case)
+    rows, layer = ins[0].shape[0], 1
+    heads_a_block(monkeypatch, 2)  # 4 blocks a row
+    step = functools.partial(ssd_step_in_place, interpret=True)
+    for act, before, (y, got), (y_w, want) in _chain(pool, ins, live_steps, layer, step):
+        got = np.asarray(got)
+        np.testing.assert_allclose(np.asarray(y)[act], np.asarray(y_w)[act], atol=TOL)
+        np.testing.assert_allclose(got[layer, :rows][act], np.asarray(want)[layer, :rows][act],
+                                   atol=TOL)
+        kept = np.ones(got.shape[:2], bool)
+        kept[layer, :rows] = ~act
+        assert (got.view(np.uint32)[kept] == before.view(np.uint32)[kept]).all()
+        assert not got[..., 16:].view(np.uint32).any()
+        assert np.isfinite(np.asarray(y)).all()  # a dead row's output is unused, not undefined
+
+
+@pytest.mark.parametrize("case,block_heads", [
+    pytest.param(POOL_CASES[2].values[0], None, id="between-dead-ones-one-block-a-row"),
+    pytest.param(POOL_CASES[4].values[0], 4, id="row-limits-two-blocks-a-row")])
+def test_the_kernels_dmas_land_before_they_are_read_and_never_meet(monkeypatch, case, block_heads):
+    """Plain interpret mode copies at ``start()``; the TPU interpreter runs a
+    DMA when it is waited for and watches every buffer for races: a block
+    computed before its wait, a slot refilled while its write is still out, a
+    read that meets a write of the pool, or a wait with no start (a hang)
+    show here (tests/test_pallas_paged.py has the burst attention's).  One
+    block a row (as many items as live rows: an odd count too), then two."""
+    from jax.experimental.pallas import tpu as pltpu
+    from githubrepostorag_tpu.ops.pallas_state import ssd_step_in_place
+
+    if not hasattr(pltpu, "InterpretParams"):
+        pytest.skip("this jax has no TPU interpreter")
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call as tpu_interpreter
+
+    pool, ins, live_steps = _pool_case(**case, layers=2)
+    pool = jnp.concatenate([pool[:, :8], pool[:, ROWS:]], axis=1)  # 8 rows, and the 3 slots past
+    ins = tuple(v[:8] if v.shape[0] == ROWS else v for v in ins)
+    live_steps = [m[:8] for m in live_steps][:2]
+    if block_heads:
+        heads_a_block(monkeypatch, block_heads)
+    step = functools.partial(ssd_step_in_place, interpret=pltpu.InterpretParams(
+        detect_races=True, dma_execution_mode="on_wait"))
+    for act, before, (y, got), (y_w, want) in _chain(pool, ins, live_steps, 1, step):
+        np.testing.assert_allclose(np.asarray(y)[act], np.asarray(y_w)[act], atol=TOL)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=TOL)
+        assert (np.asarray(got)[1, :8][~act] == before[1, :8][~act]).all()
+        assert not tpu_interpreter.races.races_found
 
 
 def test_the_convolution_with_a_bias_in_both_forms():
