@@ -14,8 +14,12 @@ pattern's, not the depth's), the burst unrolls them.  Two kinds of
 per-sequence memory ride them as carries, never sliced:
 
 * K/V page pools ``[attention layers, n_kv, P, page_size, head_dim]``
-  (``cfg.kv_layers``), committed by ``kv_cache.commit_paged`` at a traced
-  layer index, read by the paged kernels qwen2 uses;
+  (``cfg.kv_layers``), committed by ``kv_cache.commit_paged`` (the wave at a
+  traced layer index, the burst all layers at once after its scan; both say
+  their slots are RUNS, a row's columns and a row's steps being consecutive
+  positions, so a full-precision pool is written a few aligned windows of
+  slots a run, in place, and not a row an index), read by the paged kernels
+  qwen2 uses;
 * a STATE pool for the recurrent layers (``cfg.state_shapes()``,
   ``kv_cache.make_state_pools``): ``s`` ``[state layers, slots, ...]`` float32
   (the recurrence's matrix: ``[Hv, dk, dv]`` a Gated DeltaNet layer, ``[H, P,
@@ -379,11 +383,11 @@ def wave(m, params, cfg, input_ids, positions, k_pages, v_pages, slot_mapping, b
 
         _, (q, *more, k, v) = at_wave_width(project, width, page_size, (h, *cols[1:]), ())
         with jax.named_scope("kv_write"):
-            flat = slots.reshape(-1)
+            flat, run = slots.reshape(-1), slots.shape[1]  # a row's columns: consecutive positions
             kp, _ = commit_paged(kp, k.reshape(-1, nkv, hd).swapaxes(0, 1), flat, None,
-                                 page_size, layer=pi)
+                                 page_size, layer=pi, run=run)
             vp, _ = commit_paged(vp, v.reshape(-1, nkv, hd).swapaxes(0, 1), flat, None,
-                                 page_size, layer=pi)
+                                 page_size, layer=pi, run=run)
         with jax.named_scope("paged_attention"):
             if use_pallas:
                 from githubrepostorag_tpu.ops.fused_decode import fused_paged_attention
@@ -456,7 +460,8 @@ def burst(m, params, cfg, last_tokens, seq_lens, k_pages, v_pages, presence, act
     """``n_steps`` decode iterations in one program, serving/decode_burst.py's
     contract and structure: the K/V pools are loop-invariant inside the burst
     (new keys and values go to a staged buffer the kernel reads as a tail, one
-    scatter commits them at the end); the state pool is stepped in place,
+    commit a pool lays them into their pages at the end, a row's steps as one
+    run); the state pool is stepped in place,
     rows 0 .. B-1, a row that sits a step out keeping what it has.  A layer's
     rows of state cross HBM twice a step: read out of the pool once, at the
     width the pool stores them (``lane_padded``), and written into it once, by
@@ -594,7 +599,8 @@ def burst(m, params, cfg, last_tokens, seq_lens, k_pages, v_pages, presence, act
     slots = jnp.where(valid, slots, num_pages * page_size).reshape(-1)  # sentinel: dropped
     with jax.named_scope("kv_write"):
         commit = lambda pool, st: commit_paged(  # noqa: E731
-            pool, st.swapaxes(1, 2).reshape(P, nkv, b * n_steps, hd), slots, None, page_size)[0]
+            pool, st.swapaxes(1, 2).reshape(P, nkv, b * n_steps, hd), slots, None, page_size,
+            run=n_steps)[0]  # a row's steps are consecutive positions
         k_pages, v_pages = commit(k_pages, staged[0]), commit(v_pages, staged[1])
     return (packed, valid, k_pages, v_pages, presence, out_lens, last, stats,
             {"s": st_pools[0], "conv": st_pools[1]})

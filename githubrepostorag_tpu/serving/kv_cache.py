@@ -358,7 +358,11 @@ def commit_paged(
       from all rows of the commit, not a run at a time), and so do pools
       whose row is not whole 128-lane tiles or whose page does not hold
       whole windows (``_run_window``); no benchmark cell runs either with
-      ``run`` given.
+      ``run`` given.  A caller that gives no ``run`` keeps it too: the
+      packed and ring prefills (their slots are no runs) and DeepSeek-V3's
+      latent pool.  The waves and bursts of Qwen2 (PR 38) and of the
+      hybrids (models/hybrid.py, PR 43) give ``run``, and their bfloat16
+      pools take the run form in every benchmark cell that runs them.
 
     In both the kv-head axis stays an axis of its own, so pools sharded
     over kv heads (tp) commit shard-locally.

@@ -381,3 +381,86 @@ def test_wave_and_burst_commit_runs_as_they_committed_rows(monkeypatch):
     valid = np.asarray(got[-3])
     assert valid[0].all() and valid[1].sum() == 3 and not valid[2].any()
     assert not np.array_equal(got[-2], np.zeros_like(got[-2]))
+
+
+@pytest.mark.parametrize("module,config", [
+    pytest.param("olmo_hybrid", "OlmoHybridConfig", id="gated-deltanet"),
+    pytest.param("nemotron_h", "NemotronHConfig", id="mamba-2"),
+])
+def test_hybrid_wave_and_burst_commit_runs_as_they_committed_rows(monkeypatch, module, config):
+    """The hybrids' skeleton (models/hybrid.py) through a wave of four rows
+    (more than ``MAX_RUNG_ROWS``: the runs are a loop with the pool as its
+    carry; a row that starts on a page, one that starts mid-page, padded ones,
+    one with nothing new) and a burst (a row that crosses a page, one at its
+    limit after 3 steps, a dead one), pages of 16 bfloat16 rows of 128: logits,
+    tokens, both K/V pools and both state pools equal to the same programs
+    traced with commit_paged held to its row form."""
+    import importlib
+
+    import githubrepostorag_tpu.serving.kv_cache as kv_cache
+    from githubrepostorag_tpu.models import hybrid
+
+    model = importlib.import_module(f"githubrepostorag_tpu.models.{module}")
+    cfg = getattr(model, config).tiny(head_dim=128)  # a head of whole lanes: the run form
+    params = model.init_params(cfg, seed=3)
+    pages, ps, b, s, steps = 17, 16, 4, 32, 8  # a chunk is whole blocks of the chunked rules
+    bt = np.arange(1, 17, dtype=np.int32).reshape(4, 4).T  # row r holds pages r+1, r+5, r+9, r+13
+    trash = b + 1  # the state slot of rows that have nothing to write
+    rng = np.random.default_rng(5)
+    chunks, cached = [], np.zeros((b,), np.int32)
+    for new in ([32, 9, 0, 16], [13, 32, 16, 5]):
+        new = np.asarray(new, np.int32)
+        offs = np.arange(s)[None, :]
+        pos = cached[:, None] + offs
+        slot = bt[np.arange(b)[:, None], pos // ps] * ps + pos % ps
+        slot = np.where(offs < new[:, None], slot, -1).astype(np.int32)
+        src = np.where(cached > 0, np.arange(b), -1).astype(np.int32)  # fresh: zeros
+        dst = np.where(new > 0, np.arange(b), trash).astype(np.int32)
+        chunks.append(tuple(map(jnp.asarray, (
+            rng.integers(0, cfg.vocab_size, (b, s)).astype(np.int32), pos.astype(np.int32), slot,
+            cached, new, src, dst))))
+        cached = cached + new
+    lens = jnp.asarray(cached)  # 45, 41, 16, 21: row 0 crosses a page in the burst
+    limits = jnp.asarray([64, 44, 64, 64], jnp.int32)  # row 1 is at its limit after 3 steps
+    active = jnp.asarray([True, True, False, True])
+    bt = jnp.asarray(bt)
+
+    def run_both():
+        shape = (cfg.kv_layers, cfg.num_kv_heads, pages, ps, cfg.head_dim)
+        kp, vp = jnp.zeros(shape, jnp.bfloat16), jnp.ones(shape, jnp.bfloat16)
+        state = kv_cache.make_state_pools(cfg, b + 2)
+        full, col0 = jnp.full((b,), trash, jnp.int32), jnp.zeros((b,), jnp.int32)
+        wave = jax.jit(lambda kp, vp, state, ids, pos, slot, cached, new, src, dst: hybrid.wave(
+            model._Layers, params, cfg, ids, pos, kp, vp, slot, bt, cached, new, state, src, dst,
+            full, col0))
+        out = []
+        for chunk in chunks:
+            logits, kp, vp, _, state = wave(kp, vp, state, *chunk)
+            out.append(logits)
+        ones, zeros = jnp.ones((b,), jnp.float32), jnp.zeros((b,), jnp.int32)
+        burst = jax.jit(lambda kp, vp, state: hybrid.burst(
+            model._Layers, params, cfg, zeros + 7, lens, kp, vp,
+            jnp.zeros((b, cfg.vocab_size), bool), active, limits, bt, jax.random.PRNGKey(1),
+            0 * ones, ones, zeros, ones, steps, False, False, zeros, jnp.zeros((b,), bool), zeros,
+            jnp.uint32(0), state))
+        toks, valid, kp, vp, *_, state = burst(kp, vp, state)
+        return [*out, toks, valid, kp.astype(jnp.float32), vp.astype(jnp.float32),
+                state["s"], state["conv"].astype(jnp.float32)]
+
+    pool = jnp.zeros((1, ps, cfg.head_dim), jnp.bfloat16)
+    assert kv_cache._run_window(pool, steps) == 16 and kv_cache._run_window(pool, s) == 16
+    forms = []
+    lay_run = kv_cache._lay_run
+    monkeypatch.setattr(kv_cache, "_lay_run", lambda *a: forms.append("run") or lay_run(*a))
+    got = run_both()
+    assert forms  # the run form was traced: the callers said ``run=``
+    monkeypatch.setattr(kv_cache, "_run_window", lambda pools, run: None)
+    del forms[:]
+    want = run_both()
+    assert not forms
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    valid = np.asarray(got[3])
+    assert valid[0].all() and valid[1].sum() == 3 and not valid[2].any() and valid[3].all()
+    for pool in got[4:]:  # nothing compared is still what it started as
+        assert np.asarray(pool).std() > 0

@@ -37,7 +37,13 @@ import jax.numpy as jnp
 import pytest
 
 from tests.test_qwen3_next_compile import timed_lines
-from tests.test_tpu_compile import chip, pool_movers, topo  # noqa: F401 - fixtures
+from tests.test_tpu_compile import (  # noqa: F401 - fixtures
+    COMMIT_CASES,
+    assert_commits_windows_in_place,
+    chip,
+    pool_movers,
+    topo,
+)
 
 PAGES, PAGE, ROWS, ROW_PAGES, SLOTS = 2048, 128, 32, 80, 96
 SCOPES = ("ssm_proj", "ssm_conv", "ssm_chunked", "ssm_recurrent", "ssm_gate_norm", "state_read",
@@ -142,7 +148,7 @@ def test_step_program_leaves_both_caches_and_the_expert_stacks_in_place(
         chip, as_on_chip, program, rows, writes):
     hlo, pools = compiled(chip, program, rows)
     assert "tpu_custom_call" in hlo  # the paged kernel of the burst, or of the prefill: 32 / 2 x 128
-    assert pool_movers(hlo, pools["kv"]) == []
+    assert pool_movers(hlo, pools["kv"], windows=False) == []  # written a window of slots at a time
     assert pools["s"] == (8, SLOTS, 64, 64, 128) and pools["conv"] == (8, SLOTS, 3 * 6144)
     for name in ("s", "conv"):  # written in place, a slot (the burst: its rows) at a time
         movers = pool_movers(hlo, pools[name])
@@ -171,6 +177,21 @@ def test_step_program_leaves_both_caches_and_the_expert_stacks_in_place(
     big = [ln for ln in timed_lines(hlo, ("copy",))
            if any(f"[{_dims(pools[k])}]" in ln.split(" copy(")[0] for k in pools)]
     assert big == [], [ln[:200] for ln in big]
+
+
+@pytest.mark.parametrize("program,rows", COMMIT_CASES)
+def test_step_program_commits_keys_and_values_as_windows_in_place(chip, as_on_chip, program, rows):
+    """models/hybrid.py's wave and burst tell ``commit_paged`` that their slots
+    are runs (PR 43): a commit here is 2 layers x 2 kv heads of 128 (a burst's
+    window 16 KB, a wave's 66 KB a layer), in the one scan of the two that
+    holds the attention layers."""
+    hlo, pools = compiled(chip, program, rows)
+    assert_commits_windows_in_place(hlo, pools["kv"], program, rows)
+    if program == "burst":
+        # the loop over the row slots' runs (the window plan once, 12 instructions a pool's
+        # iteration): 527 timed instructions where the row form's two scatters and their indices
+        # made it 501; a commit that unrolls its windows, or plans them twice, shows here
+        assert len(list(timed_lines(hlo))) <= 527
 
 
 def test_the_wave_scans_where_the_pattern_repeats():

@@ -23,7 +23,13 @@ import jax.numpy as jnp
 import pytest
 
 from tests.test_qwen3_next_compile import timed_lines
-from tests.test_tpu_compile import chip, pool_movers, topo  # noqa: F401 - fixtures
+from tests.test_tpu_compile import (  # noqa: F401 - fixtures
+    COMMIT_CASES,
+    assert_commits_windows_in_place,
+    chip,
+    pool_movers,
+    topo,
+)
 
 PAGES, PAGE, ROWS, ROW_PAGES, SLOTS = 1280, 128, 32, 80, 97
 SCOPES = ("gdn_proj", "gdn_conv", "gdn_chunked", "gdn_recurrent", "gdn_gate_norm", "qk_norm",
@@ -117,7 +123,7 @@ def _picked(hlo, pattern):
 def test_step_program_leaves_both_caches_in_place(chip, as_on_chip, program, rows, writes):
     hlo, pools = compiled(chip, program, rows)
     assert "tpu_custom_call" in hlo  # the paged kernel of the burst, or of the prefill
-    assert pool_movers(hlo, pools["kv"]) == []
+    assert pool_movers(hlo, pools["kv"], windows=False) == []  # written a window of slots at a time
     assert pools["s"] == (6, SLOTS, 30, 96, 256)  # the value axis at a whole number of lane tiles
     for name in ("s", "conv"):  # written in place, a slot (the burst: its rows) at a time
         movers = pool_movers(hlo, pools[name])
@@ -125,6 +131,23 @@ def test_step_program_leaves_both_caches_in_place(chip, as_on_chip, program, row
         assert len(movers) == writes, (name, movers)
     # a wave is compiled without the memory-space assignment: none of its arrays lives in VMEM
     assert ("S(1)" in hlo) == (program == "burst")
+
+
+@pytest.mark.parametrize("program,rows", COMMIT_CASES)
+def test_step_program_commits_keys_and_values_as_windows_in_place(chip, as_on_chip, program, rows):
+    """models/hybrid.py's wave and burst tell ``commit_paged`` that their slots
+    are runs (PR 43): the scatter of 15,360 rows a pool is gone from the burst
+    (32 runs of 8 steps, each 2 windows of 16 slots over both layers and all
+    30 heads: 245 KB) and from every wave (a row: 5 windows of 128 slots, 983
+    KB a layer), the four-row wave's through the loop, under
+    ``WAVE_COMPILER_OPTIONS`` like the rest of it."""
+    hlo, pools = compiled(chip, program, rows)
+    assert_commits_windows_in_place(hlo, pools["kv"], program, rows)
+    if program == "burst":
+        # the loop over the row slots' runs (the window plan once, 12 instructions a pool's
+        # iteration): 261 timed instructions where the row form's two scatters and their indices
+        # made it 232; a commit that unrolls its windows, or plans them twice, shows here
+        assert len(list(timed_lines(hlo))) <= 261
 
 
 def test_this_cells_metrics_select_the_ops_under_their_scopes(chip, as_on_chip):

@@ -28,6 +28,8 @@ import jax.numpy as jnp
 import pytest
 
 from tests.test_tpu_compile import (  # noqa: F401 - fixtures
+    COMMIT_CASES,
+    assert_commits_windows_in_place,
     assert_wave_keeps_in_place,
     chip,
     pool_movers,
@@ -176,12 +178,27 @@ def timed_ops_rewriting(hlo: str, leaf) -> list:
 def test_step_program_leaves_pools_and_experts_in_place(chip, as_on_chip, program, rows, writes):
     hlo, pools = compiled(chip, program, rows)
     assert "tpu_custom_call" in hlo  # the paged kernel of the burst, or of the prefill
-    for name in ("kv", "e_wgu", "e_wd"):
+    assert pool_movers(hlo, pools["kv"], windows=False) == []  # written a window of slots at a time
+    for name in ("e_wgu", "e_wd"):
         assert pool_movers(hlo, pools[name]) == [], name
     for name in ("s", "conv"):  # written in place, a slot (the burst: its rows) at a time
         movers = pool_movers(hlo, pools[name])
         assert all(m.startswith("dynamic_update_slice") for m in movers), (name, movers)
         assert len(movers) == writes, (name, movers)
+
+
+@pytest.mark.parametrize("program,rows", COMMIT_CASES)
+def test_step_program_commits_keys_and_values_as_windows_in_place(chip, as_on_chip, program, rows):
+    """models/hybrid.py's wave and burst tell ``commit_paged`` that their slots
+    are runs (PR 43): a commit here is 2 layers x 2 kv heads of 256 (a burst's
+    window 33 KB, a wave's 131 KB a layer)."""
+    hlo, pools = compiled(chip, program, rows)
+    assert_commits_windows_in_place(hlo, pools["kv"], program, rows)
+    if program == "burst":
+        # the loop over the row slots' runs (the window plan once, 12 instructions a pool's
+        # iteration): 511 timed instructions where the row form's two scatters and their indices
+        # made it 485; a commit that unrolls its windows, or plans them twice, shows here
+        assert len(list(timed_lines(hlo))) <= 511
 
 
 @pytest.mark.parametrize("program,rows,layers_written", [
@@ -288,7 +305,9 @@ def test_the_metrics_select_the_ops_under_their_scopes(chip, as_on_chip):
 def test_the_burst_keeps_the_timed_ops_it_has(chip, as_on_chip):
     """The burst's timed ops by the name a trace shows (less XLA's running
     number), counted: the parent commit's of PR 40, whose burst this model shares
-    with Olmo-Hybrid (models/hybrid.py:burst, ops/gated_delta.gated_delta_step).
+    with Olmo-Hybrid (models/hybrid.py:burst, ops/gated_delta.gated_delta_step),
+    written again by PR 43 for the ops under ``kv_write`` and for no other (the
+    commit as windows of slots: the two row scatters and their indices went).
     The accepted ``gdn_decode_roofline_frac`` and ``moe_experts_hbm_frac`` find
     their ops by these names, so an edit made for the other hybrid that renames
     one here reads null on the chip.  A change that MEANS to move this program

@@ -193,18 +193,48 @@ def pool_movers(hlo: str, pool_shape: tuple, ops: tuple = MOVERS, windows: bool 
     return found
 
 
-def assert_wave_keeps_in_place(hlo: str, held: str, count: int) -> None:
-    """The compiled module is ``jit_forward_paged_wave`` and each of its
-    ``count`` entry parameters whose type matches ``held`` (the pools, the
-    presence mask) is aliased to an output: donated, and updated in place."""
+def assert_wave_keeps_in_place(hlo: str, held: str, count: int,
+                               module: str = "jit_forward_paged_wave") -> None:
+    """The compiled module is ``module`` and each of its ``count`` entry
+    parameters whose type matches ``held`` (the pools, the presence mask) is
+    aliased to an output: donated, and updated in place."""
     head = hlo.split("\n", 1)[0]
-    assert re.match(r"HloModule jit_forward_paged_wave\b", head), head
+    assert re.match(rf"HloModule {module}\b", head), head
     aliases = head.split("input_output_alias=")[1].split("entry_computation_layout")[0]
     aliased = {int(p) for p in re.findall(r"\(\s*(\d+), \{\}", aliases)}
     params = {int(re.search(r"parameter\((\d+)\)", line).group(1))
               for line in hlo.split("ENTRY", 1)[1].splitlines()
               if " parameter(" in line and re.search(held, line)}
     assert len(params) == count and params <= aliased, (params, aliased)
+
+
+# the programs a hybrid family's commit guard compiles: (program, rows of its 512-column wave)
+COMMIT_CASES = [pytest.param("burst", 0, id="burst")] + [
+    pytest.param("wave", rows, id=f"wave-{rows}x512") for rows in (1, 2, 4)]
+
+
+def assert_commits_windows_in_place(hlo: str, pool_shape: tuple, program: str, rows: int) -> None:
+    """A step program of a cell (``program``: "burst", 8 steps, or "wave" at
+    ``rows`` rows of 512 columns) that tells ``commit_paged`` its slots are
+    runs writes its two full-precision K/V pools as update-slices of aligned
+    windows of slots and as nothing else: no scatter of single rows into a
+    pool, nothing that copies, transposes or re-lays a pool or a layer of
+    one, both pools aliased operand to result, and exactly the windows a run
+    can fall in, each written once: inline for a wave with rungs, once in the
+    body of a loop over the runs otherwise (``kv_cache._commit_runs``)."""
+    from githubrepostorag_tpu.ops.prefill_width import MAX_RUNG_ROWS
+    from githubrepostorag_tpu.serving.kv_cache import _run_window
+
+    run = 8 if program == "burst" else 512  # the cells' ``decode_burst`` and ``prefill_chunk``
+    assert pool_movers(hlo, pool_shape, ops=("scatter",)) == []
+    assert pool_movers(hlo, pool_shape, windows=False) == []
+    module = "jit_decode_burst" if program == "burst" else "jit_forward_paged_wave"
+    assert_wave_keeps_in_place(hlo, r"bf16\[" + ",".join(map(str, pool_shape)) + r"\]", 2, module)
+    win = _run_window(jax.ShapeDtypeStruct(pool_shape, jnp.bfloat16), run)
+    per_run = (run + win - 2) // win + 1
+    inline = rows if program == "wave" and rows <= MAX_RUNG_ROWS else 1
+    windows = pool_movers(hlo, pool_shape, ("dynamic-update-slice",))
+    assert len(windows) == 2 * per_run * inline, windows
 
 
 def assert_wave_holds_every_rung(hlo: str, rungs: int, pool_shape: tuple) -> None:
