@@ -419,45 +419,6 @@ class Settings:
     sp_ring_buckets: int = field(
         default_factory=lambda: _env_int("SP_RING_BUCKETS", 0)
     )
-    # >0: n-gram speculative decoding with drafts of up to k tokens,
-    # SPEC_ITERS draft/verify rounds a device dispatch for all-greedy
-    # batches (serving/spec_burst.py; any other batch decodes plainly)
-    # instead of pipelined decode bursts; a latency knob for quoting-heavy
-    # greedy decodes, 0 (bursts) is the throughput default
-    spec_ngram_k: int = field(default_factory=lambda: _env_int("SPEC_NGRAM_K", 0))
-    # one compiled program per engine step (serving/fused_step.py): the
-    # packed prefill wave and a MIXED spec/plain decode burst dispatch
-    # together, so greedy rows keep their verify windows even when
-    # sampled rows share the batch.  Requires SPEC_NGRAM_K and
-    # PREFILL_TOKEN_BUDGET; incompatible with SPEC_DRAFT_MODEL and
-    # PREFILL_PRIORITY.
-    fused_step: bool = field(
-        default_factory=lambda: _env_bool("FUSED_STEP", False)
-    )
-    # path to a small draft checkpoint (e.g. Qwen2.5-0.5B next to a 7B
-    # target): when set, DRAFT-MODEL speculative decoding becomes the
-    # serving default (serving/draft_spec.py) — draft k tokens on the
-    # small model, verify all of them in one target forward, commit the
-    # longest agreed prefix.  Mutually exclusive with SPEC_NGRAM_K.
-    spec_draft_model: str = field(
-        default_factory=lambda: os.getenv("SPEC_DRAFT_MODEL", "")
-    )
-    # max draft length per round; the adaptive controller walks the
-    # power-of-two ladder [1..SPEC_K] on EMA acceptance rate
-    spec_k: int = field(default_factory=lambda: _env_int("SPEC_K", 4))
-    # fused draft/verify/accept rounds per device dispatch (draft-model
-    # and n-gram speculation alike)
-    spec_iters: int = field(default_factory=lambda: _env_int("SPEC_ITERS", 4))
-    # a request whose EMA acceptance rate falls below this floor drops to
-    # plain decode_burst for the rest of its life (sticky fallback)
-    spec_accept_floor: float = field(
-        default_factory=lambda: _env_float("SPEC_ACCEPT_FLOOR", 0.35)
-    )
-    # requests within this margin of their propagated deadline also fall
-    # back: plain decode stops at finer granularity than a spec burst
-    spec_deadline_margin_s: float = field(
-        default_factory=lambda: _env_float("SPEC_DEADLINE_MARGIN_S", 0.25)
-    )
     # quantized KV cache pages with per-page dequant scales
     # (kv_cache.quantize_kv_paged; scales ride the decode kernel's
     # scalar-prefetch channel).  KV_QUANT=int8 (or any truthy boolean)

@@ -91,45 +91,6 @@ PACKED_PREFILL_PADDING = Counter(
     ["replica"],
     registry=REGISTRY,
 )
-SPEC_PROPOSED = Counter(
-    "rag_spec_draft_tokens_total", "Speculative draft tokens proposed",
-    ["replica"], registry=REGISTRY
-)
-SPEC_ACCEPTED = Counter(
-    "rag_spec_accepted_tokens_total",
-    "Speculative draft tokens the model accepted and committed",
-    ["replica"],
-    registry=REGISTRY,
-)
-# literal-name aliases for the draft-model speculation dashboards (the
-# *_tokens_total pair above predates the draft-model path and keeps its
-# names for dashboard compatibility; both pairs advance together)
-SPEC_PROPOSED_TOTAL = Counter(
-    "rag_spec_proposed_total",
-    "Draft tokens proposed by the speculative decoder (n-gram or draft model)",
-    ["replica"],
-    registry=REGISTRY,
-)
-SPEC_ACCEPTED_TOTAL = Counter(
-    "rag_spec_accepted_total",
-    "Proposed draft tokens the target model accepted and committed",
-    ["replica"],
-    registry=REGISTRY,
-)
-SPEC_FALLBACKS = Counter(
-    "rag_spec_fallbacks_total",
-    "Requests the adaptive controller demoted from speculative to plain "
-    "decode, by reason (acceptance collapse / deadline pressure)",
-    ["replica", "reason"],
-    registry=REGISTRY,
-)
-SPEC_ACCEPTANCE = Histogram(
-    "rag_spec_acceptance_ratio",
-    "Per-request draft acceptance ratio (accepted / proposed) at completion",
-    ["replica"],
-    registry=REGISTRY,
-    buckets=(0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8, 0.9, 1.0),
-)
 WORKER_DEQUEUE_ERRORS = Counter(
     "rag_worker_dequeue_errors_total",
     "queue.dequeue() failures survived by the worker's backoff loop",
@@ -300,21 +261,14 @@ LEDGER_LIMITER = Gauge(
 LEDGER_STEP_SECONDS = Counter(
     "rag_engine_step_seconds_total",
     "Engine step wall time classified into phase buckets (prefill | decode "
-    "| spec_verify | kv_migration | kv_transfer | sched_stall | compile)",
+    "| kv_migration | kv_transfer | sched_stall | compile)",
     ["replica", "bucket"],
     registry=REGISTRY,
 )
 LEDGER_TOKENS = Counter(
     "rag_engine_tokens_total",
-    "Token outcomes: committed | spec_rejected | deadline_reaped",
+    "Token outcomes: committed | deadline_reaped",
     ["replica", "outcome"],
-    registry=REGISTRY,
-)
-ENGINE_FUSED_STEPS = Counter(
-    "rag_engine_fused_steps_total",
-    "Engine steps served by the single-dispatch fused program "
-    "(packed prefill + mixed spec/plain decode — serving/fused_step.py)",
-    ["replica"],
     registry=REGISTRY,
 )
 ENGINE_STEP_DISPATCHES = Gauge(
@@ -372,7 +326,7 @@ FLEET_LIFECYCLE = Gauge(
 CTRL_ACTIONS = Counter(
     "rag_ctrl_actions_total",
     "Fleet-controller remediation actions executed, by action ladder rung "
-    "(failover / grow_host_pool / spec_k_down / spread_affinity) and the "
+    "(failover / grow_host_pool / spread_affinity) and the "
     "sensed reason that justified it",
     ["action", "reason"],
     registry=REGISTRY,

@@ -25,8 +25,8 @@ from collections import deque
 from githubrepostorag_tpu import metrics
 
 # step-anatomy keys copied out of the ledger's step record into a sample
-_ANATOMY_KEYS = ("prefill", "decode", "spec_verify", "kv_migration",
-                 "kv_transfer", "sched_stall", "compile", "committed",
+_ANATOMY_KEYS = ("prefill", "decode", "kv_migration", "kv_transfer",
+                 "sched_stall", "compile", "committed",
                  "wall", "compiles")
 
 

@@ -401,22 +401,7 @@ def record_engine_spans(result: Any, parent: TraceContext | None) -> None:
             if faulted:
                 psp.add_event("kv_fault_in", pages=faulted)
     if ftok is not None and done > ftok:
-        sp = record_span("engine.decode", ftok, done, parent=parent, attrs={
+        record_span("engine.decode", ftok, done, parent=parent, attrs={
             **attrs, "output_tokens": len(getattr(result, "output_tokens", ()) or ()),
             "finish_reason": getattr(result, "finish_reason", ""),
         })
-        if sp is not None:
-            # speculative-decoding outcome as events on the decode span:
-            # the flight recorder then shows per-request acceptance and
-            # any controller fallback right in the request's timeline
-            proposed = getattr(result, "spec_proposed", 0)
-            if proposed:
-                sp.add_event(
-                    "spec", proposed=proposed,
-                    accepted=getattr(result, "spec_accepted", 0),
-                    acceptance=round(
-                        getattr(result, "spec_accepted", 0) / proposed, 4),
-                )
-            fallback = getattr(result, "spec_fallback", None)
-            if fallback:
-                sp.add_event("spec_fallback", reason=fallback)

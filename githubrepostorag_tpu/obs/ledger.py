@@ -8,8 +8,7 @@ The ledger sits between them: each driver step it snapshots the engine's
 cumulative counters, differences them against the previous snapshot, and
 classifies the step's wall time into phase buckets:
 
-    prefill | decode | spec_verify | kv_migration | kv_transfer |
-    sched_stall | compile
+    prefill | decode | kv_migration | kv_transfer | sched_stall | compile
 
 `sched_stall` is the inter-step gap (host scheduling, lock contention);
 `kv_transfer` is disaggregated-handoff pack/unpack time (the engine's
@@ -17,9 +16,8 @@ export/import gathers run under the driver lock between steps, so the
 raw gap would misread as scheduler stall without the split);
 `compile` is the step time a fresh XLA compilation left unaccounted for by
 the measured phases.  Token deltas are classified as committed (landed in a
-request's output), spec_rejected (drafted but refused by the target model —
-[vllm-pagedattention]'s wasted-token accounting), or deadline_reaped
-(committed then discarded because the request blew its deadline).
+request's output) or deadline_reaped (committed then discarded because the
+request blew its deadline).
 
 Over a rolling window (SLO_LEDGER_WINDOW_S) the ledger derives:
   * goodput — committed tokens / elapsed (the BASELINE tok/s/chip number)
@@ -46,9 +44,9 @@ from collections import deque
 
 from githubrepostorag_tpu import metrics
 
-BUCKETS = ("prefill", "decode", "spec_verify", "kv_migration",
+BUCKETS = ("prefill", "decode", "kv_migration",
            "kv_transfer", "sched_stall", "compile")
-OUTCOMES = ("committed", "spec_rejected", "deadline_reaped")
+OUTCOMES = ("committed", "deadline_reaped")
 LIMITERS = ("hbm_pages", "stall", "compile", "swap_wait", "kv_transfer",
             "none")
 
@@ -60,13 +58,11 @@ _PUBLISH_S = 0.25
 # is just {field: float} so tests and the schema gate can feed dicts
 SNAPSHOT_FIELDS = (
     "committed_tokens", "prefill_tokens", "reaped_tokens",
-    "spec_proposed", "spec_accepted",
     "admission_blocked_steps",
     "prefill_seconds_total", "decode_seconds_total",
-    "spec_verify_seconds_total",
     "migration_seconds_total", "fault_in_seconds_total",
     "transfer_seconds_total",
-    "fused_steps_total", "step_dispatches_total",
+    "step_dispatches_total",
     "bursts_ahead", "bursts_starved",
     "prefill_padded_tokens",
     # a recurrent model's state cache (serving/kv_cache.StateSlots); zero elsewhere
@@ -125,7 +121,6 @@ class TokenLedger:
         self._m_mfu = metrics.LEDGER_MFU.labels(replica=replica)
         self._m_limiter = {lim: metrics.LEDGER_LIMITER.labels(
             replica=replica, limiter=lim) for lim in LIMITERS}
-        self._m_fused = metrics.ENGINE_FUSED_STEPS.labels(replica=replica)
         self._m_dispatches = metrics.ENGINE_STEP_DISPATCHES.labels(
             replica=replica)
         # last classified step record (GIL-atomic reference swap): the
@@ -155,7 +150,6 @@ class TokenLedger:
             rec = {
                 "prefill": max(0.0, d["prefill_seconds_total"]),
                 "decode": max(0.0, d["decode_seconds_total"]),
-                "spec_verify": max(0.0, d["spec_verify_seconds_total"]),
                 "kv_migration": max(0.0, d["migration_seconds_total"]
                                     + d["fault_in_seconds_total"]),
                 "kv_transfer": xfer,
@@ -163,16 +157,13 @@ class TokenLedger:
                 "compile": 0.0,
                 "committed": max(0.0, d["committed_tokens"]),
                 "prefill_tokens": max(0.0, d["prefill_tokens"]),
-                "spec_rejected": max(0.0, d["spec_proposed"] - d["spec_accepted"]),
                 "deadline_reaped": max(0.0, d["reaped_tokens"]),
                 "blocked": 1.0 if d["admission_blocked_steps"] > 0 else 0.0,
                 "compiles": float(compiles),
                 "wall": wall,
                 "steps": 1.0,
                 # dispatch attribution: how many main-model programs this
-                # step issued, and whether the fused single-dispatch
-                # program served it (serving/fused_step.py)
-                "fused_steps": max(0.0, d["fused_steps_total"]),
+                # step issued
                 "dispatches": max(0.0, d["step_dispatches_total"]),
                 # bursts that went out while the device still had work
                 # queued, and bursts it had drained and waited for
@@ -190,13 +181,12 @@ class TokenLedger:
             if compiles > 0:
                 # kv_transfer stays out of ``measured``: it is inter-step
                 # time, never part of this step's wall
-                measured = (rec["prefill"] + rec["decode"]
-                            + rec["spec_verify"] + rec["kv_migration"])
+                measured = rec["prefill"] + rec["decode"] + rec["kv_migration"]
                 rec["compile"] = max(0.0, wall - measured)
 
             self._append(step_end, rec)
             self.last_rec = rec
-            for k in BUCKETS + OUTCOMES + ("fused_steps",):
+            for k in BUCKETS + OUTCOMES:
                 if rec[k] > 0:
                     self._pending[k] = self._pending.get(k, 0.0) + rec[k]
             if step_end - self._last_pub >= _PUBLISH_S:
@@ -222,9 +212,6 @@ class TokenLedger:
             v = self._pending.pop(o, 0.0)
             if v > 0:
                 self._m_tok[o].inc(v)
-        v = self._pending.pop("fused_steps", 0.0)
-        if v > 0:
-            self._m_fused.inc(v)
         self._publish_locked(now)
         self._last_pub = now
 
@@ -254,8 +241,7 @@ class TokenLedger:
         if not steps:
             return "none"
         busy = sum(s.get(b, 0.0) for b in
-                   ("prefill", "decode", "spec_verify", "kv_migration",
-                    "kv_transfer", "compile"))
+                   ("prefill", "decode", "kv_migration", "kv_transfer", "compile"))
         denom = max(1e-9, busy + s.get("sched_stall", 0.0))
         if s.get("compiles", 0.0) > 0 and s.get("compile", 0.0) / denom > 0.05:
             return "compile"
@@ -343,7 +329,7 @@ class TokenLedger:
             goodput = s.get("committed", 0.0) / elapsed if elapsed else 0.0
             mfu = self._mfu_locked(elapsed)
             committed = s.get("committed", 0.0)
-            wasted = s.get("spec_rejected", 0.0) + s.get("deadline_reaped", 0.0)
+            wasted = s.get("deadline_reaped", 0.0)
             return {
                 "replica": self.replica,
                 "window_s": self.window_s,
@@ -355,14 +341,12 @@ class TokenLedger:
                 "tokens": {
                     "committed": int(committed),
                     "prefill": int(s.get("prefill_tokens", 0.0)),
-                    "spec_rejected": int(s.get("spec_rejected", 0.0)),
                     "deadline_reaped": int(s.get("deadline_reaped", 0.0)),
                     "wasted_fraction": round(
                         wasted / max(1.0, committed + wasted), 6),
                 },
                 "bucket_seconds": {b: round(s.get(b, 0.0), 6) for b in BUCKETS},
                 "dispatch": {
-                    "fused_steps": int(s.get("fused_steps", 0.0)),
                     "dispatches": int(s.get("dispatches", 0.0)),
                     "dispatches_per_step": round(
                         s.get("dispatches", 0.0) / s.get("steps", 1.0)

@@ -412,8 +412,7 @@ class SLOPlane:
             if snap is not None:
                 goodput += snap["goodput_tok_s"]
                 committed += snap["tokens"]["committed"]
-                wasted += (snap["tokens"]["spec_rejected"]
-                           + snap["tokens"]["deadline_reaped"])
+                wasted += snap["tokens"]["deadline_reaped"]
             stats = {}
             if callable(stats_fn):
                 try:

@@ -7,8 +7,8 @@ opens directly in ui.perfetto.dev:
   * flight-recorder span trees (API -> worker -> agent -> engine), one
     host thread per trace so spans nest correctly;
   * per-step token-ledger anatomy per replica: one slice per driver step
-    plus counter tracks for the prefill/decode/spec_verify/kv_migration/
-    kv_transfer/sched_stall/compile buckets;
+    plus counter tracks for the prefill/decode/kv_migration/kv_transfer/
+    sched_stall/compile buckets;
   * continuous-profiler samples (queue depths + pool occupancy counters)
     so the recent past renders even with tracing off;
   * KV tier-migration events from the page observatory (fault-in,
@@ -55,7 +55,7 @@ _TID_LIFECYCLE = 2
 _TID_FAULTS = 3
 
 # ledger step-record keys rendered as per-replica counter tracks
-_BUCKET_KEYS = ("prefill", "decode", "spec_verify", "kv_migration",
+_BUCKET_KEYS = ("prefill", "decode", "kv_migration",
                 "kv_transfer", "sched_stall", "compile")
 
 # fleet-event provider registry (serving/multi_engine.py registers; the

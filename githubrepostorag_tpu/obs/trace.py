@@ -288,8 +288,8 @@ def record_span(name: str, start: float, end: float,
     """Record a retroactive span from already-measured monotonic
     timestamps (engine queue/prefill/decode attribution, coalescer wave
     timing) under ``parent`` or the active scope.  Returns the finished
-    span so callers can stamp events on it (record_engine_spans annotates
-    the decode span with speculation outcomes); None when untraced."""
+    span so callers can stamp events on it (record_engine_spans stamps a
+    prefill span's KV fault-ins); None when untraced."""
     ctx = parent if parent is not None else current_context()
     if ctx is None or not ctx.sampled:
         return None

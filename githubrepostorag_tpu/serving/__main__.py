@@ -93,20 +93,6 @@ async def serve(host: str, port: int) -> None:
         fuse=plan.n_devices == 1,  # mesh=None below iff the plan is one chip
     )
 
-    draft_params = draft_cfg = None
-    if s.spec_draft_model:
-        # draft-model speculation pairing (ROADMAP: 0.5B draft + 7B int8
-        # target).  The draft loads UNQUANTIZED and UNFUSED — the Engine
-        # fuses/replicates it itself — and must share the target's
-        # tokenizer (the Engine rejects a vocab mismatch at construction).
-        if s.spec_ngram_k:
-            raise SystemExit(
-                "SPEC_DRAFT_MODEL and SPEC_NGRAM_K are mutually exclusive: "
-                "a serving pod runs one speculation strategy"
-            )
-        logger.info("loading draft model from %s", s.spec_draft_model)
-        draft_params, draft_cfg = load_qwen2(s.spec_draft_model, dtype=ml_dtypes.bfloat16)
-
     # tokenizer first: a broken tokenizer config must fail fast, not after
     # minutes of XLA warmup compiles
     tokenizer = make_tokenizer(s.model_weights_path)
@@ -140,14 +126,6 @@ async def serve(host: str, port: int) -> None:
             prefill_priority=s.prefill_priority,
             sp_prefill_threshold=sp_threshold,
             sp_ring_buckets=s.sp_ring_buckets,
-            spec_ngram_k=s.spec_ngram_k,
-            fused_step=s.fused_step,
-            draft_params=draft_params,
-            draft_cfg=draft_cfg,
-            spec_k=s.spec_k,
-            spec_iters=s.spec_iters,
-            spec_accept_floor=s.spec_accept_floor,
-            spec_deadline_margin_s=s.spec_deadline_margin_s,
             preempt=s.preempt,
             preempt_headroom_pages=s.preempt_headroom_pages,
             default_priority=s.priority_default_class,

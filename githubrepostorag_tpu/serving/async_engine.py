@@ -58,11 +58,9 @@ class AsyncEngine:
         # last engine-counter values already exported to prometheus —
         # instance state, so a stop()/start() relaunch doesn't re-export
         # the full cumulative totals
-        self._exported = {"hit": 0, "prop": 0, "acc": 0,
-                          "packed_tok": 0, "packed_pad": 0, "reaps": 0,
-                          "fb": {}, "kv_fault": 0, "kv_wb": 0,
-                          "kv_dedup": 0, "kv_hold": 0, "kv_mig_s": 0.0,
-                          "xfer_s": 0.0, "preempts": 0, "resumes": 0}
+        self._exported = {"hit": 0, "packed_tok": 0, "packed_pad": 0, "reaps": 0,
+                          "kv_fault": 0, "kv_wb": 0, "kv_dedup": 0, "kv_hold": 0,
+                          "kv_mig_s": 0.0, "xfer_s": 0.0, "preempts": 0, "resumes": 0}
         # step profiler: scheduler-stall gauge + what the compile ledger saw
         # since the last step, on the driver thread (obs/engine_profile)
         self.profiler = EngineStepProfiler(replica=replica)
@@ -201,12 +199,6 @@ class AsyncEngine:
             PACKED_PREFILL_PADDING,
             PACKED_PREFILL_TOKENS,
             PREFIX_CACHE_HITS,
-            SPEC_ACCEPTANCE,
-            SPEC_ACCEPTED,
-            SPEC_ACCEPTED_TOTAL,
-            SPEC_FALLBACKS,
-            SPEC_PROPOSED,
-            SPEC_PROPOSED_TOTAL,
             TTFT,
         )
 
@@ -224,11 +216,6 @@ class AsyncEngine:
         m_running = ENGINE_RUNNING.labels(replica=R)
         m_waiting = ENGINE_WAITING.labels(replica=R)
         m_prefix = PREFIX_CACHE_HITS.labels(replica=R)
-        m_sprop = SPEC_PROPOSED.labels(replica=R)
-        m_sacc = SPEC_ACCEPTED.labels(replica=R)
-        m_sprop_t = SPEC_PROPOSED_TOTAL.labels(replica=R)
-        m_sacc_t = SPEC_ACCEPTED_TOTAL.labels(replica=R)
-        m_saccept = SPEC_ACCEPTANCE.labels(replica=R)
         m_ptok = PACKED_PREFILL_TOKENS.labels(replica=R)
         m_ppad = PACKED_PREFILL_PADDING.labels(replica=R)
         m_reaps = ENGINE_DEADLINE_REAPS.labels(replica=R)
@@ -245,17 +232,6 @@ class AsyncEngine:
             ptok = getattr(self.engine, "packed_prefill_tokens", 0)
             ppad = getattr(self.engine, "packed_prefill_padding", 0)
             m_prefix.inc(hit - last["hit"])
-            d_prop = self.engine.spec_proposed - last["prop"]
-            d_acc = self.engine.spec_accepted - last["acc"]
-            m_sprop.inc(d_prop)
-            m_sacc.inc(d_acc)
-            m_sprop_t.inc(d_prop)
-            m_sacc_t.inc(d_acc)
-            for reason, n in getattr(self.engine, "spec_fallbacks", {}).items():
-                prev = last["fb"].get(reason, 0)
-                if n > prev:
-                    SPEC_FALLBACKS.labels(replica=R, reason=reason).inc(n - prev)
-                    last["fb"][reason] = n
             m_ptok.inc(ptok - last["packed_tok"])
             m_ppad.inc(ppad - last["packed_pad"])
             reaps = self.engine.deadline_reaps
@@ -296,9 +272,7 @@ class AsyncEngine:
 
                 ENGINE_PREEMPT_RESUMES.labels(replica=R).inc(
                     res - last["resumes"])
-            last.update(hit=hit, prop=self.engine.spec_proposed,
-                        acc=self.engine.spec_accepted,
-                        packed_tok=ptok, packed_pad=ppad, reaps=reaps,
+            last.update(hit=hit, packed_tok=ptok, packed_pad=ppad, reaps=reaps,
                         kv_fault=fi, kv_wb=wb, kv_dedup=dd, kv_hold=hold,
                         kv_mig_s=mig_s, xfer_s=xfer_s, preempts=pre,
                         resumes=res)
@@ -420,8 +394,6 @@ class AsyncEngine:
                     if decoded > 0 and res.decode_time_s > 0:
                         tpot = res.decode_time_s / decoded
                         m_tpot.observe(tpot)
-                    if res.spec_proposed > 0:
-                        m_saccept.observe(res.spec_accepted / res.spec_proposed)
                     self.slo.observe(
                         self._priority.pop(res.request_id, None) or "interactive",
                         ttft_s=res.ttft_s, tpot_s=tpot,
@@ -627,16 +599,6 @@ class AsyncEngine:
                 "sp_prefills": getattr(self.engine, "sp_prefills", 0),
                 "sp_ring_segments": getattr(self.engine, "sp_ring_segments", 0),
                 "sp_ring_tokens": getattr(self.engine, "sp_ring_tokens", 0),
-                "spec_proposed": self.engine.spec_proposed,
-                "spec_accepted": self.engine.spec_accepted,
-                # rate-suffixed: MultiAsyncEngine.stats() averages this
-                # across replicas instead of summing it
-                "spec_acceptance_rate": (
-                    self.engine.spec_accepted / max(1, self.engine.spec_proposed)
-                ),
-                "spec_fallbacks": sum(
-                    getattr(self.engine, "spec_fallbacks", {}).values()
-                ),
                 "deadline_reaps": self.engine.deadline_reaps,
                 "kv_host_pages": getattr(self.engine._allocator, "host_pages", 0),
                 "kv_fault_ins": getattr(self.engine._allocator, "fault_ins", 0),

@@ -17,11 +17,9 @@ aliveness, breaker state) and walks a guarded action ladder:
                                                 snapshot into a warm
                                                 spare, activate it, and
                                                 force-retire the corpse
-    limiter == hbm_pages                     -> grow the host KV pool cap,
-                                                or shift the spec-k ladder
-                                                down once the pool is
-                                                capped (both pre-warmed:
-                                                no new XLA shapes)
+    limiter == hbm_pages                     -> grow the host KV pool cap
+                                                (no new XLA shapes), until
+                                                the pool is capped
     limiter == swap_wait                     -> halve the router's affinity
                                                 load-slack so prefix-hot
                                                 tenants spread across
@@ -72,7 +70,7 @@ from githubrepostorag_tpu.utils.logging import get_logger
 logger = get_logger(__name__)
 
 # ladder rungs, highest severity first (decision order per replica)
-ACTIONS = ("failover", "grow_host_pool", "spec_k_down", "spread_affinity")
+ACTIONS = ("failover", "grow_host_pool", "spread_affinity")
 
 _LOG_RING = 64
 
@@ -243,11 +241,8 @@ class FleetController:
                 desired.append((rid, "failover", "breaker_open", d))
             elif burn.get("state") == "critical":
                 desired.append((rid, "failover", "burn_critical", d))
-            elif ledger.get("limiter") == "hbm_pages":
-                action = ("grow_host_pool"
-                          if self._can_grow_host_pool(rid)
-                          else "spec_k_down")
-                desired.append((rid, action, "hbm_pages", d))
+            elif ledger.get("limiter") == "hbm_pages" and self._can_grow_host_pool(rid):
+                desired.append((rid, "grow_host_pool", "hbm_pages", d))
             elif ledger.get("limiter") == "swap_wait":
                 desired.append((rid, "spread_affinity", "swap_wait", d))
 
@@ -331,9 +326,6 @@ class FleetController:
         elif action == "grow_host_pool":
             detail = self._act_grow_host_pool(rid)
             status = "ok"
-        elif action == "spec_k_down":
-            detail = self._act_spec_k_down(rid)
-            status = "ok"
         elif action == "spread_affinity":
             detail = self._act_spread_affinity()
             status = "ok"
@@ -415,24 +407,6 @@ class FleetController:
             new = min(cap, max(cur + 1, int(cur * self.host_pool_grow)))
             alloc.host_pool_pages = new
             return {"host_pool_pages": {"from": cur, "to": new, "cap": cap}}
-        finally:
-            ae._lock.release()
-
-    def _act_spec_k_down(self, replica: str) -> dict:
-        """hbm_pages remediation, rung 2: drop the top spec-k ladder rung
-        so speculative bursts commit fewer pages per dispatch.  Every
-        remaining rung was compiled by warmup, so the shift is free."""
-        ae = self._multi._by_id[replica]
-        if not ae._lock.acquire(timeout=1.0):
-            raise RuntimeError(f"driver lock on {replica} busy; retry next tick")
-        try:
-            engine = ae.engine
-            ladder = getattr(engine, "_spec_k_ladder", None)
-            if not ladder or len(ladder) <= 1:
-                return {"noop": "spec-k ladder already at its floor"}
-            removed = ladder.pop()
-            engine.spec_k = ladder[-1]
-            return {"spec_k": {"removed_rung": removed, "top": ladder[-1]}}
         finally:
             ae._lock.release()
 

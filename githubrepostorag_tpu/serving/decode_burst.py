@@ -201,8 +201,8 @@ def decode_burst(
     quant = k_scales is not None
     # int4 pools (uint8, kv_cache.pack_int4): the staged kernel reads int8
     # pages natively but has no nibble path — bursts over int4 pages take
-    # the gather fallback, whose gather_kv unpacks and dequantizes.  The
-    # fused step path (serving/fused_step.py) is the int4 hot path.
+    # the gather fallback, whose gather_kv unpacks and dequantizes: no
+    # program of the engine reads int4 pages in a kernel (ROADMAP D14).
     use_pallas = use_pallas and k_pages.dtype != jnp.uint8
     # staged tail stays full precision even over int8 pools — it is tiny
     # (MBs) and fresh tokens re-read every step; only the committed pages

@@ -51,7 +51,6 @@ import numpy as np
 
 from githubrepostorag_tpu.models.qwen2 import (
     Qwen2Config,
-    forward_paged,
     forward_paged_packed,
     forward_paged_wave,
 )
@@ -110,13 +109,6 @@ class GenerationResult:
     # token off the stream queue; AsyncEngine.stream adds it), done_t —
     # obs/engine_profile.record_engine_spans turns them into spans
     timings: dict | None = None
-    # draft-model speculation accounting: tokens the draft proposed for
-    # this request, tokens the target accepted, and the sticky fallback
-    # reason if the adaptive controller demoted the request to plain
-    # decode ("acceptance" | "deadline" | None)
-    spec_proposed: int = 0
-    spec_accepted: int = 0
-    spec_fallback: str | None = None
     # KV tiering: prefix pages this request re-admitted from the host tier
     # instead of recomputing (0 on untiered engines)
     faulted_pages: int = 0
@@ -155,13 +147,6 @@ class _Request:
     # caller that stopped waiting
     deadline_ts: float | None = None
     deadline_expired: bool = False
-    # draft-model speculation controller state (engine.spec path): EMA of
-    # per-dispatch acceptance rate drives the k ladder; a sticky fallback
-    # reason demotes the request to plain decode for the rest of its life
-    spec_accept_ema: float | None = None
-    spec_fallback: str | None = None
-    spec_proposed_req: int = 0
-    spec_accepted_req: int = 0
     # KV tiering: chain hashes this admission promised to register (the
     # pending-claim dedup contract — released claims unblock followers)
     # and prefix pages served by host->device fault-in
@@ -285,38 +270,6 @@ class Engine:
         # compiled ring programs, more padding on small passes;
         # sp_ring_bucket_ladder() is the single source of truth warmup
         # and dispatch both read.
-        spec_ngram_k: int = 0,  # >0: n-gram speculative decoding with drafts
-        # of up to k tokens: ``spec_iters`` draft->verify->accept rounds in
-        # ONE device program (serving/spec_burst.py) whenever every running
-        # row is plain greedy; any other step decodes plainly
-        fused_step: bool = False,  # FUSED_STEP: one compiled program per
-        # engine step (serving/fused_step.py) — the packed prefill wave
-        # and a MIXED spec/plain decode burst dispatch together, so
-        # greedy rows keep their verify windows even when sampled rows
-        # share the batch (the unfused all-greedy gate demotes such
-        # batches to plain decode).  Requires spec_ngram_k > 0,
-        # prefill_token_budget set, no draft model
-        # and no prefill_priority (a skipped decode step would orphan
-        # the deferred prefill wave).
-        draft_params: dict | None = None,  # DRAFT-MODEL speculation (the
-        # default serving path when set — SPEC_DRAFT_MODEL): a second,
-        # small model drafts k tokens autoregressively on its own KV
-        # pages, the target verifies all k+1 positions in one forward,
-        # and the longest agreed prefix + correction token commits —
-        # greedy-token-identical to plain decode (serving/draft_spec.py).
-        # Mutually exclusive with spec_ngram_k.
-        draft_cfg: Qwen2Config | None = None,
-        spec_k: int = 4,  # max draft length; the adaptive controller picks
-        # each dispatch's k from the power-of-two ladder [1, 2, ..., spec_k]
-        # (warmup precompiles every rung) driven by EMA acceptance
-        spec_iters: int = 4,  # fused draft/verify/accept rounds per dispatch
-        spec_accept_floor: float = 0.35,  # a request whose EMA acceptance
-        # rate drops below this falls back to plain decode_burst for the
-        # rest of its life (sticky) — speculation that mostly misses costs
-        # a draft pass + a wider verify for ~1 token/round
-        spec_deadline_margin_s: float = 0.25,  # requests within this margin
-        # of their propagated deadline also fall back: the burst-sized
-        # spec dispatch has coarser stop granularity than plain decode
         preempt: str = "auto",  # page-granularity preempt-to-host: park a
         # batch-class victim's KV pages in the host tier (priority
         # writeback) so a protected-class admission can proceed, and
@@ -358,8 +311,7 @@ class Engine:
             unsupported = {
                 "mesh": mesh is not None, "kv_quant": bool(quant_bits(kv_quant)),
                 "prefill_token_budget": prefill_token_budget is not None,
-                "spec_ngram_k": spec_ngram_k > 0, "draft_params": draft_params is not None,
-                "fused_step": fused_step, "sp_prefill_threshold": sp_prefill_threshold is not None,
+                "sp_prefill_threshold": sp_prefill_threshold is not None,
                 "kv_tier": kv_tier == "on" or kv_host_pool_pages > 0,
                 "preempt": preempt == "on",  # parks pages in the host tier
                 # a snapshot lies at a page boundary between two blocks of a chunk
@@ -534,106 +486,7 @@ class Engine:
                 "sp prefill: threshold=%d tokens over sp=%d (segment-packed, ladder %s)",
                 sp_prefill_threshold, self._sp, self.sp_ring_bucket_ladder(),
             )
-        self.spec_ngram_k = spec_ngram_k
-        if fused_step:
-            # fail fast on inert/unsafe combos rather than silently
-            # falling back: the fused step IS the serving mode the
-            # operator asked for
-            if spec_ngram_k <= 0:
-                raise ValueError(
-                    "fused_step requires spec_ngram_k > 0 (FUSED_STEP fuses "
-                    "the n-gram spec burst with packed prefill)"
-                )
-            if prefill_token_budget is None:
-                raise ValueError(
-                    "fused_step requires prefill_token_budget (the fused "
-                    "program's prefill phase is the packed segment grid)"
-                )
-            if draft_params is not None:
-                raise ValueError(
-                    "fused_step and draft-model speculation are mutually "
-                    "exclusive; unset SPEC_DRAFT_MODEL or FUSED_STEP"
-                )
-            if prefill_priority:
-                raise ValueError(
-                    "fused_step is incompatible with prefill_priority: a "
-                    "prefill-priority step skips decode, which would "
-                    "orphan the deferred prefill wave"
-                )
-        self.fused_step_on = bool(fused_step)
-        # fixed segment-row bucket of the fused program's prefill phase:
-        # the largest packed bucket, so the compiled fused-variant set is
-        # (decode row bucket) x (has_prefill) x (filter_sampling) — wave
-        # composition never mints a new prefill shape mid-traffic
-        self._fused_pf_segs = (
-            self.packed_prefill_buckets()[-1] if self.fused_step_on else 0
-        )
-        self._fused_pf_wave: dict | None = None  # deferred packed wave
-        self.fused_steps_total = 0  # stats: fused single-dispatch steps
         self.step_dispatches_total = 0  # stats: main-model programs issued
-
-        # ---- draft-model speculation (the default serving path when a
-        # draft is configured — serving/draft_spec.py) ----
-        if (draft_params is None) != (draft_cfg is None):
-            raise ValueError("draft_params and draft_cfg must be set together")
-        if draft_params is not None and spec_ngram_k > 0:
-            raise ValueError(
-                "draft-model speculation and n-gram speculation are mutually "
-                "exclusive; unset SPEC_NGRAM_K or SPEC_DRAFT_MODEL"
-            )
-        self._draft_enabled = draft_params is not None
-        self.draft_cfg = draft_cfg
-        self.spec_k = spec_k
-        self.spec_iters = spec_iters
-        self.spec_accept_floor = spec_accept_floor
-        self.spec_deadline_margin_s = spec_deadline_margin_s
-        self.draft_params = None
-        self._dk_pages = self._dv_pages = None
-        self._force_plain = False  # warmup hook: route through _decode_step
-        self._spec_k_ladder: list[int] = []
-        if (self._draft_enabled or spec_ngram_k > 0) and spec_iters < 1:
-            raise ValueError("spec_iters must be >= 1")
-        if self._draft_enabled:
-            if draft_cfg.vocab_size != cfg.vocab_size:
-                # accept/verify compares token IDs across the two models —
-                # they must share a vocabulary (ROADMAP pairs same-family
-                # Qwen2 checkpoints)
-                raise ValueError(
-                    f"draft vocab {draft_cfg.vocab_size} != target vocab "
-                    f"{cfg.vocab_size}; draft and target must share a tokenizer"
-                )
-            if spec_k < 1:
-                raise ValueError("spec_k must be >= 1")
-            if mesh is not None:
-                # the draft is small: replicate rather than shard (its
-                # head counts need not divide tp, and replicated weights
-                # keep the inner autoregressive scan communication-free)
-                self.draft_params = jax.device_put(draft_params, self._replicated)
-            else:
-                from githubrepostorag_tpu.models.quant import fuse_projections
-
-                self.draft_params = fuse_projections(draft_params)
-            # the draft's own KV pages, indexed by the SAME block tables as
-            # the target (one allocator, two pools) — never quantized
-            dpools = make_page_pools(draft_cfg, num_pages, page_size,
-                                     dtype=kv_dtype, quant=False)
-            self._dk_pages, self._dv_pages = dpools.k, dpools.v
-            if mesh is not None:
-                self._dk_pages = jax.device_put(self._dk_pages, self._replicated)
-                self._dv_pages = jax.device_put(self._dv_pages, self._replicated)
-            # power-of-two k ladder, largest rung = spec_k: warmup compiles
-            # one program per (rung, row bucket); the controller only ever
-            # dispatches at a rung, so live traffic can't mint new shapes
-            rung = 1
-            while rung < spec_k:
-                self._spec_k_ladder.append(rung)
-                rung *= 2
-            self._spec_k_ladder.append(spec_k)
-            self._spec_k_ladder = sorted(set(self._spec_k_ladder))
-
-        self.spec_proposed = 0  # stats: draft tokens offered / accepted
-        self.spec_accepted = 0
-        self.spec_fallbacks: dict[str, int] = {}  # fallback counts by reason
         self.requests_admitted = 0  # cumulative add_request count
         self.deadline_reaps = 0  # requests reaped past their deadline
 
@@ -673,7 +526,6 @@ class Engine:
         self.admission_blocked_steps = 0  # steps with waiters the pool couldn't admit
         self.prefill_seconds_total = 0.0
         self.decode_seconds_total = 0.0
-        self.spec_verify_seconds_total = 0.0
 
         # host-side batch state
         self._block_tables = np.zeros((max_num_seqs, self.max_pages_per_seq), dtype=np.int32)
@@ -775,18 +627,6 @@ class Engine:
             from githubrepostorag_tpu.serving.long_prefill import ring_prefill_packed
 
             programs.append(ring_prefill_packed)
-        if self.fused_step_on:
-            from githubrepostorag_tpu.serving.fused_step import fused_step_burst
-
-            programs.append(fused_step_burst)
-        elif self.spec_ngram_k > 0:
-            from githubrepostorag_tpu.serving.spec_burst import spec_decode_burst
-
-            programs.append(spec_decode_burst)
-        if self._draft_enabled:
-            from githubrepostorag_tpu.serving.draft_spec import draft_spec_burst
-
-            programs.append(draft_spec_burst)
         return programs
 
     # ------------------------------------------------------- host phases --
@@ -998,42 +838,20 @@ class Engine:
             running = []
         if running:
             t_run = time.monotonic()
-            path = self._decode_path(running)
+            # the one decode path, looked up on the instance at each call (the
+            # benchmark's probe wraps it there).  ``path`` and ``dt`` also keep
+            # this frame the size it had: see tests/test_engine.py::
+            # test_the_frames_under_a_step_programs_first_call_keep_their_size
+            path = self._decode_step
             path(finished)
             dt = time.monotonic() - t_run
-            if path == self._decode_step:
-                self.decode_seconds_total += dt
-            else:
-                self.spec_verify_seconds_total += dt
+            self.decode_seconds_total += dt
         if not self._row_req:
             # nothing left running: land any in-flight burst (its tokens
             # belong to already-finished rows) and recycle deferred pages
             self._drain_chain(finished)
         self._phase(None)
         return finished
-
-    def _decode_path(self, running: list[_Request]):
-        """The decode program this step's running rows take: the one place
-        the choice is made (``step`` calls what it returns; ``warmup`` and
-        the seconds booked to speculation read it).  Speculation is all or
-        nothing per step: one row that cannot ride the speculative burst
-        (sampled, penalised, fallen back) demotes the whole dispatch to
-        plain decode — the mix is per step, not sticky.  The fused step
-        alone takes mixed batches whole (serving/fused_step.py)."""
-        if self._force_plain:
-            return self._decode_step
-        if self.fused_step_on:
-            return self._fused_step
-        if self._draft_enabled:
-            # every row is asked: a demotion is sticky and counted
-            spec, ok = self._draft_spec_step, [self._spec_capable(r) for r in running]
-        elif self.spec_ngram_k > 0:
-            spec, ok = self._spec_burst_step, [
-                r.sampling.temperature <= 0.0 and r.sampling.repetition_penalty == 1.0
-                for r in running]
-        else:
-            return self._decode_step
-        return spec if all(ok) else self._decode_step
 
     def _reap_expired(self) -> None:
         """Mark past-deadline requests cancelled so the cancel/reap path
@@ -1297,15 +1115,7 @@ class Engine:
             k, v, ks, vs = gather_pages(
                 self._k_pages, self._v_pages, idx, self._k_scales, self._v_scales
             )
-            dk = dv = None
-            if self._draft_enabled:
-                # draft pools share page indices with the target pools — a
-                # faulted-in page must restore BOTH, or drafting on the
-                # re-admitted row would propose from another request's KV
-                # (verify keeps outputs token-identical, but acceptance
-                # would silently collapse)
-                dk, dv, _, _ = gather_pages(self._dk_pages, self._dv_pages, idx)
-            bufs = (k, v, ks, vs, dk, dv)
+            bufs = (k, v, ks, vs)
             for arr in bufs:
                 if arr is not None and hasattr(arr, "copy_to_host_async"):
                     arr.copy_to_host_async()
@@ -1343,23 +1153,14 @@ class Engine:
             v_vals = np.zeros_like(k_vals)
             ks_vals = np.zeros((L, n_kv, nb), dtype=np.float32) if quant else None
             vs_vals = np.zeros((L, n_kv, nb), dtype=np.float32) if quant else None
-            dk_vals = dv_vals = None
-            if self._draft_enabled:
-                dshape = (self.draft_cfg.num_layers, self.draft_cfg.num_kv_heads,
-                          nb, ps, self.draft_cfg.head_dim)
-                dk_vals = np.zeros(dshape, dtype=self._dk_pages.dtype)
-                dv_vals = np.zeros(dshape, dtype=self._dv_pages.dtype)
             for i, (page, payload) in enumerate(burst):
-                pk, pv, pks, pvs, pdk, pdv = payload
+                pk, pv, pks, pvs = payload
                 idx[i] = page
                 k_vals[:, :, i] = pk
                 v_vals[:, :, i] = pv
                 if quant:
                     ks_vals[:, :, i] = pks
                     vs_vals[:, :, i] = pvs
-                if dk_vals is not None and pdk is not None:
-                    dk_vals[:, :, i] = pdk
-                    dv_vals[:, :, i] = pdv
             idx_d = jnp.asarray(idx)
             (self._k_pages, self._v_pages, self._k_scales,
              self._v_scales) = scatter_pages(
@@ -1369,11 +1170,6 @@ class Engine:
                 ks_vals=None if ks_vals is None else jnp.asarray(ks_vals),
                 vs_vals=None if vs_vals is None else jnp.asarray(vs_vals),
             )
-            if dk_vals is not None:
-                self._dk_pages, self._dv_pages, _, _ = scatter_pages(
-                    self._dk_pages, self._dv_pages, idx_d,
-                    jnp.asarray(dk_vals), v_vals=jnp.asarray(dv_vals),
-                )
             self.kv_fault_dispatches += 1
         self.fault_in_seconds_total += time.monotonic() - t0
 
@@ -1427,13 +1223,7 @@ class Engine:
             k, v, ks, vs = gather_pages(
                 self._k_pages, self._v_pages, idx, self._k_scales, self._v_scales
             )
-            dk = dv = None
-            if self._draft_enabled:
-                # ship the draft pools too: the decode replica's draft KV
-                # must cover the prompt or speculation there would propose
-                # from uninitialized pages (see _migrate_pages)
-                dk, dv, _, _ = gather_pages(self._dk_pages, self._dv_pages, idx)
-            payloads = split_page_payloads((k, v, ks, vs, dk, dv), len(burst))
+            payloads = split_page_payloads((k, v, ks, vs), len(burst))
             out.extend((h, p) for (h, _), p in zip(burst, payloads))
         self.kv_pages_exported += len(out)
         self.transfer_seconds_total += time.monotonic() - t0
@@ -1485,32 +1275,22 @@ class Engine:
         the scheduler routes by — one predicate, no drift."""
         return (
             self.sp_prefill_threshold is not None
-            and not self._draft_enabled
             and self._sp > 1
             and prompt_len >= self.sp_prefill_threshold
         )
 
     def _sp_eligible(self, req: _Request) -> bool:
         """Long prompts take the sequence-parallel ring-prefill path: the
-        whole prompt in one program, attention sharded over sp.  Disabled
-        under draft-model speculation: ring prefill writes only target KV,
-        and a row whose draft cache is missing its prompt could never
-        speculate (the chunked path runs every chunk through both models)."""
+        whole prompt in one program, attention sharded over sp."""
         return self.is_longctx(len(req.prompt))
 
     def _commit_first_now(self, others_running: bool) -> bool:
         """Whether a freshly-prefilled row's first token commits with an
         immediate host sync (best TTFT) instead of queueing on device into
         ``_pending_first`` for the next decode dispatch.  The single source
-        of truth for all three prefill paths:
-          - speculation (n-gram or draft model) is synchronous by design,
-            but a plain-decode chain may be in flight (mixed-batch/fallback
-            steps pipeline) and its stale device state must not race a
-            fresh commit -> commit only when no chain is live;
-          - plain decode additionally defers whenever other rows are
-            running, so admissions never stall streams on a host sync."""
-        if self.spec_ngram_k > 0 or self._draft_enabled:
-            return self._chain is None
+        of truth for all three prefill paths: a chain in flight holds stale
+        device state that must not race a fresh commit, and while other
+        rows are running an admission never stalls streams on a host sync."""
         return self._chain is None and not others_running
 
     def _dispatch_width(self, longest_chunk: int, rows: int) -> int:
@@ -1885,24 +1665,6 @@ class Engine:
                 self._moe_dispatched("prefill", cache.pop(), 1)
                 wave_ann.set_metadata(**self._moe_meta("prefill"))
             self._k_pages, self._v_pages = cache
-        if self._draft_enabled:
-            # the draft model prefills the SAME chunk into its own pools
-            # (same slots/block tables — the pools are position-aligned by
-            # construction), so decode-time drafting always has the full
-            # prompt in its cache.  Logits are discarded; the call exists
-            # for its KV writes.  It runs the whole chunk width: one warm
-            # program per row bucket, like the wave.
-            self.step_dispatches_total += 1
-            with annotate("engine.prefill_batch_draft"):
-                _, self._dk_pages, self._dv_pages = forward_paged(
-                    self.draft_params, self.draft_cfg,
-                    ids, pos,
-                    self._dk_pages, self._dv_pages,
-                    slots, bt,
-                    cached, new_lens,
-                    use_pallas=self.use_pallas, logits_at=last_idx,
-                    int4_kernel=self._int4_kernel,
-                )
 
         done: list[_Request] = []
         for i, req in enumerate(reqs):
@@ -2008,17 +1770,6 @@ class Engine:
         exactly one program per bucket in packed_prefill_buckets() —
         warmup() compiles each, live traffic adds none."""
         others_running = any(r.state == "running" for r in self._row_req.values())
-        if self.fused_step_on and others_running:
-            # decode rows are live: DEFER this wave — step()'s decode
-            # branch fuses it into the same compiled program as the burst
-            # (serving/fused_step.py _fused_step), always at the fixed
-            # ``_fused_pf_segs`` segment bucket so wave composition never
-            # mints a new fused shape.  All bookkeeping (advance,
-            # presence, first tokens) runs after that single dispatch.
-            self._fused_pf_wave = self._build_packed_wave(
-                reqs, rb=self._fused_pf_segs
-            )
-            return
         meta = self._build_packed_wave(reqs)
 
         ids_d, pos_d = jnp.asarray(meta["ids"]), jnp.asarray(meta["pos"])
@@ -2045,32 +1796,14 @@ class Engine:
                  self._k_scales, self._v_scales) = out
             else:
                 logits, self._k_pages, self._v_pages = out
-        if self._draft_enabled:
-            # mirror the packed chunk into the draft pools (see
-            # _prefill_batch) — same packed buffer, same segment IDs
-            self.step_dispatches_total += 1
-            with annotate("engine.prefill_packed_draft"):
-                _, self._dk_pages, self._dv_pages = forward_paged_packed(
-                    self.draft_params, self.draft_cfg,
-                    ids_d, pos_d,
-                    self._dk_pages, self._dv_pages,
-                    slots_d, bt_d,
-                    cached_d, new_lens_d,
-                    seg_d, last_idx_d,
-                    tq=tq, use_pallas=self.use_pallas,
-                    int4_kernel=self._int4_kernel,
-                )
         self._finish_packed_wave(meta, logits, finished, others_running)
 
-    def _build_packed_wave(
-        self, reqs: list[_Request], rb: int | None = None
-    ) -> dict:
+    def _build_packed_wave(self, reqs: list[_Request]) -> dict:
         """Greedy-pack the prefilling rows' next chunks into the [budget]
-        token buffer and build every host array the packed program needs.
-        ``rb`` pins the segment-row bucket (the fused step always builds
-        at ``_fused_pf_segs``); None buckets the actual segment count.
-        Pure array construction — the caller dispatches and then runs
-        ``_finish_packed_wave`` for the bookkeeping."""
+        token buffer and build every host array the packed program needs,
+        at the segment count's row bucket.  Pure array construction — the
+        caller dispatches and then runs ``_finish_packed_wave`` for the
+        bookkeeping."""
         budget = self.prefill_token_budget
         tq = self.packed_chunk
         packed: list[tuple[_Request, int]] = []  # (request, tokens granted)
@@ -2082,8 +1815,7 @@ class Engine:
             packed.append((req, share))
             used += share
         n = len(packed)
-        if rb is None:
-            rb = _bucket(n, self.max_num_seqs, minimum=1)
+        rb = _bucket(n, self.max_num_seqs, minimum=1)
 
         ids = np.zeros((1, budget), dtype=np.int32)
         pos = np.zeros((1, budget), dtype=np.int32)
@@ -2133,9 +1865,7 @@ class Engine:
     ) -> None:
         """Post-dispatch bookkeeping for a packed prefill wave: presence
         marks, per-request advance/page registration, and first-token
-        sampling for rows whose prompt completed.  Shared verbatim between
-        the standalone packed dispatch and the fused step (which runs it
-        on the fused program's returned prefill logits)."""
+        sampling for rows whose prompt completed."""
         packed, rb = meta["packed"], meta["rb"]
         row_d = jnp.asarray(meta["row_idx"])
         self._presence = _mark_presence_chunks(
@@ -2397,334 +2127,6 @@ class Engine:
         if prev is not None:
             self._commit_burst(prev, finished)
 
-    def _spec_burst_step(self, finished: list[GenerationResult]) -> None:
-        """``spec_iters`` fused n-gram draft/verify/accept iterations in
-        ONE dispatch (serving/spec_burst.py), for all-plain-greedy batches.
-        One [B, iters, k+1] token fetch per burst; stop/length bookkeeping
-        happens here on the packed tokens, like _commit_burst."""
-        from githubrepostorag_tpu.serving.spec_burst import spec_decode_burst
-
-        if self._chain is not None or self._pending_first:
-            # a plain-decode chain (a mixed batch's steps pipeline) is in
-            # flight: land it so the history/lens snapshot below sees every
-            # committed token
-            self._drain_chain(finished)
-        self._phase("engine.burst_prepare")
-        k = self.spec_ngram_k
-        running = [r for r in self._row_req.values() if r.state == "running"]
-        if not running:
-            return
-        rb = _bucket(len(running), self.max_num_seqs, minimum=1)
-        h = self.max_seq_len
-        hist = np.zeros((rb, h), dtype=np.int32)
-        hlens = np.zeros((rb,), dtype=np.int32)
-        lens = np.zeros((rb,), dtype=np.int32)
-        bt = np.zeros((rb, self.max_pages_per_seq), dtype=np.int32)
-        limits = np.zeros((rb,), dtype=np.int32)
-        active = np.zeros((rb,), dtype=bool)
-        for i, req in enumerate(running):
-            toks = (req.prompt + req.output)[-h:]
-            hist[i, : len(toks)] = toks
-            hlens[i] = len(toks)
-            lens[i] = req.seq_len
-            bt[i] = self._block_tables[req.row]
-            limits[i] = self._row_limits[req.row]
-            active[i] = True
-
-        self.step_dispatches_total += 1
-        with annotate("engine.spec_burst"):
-            # tpulint: disable=SHP002 -- warmup's greedy waves reach this through the bound method _decode_path returns, an edge the call graph does not follow; tests/test_spec_decode.py holds live traffic to zero compiles after warmup
-            out = spec_decode_burst(
-                self.params, self.cfg,
-                jnp.asarray(hist), jnp.asarray(hlens), jnp.asarray(lens),
-                self._k_pages, self._v_pages,
-                jnp.asarray(bt), jnp.asarray(limits), jnp.asarray(active),
-                n_iters=self.spec_iters, k=k,
-                use_pallas=self.use_pallas, int4_kernel=self._int4_kernel,
-                k_scales=self._k_scales, v_scales=self._v_scales,
-            )
-        if self.kv_quant:
-            (toks_d, prop_d, self._k_pages, self._v_pages,
-             self._k_scales, self._v_scales) = out
-        else:
-            toks_d, prop_d, self._k_pages, self._v_pages = out
-        self._phase("engine.commit_fetch")
-        toks = np.asarray(toks_d)  # [rb, iters, k+1], -1 padded
-        prop = np.asarray(prop_d)  # [rb, iters]
-        self._phase("engine.commit_host")
-        for i, req in enumerate(running):
-            for it in range(toks.shape[1]):
-                if req.state != "running":
-                    break  # the device kept drafting past this row's stop;
-                    # those iterations' tokens AND proposals are discarded
-                self.spec_proposed += int(prop[i, it])
-                committed = 0
-                for t in toks[i, it]:
-                    if t < 0 or req.state != "running":
-                        break
-                    req.seq_len += 1
-                    self._seq_lens[req.row] = req.seq_len
-                    self._commit_token(req, int(t), finished)
-                    committed += 1
-                if committed:
-                    # committed = agreed draft prefix + 1 correction token
-                    self.spec_accepted += committed - 1
-
-    def _fused_step(self, finished: list[GenerationResult]) -> None:
-        """ONE compiled program for the whole step (serving/fused_step.py):
-        the packed prefill wave _prefill_batch_packed deferred (if any)
-        runs as phase A, then ``spec_iters`` MIXED decode iterations
-        — greedy rows draft/verify/accept exactly like _spec_burst_step
-        (token-identical by construction), sampled rows draw one on-device
-        token per iteration from the same forward instead of demoting the
-        batch to plain decode.  Commit bookkeeping stays host-side on the
-        returned token block; the deferred wave's bookkeeping
-        (_finish_packed_wave) runs on the returned prefill logits, so rows
-        finishing prefill join the NEXT step's burst."""
-        from githubrepostorag_tpu.serving.fused_step import fused_step_burst
-
-        self._phase("engine.burst_prepare")
-        k = self.spec_ngram_k
-        running = [r for r in self._row_req.values() if r.state == "running"]
-        rb = _bucket(len(running), self.max_num_seqs, minimum=1)
-        h = self.max_seq_len
-        hist = np.zeros((rb, h), dtype=np.int32)
-        hlens = np.zeros((rb,), dtype=np.int32)
-        lens = np.zeros((rb,), dtype=np.int32)
-        bt = np.zeros((rb, self.max_pages_per_seq), dtype=np.int32)
-        limits = np.zeros((rb,), dtype=np.int32)
-        active = np.zeros((rb,), dtype=bool)
-        spec_ok = np.zeros((rb,), dtype=bool)
-        row_idx = np.zeros((rb,), dtype=np.int32)
-        for i, req in enumerate(running):
-            toks = (req.prompt + req.output)[-h:]
-            hist[i, : len(toks)] = toks
-            hlens[i] = len(toks)
-            lens[i] = req.seq_len
-            bt[i] = self._block_tables[req.row]
-            limits[i] = self._row_limits[req.row]
-            active[i] = True
-            spec_ok[i] = (req.sampling.temperature <= 0.0
-                          and req.sampling.repetition_penalty == 1.0)
-            row_idx[i] = req.row
-        pf_wave = self._fused_pf_wave
-        self._fused_pf_wave = None
-        has_prefill = pf_wave is not None
-        if has_prefill:
-            pf = (
-                jnp.asarray(pf_wave["ids"]), jnp.asarray(pf_wave["pos"]),
-                jnp.asarray(pf_wave["slots"]), jnp.asarray(pf_wave["bt"]),
-                jnp.asarray(pf_wave["cached"]),
-                jnp.asarray(pf_wave["new_lens"]),
-                jnp.asarray(pf_wave["seg"]), jnp.asarray(pf_wave["last_idx"]),
-            )
-        else:
-            pf = (None,) * 8
-
-        self._push_sampling()
-        self._rng, key = jax.random.split(self._rng)
-        row_d = jnp.asarray(row_idx)
-        # same per-burst sampler-variant rule as _decode_step: sort-free
-        # whenever no sampling row filters
-        filter_sampling = bool(
-            np.any(
-                (self._temp > 0.0)
-                & ((self._top_p < 1.0) | (self._top_k > 0))
-            )
-        )
-        self.fused_steps_total += 1
-        self.step_dispatches_total += 1
-        with annotate("engine.fused_step"):
-            out = fused_step_burst(
-                self.params, self.cfg,
-                jnp.asarray(hist), jnp.asarray(hlens), jnp.asarray(lens),
-                self._k_pages, self._v_pages,
-                jnp.asarray(bt), jnp.asarray(limits), jnp.asarray(active),
-                jnp.asarray(spec_ok), row_d, self._presence, key,
-                self._temp_d[row_d], self._top_p_d[row_d],
-                self._top_k_d[row_d], self._rep_pen_d[row_d],
-                *pf,
-                n_iters=self.spec_iters, k=k, tq=self.packed_chunk,
-                use_pallas=self.use_pallas, int4_kernel=self._int4_kernel,
-                filter_sampling=filter_sampling, has_prefill=has_prefill,
-                k_scales=self._k_scales, v_scales=self._v_scales,
-            )
-        if self.kv_quant:
-            (toks_d, prop_d, pf_logits, self._k_pages, self._v_pages,
-             self._presence, self._k_scales, self._v_scales) = out
-        else:
-            (toks_d, prop_d, pf_logits, self._k_pages, self._v_pages,
-             self._presence) = out
-        if has_prefill:
-            # deferred-wave bookkeeping: presence marks, advance, first
-            # tokens (the fused step keeps no chain, so _commit_first_now
-            # holds and first tokens commit synchronously)
-            self._finish_packed_wave(pf_wave, pf_logits, finished, True)
-        self._phase("engine.commit_fetch")
-        toks = np.asarray(toks_d)  # [rb, iters, k+1], -1 padded
-        prop = np.asarray(prop_d)  # [rb, iters] — 0 on sampled rows
-        self._phase("engine.commit_host")
-        for i, req in enumerate(running):
-            for it in range(toks.shape[1]):
-                if req.state != "running":
-                    break  # device drafted past this row's stop; discard
-                self.spec_proposed += int(prop[i, it])
-                committed = 0
-                for t in toks[i, it]:
-                    if t < 0 or req.state != "running":
-                        break
-                    req.seq_len += 1
-                    self._seq_lens[req.row] = req.seq_len
-                    self._commit_token(req, int(t), finished)
-                    committed += 1
-                if committed and spec_ok[i]:
-                    # committed = agreed draft prefix + 1 correction token
-                    self.spec_accepted += committed - 1
-
-    # ------------------------------------------- draft-model speculation --
-
-    def _spec_capable(self, req: _Request) -> bool:
-        """Whether this request may ride the draft-model spec burst this
-        step.  Sampling rows are simply ineligible (greedy-only path —
-        sampled parity would need rejection sampling); acceptance-collapse
-        and deadline-pressure demotions are STICKY and counted, because
-        re-probing a request the controller already gave up on would pay
-        the failed-speculation tax again every probe."""
-        if req.spec_fallback is not None:
-            return False
-        sp = req.sampling
-        if sp.temperature > 0.0 or sp.repetition_penalty != 1.0:
-            return False
-        if (
-            req.spec_accept_ema is not None
-            and req.spec_accept_ema < self.spec_accept_floor
-        ):
-            self._mark_fallback(req, "acceptance")
-            return False
-        if req.deadline_ts is not None and (
-            req.deadline_ts - time.monotonic() < self.spec_deadline_margin_s
-        ):
-            # near the propagated deadline (resilience layer, PR 4) plain
-            # decode's per-burst stop granularity beats the spec burst's
-            # spec_iters*(k+1)-token dispatch: never blow a deadline on
-            # tokens the caller will throw away
-            self._mark_fallback(req, "deadline")
-            return False
-        return True
-
-    def _mark_fallback(self, req: _Request, reason: str) -> None:
-        req.spec_fallback = reason
-        self.spec_fallbacks[reason] = self.spec_fallbacks.get(reason, 0) + 1
-
-    def _pick_spec_k(self, running: list[_Request]) -> int:
-        """Adaptive draft length: scale spec_k by the batch's mean EMA
-        acceptance rate, snapped UP to the precompiled power-of-two ladder
-        (a fresh batch with no history starts optimistic at the top rung).
-        Snapping to the ladder is what keeps the controller recompile-free:
-        every reachable k was compiled by warmup()."""
-        emas = [r.spec_accept_ema for r in running if r.spec_accept_ema is not None]
-        if not emas:
-            return self._spec_k_ladder[-1]
-        want = max(1, round((sum(emas) / len(emas)) * self.spec_k))
-        for rung in self._spec_k_ladder:
-            if rung >= want:
-                return rung
-        return self._spec_k_ladder[-1]
-
-    def _draft_spec_step(self, finished: list[GenerationResult]) -> None:
-        """One draft-model speculative dispatch (serving/draft_spec.py):
-        ``spec_iters`` fused draft/verify/accept rounds at the controller's
-        chosen k.  Synchronous like the n-gram burst — the dispatch commits
-        up to spec_iters*(k+1) tokens per row, so there is no per-token
-        round trip left to pipeline away."""
-        from githubrepostorag_tpu.serving.draft_spec import draft_spec_burst
-
-        if self._chain is not None or self._pending_first:
-            # a plain-decode chain (mixed-batch or forced-fallback steps
-            # pipeline) is in flight: land it so the history/lens snapshot
-            # below sees every committed token
-            self._drain_chain(finished)
-        self._phase("engine.burst_prepare")
-        running = [r for r in self._row_req.values() if r.state == "running"]
-        if not running:
-            return
-        k = self._pick_spec_k(running)
-        rb = _bucket(len(running), self.max_num_seqs, minimum=1)
-        h = self.max_seq_len
-        hist = np.zeros((rb, h), dtype=np.int32)
-        hlens = np.zeros((rb,), dtype=np.int32)
-        lens = np.zeros((rb,), dtype=np.int32)
-        bt = np.zeros((rb, self.max_pages_per_seq), dtype=np.int32)
-        limits = np.zeros((rb,), dtype=np.int32)
-        active = np.zeros((rb,), dtype=bool)
-        for i, req in enumerate(running):
-            toks = (req.prompt + req.output)[-h:]
-            hist[i, : len(toks)] = toks
-            hlens[i] = len(toks)
-            lens[i] = req.seq_len
-            bt[i] = self._block_tables[req.row]
-            limits[i] = self._row_limits[req.row]
-            active[i] = True
-
-        self.step_dispatches_total += 1
-        with annotate("engine.draft_spec_burst"):
-            out = draft_spec_burst(
-                self.params, self.draft_params, self.cfg, self.draft_cfg,
-                jnp.asarray(hist), jnp.asarray(hlens), jnp.asarray(lens),
-                self._k_pages, self._v_pages,
-                self._dk_pages, self._dv_pages,
-                jnp.asarray(bt), jnp.asarray(limits), jnp.asarray(active),
-                n_iters=self.spec_iters, k=k,
-                use_pallas=self.use_pallas, int4_kernel=self._int4_kernel,
-                k_scales=self._k_scales, v_scales=self._v_scales,
-            )
-        if self.kv_quant:
-            (toks_d, prop_d, self._k_pages, self._v_pages,
-             self._dk_pages, self._dv_pages,
-             self._k_scales, self._v_scales) = out
-        else:
-            (toks_d, prop_d, self._k_pages, self._v_pages,
-             self._dk_pages, self._dv_pages) = out
-        # ONE [rb, iters, k+1] fetch per dispatch; every acceptance-rate
-        # read below is host numpy (no per-iteration device round trips —
-        # the tpulint TPU007 hazard this step was designed around)
-        self._phase("engine.commit_fetch")
-        toks = np.asarray(toks_d)
-        prop = np.asarray(prop_d)
-        self._phase("engine.commit_host")
-        for i, req in enumerate(running):
-            proposed = accepted = 0
-            for it in range(toks.shape[1]):
-                if req.state != "running":
-                    break  # device drafted past this row's stop; discard
-                p_it = int(prop[i, it])
-                proposed += p_it
-                req.spec_proposed_req += p_it
-                committed = 0
-                for t in toks[i, it]:
-                    if t < 0 or req.state != "running":
-                        break
-                    if committed:
-                        # token 2..n of an iteration is accepted draft
-                        # (committed = agreed prefix + 1 correction);
-                        # counted BEFORE _commit_token so a request that
-                        # finishes mid-commit snapshots a complete tally
-                        # into its GenerationResult
-                        accepted += 1
-                        req.spec_accepted_req += 1
-                    req.seq_len += 1
-                    self._seq_lens[req.row] = req.seq_len
-                    self._commit_token(req, int(t), finished)
-                    committed += 1
-            self.spec_proposed += proposed
-            self.spec_accepted += accepted
-            if proposed:
-                rate = accepted / proposed
-                req.spec_accept_ema = (
-                    rate if req.spec_accept_ema is None
-                    else 0.3 * rate + 0.7 * req.spec_accept_ema
-                )
-
     def _first_wave(
         self,
         tokens_d: jnp.ndarray,
@@ -2733,7 +2135,7 @@ class Engine:
         finished: list[GenerationResult],
     ) -> None:
         """First tokens sampled by a path that draws them outside its prefill
-        program (packed, ring, fused): ``tokens_d[i]`` is ``req``'s for each
+        program (packed, ring): ``tokens_d[i]`` is ``req``'s for each
         ``(req, i)`` of ``wave``.  They are scattered by row into the
         first-token array, where ``_prefill_batch``'s wave program puts its
         own, and the rows join the running set."""
@@ -2753,10 +2155,9 @@ class Engine:
         """The chunk that completed these prompts is dispatched and their
         first tokens are in (this version of) the first-token array: the rows
         join the running set.  With the engine otherwise idle (nothing to
-        overlap the sync with) or in speculative mode (synchronous by design)
-        the tokens commit now (best TTFT); else the wave stays on device and
-        commits with the next burst's fetch, so admissions never stall
-        running streams."""
+        overlap the sync with) the tokens commit now (best TTFT); else the
+        wave stays on device and commits with the next burst's fetch, so
+        admissions never stall running streams."""
         now = time.monotonic()
         for req in reqs:
             req.state = "running"
@@ -2970,9 +2371,6 @@ class Engine:
                 "done_t": done_t,
             },
             cached_tokens=req.cached_tokens,
-            spec_proposed=req.spec_proposed_req,
-            spec_accepted=req.spec_accepted_req,
-            spec_fallback=req.spec_fallback,
             faulted_pages=req.faulted_pages,
             preempted=req.preempted,
         )
@@ -3067,114 +2465,6 @@ class Engine:
                 n = min(width, self.max_seq_len - 2)  # room for 2 tokens
                 if n >= self.sp_prefill_threshold:
                     self.generate([[1] * n], sp)
-        if self._decode_path([]) not in (self._decode_step, self._fused_step):
-            # a speculative path that demotes a mixed batch: the
-            # plain-decode FALLBACK must be warm before it's ever needed —
-            # a sampled row joining, or an acceptance collapse mid-request,
-            # must not pay a decode_burst compile on top of the throughput
-            # it is already losing (the greedy waves above all routed
-            # through the spec path, so the no-filter burst variant is
-            # still cold)
-            wave += 1
-            tok = 2 + wave % max(2, self.cfg.vocab_size - 2)
-            self._force_plain = True
-            try:
-                self.generate([[tok] * 3], sp)
-            finally:
-                self._force_plain = False
-        if self._draft_enabled:
-            # compile the whole (k rung x row bucket) spec-burst ladder the
-            # adaptive controller can reach.  All-False ``active`` masks
-            # every KV write and commit, so each call is a pure
-            # shape-compile pass over the live pools (donated -> rebind).
-            from githubrepostorag_tpu.serving.draft_spec import draft_spec_burst
-
-            h = self.max_seq_len
-            for kk in self._spec_k_ladder:
-                for nb in buckets:
-                    out = draft_spec_burst(
-                        self.params, self.draft_params,
-                        self.cfg, self.draft_cfg,
-                        jnp.zeros((nb, h), jnp.int32),
-                        jnp.zeros((nb,), jnp.int32),
-                        jnp.zeros((nb,), jnp.int32),
-                        self._k_pages, self._v_pages,
-                        self._dk_pages, self._dv_pages,
-                        jnp.zeros((nb, self.max_pages_per_seq), jnp.int32),
-                        jnp.zeros((nb,), jnp.int32),
-                        jnp.zeros((nb,), bool),
-                        n_iters=self.spec_iters, k=kk,
-                        use_pallas=self.use_pallas,
-                        int4_kernel=self._int4_kernel,
-                        k_scales=self._k_scales, v_scales=self._v_scales,
-                    )
-                    if self.kv_quant:
-                        (_, _, self._k_pages, self._v_pages,
-                         self._dk_pages, self._dv_pages,
-                         self._k_scales, self._v_scales) = out
-                    else:
-                        (_, _, self._k_pages, self._v_pages,
-                         self._dk_pages, self._dv_pages) = out
-        if self.fused_step_on:
-            # compile the whole fused-step variant set the live loop can
-            # reach: (decode row bucket) x (has_prefill) x
-            # (filter_sampling).  All-False ``active`` masks every KV
-            # write, history scatter and presence update, and the warm
-            # prefill phase's all--1 slot mapping drops its KV writes
-            # too, so each call is a pure shape-compile pass over the
-            # live pools (donated -> rebind); mixed live traffic can then
-            # never mint a new program mid-request.
-            from githubrepostorag_tpu.serving.fused_step import fused_step_burst
-
-            self._push_sampling()
-            h = self.max_seq_len
-            budget = self.prefill_token_budget
-            pfseg = self._fused_pf_segs
-            pf_warm = (
-                jnp.zeros((1, budget), jnp.int32),
-                jnp.zeros((1, budget), jnp.int32),
-                jnp.full((budget,), -1, jnp.int32),
-                jnp.zeros((pfseg, self.max_pages_per_seq), jnp.int32),
-                jnp.zeros((pfseg,), jnp.int32),
-                jnp.zeros((pfseg,), jnp.int32),
-                jnp.full((budget,), pfseg, jnp.int32),
-                jnp.zeros((pfseg,), jnp.int32),
-            )
-            for nb in buckets:
-                rows = jnp.zeros((nb,), jnp.int32)
-                for has_pf in (False, True):
-                    for filt in (False, True):
-                        self._rng, key = jax.random.split(self._rng)
-                        out = fused_step_burst(
-                            self.params, self.cfg,
-                            jnp.zeros((nb, h), jnp.int32),
-                            jnp.zeros((nb,), jnp.int32),
-                            jnp.zeros((nb,), jnp.int32),
-                            self._k_pages, self._v_pages,
-                            jnp.zeros((nb, self.max_pages_per_seq),
-                                      jnp.int32),
-                            jnp.zeros((nb,), jnp.int32),
-                            jnp.zeros((nb,), bool),
-                            jnp.zeros((nb,), bool),
-                            rows, self._presence, key,
-                            self._temp_d[rows], self._top_p_d[rows],
-                            self._top_k_d[rows], self._rep_pen_d[rows],
-                            *(pf_warm if has_pf else (None,) * 8),
-                            n_iters=self.spec_iters,
-                            k=self.spec_ngram_k, tq=self.packed_chunk,
-                            use_pallas=self.use_pallas,
-                            int4_kernel=self._int4_kernel,
-                            filter_sampling=filt, has_prefill=has_pf,
-                            k_scales=self._k_scales,
-                            v_scales=self._v_scales,
-                        )
-                        if self.kv_quant:
-                            (_, _, _, self._k_pages, self._v_pages,
-                             self._presence, self._k_scales,
-                             self._v_scales) = out
-                        else:
-                            (_, _, _, self._k_pages, self._v_pages,
-                             self._presence) = out
         if self.prefix_caching:
             # the cached-prefix presence-marking program ([row bucket,
             # max_seq] — one dispatch per admission wave) only runs on
@@ -3214,16 +2504,6 @@ class Engine:
                     vs_vals=(jnp.zeros((L, n_kv, nb), jnp.float32)
                              if quant else None),
                 )
-                if self._draft_enabled:
-                    dL = self.draft_cfg.num_layers
-                    dn, dhd = self.draft_cfg.num_kv_heads, self.draft_cfg.head_dim
-                    gather_pages(self._dk_pages, self._dv_pages, idx)
-                    self._dk_pages, self._dv_pages, _, _ = scatter_pages(
-                        self._dk_pages, self._dv_pages, idx,
-                        jnp.zeros((dL, dn, nb, ps, dhd), self._dk_pages.dtype),
-                        v_vals=jnp.zeros((dL, dn, nb, ps, dhd),
-                                         self._dv_pages.dtype),
-                    )
         logger.info("engine warmup complete (%d prefill row buckets)", len(buckets))
 
     def generate(

@@ -17,15 +17,14 @@ that reads each):
     server.submit_wait a submission waiting for the driver's lock, in the
                        executor: the event loop stays free
     engine.admit       reaping, preemption, admission, page allocation
-    engine.prefill_batch (+ _draft, prefill_packed, sp_prefill_packed)
+    engine.prefill_batch (+ prefill_packed, sp_prefill_packed)
                        one prefill wave: host arrays and the dispatch of
                        its one program (chunk + first-token tail);
                        ``width``: the columns a row it runs at, and
                        ``padded_tokens`` = row bucket x width, of which
                        ``new_tokens`` are real
     engine.burst_prepare  the active mask and the masks of fresh rows
-    engine.decode_burst (+ spec_burst, fused_step, draft_spec_burst)
-                       the dispatch call; ``ahead``: the device still
+    engine.decode_burst   the dispatch call; ``ahead``: the device still
                        had work queued when the step's programs went out
     engine.commit_fetch   the blocking device->host fetch of a burst
     engine.commit_host    per-token bookkeeping, callbacks, results

@@ -61,11 +61,10 @@ def build_payloads():
     snap = {f: 0.0 for f in SNAPSHOT_FIELDS}
     ledger.on_step(dict(snap), now - 1.0, now - 0.8, compiles=1)
     snap.update(committed_tokens=8, prefill_tokens=16, reaped_tokens=1,
-                spec_proposed=4, spec_accepted=3, admission_blocked_steps=1,
+                admission_blocked_steps=1,
                 prefill_seconds_total=0.1, decode_seconds_total=0.1,
-                spec_verify_seconds_total=0.05,
                 migration_seconds_total=0.01, fault_in_seconds_total=0.01,
-                fused_steps_total=1, step_dispatches_total=2)
+                step_dispatches_total=2)
     ledger.on_step(dict(snap), now - 0.7, now - 0.2)
 
     monitor = SLOMonitor("r0")

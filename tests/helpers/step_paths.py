@@ -8,7 +8,7 @@ from __future__ import annotations
 
 from typing import Callable
 
-DECODE_PATHS = ("_decode_step", "_spec_burst_step", "_fused_step", "_draft_spec_step")
+DECODE_PATHS = ("_decode_step",)
 PREFILL_PATHS = ("_prefill_batch", "_prefill_batch_packed", "_sp_prefill_packed")
 
 

@@ -39,8 +39,6 @@ class _FakeReplica:
         self._lock = threading.Lock()
         self.engine = SimpleNamespace(
             _allocator=SimpleNamespace(host_pool_pages=4, num_pages=8),
-            _spec_k_ladder=[1, 2, 4],
-            spec_k=4,
         )
 
     def driver_alive(self) -> bool:
@@ -251,28 +249,21 @@ def test_inflight_failover_suppresses_stacked_actions(monkeypatch):
 # ------------------------------------------------------------- the ladder --
 
 
-def test_hbm_pages_prefers_pool_growth_until_capped(monkeypatch):
+def test_hbm_pages_grows_the_pool_until_capped(monkeypatch):
     fleet = _FakeFleet([_FakeReplica("r0")])
-    # cap == current pool: growth impossible, rung 2 (spec-k) is chosen
-    ctrl = _ctrl(monkeypatch, fleet, CTRL_HOST_POOL_MAX_PAGES="4",
+    ctrl = _ctrl(monkeypatch, fleet, CTRL_HOST_POOL_MAX_PAGES="6",
                  CTRL_COOLDOWN_S="0")
     _script(monkeypatch, ctrl, _sensed("r0", limiter="hbm_pages"))
-    eng = fleet._by_id["r0"].engine
+    alloc = fleet._by_id["r0"].engine._allocator
 
     ctrl.tick(now=0.0)
     acted = ctrl.tick(now=1.0)
-    assert [a["action"] for a in acted] == ["spec_k_down"]
-    assert eng._spec_k_ladder == [1, 2] and eng.spec_k == 2
-    ctrl.tick(now=2.0)
-    ctrl.tick(now=3.0)
-    assert eng._spec_k_ladder == [1] and eng.spec_k == 1
-    # at the floor the action is a stamped no-op, never an error
-    ctrl.tick(now=4.0)
-    acted = ctrl.tick(now=5.0)
-    assert acted[0]["action"] == "spec_k_down"
-    assert ctrl.payload()["log"][-1]["detail"] == {
-        "noop": "spec-k ladder already at its floor"}
-    assert eng.spec_k == 1
+    assert [a["action"] for a in acted] == ["grow_host_pool"]
+    assert alloc.host_pool_pages == 6
+    # at the cap nothing is left to try: no action, nothing logged
+    logged = len(ctrl.payload()["log"])
+    assert ctrl.tick(now=2.0) == [] and ctrl.tick(now=3.0) == []
+    assert alloc.host_pool_pages == 6 and len(ctrl.payload()["log"]) == logged
 
 
 def test_swap_wait_halves_affinity_slack_with_floor(monkeypatch):

@@ -312,8 +312,7 @@ def test_dropless_dispatch_loses_nothing_when_every_token_picks_one_expert(token
 
 def test_engine_refuses_what_is_not_built_for_a_latent_pool(tiny):
     cfg, params = tiny
-    for kw in ({"kv_quant": 8}, {"spec_ngram_k": 2}, {"prefill_token_budget": 64},
-               {"kv_tier": "on"}):
+    for kw in ({"kv_quant": 8}, {"prefill_token_budget": 64}, {"kv_tier": "on"}):
         with pytest.raises(ValueError, match="latent page pool"):
             Engine(params, cfg, max_num_seqs=2, num_pages=16, page_size=PAGE, max_seq_len=64, **kw)
     eng = Engine(params, cfg, max_num_seqs=2, num_pages=16, page_size=PAGE, max_seq_len=64)

@@ -1,7 +1,7 @@
 """Disaggregated prefill/decode serving (serving/disagg.py + the
 MultiAsyncEngine handoff): role-assignment viability, fused-vs-disagg
-token identity (including prefix-dedup repeat traffic, int8 KV, and spec
-decode on the decode replica), the fused fallback when the transfer dies,
+token identity (including prefix-dedup repeat traffic and int8 KV), the
+fused fallback when the transfer dies,
 role-aware fleet stats merging, and the zero-live-recompile contract
 across mixed handoff / dedup / short-prompt traffic.
 """
@@ -181,13 +181,10 @@ async def test_disagg_repeat_traffic_dedups_the_wire(tiny, monkeypatch):
 
 @pytest.mark.parametrize("extra", [
     pytest.param(dict(kv_quant=True), id="int8_kv"),
-    pytest.param(dict(spec_ngram_k=3), id="spec_decode"),
 ])
-async def test_disagg_parity_composes_with_quant_and_spec(
-        tiny, monkeypatch, extra):
+async def test_disagg_parity_composes_with_quant(tiny, monkeypatch, extra):
     """The handoff must compose with the KV features riding the same
-    pools: int8 KV pages ship with their scales, and the decode replica
-    spec-decodes against imported pages — token-identical either way."""
+    pools: int8 KV pages ship with their scales, token-identically."""
     cfg, params = tiny
     prompts = _prompts(3, seed=7)
     sp = _sp()

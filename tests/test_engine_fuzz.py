@@ -1,8 +1,7 @@
 """Randomized scheduling fuzz over the engine's combined features.
 
 The engine now composes continuous batching, co-dispatched mixed
-prefill+decode, pipelined bursts, prefix caching, cancellation, and
-(optionally) speculative decoding.  This test drives hundreds of random
+prefill+decode, pipelined bursts, prefix caching and cancellation.  This test drives hundreds of random
 scheduling decisions — admissions with shared/unshared prompts at random
 times, cancels, varied lengths — against engines in several configurations
 and checks the global invariants after every episode:
@@ -46,14 +45,13 @@ def tiny():
 CONFIGS = [
     dict(),  # bursts + prefix caching (defaults)
     dict(prefix_caching=False),
-    dict(spec_ngram_k=3),
     dict(decode_burst=1),  # per-token stepping
     dict(prefill_chunk=32),  # three rungs of prefill width: 32, 16, 8
 ]
 
 
 @pytest.mark.parametrize(
-    "extra", CONFIGS, ids=["default", "nocache", "spec", "burst1", "widths"]
+    "extra", CONFIGS, ids=["default", "nocache", "burst1", "widths"]
 )
 def test_random_schedule_episode(tiny, extra):
     params, cfg = tiny
@@ -105,7 +103,7 @@ def run_episode(make, cfg, rng):
         base[:24],
         base[:24] + rng.integers(0, cfg.vocab_size, 9).tolist(),
         rng.integers(0, cfg.vocab_size, 37).tolist(),
-        [7, 8, 9, 10] * 7,  # loops: speculative-friendly
+        [7, 8, 9, 10] * 7,  # loops
         rng.integers(0, cfg.vocab_size, 5).tolist(),
     ]
     solo_cache: dict[tuple[int, int], list[int]] = {}
@@ -164,11 +162,10 @@ def run_episode(make, cfg, rng):
 
 
 @pytest.mark.parametrize("extra", [
-    dict(spec_ngram_k=3),  # speculative path
-    dict(prefill_chunk=32),  # plain bursts: the mixed top_p traffic flips
-    # the filter_sampling burst variant between bursts, over prefill waves
+    dict(prefill_chunk=32),  # the mixed top_p traffic flips the
+    # filter_sampling burst variant between bursts, over prefill waves
     # at three widths (32, 16, 8)
-], ids=["spec", "burst-widths"])
+], ids=["burst-widths"])
 def test_random_schedule_sampled_invariants(tiny, extra):
     """Sampled traffic (temperature > 0, top-p, penalties) under random
     scheduling: outputs are seed-dependent, so only the structural

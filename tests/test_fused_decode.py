@@ -1,10 +1,8 @@
-"""The fused decode hot path: ops/fused_decode.py's single-launch window
-kernel must be numerically indistinguishable from ``paged_attention_ref``
-(its stated oracle) across row buckets, window widths, quant modes, and
-block-table holes; int4 nibble pages must round-trip bit-exactly through
-commit/gather/migration; and the engine-level fused step
-(serving/fused_step.py) must stay greedy-token-IDENTICAL to the unfused
-path while compiling ZERO new XLA programs after warmup.
+"""ops/fused_decode.py's single-launch window kernel (every family's
+prefill kernel) must be numerically indistinguishable from
+``paged_attention_ref`` (its stated oracle) across row buckets, window
+widths, quant modes, and block-table holes; int4 nibble pages must
+round-trip bit-exactly through commit/gather/migration.
 """
 
 from __future__ import annotations
@@ -18,7 +16,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from githubrepostorag_tpu.models.qwen2 import Qwen2Config, init_params
+from githubrepostorag_tpu.models.qwen2 import Qwen2Config
 from githubrepostorag_tpu.ops.fused_decode import (
     fused_packed_attention,
     fused_window_attention,
@@ -28,7 +26,6 @@ from githubrepostorag_tpu.ops.sampling import (
     sample_tokens_capped,
     sample_tokens_nofilter,
 )
-from githubrepostorag_tpu.serving import Engine, SamplingParams
 from githubrepostorag_tpu.serving.kv_cache import (
     make_page_pools,
     pack_int4,
@@ -270,106 +267,7 @@ def test_sampling_accepts_fused_segment_logits():
     assert np.asarray(at0).tolist() == np.asarray(dflt).tolist()
 
 
-# --------------------------------------------------- engine-level parity --
-
-
-@pytest.fixture(scope="module")
-def narrator():
-    """Tiny model whose untied lm_head makes greedy output deterministic
-    and prompt-dependent — the parity fixture the unfused path is held to."""
-    cfg = Qwen2Config(vocab_size=64, hidden_size=32, num_layers=2,
-                      num_heads=4, num_kv_heads=2, head_dim=8,
-                      intermediate_size=64, tie_word_embeddings=False)
-    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
-    params["lm_head"] = jnp.roll(params["embed"], 1, axis=0).T
-    return cfg, params
-
-
-def _engine(params, cfg, **kw):
-    defaults = dict(max_num_seqs=4, num_pages=64, page_size=8, max_seq_len=128,
-                    prefill_chunk=16, prefill_token_budget=32,
-                    spec_ngram_k=3, spec_iters=2, decode_burst=4)
-    defaults.update(kw)
-    return Engine(dict(params), cfg, **defaults)
-
-
-def test_fused_step_construction_gates(narrator):
-    cfg, params = narrator
-    with pytest.raises(ValueError, match="spec_ngram_k"):
-        _engine(params, cfg, fused_step=True, spec_ngram_k=0)
-    with pytest.raises(ValueError, match="prefill_token_budget"):
-        _engine(params, cfg, fused_step=True, prefill_token_budget=None)
-    with pytest.raises(ValueError, match="SPEC_DRAFT_MODEL"):
-        _engine(params, cfg, fused_step=True, draft_params=dict(params),
-                draft_cfg=cfg)
-    with pytest.raises(ValueError, match="prefill_priority"):
-        _engine(params, cfg, fused_step=True, prefill_priority=True)
-
-
-@pytest.mark.parametrize("kv_quant", [False, True, 4], ids=["fp", "int8", "int4"])
-def test_fused_greedy_token_identical(narrator, kv_quant):
-    """THE acceptance criterion: the fused single-dispatch step produces
-    byte-identical greedy output to the unfused engine, in every kv_quant
-    mode, and returns every page to the pool."""
-    cfg, params = narrator
-    prompts = [[3, 4, 5], [7, 8, 9, 10], [1, 2]]
-    sp = SamplingParams(max_tokens=10, temperature=0.0, stop_token_ids=())
-    ref = _engine(params, cfg, kv_quant=kv_quant).generate(prompts, sp)
-
-    eng = _engine(params, cfg, fused_step=True, kv_quant=kv_quant)
-    got = eng.generate(prompts, sp)
-    for a, b in zip(got, ref):
-        assert a.output_tokens == b.output_tokens
-    assert eng.fused_steps_total > 0
-    assert eng.step_dispatches_total >= eng.fused_steps_total
-    assert eng._allocator.free_count == eng._allocator.num_pages
-    assert not eng.has_work()
-
-
-def test_fused_mixed_sampled_row_keeps_greedy_parity(narrator):
-    """A sampled row riding the fused burst must not perturb its greedy
-    neighbors (the unfused engine demotes such batches to plain decode;
-    the fused step keeps speculation for the greedy rows instead)."""
-    cfg, params = narrator
-    sp = SamplingParams(max_tokens=8, temperature=0.0, stop_token_ids=())
-    sampled = SamplingParams(max_tokens=8, temperature=0.9, top_p=0.9,
-                             stop_token_ids=())
-    # each prompt ends one token shy of re-creating its opening bigram:
-    # the greedy first token (prev+1 under the narrator head) completes it,
-    # so the n-gram drafter finds a match and proposes in the first burst
-    greedy_prompts = [[3, 4, 9, 3], [7, 8, 2, 7]]
-    ref = _engine(params, cfg).generate(greedy_prompts, sp)
-
-    eng = _engine(params, cfg, fused_step=True)
-    got = eng.generate(greedy_prompts + [[11, 12, 13]],
-                       [sp, sp, sampled])
-    assert got[0].output_tokens == ref[0].output_tokens
-    assert got[1].output_tokens == ref[1].output_tokens
-    assert len(got[2].output_tokens) == 8
-    assert all(0 <= t < cfg.vocab_size for t in got[2].output_tokens)
-    assert eng.spec_proposed > 0  # greedy rows kept speculating
-
-
-def test_fused_joint_admission_defers_prefill_into_burst(narrator):
-    """A request admitted while others decode rides the SAME dispatch: the
-    packed wave is deferred into the next fused step, so dispatches stay
-    1 per step (plus the initial prefill-only packed program)."""
-    cfg, params = narrator
-    sp = SamplingParams(max_tokens=12, temperature=0.0, stop_token_ids=())
-    ref = _engine(params, cfg).generate([[3, 4, 5], [9, 10, 11, 12]], sp)
-
-    eng = _engine(params, cfg, fused_step=True)
-    eng.add_request([3, 4, 5], sp)
-    first = eng.step()  # prefill-only packed dispatch
-    eng.add_request([9, 10, 11, 12], sp)  # joins mid-flight -> deferred
-    done = list(first)
-    while eng.has_work():
-        done.extend(eng.step())
-    by_len = sorted(done, key=lambda r: len(r.prompt_tokens))
-    assert by_len[0].output_tokens == ref[0].output_tokens
-    assert by_len[1].output_tokens == ref[1].output_tokens
-    # every step after the first prefill was a single fused dispatch
-    assert eng.step_dispatches_total == eng.fused_steps_total + 1
+# ------------------------------------------------- dispatch attribution --
 
 
 def test_ledger_dispatch_attribution():
@@ -381,40 +279,8 @@ def test_ledger_dispatch_attribution():
     ledger = TokenLedger("r0", flops_per_tok=1e9, peak_flops=1e12)
     snap = {f: 0.0 for f in SNAPSHOT_FIELDS}
     ledger.on_step(dict(snap), now - 1.0, now - 0.8)
-    snap.update(committed_tokens=5, fused_steps_total=3,
-                step_dispatches_total=4)
+    snap.update(committed_tokens=5, step_dispatches_total=4)
     ledger.on_step(dict(snap), now - 0.7, now - 0.2)
     s = ledger.snapshot()
-    assert s["dispatch"]["fused_steps"] == 3
     assert s["dispatch"]["dispatches"] == 4
     assert s["dispatch"]["dispatches_per_step"] == 2.0
-
-
-# ------------------------------------------------------ compile discipline --
-
-
-@pytest.mark.parametrize("kv_quant", [False, 4], ids=["fp", "int4"])
-def test_fused_zero_recompiles_across_mixed_traffic(narrator, kv_quant):
-    """After warmup, mixed fused traffic — both row buckets, a sampled row
-    (filter variant), joint admission mid-decode (has_prefill variant) —
-    compiles ZERO new XLA programs."""
-    from tests.helpers.compile_guard import compile_guard, watchdog_counter
-
-    cfg, params = narrator
-    eng = _engine(params, cfg, fused_step=True, kv_quant=kv_quant)
-    eng.warmup()
-
-    sp = SamplingParams(max_tokens=8, temperature=0.0, stop_token_ids=())
-    sampled = SamplingParams(max_tokens=6, temperature=0.8, top_p=0.9,
-                             stop_token_ids=())
-    with compile_guard(watchdog_counter(),
-                       label=f"fused mixed traffic (kv_quant={kv_quant})"):
-        eng.generate([[1, 2, 3]], sp)                        # bucket 1
-        eng.generate([[4, 5, 6], [7, 8, 9]], sp)             # bucket 2
-        eng.generate([[1, 2, 3], [4, 5, 6]], [sp, sampled])  # filter variant
-        eng.add_request([5, 6, 7], sp)
-        eng.step()
-        eng.add_request([9, 10, 11], sp)  # deferred wave -> has_prefill
-        while eng.has_work():
-            eng.step()
-    assert eng.fused_steps_total > 0

@@ -112,22 +112,6 @@ def test_kv_quant_composes_with_prefix_cache(tiny):
     assert warm == cold
 
 
-@pytest.mark.parametrize("spec_iters", [1, 3])
-def test_kv_quant_spec_decode_runs(tiny, spec_iters):
-    """Spec mode verifies drafts through forward_paged's quantized path:
-    the fused on-device burst threads the scale pools through its scan
-    carry, one verify round a dispatch and several."""
-    cfg, params = tiny
-    zero_layers = jax.tree.map(jnp.zeros_like, params["layers"])
-    rep_params = dict(params, layers=zero_layers)  # repeater: drafts accept
-    eng = _engine(rep_params, cfg, kv_quant=True, spec_ngram_k=4,
-                  spec_iters=spec_iters)
-    sp = SamplingParams(max_tokens=16, temperature=0.0, stop_token_ids=())
-    res = eng.generate([[5, 6, 7, 8]], sp)[0]
-    assert len(res.output_tokens) == 16
-    assert eng.spec_accepted > 0  # the repeating tail drafted + accepted
-
-
 def test_kv_quant_composes_with_sp_ring_prefill(tiny):
     """Round-4: the ring commit quantizes per page (long_prefill.py), so
     kv_quant + sp no longer rejects at construction — a long prompt rides

@@ -164,16 +164,16 @@ def test_stats_merge_sums_counters_and_means_rates():
 
     multi = MultiAsyncEngine.__new__(MultiAsyncEngine)
     multi._engines = [
-        Stub({"requests_admitted": 3, "spec_acceptance_rate": 0.8,
-              "kv_utilization": 0.5, "spec_fallbacks": 1}),
-        Stub({"requests_admitted": 1, "spec_acceptance_rate": 0.4,
-              "kv_utilization": 0.1, "spec_fallbacks": 0}),
+        Stub({"requests_admitted": 3, "prefix_hit_rate": 0.8,
+              "kv_utilization": 0.5, "deadline_reaps": 1}),
+        Stub({"requests_admitted": 1, "prefix_hit_rate": 0.4,
+              "kv_utilization": 0.1, "deadline_reaps": 0}),
     ]
     merged = MultiAsyncEngine.stats(multi)
     assert merged["requests_admitted"] == 4  # counter: summed
-    assert merged["spec_acceptance_rate"] == pytest.approx(0.6)  # rate: mean
+    assert merged["prefix_hit_rate"] == pytest.approx(0.6)  # rate: mean
     assert merged["kv_utilization"] == pytest.approx(0.3)
-    assert merged["spec_fallbacks"] == 1  # plain counter, still summed
+    assert merged["deadline_reaps"] == 1  # plain counter, still summed
     assert merged["replicas"] == 2
 
 

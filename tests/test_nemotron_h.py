@@ -359,7 +359,7 @@ def test_the_configuration_object_brings_the_programs_pools_and_counters():
 
 
 def test_what_a_state_pool_refuses_at_construction_stands_for_this_family_too():
-    for kw, named in ((dict(kv_quant=8), "kv_quant"), (dict(spec_ngram_k=2), "spec_ngram_k"),
+    for kw, named in ((dict(kv_quant=8), "kv_quant"),
                       (dict(prefill_token_budget=64), "prefill_token_budget"),
                       (dict(prefill_chunk=40), "prefill_chunk"),
                       (dict(kv_tier="on"), "kv_tier")):

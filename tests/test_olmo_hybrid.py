@@ -272,7 +272,7 @@ def test_each_configuration_object_brings_its_own_programs_and_counters():
 
 
 def test_what_a_state_pool_refuses_at_construction_stands_for_this_family_too():
-    for kw, named in ((dict(kv_quant=8), "kv_quant"), (dict(spec_ngram_k=2), "spec_ngram_k"),
+    for kw, named in ((dict(kv_quant=8), "kv_quant"),
                       (dict(prefill_token_budget=64), "prefill_token_budget"),
                       (dict(prefill_chunk=40), "prefill_chunk")):
         with pytest.raises(ValueError, match="recurrent state pool: .*" + named):

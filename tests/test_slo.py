@@ -126,15 +126,11 @@ def test_ledger_window_prunes_and_goodput_decays_to_zero():
 
 def test_ledger_wasted_token_accounting():
     led = TokenLedger("t7", window_s=60.0)
-    led.on_step(_snap(committed_tokens=6, reaped_tokens=2,
-                      spec_proposed=10, spec_accepted=6,
-                      spec_verify_seconds_total=0.2),
-                10.0, 10.3)
+    led.on_step(_snap(committed_tokens=6, reaped_tokens=2), 10.0, 10.3)
     tokens = led.snapshot(now=10.3)["tokens"]
-    assert tokens["spec_rejected"] == 4
     assert tokens["deadline_reaped"] == 2
-    # wasted = (4 rejected + 2 reaped) / (6 committed + 6 wasted)
-    assert tokens["wasted_fraction"] == pytest.approx(0.5)
+    # wasted = 2 reaped / (6 committed + 2 wasted)
+    assert tokens["wasted_fraction"] == pytest.approx(0.25)
 
 
 def test_ledger_mfu_from_flops_per_token():

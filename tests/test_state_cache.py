@@ -123,7 +123,7 @@ def test_what_the_hybrid_family_refuses_at_construction(make):
     a parked row's state is neither saved nor its prompt prefilled again, the
     engine is refused.  So are the other paths that know pages alone."""
     for kw, named in ((dict(preempt="on", kv_tier="on"), "kv_tier, preempt"),
-                      (dict(kv_quant=8), "kv_quant"), (dict(spec_ngram_k=2), "spec_ngram_k"),
+                      (dict(kv_quant=8), "kv_quant"),
                       (dict(prefill_token_budget=64), "prefill_token_budget"),
                       (dict(prefill_chunk=40), "prefill_chunk")):
         with pytest.raises(ValueError, match="recurrent state pool: .*" + named):

@@ -117,7 +117,7 @@ def test_profiler_samples_every_nth_step_into_a_bounded_ring():
     assert samples[0] == {"t": samples[0]["t"], "seq": seqs[0],
                           "running": 2, "waiting": 1, "parked": 0,
                           "free_pages": 10, "host_pages": 3,
-                          "prefill": 0.0, "decode": 1e-3, "spec_verify": 0.0,
+                          "prefill": 0.0, "decode": 1e-3,
                           "kv_migration": 0.0, "kv_transfer": 0.0,
                           "sched_stall": 0.0, "compile": 0.0,
                           "committed": 4.0, "wall": 2e-3, "compiles": 0.0}

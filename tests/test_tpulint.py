@@ -427,18 +427,18 @@ def _mutated_tree(tmp_path, relpath: str, needle: str, replacement: str) -> Path
 
 
 def test_planted_engine_debucketing_is_caught_as_shp001(tmp_path):
-    """Strip the bucket barrier from the spec-burst row sizing: the
-    request-derived batch size then reaches the dispatch shapes raw, and
+    """Strip the bucket barrier from the packed wave's row sizing: the
+    request-derived segment count then reaches the dispatch shapes raw, and
     SHP001 must fire with a full witness chain."""
     dst = _mutated_tree(
         tmp_path, "serving/engine.py",
-        "rb = _bucket(len(running), self.max_num_seqs, minimum=1)",
-        "rb = len(running)")
+        "n = len(packed)\n        rb = _bucket(n, self.max_num_seqs, minimum=1)",
+        "n = len(packed)\n        rb = len(reqs)")
     findings, _ = run_paths([dst])
     hits = [f for f in findings if f.rule == "SHP001" and not f.suppressed]
     assert hits, "debucketed engine row sizing escaped the taint pass"
     assert all(f.taint_chain for f in hits)
-    assert any("len(running)" in f.taint_chain[0] for f in hits)
+    assert any("len(reqs)" in f.taint_chain[0] for f in hits)
 
 
 def test_planted_encoder_warmup_removal_is_caught_as_shp002(tmp_path):
@@ -493,17 +493,20 @@ def test_planted_ring_perm_without_modulo_is_caught_as_spd004(tmp_path):
 
 
 def test_planted_donated_page_reread_is_caught_as_spd002(tmp_path):
-    """Stop rebinding the scatter_pages result on the migrate path: the
+    """Stop rebinding the scatter_pages result on the fault-in path: the
     donated device page pools are then re-read on the next loop pass, and
     SPD002 must carry the donate-site -> stale-read witness."""
     dst = _mutated_tree(
         tmp_path, "serving/engine.py",
-        "self._dk_pages, self._dv_pages, _, _ = scatter_pages(",
-        "_, _, _, _ = scatter_pages(")
+        "(self._k_pages, self._v_pages, self._k_scales,\n"
+        "             self._v_scales) = scatter_pages(\n"
+        "                self._k_pages, self._v_pages, idx_d,",
+        "_ = scatter_pages(\n"
+        "                self._k_pages, self._v_pages, idx_d,")
     findings, _ = run_paths([dst])
     hits = [f for f in findings if f.rule == "SPD002" and not f.suppressed]
     assert hits, "donated page-pool re-read escaped the SPMD pass"
-    assert any("self._dk_pages" in f.message for f in hits)
+    assert any("self._k_pages" in f.message for f in hits)
     for f in hits:
         assert f.taint_chain
         assert "scatter_pages" in f.taint_chain[0]
