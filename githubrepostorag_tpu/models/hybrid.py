@@ -1,12 +1,16 @@
 """The step skeleton of a hybrid of recurrent-state layers and paged
 full-attention layers: what models/qwen3_next.py, models/olmo_hybrid.py
-(Gated DeltaNet) and models/nemotron_h.py (Mamba-2) share.
+(Gated DeltaNet), models/nemotron_h.py and models/falcon_h1.py (Mamba-2)
+share, and the two recurrent mixers themselves.
 
 A model is a LAYER PATTERN, ``cfg.layer_segments``: a tuple of ``(kinds,
 repeats)``, ``kinds`` a string of one letter a layer: ``STATE`` ("R": a layer
 that keeps a slot of recurrent state a sequence), ``ATTN`` ("A": a layer that
 pages keys and values), ``PLAIN`` ("F": a layer that keeps nothing, a
-feed-forward or expert block of its own).  Three Gated DeltaNet layers then
+feed-forward or expert block of its own), ``BOTH`` ("B": a layer whose two
+mixers, one of each cache, run side by side on ONE ``mixer_input`` and are
+summed: it counts among the state layers and among the page layers, and its
+slot of state and its pages belong to the same layer).  Three Gated DeltaNet layers then
 one attention layer, twice, is ``(("RRRA", 2),)``; ``segments()`` below cuts
 any string into such runs.  The prefill wave scans a segment's repeats (one
 traced copy of ``kinds`` however often it repeats: the compile is the
@@ -46,13 +50,14 @@ which model it runs):
 * ``weights(params) -> w``: whatever its own functions index, opaque here;
   ``state_weights(w, n)`` / ``attn_weights(w, n)``: the mixer weights of the
   n-th layer of that kind;
-* ``embed(params, ids)`` (the residual stream, float32), ``position_cols(cfg,
+* ``embed(cfg, params, ids)`` (the residual stream, float32), ``position_cols(cfg,
   positions)`` (what runs along the chunk beside it: rotary tables, or
-  nothing), ``final(cfg, params, h)`` and ``head(params, h)``;
+  nothing), ``final(cfg, params, h)`` and ``head(cfg, params, h)``;
 * the block's wiring: ``mixer_input(cfg, w, li, h)`` (a pre-norm, or a cast)
   and ``after_mixer(cfg, w, li, h, y, live) -> (h, counts or None)`` (the
   residual add and, where a block has one, the feed-forward or expert layer
-  and its add); for a ``PLAIN`` layer, ``plain_layer(cfg, w, n, li, h, live) ->
+  and its add; ``y`` of a ``BOTH`` layer is the pair ``(the state mixer's
+  output, the attention mixer's)``, summed there); for a ``PLAIN`` layer, ``plain_layer(cfg, w, n, li, h, live) ->
   (h, counts or None)``, the whole block;
 * the recurrent mixer, both forms: ``state_chunk(cfg, p, x, s0, taps0, live,
   new_lens, snap_col, page_size) -> (y, s, s_snap, taps, taps_snap)`` and
@@ -74,6 +79,7 @@ which model it runs):
 from __future__ import annotations
 
 import math
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -87,14 +93,18 @@ from githubrepostorag_tpu.ops.gated_delta import (
     l2norm,
     mask_padding,
 )
+from githubrepostorag_tpu.ops import ssd
 from githubrepostorag_tpu.ops.latent_attention import einsum_f32
-from githubrepostorag_tpu.ops.norms import rms_norm_gated
+from githubrepostorag_tpu.ops.norms import rms_norm_gate_first, rms_norm_gated
+from githubrepostorag_tpu.ops.pallas_state import ssd_step_in_place
 from githubrepostorag_tpu.ops.prefill_width import at_wave_width
 from githubrepostorag_tpu.ops.sampling import sample_tokens_capped, sample_tokens_nofilter
 from githubrepostorag_tpu.runtime import _pinned_to_cpu, on_tpu
 
 
-STATE, ATTN, PLAIN = "R", "A", "F"  # the letters of ``cfg.layer_segments``
+STATE, ATTN, PLAIN, BOTH = "R", "A", "F", "B"  # the letters of ``cfg.layer_segments``
+# the kinds a layer counts among: a ``BOTH`` layer is the next state layer AND the next page layer
+_COUNTS_AS = {STATE: (STATE,), ATTN: (ATTN,), PLAIN: (PLAIN,), BOTH: (STATE, ATTN)}
 
 
 def segments(kinds: str) -> tuple:
@@ -134,8 +144,13 @@ def _walk(layer_segments):
     for kinds, reps in layer_segments:
         yield kinds, reps, dict(before)
         for k in (STATE, ATTN, PLAIN):
-            before[k] += kinds.count(k) * reps
+            before[k] += _of_kind(kinds, k) * reps
         before["all"] += len(kinds) * reps
+
+
+def _of_kind(kinds: str, k: str) -> int:
+    """Layers of ``kinds`` that count among the layers of kind ``k``."""
+    return sum(k in _COUNTS_AS[c] for c in kinds)
 
 
 def _index(base: int, rep, per: int, off: int):
@@ -147,12 +162,14 @@ def _index(base: int, rep, per: int, off: int):
 
 def _layers(kinds: str, before: dict, rep):
     """(kind, index among the layers of its kind, index among all layers) of
-    each layer of repeat ``rep`` of a segment ``_walk`` gave."""
+    each layer of repeat ``rep`` of a segment ``_walk`` gave; for a ``BOTH``
+    layer the pair (index among the state layers, among the page layers)."""
     seen = dict.fromkeys(before, 0)
     for j, kind in enumerate(kinds):
-        yield (kind, _index(before[kind], rep, kinds.count(kind), seen[kind]),
-               _index(before["all"], rep, len(kinds), j))
-        seen[kind] += 1
+        n = tuple(_index(before[k], rep, _of_kind(kinds, k), seen[k]) for k in _COUNTS_AS[kind])
+        yield kind, (n if kind == BOTH else n[0]), _index(before["all"], rep, len(kinds), j)
+        for k in _COUNTS_AS[kind]:
+            seen[k] += 1
 
 
 def tpu_compiler_options(options: dict) -> dict | None:
@@ -311,6 +328,152 @@ def gdn_step(m, cfg, p, x, s_old, taps_old):
     return m.gdn_out(cfg, p, o[:, None], z), s_new, taps
 
 
+# ------------------------------------------------------- the Mamba-2 mixer --
+# What models/nemotron_h.py and models/falcon_h1.py share (ops/ssd.py is the
+# rule, ops/pallas_state.py its kernel on the pool).  ``cfg`` states the
+# shapes: ``mamba_num_heads`` heads of ``mamba_head_dim``, ``ssm_state_size``,
+# ``n_groups`` groups that share ``B`` and ``C``, ``d_inner``, ``rms_norm_eps``.
+# A layer's weights ``p``: ``w_z`` | ``w_xbc`` | ``w_dt`` (in_proj as the three
+# runs of columns that are read apart, each its own product in either program),
+# ``conv_w``, ``conv_b``, ``A_log``, ``dt_bias``, ``D``, ``o_norm``, ``w_out``.
+
+def ssm_inputs(cfg, p, x, act, scales=None):
+    """x [B, S, d] normed -> (the convolution's input [B, S, C]: x | B | C, in
+    ``act`` as the history keeps it; z [B, S, d_inner]; dt [B, S, H] softplus'd).
+    ``scales``: what the float32 results of the three products are multiplied
+    by, ``(z, (x, B, C), dt)``, where a family scales runs of in_proj's columns
+    (muP multipliers); None: the products as they are."""
+    with jax.named_scope("ssm_proj"):
+        z, xbc, dt = (einsum_f32("bsd,de->bse", x, p[k]) for k in ("w_z", "w_xbc", "w_dt"))
+        if scales is not None:
+            gn = cfg.n_groups * cfg.ssm_state_size
+            runs = jnp.concatenate([jnp.full((n,), v, jnp.float32)
+                                    for n, v in zip((cfg.d_inner, gn, gn), scales[1])])
+            z, xbc, dt = z * scales[0], xbc * runs, dt * scales[2]
+        dt = jax.nn.softplus(dt + p["dt_bias"])
+    return xbc.astype(act), z, dt
+
+
+def ssm_heads(cfg, y):
+    """The convolution's output [B, S, C] float32 -> (x [B, S, H, P]; B, C
+    [B, S, G, N])."""
+    b, s, _ = y.shape
+    di, gn = cfg.d_inner, cfg.n_groups * cfg.ssm_state_size
+    return (y[..., :di].reshape(b, s, cfg.mamba_num_heads, cfg.mamba_head_dim),
+            y[..., di:di + gn].reshape(b, s, cfg.n_groups, cfg.ssm_state_size),
+            y[..., di + gn:].reshape(b, s, cfg.n_groups, cfg.ssm_state_size))
+
+
+def ssm_out(cfg, p, y, z, act):
+    """y [B, S, H, P] float32 and the gate -> the mixer's output [B, S, d]:
+    the norm GATE FIRST and BY GROUP, then ``out_proj``."""
+    with jax.named_scope("ssm_gate_norm"):
+        y = rms_norm_gate_first(y.reshape(*y.shape[:2], -1), z, p["o_norm"], cfg.n_groups,
+                                cfg.rms_norm_eps)
+    return einsum_f32("bse,ed->bsd", y.astype(act), p["w_out"])
+
+
+def ssm_chunk(m, cfg, p, x, s0, taps0, live, new_lens, snap_col, page_size):
+    """A Mamba-2 mixer over a chunk, ``m.state_chunk`` of the models that have
+    one: ``m.ssm_inputs``, the convolution (with its bias) and its history, the
+    chunked rule, ``m.ssm_out``."""
+    mixed, z, dt = m.ssm_inputs(cfg, p, x)
+    with jax.named_scope("ssm_conv"):
+        y, taps, taps_snap = causal_conv(
+            mixed, taps0.reshape(x.shape[0], -1, mixed.shape[-1]), p["conv_w"], new_lens,
+            snap_col, bias=p["conv_b"])
+        taps, taps_snap = (t.reshape(t.shape[0], -1) for t in (taps, taps_snap))
+    xs, b, c = ssm_heads(cfg, y)
+    with jax.named_scope("ssm_chunked"):
+        o, s_new, s_snap = ssd.ssd_chunked(
+            s0, xs, ssd.mask_padding(live, dt), -jnp.exp(p["A_log"]), b, c, p["D"], snap_col,
+            block=math.gcd(ssd.BLOCK, page_size))
+    return m.ssm_out(cfg, p, o, z), s_new, s_snap, taps, taps_snap
+
+
+def _ssm_token(m, cfg, p, x, taps_old):
+    """One token a row up to the rule: (x [B, H, P]; dt [B, H]; B, C [B, G, N];
+    the gate z; the history after the token)."""
+    bsz = x.shape[0]
+    mixed, z, dt = m.ssm_inputs(cfg, p, x)
+    with jax.named_scope("ssm_conv"):
+        y, taps = causal_conv_step(mixed[:, 0], taps_old.reshape(bsz, -1, mixed.shape[-1]),
+                                   p["conv_w"], bias=p["conv_b"])
+        taps = taps.reshape(bsz, -1)
+    xs, b, c = ssm_heads(cfg, y[:, None])
+    return xs[:, 0], dt[:, 0], b[:, 0], c[:, 0], z, taps
+
+
+def ssm_step(m, cfg, p, x, s_old, taps_old):
+    """A Mamba-2 mixer over one token a row (``m.state_step``), as array code:
+    the CPU's path, and what the kernel below is held to."""
+    xs, dt, b, c, z, taps = _ssm_token(m, cfg, p, x, taps_old)
+    with jax.named_scope("ssm_recurrent"):
+        o, s_new = ssd.ssd_step(s_old.astype(jnp.float32), xs, dt, -jnp.exp(p["A_log"]), b, c,
+                                p["D"])
+    return m.ssm_out(cfg, p, o[:, None], z), s_new, taps
+
+
+@partial(jax.jit, static_argnames=("interpret",))
+def _rule_in_pool(s_pool, n, act, xs, dt, a, b, c, d, interpret):
+    """ops/pallas_state.ssd_step_in_place under the rule's scope: the call is
+    named for it in a device trace, where the rule's roofline looks.  Jitted so
+    that the burst traces the kernel's body (every head unrolled: 1.6 s at 64)
+    once and not once a layer: the layer's index is an operand."""
+    with jax.named_scope("ssm_recurrent"):
+        return ssd_step_in_place(s_pool, n, act, xs, dt, a, b, c, d, interpret=interpret)
+
+
+def ssm_step_in_pool(m, cfg, p, x, s_pool, n, taps_old, act, interpret):
+    """The same mixer with the rule as a kernel on the state pool itself
+    (``m.state_step_in_pool``): layer ``n``'s rows that are ``act`` are read
+    once and written once where they lie, the others are not touched."""
+    xs, dt, b, c, z, taps = _ssm_token(m, cfg, p, x, taps_old)
+    o, s_pool = _rule_in_pool(s_pool, jnp.int32(n), act, xs, dt, -jnp.exp(p["A_log"]), b, c,
+                              p["D"], interpret=interpret)
+    return m.ssm_out(cfg, p, o[:, None], z), s_pool, taps
+
+
+U_MAX = 2147483648.0 * (0.02 / 1.24e9)  # a draw is uniform in +-U_MAX (models/quant._devrand)
+
+
+def draw_leaves(order: list, seed: int) -> dict:
+    """The leaves of ``order`` ((path, shape, gain), a family's ``leaf_order``)
+    made on the device from the seed, each a bfloat16 draw of std ~0.02
+    (models/quant._devrand) times its gain (a power of two: exact); the salt
+    advances once a leaf, in ``order``'s order."""
+    from githubrepostorag_tpu.models.quant import _devrand
+
+    salt = jnp.uint32(seed * 40503 + 12345)
+    draw = jax.jit(_devrand, static_argnums=(0, 2))
+    params: dict = {}
+    for path, shape, gain in order:
+        salt = salt * jnp.uint32(747796405) + jnp.uint32(1)
+        leaf = draw(tuple(shape), salt, "bf16")
+        if gain != 1.0:
+            leaf = (leaf.astype(jnp.float32) * gain).astype(jnp.bfloat16)
+        node = params
+        for key in path[:-1]:
+            node = node.setdefault(key, {})
+        node[path[-1]] = leaf
+    return params
+
+
+def ssm_scalars(cfg, a_u, dt_u):
+    """(``A_log``, ``dt_bias``) [layers, heads] float32 from two uniform draws
+    in +-``U_MAX``, as the Mamba-2 families' initialiser makes them: ``A`` from
+    U(1, 16); ``dt_bias`` the inverse softplus of a step drawn log-uniformly in
+    [``time_step_min``, ``time_step_max``], floored at ``time_step_floor``.  A
+    token's decay ``exp(-A softplus(dt + dt_bias))`` then runs from ~0.2 to
+    0.999 over the heads: some remember thousands of tokens, none forgets
+    within one (the Gated DeltaNets' U(0, 16) did, and got a ladder)."""
+    u = lambda x: x.astype(jnp.float32) / (2.0 * U_MAX) + 0.5  # noqa: E731 - in [0, 1]
+    a = 1.0 + 15.0 * u(a_u)
+    lo, hi = math.log(cfg.time_step_min), math.log(cfg.time_step_max)
+    dt = jnp.maximum(jnp.exp(lo + u(dt_u) * (hi - lo)), cfg.time_step_floor)
+    return jnp.log(a), dt + jnp.log(-jnp.expm1(-dt))
+
+
 # ----------------------------------------------------------- step programs --
 
 def wave(m, params, cfg, input_ids, positions, k_pages, v_pages, slot_mapping, block_tables,
@@ -325,7 +488,7 @@ def wave(m, params, cfg, input_ids, positions, k_pages, v_pages, slot_mapping, b
 
     num_pages, page_size = k_pages.shape[2], k_pages.shape[3]
     nkv, hd = cfg.num_kv_heads, cfg.head_dim
-    h = m.embed(params, input_ids)
+    h = m.embed(cfg, params, input_ids)
     along = m.position_cols(cfg, positions)
     slots = jnp.where(slot_mapping < 0, num_pages * page_size, slot_mapping)  # dropped
     live = jnp.arange(input_ids.shape[1])[None, :] < new_lens[:, None]
@@ -348,12 +511,23 @@ def wave(m, params, cfg, input_ids, positions, k_pages, v_pages, slot_mapping, b
     # layer's rows of state are read before its switch and written after it;
     # the attention layer's switch ends at q, k, v, its pages are committed
     # and attended outside, and a second switch takes the rest of the layer.
-    def state_layer(g, li, h, st_pools, stats):
+    def read_state(st_pools, g):
         s_pool, c_pool = st_pools
         with jax.named_scope("state_read"):
             s0 = _cut(state_read(s_pool, g, state_src), cut).astype(jnp.float32)
             taps0 = state_read(c_pool, g, state_src)
+        return s0, taps0
 
+    def write_state(st_pools, g, s_new, s_snap, taps, taps_snap):
+        s_pool, c_pool = st_pools
+        with jax.named_scope("state_write"):
+            s_new, s_snap = (_fill(x, s_pool.shape[-1]) for x in (s_new, s_snap))
+            s_pool = state_write(state_write(s_pool, g, state_dst, s_new), g, state_snap, s_snap)
+            c_pool = state_write(state_write(c_pool, g, state_dst, taps), g, state_snap,
+                                 taps_snap)
+        return s_pool, c_pool
+
+    def state_layer(g, li, h, st_pools, stats):
         def layer(cols, came_in):
             h, live = cols[0], cols[len(along) + 1]
             s0, taps0 = came_in
@@ -363,25 +537,14 @@ def wave(m, params, cfg, input_ids, positions, k_pages, v_pages, slot_mapping, b
             h, st = m.after_mixer(cfg, w, li, h, y, live)
             return h, (s_new, s_snap, taps, taps_snap, st)
 
-        h, (s_new, s_snap, taps, taps_snap, st) = at_wave_width(
-            layer, width, page_size, (h, *cols[1:]), (s0, taps0))
-        with jax.named_scope("state_write"):
-            s_new, s_snap = (_fill(x, s_pool.shape[-1]) for x in (s_new, s_snap))
-            s_pool = state_write(state_write(s_pool, g, state_dst, s_new), g, state_snap, s_snap)
-            c_pool = state_write(state_write(c_pool, g, state_dst, taps), g, state_snap,
-                                 taps_snap)
-        return h, (s_pool, c_pool), add(stats, st)
+        h, (*new, st) = at_wave_width(
+            layer, width, page_size, (h, *cols[1:]), read_state(st_pools, g))
+        return h, write_state(st_pools, g, *new), add(stats, st)
 
-    def attn_layer(pi, li, h, kv_pools, stats):
+    def attend(pi, q, k, v, kv_pools):
+        """A layer's keys and values into their pages and the chunk's attention
+        over them, outside every switch.  Returns (the pools, attn)."""
         kp, vp = kv_pools
-
-        def project(cols, _):
-            h = cols[0]
-            q, k, v, more = m.attn_project(cfg, m.attn_weights(w, pi),
-                                           m.mixer_input(cfg, w, li, h), *cols[1:len(along) + 1])
-            return h, tuple(padded(t) for t in (q, *more, k, v))
-
-        _, (q, *more, k, v) = at_wave_width(project, width, page_size, (h, *cols[1:]), ())
         with jax.named_scope("kv_write"):
             flat, run = slots.reshape(-1), slots.shape[1]  # a row's columns: consecutive positions
             kp, _ = commit_paged(kp, k.reshape(-1, nkv, hd).swapaxes(0, 1), flat, None,
@@ -413,6 +576,17 @@ def wave(m, params, cfg, input_ids, positions, k_pages, v_pages, slot_mapping, b
             else:
                 attn = paged_attention_ref(q, kp[pi], vp[pi], block_tables, cached_lens,
                                            new_lens)
+        return (kp, vp), attn
+
+    def attn_layer(pi, li, h, kv_pools, stats):
+        def project(cols, _):
+            h = cols[0]
+            q, k, v, more = m.attn_project(cfg, m.attn_weights(w, pi),
+                                           m.mixer_input(cfg, w, li, h), *cols[1:len(along) + 1])
+            return h, tuple(padded(t) for t in (q, *more, k, v))
+
+        _, (q, *more, k, v) = at_wave_width(project, width, page_size, (h, *cols[1:]), ())
+        kv_pools, attn = attend(pi, q, k, v, kv_pools)
 
         def rest(cols, came_in):
             h, live, attn, *more = cols[0], *cols[len(along) + 1:]
@@ -420,7 +594,38 @@ def wave(m, params, cfg, input_ids, positions, k_pages, v_pages, slot_mapping, b
                                  live)
 
         h, st = at_wave_width(rest, width, page_size, (h, *cols[1:], attn, *more), ())
-        return h, (kp, vp), add(stats, st)
+        return h, kv_pools, add(stats, st)
+
+    def both_layer(n, li, h, st_pools, kv_pools, stats):
+        """Both mixers on one ``mixer_input``: the first switch holds the state
+        mixer whole and the attention mixer up to q, k, v (they share nothing
+        but ``x``: the compiler orders them as it likes); the second the
+        attention's output projection, the sum and the rest of the layer."""
+        g, pi = n
+
+        def mixers(cols, came_in):
+            h, live = cols[0], cols[len(along) + 1]
+            s0, taps0 = came_in
+            x = m.mixer_input(cfg, w, li, h)
+            y, s_new, s_snap, taps, taps_snap = m.state_chunk(
+                cfg, m.state_weights(w, g), x, s0, taps0, live, new_lens, snap_col, page_size)
+            q, k, v, more = m.attn_project(cfg, m.attn_weights(w, pi), x,
+                                           *cols[1:len(along) + 1])
+            return h, (s_new, s_snap, taps, taps_snap,
+                       tuple(padded(t) for t in (y, q, *more, k, v)))
+
+        _, (*new, (y, q, *more, k, v)) = at_wave_width(
+            mixers, width, page_size, (h, *cols[1:]), read_state(st_pools, g))
+        st_pools = write_state(st_pools, g, *new)
+        kv_pools, attn = attend(pi, q, k, v, kv_pools)
+
+        def rest(cols, came_in):
+            h, live, y, attn, *more = cols[0], *cols[len(along) + 1:]
+            return m.after_mixer(
+                cfg, w, li, h, (y, m.attn_out(m.attn_weights(w, pi), attn, *more)), live)
+
+        h, st = at_wave_width(rest, width, page_size, (h, *cols[1:], y, attn, *more), ())
+        return h, st_pools, kv_pools, add(stats, st)
 
     def plain_layer(n, li, h, stats):
         def layer(cols, _):
@@ -439,6 +644,9 @@ def wave(m, params, cfg, input_ids, positions, k_pages, v_pages, slot_mapping, b
                     h, st_pools, stats = state_layer(n, li, h, st_pools, stats)
                 elif kind == ATTN:
                     h, kv_pools, stats = attn_layer(n, li, h, kv_pools, stats)
+                elif kind == BOTH:
+                    h, st_pools, kv_pools, stats = both_layer(n, li, h, st_pools, kv_pools,
+                                                              stats)
                 else:
                     h, stats = plain_layer(n, li, h, stats)
             return (h, rep + 1, kv_pools, st_pools, stats), None
@@ -450,7 +658,7 @@ def wave(m, params, cfg, input_ids, positions, k_pages, v_pages, slot_mapping, b
         h = m.final(cfg, params, h)
         if logits_at is not None:
             h = jnp.take_along_axis(h, logits_at[:, None, None], axis=1)
-        logits = m.head(params, h)
+        logits = m.head(cfg, params, h)
     return logits, k_pages, v_pages, stats, {"s": st_pools[0], "conv": st_pools[1]}
 
 
@@ -500,7 +708,7 @@ def burst(m, params, cfg, last_tokens, seq_lens, k_pages, v_pages, presence, act
         last, lens, staged, st_pools, pres, act, stats = carry
         step, step_rng = step_xs
         act = act & (lens < row_limits)
-        h = m.embed(params, jnp.maximum(last, 0)[:, None])
+        h = m.embed(cfg, params, jnp.maximum(last, 0)[:, None])
         along = m.position_cols(cfg, lens[:, None])
 
         def state_mixer(p, g, x, st_pools):
@@ -567,13 +775,18 @@ def burst(m, params, cfg, last_tokens, seq_lens, k_pages, v_pages, presence, act
                         x = m.mixer_input(cfg, w, li, h)
                         if kind == STATE:
                             y, st_pools = state_mixer(m.state_weights(w, n), n, x, st_pools)
-                        else:
+                        elif kind == ATTN:
                             y, staged = attn_mixer(m.attn_weights(w, n), n, x, staged)
+                        else:  # BOTH: one input, two mixers that meet in ``after_mixer``
+                            g, pi = n
+                            ys, st_pools = state_mixer(m.state_weights(w, g), g, x, st_pools)
+                            ya, staged = attn_mixer(m.attn_weights(w, pi), pi, x, staged)
+                            y = (ys, ya)
                         h, st = m.after_mixer(cfg, w, li, h, y, live)
                     if st is not None:
                         stats = stats + st
         with jax.named_scope("sample"):
-            logits = m.head(params, m.final(cfg, params, h))
+            logits = m.head(cfg, params, m.final(cfg, params, h))
             if filter_sampling:
                 toks = sample_tokens_capped(logits[:, 0], step_rng, temperature, top_p, top_k,
                                             repetition_penalty, pres)

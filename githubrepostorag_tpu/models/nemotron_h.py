@@ -2,7 +2,8 @@
 a hybrid whose block is ONE sublayer, ``h = h + mixer(RMSNorm(h))``, of three
 kinds in the order a pattern string gives (``hybrid_override_pattern``):
 
-* ``M``, Mamba-2: a state-space layer (ops/ssd.py).  ``in_proj`` gives the gate
+* ``M``, Mamba-2: a state-space layer (ops/ssd.py; the mixer itself is
+  models/hybrid.py's ``ssm_*``, shared with models/falcon_h1.py).  ``in_proj`` gives the gate
   ``z``, the convolution's input ``x | B | C`` and a step size a head; a causal
   depthwise convolution WITH a bias, then SiLU; ``dt = softplus(dt + dt_bias)``,
   ``A = -exp(A_log)``; the state ``S [heads, head width, state size]`` float32,
@@ -29,7 +30,6 @@ bfloat16 operands.
 
 from __future__ import annotations
 
-import math
 from dataclasses import dataclass
 from functools import partial
 
@@ -38,14 +38,11 @@ import jax.numpy as jnp
 
 from githubrepostorag_tpu.models import hybrid
 from githubrepostorag_tpu.models.moe import dropless_experts, route_noaux_tc
-from githubrepostorag_tpu.models.quant import _devrand, embedding_lookup
+from githubrepostorag_tpu.models.quant import embedding_lookup
 from githubrepostorag_tpu.obs import startup
-from githubrepostorag_tpu.ops.gated_delta import causal_conv, causal_conv_step
 from githubrepostorag_tpu.ops.latent_attention import einsum_f32
-from githubrepostorag_tpu.ops.norms import rms_norm, rms_norm_gate_first
-from githubrepostorag_tpu.ops.pallas_state import ssd_step_in_place
+from githubrepostorag_tpu.ops.norms import rms_norm
 from githubrepostorag_tpu.ops.sampling import first_token_tail
-from githubrepostorag_tpu.ops.ssd import BLOCK, mask_padding, ssd_chunked, ssd_step
 
 ACT = jnp.bfloat16  # products take bfloat16 operands; the residual stream is float32
 # columns of a prefill chunk one call of the attention kernel takes: 16 query
@@ -162,14 +159,13 @@ class NemotronHConfig:
 ROUTER_GAIN = 2.0  # the router's draw, times this: logits of std ~2.1 (sigmoid scores unsaturated)
 CONV_GAIN = 16.0  # the convolution's taps, times this: std ~0.32 (models/qwen3_next.py)
 BIAS_GAIN = 4.0  # the convolution's bias, times this: std ~0.08
-U_MAX = 2147483648.0 * (0.02 / 1.24e9)  # a draw is uniform in +-U_MAX (models/quant._devrand)
 
 
 def leaf_order(cfg: NemotronHConfig) -> list:
     """(path, shape, gain) of every leaf the initialiser draws, in draw order:
     each draw advances the salt once.  A draw is a bfloat16 leaf of std ~0.02
     times ``gain`` (a power of two: exact).  ``a_u`` and ``dt_u`` are the
-    uniform draws ``A_log`` and ``dt_bias`` are made from (``ssm_scalars``),
+    uniform draws ``A_log`` and ``dt_bias`` are made from (``hybrid.ssm_scalars``),
     ``e_bias`` the router's selection bias (float32, std 0.02: it reorders near
     neighbours and gives no expert a following of its own,
     models/deepseek_v3.py).  The benchmark's reference re-states this list."""
@@ -201,45 +197,20 @@ def leaf_order(cfg: NemotronHConfig) -> list:
     ]
 
 
-def ssm_scalars(cfg: NemotronHConfig, a_u, dt_u):
-    """(``A_log``, ``dt_bias``) [M layers, heads] float32 from two uniform draws
-    in +-``U_MAX``, as the family's initialiser makes them: ``A`` from U(1, 16);
-    ``dt_bias`` the inverse softplus of a step drawn log-uniformly in
-    [``time_step_min``, ``time_step_max``], floored at ``time_step_floor``.  A
-    token's decay ``exp(-A softplus(dt + dt_bias))`` then runs from ~0.2 to
-    0.999 over the heads: some remember thousands of tokens, none forgets
-    within one (the Gated DeltaNets' U(0, 16) did, and got a ladder)."""
-    u = lambda x: x.astype(jnp.float32) / (2.0 * U_MAX) + 0.5  # noqa: E731 - in [0, 1]
-    a = 1.0 + 15.0 * u(a_u)
-    lo, hi = math.log(cfg.time_step_min), math.log(cfg.time_step_max)
-    dt = jnp.maximum(jnp.exp(lo + u(dt_u) * (hi - lo)), cfg.time_step_floor)
-    return jnp.log(a), dt + jnp.log(-jnp.expm1(-dt))
-
-
 @startup.records("startup.weights", settle=True)
 def init_params(cfg: NemotronHConfig, seed: int = 0) -> dict:
     """Weights made on the device from the seed, leaf by leaf, in bfloat16
     (models/quant._devrand), as the other hybrids' are.  Every norm at one,
     the skip ``D`` at one (the published initialiser's), ``A_log`` and
-    ``dt_bias`` as ``ssm_scalars`` says.  The expert stacks hold the
+    ``dt_bias`` as ``hybrid.ssm_scalars`` says.  The expert stacks hold the
     ``experts_held`` range only.  ``wq | wk | wv`` are laid side by side as the
     one product the attention layer runs; the Mamba-2 projections stay three
     leaves, each read whole by its own product in either program."""
-    salt = jnp.uint32(seed * 40503 + 12345)
-    params: dict = {"norm": jnp.ones((cfg.hidden_size,), jnp.bfloat16)}
-    draw = jax.jit(_devrand, static_argnums=(0, 2))
-    for path, shape, gain in leaf_order(cfg):
-        salt = salt * jnp.uint32(747796405) + jnp.uint32(1)
-        leaf = draw(tuple(shape), salt, "bf16")
-        if gain != 1.0:
-            leaf = (leaf.astype(jnp.float32) * gain).astype(jnp.bfloat16)
-        node = params
-        for key in path[:-1]:
-            node = node.setdefault(key, {})
-        node[path[-1]] = leaf
+    params = hybrid.draw_leaves(leaf_order(cfg), seed)
+    params["norm"] = jnp.ones((cfg.hidden_size,), jnp.bfloat16)
     ssm, attn, moe = params["ssm"], params["attn"], params["moe"]
     attn["wqkv"] = jnp.concatenate([attn.pop("wq"), attn.pop("wk"), attn.pop("wv")], axis=-1)
-    ssm["A_log"], ssm["dt_bias"] = ssm_scalars(cfg, ssm.pop("a_u"), ssm.pop("dt_u"))
+    ssm["A_log"], ssm["dt_bias"] = hybrid.ssm_scalars(cfg, ssm.pop("a_u"), ssm.pop("dt_u"))
     ssm.update(D=jnp.ones((cfg.state_layers, cfg.mamba_num_heads), jnp.float32),
                o_norm=jnp.ones((cfg.state_layers, cfg.d_inner), jnp.bfloat16))
     moe["e_bias"] = moe["e_bias"].astype(jnp.float32)
@@ -251,91 +222,6 @@ def init_params(cfg: NemotronHConfig, seed: int = 0) -> dict:
 
 def _norm(cfg, x, w):
     return rms_norm(x, w, cfg.rms_norm_eps).astype(ACT)
-
-
-def _ssm_inputs(cfg, p, x):
-    """x [B, S, d] normed -> (the convolution's input [B, S, C]: x | B | C, in
-    ``ACT`` as the history keeps it; z [B, S, d_inner]; dt [B, S, H] softplus'd)."""
-    with jax.named_scope("ssm_proj"):
-        z, xbc, dt = (einsum_f32("bsd,de->bse", x, p[k]) for k in ("w_z", "w_xbc", "w_dt"))
-        dt = jax.nn.softplus(dt + p["dt_bias"])
-    return xbc.astype(ACT), z, dt
-
-
-def _ssm_heads(cfg, y):
-    """The convolution's output [B, S, C] float32 -> (x [B, S, H, P]; B, C
-    [B, S, G, N])."""
-    b, s, _ = y.shape
-    di, gn = cfg.d_inner, cfg.n_groups * cfg.ssm_state_size
-    return (y[..., :di].reshape(b, s, cfg.mamba_num_heads, cfg.mamba_head_dim),
-            y[..., di:di + gn].reshape(b, s, cfg.n_groups, cfg.ssm_state_size),
-            y[..., di + gn:].reshape(b, s, cfg.n_groups, cfg.ssm_state_size))
-
-
-def _ssm_out(cfg, p, y, z):
-    """y [B, S, H, P] float32 and the gate -> the mixer's output [B, S, d]."""
-    with jax.named_scope("ssm_gate_norm"):
-        y = rms_norm_gate_first(y.reshape(*y.shape[:2], -1), z, p["o_norm"], cfg.n_groups,
-                                cfg.rms_norm_eps)
-    return einsum_f32("bse,ed->bsd", y.astype(ACT), p["w_out"])
-
-
-def _ssm_chunk(cfg, p, x, s0, taps0, live, new_lens, snap_col, page_size):
-    """A Mamba-2 mixer over a chunk (models/hybrid.py's ``state_chunk``)."""
-    mixed, z, dt = _ssm_inputs(cfg, p, x)
-    with jax.named_scope("ssm_conv"):
-        y, taps, taps_snap = causal_conv(
-            mixed, taps0.reshape(x.shape[0], -1, mixed.shape[-1]), p["conv_w"], new_lens,
-            snap_col, bias=p["conv_b"])
-        taps, taps_snap = (t.reshape(t.shape[0], -1) for t in (taps, taps_snap))
-    xs, b, c = _ssm_heads(cfg, y)
-    with jax.named_scope("ssm_chunked"):
-        o, s_new, s_snap = ssd_chunked(
-            s0, xs, mask_padding(live, dt), -jnp.exp(p["A_log"]), b, c, p["D"], snap_col,
-            block=math.gcd(BLOCK, page_size))
-    return _ssm_out(cfg, p, o, z), s_new, s_snap, taps, taps_snap
-
-
-def _ssm_token(cfg, p, x, taps_old):
-    """One token a row up to the rule: (x [B, H, P]; dt [B, H]; B, C [B, G, N];
-    the gate z; the history after the token)."""
-    bsz = x.shape[0]
-    mixed, z, dt = _ssm_inputs(cfg, p, x)
-    with jax.named_scope("ssm_conv"):
-        y, taps = causal_conv_step(mixed[:, 0], taps_old.reshape(bsz, -1, mixed.shape[-1]),
-                                   p["conv_w"], bias=p["conv_b"])
-        taps = taps.reshape(bsz, -1)
-    xs, b, c = _ssm_heads(cfg, y[:, None])
-    return xs[:, 0], dt[:, 0], b[:, 0], c[:, 0], z, taps
-
-
-def _ssm_step(cfg, p, x, s_old, taps_old):
-    """A Mamba-2 mixer over one token a row (``state_step``), as array code:
-    the CPU's path, and what the kernel below is held to."""
-    xs, dt, b, c, z, taps = _ssm_token(cfg, p, x, taps_old)
-    with jax.named_scope("ssm_recurrent"):
-        o, s_new = ssd_step(s_old.astype(jnp.float32), xs, dt, -jnp.exp(p["A_log"]), b, c, p["D"])
-    return _ssm_out(cfg, p, o[:, None], z), s_new, taps
-
-
-@partial(jax.jit, static_argnames=("interpret",))
-def _rule_in_pool(s_pool, n, act, xs, dt, a, b, c, d, interpret):
-    """ops/pallas_state.ssd_step_in_place under the rule's scope: the call is
-    named for it in a device trace, where the rule's roofline looks.  Jitted so
-    that the burst traces the kernel's body (64 heads unrolled: 1.6 s) once and
-    not once a layer: the layer's index is an operand."""
-    with jax.named_scope("ssm_recurrent"):
-        return ssd_step_in_place(s_pool, n, act, xs, dt, a, b, c, d, interpret=interpret)
-
-
-def _ssm_step_in_pool(cfg, p, x, s_pool, n, taps_old, act, interpret):
-    """The same mixer with the rule as a kernel on the state pool itself
-    (``state_step_in_pool``): layer ``n``'s rows that are ``act`` are read once
-    and written once where they lie, the others are not touched."""
-    xs, dt, b, c, z, taps = _ssm_token(cfg, p, x, taps_old)
-    o, s_pool = _rule_in_pool(s_pool, jnp.int32(n), act, xs, dt, -jnp.exp(p["A_log"]), b, c,
-                              p["D"], interpret=interpret)
-    return _ssm_out(cfg, p, o[:, None], z), s_pool, taps
 
 
 def _attn_project(cfg, p, x):
@@ -417,16 +303,19 @@ class _Layers:
     weights = staticmethod(lambda params: _split(params))
     state_weights = staticmethod(lambda w, n: hybrid.at(w[0]["ssm"], n))
     attn_weights = staticmethod(lambda w, n: hybrid.at(w[0]["attn"], n))
-    state_chunk = staticmethod(lambda *a: _ssm_chunk(*a))
-    state_step = staticmethod(lambda *a: _ssm_step(*a))
-    state_step_in_pool = staticmethod(lambda *a: _ssm_step_in_pool(*a))
+    # the Mamba-2 mixer is models/hybrid.py's, shared with models/falcon_h1.py
+    state_chunk = staticmethod(lambda *a: hybrid.ssm_chunk(_Layers, *a))
+    state_step = staticmethod(lambda *a: hybrid.ssm_step(_Layers, *a))
+    state_step_in_pool = staticmethod(lambda *a: hybrid.ssm_step_in_pool(_Layers, *a))
+    ssm_inputs = staticmethod(lambda cfg, p, x: hybrid.ssm_inputs(cfg, p, x, ACT))
+    ssm_out = staticmethod(lambda cfg, p, y, z: hybrid.ssm_out(cfg, p, y, z, ACT))
     attn_project = staticmethod(lambda cfg, p, x: _attn_project(cfg, p, x))
     attn_out = staticmethod(lambda p, attn: _attn_out(p, attn))
     position_cols = staticmethod(lambda cfg, positions: ())  # positions do not enter
     after_mixer = staticmethod(lambda cfg, w, li, h, y, live: (h + y, None))
 
     @staticmethod
-    def embed(params, ids):
+    def embed(cfg, params, ids):
         return embedding_lookup(params["embed"], ids).astype(jnp.float32)
 
     @staticmethod
@@ -446,7 +335,7 @@ class _Layers:
         return _norm(cfg, h, params["norm"])
 
     @staticmethod
-    def head(params, h):
+    def head(cfg, params, h):
         return einsum_f32("bsd,dv->bsv", h, params["lm_head"])
 
 

@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from githubrepostorag_tpu.models import hybrid
-from githubrepostorag_tpu.models.quant import _devrand, embedding_lookup
+from githubrepostorag_tpu.models.quant import embedding_lookup
 from githubrepostorag_tpu.obs import startup
 from githubrepostorag_tpu.ops.latent_attention import einsum_f32
 from githubrepostorag_tpu.ops.norms import rms_norm
@@ -198,18 +198,8 @@ def init_params(cfg: OlmoHybridConfig, seed: int = 0) -> dict:
     product reads are then laid side by side: ``w_q | w_k | w_v | w_g`` and
     ``w_b | w_a`` of a Gated DeltaNet layer (the order models/hybrid.gdn_inputs
     reads), ``wq | wk | wv`` of an attention layer, gate | up of the MLP."""
-    salt = jnp.uint32(seed * 40503 + 12345)
-    params: dict = {"norm": jnp.ones((cfg.hidden_size,), jnp.bfloat16)}
-    draw = jax.jit(_devrand, static_argnums=(0, 2))
-    for path, shape, gain in leaf_order(cfg):
-        salt = salt * jnp.uint32(747796405) + jnp.uint32(1)
-        leaf = draw(tuple(shape), salt, "bf16")
-        if gain != 1.0:
-            leaf = (leaf.astype(jnp.float32) * gain).astype(jnp.bfloat16)
-        node = params
-        for key in path[:-1]:
-            node = node.setdefault(key, {})
-        node[path[-1]] = leaf
+    params = hybrid.draw_leaves(leaf_order(cfg), seed)
+    params["norm"] = jnp.ones((cfg.hidden_size,), jnp.bfloat16)
     gdn, attn, mlp = params["gdn"], params["attn"], params["mlp"]
     _side_by_side(gdn, "w_qkvz", ("w_q", "w_k", "w_v", "w_g"))
     _side_by_side(gdn, "w_ba", ("w_b", "w_a"))
@@ -276,7 +266,7 @@ class _Layers:
     mixer_input = staticmethod(lambda cfg, w, li, h: h.astype(ACT))  # no input norm
 
     @staticmethod
-    def embed(params, ids):
+    def embed(cfg, params, ids):
         return embedding_lookup(params["embed"], ids).astype(jnp.float32)
 
     @staticmethod
@@ -284,7 +274,7 @@ class _Layers:
         return rms_norm(h, params["norm"], cfg.rms_norm_eps).astype(ACT)
 
     @staticmethod
-    def head(params, h):
+    def head(cfg, params, h):
         return einsum_f32("bsd,dv->bsv", h, params["lm_head"])
 
 

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 
 from githubrepostorag_tpu.models import hybrid
 from githubrepostorag_tpu.models.moe import dropless_experts
-from githubrepostorag_tpu.models.quant import _devrand, embedding_lookup
+from githubrepostorag_tpu.models.quant import embedding_lookup
 from githubrepostorag_tpu.obs import startup
 from githubrepostorag_tpu.ops.latent_attention import einsum_f32
 from githubrepostorag_tpu.ops.norms import rms_norm_zero_centered
@@ -237,18 +237,8 @@ def init_params(cfg: Qwen3NextConfig, seed: int = 0) -> dict:
     as the one product the attention layer runs, and the Gated DeltaNet
     projections' columns, drawn in the published order, are put once into
     the order their products are read in (``_by_kind``)."""
-    salt = jnp.uint32(seed * 40503 + 12345)
-    params: dict = {"norm": jnp.zeros((cfg.hidden_size,), jnp.bfloat16)}
-    draw = jax.jit(_devrand, static_argnums=(0, 2))
-    for path, shape, gain in leaf_order(cfg):
-        salt = salt * jnp.uint32(747796405) + jnp.uint32(1)
-        leaf = draw(tuple(shape), salt, "bf16")
-        if gain != 1.0:
-            leaf = (leaf.astype(jnp.float32) * gain).astype(jnp.bfloat16)
-        node = params
-        for key in path[:-1]:
-            node = node.setdefault(key, {})
-        node[path[-1]] = leaf
+    params = hybrid.draw_leaves(leaf_order(cfg), seed)
+    params["norm"] = jnp.zeros((cfg.hidden_size,), jnp.bfloat16)
     attn = params["attn"]
     attn["wqkv"] = jnp.concatenate([attn.pop("wq"), attn.pop("wk"), attn.pop("wv")], axis=-1)
     gdn = params["gdn"]
@@ -366,10 +356,10 @@ class _Layers:
     gdn_out = staticmethod(lambda cfg, p, o, z: hybrid.gdn_out(cfg, p, o, z, ACT))
     attn_project = staticmethod(lambda cfg, p, x, cos, sin: _attn_project(cfg, p, x, cos, sin))
     attn_out = staticmethod(lambda p, attn, gate: _attn_out(p, attn, gate))
-    head = staticmethod(lambda params, h: _head(params, h))
+    head = staticmethod(lambda cfg, params, h: _head(params, h))
 
     @staticmethod
-    def embed(params, ids):
+    def embed(cfg, params, ids):
         return embedding_lookup(params["embed"], ids).astype(jnp.float32)
 
     @staticmethod

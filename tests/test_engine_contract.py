@@ -1,8 +1,8 @@
 """What ``Engine.step``'s one decode path owes every family, whatever its
 step programs: the contracts the speculative paths were held to before
-PR 45 took them out, asked of the plain path under each of the five
+PR 45 took them out, asked of the plain path under each of the six
 families' own programs (qwen2, deepseek_v3, qwen3_next, olmo_hybrid,
-nemotron_h) at tiny widths, in float32 so that a token is a statement about
+nemotron_h, falcon_h1) at tiny widths, in float32 so that a token is a statement about
 scheduling and not about rounding.
 
 One warm engine a family (prefix cache on) takes the traffic; a second
@@ -26,12 +26,13 @@ from tests.helpers.compile_guard import compile_guard, watchdog_counter
 PAGE, CHUNK, ROWS, SEQ, BURST = 16, 64, 2, 160, 4
 GEOMETRY = dict(max_num_seqs=ROWS, num_pages=48, page_size=PAGE, max_seq_len=SEQ,
                 prefill_chunk=CHUNK, decode_burst=BURST, kv_dtype=jnp.float32)
-FAMILIES = ("qwen2", "deepseek_v3", "qwen3_next", "olmo_hybrid", "nemotron_h")
+FAMILIES = ("qwen2", "deepseek_v3", "qwen3_next", "olmo_hybrid", "nemotron_h", "falcon_h1")
 # each family's ``tiny()``, a hybrid cut to one period of its layer pattern:
 # the contracts are the scheduler's, and every kind of layer is still there
 TINY = {"deepseek_v3": dict(experts_held=(4, 12)),
         "qwen3_next": dict(experts_held=(4, 12), num_layers=4),
-        "olmo_hybrid": dict(num_layers=4), "nemotron_h": dict(pattern="MEM*E")}
+        "olmo_hybrid": dict(num_layers=4), "nemotron_h": dict(pattern="MEM*E"),
+        "falcon_h1": dict(num_layers=2)}  # every layer is a period: both caches in each
 
 RNG = np.random.default_rng(45)
 HEAD = [int(t) for t in RNG.integers(3, 500, size=3 * PAGE)]  # three shareable pages

@@ -177,7 +177,7 @@ def test_each_published_convention_shows_in_float32(in_float32, monkeypatch, ref
                                                     departure):
     """The tight limit sees every piece of the block's wiring: the program
     with one of them changed is not the reference by 20x the limit."""
-    from githubrepostorag_tpu.models import moe
+    from githubrepostorag_tpu.models import hybrid, moe
     from githubrepostorag_tpu.ops import gated_delta, norms, ssd
     from githubrepostorag_tpu.ops.rope import rope_cos_sin, rope_rotate_leading
 
@@ -186,16 +186,17 @@ def test_each_published_convention_shows_in_float32(in_float32, monkeypatch, ref
             xg = x.reshape(*x.shape[:-1], groups, -1)
             xg = xg * jax.lax.rsqrt(jnp.mean(xg * xg, axis=-1, keepdims=True) + eps)
             return xg.reshape(x.shape) * weight * jax.nn.silu(gate)
-        monkeypatch.setattr(model, "rms_norm_gate_first", norm_first)
+        monkeypatch.setattr(hybrid, "rms_norm_gate_first", norm_first)  # the shared mixer's
     elif departure == "norm_over_the_whole_width":
-        monkeypatch.setattr(model, "rms_norm_gate_first",
+        monkeypatch.setattr(hybrid, "rms_norm_gate_first",
                             lambda x, g, w, groups, eps: norms.rms_norm_gate_first(x, g, w, 1, eps))
     elif departure == "no_conv_bias":
-        monkeypatch.setattr(model, "causal_conv",
+        monkeypatch.setattr(hybrid, "causal_conv",
                             lambda *a, bias=None: gated_delta.causal_conv(*a))
     elif departure == "no_skip":
-        monkeypatch.setattr(model, "ssd_chunked", lambda s, x, dt, a, b, c, d, *r, **k:
-                            ssd.ssd_chunked(s, x, dt, a, b, c, jnp.zeros_like(d), *r, **k))
+        chunked = ssd.ssd_chunked
+        monkeypatch.setattr(ssd, "ssd_chunked", lambda s, x, dt, a, b, c, d, *r, **k:
+                            chunked(s, x, dt, a, b, c, jnp.zeros_like(d), *r, **k))
     elif departure == "biased_weights":  # the selection bias left in the weights
         def biased(scores, bias, *a):
             return moe.route_noaux_tc(scores + bias[None], jnp.zeros_like(bias), *a)
