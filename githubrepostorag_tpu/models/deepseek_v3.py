@@ -499,7 +499,11 @@ def decode_burst(
     b, L = last_tokens.shape[0], cfg.num_layers
     num_pages, page_size = k_pages.shape[2], k_pages.shape[3]
     rows = jnp.arange(b)
-    start_lens = seq_lens
+    start_lens = seq_lens  # pool validity is frozen for the whole burst
+    # what attention walks: a row that sits the whole burst out (mid-prefill,
+    # finished and still in the chained lens, at its limit) has its result
+    # thrown away, so it is handed over as holding nothing
+    walk_lens = jnp.where(active & (seq_lens < row_limits), start_lens, 0)
     interpret = not on_tpu()
     scale = cfg.softmax_scale
 
@@ -521,7 +525,7 @@ def decode_burst(
                     out = latent_decode_attention(
                         (q_lat * scale).astype(latent.dtype),
                         (q_rope[:, 0].astype(jnp.float32) * scale).astype(latent.dtype),
-                        k_pages, li, block_tables, start_lens,
+                        k_pages, li, block_tables, walk_lens,
                         jax.lax.dynamic_index_in_dim(staged2, li, 0, keepdims=False),
                         step + 1, use_pallas=use_pallas, interpret=interpret)
                     o = jnp.einsum("bhc,hcv->bhv", out, p["wuv"])

@@ -12,9 +12,9 @@ things (DeepSeek-V2, arXiv:2405.04434, section 2.1):
   query heads over one shared key of width 576 whose first 512 columns are
   also the value.  A token of context costs one row read (1,152 B in
   bfloat16) and 2 * H * (576 + 512) FLOPs.  On the chip a Pallas kernel walks
-  the block table page by page (flash-decoding, as ops/pallas_paged.py) and
-  folds in the burst's staged tail; elsewhere a gather and dense products
-  stand in for it and are its oracle.
+  the pages that live rows hold with its own DMAs (ops/pallas_paged.py's
+  burst kernel over one pool) and folds in the burst's staged tail; elsewhere
+  a gather and dense products stand in for it and are its oracle.
 
 * **prefill of a chunk** (``latent_prefill_attention``): the materialised
   form.  For S new tokens against a long cached prefix the absorbed form
@@ -65,114 +65,249 @@ def _softmax_step(s, values, m_ref, l_ref, acc_ref):
 
 
 PAGES_PER_STEP = 8  # my chip runs, PR 27, one layer at the cell's shapes: the prefill kernel
-# (1 x 512 queries over 8,704 rows) 20.6 / 9.7 / 6.6 / 5.6 ms at 1 / 2 / 4 / 8 pages a step, the
-# decode kernel (22 live rows x 8,700) 1.63 / 1.04 / 0.82 / 0.75 ms
+# (1 x 512 queries over 8,704 rows) 20.6 / 9.7 / 6.6 / 5.6 ms at 1 / 2 / 4 / 8 pages a step
+# (the decode kernel walks in waves of its own since PR 48: DECODE_WAVE_PAGES, below)
 
 
 TILE_PAGES = 4  # pages of K and V the XLA oracle of the prefill path rebuilds at a time
 
 
 def _pages_per_step(max_pages: int) -> int:
-    """Pages one grid step reads: the pool is handed to the kernel that many
-    times, each operand's index map picking its own page of the block table,
-    so a step's products are that many pages wide and its fixed cost (~0.35
-    us, and small products that leave the MXU idle) is paid that much less
-    often.  Must divide the table's width."""
+    """Pages one grid step of the prefill kernel reads: the pool is handed to
+    it that many times, each operand's index map picking its own page of the
+    block table, so a step's products are that many pages wide and its fixed
+    cost (~0.35 us, and small products that leave the MXU idle) is paid that
+    much less often.  Must divide the table's width."""
     return next(n for n in (PAGES_PER_STEP, 4, 2, 1) if max_pages % n == 0)
 
 
 # ------------------------------------------------------------------ decode --
 
-def _decode_kernel(*refs, page_size: int, rank: int, rope: int, pps: int):
-    """Grid (B, max_pages / pps + 1): the first steps walk the row's block
-    table ``pps`` pages at a time (steps past ``lens`` skip compute and re-use
-    page 0's block, so no DMA is issued for them), the last folds in the
-    staged tail and writes the normalised output.  Products take bfloat16
-    operands and accumulate in float32: at 242 FLOP per byte the kernel sits
+DECODE_WAVE_PAGES = 8  # pages a wave of the decode kernel holds.  My chip runs, PR 48, one layer
+# at the cell's shapes (18 live rows of 8.3-9.4k of 32): 0.370 ms a call; 4 pages 0.376, 16 pages
+# 0.389; with the arithmetic taken out 0.286 (the DMAs: 713 GB/s), with the DMAs taken out 0.331
+
+
+WAVE_SLOTS = 5  # the wave folded, the two whose scores are taken beside it, two in flight (7 or 9
+# slots: the same 0.370 ms)
+
+
+def _decode_kernel(bt_ref, lens_ref, slen_ref, layer_ref, q_ref, pool_hbm, st_ref, out_ref,
+                   buf, sems, state, s_ref, m_ref, l_ref, acc_ref, *, page_size: int, rank: int,
+                   wave: int):
+    """Absorbed decode attention: online softmax over [the pages a row holds
+    | the burst's staged tail], ops/pallas_paged.py:_burst_kernel's walk over
+    one latent pool.  Grid (B / R,): a step takes R row slots one after the
+    other.  The pages of the LIVE rows are one stream of waves of ``wave``
+    pages (a row's ``ceil(len / page_size)`` pages, row after row): each page
+    is one DMA from the pool in HBM straight into rows [j * page_size, (j + 1)
+    * page_size) of a VMEM slot, so a wave is one contiguous [wave *
+    page_size, width] tile, and the stream is started three to five waves
+    ahead of the wave being folded, across rows and grid steps.  A dead row
+    (length 0) is no part of it: no page, no DMA, no grid step; it costs the
+    one small product over its staged rows.
+
+    A row's waves are folded two at a time, and a wave's scores are taken in
+    the basic block that folds the wave before it (the even waves' through
+    ``s_ref``, the odd ones' as a value), so its products run on the MXU while
+    the other's softmax runs on the VPU: one wave at a time, product -> max ->
+    exp -> weighted sum is a chain, and the MXU waits through the softmax
+    (0.411 ms a call against 0.370, PR 48).  Only a row's last wave can hold a
+    key past its length, so only it is masked.  The row then folds in its
+    staged rows (positions < ``staged_len``) and writes its normalised output.
+
+    One score product over the STORED width: q = [q_lat | q_rope | 0] against
+    rows [c_kv | k_rope | pad]; the values are a row's first ``rank`` columns,
+    a lane-aligned view of the same tile.  Products take the pool's dtype
+    (bfloat16) and accumulate in float32: at 242 FLOP per byte the kernel sits
     on the v5e's ridge, and a float32 product would put it far on the wrong
     side.
 
-    Refs: scalar prefetch [block tables, pool lens, staged len, layer], blocks
-    [q_lat (1, H, rank), q_rope (1, H, rope), ``pps`` pages of the pool, staged
-    (1, n_steps, width)], out (1, H, rank), scratch [m, l (H, 128), acc]."""
-    bt_ref, lens_ref, slen_ref, layer_ref, ql_ref, qr_ref = refs[:6]
-    k_refs = refs[6:6 + pps]
-    st_ref, out_ref, m_ref, l_ref, acc_ref = refs[6 + pps:]
-    bi, pi = pl.program_id(0), pl.program_id(1)
-    num_pi = pl.num_programs(1)
+    Refs: scalar prefetch [block tables (B, max_pages), pool lens (B), staged
+    len (1), layer (1)], q (R, H, width), the pool WHOLE in HBM, staged (R,
+    n_steps, width), out (R, H, rank), scratch [wave slots (WAVE_SLOTS, wave *
+    page_size, width), their DMA semaphores, the stream's state (4,) SMEM, the
+    even waves' scores (H, wave * page_size), m, l (H, 128), acc (H, rank)
+    float32]."""
+    rows, max_pages = bt_ref.shape
+    block_rows, slots = q_ref.shape[0], buf.shape[0]
+    pool = pool_hbm.at[layer_ref[0], 0]  # [P, page_size, width]
+    nt = (((1,), (1,)), ((), ()))  # a [m, k] . b [n, k] -> [m, n]
+    # the stream's state: the (row, wave) to start next and its slot; the next wave folded's slot
+    next_row, next_wave, next_slot, fold_slot = range(4)
 
-    @pl.when(pi == 0)
+    def waves_of(row):
+        return (lens_ref[row] + wave * page_size - 1) // (wave * page_size)
+
+    def page_dmas(row, w, slot, go):
+        """``go`` (start or wait) on the DMA of every page ``row`` holds in
+        its wave ``w``, each into its own rows of slot ``slot``."""
+        held = (lens_ref[row] + page_size - 1) // page_size
+        for j in range(wave):
+            @pl.when(w * wave + j < held)
+            def _():
+                page = bt_ref[row, jnp.minimum(w * wave + j, max_pages - 1)]
+                go(pltpu.make_async_copy(
+                    pool.at[page], buf.at[slot, pl.ds(j * page_size, page_size)], sems.at[slot]))
+
+    def live_row(after):
+        """The first live row at or after ``after`` (``rows``: none)."""
+        return jax.lax.while_loop(
+            lambda r: (r < rows) & (lens_ref[jnp.minimum(r, rows - 1)] == 0),
+            lambda r: r + 1, after)
+
+    def start_next():
+        """Start the stream's next wave, if it has one, and step its state."""
+        row, w, slot = state[next_row], state[next_wave], state[next_slot]
+
+        @pl.when(row < rows)
+        def _():
+            page_dmas(row, w, slot, lambda dma: dma.start())
+            last = w + 1 >= waves_of(row)
+            state[next_row] = jnp.where(last, live_row(row + 1), row)
+            state[next_wave] = jnp.where(last, 0, w + 1)
+            state[next_slot] = (slot + 1) % slots
+
+    # read out here: the interpreter has no program_id inside a loop's body
+    first_row = pl.program_id(0) * block_rows
+
+    @pl.when(first_row == 0)
     def _():
+        # a row's last wave fills only the pages the row holds; what the rest
+        # of the slot holds meets a weight of exactly 0 and must be finite
+        buf[...] = jnp.zeros_like(buf)
+        state[next_row] = live_row(0)
+        state[next_wave] = 0
+        state[next_slot] = 0
+        state[fold_slot] = 0
+        for _ in range(slots - 2):
+            start_next()
+
+    def one_row(r, carry):
+        bi = first_row + r
+        total = lens_ref[bi]
+        n_waves = waves_of(bi)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        q = q_ref[r]  # [H, width]
+        slot0 = state[fold_slot]  # where this row's first wave was sent
 
-    total = lens_ref[bi]
-    start = pi * pps * page_size
-    nt = (((1,), (1,)), ((), ()))  # a [m, k] . b [n, k] -> [m, n]
+        def slot_of(w):
+            return (slot0 + w) % slots
 
-    def scores(c_kv, k_rope):
-        return jax.lax.dot_general(ql_ref[0], c_kv, nt, preferred_element_type=jnp.float32) \
-            + jax.lax.dot_general(qr_ref[0], k_rope, nt, preferred_element_type=jnp.float32)
+        def landed(w):
+            page_dmas(bi, w, slot_of(w), lambda dma: dma.wait())
 
-    @pl.when((pi < num_pi - 1) & (start < total))
-    def _():
-        rows = [k[0, 0, 0] for k in k_refs]  # pps x [page_size, width]
-        tile = rows[0] if pps == 1 else jnp.concatenate(rows, axis=0)
-        c_kv = tile[:, :rank]
-        s = scores(c_kv, tile[:, rank:rank + rope])
-        kv_pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        _softmax_step(jnp.where(kv_pos < total, s, NEG_INF), c_kv, m_ref, l_ref, acc_ref)
+        def scores(w):
+            return jax.lax.dot_general(q, buf[slot_of(w)], nt, preferred_element_type=jnp.float32)
 
-    @pl.when(pi == num_pi - 1)
-    def _():
-        c_kv = st_ref[0, :, :rank]  # [n_steps, rank]
-        s = scores(c_kv, st_ref[0, :, rank:rank + rope])
+        def fold(s, w, last: bool):
+            if last:
+                kv_pos = w * wave * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                s = jnp.where(kv_pos < total, s, NEG_INF)
+            _softmax_step(s, buf[slot_of(w), :, :rank], m_ref, l_ref, acc_ref)
+
+        @pl.when(n_waves > 0)
+        def _():
+            landed(0)
+            s_ref[...] = scores(0)
+
+        def two_waves(k, carry):
+            """Waves 2k and 2k + 1, where 2k + 2 is there too: neither is the last."""
+            w = 2 * k
+            start_next()
+            start_next()
+            landed(w + 1)
+            landed(w + 2)
+            s_odd = scores(w + 1)
+            fold(s_ref[...], w, last=False)
+            s_ref[...] = scores(w + 2)
+            fold(s_odd, w + 1, last=False)
+            return carry
+
+        pairs = jnp.maximum(n_waves - 1, 0) // 2
+        jax.lax.fori_loop(0, pairs, two_waves, 0)
+        w = 2 * pairs  # what is left: waves w and w + 1, wave w, or nothing (a dead row)
+
+        @pl.when(n_waves - w == 2)
+        def _():
+            start_next()
+            start_next()
+            landed(w + 1)
+            s_odd = scores(w + 1)
+            fold(s_ref[...], w, last=False)
+            fold(s_odd, w + 1, last=True)
+
+        @pl.when(n_waves - w == 1)
+        def _():
+            start_next()
+            fold(s_ref[...], w, last=True)
+
+        state[fold_slot] = slot_of(n_waves)
+
+        staged = st_ref[r]  # [n_steps, width]
+        s = jax.lax.dot_general(q, staged, nt, preferred_element_type=jnp.float32)
         idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        _softmax_step(jnp.where(idx < slen_ref[0], s, NEG_INF), c_kv, m_ref, l_ref, acc_ref)
-        l = l_ref[:, :1]  # staged_len >= 1, so l > 0 for padding rows too
-        out_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(out_ref.dtype)
+        _softmax_step(jnp.where(idx < slen_ref[0], s, NEG_INF), staged[:, :rank],
+                      m_ref, l_ref, acc_ref)
+        l = l_ref[:, :1]  # staged_len >= 1, so l > 0 for dead rows too
+        out_ref[r] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(out_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, block_rows, one_row, 0)
 
 
+DECODE_ROWS_PER_STEP = 8  # row slots one grid step of the decode kernel takes (4: the same time)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def _decode_pallas(q_lat, q_rope, pool, layer, block_tables, pool_lens, staged, staged_len,
                    interpret: bool):
+    """Jitted for its trace cache alone, as ``_prefill_pallas`` is: the dense
+    and the expert stack of every burst program call it with the same shapes,
+    and the kernel's unrolled DMA descriptors make it the dearest thing in a
+    burst to trace and to lower."""
     b, h, rank = q_lat.shape
-    rope = q_rope.shape[-1]
     page_size, width = pool.shape[3], pool.shape[4]
     max_pages, n_steps = block_tables.shape[1], staged.shape[1]
-    pps = _pages_per_step(max_pages)
-    walk = max_pages // pps
+    wave = min(DECODE_WAVE_PAGES, max_pages)
+    block_rows = next(r for r in range(min(b, DECODE_ROWS_PER_STEP), 0, -1) if b % r == 0)
+    # the pad columns of the pool are zeros by construction; the query's are made so here
+    pad = jnp.zeros((b, h, width - rank - q_rope.shape[-1]), q_lat.dtype)
+    q = jnp.concatenate([q_lat, q_rope, pad], axis=-1)
 
-    def row_map(bi, pi, *refs):
-        return (bi, 0, 0)
-
-    def page_map(j):
-        def index(bi, pi, bt, lens, slen, li):
-            at = jnp.minimum(pi, walk - 1) * pps + j
-            page = jax.lax.select((pi < walk) & (at * page_size < lens[bi]), bt[bi, at], 0)
-            return (li[0], 0, page, 0, 0)
-        return index
+    def row_map(gi, *refs):
+        return (gi, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(b, walk + 1),
-        in_specs=[pl.BlockSpec((1, h, rank), row_map), pl.BlockSpec((1, h, rope), row_map)]
-        + [pl.BlockSpec((1, 1, 1, page_size, width), page_map(j)) for j in range(pps)]
-        + [pl.BlockSpec((1, n_steps, width), row_map)],
-        out_specs=pl.BlockSpec((1, h, rank), row_map),
-        scratch_shapes=[pltpu.VMEM((h, 128), jnp.float32), pltpu.VMEM((h, 128), jnp.float32),
+        grid=(b // block_rows,),
+        in_specs=[pl.BlockSpec((block_rows, h, width), row_map),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec((block_rows, n_steps, width), row_map)],
+        out_specs=pl.BlockSpec((block_rows, h, rank), row_map),
+        scratch_shapes=[pltpu.VMEM((WAVE_SLOTS, wave * page_size, width), pool.dtype),
+                        pltpu.SemaphoreType.DMA((WAVE_SLOTS,)), pltpu.SMEM((4,), jnp.int32),
+                        pltpu.VMEM((h, wave * page_size), jnp.float32),
+                        pltpu.VMEM((h, 128), jnp.float32), pltpu.VMEM((h, 128), jnp.float32),
                         pltpu.VMEM((h, rank), jnp.float32)],
     )
-    # tpulint: disable=SHP003 -- built at trace time only: the one caller is the jitted decode burst (models/deepseek_v3.py), reached through a closure the linter does not follow
-    return pl.pallas_call(
-        functools.partial(_decode_kernel, page_size=page_size, rank=rank, rope=rope, pps=pps),
+    call = pl.pallas_call(
+        functools.partial(_decode_kernel, page_size=page_size, rank=rank, wave=wave),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, rank), q_lat.dtype),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        # rows in order on one core: the first step zeroes the slots and starts
+        # the stream, which runs ahead of the rows across steps
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), pool_lens.astype(jnp.int32),
-      jnp.reshape(staged_len, (1,)).astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
-      q_lat, q_rope, *([pool] * pps), staged)
+    )
+    # XLA names the custom call after the innermost scope: the name a trace's
+    # reader finds it by, kept under this function's own jit
+    with jax.named_scope("latent_attention"):
+        return call(block_tables.astype(jnp.int32), pool_lens.astype(jnp.int32),
+                    jnp.reshape(staged_len, (1,)).astype(jnp.int32),
+                    jnp.reshape(layer, (1,)).astype(jnp.int32), q, pool, staged)
 
 
 def _decode_gather(q_lat, q_rope, pool, layer, block_tables, pool_lens, staged, staged_len):

@@ -213,6 +213,147 @@ def test_absorbed_attention_equals_materialised_attention(use_pallas, monkeypatc
     np.testing.assert_allclose(np.asarray(absorbed), np.asarray(mat[:, 0]), rtol=2e-4, atol=2e-4)
 
 
+def walk_case(lens, staged_len=3, wave=2, ps=16, max_pages=8, order="shuffled"):
+    """A case of the decode kernel's walk: rows of ``lens`` cached tokens over
+    pages of ``ps`` rows and tables of ``max_pages``, waves of ``wave`` pages."""
+    return dict(lens=lens, staged_len=staged_len, wave=wave, ps=ps, max_pages=max_pages,
+                order=order)
+
+
+def walk_args(lens, staged_len, ps, max_pages, order, seed=0, h=4, rank=16, rope=8, width=128,
+              n_steps=8, layers=3):
+    """Arguments of ``latent_decode_attention`` (float32, layer 1 of 3): every
+    live row's pages its own, a dead row's table zeroed, page 0 no row's."""
+    rng = np.random.default_rng(seed)
+    need = [-(-n // ps) for n in lens]
+    pages = sum(need) + 3
+    pool = np.zeros((layers, 1, pages, ps, width), np.float32)
+    pool[..., :rank + rope] = rng.normal(size=(layers, 1, pages, ps, rank + rope))
+    ids = {"shuffled": rng.permutation(pages - 1) + 1, "descending": np.arange(pages - 1, 0, -1)}
+    bt = np.zeros((len(lens), max_pages), np.int32)
+    taken = 0
+    for row, n in enumerate(need):
+        bt[row, :n] = ids[order][taken:taken + n]
+        taken += n
+    staged = np.zeros((len(lens), n_steps, width), np.float32)
+    staged[..., :rank + rope] = rng.normal(size=(len(lens), n_steps, rank + rope))
+    q_lat, q_rope = rng.normal(size=(len(lens), h, rank)), rng.normal(size=(len(lens), h, rope))
+    return (jnp.asarray(q_lat, jnp.float32), jnp.asarray(q_rope, jnp.float32), jnp.asarray(pool),
+            jnp.int32(1), jnp.asarray(bt), jnp.asarray(lens, jnp.int32), jnp.asarray(staged),
+            jnp.int32(staged_len))
+
+
+def three_live_of_32():
+    lens = [0] * 32
+    lens[5], lens[17], lens[30] = 70, 33, 128  # the last slot is a dead row
+    return lens
+
+
+WALK_CASES = [
+    pytest.param(walk_case(three_live_of_32()), id="32-slots-3-live"),
+    pytest.param(walk_case([0, 1, 127, 128, 129, 256], ps=128, max_pages=2, wave=1),
+                 id="around-a-page-of-128-one-wave-plus-1"),
+    pytest.param(walk_case([0, 1, 127, 128, 129, 256], ps=128, max_pages=2, wave=2),
+                 id="around-a-page-of-128-one-wave-exactly"),
+    pytest.param(walk_case([1, 15, 16, 17]), id="one-token-and-around-a-page"),
+    pytest.param(walk_case([31, 32, 33, 0]), id="around-a-wave"),
+    pytest.param(walk_case([64, 65, 127, 128], 4), id="waves-plus-one-to-the-whole-table"),
+    pytest.param(walk_case([128] * 4, 7), id="every-row-the-whole-table"),
+    pytest.param(walk_case([128] * 4, 7, wave=8), id="whole-table-one-wave"),
+    pytest.param(walk_case([16 * n for n in (8, 1, 7, 2, 6, 3, 5, 4)], 2, wave=1),
+                 id="one-to-eight-waves-folded-in-pairs"),
+    pytest.param(walk_case([0, 128, 0, 0, 113, 0, 128, 0, 0, 97, 0], 5, wave=1),
+                 id="the-stream-runs-ahead-over-dead-rows"),
+    pytest.param(walk_case([0, 77, 0, 0], 6), id="live-between-dead-and-a-dead-row-last"),
+    pytest.param(walk_case([0, 0, 0, 0, 0, 77, 0, 0], 6), id="one-live-row-of-many"),
+    pytest.param(walk_case([0, 0, 0, 0], 1), id="no-live-row-first-step"),
+    pytest.param(walk_case([40, 0, 128, 9], 1), id="staged-len-1"),
+    pytest.param(walk_case([40, 0, 128, 9], 8), id="staged-len-n-steps"),
+    pytest.param(walk_case([0, 33, 128, 16], 3, wave=1), id="waves-of-one-page"),
+    pytest.param(walk_case([1, 63, 64, 65, 127, 128, 0, 33], 3, wave=4), id="around-waves-of-4"),
+    pytest.param(walk_case([50, 0, 97, 16], 3, order="descending"), id="pages-out-of-order"),
+    pytest.param(walk_case([0, 97, 0, 16, 17, 128], 8, wave=16), id="wave-wider-than-the-table"),
+]
+
+
+@pytest.mark.parametrize("case", WALK_CASES)
+def test_decode_kernel_walks_what_live_rows_hold(monkeypatch, case):
+    """The decode kernel, interpreted, against the gather path over ragged
+    tables: what a walk over the rows' own pages can get wrong."""
+    case = dict(case)
+    monkeypatch.setattr(latent_ops, "DECODE_WAVE_PAGES", case.pop("wave"))
+    args = walk_args(**case)
+    want = latent_decode_attention(*args)
+    got = latent_decode_attention(*args, use_pallas=True, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+
+
+def test_decode_kernel_dmas_land_before_they_are_read(monkeypatch):
+    """Plain interpret mode copies at ``start()``; the TPU interpreter runs a
+    DMA only when it is waited for and watches every buffer for races: a wave
+    folded before its wait, a slot refilled while it is still read (the stream
+    is up to five waves ahead, over rows' ends) or a wait that matches no
+    start shows here (the last as a hang, not a failure)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    if not hasattr(pltpu, "InterpretParams"):
+        pytest.skip("this jax has no TPU interpreter")
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call as tpu_interpreter
+
+    monkeypatch.setattr(latent_ops, "DECODE_WAVE_PAGES", 1)
+    args = walk_args([0, 100, 0, 33, 128, 0, 17, 64, 0], 3, 16, 8, "shuffled", seed=5)
+    want = latent_decode_attention(*args)
+    got = latent_decode_attention(
+        *args, use_pallas=True,
+        interpret=pltpu.InterpretParams(detect_races=True, dma_execution_mode="on_wait"))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+    assert not tpu_interpreter.races.races_found
+
+
+def test_a_row_that_sits_the_burst_out_is_handed_over_as_holding_nothing(tiny, monkeypatch):
+    """A finished row still in the chained lens (inactive) and a row at its
+    ``row_limits``, both with a stale ``seq_lens`` and a zeroed block table:
+    attention is told they hold nothing, and the live row's tokens are what
+    they are with those rows empty."""
+    cfg, params = tiny
+    rng = np.random.default_rng(7)
+    pool = jnp.asarray(rng.normal(size=(cfg.num_layers, 1, PAGES, PAGE, cfg.head_dim)) * 0.1,
+                       jnp.bfloat16).at[..., cfg.kv_lora_rank + cfg.qk_rope_head_dim:].set(0)
+    bt = np.zeros((4, 6), np.int32)
+    bt[1] = np.arange(7, 13)  # row 1 is live: 20 rows cached on pages 7, 8, 9
+    handed = []
+    inner = ds.latent_decode_attention
+
+    def recording(q_lat, q_rope, pool, layer, block_tables, pool_lens, *rest, **kw):
+        jax.debug.callback(lambda lens: handed.append(np.asarray(lens)), pool_lens)
+        return inner(q_lat, q_rope, pool, layer, block_tables, pool_lens, *rest, **kw)
+
+    monkeypatch.setattr(ds, "latent_decode_attention", recording)
+
+    def burst(seq_lens, active, limits):
+        b = len(seq_lens)
+        out = ds.decode_burst.__wrapped__(
+            params, cfg, jnp.asarray([3, 5, 7, 9], jnp.int32), jnp.asarray(seq_lens, jnp.int32),
+            pool, None, jnp.zeros((b, cfg.vocab_size), bool), jnp.asarray(active),
+            jnp.asarray(limits, jnp.int32), jnp.asarray(bt), jax.random.PRNGKey(0),
+            jnp.zeros((b,), jnp.float32), jnp.ones((b,), jnp.float32), jnp.zeros((b,), jnp.int32),
+            jnp.ones((b,), jnp.float32), n_steps=3, first_tokens=jnp.zeros((b,), jnp.int32),
+            fresh=jnp.zeros((b,), bool), fresh_lens=jnp.zeros((b,), jnp.int32),
+            key_step=jnp.uint32(0))
+        jax.effects_barrier()
+        return np.asarray(out[0]), np.asarray(out[5])
+
+    # row 0 finished (inactive, 37 in the chained lens), row 2 at its limit, row 3 free
+    stale, stale_lens = burst([37, 20, 40, 0], [False, True, True, False], [0, 48, 40, 0])
+    assert handed and all(list(lens) == [0, 20, 0, 0] for lens in handed)
+    handed.clear()
+    clean, clean_lens = burst([0, 20, 0, 0], [False, True, False, False], [0, 48, 0, 0])
+    assert all(list(lens) == [0, 20, 0, 0] for lens in handed)
+    assert (stale[1] >= 0).all() and list(stale[1]) == list(clean[1])
+    assert (stale[[0, 2, 3]] == -1).all()  # no token from a row that sat out
+    assert list(stale_lens) == [37, 23, 40, 0] and list(clean_lens) == [0, 23, 0, 0]
+
+
 def test_prefill_kernel_equals_the_tiled_oracle(monkeypatch):
     """A 16-token chunk over cached prefixes of 13 and 30 rows (and a padding
     row that walks no page): the Pallas kernel, interpreted, against the XLA

@@ -1,10 +1,11 @@
 """DeepSeek-V3's two step programs compiled whole for a TPU v5e that is
 described, not attached, at the shapes of the benchmark's cell
 (``deepseek-v3-ep16-bf16``: published widths, 1 dense + 4 expert layers, 16
-experts held, 2,048 pages of 128 latent rows): the decode kernel passes the
-chip's compiler, as does the prefill kernel, and nothing in the optimized HLO copies, transposes or
-slices the latent pool or a layer of it, nor an expert stack (PERF.md,
-Findings, PR 25 and PR 27); and the ops that the benchmark's
+experts held, 2,048 pages of 128 latent rows): the decode kernel (its own
+DMAs out of the pool whole in HBM) passes the chip's compiler, as does the
+prefill kernel, and nothing in the optimized HLO copies, transposes or slices
+the latent pool or a layer of it, nor an expert stack (PERF.md, Findings,
+PR 25 and PR 27); and the ops that the benchmark's
 ``moe_experts_hbm_frac`` picks out of a trace by their shapes are the
 products under the ``moe_experts`` scope, all of them and no others.  Nothing
 executes; a pass here is not a chip run.
@@ -103,6 +104,9 @@ def test_step_program_leaves_latent_pool_and_experts_in_place(chip, as_on_chip, 
     lowered, pool_shape, params = lowered_program(chip, program, variant)
     hlo = lowered.compile().as_text()
     assert "tpu_custom_call" in hlo  # the decode kernel, or the prefill kernel, is in the program
+    if program == "burst":  # one decode kernel a stack (dense, scanned), named as its metric reads it
+        kernels = re.findall(r"%(latent_attention[.\d]*) = [^\n]*\"tpu_custom_call\"", hlo)
+        assert len(kernels) == 2 and hlo.count('"tpu_custom_call"') == 2, kernels
     assert pool_movers(hlo, pool_shape) == []
     for name in ("e_wgu", "e_wd"):  # [Lm, n_held, in, out]: no copy of a stack or a layer's slab
         assert pool_movers(hlo, params["moe"][name].shape) == []
