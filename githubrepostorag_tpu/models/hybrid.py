@@ -69,7 +69,8 @@ which model it runs):
   ``state_step_in_pool(cfg, p, x, s_pool, n, taps_old, act, interpret) -> (y,
   s_pool, taps)``: the burst then hands it the whole pool, the layer's index
   and the step's live mask, and neither slices the pool nor selects the old
-  rows itself (``use_pallas``; ``state_step`` stays the array form);
+  rows itself (``use_pallas``; ``state_step`` stays the array form;
+  ``ssm_step_in_pool`` and ``gdn_step_in_pool`` below are the two there are);
 * ``attn_project(cfg, p, x, *position_cols) -> (q, k, v, more)`` /
   ``attn_out(p, attn, *more)`` around the shared pages and kernels;
 * ``attn_window``: columns of a prefill chunk one call of the attention
@@ -96,7 +97,7 @@ from githubrepostorag_tpu.ops.gated_delta import (
 from githubrepostorag_tpu.ops import ssd
 from githubrepostorag_tpu.ops.latent_attention import einsum_f32
 from githubrepostorag_tpu.ops.norms import rms_norm_gate_first, rms_norm_gated
-from githubrepostorag_tpu.ops.pallas_state import ssd_step_in_place
+from githubrepostorag_tpu.ops.pallas_state import gated_delta_step_in_place, ssd_step_in_place
 from githubrepostorag_tpu.ops.prefill_width import at_wave_width
 from githubrepostorag_tpu.ops.sampling import sample_tokens_capped, sample_tokens_nofilter
 from githubrepostorag_tpu.runtime import _pinned_to_cpu, on_tpu
@@ -312,9 +313,9 @@ def gdn_chunk(m, cfg, p, x, s0, taps0, live, new_lens, snap_col, page_size):
     return m.gdn_out(cfg, p, o, z), s_new, s_snap, taps, taps_snap
 
 
-def gdn_step(m, cfg, p, x, s_old, taps_old):
-    """A Gated DeltaNet mixer over one token a row, ``m.state_step``: the
-    state at the width the pool stores it (``gated_delta_step``)."""
+def _gdn_token(m, cfg, p, x, taps_old):
+    """One token a row up to the rule: (q, k [B, Hv, dk]; v [B, Hv, dv]; g, beta
+    [B, Hv]; the gate z; the history after the token)."""
     b = x.shape[0]
     mixed, z, beta, gate = m.gdn_inputs(cfg, p, x)
     with jax.named_scope("gdn_conv"):
@@ -322,10 +323,36 @@ def gdn_step(m, cfg, p, x, s_old, taps_old):
                                    p["conv_w"])
         taps = taps.reshape(b, -1)
     q, k, v = gdn_heads(cfg, y[:, None])
+    return q[:, 0], k[:, 0], v[:, 0], gate[:, 0], beta[:, 0], z, taps
+
+
+def gdn_step(m, cfg, p, x, s_old, taps_old):
+    """A Gated DeltaNet mixer over one token a row, ``m.state_step``: the
+    state at the width the pool stores it (``gated_delta_step``)."""
+    q, k, v, gate, beta, z, taps = _gdn_token(m, cfg, p, x, taps_old)
     with jax.named_scope("gdn_recurrent"):
-        o, s_new = gated_delta_step(s_old.astype(jnp.float32), q[:, 0], k[:, 0], v[:, 0],
-                                    gate[:, 0], beta[:, 0])
+        o, s_new = gated_delta_step(s_old.astype(jnp.float32), q, k, v, gate, beta)
     return m.gdn_out(cfg, p, o[:, None], z), s_new, taps
+
+
+@partial(jax.jit, static_argnames=("interpret",))
+def _gdn_rule_in_pool(s_pool, n, act, q, k, v, g, beta, interpret):
+    """ops/pallas_state.gated_delta_step_in_place under the rule's scope, as
+    ``_rule_in_pool`` is Mamba-2's: named for the scope and for its first
+    result (``o``) in a device trace, and its body (every head unrolled) traced
+    once a burst and not once a layer."""
+    with jax.named_scope("gdn_recurrent"):
+        return gated_delta_step_in_place(s_pool, n, act, q, k, v, g, beta, interpret=interpret)
+
+
+def gdn_step_in_pool(m, cfg, p, x, s_pool, n, taps_old, act, interpret):
+    """The same mixer with the rule as a kernel on the state pool itself
+    (``m.state_step_in_pool``): layer ``n``'s rows that are ``act`` are read
+    once and written once where they lie, the others are not touched."""
+    q, k, v, gate, beta, z, taps = _gdn_token(m, cfg, p, x, taps_old)
+    o, s_pool = _gdn_rule_in_pool(s_pool, jnp.int32(n), act, q, k, v, gate, beta,
+                                  interpret=interpret)
+    return m.gdn_out(cfg, p, o[:, None], z), s_pool, taps
 
 
 # ------------------------------------------------------- the Mamba-2 mixer --

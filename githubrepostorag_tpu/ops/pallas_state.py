@@ -19,9 +19,14 @@ starts no DMA in either direction and a slot past the rows is never addressed:
 ``where(act, new, old)`` as control, not as data.  A block is read once, before
 it is written, and no two blocks overlap, so a read can never meet a write.
 
-``ssd_step_in_place`` is Mamba-2's rule on that walk (ops/ssd.ssd_step is the
-same rule as array code: the CPU path, and the oracle of tests/test_ssd.py).
-A second body (the Gated DeltaNet rule) takes the same walk.
+Two rules take that walk, each as a ``body``:
+
+* ``ssd_step_in_place``: Mamba-2's (ops/ssd.ssd_step is the same rule as array
+  code: the CPU path, and the oracle of tests/test_ssd.py).
+* ``gated_delta_step_in_place``: the Gated DeltaNet's (ops/gated_delta
+  .gated_delta_step likewise: the CPU path, the oracle of
+  tests/test_gated_delta.py, and the rule of a family whose roofline metric
+  names XLA's own fusions and so cannot read a kernel: PERF.md, PR 49).
 """
 
 from __future__ import annotations
@@ -144,6 +149,13 @@ def step_rows_in_place(body, pool, layer, act, operands, results, block_heads: i
       *smem_operands, *operands, pool)
 
 
+def _heads_a_block(heads: int, a: int, b: int, itemsize: int) -> int:
+    """As many of a row's ``heads`` states ``[a, b]`` as ``BLOCK_BYTES`` hold,
+    and a divisor of ``heads``."""
+    fit = max(1, BLOCK_BYTES // (a * b * itemsize))
+    return max(k for k in range(1, heads + 1) if heads % k == 0 and k <= fit)
+
+
 def _ssd_body(row, block, s, decay_ref, dt_ref, x_ref, b_ref, c_ref, heads_ref, y_ref, *,
               heads_per_group: int):
     """Mamba-2's rule on one block of heads of one row, in ops/ssd.ssd_step's
@@ -179,13 +191,56 @@ def ssd_step_in_place(pool, layer, act, x, dt, a, b, c, d, interpret=False):
     g, n = b.shape[1], pool.shape[-1]
     if b.shape[-1] != n:  # the padding lanes read as nothing and stay zero (ssd_step)
         b, c = (jnp.pad(v, ((0, 0), (0, 0), (0, n - v.shape[-1]))) for v in (b, c))
-    fit = max(1, BLOCK_BYTES // (p * n * pool.dtype.itemsize))
-    block_heads = max(k for k in range(1, h + 1) if h % k == 0 and k <= fit)
     decay = jnp.exp(dt * a)
     bc = jnp.repeat(jnp.sum(b * c, axis=-1), h // g, axis=1)
     heads = jnp.stack([decay, dt, bc, jnp.broadcast_to(d, dt.shape)], axis=1)
     y_t, pool = step_rows_in_place(
         functools.partial(_ssd_body, heads_per_group=h // g), pool, layer, act,
         (x.swapaxes(1, 2), b, c, heads), (jax.ShapeDtypeStruct((bsz, p, h), jnp.float32),),
-        block_heads, smem_operands=(decay, dt), interpret=interpret)
+        _heads_a_block(h, p, n, pool.dtype.itemsize), smem_operands=(decay, dt),
+        interpret=interpret)
     return jnp.where(act[:, None, None], y_t.swapaxes(1, 2), 0.0), pool
+
+
+def _gdn_body(row, block, s, decay_ref, beta_ref, kq_ref, k_ref, q_ref, v_ref, o_ref):
+    """The Gated DeltaNet rule on one block of heads of one row, in
+    ops/gated_delta.gated_delta_step's own operations: ``exp(g) S^T k`` and
+    ``exp(g) S^T q`` of the state that came in (reductions down the sublanes),
+    ``delta = beta (v - exp(g) S^T k)``, the head's ``o = exp(g) S^T q + (k . q)
+    delta`` and ``S exp(g) + k (x) delta``, all from the one copy ``s`` [K, dk,
+    dv'].  ``decay_ref`` (``exp(g)``), ``beta_ref``, ``kq_ref`` (``k . q``)
+    [B, H] SMEM: a head's scalars; ``k_ref``, ``q_ref`` [B, dk, H]: a head's
+    column lies down the sublanes as the state's rows do; ``v_ref`` [B, H, dv']
+    with lanes ``dv:`` zero, where the state's are: ``delta`` is zero there and
+    a padding lane comes out ``0 * decay + k * 0``; ``o_ref`` [B, H, dv]."""
+    new = []
+    for i in range(s.shape[0]):
+        h = block * s.shape[0] + i
+        decay, k, q = decay_ref[row, h], k_ref[row, :, h:h + 1], q_ref[row, :, h:h + 1]
+        kv = decay * jnp.sum(s[i] * k, axis=0, keepdims=True)
+        qv = decay * jnp.sum(s[i] * q, axis=0, keepdims=True)
+        delta = beta_ref[row, h] * (v_ref[row, h:h + 1, :] - kv)
+        o_ref[row, h:h + 1, :] = (qv + kq_ref[row, h] * delta)[:, :o_ref.shape[-1]]
+        new.append(s[i] * decay + k * delta)
+    return jnp.stack(new)
+
+
+def gated_delta_step_in_place(pool, layer, act, q, k, v, g, beta, interpret=False):
+    """ops/gated_delta.gated_delta_step on the live rows of ``pool[layer]``, in
+    place.  ``pool`` [L, slots, H, dk, dv'] float32, dv' >= dv a whole number of
+    lane tiles, lanes ``dv:`` zero; ``layer`` an index; ``act`` [B] bool; ``q``,
+    ``k`` [B, H, dk]; ``v`` [B, H, dv]; ``g``, ``beta`` [B, H].  Returns (o
+    [B, H, dv], the pool), ``o`` the call's FIRST result (a trace names an
+    instruction for it): a row that is not ``act`` keeps its state (nobody
+    touches its slot) and its ``o`` is zero.  A block is as many heads as
+    ``BLOCK_BYTES`` hold."""
+    bsz, h, dk = k.shape
+    dv, n = v.shape[-1], pool.shape[-1]
+    if dv != n:
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, n - dv)))
+    o, pool = step_rows_in_place(
+        _gdn_body, pool, layer, act, (k.swapaxes(1, 2), q.swapaxes(1, 2), v),
+        (jax.ShapeDtypeStruct((bsz, h, dv), jnp.float32),),
+        _heads_a_block(h, dk, n, pool.dtype.itemsize),
+        smem_operands=(jnp.exp(g), beta, jnp.sum(k * q, axis=-1)), interpret=interpret)
+    return jnp.where(act[:, None, None], o, 0.0), pool

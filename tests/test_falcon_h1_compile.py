@@ -30,6 +30,7 @@ import pytest
 
 from tests.test_qwen3_next_compile import timed_lines
 from tests.test_tpu_compile import (  # noqa: F401 - fixtures
+    assert_calls_step_pool_in_place,
     assert_commits_windows_in_place,
     chip,
     pool_movers,
@@ -152,13 +153,8 @@ def test_step_program_leaves_pages_and_state_of_every_layer_in_place(
         # of all 32 rows' states exists anywhere
         pool = f"f32[{_dims(pools['s'])}]"
         calls = [ln for ln in timed_lines(hlo, ("custom-call",)) if "/ssm_recurrent/" in ln]
+        assert_calls_step_pool_in_place(calls, pool)
         assert len(calls) == LAYERS, [c[:120] for c in calls]
-        for call in calls:
-            result, operands = call.split(" custom-call(", 1)
-            layouts = operands.split("operand_layout_constraints={", 1)[1].split("}, output_to", 1)[0]
-            assert result.count(pool) == 1 and layouts.count(pool) == 1, call[:400]
-            at = len(re.findall(r"[a-z0-9]+\[[0-9,]*\]\{", layouts.split(pool)[0]))  # its operand
-            assert f"output_to_operand_aliasing={{{{1}}: ({at}, {{}})}}" in call, call[:1200]
         assert f"f32[{ROWS},32,128,256]" not in hlo
     # every pool lies as the program is handed it: row-major, the last axis on the lanes
     layout = hlo.split("entry_computation_layout={(", 1)[1].split(")->", 1)[0]

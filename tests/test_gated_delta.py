@@ -3,12 +3,16 @@ prefill wave, the one-token form of the burst (ops/gated_delta.py) and the
 benchmark's reference recurrence (benchmarks/reference_qwen3_next.py, which
 imports nothing of the program) agree; across block and chunk boundaries,
 from a state that is not zero, with padded columns at each of the wave's
-rungs, and with rows that sit a burst step out.
+rungs, and with rows that sit a burst step out.  And the one-token form as a
+kernel that steps a state pool where it lies (ops/pallas_state.py), under the
+interpreter, against the array form.
 
 Tolerances: everything here is float32 on the CPU, and the three forms sum
 the same terms in different orders; 2e-5 absolute on outputs and states of
 order one is a few hundred roundings, not a different formula (a missing
 decay or a transposed state reads 1e-1 and more)."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -159,6 +163,108 @@ def test_the_rule_at_the_stored_width_is_the_rule_and_its_padding_stays_zero(dk,
         assert (bits(wide)[idle] == before[idle]).all(), t
     assert (bits(wide)[3] == bits(wide0)[3]).all()
     assert float(jnp.abs(wide[:3, ..., :dv] - s0[:3]).max()) > 0.1  # the others did step
+
+
+# ----------------------------------- the one-token rule as a kernel on the pool --
+
+POOL_ROWS = 6  # the engine's rows: the pool's first slots; 2 more stand for a snapshot and the spare
+WIDTHS = {"96x192-in-256": (96, 192, 256), "128x128-in-128": (128, 128, 128)}
+SOME = np.isin(np.arange(POOL_ROWS), [1, 2, 4])
+LIVE = {"all-live": [np.ones(POOL_ROWS, bool)] * 3, "some-live": [SOME, ~SOME, SOME, SOME],
+        "none-live": [np.zeros(POOL_ROWS, bool)] * 2}
+
+
+def _pool_case(widths, live, layers=2, h=4, seed=11):
+    """A pool [layers, rows + 2, H, dk, stored] (lanes ``dv:`` zero, the rest
+    not) and the inputs of ``gated_delta_step`` a step, ``beta`` up to 2."""
+    dk, dv, stored = WIDTHS[widths]
+    q, k, v, g, beta, _ = inputs(POOL_ROWS, len(LIVE[live]), h=h, dk=dk, dv=dv, seed=seed,
+                                 beta=(0.05, 2.0))
+    pool = np.zeros((layers, POOL_ROWS + 2, h, dk, stored), np.float32)
+    pool[..., :dv] = np.random.default_rng(seed).normal(size=pool.shape[:-1] + (dv,))
+    return jnp.asarray(pool), (q, k, v, g, beta), [jnp.asarray(m) for m in LIVE[live]]
+
+
+def _chain(pool, ins, live_steps, layer, step_fn):
+    """Every step's (o, pool) from ``step_fn`` beside the array form's."""
+    want = pool
+    for t, act in enumerate(live_steps):
+        before = np.asarray(pool)
+        args = tuple(x[:, t] for x in ins)
+        o, pool = step_fn(pool, layer, act, *args)
+        o_w, s_w = gd.gated_delta_step(want[layer, :POOL_ROWS], *args)
+        want = want.at[layer, :POOL_ROWS].set(
+            jnp.where(act[:, None, None, None], s_w, want[layer, :POOL_ROWS]))
+        yield np.asarray(act), before, (np.asarray(o), np.asarray(pool)), (o_w, np.asarray(want))
+
+
+def heads_a_block(monkeypatch, heads, widths):
+    from githubrepostorag_tpu.ops import pallas_state
+
+    dk, _, stored = WIDTHS[widths]
+    monkeypatch.setattr(pallas_state, "BLOCK_BYTES", heads * dk * stored * 4)
+
+
+@pytest.mark.parametrize("live", LIVE)
+@pytest.mark.parametrize("widths", WIDTHS)
+def test_the_kernel_steps_live_rows_as_the_array_form_and_touches_nothing_else(
+        monkeypatch, widths, live):
+    """ops/pallas_state.gated_delta_step_in_place under the interpreter, at
+    Olmo-Hybrid's widths (values of 192 stored at 256 lanes) and at
+    Qwen3-Next's (128 at 128), two blocks of heads a row, chained over steps
+    from a state that is not zero: a live row's state and output are
+    ``gated_delta_step``'s to rounding (the elementwise operations are the
+    same; the sum down ``dk`` sublanes may be taken in another order); a dead
+    row's slot, every slot past the rows and the other layer are the bits that
+    were there; lanes ``dv:`` stay zero bit for bit; a dead row's output is
+    zero."""
+    from githubrepostorag_tpu.ops.pallas_state import gated_delta_step_in_place
+
+    pool, ins, live_steps = _pool_case(widths, live)
+    dv, layer = WIDTHS[widths][1], 1
+    heads_a_block(monkeypatch, 2, widths)
+    step = jax.jit(functools.partial(gated_delta_step_in_place, interpret=True))
+    for act, before, (o, got), (o_w, want) in _chain(pool, ins, live_steps, layer, step):
+        assert o.shape == o_w.shape
+        np.testing.assert_allclose(o[act], np.asarray(o_w)[act], atol=TOL)
+        np.testing.assert_allclose(got[layer, :POOL_ROWS][act], want[layer, :POOL_ROWS][act],
+                                   atol=TOL)
+        kept = np.ones(got.shape[:2], bool)
+        kept[layer, :POOL_ROWS] = ~act
+        assert (bits(got)[kept] == bits(before)[kept]).all()
+        assert not bits(got[..., dv:]).any()  # not even a negative zero
+        assert not o[~act].any()
+    if live != "none-live":
+        assert float(np.abs(got[layer] - np.asarray(pool)[layer]).max()) > 0.1  # they did step
+
+
+@pytest.mark.parametrize("widths,block_heads", [
+    pytest.param("96x192-in-256", 4, id="96x192-in-256-one-block-a-row"),
+    pytest.param("128x128-in-128", 2, id="128x128-in-128-two-blocks-a-row")])
+def test_the_kernels_dmas_land_before_they_are_read_and_never_meet(monkeypatch, widths,
+                                                                   block_heads):
+    """Plain interpret mode copies at ``start()``; the TPU interpreter runs a
+    DMA when it is waited for and watches every buffer for races (tests/
+    test_ssd.py has the same walk under Mamba-2's body): a block computed
+    before its wait, a slot refilled while its write is still out, a read that
+    meets a write of the pool, or a wait with no start (a hang) show here.  An
+    odd count of items (three live rows of one block), then an even one."""
+    from jax.experimental.pallas import tpu as pltpu
+    from githubrepostorag_tpu.ops.pallas_state import gated_delta_step_in_place
+
+    if not hasattr(pltpu, "InterpretParams"):
+        pytest.skip("this jax has no TPU interpreter")
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call as tpu_interpreter
+
+    pool, ins, live_steps = _pool_case(widths, "some-live")
+    heads_a_block(monkeypatch, block_heads, widths)
+    step = jax.jit(functools.partial(gated_delta_step_in_place, interpret=pltpu.InterpretParams(
+        detect_races=True, dma_execution_mode="on_wait")))
+    for act, before, (o, got), (o_w, want) in _chain(pool, ins, live_steps[:2], 1, step):
+        np.testing.assert_allclose(o[act], np.asarray(o_w)[act], atol=TOL)
+        np.testing.assert_allclose(got, want, atol=TOL)
+        assert (bits(got)[1, :POOL_ROWS][~act] == bits(before)[1, :POOL_ROWS][~act]).all()
+        assert not tpu_interpreter.races.races_found
 
 
 def test_at_equal_widths_the_rule_traces_to_what_it_always_did():

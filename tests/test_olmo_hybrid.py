@@ -226,6 +226,32 @@ def test_engine_prefill_decode_and_a_restored_snapshot_are_the_references(in_flo
     assert max(decode_gaps(B, again_b, control="fp8")) > 1e-3  # held to the control, it fails
 
 
+def test_engine_with_the_kernel_on_the_pool_gives_the_array_forms_tokens_and_state(in_float32):
+    """The burst's one-token rule as ops/pallas_state.py's kernel (``use_pallas``,
+    interpreted here) against ``gated_delta_step`` (the engine's path on the
+    CPU): three prompts at once whose answers end at 3, 6 and 9 tokens, so in
+    bursts of 4 a row turns dead BETWEEN two steps of a burst (``act & (lens <
+    row_limits)``) while its neighbours step on, then the first prompt again
+    from its snapshot.  The same tokens, and the same state pool to float32
+    rounding (the kernel sums down ``dk`` sublanes in its own order): every
+    slot, so a row that finished, a row that never ran and the snapshots hold
+    what the array form left there; the padding lanes all zero."""
+    plain, kernel = build_engine(jnp.float32), build_engine(jnp.float32, use_pallas=True)
+    prompts = [A, B, A[:120]]
+    sps = [SamplingParams(max_tokens=n, temperature=0.0, stop_token_ids=()) for n in (3, 6, 9)]
+    for batch, sp in ((prompts, sps), ([A], [SP])):
+        want, got = (
+            [(r.cached_tokens, list(r.output_tokens)) for r in eng.generate(batch, sp)]
+            for eng in (plain, kernel))
+        assert got == want and [len(t) for _, t in got] == [p.max_tokens for p in sp]
+        for prompt, (_, tokens) in zip(batch, got):
+            assert max(decode_gaps(prompt, tokens)) < 1e-3
+        np.testing.assert_allclose(np.asarray(kernel.state_pools["s"]),
+                                   np.asarray(plain.state_pools["s"]), atol=2e-5, rtol=0)
+        assert padding_lanes(kernel) == (0, True)
+    assert kernel.state_restored == plain.state_restored == 1
+
+
 def test_engine_in_bfloat16_stays_inside_the_decode_tolerance():
     """As served (bfloat16 weights, products and pages, float32 state): the
     tokens of a cold and of a resumed prompt lie 0.05 of a row's spread below

@@ -25,6 +25,7 @@ import pytest
 from tests.test_qwen3_next_compile import timed_lines
 from tests.test_tpu_compile import (  # noqa: F401 - fixtures
     COMMIT_CASES,
+    assert_calls_step_pool_in_place,
     assert_commits_windows_in_place,
     chip,
     pool_movers,
@@ -116,9 +117,11 @@ def _picked(hlo, pattern):
 
 
 @pytest.mark.parametrize("program,rows,writes", [
-    pytest.param("burst", 0, 6, id="burst"),      # a Gated DeltaNet layer: one write of its rows
-    pytest.param("wave", 1, 6, id="wave-1x512"),  # a row: its state after the chunk, its snapshot
-    pytest.param("wave", 4, 24, id="wave-4x512"),
+    # a Gated DeltaNet layer: one write of its rows of history; the STATE is the kernel's alone
+    pytest.param("burst", 0, {"s": 0, "conv": 6}, id="burst"),
+    # a row: its state after the chunk, its snapshot
+    pytest.param("wave", 1, {"s": 6, "conv": 6}, id="wave-1x512"),
+    pytest.param("wave", 4, {"s": 24, "conv": 24}, id="wave-4x512"),
 ])
 def test_step_program_leaves_both_caches_in_place(chip, as_on_chip, program, rows, writes):
     hlo, pools = compiled(chip, program, rows)
@@ -128,7 +131,14 @@ def test_step_program_leaves_both_caches_in_place(chip, as_on_chip, program, row
     for name in ("s", "conv"):  # written in place, a slot (the burst: its rows) at a time
         movers = pool_movers(hlo, pools[name])
         assert all(m.startswith("dynamic_update_slice") for m in movers), (name, movers)
-        assert len(movers) == writes, (name, movers)
+        assert len(movers) == writes[name], (name, movers)
+    if program == "burst":
+        # a Gated DeltaNet layer's rule is ONE call (PR 49): the state pool goes in whole and comes
+        # out as the same buffer (ops/pallas_state.py), and no array of all 32 rows' states exists
+        calls = [ln for ln in timed_lines(hlo, ("custom-call",)) if "/gdn_recurrent/" in ln]
+        assert_calls_step_pool_in_place(calls, "f32[6,97,30,96,256]")
+        assert len(calls) == 6, [c[:120] for c in calls]
+        assert "f32[32,30,96,256]" not in hlo and "f32[32,30,96,192]" not in hlo
     # a wave is compiled without the memory-space assignment: none of its arrays lives in VMEM
     assert ("S(1)" in hlo) == (program == "burst")
 
@@ -145,9 +155,11 @@ def test_step_program_commits_keys_and_values_as_windows_in_place(chip, as_on_ch
     assert_commits_windows_in_place(hlo, pools["kv"], program, rows)
     if program == "burst":
         # the loop over the row slots' runs (the window plan once, 12 instructions a pool's
-        # iteration): 261 timed instructions where the row form's two scatters and their indices
-        # made it 232; a commit that unrolls its windows, or plans them twice, shows here
-        assert len(list(timed_lines(hlo))) <= 261
+        # iteration): 262 timed instructions (261 until the rule became a kernel, PR 49: a layer's
+        # slice, sums and update are one call now, and k | q turned on their side and the dead
+        # rows' mask two more) where the row form's two scatters and their indices made it 232; a
+        # commit that unrolls its windows, or plans them twice, shows here
+        assert len(list(timed_lines(hlo))) <= 262
 
 
 def test_this_cells_metrics_select_the_ops_under_their_scopes(chip, as_on_chip):
@@ -163,45 +175,42 @@ def test_this_cells_metrics_select_the_ops_under_their_scopes(chip, as_on_chip):
     wave, _ = compiled(chip, "wave", 1)
     spec = lambda name: manifest.metric_spec(name)["args"]  # noqa: E731
 
-    # the passes a layer makes over its rows of state: read out of the pool ONCE, at the 256 lanes
-    # it stores (a fused slice that keeps no scope, held in VMEM), S^T k | S^T q over lanes :192 of
-    # that copy, and ONE update written into the pool in place from the same copy (PR 40; until
-    # then the rule gave rows of 192 lanes and a pad-and-write laid them into the pool: two writes)
-    decode = _picked(burst, re.compile(spec("olmo_gdn_decode_roofline_frac")["op"]))
-    assert set(decode) == {"gdn_recurrent", ""}
-    assert len(decode["gdn_recurrent"]) == 12 and len(decode[""]) == 6
-    assert all(n.startswith("slice_bitcast_fusion") for n in decode[""])
-    for prefix in ("bitcast_dynamic-update-slice_fusion", "multiply_reduce_fusion"):
-        assert sum(n.startswith(prefix) for n in decode["gdn_recurrent"]) == 6, prefix
+    # the one-token rule, a layer and step: ONE call of the kernel (PR 49), named for its scope and
+    # for its FIRST result (o, [32, 30, 192]: the pool is its second), so the pattern that read
+    # XLA's three passes over all 32 row slots (PR 40: the rows sliced out of the pool into VMEM,
+    # S^T k | S^T q, one update written in place) reads it under the same name; beside it the
+    # mask of the dead rows' o, which XLA fuses into the gate norm's first product: 0.7 MB
+    rule = re.compile(spec("olmo_gdn_decode_roofline_frac")["op"])
+    decode = _picked(burst, rule)
+    assert set(decode) == {"gdn_recurrent", "gdn_gate_norm"}
+    names = [re.sub(r"\.\d+", "", n) for n in decode["gdn_recurrent"]]
+    assert names == ["gdn_recurrent_f32_32_30_192_"] * 6, names
+    assert len(decode["gdn_gate_norm"]) == 6
+    assert all(n.startswith("select_multiply_fusion") and n.endswith("_f32_32_30_192_")
+               for n in decode["gdn_gate_norm"])
     timed = list(timed_lines(burst))
-    assert not [ln for ln in timed if "/gdn_recurrent/" in ln and "add_select_fusion" in ln]
-    assert not [ln for ln in timed if " = f32[32,30,96,192]" in ln]  # no row at 192 lanes
-    # what the rule reads or writes at state size is in the metric's seconds: the other timed ops
-    # of the scope are per-head vectors ([32, 30]), a thousandth of a pass
+    # nothing of state size beside the kernels: no slice of the rows, no sums, no update of XLA's
+    # own; no timed instruction reads or writes the pool but the six calls
+    everything = {n for n, _ in timed_ops(burst)}
+    assert not [n for n in everything if n.startswith(
+        ("slice_bitcast_fusion_f32_32_30_96_256_", "multiply_reduce_fusion_f32_32_30_192_",
+         "bitcast_dynamic-update-slice_fusion_f32_6_97_30_96_256_"))]
+    holds_pool = {m.group(1) for m in re.finditer(r"(%[\w.\-]+) = ([^=]*?) [a-z\-]+\(", burst)
+                  if "f32[6,97,30,96,256]" in m.group(2)}  # whatever is, or carries, the pool
+    at_pool = [ln for ln in timed if holds_pool & set(re.findall(r"%[\w.\-]+", ln.split("), ")[0]))]
+    assert len(at_pool) == 6 and all(
+        " custom-call(" in ln and "/gdn_recurrent/" in ln for ln in at_pool), at_pool
+    # what the scope holds besides is k | q turned on their side for the kernel ([32, 96, 30], 0.7
+    # MB a layer) and per-head vectors ([32, 30]): a thousandth of the rows' state
     rest = {n for n, scope in timed_ops(burst) if scope == "gdn_recurrent"} - decode["gdn_recurrent"]
-    assert all(n.endswith("_f32_32_30_") for n in rest), rest
-    # a layer: one op whose result is the pool; its operands are the pool it updates in place and
-    # the rows' slice, which lives in VMEM (S(1)) and is the only read of the state it makes: the
-    # ``optimization_barrier`` in models/hybrid.py:burst buys that (without it the update slices
-    # the pool again: 283 MB a layer and step for 189)
-    results = dict(ln.split(" fusion(")[0].split(" = ", 1) for ln in timed)  # name -> type
-    slices = {name for name, typ in results.items() if typ.startswith("f32[32,30,96,256]")}
-    assert len(slices) == 6
-    assert all("slice_bitcast_fusion" in name and "S(1)" in results[name] for name in slices)
-    writes = [ln for ln in timed if " = f32[6,97,30,96,256]" in ln]
-    assert len(writes) == 6 and all("/gdn_recurrent/" in ln for ln in writes)
-    for ln in writes:
-        operands = re.findall(r"%[\w.\-]+", re.search(r" fusion\(([^)]*)\)", ln).group(1))
-        assert sum(op in slices for op in operands) == 1, ln[:300]
-        body = re.search(r"calls=(%[\w.\-]+)", ln).group(1)
-        fused = burst.split(f"\n{body} (", 1)[1].split("\n}", 1)[0].split("{\n", 1)[1]
-        # inside the fusion the pool (parameter 0) is the update-slice's target and nothing else
-        assert len(re.findall(r"%param_0\.\d+\b", fused)) == 2, fused[:2000]
-        assert " dynamic-slice(" not in fused and " slice(" not in fused
+    assert all(n.endswith(("_f32_32_30_", "_f32_32_96_30_")) for n in rest), rest
+    # a kernel named after the scope is read (it is a custom call in a trace), an unnamed one is not
+    assert rule.search("gdn_recurrent.30_f32_32_30_192_")
+    assert not rule.search("custom-call.2_f32_32_30_192_")
     # XLA names a fusion after its ops and its result, so a wave's slot writes into the same pool
     # read like the burst's rows: the metric's seconds hold them too (one slot a row and a snapshot,
     # against 32 rows a layer and step: they can only lower the reading), and nothing else of a wave
-    in_wave = _picked(wave, re.compile(spec("olmo_gdn_decode_roofline_frac")["op"]))
+    in_wave = _picked(wave, rule)
     assert set(in_wave) == {"state_write"}
     assert all(n.startswith("bitcast_dynamic-update-slice_fusion") for n in in_wave["state_write"])
 
@@ -213,7 +222,11 @@ def test_this_cells_metrics_select_the_ops_under_their_scopes(chip, as_on_chip):
 
     moves = re.compile(spec("olmo_state_pool_move_share")["pattern"])
     assert set(_picked(wave, moves)) <= {"state_write"}  # the in-place row writes, nothing else
-    assert set(_picked(burst, moves)) <= {"gdn_recurrent", "gdn_conv", ""}
+    # the kernel computes, it does not move the pool: its name ends in o's shape, not the pool's
+    # (had the pool been its first result, ``gdn_recurrent.N_f32_6_97_30_96_256_`` would be a move);
+    # what is left of the burst is the histories' rows written back
+    assert set(_picked(burst, moves)) <= {"gdn_conv", ""}
+    assert moves.search("gdn_recurrent.30_f32_6_97_30_96_256_")
 
     # the burst's attention kernel is named for its scope, where the accepted metric looks
     paged = re.compile(manifest.metric_spec("paged_attn_hbm_frac")["args"]["op"])

@@ -193,6 +193,19 @@ def pool_movers(hlo: str, pool_shape: tuple, ops: tuple = MOVERS, windows: bool 
     return found
 
 
+def assert_calls_step_pool_in_place(calls: list, pool: str) -> None:
+    """Each of ``calls`` (custom-call lines of the optimized HLO: the kernels
+    of ops/pallas_state.py under their scope) takes the pool (its type as the
+    HLO prints it, ``f32[8,97,64,64,128]``) whole, once, and gives it back as
+    its SECOND result in the same buffer."""
+    for call in calls:
+        result, operands = call.split(" custom-call(", 1)
+        layouts = operands.split("operand_layout_constraints={", 1)[1].split("}, output_to", 1)[0]
+        assert result.count(pool) == 1 and layouts.count(pool) == 1, call[:400]
+        at = len(re.findall(r"[a-z0-9]+\[[0-9,]*\]\{", layouts.split(pool)[0]))  # its operand
+        assert f"output_to_operand_aliasing={{{{1}}: ({at}, {{}})}}" in call, call[:1200]
+
+
 def assert_wave_keeps_in_place(hlo: str, held: str, count: int,
                                module: str = "jit_forward_paged_wave") -> None:
     """The compiled module is ``module`` and each of its ``count`` entry
