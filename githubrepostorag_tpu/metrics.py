@@ -505,6 +505,19 @@ STATE_SLOTS_IN_USE = Gauge(
     "Snapshot slots of the state pool that hold a snapshot",
     registry=REGISTRY,
 )
+SLIDING_PAGES_FREED = Counter(
+    "rag_kv_sliding_pages_freed_total",
+    "Pages of a sliding kind (serving/kv_cache.SlidingPages) that rows released "
+    "once every key in them lay behind the window of the row's next token",
+    registry=REGISTRY,
+)
+KV_PAGES_IN_USE = Gauge(
+    "rag_kv_pages_in_use",
+    "Pages that sequences hold, by kind of page, of an engine whose model states "
+    "a sliding kind beside the global one",
+    ["kind"],
+    registry=REGISTRY,
+)
 
 
 def render() -> bytes:

@@ -22,6 +22,7 @@ def dense_attention(
     q_offset: jnp.ndarray | int = 0,
     kv_lengths: jnp.ndarray | None = None,  # [B] valid kv length per seq
     kv_valid: jnp.ndarray | None = None,  # [B, Sk] bool — arbitrary validity
+    sliding: int | None = None,  # a query at position p sees keys p - sliding < j <= p
 ) -> jnp.ndarray:
     """Scaled-dot-product attention with causal masking and GQA.
 
@@ -48,6 +49,9 @@ def dense_attention(
         q_pos = jnp.arange(sq) + jnp.asarray(q_offset).reshape(-1, 1)  # [B or 1, Sq]
         causal_mask = kv_pos[None, None, :] > q_pos[:, :, None]  # [B or 1, Sq, Sk]
         mask = mask | causal_mask[:, None, None, :, :]
+        if sliding is not None:
+            behind = kv_pos[None, None, :] <= q_pos[:, :, None] - sliding
+            mask = mask | behind[:, None, None, :, :]
     if kv_lengths is not None:
         pad_mask = kv_pos[None, :] >= kv_lengths[:, None]  # [B, Sk]
         mask = mask | pad_mask[:, None, None, None, :]

@@ -54,6 +54,8 @@ def _fused_window_kernel(
     page_size: int,
     scale: float,
     quant: int,  # 0 = full precision, 8 = int8 pages, 4 = int4 nibble pages
+    sliding: int | None = None,  # a sliding layer's window, in keys
+    bf16_products: bool = False,  # the two products on the pool's own bfloat16 (float32 sums)
 ):
     block_tables_ref, cached_lens_ref, total_lens_ref = refs[:3]
     if quant:
@@ -73,11 +75,22 @@ def _fused_window_kernel(
 
     cached = cached_lens_ref[bi]  # each q row's base position in the window
     total = total_lens_ref[bi]  # valid kv length for this row
-    page_start = pi * page_size
+    page_index = pi
+    if sliding is not None:
+        # the lowest query (position ``cached``) sees no key at or before
+        # cached - sliding: the walk begins at the page of its first key (the
+        # grid's page axis is as long as a window and a chunk, not as the table)
+        page_index = pi + jnp.maximum(cached - sliding + 1, 0) // page_size
+    page_start = page_index * page_size
+    wanted = page_start < total
 
-    @pl.when(page_start < total)
+    @pl.when(wanted)
     def _():
-        q = q_ref[0, 0].astype(jnp.float32)  # [group, W, hd]
+        # ``bf16_products``: q, k, v and the softmax weights enter the products as
+        # bfloat16, accumulated in float32 (one pass of the MXU where a float32
+        # product takes several; a 25k-token context is 196 of these a head)
+        wide = (lambda x: x) if bf16_products else (lambda x: x.astype(jnp.float32))
+        q = wide(q_ref[0, 0])  # [group, W, hd]
         half = q.shape[-1] // 2
 
         if quant == 4:
@@ -99,7 +112,7 @@ def _fused_window_kernel(
                 preferred_element_type=jnp.float32,
             )
         else:
-            k = k_ref[0, 0].astype(jnp.float32)  # [page_size, hd]
+            k = wide(k_ref[0, 0])  # [page_size, hd]
             s = jax.lax.dot_general(
                 q, k, (((2,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -108,7 +121,7 @@ def _fused_window_kernel(
         if quant:
             # per-page scalar dequant rides the softmax scale: this grid
             # step covers exactly one (kv head, page) pair
-            page = block_tables_ref[bi, pi]
+            page = block_tables_ref[bi, page_index]
             s = s * (scale * ks_ref[hi, page])
         else:
             s = s * scale
@@ -117,7 +130,10 @@ def _fused_window_kernel(
         # cached + ti; kv beyond the row's valid length is padding
         kv_pos = page_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         q_pos = cached + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where((kv_pos <= q_pos) & (kv_pos < total), s, NEG_INF)
+        seen = (kv_pos <= q_pos) & (kv_pos < total)
+        if sliding is not None:
+            seen = seen & (kv_pos > q_pos - sliding)
+        s = jnp.where(seen, s, NEG_INF)
 
         m_prev = m_ref[:, :, :1]  # [group, W, 1]
         l_prev = l_ref[:, :, :1]
@@ -132,7 +148,7 @@ def _fused_window_kernel(
             vi = v_ref[0, 0].astype(jnp.int32)  # [page_size, hd//2]
             v_lo = (((vi & 0xF) ^ 8) - 8).astype(jnp.float32)
             v_hi = (((vi >> 4) ^ 8) - 8).astype(jnp.float32)
-            vs = vs_ref[hi, block_tables_ref[bi, pi]]
+            vs = vs_ref[hi, block_tables_ref[bi, page_index]]
             o_lo = jax.lax.dot_general(
                 p, v_lo, (((2,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -147,13 +163,13 @@ def _fused_window_kernel(
             acc_ref[:, :, :half] = acc[:, :, :half] * alpha + o_lo
             acc_ref[:, :, half:] = acc[:, :, half:] * alpha + o_hi
         else:
-            v = v_ref[0, 0].astype(jnp.float32)
+            v = wide(v_ref[0, 0])
             o = jax.lax.dot_general(
-                p, v, (((2,), (0,)), ((), ())),
+                p if p.dtype == v.dtype else p.astype(v.dtype), v, (((2,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
             if quant:
-                o = o * vs_ref[hi, block_tables_ref[bi, pi]]
+                o = o * vs_ref[hi, block_tables_ref[bi, page_index]]
             acc_ref[...] = acc_ref[...] * alpha + o
 
     @pl.when(pi == num_pi - 1)
@@ -165,7 +181,6 @@ def _fused_window_kernel(
         out_ref[0, 0] = (acc_ref[...] / safe_l).astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
 def fused_window_attention(
     q_win: jnp.ndarray,  # [B, S, n_q, hd] — per-row windows based at cached_lens
     k_pages: jnp.ndarray,  # [(L,) n_kv, P, page_size, hd] (or [.., hd//2] uint8 int4)
@@ -177,6 +192,8 @@ def fused_window_attention(
     v_scales: jnp.ndarray | None = None,
     layer: jnp.ndarray | None = None,  # [] / [1] int32, REQUIRED for rank-5
     interpret: bool = False,
+    sliding: int | None = None,  # a sliding layer's window, in keys
+    bf16_products: bool = False,
 ) -> jnp.ndarray:
     """ONE Pallas launch for every row's S-token window: grid
     (B, n_kv, max_pages), one page slab in VMEM per step.  Same contract
@@ -185,7 +202,14 @@ def fused_window_attention(
     Rank-5 pools + ``layer``: the WHOLE [L, n_kv, P, ps, hd] pool and the
     layer index as one more prefetched scalar, so the index map addresses
     (layer, head, page) and no layer of the pool is sliced out — the same
-    form as pallas_paged.paged_attention_decode_staged."""
+    form as pallas_paged.paged_attention_decode_staged.
+
+    ``sliding``: the query at position p sees the keys ``p - sliding < j <= p``;
+    a row's walk begins at the page of its lowest query's first key and is as
+    long as a window and the S columns can span, whatever the table's length
+    (the table is indexed by absolute page; what it names before that page is
+    never read).  None: the program it was.  ``bf16_products`` (full-precision pools only):
+    the kernel's two products take bfloat16 operands."""
     b, s_w, n_q, hd = q_win.shape
     layered = k_pages.ndim == 5
     if layered:
@@ -210,7 +234,11 @@ def fused_window_attention(
     def kv_map(bi, hi, pi, bt, cl, tl, *scalars):
         # Clamp the walk to allocated pages: beyond the row's length the
         # kernel skips compute, so any valid page id works — page 0.
-        page = jax.lax.select(pi * page_size < tl[bi], bt[bi, pi], 0)
+        at = pi
+        if sliding is not None:
+            at = jnp.minimum(pi + jnp.maximum(cl[bi] - sliding + 1, 0) // page_size,
+                             max_pages - 1)
+        page = jax.lax.select(at * page_size < tl[bi], bt[bi, at], 0)
         if layered:  # the layer index is the LAST prefetched scalar
             return (scalars[-1][0], hi, page, 0, 0)
         return (hi, page, 0, 0)
@@ -230,9 +258,12 @@ def fused_window_attention(
     if layered:
         prefetch.append(jnp.reshape(layer, (1,)).astype(jnp.int32))
 
+    walk = max_pages
+    if sliding is not None:  # pages from the lowest query's first key to the highest query
+        walk = min(max_pages, (sliding + s_w - 2) // page_size + 2)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=(b, n_kv, max_pages),
+        grid=(b, n_kv, walk),
         in_specs=[
             pl.BlockSpec((1, 1, group, s_w, hd), q_map),
             pl.BlockSpec(kv_block, kv_map),
@@ -247,7 +278,8 @@ def fused_window_attention(
     )
 
     kernel = functools.partial(
-        _fused_window_kernel, page_size=page_size, scale=scale, quant=quant
+        _fused_window_kernel, page_size=page_size, scale=scale, quant=quant, sliding=sliding,
+        bf16_products=bf16_products and not quant,
     )
     out = pl.pallas_call(
         kernel,
@@ -263,16 +295,33 @@ def fused_window_attention(
     return out.transpose(0, 3, 1, 2, 4).reshape(b, s_w, n_q, hd)
 
 
+_window_attention = fused_window_attention
+fused_window_attention = functools.partial(
+    jax.jit, static_argnames=("interpret", "sliding", "bf16_products"))(_window_attention)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "sliding", "bf16_products"))
+def sliding_prefill_attention(*args, **kw):
+    """``fused_window_attention`` under the name a SLIDING layer's calls carry
+    in a device trace: an instruction is named for the innermost jit around it
+    (inside a ``lax.cond`` branch a bare scope's name is lost to the
+    branch's), and a trace has to tell these calls from a global layer's."""
+    return _window_attention(*args, **kw)
+
+
 def fused_paged_attention(q, k_pages, v_pages, block_tables, cached_lens,
-                          new_lens, k_scales=None, v_scales=None, layer=None):
+                          new_lens, k_scales=None, v_scales=None, layer=None, sliding=None,
+                          bf16_products=False):
     """Drop-in for ``paged_attention_ref``/``pallas_paged.paged_attention``
     at the forward_paged seam: spec-verify windows (S = k+1), plain decode
     (S = 1), and quantized pools all hit the SAME kernel instead of the
     dispatcher's gather fallback.  Interpret mode off-TPU keeps CPU tests
     on the kernel's exact compute graph."""
-    return fused_window_attention(
+    call = fused_window_attention if sliding is None else sliding_prefill_attention
+    return call(
         q, k_pages, v_pages, block_tables, cached_lens, new_lens,
-        k_scales, v_scales, layer, interpret=not on_tpu(),
+        k_scales, v_scales, layer, interpret=not on_tpu(), sliding=sliding,
+        bf16_products=bf16_products,
     )
 
 

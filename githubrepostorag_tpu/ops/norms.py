@@ -20,6 +20,16 @@ def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-6) -> jnp.ndar
     return normed.astype(orig_dtype) * weight
 
 
+def layer_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
+    """Cohere's LayerNorm: the mean taken off, no bias, ``(x - mean x) /
+    sqrt(var x + eps) * w``; statistics and the weight multiply in float32 (the
+    published module casts back after the weight)."""
+    xf = x.astype(jnp.float32)
+    xc = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(xc * xc, axis=-1, keepdims=True)
+    return (xc * jax.lax.rsqrt(var + eps) * weight.astype(jnp.float32)).astype(x.dtype)
+
+
 def rms_norm_zero_centered(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
     """Qwen3-Next's RMSNorm: the stored weight is the scale's distance from
     one, ``y = x / rms(x) * (1 + w)``, all of it in float32 (the published

@@ -58,7 +58,11 @@ def gather_kv(k_pages, v_pages, block_tables, k_scales=None, v_scales=None,
 
 
 def paged_attention_ref(q, k_pages, v_pages, block_tables, cached_lens, new_lens,
-                        k_scales=None, v_scales=None):
+                        k_scales=None, v_scales=None, sliding=None):
+    """``sliding``: the window of a sliding layer, in keys: the query at
+    position p sees the keys ``p - sliding < j <= p`` and no older one (its
+    table's entries for pages wholly behind every window are never looked at:
+    whatever page they name is masked)."""
     k, v = gather_kv(k_pages, v_pages, block_tables, k_scales, v_scales,
                      dtype=q.dtype)
     # The new tokens are already scattered into the pages before attention,
@@ -70,4 +74,5 @@ def paged_attention_ref(q, k_pages, v_pages, block_tables, cached_lens, new_lens
         causal=True,
         q_offset=cached_lens,
         kv_lengths=cached_lens + new_lens,
+        sliding=sliding,
     )

@@ -227,6 +227,8 @@ def _burst_kernel(
     layered: bool = False,
     kv_quant: bool = False,
     head_slices: int = 1,
+    sliding: bool = False,
+    bf16_products: bool = False,
 ):
     """Decode-burst attention: online softmax over [the pages the row holds
     | staged tail].  Grid (B / R,): a step takes R row slots, one after the
@@ -254,8 +256,22 @@ def _burst_kernel(
     m, l (n_kv, group, 128) f32, acc (n_kv, group, hd) f32].  ``kv_quant``:
     pool pages are int8; each page's scale is read per kv head from the SMEM
     scalar channel (zero extra DMAs: per-token scale tiles measured 5-18x
-    slower, r04) and dequant happens here in VMEM, right before the dots."""
-    n_scalars = (4 if layered else 3) + (2 if kv_quant else 0)
+    slower, r04) and dequant happens here in VMEM, right before the dots.
+
+    ``sliding``: one more prefetched scalar after the layer, ``pool_starts``
+    (B): the FIRST KEY a row may see (a sliding layer: the row's position less
+    the window).  The row's walk begins at that key's page (``first_page``: the
+    table is indexed by absolute page, the pages before it are never named),
+    the keys of that page that lie before the start are masked in the first
+    wave, and a row whose start lies past what it holds walks nothing, as a
+    dead row does.  Without it the program is the one it was.
+
+    ``bf16_products`` (full-precision pools): the pages enter the two products
+    as the bfloat16 they are stored in, with q and the softmax weights rounded
+    to it, and float32 sums: no float32 copy of a wave is made (at 8 kv heads
+    of 128 a wave's copies are 2 MB a page pair, and the conversions cost more
+    than the pages' DMAs), and the MXU takes one pass where float32 takes several."""
+    n_scalars = (4 if layered else 3) + (2 if kv_quant else 0) + (1 if sliding else 0)
     scalar_refs = refs[:n_scalars]
     block_tables_ref, pool_lens_ref, staged_len_ref = scalar_refs[:3]
     q_ref, k_hbm, v_hbm, sk_ref, sv_ref, out_ref = refs[n_scalars : n_scalars + 6]
@@ -263,6 +279,7 @@ def _burst_kernel(
     if layered:
         k_hbm, v_hbm = k_hbm.at[scalar_refs[3][0]], v_hbm.at[scalar_refs[3][0]]
     ks_ref, vs_ref = scalar_refs[-2:] if kv_quant else (None, None)
+    starts_ref = scalar_refs[4 if layered else 3] if sliding else None
     n_kv_heads = k_buf.shape[1]  # of one wave: all of them, or a slice
     rows, max_pages = block_tables_ref.shape
     block_rows = q_ref.shape[0]
@@ -271,11 +288,24 @@ def _burst_kernel(
     def row_of(vr):
         return vr if head_slices == 1 else vr % rows
 
+    def first_page(vr):
+        """The page of the first key virtual row ``vr`` may see."""
+        return starts_ref[row_of(vr)] // page_size
+
     def pages_of(vr):
-        return (pool_lens_ref[row_of(vr)] + page_size - 1) // page_size
+        held = (pool_lens_ref[row_of(vr)] + page_size - 1) // page_size
+        return jnp.maximum(held - first_page(vr), 0) if sliding else held
 
     def page_at(vr, w, j):
-        return block_tables_ref[row_of(vr), jnp.minimum(w * wave + j, max_pages - 1)]
+        at = w * wave + j
+        if sliding:
+            at = at + first_page(vr)
+        return block_tables_ref[row_of(vr), jnp.minimum(at, max_pages - 1)]
+
+    def dead(vr):
+        if sliding:
+            return pages_of(vr) == 0
+        return pool_lens_ref[row_of(vr)] == 0
 
     def heads_of(hbm, vr):
         """The pool's kv heads that virtual row ``vr``'s slice takes."""
@@ -301,7 +331,7 @@ def _burst_kernel(
         """Start the first wave of the next live row at or after ``after``,
         if there is one, so that it lands while the rows before it work."""
         row = jax.lax.while_loop(
-            lambda r: (r < walked) & (pool_lens_ref[row_of(jnp.minimum(r, walked - 1))] == 0),
+            lambda r: (r < walked) & dead(jnp.minimum(r, walked - 1)),
             lambda r: r + 1, after)
 
         @pl.when(row < walked)
@@ -310,6 +340,8 @@ def _burst_kernel(
 
     def tile(buf, scales_ref, row, w, slot):
         """Wave ``w`` of ``row`` as float32 [n_kv, wave * page_size, hd]."""
+        if bf16_products and not kv_quant:
+            return buf[slot]
         x = buf[slot].astype(jnp.float32)
         if not kv_quant:
             return x
@@ -346,6 +378,8 @@ def _burst_kernel(
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
         l_ref[:, :, :1] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        if p.dtype != vals.dtype:  # ``bf16_products``: the weights meet the pages in their type
+            p = p.astype(vals.dtype)
         acc_ref[...] = acc_ref[...] * alpha + pdot(p, vals)
         m_ref[:, :, :1] = m_new
 
@@ -369,7 +403,8 @@ def _burst_kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        q = q_ref[r].astype(jnp.float32)  # [n_kv, group, hd]
+        narrow = bf16_products and not kv_quant
+        q = q_ref[r] if narrow else q_ref[r].astype(jnp.float32)  # [n_kv, group, hd]
 
         def fold_wave(w, carry):
             slot = (first_slot + w) % 2
@@ -385,15 +420,20 @@ def _burst_kernel(
             page_dmas(bi, w, slot, lambda dma: dma.wait())
             s = bdot(q, tile(k_buf, ks_ref, bi, w, slot)) * scale  # [n_kv, group, wave * ps]
             kv_pos = w * wave * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-            accumulate(jnp.where(kv_pos < total, s, NEG_INF), tile(v_buf, vs_ref, bi, w, slot))
+            seen = kv_pos < total
+            if sliding:
+                kv_pos = kv_pos + first_page(bi) * page_size
+                seen = (kv_pos < total) & (kv_pos >= starts_ref[row_of(bi)])
+            accumulate(jnp.where(seen, s, NEG_INF), tile(v_buf, vs_ref, bi, w, slot))
             return carry
 
         jax.lax.fori_loop(0, n_waves, fold_wave, 0)
         slot_ref[0] = (first_slot + n_waves) % 2
 
-        s = bdot(q, sk_ref[r].astype(jnp.float32)) * scale  # [n_kv, group, n_steps]
+        staged = (lambda ref: ref[r]) if narrow else (lambda ref: ref[r].astype(jnp.float32))
+        s = bdot(q, staged(sk_ref)) * scale  # [n_kv, group, n_steps]
         idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        accumulate(jnp.where(idx < staged_len_ref[0], s, NEG_INF), sv_ref[r].astype(jnp.float32))
+        accumulate(jnp.where(idx < staged_len_ref[0], s, NEG_INF), staged(sv_ref))
         # staged_len >= 1 always, so l > 0 for every row incl. dead ones
         l = l_ref[:, :, :1]
         out_ref[r] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(out_ref.dtype)
@@ -415,6 +455,8 @@ def paged_attention_decode_staged(
     k_scales: jnp.ndarray | None = None,  # per-PAGE dequant scales (int8
     v_scales: jnp.ndarray | None = None,  # pools): [(L,) n_kv, P] f32
     interpret: bool = False,
+    pool_starts: jnp.ndarray | None = None,  # [B] the first key each row may see
+    bf16_products: bool = False,  # see ``_burst_kernel``
 ) -> jnp.ndarray:
     """Burst-decode attention over [pool prefix | staged tail] without ever
     materializing the gathered KV in HBM (replaces gather_kv+dense in
@@ -433,11 +475,18 @@ def paged_attention_decode_staged(
     and dequantize in VMEM right before the dots with their per-PAGE scale
     read from the scalar-prefetch SMEM channel — KV HBM reads halve at zero
     extra DMAs (per-token scale tiles measured 5-18x slower, r04); the
-    staged tail stays full precision."""
+    staged tail stays full precision.
+
+    ``pool_starts`` (a sliding layer): keys of the pool before a row's start
+    are neither read (whole pages) nor seen (the start's own page);
+    ``block_tables`` is indexed by absolute page all the same.  The staged tail
+    is seen whole: a burst is shorter than any window."""
     b, s, n_q, hd = q.shape
     assert s == 1, "staged kernel is the decode path (S == 1)"
     layered = k_pages.ndim == 5
     kv_quant = k_scales is not None
+    sliding = pool_starts is not None
+    assert not (sliding and kv_quant), "no quantized pool has a first key"
     if layered:
         assert layer is not None, "rank-5 pools need the layer index"
     n_kv, _, page_size, _ = k_pages.shape[-4:]
@@ -456,6 +505,8 @@ def paged_attention_decode_staged(
     ]
     if layered:
         scalars.append(jnp.reshape(layer, (1,)).astype(jnp.int32))
+    if sliding:
+        scalars.append(pool_starts.astype(jnp.int32))
     if kv_quant:
         # per-page scales [n_kv, P] join the SCALAR-PREFETCH channel (SMEM,
         # like the block tables): zero extra DMAs.  Layer-sliced here — a
@@ -505,7 +556,8 @@ def paged_attention_decode_staged(
 
     kernel = functools.partial(
         _burst_kernel, page_size=page_size, scale=1.0 / (hd ** 0.5), wave=wave,
-        layered=layered, kv_quant=kv_quant, head_slices=head_slices,
+        layered=layered, kv_quant=kv_quant, head_slices=head_slices, sliding=sliding,
+        bf16_products=bf16_products,
     )
     out = pl.pallas_call(
         kernel,

@@ -1,4 +1,5 @@
-"""Rotary position embeddings (rotate-half convention, Llama/Qwen2 family).
+"""Rotary position embeddings (rotate-half convention, Llama/Qwen2 family; the
+interleaved one, Cohere's ``rope_gptj``, at the end).
 
 cos/sin are computed in float32 from integer positions so decode steps at
 position 30k+ keep full precision, then applied in the activation dtype.
@@ -73,3 +74,23 @@ def rope_rotate_leading(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> j
     rest passed through."""
     r = cos.shape[-1]
     return jnp.concatenate([rope_rotate(x[..., :r], cos, sin), x[..., r:]], axis=-1)
+
+
+def rope_cos_sin_interleaved(positions: jnp.ndarray, head_dim: int, theta: float = 10000.0):
+    """positions [B, S] -> cos, sin each [B, S, head_dim] float32 for the
+    INTERLEAVED form (``rope_gptj``: pair i is columns 2i and 2i + 1, so each
+    frequency stands twice side by side)."""
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    angles = jnp.repeat(positions[..., None].astype(jnp.float32) * inv_freq, 2, axis=-1)
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+def rope_rotate_interleaved(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
+    """One tensor [..., hd] rotated in interleaved pairs, in float32, cast back:
+    ``x * cos + rot(x) * sin`` with ``rot(x)[2i] = -x[2i + 1]``, ``rot(x)[2i + 1]
+    = x[2i]``.  The partner of a column is its neighbour, so two rolls along the
+    lanes and a select by parity stand for the reshape to pairs."""
+    xf = x.astype(jnp.float32)
+    even = (jnp.arange(x.shape[-1]) % 2) == 0
+    rot = jnp.where(even, -jnp.roll(xf, -1, axis=-1), jnp.roll(xf, 1, axis=-1))
+    return (xf * cos + rot * sin).astype(x.dtype)

@@ -67,12 +67,15 @@ from githubrepostorag_tpu.serving.kv_cache import (
     OutOfPages,
     PageAllocator,
     PrefixCachingAllocator,
+    SlidingPages,
+    SlidingRow,
     StateSlots,
     TieredPageAllocator,
     make_page_pools,
     make_state_pools,
     packed_slot_mapping,
     page_hashes,
+    page_kinds,
     pages_needed,
     quant_bits,
     slot_mapping,
@@ -80,7 +83,9 @@ from githubrepostorag_tpu.serving.kv_cache import (
 from githubrepostorag_tpu.serving.sampling_params import SamplingParams
 from githubrepostorag_tpu.metrics import (
     BURST_DISPATCH,
+    KV_PAGES_IN_USE,
     PREFILL_WAVE,
+    SLIDING_PAGES_FREED,
     STATE_SLOTS_IN_USE,
     STATE_SNAPSHOTS,
 )
@@ -171,6 +176,9 @@ class _Request:
     page_match: int = 0
     state_src: int = -1
     snap_at: list[int] = field(default_factory=list)
+    # a model with a sliding kind of page (serving/kv_cache.SlidingPages): the
+    # row's pages of that kind, by absolute page index
+    sliding: SlidingRow | None = None
 
 
 from githubrepostorag_tpu.utils import next_bucket as _bucket
@@ -287,6 +295,9 @@ class Engine:
         state_snapshots: int | None = None,  # snapshot slots of a recurrent
         # model's state pool (serving/kv_cache.StateSlots), sized beside
         # num_pages; None = two a row.  Unused by every other model
+        sliding_pages: int | None = None,  # pages of the sliding kind's pool
+        # (serving/kv_cache.SlidingPages) of a model that states one, sized
+        # beside num_pages (the global kind's); None = num_pages
     ) -> None:
         # startup.engine_init: pools, allocator, state slots (obs/startup.py)
         init_phase = startup_record().begin("startup.engine_init")
@@ -297,13 +308,20 @@ class Engine:
         # are one latent pool with no V pool, ``recurrent_state`` that it keeps
         # a state pool beside its K/V pools (``kv_layers``, ``state_layers``,
         # ``state_shapes()``), ``expert_counters`` that its programs return the
-        # expert layers' counts.  Without ``step_programs`` the model is served
-        # by qwen2's programs
+        # expert layers' counts, ``page_kinds`` that some of its layers page the
+        # last ``window`` keys only, in a pool of their own.  Without
+        # ``step_programs`` the model is served by qwen2's programs
         programs = getattr(cfg, "step_programs", None)
         self._own_programs = programs is not None
         self._latent = bool(getattr(cfg, "latent_kv", False))
         self._recurrent = bool(getattr(cfg, "recurrent_state", False))
         self._expert_counters = bool(getattr(cfg, "expert_counters", False))
+        kinds = page_kinds(cfg)
+        if len(kinds) > 2 or (len(kinds) == 2 and (kinds[0][2] or not kinds[1][2]
+                                                   or not self._own_programs)):
+            raise ValueError("page kinds: one global kind, then at most one with a window, "
+                             "served by the model's own step programs")
+        sliding_kind = kinds[1] if len(kinds) == 2 else None
         self._wave_fn = forward_paged_wave
         if self._own_programs:
             family = importlib.import_module(programs)
@@ -316,11 +334,15 @@ class Engine:
                 "preempt": preempt == "on",  # parks pages in the host tier
                 # a snapshot lies at a page boundary between two blocks of a chunk
                 "prefill_chunk": self._recurrent and prefill_chunk % page_size != 0,
+                # a tier, a park and a handoff name a page by its hash alone;
+                # of a sliding kind that says nothing of whether its window is held
+                "prefix_caching": sliding_kind is not None and not prefix_caching,
             }
             if any(unsupported.values()):
-                raise ValueError(
-                    f"not built for a {'latent page' if self._latent else 'recurrent state'} "
-                    "pool: " + ", ".join(k for k, v in unsupported.items() if v))
+                pool = ("latent page" if self._latent else "recurrent state" if self._recurrent
+                        else "sliding page")
+                raise ValueError(f"not built for a {pool} pool: "
+                                 + ", ".join(k for k, v in unsupported.items() if v))
             self._wave_fn = family.forward_paged_wave
             self._decode_burst_fn = family.decode_burst
         else:
@@ -405,6 +427,26 @@ class Engine:
                 max_num_seqs, 2 * max_num_seqs if state_snapshots is None else state_snapshots)
             self._state_pools = make_state_pools(cfg, self._state.total)
             self._state_published: dict[str, int] = {}  # what the counters have been told
+        # a second kind of page: the pool of the layers that attend a window,
+        # its ledger (which releases a row's pages behind its window) and the
+        # rows' tables, indexed by absolute page as the global kind's are
+        self._sliding = self._sk_pages = self._sv_pages = None
+        if sliding_kind is not None:
+            _, layers, window = sliding_kind
+            pages = num_pages if sliding_pages is None else sliding_pages
+            self._sliding = SlidingPages(pages, page_size, window, prefill_chunk)
+            if pages < 2 * self._sliding.cap:
+                raise ValueError(f"sliding_pages={pages} cannot hold one row's window and its "
+                                 f"own pages ({2 * self._sliding.cap})")
+            sl = make_page_pools(cfg, pages, page_size, dtype=kv_dtype, layers=layers)
+            self._sk_pages, self._sv_pages = sl.k, sl.v
+            self._sliding_tables = np.zeros(
+                (max_num_seqs, self.max_pages_per_seq), dtype=np.int32)
+            self._deferred_sliding: list[int] = []
+            self._m_sliding = (SLIDING_PAGES_FREED, KV_PAGES_IN_USE.labels(kind="sliding"),
+                               KV_PAGES_IN_USE.labels(kind="global"))
+            self._sliding_published = 0
+        self.sliding_hit_tokens = 0  # stats: of page_hit_tokens, those the sliding kind held too
         self.state_restored = 0  # stats: prefills resumed from a snapshot
         self.page_hit_tokens = 0  # stats: prompt tokens whose pages the prefix cache held
         self.state_hit_tokens = 0  # ... and of those, the tokens a snapshot let a prefill skip
@@ -614,7 +656,8 @@ class Engine:
         held = lambda tree: sum(int(x.nbytes) for x in jax.tree.leaves(tree))  # noqa: E731
         startup_record().note("pool_bytes", {
             "pages": held((self._k_pages, self._v_pages, self._k_scales, self._v_scales)),
-            "state": held(self._state_pools)})
+            "state": held(self._state_pools),
+            **({"sliding": held((self._sk_pages, self._sv_pages))} if self._sliding else {})})
 
     def step_programs(self) -> list:
         """Every jitted callable a step of THIS engine can dispatch: the
@@ -650,6 +693,20 @@ class Engine:
     @value_pool.setter
     def value_pool(self, pool: jnp.ndarray) -> None:
         self._v_pages = pool
+
+    @property
+    def sliding_pools(self) -> tuple | None:
+        """The (K, V) pools of a model's sliding kind of page (None for every
+        other model), ``sliding_ledger`` their ledger: settable like ``page_pool``."""
+        return None if self._sliding is None else (self._sk_pages, self._sv_pages)
+
+    @sliding_pools.setter
+    def sliding_pools(self, pools: tuple) -> None:
+        self._sk_pages, self._sv_pages = pools
+
+    @property
+    def sliding_ledger(self):
+        return self._sliding
 
     @property
     def state_pools(self) -> dict | None:
@@ -1381,7 +1438,29 @@ class Engine:
                 # again on pages of the request's own
                 req.page_match = self._allocator.match_len(hashes)
                 hashes = hashes[:self._state.depth(hashes[:req.page_match])]
+            if self._sliding is not None:
+                # nor deeper than where the sliding kind still holds the pages
+                # of the window that ends there
+                req.page_match = self._allocator.match_len(hashes)
+                hashes = hashes[:self._sliding.depth(hashes, req.page_match)]
         return need, hashes
+
+    def _pools_admit(self, hashes: list[bytes], need: int, headroom: int = 0,
+                     drained: bool = False) -> bool:
+        """``can_admit`` of every kind of page: the global kind's allocator, and
+        the sliding kind's ledger for the window's pages and the row's own
+        (``drained``: counting what a drain of the chain would recycle)."""
+        extra = extra_s = 0
+        if drained:
+            # only deferred pages nobody else shares actually free on drain
+            extra = sum(self._allocator.releasable_count(pages)
+                        for _, pages, _ in self._deferred)
+            if self._sliding is not None:
+                extra_s = self._sliding.alloc.releasable_count(self._deferred_sliding)
+        if not self._allocator.can_admit(hashes, need, extra_free=extra, headroom=headroom):
+            return False
+        return self._sliding is None or self._sliding.can_admit(
+            hashes, len(hashes), need, extra_free=extra_s)
 
     def _admission_feasible(self) -> bool:
         """True when the head-of-queue request could actually be admitted
@@ -1394,12 +1473,8 @@ class Engine:
         req = self._waiting[0]
         need, hashes = self._head_need_hashes(req)
         rows_avail = bool(self._free_rows) or bool(self._deferred)
-        # only deferred pages nobody else shares actually free on drain
-        extra = sum(
-            self._allocator.releasable_count(pages) for _, pages, _ in self._deferred
-        )
-        return rows_avail and self._allocator.can_admit(
-            hashes, need, extra_free=extra, headroom=self._class_headroom(req))
+        return rows_avail and self._pools_admit(
+            hashes, need, headroom=self._class_headroom(req), drained=True)
 
     def _try_prefill(self, finished: list[GenerationResult]) -> bool:
         """Admit every waiting request the pool can back, then run ONE
@@ -1414,7 +1489,7 @@ class Engine:
         if self._waiting:
             req0 = self._waiting[0]
             need0, hashes0 = self._head_need_hashes(req0)
-            can_free = bool(self._free_rows) and self._allocator.can_admit(
+            can_free = bool(self._free_rows) and self._pools_admit(
                 hashes0, need0, headroom=self._class_headroom(req0))
             if not can_free and self._admission_feasible():
                 self._drain_chain(finished)
@@ -1422,8 +1497,9 @@ class Engine:
         admit = self._phase("engine.admit")
         admitted = 0
         cached_admits: list[_Request] = []  # batched presence marking below
-        while self._waiting and self._free_rows:
-            req = self._waiting[0]
+        at = 0  # requests held for a leader (``_held_for_leader``) are passed over
+        while at < len(self._waiting) and self._free_rows:
+            req = self._waiting[at]
             need, hashes = self._head_need_hashes(req)
             assert need <= self.max_pages_per_seq, "intake clamp must bound the page need"
             if (req.priority != self.protected_priority and self._preempt_on
@@ -1433,20 +1509,11 @@ class Engine:
                 # batch admission pauses entirely — every free page belongs
                 # to the class we're preempting FOR
                 break
-            if not self._allocator.can_admit(
-                    hashes, need, headroom=self._class_headroom(req)):
+            if not self._pools_admit(hashes, need, headroom=self._class_headroom(req)):
                 break  # headroom reservation: batch leaves protected room
-            if self._kv_tier_on and hashes:
-                pending = self._allocator.pending_claim_pages(hashes)
-                if pending and self._allocator.plain_free_count < need:
-                    # an identical prefix is mid-prefill on another row and
-                    # pages are tight: hold one registration instead of
-                    # duplicating the leader's whole footprint (cross-user
-                    # dedup under oversubscription).  Bounded wait — the
-                    # leader's registration or release (reap/cancel incl.)
-                    # drops the claim and unblocks the queue next step.
-                    self.dedup_holds += 1
-                    break
+            if self._held_for_leader(req, hashes, need):
+                at += 1
+                continue
             faults_before = (
                 self._allocator.fault_ins if self._kv_tier_on else 0
             )
@@ -1460,7 +1527,7 @@ class Engine:
             except OutOfPages:
                 self._allocator.release(shared)
                 break  # wait for running requests to finish
-            self._waiting.pop(0)
+            self._waiting.pop(at)
             admitted += 1
             row = self._free_rows.pop()
             req.row, req.pages, req.state = row, pages, "prefilling"
@@ -1484,6 +1551,10 @@ class Engine:
                 self._allocator.hit_tokens += req.cached_tokens
             if self._state is not None:
                 self._admit_state(req, len(shared))
+            if self._sliding is not None:
+                self.page_hit_tokens += req.page_match * self.page_size
+                self.sliding_hit_tokens += req.cached_tokens
+                req.sliding = self._sliding.admit(req.page_hashes, len(shared), need)
             if req.resume_pending:
                 # a parked victim is back: its folded prompt prefix-shared
                 # the full pages it parked (device hit or host fault-in);
@@ -1505,6 +1576,8 @@ class Engine:
             # device-side decode guard: a burst may never scatter past this
             # row's allocated pages (nor past the cache-length cap)
             self._row_limits[row] = min(len(pages) * self.page_size, self.max_seq_len - 1)
+            if req.sliding is not None:
+                self._sliding_row(req)
             self._set_row_sampling(row, req.sampling)
             if req.cached_tokens:
                 cached_admits.append(req)
@@ -1633,6 +1706,8 @@ class Engine:
         if self._state is not None:
             state_args = self._wave_state(reqs, valids, rb)
             wave_ann.set_metadata(**self._state_meta())
+        if self._sliding is not None:  # the second kind of page rides where the state does
+            state_args = {**state_args, **self._wave_sliding(reqs, valids, starts, rb, wave_ann)}
 
         # ONE program: the chunk, then its tail (prompt tokens into the
         # presence mask, the first token of every completed row drawn, marked
@@ -1658,7 +1733,10 @@ class Engine:
             self._k_pages, self._v_pages, self._k_scales, self._v_scales = cache
         else:
             # a model's own programs hand back, after the pools, the expert
-            # layers' counts and the state pool, where it has them
+            # layers' counts, the state pool and the sliding kind's pools, where
+            # it has them
+            if self._sliding is not None:
+                self._sv_pages, self._sk_pages = cache.pop(), cache.pop()
             if self._recurrent:
                 self._state_pools = cache.pop()
             if self._expert_counters:
@@ -1673,10 +1751,99 @@ class Engine:
             req.seq_len = req.prefill_pos
             self._seq_lens[req.row] = req.seq_len
             self._register_full_pages(req)
+            if req.sliding is not None:
+                self._sliding_row(req)
             if done_mask[i]:
                 done.append(req)
         if done:
             self._rows_join(done, others_running, finished)
+
+    def _held_for_leader(self, req: _Request, hashes: list[bytes], need: int) -> bool:
+        """The scheduler's ONE rule for a follower: a request whose next page
+        (the page after what the cache can serve it, ``hashes``) a row still
+        prefilling is about to publish is not admitted this step, because it
+        would compute that row's prefix a second time beside it, page for
+        page.  It is passed over, not waited behind: requests after it in the
+        queue are admitted in their order.  The wait is bounded: the leader
+        always advances, and its end, cancelled or reaped, drops the hold.
+
+        How the leader is found, and when holding pays, is the page kind's:
+        with a host tier the leader's admission CLAIMS the hashes it will
+        register (``pending_claim_pages``) and a follower is held only when
+        pages are too tight to back a twin (it can share each page as the
+        leader registers it); with a sliding kind a long prompt's pages are
+        published only behind its window or at its prefill's end, so a
+        follower can share nothing the leader has just written and is held
+        whatever the pools have free, and the leader is the prefilling row
+        whose chain hash at that page is the follower's."""
+        if self._sliding is not None:
+            depth = len(hashes)
+            return depth < len(req.page_hashes) and any(
+                r.state == "prefilling" and len(r.page_hashes) > depth
+                and r.page_hashes[depth] == req.page_hashes[depth]
+                for r in self._row_req.values())
+        if self._kv_tier_on and hashes:
+            if (self._allocator.pending_claim_pages(hashes)
+                    and self._allocator.plain_free_count < need):
+                self.dedup_holds += 1
+                return True
+        return False
+
+    def _sliding_row(self, req: _Request) -> None:
+        """The row's next token lies at ``req.seq_len``: its sliding pages
+        behind that token's window are released, the pages it is owed ahead
+        taken (``SlidingPages.advance``), and its row of the sliding table and
+        its limit follow.  Every program in flight or to come has its queries
+        at or past that position, so none reads a page released here."""
+        sl, row = self._sliding, req.sliding
+        full = min(req.prefill_pos // self.page_size, len(req.page_hashes))
+        sl.advance(row, req.seq_len, req.page_hashes, full)
+        table = self._sliding_tables[req.row]
+        table[:row.first] = 0
+        table[row.first:row.covered] = row.pages[row.first:]
+        self._row_limits[req.row] = min(
+            len(req.pages) * self.page_size, row.covered * self.page_size, self.max_seq_len - 1)
+
+    def _publish_sliding(self) -> None:
+        """The ledger's counts to the metrics, once a wave and once a burst's commit."""
+        freed, in_use, in_use_global = self._m_sliding
+        freed.inc(self._sliding.freed - self._sliding_published)
+        self._sliding_published = self._sliding.freed
+        in_use.set(self._sliding.in_use)
+        in_use_global.set(self._allocator.num_pages - self._allocator.free_count)
+
+    def _burst_sliding_meta(self) -> dict:
+        """``sliding_tokens`` of a burst's annotation: over the running rows,
+        the keys of a row that a sliding layer's window holds (what its kernel
+        walks in the pool), beside ``kv_tokens`` (what a global layer's does)."""
+        w = self._sliding.window - 1
+        return {"sliding_tokens": sum(min(r.seq_len, w) for r in self._row_req.values()
+                                      if r.state == "running")}
+
+    def _wave_sliding(self, reqs: list[_Request], valids: list[int], starts: list[int],
+                      rb: int, wave_ann) -> dict:
+        """The sliding kind's arguments of one wave: its pools, the rows' tables
+        and slots of that kind; and its stats on the wave's annotation: the
+        (query, key) pairs inside the window, the keys the rows walk there, and
+        the cumulative counts of the ledger."""
+        chunk, w = self.prefill_chunk, self._sliding.window
+        slots = np.full((rb, chunk), -1, dtype=np.int32)
+        bt = np.zeros((rb, self.max_pages_per_seq), dtype=np.int32)
+        pairs = keys = 0
+        for i, req in enumerate(reqs):
+            table = self._sliding_tables[req.row]
+            slots[i] = slot_mapping(table, starts[i], valids[i], self.page_size, chunk)
+            bt[i] = table
+            seen = np.minimum(np.arange(starts[i] + 1, starts[i] + valids[i] + 1), w)
+            pairs += int(seen.sum())
+            keys += starts[i] + valids[i] - max(0, starts[i] - w + 1)
+        wave_ann.set_metadata(
+            sliding_pairs=pairs, sliding_keys=keys, page_hit_tokens=self.page_hit_tokens,
+            sliding_hit_tokens=self.sliding_hit_tokens,
+            sliding_pages_freed=self._sliding.freed)
+        self._publish_sliding()
+        return {"sliding_k": self._sk_pages, "sliding_v": self._sv_pages,
+                "sliding_slots": slots, "sliding_tables": bt}
 
     def _admit_state(self, req: _Request, shared_pages: int) -> None:
         """A recurrent model's admission: the snapshot the first wave resumes
@@ -2077,7 +2244,8 @@ class Engine:
         self._m_burst[ahead].inc()
         self._phase("engine.decode_burst", rows=live_rows, kv_tokens=kv_tokens,
                     steps=n_steps, ahead=int(ahead),
-                    **(self._moe_meta("burst") if self._expert_counters else {}))
+                    **(self._moe_meta("burst") if self._expert_counters else {}),
+                    **(self._burst_sliding_meta() if self._sliding else {}))
         out = self._decode_burst_fn(
             self.params, self.cfg,
             last_d, lens_d,
@@ -2105,11 +2273,15 @@ class Engine:
             first_tokens=self._first_d, fresh=fresh, fresh_lens=fresh_lens,
             key_step=self._next_key_step(),
             **({"state": self._state_pools} if self._recurrent else {}),
+            **({"sliding_k": self._sk_pages, "sliding_v": self._sv_pages,
+                "sliding_tables": self._sliding_tables.copy()} if self._sliding else {}),
         )
         if self.kv_quant:
             (toks, _, self._k_pages, self._v_pages, self._presence,
              out_lens, last, self._k_scales, self._v_scales) = out
         else:
+            if self._sliding is not None:
+                *out, self._sk_pages, self._sv_pages = out
             if self._recurrent:
                 *out, self._state_pools = out
             if self._expert_counters:
@@ -2221,6 +2393,11 @@ class Engine:
                 req.seq_len += 1
                 self._seq_lens[row] = req.seq_len
                 self._commit_token(req, int(toks[row, i]), finished)
+        if self._sliding is not None:
+            for req in self._row_req.values():
+                if req.state == "running":
+                    self._sliding_row(req)
+            self._publish_sliding()
 
     def _moe_dispatched(self, program: str, counts: jnp.ndarray, steps: int) -> None:
         """A step program that ran expert layers was dispatched: keep its
@@ -2266,6 +2443,9 @@ class Engine:
             self._obs_release(rid)
             self._free_rows.append(row)
         self._deferred.clear()
+        if self._sliding is not None and self._deferred_sliding:
+            self._sliding.release_pages(self._deferred_sliding)
+            self._deferred_sliding = []
 
     def _push_sampling(self) -> None:
         """Mirror host sampling params to device arrays when dirty."""
@@ -2305,6 +2485,13 @@ class Engine:
             # (reap/cancel mid-prefill) so held followers aren't stranded
             self._allocator.unclaim(req.claimed_hashes)
             req.claimed_hashes = []
+        if req.sliding is not None:
+            held, req.sliding = self._sliding.release(req.sliding), None
+            if self._chain is not None:
+                self._deferred_sliding += held
+            else:
+                self._sliding.release_pages(held)
+            self._sliding_tables[req.row] = 0
         if req.row >= 0:
             if self._chain is not None:
                 # an in-flight burst still reads this row's pages; recycle

@@ -80,12 +80,28 @@ def quant_bits(quant) -> int:
     raise ValueError(f"kv_quant={quant!r} not understood; use int4, int8, or a bool")
 
 
+def page_kinds(cfg) -> tuple:
+    """The kinds of page a configuration object states: ``(name, layers, window
+    or None)`` each, the first the GLOBAL kind (pages that keep every key of a
+    sequence for its life: ``num_pages``, the allocator and the block tables
+    every model has), any other a SLIDING kind (pages of layers that attend the
+    last ``window`` keys and nothing older: a pool, a table and a ledger of
+    their own, ``SlidingPages``).  A model that states none is one global kind
+    of ``kv_layers`` (or all its) layers."""
+    stated = getattr(cfg, "page_kinds", None)
+    if stated:
+        return tuple(stated)
+    return (("global", getattr(cfg, "kv_layers", cfg.num_layers), None),)
+
+
 def make_page_pools(
     cfg: Qwen2Config, num_pages: int, page_size: int, dtype=jnp.bfloat16,
-    quant=False,
+    quant=False, layers: int | None = None,
 ) -> PagePools:
-    # a hybrid model pages keys and values in some of its layers only
-    layers = getattr(cfg, "kv_layers", cfg.num_layers)
+    # a hybrid model pages keys and values in some of its layers only; a kind
+    # of page other than the global one says how many layers it serves
+    if layers is None:
+        layers = getattr(cfg, "kv_layers", cfg.num_layers)
     shape = (layers, cfg.num_kv_heads, num_pages, page_size, cfg.head_dim)
     bits = quant_bits(quant)
     if getattr(cfg, "latent_kv", False):
@@ -1092,6 +1108,141 @@ class StateSlots:
         if slot is not None:
             self._free.append(slot)
             self.evicted += 1
+
+
+@dataclass
+class SlidingRow:
+    """One sequence's pages of a sliding kind (``SlidingPages``): ``pages`` by
+    ABSOLUTE page index (page j holds positions ``[j * page_size, (j + 1) *
+    page_size)``), -1 where the page was released behind the window or came
+    from nobody; ``len(pages)`` is how far the row is covered, ``total`` how
+    far it will ever need to be.  ``first`` is the first index still held,
+    ``shared`` the index below which what it holds came from the prefix cache,
+    ``private`` the pages of its own it holds, ``registered`` the index below
+    which its full prompt pages have been offered to the cache."""
+
+    total: int
+    first: int = 0
+    shared: int = 0
+    private: int = 0
+    registered: int = 0
+    pages: list = None
+
+    @property
+    def covered(self) -> int:
+        return len(self.pages)
+
+
+class SlidingPages:
+    """Host-side ledger of a SLIDING kind of page: the pool of the layers that
+    attend the last ``window`` keys.  Pages are named by the prefix cache's own
+    chain hashes (a page's content depends on the whole prefix in either
+    kind), refcounted and kept in an LRU exactly as the global kind's are (the
+    same allocator class, a second instance).  What differs is how long a
+    sequence holds a page:
+
+    * a row RELEASES a page once every key in it is older than the window of
+      the row's next token (``advance``); a released page that is registered
+      parks in the LRU and can serve a later prefix hit;
+    * a prefix hit of ``d`` pages is usable only where this kind still holds
+      the pages that cover ``[d * page_size - window + 1, d * page_size)``
+      (``depth``): the rule ``StateSlots`` follows for snapshots, with pages in
+      the snapshot's place;
+    * a row whose pages all fit ``cap`` (the window, a prefill chunk and one
+      more) gets them all at admission and never asks again.  A longer one (a
+      cold long prompt) gets ``cap`` of its own and takes a page ahead for
+      every page of its own it releases behind, in the same call.  So that
+      taking one can never fail, such a row's own pages stay unregistered
+      until it is covered to its end: nobody shares them, so releasing one
+      always frees it (it is registered as it is released, and parks).  Once
+      covered to its end the row registers its full prompt pages as the global
+      kind does, chunk by chunk.  An admission is therefore never refused for
+      what a running row may still need, and no row waits on another.
+    """
+
+    def __init__(self, num_pages: int, page_size: int, window: int, chunk: int) -> None:
+        self.alloc = PrefixCachingAllocator(num_pages)
+        self.num_pages, self.page_size, self.window = num_pages, page_size, window
+        self.cap = pages_needed(window + chunk, page_size) + 1
+        self.freed = 0  # stats: pages released behind a window
+        self.in_use = 0  # claims rows hold
+
+    def first_page(self, pos: int) -> int:
+        """The first page the token at position ``pos`` sees a key of."""
+        return max(0, pos - self.window + 1) // self.page_size
+
+    def depth(self, hashes: list[bytes], match: int) -> int:
+        """The deepest d <= ``match`` (pages of ``hashes`` the global kind
+        holds) whose window's pages this kind holds too; 0 where none."""
+        held = self.alloc._hash_to_page
+        run = 0  # pages held in a row, ending at the index looked at
+        runs = []
+        for h in hashes[:match]:
+            run = run + 1 if h in held else 0
+            runs.append(run)
+        for d in range(match, 0, -1):
+            if runs[d - 1] >= d - self.first_page(d * self.page_size):
+                return d
+        return 0
+
+    def _plan(self, d: int, total: int) -> tuple:
+        """(first page held, pages of its own at admission) of a row admitted
+        with ``d`` pages from the cache."""
+        return self.first_page(d * self.page_size), min(total - d, self.cap)
+
+    def can_admit(self, hashes: list[bytes], d: int, total: int, extra_free: int = 0) -> bool:
+        first, fresh = self._plan(d, total)
+        return self.alloc.can_admit(hashes[first:d], d - first + fresh, extra_free=extra_free)
+
+    def admit(self, hashes: list[bytes], d: int, total: int) -> SlidingRow:
+        """The pages of a row admitted ``d`` pages deep (``depth`` said so and
+        ``can_admit`` agreed): the window's pages shared, its own allocated."""
+        first, fresh = self._plan(d, total)
+        shared = self.alloc.share(hashes[first:d])
+        assert len(shared) == d - first, "depth() promised the window's pages"
+        self.in_use += len(shared) + fresh
+        return SlidingRow(total=total, first=first, shared=d, private=fresh, registered=d,
+                          pages=[-1] * first + shared + self.alloc.allocate(fresh))
+
+    def advance(self, row: SlidingRow, next_pos: int, hashes: list[bytes], full: int) -> None:
+        """The row's next token is at ``next_pos`` and its prompt's first
+        ``full`` pages are written whole: release what lies behind the window,
+        take what the row is owed ahead, offer the cache what it may have."""
+        alloc, partial = self.alloc, row.covered < row.total
+        upto = min(self.first_page(next_pos), row.covered)
+        known = min(full, len(hashes))
+        for j in range(row.first, upto):
+            page = row.pages[j]
+            if j >= row.shared:
+                row.private -= 1
+                if partial and j < known:  # registered as it leaves: nobody shared it before
+                    alloc.register(hashes[j], page)
+            alloc.release([page])
+            row.pages[j] = -1
+        self.freed += max(0, upto - row.first)
+        self.in_use -= max(0, upto - row.first)
+        row.first = max(row.first, upto)
+        if partial:
+            take = min(row.total - row.covered, self.cap - row.private)
+            if take > 0:
+                row.pages += alloc.allocate(take)  # no more than it has just freed
+                row.private += take
+                self.in_use += take
+        if row.covered == row.total:
+            for j in range(max(row.registered, row.first), known):
+                alloc.register(hashes[j], row.pages[j])
+            row.registered = max(row.registered, known)
+
+    def release(self, row: SlidingRow) -> list[int]:
+        """The row ends.  Returns the pages it still holds, for the caller to
+        release now or once no burst in flight reads them (``release_pages``)."""
+        pages = [p for p in row.pages[row.first:] if p >= 0]
+        row.pages, row.first = [], 0
+        return pages
+
+    def release_pages(self, pages: list[int]) -> None:
+        self.alloc.release(pages)
+        self.in_use -= len(pages)
 
 
 def pages_needed(num_tokens: int, page_size: int) -> int:

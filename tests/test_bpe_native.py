@@ -295,3 +295,34 @@ def test_long_input_stability(trained, native):
     ids = native.encode(text)
     assert ids == hf.encode(text).ids
     assert native.decode(ids) == hf.decode(ids, skip_special_tokens=True)
+
+
+@pytest.mark.parametrize("pattern", [None, r"[a-z]+", r"( ?[a-z]+)|\d"],
+                         ids=["the-checkpoints-own", "skips-characters", "captures"])
+def test_ascii_text_takes_one_native_call_and_agrees_with_the_merge_loop(trained, pattern):
+    """ASCII text goes through ``_encode_ascii`` (one native call over the
+    pattern's match boundaries, no list a segment); any other text, and a
+    tokenizer with ``ignore_merges`` or without the library, keeps the general
+    path.  Both must say what the pure-Python merge loop says, also where the
+    pattern leaves characters out (each gap is a segment of its own) or has a
+    capturing group (``findall`` then returns the group, not the match)."""
+    import regex
+
+    path, hf = trained
+    fast, py = NativeBPETokenizer(path), NativeBPETokenizer(path, use_native=False)
+    if pattern is not None:
+        fast._re = py._re = regex.compile(pattern)
+    taken = []
+    inner = fast._encode_ascii
+    fast._encode_ascii = lambda text: taken.append(text) or inner(text)
+    ascii_texts = [t for t in BATTERY + CORPUS if t and t.isascii()]
+    ascii_texts += ["\x00\x01 odd\r\n\tbytes  ", "x" * 700, " ".join(ascii_texts) * 6]
+    for text in ascii_texts:
+        assert fast.encode(text) == py.encode(text), repr(text[:40])
+        if pattern is None:
+            assert fast.encode(text) == hf.encode(text).ids, repr(text[:40])
+    assert len(taken) >= len(ascii_texts)
+    taken.clear()
+    for text in (t for t in BATTERY if not t.isascii()):
+        assert fast.encode(text) == py.encode(text), repr(text)
+    assert taken == [] or all(t.isascii() for t in taken)  # a special token's neighbours may be
