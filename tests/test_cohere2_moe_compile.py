@@ -197,7 +197,8 @@ def test_this_cells_metrics_select_the_ops_under_their_scopes(chip, as_on_chip):
     assert set(got) == {"sliding_prefill_attention"} and _picked(burst, prefill) == {}
     calls = [n for n, _ in timed_ops(wave) if prefill.search(n)]
     assert len(calls) == 3 * 4, calls
-    assert len(re.findall(r"%fused_window_attention\.\d+ = ", wave)) == 4  # the global layer's
+    # the global layer's four (the compiler numbers the later ones; the first may go bare)
+    assert len(re.findall(r"%fused_window_attention(\.\d+)? = ", wave)) == 4
 
     # the guard on the pools of BOTH kinds: nothing either program times moves one whole
     moves = re.compile(spec("sliding_pool_move_share")["pattern"])

@@ -17,7 +17,10 @@ import jax
 import jax.numpy as jnp
 
 from githubrepostorag_tpu.models.qwen2 import Qwen2Config
+from githubrepostorag_tpu.ops import fused_decode
 from githubrepostorag_tpu.ops.fused_decode import (
+    _fold_pages,
+    _query_tile,
     fused_packed_attention,
     fused_window_attention,
 )
@@ -35,9 +38,18 @@ from githubrepostorag_tpu.serving.kv_cache import (
 )
 
 # kernel-test geometry: 2 kv heads x group 2, 8-wide heads, 4-token pages;
-# each row walks MP pages out of a P=32 pool through a shuffled block table
-N_KV, GROUP, HD, PS, P, MP = 2, 2, 8, 4, 32, 4
+# each row walks MP pages (or a WALKS entry's) out of a P=64 pool through a
+# shuffled block table
+N_KV, GROUP, HD, PS, P, MP = 2, 2, 8, 4, 64, 4
 N_Q = N_KV * GROUP
+# name -> (pages a row's table holds, sliding window in keys or None).  A grid
+# step folds ``_fold_pages`` pages, 8 at the most: a table of 4 is one step
+# shorter than that, 11 pages are two steps of 6 (the walk no multiple of the
+# fold), 19 are three of 7; under a window of 37 keys the walk is 12 or 13 pages
+# that begin at the page of a row's first visible key, wherever in the table
+# that falls.  Quantised pools fold one page a step whatever the table.
+WALKS = {"table4": (4, None), "table11": (11, None), "table19": (19, None),
+         "table19-window37": (19, 37)}
 
 
 def _rand_pools(key, quant):
@@ -61,14 +73,18 @@ def _rand_pools(key, quant):
     return k, v, k_s, v_s
 
 
-def _window_case(key, b, s_w, quant):
+def _window_case(key, b, s_w, quant, mp=MP):
     kq, kb, kp = jax.random.split(key, 3)
     k, v, ks, vs = _rand_pools(kp, quant)
     # block tables with HOLES: rows own disjoint shuffled page sets, so a
     # kernel that walked pages in pool order would read the wrong tokens
-    bt = jax.random.permutation(kb, P)[: b * MP].reshape(b, MP).astype(jnp.int32)
+    # (the pages a grid step folds together lie anywhere in the pool)
+    bt = jax.random.permutation(kb, P)[: b * mp].reshape(b, mp).astype(jnp.int32)
     q = jax.random.normal(kq, (b, s_w, N_Q, HD), jnp.float32)
-    cached = jnp.asarray([(3 * i) % (MP * PS - s_w + 1) for i in range(b)], jnp.int32)
+    # row 0 fills its table; the others end 3, 6 tokens short of it, so no
+    # ``cached_lens`` but one in four is page-aligned and a row's last step
+    # holds pages past its length
+    cached = jnp.asarray([mp * PS - s_w - 3 * i for i in range(b)], jnp.int32)
     new = jnp.full((b,), s_w, jnp.int32)
     return q, k, v, bt, cached, new, ks, vs
 
@@ -76,22 +92,86 @@ def _window_case(key, b, s_w, quant):
 # ------------------------------------------------------- kernel vs oracle --
 
 
+@pytest.mark.parametrize("walk", WALKS)
 @pytest.mark.parametrize("quant", [0, 8, 4], ids=["fp", "int8", "int4"])
 @pytest.mark.parametrize("s_w", [1, 5, 9])  # plain decode, k=4 verify, k=8
 @pytest.mark.parametrize("b", [1, 3])
-def test_fused_window_matches_paged_ref(quant, s_w, b):
+def test_fused_window_matches_paged_ref(quant, s_w, b, walk):
+    mp, sliding = WALKS[walk]
     key = jax.random.PRNGKey(quant * 100 + s_w * 10 + b)
-    q, k, v, bt, cached, new, ks, vs = _window_case(key, b, s_w, quant)
-    got = fused_window_attention(q, k, v, bt, cached, new, ks, vs, interpret=True)
-    ref = paged_attention_ref(q, k, v, bt, cached, new, ks, vs)
+    q, k, v, bt, cached, new, ks, vs = _window_case(key, b, s_w, quant, mp)
+    got = fused_window_attention(q, k, v, bt, cached, new, ks, vs, interpret=True,
+                                 sliding=sliding)
+    ref = paged_attention_ref(q, k, v, bt, cached, new, ks, vs, sliding=sliding)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-4, atol=1e-4)
 
 
-def test_fused_window_inactive_rows_are_finite_zero():
+# (walk, group, columns, head, page size, pool itemsize, quant) -> pages a step folds
+@pytest.mark.parametrize("shape,fold", [
+    ((208, 16, 128, 128, 128, 2, 0), 8),  # Command A+'s global layer: 26 steps of 8
+    ((34, 16, 128, 128, 128, 2, 0), 7),  # its sliding walk: 5 steps of 7, not of 8
+    ((80, 1, 512, 128, 128, 2, 0), 8),  # Olmo-Hybrid
+    ((80, 5, 512, 128, 128, 2, 0), 8),  # Falcon-H1
+    ((80, 8, 256, 256, 128, 2, 0), 8),  # Qwen3-Next: head 256, 8 MB resident
+    ((16, 7, 512, 128, 128, 2, 0), 8),  # Qwen2-7B's cells: a row's 2-8 pages are one step
+    ((4, 2, 5, 8, 4, 4, 0), 4), ((11, 2, 5, 8, 4, 4, 0), 6), ((19, 2, 9, 8, 4, 4, 0), 7),
+    ((3, 16, 128, 128, 128, 2, 0), 3),  # a walk shorter than a step is one step
+    ((208, 16, 128, 128, 128, 1, 8), 1), ((208, 16, 128, 128, 128, 1, 4), 1),  # quantised: N = 1
+    ((208, 16, 512, 256, 128, 2, 0), 1),  # nothing left beside the resident blocks: a page a step
+])
+def test_fold_pages_follows_the_calls_shapes(shape, fold):
+    assert _fold_pages(*shape) == fold
+
+
+@pytest.mark.parametrize("group,s_w,span,tile", [
+    (16, 128, 1024, (4, 128)),  # Command A+: 2 MB of scores is four heads' columns
+    (16, 128, 896, (4, 128)), (1, 512, 1024, (1, 512)), (5, 512, 1024, (1, 512)),
+    (7, 512, 1024, (1, 512)), (8, 256, 1024, (2, 256)),
+    (1, 2048, 1024, (1, 512)),  # a head's columns past a tile: a divisor of them
+    (7, 1, 1024, (7, 1)), (2, 5, 16, (2, 5)), (4, 9, 1024, (4, 9)),  # windows go whole
+])
+def test_query_tile_follows_the_calls_shapes(group, s_w, span, tile):
+    assert _query_tile(group, s_w, span) == tile
+
+
+# the wave's call of each cell's family, one kv head, two rows: (group, columns, head,
+# bfloat16 products, bytes of scores a pass may make or None for the module's)
+@pytest.mark.parametrize("group,s_w,hd,narrow,tile_bytes", [
+    (16, 128, 128, True, None),  # Command A+: 16 x 128 columns, passes of 4 heads
+    (1, 512, 128, False, None),  # Olmo-Hybrid
+    (5, 512, 128, False, None),  # Falcon-H1: passes of one head
+    (7, 512, 128, False, None),  # Qwen2-7B
+    (8, 256, 256, False, None),  # Qwen3-Next: head 256, passes of 2 heads
+    (2, 512, 128, False, 128 * 1024),  # a head's columns in passes of 32
+], ids=["16x128", "1x512", "5x512", "7x512", "8x256-head256", "2x512-split-columns"])
+def test_fused_window_at_the_cells_wave_shapes(group, s_w, hd, narrow, tile_bytes, monkeypatch):
+    """128-token pages, a table of 10: two steps of 5 pages; the first row fills
+    its table, the second begins and ends in mid-page."""
+    if tile_bytes:
+        monkeypatch.setattr(fused_decode, "TILE_BYTES", tile_bytes)
+    ps, pages, mp = 128, 24, 10
+    rng = np.random.default_rng(group * s_w)
+    k, v = (jnp.asarray(rng.standard_normal((1, pages, ps, hd)), jnp.bfloat16) for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((2, s_w, group, hd)), jnp.bfloat16)
+    bt = jnp.asarray(np.stack([rng.permutation(pages)[:mp] for _ in range(2)]), jnp.int32)
+    new = jnp.asarray([s_w, s_w - 37], jnp.int32)
+    cached = jnp.asarray([mp * ps - s_w, 3 * ps + 11], jnp.int32)
+    assert _fold_pages(mp, group, s_w, hd, ps, 2, 0) == 5
+    # fresh jit: TILE_BYTES is read while the call is traced
+    call = jax.jit(fused_decode._window_attention, static_argnames=("interpret", "bf16_products"))
+    got = call(q, k, v, bt, cached, new, interpret=True, bf16_products=narrow)
+    ref = paged_attention_ref(q, k, v, bt, cached, new)
+    live = np.arange(s_w)[None, :] < np.asarray(new)[:, None]
+    np.testing.assert_allclose(np.asarray(got, np.float32)[live], np.asarray(ref, np.float32)[live],
+                               atol=4e-2 if narrow else 2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize("walk", ["table4", "table11"])
+def test_fused_window_inactive_rows_are_finite_zero(walk):
     """Bucket-padding rows (total length 0) must come out exactly zero —
     never NaN from an empty softmax — while live rows still match."""
-    q, k, v, bt, cached, new, ks, vs = _window_case(jax.random.PRNGKey(0), 3, 5, 0)
+    q, k, v, bt, cached, new, ks, vs = _window_case(jax.random.PRNGKey(0), 3, 5, 0, WALKS[walk][0])
     cached = cached.at[1].set(0)
     new = new.at[1].set(0)
     got = fused_window_attention(q, k, v, bt, cached, new, interpret=True)

@@ -26,7 +26,11 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from githubrepostorag_tpu.ops.fused_decode import fused_window_attention
+from githubrepostorag_tpu.ops.fused_decode import (
+    _fold_pages,
+    fused_window_attention,
+    sliding_prefill_attention,
+)
 from githubrepostorag_tpu.ops.packed_prefill import packed_prefill_attention_seg
 from githubrepostorag_tpu.ops.pallas_int4 import int4_matmul
 from githubrepostorag_tpu.ops.pallas_paged import paged_attention_decode_staged
@@ -143,6 +147,42 @@ def test_kernel_compiles_for_v5e(chip, build):
     shapes = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip) for shape, dtype in args]
     compiled = fn.lower(*shapes).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# the wave's attention call as the cells' programs make it (PR 51): name -> (rows, kv heads,
+# query heads a kv head, columns, head, pages a row's table holds, pages of the pool, a sliding
+# layer's window, bfloat16 products, the pages a grid step folds)
+WAVE_CALLS = {
+    # Command A+: 128 columns a call (ATTN_SPAN), tables of 208 pages; the eight-row call is
+    # the tightest fit of any cell (models/cohere2_moe.py)
+    **{f"command-a-plus-{rows}row": (rows, 8, 16, 128, 128, 208, 2560, None, True, 8)
+       for rows in (1, 2, 4, 8)},
+    "command-a-plus-8row-sliding": (8, 8, 16, 128, 128, 208, 1024, 4096, True, 7),
+    # the hybrids' 512-column calls, contexts to 10,240
+    "olmo-hybrid-2row": (2, 30, 1, 512, 128, 80, 1280, None, False, 8),
+    "falcon-h1-2row": (2, 4, 5, 512, 128, 80, 1280, None, False, 8),
+}
+
+
+@pytest.mark.parametrize("name", WAVE_CALLS)
+def test_wave_kernel_folds_pages_and_fits_v5e(chip, name):
+    """The rule picks the pages a step folds from the call's shapes alone, and
+    the call it shapes fits the 16 MB a v5e kernel may hold: as many K and as
+    many V operands as pages folded, each the whole pool."""
+    rows, n_kv, group, cols, hd, table, pages, window, narrow, fold = WAVE_CALLS[name]
+    walk = table if window is None else min(table, (window + cols - 2) // PAGE + 2)
+    assert _fold_pages(walk, group, cols, hd, PAGE, 2, 0) == fold
+    pool = ((1, n_kv, pages, PAGE, hd), jnp.bfloat16)
+    args = [((rows, cols, n_kv * group, hd), jnp.bfloat16), pool, pool, ((rows, table), jnp.int32),
+            ((rows,), jnp.int32), ((rows,), jnp.int32), None, None, ((), jnp.int32)]
+    shapes = [a and jax.ShapeDtypeStruct(*a, sharding=chip) for a in args]
+    call = fused_window_attention if window is None else sliding_prefill_attention
+    hlo = call.lower(*shapes, interpret=False, sliding=window, bf16_products=narrow).compile().as_text()
+    custom = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln and " custom-call(" in ln]
+    assert len(custom) == 1
+    assert custom[0].count(f"bf16[1,{n_kv},{pages},{PAGE},{hd}]") == 2 * fold  # the pool itself, no copy
+    # the name and the shape a trace's op names carry (benchmarks/readers, tests/benchmarks)
+    assert re.match(rf"\s*%{call.__name__}\.\d+ = bf16\[{rows},{n_kv},{group},{cols},{hd}\]", custom[0])
 
 
 # ---- the step programs keep the K/V page pools where they are -------------
