@@ -471,6 +471,17 @@ BURST_DISPATCH = Counter(
     ["ahead"],
     registry=REGISTRY,
 )
+ENGINE_CYCLE = Histogram(
+    "rag_engine_cycle_seconds",
+    "The decode cycle: seconds from one decode burst's tokens landing on the "
+    "host to the next burst's, which every live row waits for its next "
+    "burst of tokens, by the prefill waves dispatched inside it (waves=0: "
+    "the burst alone; 1; 2+): the distance between the labels is what "
+    "prefill costs the rows that are decoding",
+    ["waves"],
+    registry=REGISTRY,
+    buckets=(0.005, 0.01, 0.025, 0.05, 0.075, 0.1, 0.15, 0.2, 0.3, 0.5, 1.0, 2.5),
+)
 PREFILL_WAVE = Counter(
     "rag_engine_prefill_wave_total",
     "Padded prefill waves dispatched, by the columns a row the wave program "

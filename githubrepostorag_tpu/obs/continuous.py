@@ -55,6 +55,10 @@ class ContinuousProfiler:
         # the replica's finished-request records (AsyncEngine.request_ring):
         # hung here by its owner so readers find it through profilers()
         self.request_ring: deque[dict] | None = None
+        # and its engine's burst landings with their cycles (Engine.cycle_ring),
+        # beside the module names of the engine's two step programs
+        self.cycle_ring: deque[dict] | None = None
+        self.cycle_programs: dict[str, str] | None = None
         self._m_samples = metrics.PROFILE_SAMPLES.labels(replica=replica)
 
     def on_step(self, now: float, rec: dict | None,

@@ -371,6 +371,10 @@ TTFT_PARTS = (
 )
 
 
+# counts in the same record that ride on the ``engine.decode`` span
+DECODE_COUNTS = ("decode_cycles", "decode_wave_cycles", "decode_wave_tokens")
+
+
 def record_engine_spans(result: Any, parent: TraceContext | None) -> None:
     """Turn a ``GenerationResult``'s monotonic phase stamps into spans
     under ``parent``: the six parts of time to first token, and prefill /
@@ -401,7 +405,10 @@ def record_engine_spans(result: Any, parent: TraceContext | None) -> None:
             if faulted:
                 psp.add_event("kv_fault_in", pages=faulted)
     if ftok is not None and done > ftok:
+        # the request's decode by burst landing (Engine._cycle_landed): how many
+        # brought it tokens, and how many of those cycles carried a prefill wave
+        cycles = {k: timings[k] for k in DECODE_COUNTS if k in timings}
         record_span("engine.decode", ftok, done, parent=parent, attrs={
             **attrs, "output_tokens": len(getattr(result, "output_tokens", ()) or ()),
-            "finish_reason": getattr(result, "finish_reason", ""),
+            "finish_reason": getattr(result, "finish_reason", ""), **cycles,
         })

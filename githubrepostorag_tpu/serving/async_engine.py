@@ -107,6 +107,9 @@ class AsyncEngine:
         # reach it through the profiler registry (continuous.profilers()).
         self.request_ring: deque[dict] = deque(maxlen=REQUEST_RING)
         self.continuous.request_ring = self.request_ring
+        # the engine's own record of its decode cycles hangs beside it
+        self.continuous.cycle_ring = getattr(engine, "cycle_ring", None)
+        self.continuous.cycle_programs = getattr(engine, "cycle_programs", None)
         # lifecycle is event-loop state: MultiAsyncEngine transitions it and
         # its _pick reads it, both on the loop; other threads only render it
         self.lifecycle = "active"

@@ -41,6 +41,7 @@ from __future__ import annotations
 import importlib
 import itertools
 import time
+from collections import deque
 from dataclasses import dataclass, field, replace
 from functools import partial
 from typing import Callable
@@ -83,6 +84,7 @@ from githubrepostorag_tpu.serving.kv_cache import (
 from githubrepostorag_tpu.serving.sampling_params import SamplingParams
 from githubrepostorag_tpu.metrics import (
     BURST_DISPATCH,
+    ENGINE_CYCLE,
     KV_PAGES_IN_USE,
     PREFILL_WAVE,
     SLIDING_PAGES_FREED,
@@ -98,6 +100,8 @@ logger = get_logger(__name__)
 
 TokenCallback = Callable[[str, int], None]  # (request_id, token_id)
 
+CYCLE_RING = 4096  # burst landings kept with their cycle: some minutes of traffic
+
 
 @dataclass
 class GenerationResult:
@@ -112,7 +116,12 @@ class GenerationResult:
     # (before the driver lock), submit_t, prefill_start_t, prefill_end_t
     # (completing chunk dispatched), first_token_t, first_emit_t (first
     # token off the stream queue; AsyncEngine.stream adds it), done_t —
-    # obs/engine_profile.record_engine_spans turns them into spans
+    # obs/engine_profile.record_engine_spans turns them into spans.  Beside
+    # them the request's decode, one count a burst landing and not a token:
+    # last_token_t (the landing that brought its last token), decode_cycles
+    # (landings that brought it a token), decode_wave_cycles (of those after
+    # its first, the cycles that carried a prefill wave), decode_wave_tokens
+    # (those waves' new tokens): what its time between tokens was made of
     timings: dict | None = None
     # KV tiering: prefix pages this request re-admitted from the host tier
     # instead of recomputing (0 on untiered engines)
@@ -145,6 +154,11 @@ class _Request:
     prefill_start_t: float | None = None  # admission: waiting -> prefilling
     prefill_end_t: float | None = None  # completing chunk dispatched (host)
     first_token_t: float | None = None
+    # the request's decode by burst landing (Engine._cycle_landed)
+    last_token_t: float | None = None
+    decode_cycles: int = 0
+    decode_wave_cycles: int = 0
+    decode_wave_tokens: int = 0
     recv_t: float | None = None  # caller's stamps (AsyncEngine.stream)
     enqueue_t: float | None = None
     # absolute time.monotonic() budget; past it the request is reaped at
@@ -380,7 +394,6 @@ class Engine:
             # read back once a later burst's tokens prove the device is past it
             self.moe_stats = {"burst": [0, 0, 0], "prefill": [0, 0, 0]}
             self._moe_pending: list[tuple[int, str, jnp.ndarray, int]] = []
-            self._dispatch_seq = 0
         self.params = params
         self.cfg = cfg
         self.max_num_seqs = max_num_seqs
@@ -528,7 +541,10 @@ class Engine:
                 "sp prefill: threshold=%d tokens over sp=%d (segment-packed, ladder %s)",
                 sp_prefill_threshold, self._sp, self.sp_ring_bucket_ladder(),
             )
-        self.step_dispatches_total = 0  # stats: main-model programs issued
+        # stats: main-model programs issued.  Also every dispatch's NUMBER:
+        # ``seq`` on the dispatch annotations, in the burst's chain entry and
+        # on its landing, and what the expert counts are read back up to
+        self.step_dispatches_total = 0
         self.requests_admitted = 0  # cumulative add_request count
         self.deadline_reaps = 0  # requests reaped past their deadline
 
@@ -638,6 +654,28 @@ class Engine:
         # with the allocator-side occupancy integral
         self._deferred: list[tuple[int, list[int], str]] = []
         self._pending_first: list[tuple[jnp.ndarray, list[tuple[_Request, int]]]] = []
+        # The decode cycle: from one burst's tokens landing on the host to the
+        # next burst's.  With the host a burst ahead the landings are paced by
+        # the device, so a cycle is the device's time for everything
+        # dispatched between two bursts (the later burst included) plus
+        # whatever it sat idle, and every live row's gap between tokens is the
+        # cycle over the burst's steps.  A wave adds its new tokens to
+        # ``_wave_tokens_since_burst``; a burst takes the sum into its chain
+        # entry beside its dispatch number; its landing (``_cycle_landed``)
+        # writes both, the waves between (every burst lands, in order: the
+        # dispatch numbers between this burst's and the last landed one's),
+        # the stamp and the cycle's seconds into ``cycle_ring`` (found beside
+        # ``AsyncEngine.request_ring``).  ``cycle_programs`` names the XLA
+        # modules of the two step programs, for whoever splits a device trace
+        # by cycle: which events are this engine's burst and wave is the
+        # engine's to say
+        self._wave_tokens_since_burst = 0
+        self._landed_seq = 0  # the last burst to land
+        self._landed_t: float | None = None  # None: no burst before this one in flight
+        self.cycle_ring: deque[dict] = deque(maxlen=CYCLE_RING)
+        self.cycle_programs = {"burst": "jit_" + self._decode_burst_fn.__name__,
+                               "wave": "jit_" + self._wave_fn.__name__}
+        self._m_cycle = [ENGINE_CYCLE.labels(waves=w) for w in ("0", "1", "2+")]
 
         # advisory page observatory (obs/hbm.py) — request-attribution seams
         self._page_obs = None
@@ -1699,7 +1737,8 @@ class Engine:
         wave_ann.set_metadata(
             rows=n, new_tokens=sum(valids), cached_tokens=sum(starts),
             pairs=sum(v * c + v * (v + 1) // 2 for v, c in zip(valids, starts)),
-            completes=int(done_mask.sum()), width=width, padded_tokens=rb * width)
+            completes=int(done_mask.sum()), width=width, padded_tokens=rb * width,
+            seq=self.step_dispatches_total + 1)
         self.prefill_padded_tokens += rb * width
         self._m_wave[width].inc()
         state_args = {}
@@ -1717,6 +1756,7 @@ class Engine:
         # the wave is read before _commit_first_tokens fetches it
         self.step_dispatches_total += 1
         self._note_dispatch()
+        self._wave_tokens_since_burst += sum(valids)
         self._first_d, self._presence, *cache = self._wave_fn(
             self.params, self.cfg,
             ids, pos,
@@ -1946,6 +1986,7 @@ class Engine:
         seg_d, last_idx_d = jnp.asarray(meta["seg"]), jnp.asarray(meta["last_idx"])
         tq = self.packed_chunk
         self.step_dispatches_total += 1
+        self._wave_tokens_since_burst += int(meta["new_lens"].sum())
         with annotate("engine.prefill_packed"):
             out = forward_paged_packed(
                 self.params, self.cfg,
@@ -2124,6 +2165,7 @@ class Engine:
         self.prefill_tokens += total
 
         self.step_dispatches_total += 1
+        self._wave_tokens_since_burst += total
         with annotate("engine.sp_prefill_packed"):
             (logits, self._k_pages, self._v_pages,
              self._k_scales, self._v_scales) = ring_prefill_packed(
@@ -2243,7 +2285,7 @@ class Engine:
         self.bursts_starved += not ahead
         self._m_burst[ahead].inc()
         self._phase("engine.decode_burst", rows=live_rows, kv_tokens=kv_tokens,
-                    steps=n_steps, ahead=int(ahead),
+                    steps=n_steps, ahead=int(ahead), seq=self.step_dispatches_total,
                     **(self._moe_meta("burst") if self._expert_counters else {}),
                     **(self._burst_sliding_meta() if self._sliding else {}))
         out = self._decode_burst_fn(
@@ -2293,9 +2335,9 @@ class Engine:
         self._chain = {
             "last": last, "lens": out_lens, "pending": toks,
             "first": first_waves,
+            # for the cycle this burst's landing closes (_commit_burst)
+            "seq": self.step_dispatches_total, "wave_tokens": self._take_wave_tokens(),
         }
-        if self._expert_counters:
-            self._chain["seq"] = self._dispatch_seq
         if prev is not None:
             self._commit_burst(prev, finished)
 
@@ -2355,6 +2397,11 @@ class Engine:
         if self._presence.is_ready():
             self._step_starved = True
 
+    def _take_wave_tokens(self) -> int:
+        """A burst takes what the waves since the burst before it added."""
+        taken, self._wave_tokens_since_burst = self._wave_tokens_since_burst, 0
+        return taken
+
     def _commit_first_tokens(
         self,
         waves: list[tuple[jnp.ndarray, list[tuple[_Request, int]]]],
@@ -2382,9 +2429,19 @@ class Engine:
         self._commit_first_tokens(entry.get("first", []), finished)
         self._phase("engine.commit_fetch")
         toks = np.asarray(entry["pending"])  # [B, n_steps]
-        if "seq" in entry:
+        if self._expert_counters:
             self._moe_read_back(entry["seq"])
-        self._phase("engine.commit_host", tokens=int((toks >= 0).sum()))
+        got = toks >= 0
+        # What this landing says of the cycle it closes: the burst's dispatch
+        # number, the prefill waves dispatched since the burst before it (the
+        # numbers between the two) and their new tokens.  This annotation's
+        # start is the cycle's end on the trace's clock (a first-token wave's
+        # commit_host carries no ``seq``)
+        facts = {"seq": entry["seq"], "waves": entry["seq"] - self._landed_seq - 1,
+                 "wave_tokens": entry["wave_tokens"]}
+        self._phase("engine.commit_host", tokens=int(got.sum()),
+                    chained=int(self._landed_t is not None), **facts)
+        self._cycle_landed(facts, got.any(axis=1))
         for i in range(toks.shape[1]):
             for row in sorted(self._row_req):
                 req = self._row_req.get(row)
@@ -2399,14 +2456,36 @@ class Engine:
                     self._sliding_row(req)
             self._publish_sliding()
 
+    def _cycle_landed(self, facts: dict, got: np.ndarray) -> None:
+        """A burst's tokens are on the host (``_phase`` has just stamped the
+        end of the fetch): write the cycle it closes, and each row's share.
+        A landing with no burst before it in flight (the first after the
+        engine was empty or after ``_drain_chain``) starts the clock and
+        records no cycle.  ``got``: the rows this burst brought a token.  A row
+        counts the landing; it counts the cycle's waves too unless this is
+        its first landing: the burst a row joins in follows the wave that
+        completed its prompt, which ran before its first token."""
+        landed, before = self._phase_t0, self._landed_t
+        self._landed_t, self._landed_seq = landed, facts["seq"]
+        waves, wave_tokens = facts["waves"], facts["wave_tokens"]
+        if before is not None:
+            self.cycle_ring.append({**facts, "landed_t": landed, "cycle_s": landed - before})
+            self._m_cycle[min(waves, 2)].observe(landed - before)
+        for row, req in self._row_req.items():
+            if req.state == "running" and got[row]:
+                if waves and req.decode_cycles:
+                    req.decode_wave_cycles += 1
+                    req.decode_wave_tokens += wave_tokens
+                req.decode_cycles += 1
+                req.last_token_t = landed
+
     def _moe_dispatched(self, program: str, counts: jnp.ndarray, steps: int) -> None:
         """A step program that ran expert layers was dispatched: keep its
         device-side [experts hit, expert tokens] until they can be read
         without waiting, and book the expert slots it offered."""
-        self._dispatch_seq += 1
         cfg = self.cfg
         slots = cfg.n_held * cfg.expert_layers * steps
-        self._moe_pending.append((self._dispatch_seq, program, counts, slots))
+        self._moe_pending.append((self.step_dispatches_total, program, counts, slots))
 
     def _moe_read_back(self, upto: int) -> None:
         """Add the counts of every dispatch up to ``upto`` (a burst whose
@@ -2434,6 +2513,7 @@ class Engine:
             entry = self._chain
             self._chain = None  # releases during this commit recycle directly
             self._commit_burst(entry, finished)
+        self._landed_t = None  # the next burst has none before it in flight
         if self._pending_first:
             waves = self._pending_first
             self._pending_first = []
@@ -2556,6 +2636,10 @@ class Engine:
                 "prefill_end_t": req.prefill_end_t,
                 "first_token_t": req.first_token_t,
                 "done_t": done_t,
+                "last_token_t": req.last_token_t,
+                "decode_cycles": req.decode_cycles,
+                "decode_wave_cycles": req.decode_wave_cycles,
+                "decode_wave_tokens": req.decode_wave_tokens,
             },
             cached_tokens=req.cached_tokens,
             faulted_pages=req.faulted_pages,

@@ -25,9 +25,16 @@ that reads each):
                        ``new_tokens`` are real
     engine.burst_prepare  the active mask and the masks of fresh rows
     engine.decode_burst   the dispatch call; ``ahead``: the device still
-                       had work queued when the step's programs went out
+                       had work queued when the step's programs went out;
+                       ``seq``: the dispatch's number (a wave's too), which
+                       the burst's landing carries back
     engine.commit_fetch   the blocking device->host fetch of a burst
-    engine.commit_host    per-token bookkeeping, callbacks, results
+    engine.commit_host    per-token bookkeeping, callbacks, results; a
+                       BURST's starts where its tokens landed, the end of a
+                       decode cycle, and says which: ``seq``, ``waves`` /
+                       ``wave_tokens`` (the prefill waves dispatched since the
+                       burst before), ``chained`` (a burst was in flight
+                       before it: the cycle is whole)
     embed.batch        one encoder batch, dispatch to vectors on host
     index.search       one device-index wave, dispatch to hits on host
     encoder.warmup, ingest.<stage>
