@@ -490,6 +490,15 @@ PREFILL_WAVE = Counter(
     ["width"],
     registry=REGISTRY,
 )
+PREFILL_ATTN_TILES = Counter(
+    "rag_engine_prefill_attn_tiles_total",
+    "(query tile, key step) pairs of the prefill waves' attention grid, over "
+    "the rows a wave program ran, by what the latent family's kernel did with "
+    "them: run, or skipped (no real query in the tile: the wave's padding; or "
+    "no key of the row in the step)",
+    ["kind"],
+    registry=REGISTRY,
+)
 MOE_EXPERTS_HIT = Counter(
     "rag_moe_experts_hit_total",
     "Held experts that received a token, summed over expert layers and steps "

@@ -43,6 +43,7 @@ from githubrepostorag_tpu.ops.latent_attention import (
     einsum_f32,
     latent_decode_attention,
     latent_prefill_attention,
+    prefill_tile_counts,
 )
 from githubrepostorag_tpu.ops.norms import rms_norm
 from githubrepostorag_tpu.ops.prefill_width import at_wave_width, layer_weights
@@ -377,6 +378,11 @@ def forward_paged(
     its cached prefix and itself.  Returns (logits, pool, None, stats [2])."""
     return forward_paged_impl(params, cfg, input_ids, positions, k_pages, slot_mapping,
                               block_tables, cached_lens, new_lens, use_pallas, logits_at)
+
+
+# the engine's hook for a wave's annotation and counters (cached lens, new lens, width, pages a
+# row, page size): the (query tile, key step) pairs the wave's attention kernel runs and skips
+wave_attention_tiles = prefill_tile_counts
 
 
 @partial(jax.jit, static_argnames=("cfg", "use_pallas", "int4_kernel", "mesh"),

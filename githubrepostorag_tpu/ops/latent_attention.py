@@ -21,8 +21,13 @@ things (DeepSeek-V2, arXiv:2405.04434, section 2.1):
   costs about twice the FLOPs, so ``k_nope`` and ``v`` are rebuilt from the
   cached latents a page at a time (a whole 8k prefix would be 537 MB a row
   and layer) under an online softmax.  On the chip a Pallas kernel does it
-  per (row, head), K, V and the scores living in VMEM only; elsewhere an XLA
-  loop over tiles of pages is its oracle (on the chip its [H, S, tile]
+  per (row, a few heads), K, V and the scores living in VMEM only, and does
+  only what the wave's (query, key) pairs need: the query tiles of a row
+  that hold a real token (the wave's padding is not computed) and the heads
+  of a step in one basic block, so that one's products hide another's
+  softmax (PERF.md, Findings, PR 53; ``prefill_tile_counts`` is the same
+  rules on the host).  Elsewhere
+  an XLA loop over tiles of pages is its oracle (on the chip its [H, S, tile]
   float32 scores crossed HBM several times a tile: 5% of the FLOP peak,
   PERF.md, Findings, PR 27).
 """
@@ -65,11 +70,36 @@ def _softmax_step(s, values, m_ref, l_ref, acc_ref):
 
 
 PAGES_PER_STEP = 8  # my chip runs, PR 27, one layer at the cell's shapes: the prefill kernel
-# (1 x 512 queries over 8,704 rows) 20.6 / 9.7 / 6.6 / 5.6 ms at 1 / 2 / 4 / 8 pages a step
-# (the decode kernel walks in waves of its own since PR 48: DECODE_WAVE_PAGES, below)
+# (1 x 512 queries over 8,704 rows) 20.6 / 9.7 / 6.6 / 5.6 ms at 1 / 2 / 4 / 8 pages a step, a head
+# a step and every column computed; 4.3 ms at 8 since PR 53 (358 of the columns real: PERF.md
+# section 5).  The decode kernel walks in waves of its own since PR 48: DECODE_WAVE_PAGES, below
 
 
 TILE_PAGES = 4  # pages of K and V the XLA oracle of the prefill path rebuilds at a time
+
+
+QUERY_TILE = 128  # columns of a chunk the prefill kernel runs or leaves out together
+
+
+HEAD_COLUMNS = 1024  # columns over all the heads a grid step of the prefill kernel takes.  My chip
+# runs, PR 53: the body is unrolled over heads and live tiles, and a kernel of more than ~64k VLIW
+# bundles runs 2-4 times slower (61.6k: 8.5 ms a two-row call at 512 columns; 69k: 21.6 ms); at
+# 1,024 the three rungs are 30k / 24k / 21k bundles; four heads at 512 columns want 40 MB of VMEM
+
+
+def _query_tile(s: int) -> int:
+    """Columns of one query tile: ``QUERY_TILE`` where it divides the chunk,
+    else the chunk whole."""
+    return QUERY_TILE if s % QUERY_TILE == 0 else s
+
+
+def _heads_per_step(h: int, s: int) -> int:
+    """Heads one grid step of the prefill kernel takes: the step's pages are
+    brought in and laid under one another once for all of them, and one head's
+    products run under another's softmax.  As many as keep heads x columns
+    within ``HEAD_COLUMNS`` (2 / 4 / 8 at 512 / 256 / 128 columns); must
+    divide the head count."""
+    return next(n for n in range(min(h, max(1, HEAD_COLUMNS // s)), 0, -1) if h % n == 0)
 
 
 def _pages_per_step(max_pages: int) -> int:
@@ -350,22 +380,100 @@ def latent_decode_attention(
 
 # ----------------------------------------------------------------- prefill --
 
-def _prefill_kernel(*refs, page_size: int, rank: int, rope: int, pps: int):
-    """Grid (B, H, max_pages / pps): one head of one row walks the row's block
-    table ``pps`` pages at a time; K and V of those pages are rebuilt from
-    their latent rows in VMEM (c_kv W_uk,h^T and c_kv W_uv,h), scores never
-    leave VMEM, and an online softmax carries (m, l, acc) over the walk.
-    Steps past the row's last key skip compute and re-use page 0's block.
+def _step_runs(start, kv_len):
+    """A key step of the prefill kernel's walk holds a key of the row."""
+    return start < kv_len
+
+
+def _live_tiles(new, tile: int):
+    """Query tiles of a row that hold a real token: the others are padding up
+    to the wave's width and are not computed."""
+    return (new + tile - 1) // tile
+
+
+def _causal_mask(sc, start, cached, kv_len):
+    """Scores [columns, keys] of a step from key ``start`` with every key a
+    query may not see at ``NEG_INF``: the keys after its own position
+    (``cached`` + its column) and those past the row's last."""
+    kv_pos = start + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+    q_pos = cached + jax.lax.broadcasted_iota(jnp.int32, (sc.shape[0], 1), 0)
+    return jnp.where(kv_pos <= jnp.minimum(q_pos, kv_len - 1), sc, NEG_INF)
+
+
+@jax.jit
+def _head_scores(c_kv, k_rope, w_uk, w_uv, q, start, cached, kv_len):
+    """One head's K and V of a step rebuilt from its latent rows (c_kv
+    W_uk,h^T and c_kv W_uv,h, bfloat16 out of float32 sums) and the masked
+    scores of its queries against them: w_uk [1, rank, nope], w_uv [1, rank,
+    v], q [1, 1, columns, nope + rope] (the kernel's blocks of one head, as
+    sliced off their refs) -> ([columns, keys] float32, V [keys, v]).
+    Jitted for its trace cache alone, as ``_head_fold`` is: the kernel's
+    bodies are unrolled over heads and live columns and every rung and row
+    bucket has a kernel of its own, so traced inline they cost a server 7 s of
+    its start (PERF.md, Findings, PR 53); so a shape is traced once a process."""
+    nope = w_uk.shape[-1]
+    q = q[0, 0]
+    nt = (((1,), (1,)), ((), ()))  # a [m, k] . b [n, k] -> [m, n]
+    k_nope = jnp.dot(c_kv, w_uk[0], preferred_element_type=jnp.float32).astype(c_kv.dtype)
+    v = jnp.dot(c_kv, w_uv[0], preferred_element_type=jnp.float32).astype(c_kv.dtype)
+    sc = jax.lax.dot_general(q[:, :nope], k_nope, nt, preferred_element_type=jnp.float32) \
+        + jax.lax.dot_general(q[:, nope:], k_rope, nt, preferred_element_type=jnp.float32)
+    return _causal_mask(sc, start, cached, kv_len), v
+
+
+@jax.jit
+def _head_fold(sc, v, m_prev, l_prev, acc):
+    """``_softmax_step``'s update as values: scores [columns, keys] over ``v``
+    into a head's (m, l [1, columns, 1], acc [1, columns, v])."""
+    m_new = jnp.maximum(m_prev[0], jnp.max(sc, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev[0] - m_new)
+    p = jnp.exp(sc - m_new)
+    l_new = l_prev[0] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_new = acc[0] * alpha + jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    return m_new[None], l_new[None], acc_new[None]
+
+
+def prefill_tile_counts(cached_lens, new_lens, columns: int, max_pages: int,
+                        page_size: int) -> dict[str, int]:
+    """On the host, what ``_prefill_kernel`` does with a wave by the rules it
+    and its wrapper go by: the (query tile, key step) pairs of the rows' grid
+    it ``run``s and the ones it ``skipped`` (no real query in the tile, or no
+    key of the row in the step).  The two add up to rows x tiles a row x
+    steps of the table."""
+    span = _pages_per_step(max_pages) * page_size
+    tile = _query_tile(columns)
+    counts = {"run": 0, "skipped": 0}
+    for cached, new in zip(map(int, cached_lens), map(int, new_lens)):
+        for start in range(0, max_pages * page_size, span):
+            live = _live_tiles(new, tile) if _step_runs(start, cached + new) else 0
+            counts["run"] += live
+            counts["skipped"] += columns // tile - live
+    return counts
+
+
+def _prefill_kernel(*refs, page_size: int, rank: int, pps: int, tile: int):
+    """Grid (B, H / hb, max_pages / pps): ``hb`` heads of one row walk the
+    row's block table ``pps`` pages at a time.  A step lays its pages under
+    one another once, rebuilds K and V of each head from the latent rows in
+    VMEM (``_head_scores``), takes the scores of the row's LIVE columns (whole
+    query tiles up to its last real token: the rest are the wave's padding,
+    stay zero and cost nothing) and folds them into that head's online softmax
+    (m, l, acc: ``_head_fold``); scores never leave VMEM.  The heads of a step
+    are unrolled in ONE basic block a case of live columns, so that one head's
+    products run on the MXU under another's softmax on the VPU.  Steps past
+    the row's last key skip compute and re-use page 0's block.
 
     Refs: scalar prefetch [block tables, cached lens, kv lens, layer], blocks
-    [q_nope (1, 1, S, nope), q_rope (1, 1, S, rope), ``pps`` pages of the pool,
-    W_uk,h^T (1, rank, nope), W_uv,h (1, rank, v)], out (1, 1, S, v), scratch
-    [m, l (S, 128), acc (S, v)]."""
-    bt_ref, cached_ref, lens_ref, layer_ref, qn_ref, qr_ref = refs[:6]
-    k_refs = refs[6:6 + pps]
-    wuk_ref, wuv_ref, out_ref, m_ref, l_ref, acc_ref = refs[6 + pps:]
+    [q = [q_nope | q_rope] (1, hb, S, nope + rope), ``pps`` pages of the pool,
+    W_uk^T (hb, rank, nope), W_uv (hb, rank, v)], out (1, hb, S, v), scratch
+    [m, l (hb, S, 128), acc (hb, S, v)]."""
+    bt_ref, cached_ref, lens_ref, layer_ref, q_ref = refs[:5]
+    k_refs = refs[5:5 + pps]
+    wuk_ref, wuv_ref, out_ref, m_ref, l_ref, acc_ref = refs[5 + pps:]
     bi, pi = pl.program_id(0), pl.program_id(2)
     num_pi = pl.num_programs(2)
+    hb, s, _ = q_ref.shape[1:]
+    rope = q_ref.shape[-1] - wuk_ref.shape[-1]
 
     @pl.when(pi == 0)
     def _():
@@ -375,40 +483,52 @@ def _prefill_kernel(*refs, page_size: int, rank: int, rope: int, pps: int):
 
     kv_len, cached = lens_ref[bi], cached_ref[bi]
     start = pi * pps * page_size
-    nt = (((1,), (1,)), ((), ()))  # a [m, k] . b [n, k] -> [m, n]
 
-    @pl.when(start < kv_len)
-    def _():
-        rows = [k[0, 0, 0] for k in k_refs]  # pps x [page_size, width]
-        tile = rows[0] if pps == 1 else jnp.concatenate(rows, axis=0)
-        c_kv, k_rope = tile[:, :rank], tile[:, rank:rank + rope]
-        k_nope = jnp.dot(c_kv, wuk_ref[0], preferred_element_type=jnp.float32).astype(c_kv.dtype)
-        v = jnp.dot(c_kv, wuv_ref[0], preferred_element_type=jnp.float32).astype(c_kv.dtype)
-        s = jax.lax.dot_general(qn_ref[0, 0], k_nope, nt, preferred_element_type=jnp.float32) \
-            + jax.lax.dot_general(qr_ref[0, 0], k_rope, nt, preferred_element_type=jnp.float32)
-        kv_pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        q_pos = cached + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        s = jnp.where((kv_pos <= q_pos) & (kv_pos < kv_len), s, NEG_INF)
-        _softmax_step(s, v, m_ref, l_ref, acc_ref)
+    def fold(live: int):
+        """The step for the first ``live`` columns of every head of the block.
+        Refs are cut with slices only, a head's h:h + 1 too: an integer index
+        is traced four times as slowly, and this is traced and lowered for
+        every case of every rung's and row bucket's kernel."""
+        rows = [k[...].reshape(page_size, -1) for k in k_refs]  # pps x [page_size, width]
+        page_rows = rows[0] if pps == 1 else jnp.concatenate(rows, axis=0)
+        c_kv, k_rope = page_rows[:, :rank], page_rows[:, rank:rank + rope]
+
+        def scores(h):
+            return _head_scores(c_kv, k_rope, wuk_ref[h:h + 1], wuv_ref[h:h + 1],
+                                q_ref[:, h:h + 1, :live, :], start, cached, kv_len)
+
+        ahead = scores(0)
+        for h in range(hb):  # a head's products are asked for before the softmax of the one before
+            sc, v = ahead
+            if h + 1 < hb:
+                ahead = scores(h + 1)
+            at = (slice(h, h + 1), slice(0, live))
+            m_ref[(*at, slice(0, 1))], l_ref[(*at, slice(0, 1))], acc_ref[at] = _head_fold(
+                sc, v, m_ref[(*at, slice(0, 1))], l_ref[(*at, slice(0, 1))], acc_ref[at])
+
+    runs, live_tiles = _step_runs(start, kv_len), _live_tiles(kv_len - cached, tile)
+    for n in range(1, s // tile + 1):
+        pl.when(runs & (live_tiles == n))(functools.partial(fold, n * tile))
 
     @pl.when(pi == num_pi - 1)
     def _():
-        l = l_ref[:, :1]  # a padding row walks no page: l == 0
-        out_ref[0, 0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(out_ref.dtype)
+        l = l_ref[:, :, :1]  # a padding row walks no page, a padding tile folds nothing: l == 0
+        out_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _prefill_pallas(qn, qr, pool, layer, block_tables, cached_lens, kv_lens, w_uk, w_uv,
+def _prefill_pallas(q, pool, layer, block_tables, cached_lens, kv_lens, w_uk, w_uv,
                     interpret: bool):
-    """qn [B, H, S, nope], qr [B, H, S, rope] (scaled) -> [B, H, S, v].
+    """q [B, H, S, nope + rope] (scaled) -> [B, H, S, v].
     Jitted for its trace cache alone (it is only ever called inside a step
     program): the dense and the expert stack call it with the same shapes,
     and tracing the call is most of what tracing a layer costs."""
-    b, h, s, nope = qn.shape
-    rope, rank, vd = qr.shape[-1], w_uk.shape[-1], w_uv.shape[-1]
+    b, h, s, _ = q.shape
+    nope, rank, vd = w_uk.shape[1], w_uk.shape[2], w_uv.shape[-1]
     page_size, width = pool.shape[3], pool.shape[4]
     max_pages = block_tables.shape[1]
     pps = _pages_per_step(max_pages)
+    hb = _heads_per_step(h, s)
 
     def q_map(bi, hi, pi, *refs):
         return (bi, hi, 0, 0)
@@ -424,18 +544,20 @@ def _prefill_pallas(qn, qr, pool, layer, block_tables, cached_lens, kv_lens, w_u
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(b, h, max_pages // pps),
-        in_specs=[pl.BlockSpec((1, 1, s, nope), q_map), pl.BlockSpec((1, 1, s, rope), q_map)]
+        grid=(b, h // hb, max_pages // pps),
+        in_specs=[pl.BlockSpec((1, hb, s, q.shape[-1]), q_map)]
         + [pl.BlockSpec((1, 1, 1, page_size, width), page_map(j)) for j in range(pps)]
-        + [pl.BlockSpec((1, rank, nope), w_map), pl.BlockSpec((1, rank, vd), w_map)],
-        out_specs=pl.BlockSpec((1, 1, s, vd), q_map),
-        scratch_shapes=[pltpu.VMEM((s, 128), jnp.float32), pltpu.VMEM((s, 128), jnp.float32),
-                        pltpu.VMEM((s, vd), jnp.float32)],
+        + [pl.BlockSpec((hb, rank, nope), w_map), pl.BlockSpec((hb, rank, vd), w_map)],
+        out_specs=pl.BlockSpec((1, hb, s, vd), q_map),
+        scratch_shapes=[pltpu.VMEM((hb, s, 128), jnp.float32),
+                        pltpu.VMEM((hb, s, 128), jnp.float32),
+                        pltpu.VMEM((hb, s, vd), jnp.float32)],
     )
     call = pl.pallas_call(
-        functools.partial(_prefill_kernel, page_size=page_size, rank=rank, rope=rope, pps=pps),
+        functools.partial(_prefill_kernel, page_size=page_size, rank=rank, pps=pps,
+                          tile=_query_tile(s)),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, s, vd), qn.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, s, vd), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
@@ -445,7 +567,7 @@ def _prefill_pallas(qn, qr, pool, layer, block_tables, cached_lens, kv_lens, w_u
     with jax.named_scope("latent_prefill_attention"):
         return call(block_tables.astype(jnp.int32), cached_lens.astype(jnp.int32),
                     kv_lens.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
-                    qn, qr, *([pool] * pps), w_uk.swapaxes(1, 2), w_uv)
+                    q, *([pool] * pps), w_uk.swapaxes(1, 2), w_uv)
 
 
 def latent_prefill_attention(
@@ -471,9 +593,9 @@ def latent_prefill_attention(
     qn = (q_nope.astype(jnp.float32) * scale).astype(q_nope.dtype)
     qr = (q_rope.astype(jnp.float32) * scale).astype(q_rope.dtype)
     if use_pallas:
-        out = _prefill_pallas(qn.swapaxes(1, 2), qr.swapaxes(1, 2), pool, layer, block_tables,
-                              cached_lens, kv_lens, w_uk, w_uv, interpret)
-        return out.swapaxes(1, 2)
+        q = jnp.concatenate([qn, qr], axis=-1).swapaxes(1, 2)  # one block a head for the kernel
+        return _prefill_pallas(q, pool, layer, block_tables, cached_lens, kv_lens, w_uk, w_uv,
+                               interpret).swapaxes(1, 2)
     ps, width = pool.shape[3], pool.shape[4]
     rank, vd, rope = w_uk.shape[-1], w_uv.shape[-1], q_rope.shape[-1]
     max_pages = block_tables.shape[1]

@@ -86,6 +86,7 @@ from githubrepostorag_tpu.metrics import (
     BURST_DISPATCH,
     ENGINE_CYCLE,
     KV_PAGES_IN_USE,
+    PREFILL_ATTN_TILES,
     PREFILL_WAVE,
     SLIDING_PAGES_FREED,
     STATE_SLOTS_IN_USE,
@@ -337,8 +338,12 @@ class Engine:
                              "served by the model's own step programs")
         sliding_kind = kinds[1] if len(kinds) == 2 else None
         self._wave_fn = forward_paged_wave
+        # a family whose wave kernel decides its work a (query tile, key step)
+        # at a time says how by ``wave_attention_tiles`` (_count_wave_tiles)
+        self._wave_tiles = None
         if self._own_programs:
             family = importlib.import_module(programs)
+            self._wave_tiles = getattr(family, "wave_attention_tiles", None)
 
             unsupported = {
                 "mesh": mesh is not None, "kv_quant": bool(quant_bits(kv_quant)),
@@ -624,6 +629,9 @@ class Engine:
         self.prefill_padded_tokens = 0
         self._m_wave = {w: PREFILL_WAVE.labels(width=str(w))
                         for w in self.prefill_width_buckets}
+        if self._wave_tiles is not None:
+            self.prefill_attn_tiles = {"run": 0, "skipped": 0}
+            self._m_tiles = {k: PREFILL_ATTN_TILES.labels(kind=k) for k in self.prefill_attn_tiles}
         self._waiting: list[_Request] = []
         self._rejected: list[_Request] = []
         self._requests: dict[str, _Request] = {}
@@ -1741,6 +1749,8 @@ class Engine:
             seq=self.step_dispatches_total + 1)
         self.prefill_padded_tokens += rb * width
         self._m_wave[width].inc()
+        if self._wave_tiles is not None:
+            self._count_wave_tiles(wave_ann, cached, new_lens, width)
         state_args = {}
         if self._state is not None:
             state_args = self._wave_state(reqs, valids, rb)
@@ -1851,6 +1861,20 @@ class Engine:
         self._sliding_published = self._sliding.freed
         in_use.set(self._sliding.in_use)
         in_use_global.set(self._allocator.num_pages - self._allocator.free_count)
+
+    def _count_wave_tiles(self, wave_ann, cached: np.ndarray, new_lens: np.ndarray,
+                          width: int) -> None:
+        """What the family's wave kernel does with this wave, by the rule its
+        wrapper builds the grid from: the (query tile, key step) pairs over the
+        rows the program runs (padding rows too) and how many of them it runs
+        and how many it skips; on the wave's annotation and the engine's
+        counters."""
+        counts = self._wave_tiles(cached, new_lens, width, self.max_pages_per_seq, self.page_size)
+        wave_ann.set_metadata(attn_tiles=sum(counts.values()),
+                              **{f"attn_tiles_{kind}": n for kind, n in counts.items()})
+        for kind, n in counts.items():
+            self.prefill_attn_tiles[kind] += n
+            self._m_tiles[kind].inc(n)
 
     def _burst_sliding_meta(self) -> dict:
         """``sliding_tokens`` of a burst's annotation: over the running rows,

@@ -354,27 +354,126 @@ def test_a_row_that_sits_the_burst_out_is_handed_over_as_holding_nothing(tiny, m
     assert list(stale_lens) == [37, 23, 40, 0] and list(clean_lens) == [0, 23, 0, 0]
 
 
-def test_prefill_kernel_equals_the_tiled_oracle(monkeypatch):
-    """A 16-token chunk over cached prefixes of 13 and 30 rows (and a padding
-    row that walks no page): the Pallas kernel, interpreted, against the XLA
-    path that materialises K and V a tile at a time."""
-    monkeypatch.setattr(latent_ops, "TILE_PAGES", 2)
-    h, nope, rope, rank, vd, ps, width, s = 4, 16, 8, 16, 16, 8, 128, 16
+def chunk_case(cached, new, s=16, table=6, h=4, head_columns=32):
+    """A chunk of ``s`` columns a row over rows of ``cached`` + ``new`` keys
+    (pages of 8, query tiles of 8, a table of ``table`` pages a row)."""
+    return dict(cached=cached, new=new, s=s, table=table, h=h, head_columns=head_columns)
+
+
+def chunk_args(cached, new, s, table, h):
+    nope, rope, rank, vd, ps, width = 16, 8, 16, 16, 8, 128
+    rows = len(cached)
     k = jax.random.split(jax.random.PRNGKey(9), 5)
-    cached = jnp.asarray([13, 30, 0], jnp.int32)
-    new = jnp.asarray([16, 9, 0], jnp.int32)
-    bt = jnp.asarray([[0, 1, 2, 3, 8, 9], [4, 5, 6, 7, 10, 11], [0, 0, 0, 0, 0, 0]], jnp.int32)
-    pool = jnp.zeros((2, 1, 12, ps, width)).at[..., :rank + rope].set(
-        jax.random.normal(k[0], (2, 1, 12, ps, rank + rope)))
-    q_nope, q_rope = jax.random.normal(k[1], (3, s, h, nope)), jax.random.normal(k[2], (3, s, h, rope))
+    # a row's pages lie apart in the pool and out of order; a padding row names page 0
+    bt = np.arange(rows * table, dtype=np.int32).reshape(table, rows).T[:, ::-1].copy()
+    bt[np.asarray(cached) + np.asarray(new) == 0] = 0
+    pool = jnp.zeros((2, 1, rows * table, ps, width)).at[..., :rank + rope].set(
+        jax.random.normal(k[0], (2, 1, rows * table, ps, rank + rope)))
+    q_nope = jax.random.normal(k[1], (rows, s, h, nope))
+    q_rope = jax.random.normal(k[2], (rows, s, h, rope))
     w_uk, w_uv = jax.random.normal(k[3], (h, nope, rank)), jax.random.normal(k[4], (h, rank, vd))
-    args = (q_nope, q_rope, pool, jnp.int32(1), bt, cached, new, w_uk, w_uv, 0.2)
+    return (q_nope, q_rope, pool, jnp.int32(1), jnp.asarray(bt), jnp.asarray(cached, jnp.int32),
+            jnp.asarray(new, jnp.int32), w_uk, w_uv, 0.2)
+
+
+# pages of 8 keys, query tiles of 8 columns; a step is the largest of 8 / 4 / 2 / 1 pages that
+# divides the table: 6 pages -> 2 a step (16 keys), 8 -> 8, 4 -> 4, 5 -> 1
+CHUNK_CASES = [
+    # row 1: step 0 inside the cached prefix (every key seen), step 1 crosses ``cached``, step 2 has
+    # the row's last key; its 9 real tokens end inside the second query tile
+    pytest.param(chunk_case([13, 30, 0], [16, 9, 0]), id="inside-the-prefix-and-across-it"),
+    pytest.param(chunk_case([32, 16, 0, 34], [5, 17, 0, 8], s=32),
+                 id="last-tiles-all-padding"),
+    pytest.param(chunk_case([0, 0], [16, 7]), id="cold-prompts"),
+    pytest.param(chunk_case([32, 0, 8], [16, 0, 3]), id="a-padding-row-between-two-lengths"),
+    # heads a step: 16 columns in ``head_columns`` of 64 ask for 4, of 32 (the default) for 2
+    pytest.param(chunk_case([16, 30], [16, 9], h=6, head_columns=64), id="heads-3-a-step-of-6"),
+    pytest.param(chunk_case([16, 30], [16, 9], h=3), id="heads-no-multiple-of-the-rule"),
+    pytest.param(chunk_case([16, 30], [16, 9], h=8, head_columns=64), id="heads-4-a-step-of-8"),
+    pytest.param(chunk_case([13, 9], [16, 12], table=4), id="four-pages-a-step"),
+    pytest.param(chunk_case([13, 24], [16, 9], table=5), id="one-page-a-step"),
+    pytest.param(chunk_case([40, 17], [16, 24], s=24, table=8), id="the-table-one-step"),
+]
+
+
+def patched_for_chunks(monkeypatch, case):
+    monkeypatch.setattr(latent_ops, "TILE_PAGES", 2)
+    monkeypatch.setattr(latent_ops, "QUERY_TILE", 8)
+    monkeypatch.setattr(latent_ops, "HEAD_COLUMNS", case["head_columns"])
+    return {k: v for k, v in case.items() if k != "head_columns"}
+
+
+@pytest.mark.parametrize("case", CHUNK_CASES)
+def test_prefill_kernel_equals_the_tiled_oracle(monkeypatch, case):
+    """The Pallas kernel, interpreted, against the XLA path that materialises K
+    and V a tile at a time, at real rows only, over what the kernel's body
+    tells apart: steps inside the cached prefix, across its end and past the
+    row's last key, query tiles run and left out, heads a step, pages a step.
+    A column in a tile that holds no real token and a padding row (which
+    walks no page) come back zero."""
+    case = patched_for_chunks(monkeypatch, case)
+    args = chunk_args(**case)
     want = latent_prefill_attention(*args)
-    got = latent_prefill_attention(*args, use_pallas=True, interpret=True)
-    for row, n in enumerate([16, 9]):  # padded queries attend what their position allows: unused
-        np.testing.assert_allclose(np.asarray(got[row, :n]), np.asarray(want[row, :n]),
-                                   rtol=2e-4, atol=2e-4)
-    assert np.isfinite(np.asarray(got)).all() and not np.asarray(got[2]).any()
+    got = np.asarray(latent_prefill_attention(*args, use_pallas=True, interpret=True))
+    assert np.isfinite(got).all()
+    for row, n in enumerate(case["new"]):  # a padded query attends what its position allows: unused
+        np.testing.assert_allclose(got[row, :n], np.asarray(want[row, :n]), rtol=2e-4, atol=2e-4)
+        assert not got[row, -(-n // 8) * 8:].any()
+
+
+@pytest.mark.parametrize("case", CHUNK_CASES)
+def test_the_hosts_tile_counts_are_what_the_kernel_selects(monkeypatch, case):
+    """``prefill_tile_counts`` (the wave annotation's ``attn_tiles_*``) against
+    the kernel itself, interpreted: every fold of a (head, step) reports the
+    columns it took, and the tiles nobody reported are the skipped ones."""
+    case = patched_for_chunks(monkeypatch, case)
+    folded, head_fold = [], latent_ops._head_fold
+
+    def watched_fold(sc, *args):
+        jax.debug.callback(lambda _: folded.append(sc.shape[0] // 8), sc[0, 0])
+        return head_fold(sc, *args)
+
+    monkeypatch.setattr(latent_ops, "_head_fold", watched_fold)
+    # the jitted wrapper keeps traces of the unwatched kernel
+    monkeypatch.setattr(latent_ops, "_prefill_pallas", latent_ops._prefill_pallas.__wrapped__)
+    latent_prefill_attention(*chunk_args(**case), use_pallas=True, interpret=True)
+    jax.effects_barrier()
+    h, s, table = case["h"], case["s"], case["table"]
+    span = latent_ops._pages_per_step(table) * 8
+    counts = latent_ops.prefill_tile_counts(case["cached"], case["new"], s, table, 8)
+    assert sum(counts.values()) == len(case["new"]) * (s // 8) * (table * 8 // span)
+    assert sum(folded) == h * counts["run"]
+    assert counts["run"] == sum(  # by hand: the steps that hold a key of the row, live tiles each
+        -(-(c + n) // span) * -(-n // 8) for c, n in zip(case["cached"], case["new"]))
+
+
+async def test_the_wave_tile_counts_reach_the_annotation_and_prometheus(tiny, monkeypatch):
+    """The latent family's hook: a wave's annotation carries the tiles its
+    attention kernel runs and skips, the engine adds them up, and /metrics
+    exports them by kind."""
+    from githubrepostorag_tpu.metrics import render
+    from tests.helpers.step_programs import recorded_waves
+
+    cfg, params = tiny
+    # a table of 12 pages is walked 4 pages (32 keys) a step, a chunk of 32 columns whole
+    eng = Engine(params, cfg, max_num_seqs=4, num_pages=PAGES, page_size=PAGE, max_seq_len=96,
+                 prefill_chunk=32, decode_burst=4, rng_seed=0)
+    waves = recorded_waves(monkeypatch)
+
+    def exported():
+        return {line.split('kind="')[1].split('"')[0]: float(line.split()[-1])
+                for line in render().decode().splitlines()
+                if line.startswith("rag_engine_prefill_attn_tiles_total{")}
+
+    before = exported()
+    prompt = list(range(3, 43))  # 40 tokens: a chunk of 32, then 8 over a prefix of 32
+    eng.generate([prompt], SamplingParams(max_tokens=2, temperature=0.0, stop_token_ids=()))
+    # one row, one tile a row, three steps: the first chunk's keys lie in one, the second's in two
+    assert [(w["attn_tiles"], w["attn_tiles_run"], w["attn_tiles_skipped"]) for w in waves] == [
+        (3, 1, 2), (3, 2, 1)]
+    assert eng.prefill_attn_tiles == {"run": 3, "skipped": 3}
+    assert {k: exported()[k] - before.get(k, 0.0) for k in ("run", "skipped")} == {
+        "run": 3, "skipped": 3}
 
 
 def test_router_is_the_group_limited_top_k_and_the_bias_only_selects():

@@ -92,6 +92,21 @@ def lowered_program(where, program: str, variant):
     return lowered, pool_shape, params
 
 
+def prefill_kernels(hlo: str) -> int:
+    """The prefill kernel's custom calls in a compiled program, each named as
+    ``latent_prefill_attn_flops_frac`` finds it (``^latent_prefill_attention``)
+    and over the operands the kernel takes since PR 53: block tables, cached
+    lens, kv lens, layer, q = [q_nope | q_rope], the pool once a page of a step
+    (8 at the cell's table of 80 pages), W_uk^T, W_uv."""
+    calls = re.findall(r"%latent_prefill_attention[.\d]* = [^\n]*custom-call\(([^\n]*?)\), "
+                       r"custom_call_target=\"tpu_custom_call\"", hlo)
+    assert len(calls) == hlo.count('"tpu_custom_call"')  # and no other kernel in the program
+    for args in calls:
+        operands = re.findall(r"%[\w.\-]+", args)
+        assert len(operands) == 15 and len(set(operands[5:13])) == 1, operands
+    return len(calls)
+
+
 @pytest.mark.parametrize("program,variant", [
     pytest.param("burst", False, id="burst-nofilter"),
     pytest.param("prefill", 1, id="prefill-1x512"),
@@ -107,6 +122,8 @@ def test_step_program_leaves_latent_pool_and_experts_in_place(chip, as_on_chip, 
     if program == "burst":  # one decode kernel a stack (dense, scanned), named as its metric reads it
         kernels = re.findall(r"%(latent_attention[.\d]*) = [^\n]*\"tpu_custom_call\"", hlo)
         assert len(kernels) == 2 and hlo.count('"tpu_custom_call"') == 2, kernels
+    else:  # one a layer kind, and a rung (a wave of one or two rows has three, of more one)
+        assert prefill_kernels(hlo) == (6 if (program, variant) == ("wave", 1) else 2)
     assert pool_movers(hlo, pool_shape) == []
     for name in ("e_wgu", "e_wd"):  # [Lm, n_held, in, out]: no copy of a stack or a layer's slab
         assert pool_movers(hlo, params["moe"][name].shape) == []
@@ -120,6 +137,7 @@ def test_the_wave_is_one_program_that_donates_the_pool_and_presence(chip, as_on_
     assert_wave_keeps_in_place(hlo, rf"bf16\[5,1,{PAGES},{PAGE},640\]|pred\[{ROWS},16160\]", 2)
     # the dense stack's scan and the expert stack's: 512, 256, 128 columns in each
     assert_wave_holds_every_rung(hlo, 3, pool_shape)
+    assert prefill_kernels(hlo) == 6  # one a layer kind and rung
 
 
 def test_the_expert_metric_selects_the_products_under_the_moe_experts_scope(chip, as_on_chip):
