@@ -13,6 +13,21 @@ static length (the most tiles the shapes allow), so the layer differentiates
 and shards like any other; with the expert axis sharded P("ep", ...) GSPMD
 resolves the per-tile expert index itself.
 
+Which form serves which regime (``listed``; a value of the caller's shapes,
+not a switch a deployment sets):
+
+* FEW LARGE experts, some hit (16 held of 100 MB each, ~6 hit: Command A+;
+  16 of 88 MB: DeepSeek-V3; 32 of 30 MB: Nemotron-H): the scan.  A tile's
+  bookkeeping is nothing beside 30-100 MB of weights, and the scan
+  differentiates.
+* MANY SMALL experts, a third hit (128 held of 6 MB each, ~40 hit: Qwen3-Next):
+  the listed form, whose loop runs the tiles that exist and no others.
+* EVERY expert held, nearly all hit (64 of 64 at 12 MB each, 75-92% hit by a
+  burst's ~19 rows, every one by a wave: Mellum2, PR 54): the listed form too.
+  There is almost nothing to skip, so what either form saves is its own
+  bookkeeping, and the listed form has less of it; measured on the chip in
+  ``PERF.md`` section 6, PR 54.
+
 Routers: ``moe_mlp`` is HF ``Qwen2MoeSparseMoeBlock`` (float32 softmax,
 top-k, optional renormalisation, a shared expert behind a sigmoid gate);
 ``route_noaux_tc`` is DeepSeek-V3's (sigmoid scores, a selection-only bias,

@@ -14,11 +14,16 @@ import jax.numpy as jnp
 
 def yarn_inv_freq(head_dim: int, theta: float, factor: float, original_max: int,
                   beta_fast: float = 32.0, beta_slow: float = 1.0) -> jnp.ndarray:
-    """YaRN inverse frequencies [head_dim/2] (DeepSeek-V2/V3's rotary
-    embedding): dimensions that turn more than ``beta_fast`` times inside the
-    original context keep their frequency, those that turn less than
+    """YaRN inverse frequencies [head_dim/2]: dimensions that turn more than
+    ``beta_fast`` times inside the original context keep their frequency,
+    those that turn less than
     ``beta_slow`` times are interpolated (divided by ``factor``), and a linear
-    ramp blends the two between the correction dimensions."""
+    ramp blends the two between the correction dimensions.  Two users, who
+    differ in where YaRN's temperature goes: DeepSeek-V3 (models/deepseek_v3.py)
+    folds ``yarn_mscale`` squared into its softmax scale and rotates by the plain
+    cos and sin; Mellum2's global layers (models/mellum.py) are handed the
+    source's ``attention_factor`` and hand it to ``rope_cos_sin(factor=)``, so
+    the rotated queries AND the keys its pool stores carry it."""
     def correction_dim(rotations: float) -> float:
         return head_dim * math.log(original_max / (rotations * 2 * math.pi)) / (
             2 * math.log(theta))
@@ -39,14 +44,18 @@ def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
 
 
 def rope_cos_sin(positions: jnp.ndarray, head_dim: int, theta: float = 10000.0,
-                 inv_freq: jnp.ndarray | None = None):
+                 inv_freq: jnp.ndarray | None = None, factor: float = 1.0):
     """positions [B, S] (int32) -> cos, sin each [B, S, head_dim].
-    ``inv_freq`` [head_dim/2] replaces the plain theta ladder (YaRN)."""
+    ``inv_freq`` [head_dim/2] replaces the plain theta ladder (YaRN, both users
+    of ``yarn_inv_freq``); ``factor`` multiplies both tables (a YaRN
+    ``attention_factor`` that the source states for the table itself: whatever
+    is rotated by them, a stored key too, is ``factor`` times as long)."""
     if inv_freq is None:
         inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # [B, S, hd/2]
     angles = jnp.concatenate([angles, angles], axis=-1)  # [B, S, hd]
-    return jnp.cos(angles), jnp.sin(angles)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    return (cos, sin) if factor == 1.0 else (cos * factor, sin * factor)
 
 
 def _rotate_half(x: jnp.ndarray) -> jnp.ndarray:

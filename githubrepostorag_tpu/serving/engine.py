@@ -398,6 +398,9 @@ class Engine:
             # offered, per step program, cumulative; a dispatch's counts are
             # read back once a later burst's tokens prove the device is past it
             self.moe_stats = {"burst": [0, 0, 0], "prefill": [0, 0, 0]}
+            # the fullest held expert's pairs, summed over expert layers and
+            # steps: only for the programs that return that third count
+            self.moe_max_pairs: dict[str, int] = {}
             self._moe_pending: list[tuple[int, str, jnp.ndarray, int]] = []
         self.params = params
         self.cfg = cfg
@@ -2505,7 +2508,8 @@ class Engine:
 
     def _moe_dispatched(self, program: str, counts: jnp.ndarray, steps: int) -> None:
         """A step program that ran expert layers was dispatched: keep its
-        device-side [experts hit, expert tokens] until they can be read
+        device-side [experts hit, expert tokens] (and, where the program
+        returns it, the fullest held expert's pairs) until they can be read
         without waiting, and book the expert slots it offered."""
         cfg = self.cfg
         slots = cfg.n_held * cfg.expert_layers * steps
@@ -2518,16 +2522,21 @@ class Engine:
 
         while self._moe_pending and self._moe_pending[0][0] <= upto:
             _, program, counts, slots = self._moe_pending.pop(0)
-            hit, tokens = (int(x) for x in np.asarray(counts))
+            hit, tokens, *fullest = (int(x) for x in np.asarray(counts))
             acc = self.moe_stats[program]
             acc[0], acc[1], acc[2] = acc[0] + hit, acc[1] + tokens, acc[2] + slots
+            if fullest:  # a program that also counts its fullest held expert's pairs
+                self.moe_max_pairs[program] = self.moe_max_pairs.get(program, 0) + fullest[0]
             MOE_EXPERTS_HIT.labels(program=program).inc(hit)
             MOE_EXPERT_TOKENS.labels(program=program).inc(tokens)
 
     def _moe_meta(self, program: str) -> dict:
         """The cumulative counts, as an annotation's stats."""
         hit, tokens, slots = self.moe_stats[program]
-        return {"experts_hit": hit, "expert_tokens": tokens, "expert_slots": slots}
+        meta = {"experts_hit": hit, "expert_tokens": tokens, "expert_slots": slots}
+        if program in self.moe_max_pairs:
+            meta["experts_max_pairs"] = self.moe_max_pairs[program]
+        return meta
 
     def _drain_chain(self, finished: list[GenerationResult]) -> None:
         """Land the in-flight burst (if any), commit its tokens and any

@@ -50,6 +50,9 @@ N_Q = N_KV * GROUP
 # that falls.  Quantised pools fold one page a step whatever the table.
 WALKS = {"table4": (4, None), "table11": (11, None), "table19": (19, None),
          "table19-window37": (19, 37)}
+# Mellum2's proportions (PR 54): a window of 8 pages under a call of 2 pages' columns walks 11
+# pages in 2 steps; under a whole chunk of 4 pages' columns, 13
+WINDOW_WALKS = {"window8-call2": (8 * PS, 2 * PS, 11), "window8-chunk4": (8 * PS, 4 * PS, 13)}
 
 
 def _rand_pools(key, quant):
@@ -107,6 +110,33 @@ def test_fused_window_matches_paged_ref(quant, s_w, b, walk):
                                rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("name", WINDOW_WALKS)
+def test_fused_window_walks_a_window_of_eight_pages_at_eight_heads_a_kv_head(name):
+    """A sliding layer whose window is several times a call's columns, at 8
+    query heads a kv head: rows whose window starts in mid-page, at a page
+    boundary and at 0, over a table whose entries behind the window name a
+    page of garbage."""
+    window, s_w, walk = WINDOW_WALKS[name]
+    group, pages, mp = 8, 96, 24
+    assert (window + s_w - 2) // PS + 2 == walk
+    rng = np.random.default_rng(walk)
+    k, v = (jnp.asarray(rng.standard_normal((N_KV, pages, PS, HD)), jnp.float32) for _ in range(2))
+    k = k.at[:, 0].set(1e4)  # page 0 is what entries behind the window name: never read
+    cached = np.array([70, 64, 3], np.int32)
+    new = np.array([s_w, s_w - 3, s_w], np.int32)
+    bt = np.zeros((3, mp), np.int32)
+    for r in range(3):
+        first, held = max(0, cached[r] - window + 1) // PS, -(-(cached[r] + new[r]) // PS)
+        bt[r, first:held] = 1 + rng.permutation(pages - 1)[:held - first]
+    q = jnp.asarray(rng.standard_normal((3, s_w, N_KV * group, HD)), jnp.float32)
+    args = (q, k, v, jnp.asarray(bt), jnp.asarray(cached), jnp.asarray(new))
+    got = fused_window_attention(*args, interpret=True, sliding=window)
+    ref = paged_attention_ref(*args, sliding=window)
+    live = np.arange(s_w)[None, :] < new[:, None]
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(ref)[live], atol=2e-4, rtol=2e-4)
+    assert not np.allclose(np.asarray(paged_attention_ref(*args))[0], np.asarray(ref)[0], atol=1e-1)
+
+
 # (walk, group, columns, head, page size, pool itemsize, quant) -> pages a step folds
 @pytest.mark.parametrize("shape,fold", [
     ((208, 16, 128, 128, 128, 2, 0), 8),  # Command A+'s global layer: 26 steps of 8
@@ -114,6 +144,9 @@ def test_fused_window_matches_paged_ref(quant, s_w, b, walk):
     ((80, 1, 512, 128, 128, 2, 0), 8),  # Olmo-Hybrid
     ((80, 5, 512, 128, 128, 2, 0), 8),  # Falcon-H1
     ((80, 8, 256, 256, 128, 2, 0), 8),  # Qwen3-Next: head 256, 8 MB resident
+    ((208, 8, 256, 128, 128, 2, 0), 8),  # Mellum2's global layers: 8 heads a kv head, 256 columns
+    ((11, 8, 256, 128, 128, 2, 0), 6),  # its sliding walk (a window of 8 pages): 2 steps of 6
+    ((13, 8, 512, 128, 128, 2, 0), 7),  # the same window under a whole 512-column chunk: 2 of 7
     ((16, 7, 512, 128, 128, 2, 0), 8),  # Qwen2-7B's cells: a row's 2-8 pages are one step
     ((4, 2, 5, 8, 4, 4, 0), 4), ((11, 2, 5, 8, 4, 4, 0), 6), ((19, 2, 9, 8, 4, 4, 0), 7),
     ((3, 16, 128, 128, 128, 2, 0), 3),  # a walk shorter than a step is one step
@@ -144,7 +177,8 @@ def test_query_tile_follows_the_calls_shapes(group, s_w, span, tile):
     (7, 512, 128, False, None),  # Qwen2-7B
     (8, 256, 256, False, None),  # Qwen3-Next: head 256, passes of 2 heads
     (2, 512, 128, False, 128 * 1024),  # a head's columns in passes of 32
-], ids=["16x128", "1x512", "5x512", "7x512", "8x256-head256", "2x512-split-columns"])
+    (8, 256, 128, True, None),  # Mellum2: 8 x 256 columns, passes of 2 heads
+], ids=["16x128", "1x512", "5x512", "7x512", "8x256-head256", "2x512-split-columns", "8x256"])
 def test_fused_window_at_the_cells_wave_shapes(group, s_w, hd, narrow, tile_bytes, monkeypatch):
     """128-token pages, a table of 10: two steps of 5 pages; the first row fills
     its table, the second begins and ends in mid-page."""

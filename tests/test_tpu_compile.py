@@ -161,6 +161,12 @@ WAVE_CALLS = {
     # the hybrids' 512-column calls, contexts to 10,240
     "olmo-hybrid-2row": (2, 30, 1, 512, 128, 80, 1280, None, False, 8),
     "falcon-h1-2row": (2, 4, 5, 512, 128, 80, 1280, None, False, 8),
+    # Mellum2: 8 query heads a kv head, 256 columns a call (models/mellum.ATTN_SPAN), tables of
+    # 208 pages; a sliding layer's window is 8 pages where a call's columns are 2: a walk of 11
+    # pages in 2 steps of 6
+    "mellum2-1row": (1, 4, 8, 256, 128, 208, 2560, None, True, 8),
+    "mellum2-8row": (8, 4, 8, 256, 128, 208, 2560, None, True, 8),
+    "mellum2-8row-sliding": (8, 4, 8, 256, 128, 208, 1024, 1024, True, 6),
 }
 
 
