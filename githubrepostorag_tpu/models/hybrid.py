@@ -749,9 +749,9 @@ def burst(m, params, cfg, last_tokens, seq_lens, k_pages, v_pages, presence, act
                 # a pool stored wider than its values (lane_padded).  Behind the barrier the
                 # compiler keeps ONE copy of the rows (on a v5e: in VMEM) for the reductions
                 # and for the update; without it the update slices the pool a second time,
-                # 94 MB more a layer and step at Olmo-Hybrid's shapes.  Held by
-                # tests/test_olmo_hybrid_compile.py (the update's operands); at equal widths
-                # (Qwen3-Next) the program is what it was, tests/test_qwen3_next_compile.py
+                # 94 MB more a layer and step at Olmo-Hybrid's shapes (PR 40).  On the chip
+                # both Gated DeltaNet families take the kernel above since PR 56: this branch
+                # is the CPU's path and the kernel's oracle (ROADMAP D26 asks what may go)
                 s_old = jax.lax.optimization_barrier(s_old)
             y, s_new, taps = m.state_step(cfg, p, x, s_old, taps_old)
             with jax.named_scope(m.step_scope):  # the update, into the pool, under the rule's name

@@ -351,6 +351,7 @@ class _Layers:
     state_weights = staticmethod(lambda w, g: hybrid.at(w[0]["gdn"], g))
     state_chunk = staticmethod(lambda *a: hybrid.gdn_chunk(_Layers, *a))
     state_step = staticmethod(lambda *a: hybrid.gdn_step(_Layers, *a))
+    state_step_in_pool = staticmethod(lambda *a: hybrid.gdn_step_in_pool(_Layers, *a))
     attn_weights = staticmethod(lambda w, pi: hybrid.at(w[0]["attn"], pi))
     gdn_inputs = staticmethod(lambda cfg, p, x: _gdn_inputs(cfg, p, x))
     gdn_out = staticmethod(lambda cfg, p, o, z: hybrid.gdn_out(cfg, p, o, z, ACT))

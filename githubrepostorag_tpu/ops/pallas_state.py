@@ -24,9 +24,9 @@ Two rules take that walk, each as a ``body``:
 * ``ssd_step_in_place``: Mamba-2's (ops/ssd.ssd_step is the same rule as array
   code: the CPU path, and the oracle of tests/test_ssd.py).
 * ``gated_delta_step_in_place``: the Gated DeltaNet's (ops/gated_delta
-  .gated_delta_step likewise: the CPU path, the oracle of
-  tests/test_gated_delta.py, and the rule of a family whose roofline metric
-  names XLA's own fusions and so cannot read a kernel: PERF.md, PR 49).
+  .gated_delta_step likewise: the CPU path and the oracle of
+  tests/test_gated_delta.py; both families' bursts take the kernel on the
+  chip, Olmo-Hybrid's since PR 49 and Qwen3-Next's since PR 56: PERF.md).
 """
 
 from __future__ import annotations

@@ -322,3 +322,50 @@ def test_a_wave_and_a_burst_give_what_they_gave_with_the_projections_as_publishe
     else:  # chunk by chunk through forward_paged, then a burst of four steps
         assert (stored[0] == published[0]).all() and stored[1] == published[1]
         assert (stored[2] == published[2]).all()
+
+
+# ------------------------------------------------------------ the engine --
+def test_engine_with_the_kernel_on_the_pool_gives_the_array_forms_tokens_and_state(monkeypatch):
+    """The burst's one-token rule as ops/pallas_state.py's kernel (``use_pallas``,
+    interpreted here: what the chip runs since PR 56) against ``gated_delta_step``
+    (the engine's path on the CPU), in float32 at one period: three prompts at
+    once whose answers end at 3, 6 and 9 tokens, so in bursts of 4 a row turns
+    dead BETWEEN two steps of a burst (``act & (lens < row_limits)``) while its
+    neighbours step on, then all three again, each from its snapshot.  The same
+    tokens, and the same state pool to float32 rounding (the kernel sums down
+    ``dk`` sublanes in its own order): every slot, so a row that finished, a
+    row that never ran and the snapshots hold what the array form left there.
+    (Every prompt is three chunks and every resumed one is one, so an engine
+    compiles one wave and one burst: ~20 s.)"""
+    from githubrepostorag_tpu.serving import Engine, SamplingParams
+
+    in_pool = []
+    rule = model.hybrid.gdn_step_in_pool
+    monkeypatch.setattr(model.hybrid, "gdn_step_in_pool",
+                        lambda *a: in_pool.append(a[-1]) or rule(*a))
+    monkeypatch.setattr(model, "ACT", jnp.float32)
+    cfg = model.Qwen3NextConfig.tiny(experts_held=(4, 12), num_layers=4)
+    params = jax.tree.map(lambda x: x.astype(jnp.float32), model.init_params(cfg, seed=SEED))
+    rng = np.random.default_rng(1)
+    head, tail_a, tail_b = ([int(t) for t in rng.integers(1, 500, size=n)] for n in (100, 50, 40))
+    a = head + tail_a  # 150 tokens: its last page boundary 144; the others share 6 and 8 pages
+    prompts = [a, head + tail_b, a[:140]]
+    sps = [SamplingParams(max_tokens=n, temperature=0.0, stop_token_ids=()) for n in (3, 6, 9)]
+    pools, told = [], []
+    for use_pallas in (False, True):
+        eng = Engine(params, cfg, max_num_seqs=4, num_pages=64, page_size=PAGE, max_seq_len=256,
+                     prefill_chunk=64, decode_burst=4, kv_dtype=jnp.float32, state_snapshots=4,
+                     use_pallas=use_pallas)
+        told.append([[(r.cached_tokens, list(r.output_tokens)) for r in eng.generate(prompts, sps)]
+                     for _ in range(2)])
+        pools.append([np.asarray(eng.state_pools[k]) for k in ("s", "conv")])
+        assert eng.state_restored == 3
+        # the array form never asks for the kernel; the other traces it once a layer, interpreted
+        assert in_pool == [True] * (3 * use_pallas)
+    assert told[1] == told[0]
+    cold, resumed = told[1]
+    assert [(c, len(t)) for c, t in cold] == [(0, 3), (0, 6), (0, 9)]
+    assert resumed == [(144, cold[0][1]), (128, cold[1][1]), (128, cold[2][1])]
+    for got, want in zip(pools[1], pools[0]):  # the states, then the convolutions' histories
+        assert got.any()
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)

@@ -5,9 +5,11 @@ held, 2,048 pages of 128 tokens for the 2 attention layers, 32 live + 64
 snapshot + 1 slots of state for the 6 Gated DeltaNet layers): both paged
 kernels pass the chip's compiler at head size 256 with a group of 8; nothing
 in the optimized HLO copies, transposes or slices a K/V pool, the state pool,
-an expert stack or a Gated DeltaNet weight stack (the state pool is WRITTEN
-in place, a row's slot at a time, and by nothing else; the burst slices a
-layer of a weight stack inside the product that reads it, and nowhere else);
+an expert stack or a Gated DeltaNet weight stack (a wave WRITES the state pool
+in place, a row's slot at a time, and the burst hands it whole to one kernel a
+Gated DeltaNet layer, ops/pallas_state.py, aliased in and out, PR 56; the
+burst slices a layer of a weight stack inside the product that reads it, and
+nowhere else);
 and the ops that the benchmark's metrics pick out of a trace by their shapes
 are the ops under the scopes they are meant to read.  Nothing executes; a
 pass here is not a chip run.
@@ -29,6 +31,7 @@ import pytest
 
 from tests.test_tpu_compile import (  # noqa: F401 - fixtures
     COMMIT_CASES,
+    assert_calls_step_pool_in_place,
     assert_commits_windows_in_place,
     assert_wave_keeps_in_place,
     chip,
@@ -171,9 +174,11 @@ def timed_ops_rewriting(hlo: str, leaf) -> list:
 
 
 @pytest.mark.parametrize("program,rows,writes", [
-    pytest.param("burst", 0, 6, id="burst"),        # a Gated DeltaNet layer: one write of its rows
-    pytest.param("wave", 1, 6, id="wave-1x512"),    # a row: its state after the chunk, its snapshot
-    pytest.param("wave", 8, 48, id="wave-8x512"),
+    # a Gated DeltaNet layer: one write of its rows of history; the STATE is the kernel's alone
+    pytest.param("burst", 0, {"s": 0, "conv": 6}, id="burst"),
+    # a row: its state after the chunk, its snapshot
+    pytest.param("wave", 1, {"s": 6, "conv": 6}, id="wave-1x512"),
+    pytest.param("wave", 8, {"s": 48, "conv": 48}, id="wave-8x512"),
 ])
 def test_step_program_leaves_pools_and_experts_in_place(chip, as_on_chip, program, rows, writes):
     hlo, pools = compiled(chip, program, rows)
@@ -184,7 +189,15 @@ def test_step_program_leaves_pools_and_experts_in_place(chip, as_on_chip, progra
     for name in ("s", "conv"):  # written in place, a slot (the burst: its rows) at a time
         movers = pool_movers(hlo, pools[name])
         assert all(m.startswith("dynamic_update_slice") for m in movers), (name, movers)
-        assert len(movers) == writes, (name, movers)
+        assert len(movers) == writes[name], (name, movers)
+    if program == "burst":
+        # a Gated DeltaNet layer's rule is ONE call (PR 56, as Olmo-Hybrid's since PR 49): the state
+        # pool goes in whole and comes out as the same buffer (ops/pallas_state.py), and no array
+        # of all 32 rows' states exists
+        calls = [ln for ln in timed_lines(hlo, ("custom-call",)) if "/gdn_recurrent/" in ln]
+        assert_calls_step_pool_in_place(calls, "f32[6,97,32,128,128]")
+        assert len(calls) == 6, [c[:120] for c in calls]
+        assert "f32[32,32,128,128]" not in hlo
 
 
 @pytest.mark.parametrize("program,rows", COMMIT_CASES)
@@ -196,9 +209,12 @@ def test_step_program_commits_keys_and_values_as_windows_in_place(chip, as_on_ch
     assert_commits_windows_in_place(hlo, pools["kv"], program, rows)
     if program == "burst":
         # the loop over the row slots' runs (the window plan once, 12 instructions a pool's
-        # iteration): 511 timed instructions where the row form's two scatters and their indices
-        # made it 485; a commit that unrolls its windows, or plans them twice, shows here
-        assert len(list(timed_lines(hlo))) <= 511
+        # iteration): 539 timed instructions (511 until the rule became a kernel, PR 56: a layer's
+        # two passes are one call, and k | q turned on their side in VMEM, their sum, exp(g) and
+        # the dead rows' mask are small ops of their own, 28 more) where the row form's two
+        # scatters and their indices made it 485; a commit that unrolls its windows, or plans
+        # them twice, shows here
+        assert len(list(timed_lines(hlo))) <= 539
 
 
 @pytest.mark.parametrize("program,rows,layers_written", [
@@ -282,9 +298,21 @@ def test_the_metrics_select_the_ops_under_their_scopes(chip, as_on_chip):
     # the shared expert is 512 wide too: its products keep three axes and are not picked
     assert any(re.search(r"_bf16_32_1_1024_$", n) for n, s in timed_ops(burst) if s == "moe_shared")
 
-    decode = _picked(burst, re.compile(spec("gdn_decode_roofline_frac")["op"]))
-    assert set(decode) == {"gdn_recurrent"} and len(decode["gdn_recurrent"]) == 12  # 2 passes x 6 layers
-    assert _picked(wave, re.compile(spec("gdn_decode_roofline_frac")["op"])) == {}
+    # the one-token rule, a layer and step: ONE call of the kernel (PR 56), named for its scope and
+    # for its FIRST result (o, [32, 32, 128]: the pool is its second), picked by the accepted
+    # pattern's arm for the scope; XLA's two passes over all 32 row slots, which its other arm
+    # names, are gone, and the pattern picks nothing outside the scope (the mask of the dead rows'
+    # o is ``select_multiply_fusion``, not ``fusion``, and k | q on their side are copies)
+    rule = re.compile(spec("gdn_decode_roofline_frac")["op"])
+    decode = _picked(burst, rule)
+    assert set(decode) == {"gdn_recurrent"} and len(decode["gdn_recurrent"]) == 6  # a layer's call
+    names = [re.sub(r"\.\d+", "", n) for n in decode["gdn_recurrent"]]
+    assert names == ["gdn_recurrent_f32_32_32_128_"] * 6, names
+    # what the scope holds besides is per-head vectors ([32, 32]: exp(g), sum(k q)), a
+    # thousandth of the rows' state, and nothing of state size
+    rest = {n for n, scope in timed_ops(burst) if scope == "gdn_recurrent"} - decode["gdn_recurrent"]
+    assert rest and all(n.endswith("_f32_32_32_") for n in rest), rest
+    assert _picked(wave, rule) == {}
 
     chunked = re.compile(spec("gdn_prefill_roofline_frac")["op"].format(
         **family.state_op_sizes(model, cell.config)))
@@ -294,7 +322,9 @@ def test_the_metrics_select_the_ops_under_their_scopes(chip, as_on_chip):
 
     moves = re.compile(spec("state_pool_move_share")["pattern"])
     assert set(_picked(wave, moves)) <= {"state_write"}  # the in-place row writes, nothing else
-    assert set(_picked(burst, moves)) <= {"gdn_recurrent", "gdn_conv", ""}
+    # the kernel computes, it does not move the pool: its name ends in o's shape, not the pool's;
+    # what is left of the burst is the histories' rows written back
+    assert set(_picked(burst, moves)) <= {"gdn_conv", ""}
 
     # the burst's attention kernel is named for its scope, where the accepted metric looks
     paged = re.compile(manifest.metric_spec("paged_attn_hbm_frac")["args"]["op"])
@@ -305,9 +335,11 @@ def test_the_metrics_select_the_ops_under_their_scopes(chip, as_on_chip):
 def test_the_burst_keeps_the_timed_ops_it_has(chip, as_on_chip):
     """The burst's timed ops by the name a trace shows (less XLA's running
     number), counted: the parent commit's of PR 40, whose burst this model shares
-    with Olmo-Hybrid (models/hybrid.py:burst, ops/gated_delta.gated_delta_step),
-    written again by PR 43 for the ops under ``kv_write`` and for no other (the
-    commit as windows of slots: the two row scatters and their indices went).
+    with Olmo-Hybrid (models/hybrid.py:burst), written again by PR 43 for the ops
+    under ``kv_write`` and for no other (the commit as windows of slots: the two
+    row scatters and their indices went), and by PR 56 for the one-token rule and
+    for no other (ops/pallas_state.gated_delta_step_in_place on the pool: the
+    rule's two fusions went, the kernel and its small operands came).
     The accepted ``gdn_decode_roofline_frac`` and ``moe_experts_hbm_frac`` find
     their ops by these names, so an edit made for the other hybrid that renames
     one here reads null on the chip.  A change that MEANS to move this program
@@ -317,6 +349,7 @@ def test_the_burst_keeps_the_timed_ops_it_has(chip, as_on_chip):
     burst, _ = compiled(chip, "burst", 0)
     got = collections.Counter(re.sub(r"\.\d+", "", name) for name, _ in timed_ops(burst))
     assert got == want, {"gone": dict(want - got), "new": dict(got - want)}
-    # the recurrence: two passes a layer, the second the update written into the pool in place
-    assert want["fusion_f32_32_32_128_"] == 6
-    assert want["select_dynamic-update-slice_fusion_f32_6_97_32_128_128_"] == 6
+    # the recurrence: one kernel a layer on the pool where it lies; XLA's two passes are gone
+    assert want["gdn_recurrent_f32_32_32_128_"] == 6
+    assert "fusion_f32_32_32_128_" not in want
+    assert "select_dynamic-update-slice_fusion_f32_6_97_32_128_128_" not in want
