@@ -73,14 +73,24 @@ which model it runs):
   ``ssm_step_in_pool`` and ``gdn_step_in_pool`` below are the two there are);
 * ``attn_project(cfg, p, x, *position_cols) -> (q, k, v, more)`` /
   ``attn_out(p, attn, *more)`` around the shared pages and kernels;
-* ``attn_window``: columns of a prefill chunk one call of the attention
-  kernel takes.
+* ``attn_window``: columns of a prefill chunk one call of the K/V attention
+  kernel takes (``KVPages`` reads it; a model with pages of its own needs none);
+* optionally ``pages``: the page layer's two halves, what rows a layer commits
+  and the attention over the pools and over the burst's staged tail.  Absent,
+  ``KVPages`` below: keys and values in two pools, what ``attn_project`` hands
+  over as ``(q, k, v, more)``.  A model whose page layers keep something else
+  (models/bailing_hybrid.py: ONE latent row a token, no V pool) brings an
+  object of the same four functions and says how many arrays a layer commits
+  (``rows``); ``attn_project`` then returns ``(q, *those, more)``;
+* optionally ``counts``: how many numbers its layers' counts are (2: experts
+  hit and pairs routed to held experts; 3 adds the fullest held expert's pairs).
 """
 
 from __future__ import annotations
 
 import math
 from functools import partial
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -501,6 +511,114 @@ def ssm_scalars(cfg, a_u, dt_u):
     return jnp.log(a), dt + jnp.log(-jnp.expm1(-dt))
 
 
+# ------------------------------------------------------------ the page layer --
+
+class KVPages:
+    """The page layer's two halves as the K/V families keep them (``m.pages``
+    where a model brings none): a layer commits its keys and its values into
+    two pools ``[attention layers, n_kv, P, page, head_dim]``.  ``c`` is what the
+    step program knows of the call (slots, tables, lengths, the step)."""
+
+    rows = 2  # arrays a layer commits: k, v
+
+    @staticmethod
+    def wave_attend(m, cfg, weights, pi, q, rows, kv_pools, c):
+        """A layer's keys and values into their pages and the chunk's attention
+        over them, outside every switch.  Returns (the pools, attn)."""
+        from githubrepostorag_tpu.ops.paged_attention import paged_attention_ref
+        from githubrepostorag_tpu.serving.kv_cache import commit_paged
+
+        (k, v), (kp, vp) = rows, kv_pools
+        nkv, hd = cfg.num_kv_heads, cfg.head_dim
+        with jax.named_scope("kv_write"):
+            flat, run = c.slots.reshape(-1), c.slots.shape[1]  # a row's columns: consecutive positions
+            kp, _ = commit_paged(kp, k.reshape(-1, nkv, hd).swapaxes(0, 1), flat, None,
+                                 c.page_size, layer=pi, run=run)
+            vp, _ = commit_paged(vp, v.reshape(-1, nkv, hd).swapaxes(0, 1), flat, None,
+                                 c.page_size, layer=pi, run=run)
+        with jax.named_scope("paged_attention"):
+            if c.use_pallas:
+                from githubrepostorag_tpu.ops.fused_decode import fused_paged_attention
+
+                # the kernel keeps a window's queries, accumulator and softmax
+                # state for a kv head's whole group in VMEM: 8 heads of 256 over
+                # 512 columns are 16.8 MB, past what a v5e kernel may hold, so a
+                # chunk goes through in windows of ``m.attn_window`` columns (the
+                # keys of the whole chunk are committed: a later window attends the
+                # earlier ones as cache); a window past the wave's width is skipped
+                span = m.attn_window
+
+                def window(col):
+                    qw = q[:, col:col + span]
+                    run = lambda: fused_paged_attention(  # noqa: E731
+                        qw, kp, vp, c.block_tables, c.cached_lens + jnp.minimum(c.new_lens, col),
+                        jnp.clip(c.new_lens - col, 0, span), layer=pi)
+                    if col == 0 or c.width is None:
+                        return run()
+                    return jax.lax.cond(c.width > col, run, lambda: jnp.zeros_like(qw))
+
+                attn = jnp.concatenate([window(col) for col in range(0, c.chunk, span)], axis=1)
+            else:
+                attn = paged_attention_ref(q, kp[pi], vp[pi], c.block_tables, c.cached_lens,
+                                           c.new_lens)
+        return (kp, vp), attn
+
+    @staticmethod
+    def staged(cfg, kv_pools, b, n_steps):
+        """The burst's staged rows, empty: what its layers write and its attention
+        reads as a tail until one commit lays them into their pages."""
+        return tuple(jnp.zeros((cfg.kv_layers, b, cfg.num_kv_heads, n_steps, cfg.head_dim),
+                               kv_pools[0].dtype) for _ in range(2))
+
+    @staticmethod
+    def burst_attend(m, cfg, p, pi, q, rows, staged, kv_pools, c):
+        """A layer's token into the staged rows and its attention over the pools
+        and the tail.  Returns (attn, staged)."""
+        from githubrepostorag_tpu.ops.attention import dense_attention
+        from githubrepostorag_tpu.ops.paged_attention import gather_kv
+        from githubrepostorag_tpu.ops.pallas_paged import paged_attention_decode_staged
+
+        (k, v), (sk, sv), (k_pages, v_pages) = rows, staged, kv_pools
+        with jax.named_scope("kv_write"):
+            sk = jax.lax.dynamic_update_slice(
+                sk, k.swapaxes(1, 2).astype(sk.dtype)[None], (pi, 0, 0, c.step, 0))
+            sv = jax.lax.dynamic_update_slice(
+                sv, v.swapaxes(1, 2).astype(sv.dtype)[None], (pi, 0, 0, c.step, 0))
+        sk_l = jax.lax.dynamic_index_in_dim(sk, pi, 0, keepdims=False)
+        sv_l = jax.lax.dynamic_index_in_dim(sv, pi, 0, keepdims=False)
+        # under the scope: the kernel's instruction is named for it in the
+        # device trace, where the accepted metric looks for it
+        with jax.named_scope("paged_attention"):
+            if c.use_pallas:
+                attn = paged_attention_decode_staged(
+                    q, k_pages, v_pages, c.block_tables, c.walk_lens, sk_l, sv_l,
+                    jnp.reshape(c.step + 1, (1,)), jnp.reshape(pi, (1,)), interpret=c.interpret)
+            else:
+                pool_k, pool_v = gather_kv(k_pages[pi], v_pages[pi], c.block_tables)
+                valid = jnp.concatenate(
+                    [jnp.arange(pool_k.shape[1])[None, :] < c.start_lens[:, None],
+                     jnp.broadcast_to((jnp.arange(c.n_steps) <= c.step)[None, :],
+                                      (c.b, c.n_steps))],
+                    axis=1)
+                attn = dense_attention(
+                    q, jnp.concatenate([pool_k, sk_l.swapaxes(1, 2)], axis=1),
+                    jnp.concatenate([pool_v, sv_l.swapaxes(1, 2)], axis=1),
+                    causal=False, kv_valid=valid)
+        return attn, (sk, sv)
+
+    @staticmethod
+    def burst_commit(cfg, kv_pools, staged, slots, b, n_steps, page_size):
+        """The burst's staged rows into their pages, one commit a pool."""
+        from githubrepostorag_tpu.serving.kv_cache import commit_paged
+
+        nkv, hd = cfg.num_kv_heads, cfg.head_dim
+        with jax.named_scope("kv_write"):
+            commit = lambda pool, st: commit_paged(  # noqa: E731
+                pool, st.swapaxes(1, 2).reshape(cfg.kv_layers, nkv, b * n_steps, hd), slots,
+                None, page_size, run=n_steps)[0]  # a row's steps are consecutive positions
+            return commit(kv_pools[0], staged[0]), commit(kv_pools[1], staged[1])
+
+
 # ----------------------------------------------------------- step programs --
 
 def wave(m, params, cfg, input_ids, positions, k_pages, v_pages, slot_mapping, block_tables,
@@ -510,11 +628,8 @@ def wave(m, params, cfg, input_ids, positions, k_pages, v_pages, slot_mapping, b
     the pools (module docstring), traced into the wave program too
     (``width``: the wave's, see ops/prefill_width.at_wave_width).  Returns
     (logits, k_pages, v_pages, the layers' counts [2], state)."""
-    from githubrepostorag_tpu.ops.paged_attention import paged_attention_ref
-    from githubrepostorag_tpu.serving.kv_cache import commit_paged
-
     num_pages, page_size = k_pages.shape[2], k_pages.shape[3]
-    nkv, hd = cfg.num_kv_heads, cfg.head_dim
+    pages = getattr(m, "pages", KVPages)
     h = m.embed(cfg, params, input_ids)
     along = m.position_cols(cfg, positions)
     slots = jnp.where(slot_mapping < 0, num_pages * page_size, slot_mapping)  # dropped
@@ -568,52 +683,32 @@ def wave(m, params, cfg, input_ids, positions, k_pages, v_pages, slot_mapping, b
             layer, width, page_size, (h, *cols[1:]), read_state(st_pools, g))
         return h, write_state(st_pools, g, *new), add(stats, st)
 
-    def attend(pi, q, k, v, kv_pools):
-        """A layer's keys and values into their pages and the chunk's attention
-        over them, outside every switch.  Returns (the pools, attn)."""
-        kp, vp = kv_pools
-        with jax.named_scope("kv_write"):
-            flat, run = slots.reshape(-1), slots.shape[1]  # a row's columns: consecutive positions
-            kp, _ = commit_paged(kp, k.reshape(-1, nkv, hd).swapaxes(0, 1), flat, None,
-                                 page_size, layer=pi, run=run)
-            vp, _ = commit_paged(vp, v.reshape(-1, nkv, hd).swapaxes(0, 1), flat, None,
-                                 page_size, layer=pi, run=run)
-        with jax.named_scope("paged_attention"):
-            if use_pallas:
-                from githubrepostorag_tpu.ops.fused_decode import fused_paged_attention
+    call = SimpleNamespace(slots=slots, block_tables=block_tables, cached_lens=cached_lens,
+                           new_lens=new_lens, width=width, chunk=chunk, page_size=page_size,
+                           use_pallas=use_pallas)
 
-                # the kernel keeps a window's queries, accumulator and softmax
-                # state for a kv head's whole group in VMEM: 8 heads of 256 over
-                # 512 columns are 16.8 MB, past what a v5e kernel may hold, so a
-                # chunk goes through in windows of ``m.attn_window`` columns (the
-                # keys of the whole chunk are committed: a later window attends the
-                # earlier ones as cache); a window past the wave's width is skipped
-                span = m.attn_window
+    def attend(pi, q, rows, kv_pools):
+        """What a page layer commits into its pages and the chunk's attention over
+        them (``pages``), outside every switch.  Returns (the pools, attn)."""
+        return pages.wave_attend(m, cfg, lambda: m.attn_weights(w, pi), pi, q, rows, kv_pools,
+                                 call)
 
-                def window(c):
-                    qw = q[:, c:c + span]
-                    run = lambda: fused_paged_attention(  # noqa: E731
-                        qw, kp, vp, block_tables, cached_lens + jnp.minimum(new_lens, c),
-                        jnp.clip(new_lens - c, 0, span), layer=pi)
-                    if c == 0 or width is None:
-                        return run()
-                    return jax.lax.cond(width > c, run, lambda: jnp.zeros_like(qw))
+    def projected(cols, p, x):
+        """``m.attn_project`` as (q, what rides to ``attn_out``, the rows to commit)."""
+        q, *rows, more = m.attn_project(cfg, p, x, *cols[1:len(along) + 1])
+        return tuple(padded(t) for t in (q, *more, *rows))
 
-                attn = jnp.concatenate([window(c) for c in range(0, chunk, span)], axis=1)
-            else:
-                attn = paged_attention_ref(q, kp[pi], vp[pi], block_tables, cached_lens,
-                                           new_lens)
-        return (kp, vp), attn
+    def unpacked(out):
+        q, *rest = out
+        return q, rest[:len(rest) - pages.rows], rest[len(rest) - pages.rows:]
 
     def attn_layer(pi, li, h, kv_pools, stats):
         def project(cols, _):
             h = cols[0]
-            q, k, v, more = m.attn_project(cfg, m.attn_weights(w, pi),
-                                           m.mixer_input(cfg, w, li, h), *cols[1:len(along) + 1])
-            return h, tuple(padded(t) for t in (q, *more, k, v))
+            return h, projected(cols, m.attn_weights(w, pi), m.mixer_input(cfg, w, li, h))
 
-        _, (q, *more, k, v) = at_wave_width(project, width, page_size, (h, *cols[1:]), ())
-        kv_pools, attn = attend(pi, q, k, v, kv_pools)
+        q, more, rows = unpacked(at_wave_width(project, width, page_size, (h, *cols[1:]), ())[1])
+        kv_pools, attn = attend(pi, q, rows, kv_pools)
 
         def rest(cols, came_in):
             h, live, attn, *more = cols[0], *cols[len(along) + 1:]
@@ -636,15 +731,13 @@ def wave(m, params, cfg, input_ids, positions, k_pages, v_pages, slot_mapping, b
             x = m.mixer_input(cfg, w, li, h)
             y, s_new, s_snap, taps, taps_snap = m.state_chunk(
                 cfg, m.state_weights(w, g), x, s0, taps0, live, new_lens, snap_col, page_size)
-            q, k, v, more = m.attn_project(cfg, m.attn_weights(w, pi), x,
-                                           *cols[1:len(along) + 1])
-            return h, (s_new, s_snap, taps, taps_snap,
-                       tuple(padded(t) for t in (y, q, *more, k, v)))
+            return h, (s_new, s_snap, taps, taps_snap, (padded(y), *projected(cols, m.attn_weights(w, pi), x)))
 
-        _, (*new, (y, q, *more, k, v)) = at_wave_width(
+        _, (*new, (y, *out)) = at_wave_width(
             mixers, width, page_size, (h, *cols[1:]), read_state(st_pools, g))
+        q, more, rows = unpacked(out)
         st_pools = write_state(st_pools, g, *new)
-        kv_pools, attn = attend(pi, q, k, v, kv_pools)
+        kv_pools, attn = attend(pi, q, rows, kv_pools)
 
         def rest(cols, came_in):
             h, live, y, attn, *more = cols[0], *cols[len(along) + 1:]
@@ -662,7 +755,7 @@ def wave(m, params, cfg, input_ids, positions, k_pages, v_pages, slot_mapping, b
         return h, add(stats, st)
 
     kv_pools, st_pools = (k_pages, v_pages), (state["s"], state["conv"])
-    stats = jnp.zeros((2,), jnp.int32)
+    stats = jnp.zeros((getattr(m, "counts", 2),), jnp.int32)
     for kinds, reps, before in _walk(cfg.layer_segments):
         def body(carry, _, kinds=kinds, before=before):
             h, rep, kv_pools, st_pools, stats = carry
@@ -703,16 +796,12 @@ def burst(m, params, cfg, last_tokens, seq_lens, k_pages, v_pages, presence, act
     the update itself; nothing pads, cuts or copies them in between.  Returns
     (packed tokens [B, n_steps], valid, k_pages, v_pages, presence, seq_lens,
     last_tokens, the layers' counts [2], state)."""
-    from githubrepostorag_tpu.ops.attention import dense_attention
-    from githubrepostorag_tpu.ops.paged_attention import gather_kv
-    from githubrepostorag_tpu.ops.pallas_paged import paged_attention_decode_staged
     from githubrepostorag_tpu.serving.decode_burst import overlay_fresh
-    from githubrepostorag_tpu.serving.kv_cache import commit_paged
 
     last_tokens, seq_lens, rng = overlay_fresh(
         last_tokens, seq_lens, rng, first_tokens, fresh, fresh_lens, key_step)
-    b, P = last_tokens.shape[0], cfg.kv_layers
-    nkv, hd = cfg.num_kv_heads, cfg.head_dim
+    b = last_tokens.shape[0]
+    pages = getattr(m, "pages", KVPages)
     num_pages, page_size = k_pages.shape[2], k_pages.shape[3]
     rows = jnp.arange(b)
     start_lens = seq_lens
@@ -761,33 +850,13 @@ def burst(m, params, cfg, last_tokens, seq_lens, k_pages, v_pages, presence, act
             return y, (s_pool, c_pool)
 
         def attn_mixer(p, pi, x, staged):
-            sk, sv = staged
-            q, k, v, more = m.attn_project(cfg, p, x, *along)
-            with jax.named_scope("kv_write"):
-                sk = jax.lax.dynamic_update_slice(
-                    sk, k.swapaxes(1, 2).astype(sk.dtype)[None], (pi, 0, 0, step, 0))
-                sv = jax.lax.dynamic_update_slice(
-                    sv, v.swapaxes(1, 2).astype(sv.dtype)[None], (pi, 0, 0, step, 0))
-            sk_l = jax.lax.dynamic_index_in_dim(sk, pi, 0, keepdims=False)
-            sv_l = jax.lax.dynamic_index_in_dim(sv, pi, 0, keepdims=False)
-            # under the scope: the kernel's instruction is named for it in the
-            # device trace, where the accepted metric looks for it
-            with jax.named_scope("paged_attention"):
-                if use_pallas:
-                    attn = paged_attention_decode_staged(
-                        q, k_pages, v_pages, block_tables, walk_lens, sk_l, sv_l,
-                        jnp.reshape(step + 1, (1,)), jnp.reshape(pi, (1,)), interpret=interpret)
-                else:
-                    pool_k, pool_v = gather_kv(k_pages[pi], v_pages[pi], block_tables)
-                    valid = jnp.concatenate(
-                        [jnp.arange(pool_k.shape[1])[None, :] < start_lens[:, None],
-                         jnp.broadcast_to((jnp.arange(n_steps) <= step)[None, :], (b, n_steps))],
-                        axis=1)
-                    attn = dense_attention(
-                        q, jnp.concatenate([pool_k, sk_l.swapaxes(1, 2)], axis=1),
-                        jnp.concatenate([pool_v, sv_l.swapaxes(1, 2)], axis=1),
-                        causal=False, kv_valid=valid)
-            return m.attn_out(p, attn, *more), (sk, sv)
+            q, *rows, more = m.attn_project(cfg, p, x, *along)
+            attn, staged = pages.burst_attend(
+                m, cfg, p, pi, q, rows, staged, (k_pages, v_pages),
+                SimpleNamespace(block_tables=block_tables, walk_lens=walk_lens,
+                                start_lens=start_lens, step=step, n_steps=n_steps, b=b,
+                                use_pallas=use_pallas, interpret=interpret))
+            return m.attn_out(p, attn, *more), staged
 
         # the layers are unrolled, not scanned: with a layer's index static its
         # weights are views of the stacks, and a burst of 8 layers still
@@ -825,9 +894,9 @@ def burst(m, params, cfg, last_tokens, seq_lens, k_pages, v_pages, presence, act
         lens = lens + act.astype(jnp.int32)
         return (toks, lens, staged, st_pools, pres, act, stats), (toks, act)
 
-    staged0 = tuple(jnp.zeros((P, b, nkv, n_steps, hd), k_pages.dtype) for _ in range(2))
+    staged0 = pages.staged(cfg, (k_pages, v_pages), b, n_steps)
     carry0 = (last_tokens, seq_lens, staged0, (state["s"], state["conv"]), presence, active,
-              jnp.zeros((2,), jnp.int32))
+              jnp.zeros((getattr(m, "counts", 2),), jnp.int32))
     (last, out_lens, staged, st_pools, presence, _, stats), (toks, valid) = jax.lax.scan(
         one_step, carry0, (jnp.arange(n_steps), jax.random.split(rng, n_steps)))
     toks, valid = toks.T, valid.T
@@ -837,10 +906,7 @@ def burst(m, params, cfg, last_tokens, seq_lens, k_pages, v_pages, presence, act
     page_idx = jnp.clip(pos // page_size, 0, block_tables.shape[1] - 1)
     slots = jnp.take_along_axis(block_tables, page_idx, axis=1) * page_size + pos % page_size
     slots = jnp.where(valid, slots, num_pages * page_size).reshape(-1)  # sentinel: dropped
-    with jax.named_scope("kv_write"):
-        commit = lambda pool, st: commit_paged(  # noqa: E731
-            pool, st.swapaxes(1, 2).reshape(P, nkv, b * n_steps, hd), slots, None, page_size,
-            run=n_steps)[0]  # a row's steps are consecutive positions
-        k_pages, v_pages = commit(k_pages, staged[0]), commit(v_pages, staged[1])
+    k_pages, v_pages = pages.burst_commit(cfg, (k_pages, v_pages), staged, slots, b, n_steps,
+                                          page_size)
     return (packed, valid, k_pages, v_pages, presence, out_lens, last, stats,
             {"s": st_pools[0], "conv": st_pools[1]})

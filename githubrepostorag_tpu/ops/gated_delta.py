@@ -27,6 +27,20 @@ Two forms of the same recurrence:
   its state 8 times and not 512.  The per-token scan the step form would
   give over such a chunk moves 4 MB of state a token, row and layer.
 
+Kimi Delta Attention (Kimi Linear, arXiv:2510.26692) is the same rule with the
+decay a key CHANNEL and not a head: ``g`` [.., H, dk], ``S <- diag(exp(g_t))
+S``.  Both forms take either shape of ``g`` and trace, for a head's scalar,
+to what they always did.  The step form scales the state's rows instead of
+the whole matrix.  The chunked form cannot keep ``decay = exp(gc_i - gc_j)``
+outside ``K K^T``: it goes inside the contraction as ``(k_i exp(gc_i - r)) .
+(k_j exp(r - gc_j))`` about a reference ``r``, and the second factor overflows
+float32 unless ``r`` is near: with the gate bounded below (``g >= g_min`` a
+token: what a model's lower-bounded gate is FOR) the factors of a pair are
+taken from a reference token at most ``channel_span(g_min)`` tokens away (16
+at -5: ``exp(75)``).  Inside a diagonal sub-block of that many tokens the
+reference is the sub-block's first token; between sub-blocks it is the LATER
+sub-block's first token, where both factors are at most one.
+
 Tokens that are not real (the padding of a wave's row, past ``new_lens``)
 arrive with ``k = 0``, ``beta = 0`` and ``g = 0`` (``mask_padding``): they
 multiply the state by one and add zero to it, bit for bit.
@@ -55,8 +69,10 @@ def l2norm(x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
 
 
 def mask_padding(live: jnp.ndarray, k, g, beta):
-    """``live`` [R, T] marks real tokens; the others leave the state as it is."""
-    return (jnp.where(live[..., None, None], k, 0.0), jnp.where(live[..., None], g, 0.0),
+    """``live`` [R, T] marks real tokens; the others leave the state as it is
+    (``g`` a head's [R, T, H] or a channel's [R, T, H, dk])."""
+    g_live = live[..., None, None] if g.ndim == k.ndim else live[..., None]
+    return (jnp.where(live[..., None, None], k, 0.0), jnp.where(g_live, g, 0.0),
             jnp.where(live[..., None], beta, 0.0))
 
 
@@ -76,6 +92,8 @@ def gated_delta_step(state, q, k, v, g, beta):
     bit, so nobody cuts the padding off or writes it back, and the caller
     lays what it gets straight into the pool.  At equal widths this traces
     to what it always did."""
+    if g.ndim == k.ndim:
+        return _channel_step(state, q, k, v, g, beta)
     dv = v.shape[-1]
     read = state[..., :dv]  # the whole state at equal widths: traces to nothing
     decay = jnp.exp(g)[..., None]
@@ -86,6 +104,22 @@ def gated_delta_step(state, q, k, v, g, beta):
     if state.shape[-1] != dv:
         delta = jnp.pad(delta, ((0, 0),) * (delta.ndim - 1) + ((0, state.shape[-1] - dv),))
     return o, state * decay[..., None] + k[..., None] * delta[..., None, :]
+
+
+def _channel_step(state, q, k, v, g, beta):
+    """``gated_delta_step`` with the decay a key channel (``g`` [B, H, dk]): the
+    state's ROWS are scaled, so ``S^T k`` and ``S^T q`` of the decayed state are
+    ``S^T (a k)`` and ``S^T (a q)`` of the one that came in, ``a = exp(g)``."""
+    dv = v.shape[-1]
+    read = state[..., :dv]
+    a = jnp.exp(g)
+    kv = jnp.sum(read * (a * k)[..., None], axis=-2)
+    qv = jnp.sum(read * (a * q)[..., None], axis=-2)
+    delta = beta[..., None] * (v - kv)
+    o = qv + jnp.sum(k * q, axis=-1, keepdims=True) * delta
+    if state.shape[-1] != dv:
+        delta = jnp.pad(delta, ((0, 0),) * (delta.ndim - 1) + ((0, state.shape[-1] - dv),))
+    return o, state * a[..., None] + k[..., None] * delta[..., None, :]
 
 
 # The Neumann product of an n x n block sums the powers (-a)^k, k < n, whose
@@ -137,15 +171,54 @@ def _unit_lower_inverse(a: jnp.ndarray, neumann: int = NEUMANN_MAX) -> jnp.ndarr
     return inv
 
 
+CHANNEL_EXPONENT = 80.0  # the largest exponent a factor of the per-channel form may take
+
+
+def channel_span(g_min: float, block: int = BLOCK) -> int:
+    """Tokens of a diagonal sub-block of the per-channel chunked form: the
+    largest power of two n, ``block`` at the most, with ``(n - 1) |g_min|``
+    inside ``CHANNEL_EXPONENT`` (16 at -5)."""
+    n = block
+    while n > 1 and (n - 1) * abs(g_min) > CHANNEL_EXPONENT:
+        n //= 2
+    return n
+
+
+def _channel_pairs(q, k, kb, gc, span: int):
+    """``(beta K K^T) * decay`` and ``(Q K^T) * decay`` [..., C, C] of a block
+    whose decay is a channel's: ``gc`` [..., C, dk] the log decay from the
+    block's start.  Rows go a sub-block of ``span`` tokens at a time, about the
+    sub-block's first token ``r``: the left factor ``x_i exp(gc_i - r)`` is at
+    most one; the right, ``k_j exp(r - gc_j)``, is at most one before the
+    sub-block, at most ``exp((span - 1) |g_min|)`` inside it, and zero after it
+    (the strictly upper part, which nobody reads, and where it would overflow)."""
+    c, dk = gc.shape[-2:]
+    n = c // span
+    lead = gc.shape[:-2]
+    sub = lambda x: x.reshape(*lead, n, span, dk)  # noqa: E731
+    ref = sub(gc)[..., :1, :]  # [..., n, 1, dk]
+    left = jnp.exp(sub(gc) - ref)
+    upto = (jnp.arange(c)[None, :] < (jnp.arange(n)[:, None] + 1) * span)[..., None]  # [n, C, 1]
+    right = k[..., None, :, :] * jnp.exp(jnp.where(upto, ref - gc[..., None, :, :], -jnp.inf))
+    pairs = lambda x: jnp.einsum(  # noqa: E731
+        "...nik,...njk->...nij", sub(x) * left, right, precision=HI).reshape(*lead, c, c)
+    return pairs(kb), pairs(q)
+
+
 def gated_delta_chunked(state, q, k, v, g, beta, snap_col=None, block: int = BLOCK,
-                        beta_max: float = 1.0):
+                        beta_max: float = 1.0, g_min: float | None = None):
     """A chunk of T tokens a row, T a multiple of ``block``.  ``state``
     [R, H, dk, dv] float32; ``q``, ``k`` [R, T, H, dk]; ``v`` [R, T, H, dv];
     ``g``, ``beta`` [R, T, H], padding masked (``mask_padding``); ``beta_max``
     the most ``beta`` can be (it sets the Neumann block, ``neumann_size``).  Returns
     (o [R, T, H, dv], the state after the chunk, the state after
     ``snap_col`` [R] tokens of it: a multiple of ``block``; the state that
-    came in where it is not positive or not given)."""
+    came in where it is not positive or not given).
+
+    ``g`` [R, T, H, dk] is a decay a key channel (module docstring), bounded
+    below by ``g_min`` a token."""
+    if g.ndim == q.ndim:
+        return _channel_chunked(state, q, k, v, g, beta, snap_col, block, beta_max, g_min)
     r, t, h, dk = q.shape
     n, c = t // block, block
 
@@ -180,6 +253,49 @@ def gated_delta_chunked(state, q, k, v, g, beta, snap_col=None, block: int = BLO
 
     (state, snap), o = jax.lax.scan(
         step, (state, state), (jnp.arange(n), u, w, qk, q_in, k_out, gc[..., -1]))
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)  # [R, N, C, H, dv]
+    return o.reshape(r, t, h, -1), state, snap
+
+
+def _channel_chunked(state, q, k, v, g, beta, snap_col, block, beta_max, g_min):
+    """``gated_delta_chunked`` with the decay a key channel: the same WY form,
+    the decay inside the two products of pairs (``_channel_pairs``) and along
+    the key axis of everything that meets the carried state."""
+    if g_min is None:
+        raise ValueError("a decay a channel needs its lower bound a token (g_min)")
+    r, t, h, dk = q.shape
+    n, c = t // block, block
+
+    def blocks(x):  # [R, T, H, ...] -> [N, R, H, C, ...]
+        x = x.reshape(r, n, c, *x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
+
+    q, k, v, g, beta = (blocks(x.astype(jnp.float32)) for x in (q, k, v, g, beta))
+    gc = jnp.cumsum(g, axis=-2)  # [N, R, H, C, dk]: log decay from the block's start
+    lower = jnp.tril(jnp.ones((c, c), bool))
+    kb, vb = k * beta[..., None], v * beta[..., None]
+    a, qk = _channel_pairs(q, k, kb, gc, channel_span(g_min, c))
+    inv = _unit_lower_inverse(jnp.where(jnp.tril(lower, -1), a, 0.0), neumann_size(beta_max))
+    u = jnp.einsum("...ij,...jv->...iv", inv, vb, precision=HI)
+    w = jnp.einsum("...ij,...jk->...ik", inv, kb * jnp.exp(gc), precision=HI)
+    qk = jnp.where(lower, qk, 0.0)
+    q_in = q * jnp.exp(gc)  # the query against the state carried in
+    k_out = k * jnp.exp(gc[..., -1:, :] - gc)  # a key's share of the state carried out
+    snap_col = jnp.zeros((r,), jnp.int32) if snap_col is None else snap_col
+
+    def step(carry, xs):
+        s, snap = carry
+        i, u_i, w_i, qk_i, q_i, k_i, g_end = xs
+        v_new = u_i - jnp.einsum("rhck,rhkv->rhcv", w_i, s, precision=HI)
+        o = jnp.einsum("rhck,rhkv->rhcv", q_i, s, precision=HI) \
+            + jnp.einsum("rhij,rhjv->rhiv", qk_i, v_new, precision=HI)
+        s = s * jnp.exp(g_end)[..., None] \
+            + jnp.einsum("rhck,rhcv->rhkv", k_i, v_new, precision=HI)
+        snap = jnp.where((snap_col == (i + 1) * c)[:, None, None, None], s, snap)
+        return (s, snap), o
+
+    (state, snap), o = jax.lax.scan(
+        step, (state, state), (jnp.arange(n), u, w, qk, q_in, k_out, gc[..., -1, :]))
     o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)  # [R, N, C, H, dv]
     return o.reshape(r, t, h, -1), state, snap
 

@@ -40,13 +40,14 @@ def rms_norm_zero_centered(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-
 
 
 def rms_norm_gated(x: jnp.ndarray, gate: jnp.ndarray, weight: jnp.ndarray,
-                   eps: float = 1e-6) -> jnp.ndarray:
+                   eps: float = 1e-6, gate_fn=jax.nn.silu) -> jnp.ndarray:
     """The Gated DeltaNet output norm: ``rmsnorm(x) * w * silu(gate)`` over
-    the last axis (one head), norm before gate, plain weight; float32 out."""
+    the last axis (one head), norm before gate, plain weight; float32 out
+    (``gate_fn``: Kimi Delta Attention's gate is a sigmoid)."""
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
     normed = xf * jax.lax.rsqrt(var + eps) * weight.astype(jnp.float32)
-    return normed * jax.nn.silu(gate.astype(jnp.float32))
+    return normed * gate_fn(gate.astype(jnp.float32))
 
 
 def rms_norm_gate_first(x: jnp.ndarray, gate: jnp.ndarray, weight: jnp.ndarray, groups: int,

@@ -19,7 +19,7 @@ starts no DMA in either direction and a slot past the rows is never addressed:
 ``where(act, new, old)`` as control, not as data.  A block is read once, before
 it is written, and no two blocks overlap, so a read can never meet a write.
 
-Two rules take that walk, each as a ``body``:
+Three rules take that walk, each as a ``body``:
 
 * ``ssd_step_in_place``: Mamba-2's (ops/ssd.ssd_step is the same rule as array
   code: the CPU path, and the oracle of tests/test_ssd.py).
@@ -27,6 +27,10 @@ Two rules take that walk, each as a ``body``:
   .gated_delta_step likewise: the CPU path and the oracle of
   tests/test_gated_delta.py; both families' bursts take the kernel on the
   chip, Olmo-Hybrid's since PR 49 and Qwen3-Next's since PR 56: PERF.md).
+* ``kda_step_in_place``: the same rule with the decay a key channel (Kimi
+  Delta Attention: a column ``[dk]`` a head beside ``k`` and ``q`` in VMEM where
+  the Gated DeltaNet's is a scalar in SMEM; the array form is
+  ``gated_delta_step`` with ``g`` [B, H, dk]).
 """
 
 from __future__ import annotations
@@ -243,4 +247,40 @@ def gated_delta_step_in_place(pool, layer, act, q, k, v, g, beta, interpret=Fals
         (jax.ShapeDtypeStruct((bsz, h, dv), jnp.float32),),
         _heads_a_block(h, dk, n, pool.dtype.itemsize),
         smem_operands=(jnp.exp(g), beta, jnp.sum(k * q, axis=-1)), interpret=interpret)
+    return jnp.where(act[:, None, None], o, 0.0), pool
+
+
+def _kda_body(row, block, s, beta_ref, kq_ref, a_ref, k_ref, ak_ref, aq_ref, v_ref, o_ref):
+    """``_gdn_body`` with the decay a key channel: ``a_ref`` (``exp(g)``),
+    ``k_ref``, ``ak_ref`` (``a k``) and ``aq_ref`` (``a q``) [B, dk, H], a head's
+    column down the sublanes as the state's rows lie: ``S^T (a k)`` and ``S^T (a
+    q)`` of the state that came in are those of the decayed one, and the update
+    scales the state's ROWS: ``a[:, None] S + k (x) delta``.  ``beta_ref``,
+    ``kq_ref`` (``k . q``) [B, H] SMEM; ``v_ref`` [B, H, dv'], ``o_ref`` [B, H, dv]."""
+    new = []
+    for i in range(s.shape[0]):
+        h = block * s.shape[0] + i
+        kv = jnp.sum(s[i] * ak_ref[row, :, h:h + 1], axis=0, keepdims=True)
+        qv = jnp.sum(s[i] * aq_ref[row, :, h:h + 1], axis=0, keepdims=True)
+        delta = beta_ref[row, h] * (v_ref[row, h:h + 1, :] - kv)
+        o_ref[row, h:h + 1, :] = (qv + kq_ref[row, h] * delta)[:, :o_ref.shape[-1]]
+        new.append(s[i] * a_ref[row, :, h:h + 1] + k_ref[row, :, h:h + 1] * delta)
+    return jnp.stack(new)
+
+
+def kda_step_in_place(pool, layer, act, q, k, v, g, beta, interpret=False):
+    """``gated_delta_step_in_place`` with ``g`` [B, H, dk], a decay a key
+    channel: the same walk over the live rows of ``pool[layer]``, the same
+    results (o [B, H, dv] first, the pool)."""
+    bsz, h, dk = k.shape
+    dv, n = v.shape[-1], pool.shape[-1]
+    if dv != n:
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, n - dv)))
+    a = jnp.exp(g)
+    o, pool = step_rows_in_place(
+        _kda_body, pool, layer, act,
+        (*(x.swapaxes(1, 2) for x in (a, k, a * k, a * q)), v),
+        (jax.ShapeDtypeStruct((bsz, h, dv), jnp.float32),),
+        _heads_a_block(h, dk, n, pool.dtype.itemsize),
+        smem_operands=(beta, jnp.sum(k * q, axis=-1)), interpret=interpret)
     return jnp.where(act[:, None, None], o, 0.0), pool

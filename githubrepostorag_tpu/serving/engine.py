@@ -102,6 +102,9 @@ logger = get_logger(__name__)
 TokenCallback = Callable[[str, int], None]  # (request_id, token_id)
 
 CYCLE_RING = 4096  # burst landings kept with their cycle: some minutes of traffic
+# prefill chunks between the snapshots a recurrent model's cold stretch leaves (_admit_state):
+# 8,192 tokens at 512-token chunks, 64 pages of 128
+SNAPSHOT_STRIDE_CHUNKS = 16
 
 
 @dataclass
@@ -358,8 +361,9 @@ class Engine:
                 "prefix_caching": sliding_kind is not None and not prefix_caching,
             }
             if any(unsupported.values()):
-                pool = ("latent page" if self._latent else "recurrent state" if self._recurrent
-                        else "sliding page")
+                pools = [name for name, has in (("latent page", self._latent),
+                                                ("recurrent state", self._recurrent)) if has]
+                pool = " and a ".join(pools or ["sliding page"])
                 raise ValueError(f"not built for a {pool} pool: "
                                  + ", ".join(k for k, v in unsupported.items() if v))
             self._wave_fn = family.forward_paged_wave
@@ -1828,7 +1832,20 @@ class Engine:
         published only behind its window or at its prefill's end, so a
         follower can share nothing the leader has just written and is held
         whatever the pools have free, and the leader is the prefilling row
-        whose chain hash at that page is the follower's."""
+        whose chain hash at that page is the follower's; with a state pool a
+        follower can resume only where a snapshot lies, so it is held while a
+        prefilling row of its prefix still OWES one (``snap_at``) deeper than
+        the cache serves it now and no deeper than the two share: admitted
+        beside that row it would compute the head again on pages of its own
+        (four clients a topic did, at 197 pages each: PERF.md, PR 57)."""
+        if self._state is not None:
+            depth = len(hashes)
+            shareable = min(len(req.page_hashes), (len(req.prompt) - 1) // self.page_size)
+            return any(
+                r.state == "prefilling" and any(
+                    depth < d <= min(shareable, len(r.page_hashes))
+                    and r.page_hashes[d - 1] == req.page_hashes[d - 1] for d in r.snap_at)
+                for r in self._row_req.values())
         if self._sliding is not None:
             depth = len(hashes)
             return depth < len(req.page_hashes) and any(
@@ -1919,8 +1936,13 @@ class Engine:
         the branch point the page match revealed (pages the cache held deeper
         than a snapshot lay: the next prompt of that prefix resumes there) and
         at the prompt's last shareable page boundary (a repeat, or a longer
-        prompt of the same head).  Two slots a cold prompt at most, not one a
-        chunk: a topic's 8,192-token head costs one slot, not sixteen."""
+        prompt of the same head), and, through a stretch it computes cold, at
+        every ``SNAPSHOT_STRIDE_CHUNKS`` chunks of absolute depth: a state layer
+        cannot resume from pages, so without them the SECOND prompt of a long
+        head computes all of it again beside the pages the first left (at 24,576
+        shared tokens every follower of a topic did, on 197 pages of its own:
+        PERF.md, Findings, PR 57), and with them it recomputes a stride at the
+        most.  A slot a stride, not one a chunk: a 25k-token head costs three."""
         ps = self.page_size
         self.page_hit_tokens += req.page_match * ps
         self.state_hit_tokens += shared_pages * ps
@@ -1929,7 +1951,9 @@ class Engine:
             req.state_src = self._state.take(req.page_hashes[shared_pages - 1])
             self.state_restored += 1
         last = min(len(req.page_hashes), (len(req.prompt) - 1) // ps)
-        req.snap_at = sorted({d for d in (req.page_match, last) if d > shared_pages})
+        stride = max(1, SNAPSHOT_STRIDE_CHUNKS * self.prefill_chunk // ps)
+        strides = range(stride * (shared_pages // stride + 1), last, stride)
+        req.snap_at = sorted({d for d in (req.page_match, last, *strides) if d > shared_pages})
 
     def _wave_state(self, reqs: list[_Request], valids: list[int], rb: int) -> dict:
         """The state arguments of one wave (models/hybrid.py): per wave

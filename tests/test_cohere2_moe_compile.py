@@ -60,10 +60,21 @@ def cell_config():
     return cell, family, family.model_config(family.model_of(cell.config, rehearse=False))
 
 
+LOWERED_ONLY = (2, 8)  # wave buckets held on their lowered text: the one- and four-row forms again
+
+
 @functools.lru_cache(maxsize=None)
 def compiled(where, program: str, rows: int):
     """(optimized HLO, the shapes of what must stay in place) of the burst or
     of the wave at a row bucket, compiled once a module."""
+    lowered, held = lowered_program(where, program, rows)
+    return lowered.compile().as_text(), held
+
+
+@functools.lru_cache(maxsize=None)
+def lowered_program(where, program: str, rows: int):
+    """(the lowered program, the shapes of what must stay in place): traced and
+    lowered for the described chip, not yet through its back end."""
     from githubrepostorag_tpu.models.cohere2_moe import decode_burst, forward_paged_wave, init_params
 
     cell, _, cfg = cell_config()
@@ -104,7 +115,7 @@ def compiled(where, program: str, rows: int):
             sliding_tables=sds((rows, ROW_PAGES), i32))
     held = {"global": g_shape, "sliding": s_shape, "embed": params["embed"].shape,
             **{k: params["layers"][k].shape for k in ("e_wgu", "e_wd", "s_wgu", "s_wd", "wqkv")}}
-    return lowered.compile().as_text(), held
+    return lowered, held
 
 
 def timed_ops(hlo: str):
@@ -133,6 +144,28 @@ def _dims(shape):
 @pytest.mark.parametrize("program,rows", PROGRAMS)
 def test_step_program_leaves_both_kinds_of_pool_and_the_weights_in_place(
         chip, as_on_chip, program, rows):
+    if rows in LOWERED_ONLY:
+        # the two- and eight-row waves are the one- and four-row FORMS at another bucket (with
+        # rungs and inline windows; one loop over the runs), which the back end is shown below
+        # and in the commit guard.  What could differ by bucket shows before the back end
+        # (ROADMAP D23: 65 s of compile a bucket): both kernels are called, and no pool is the
+        # result of a scatter, a gather, a transpose or a concatenation, only of update-slices
+        # of windows and of the kernels' own aliased results
+        lowered, held = lowered_program(chip, program, rows)
+        text = lowered.as_text()
+        assert "tpu_custom_call" in text
+        for kind in ("global", "sliding"):
+            pool = "x".join(map(str, held[kind])) + "xbf16>"
+            made = {m.group(1) for m in re.finditer(
+                r"= \"?(stablehlo\.[a-z_]+|func\.call)\"?.*-> tensor<" + pool + "$", text, re.M)}
+            assert made and made <= {"stablehlo.dynamic_update_slice", "stablehlo.custom_call",
+                                     "stablehlo.while", "stablehlo.case", "func.call",
+                                     "stablehlo.optimization_barrier"}, (kind, made)
+            # a scatter states its operands' types where its region closes, the others in one line
+            moved = [ln[:200] for ln in text.splitlines() if pool in ln and re.search(
+                r"scatter_dimension_numbers|stablehlo\.(gather|transpose|concatenate|copy)\b", ln)]
+            assert moved == [], (kind, moved)
+        return
     hlo, held = compiled(chip, program, rows)
     assert "tpu_custom_call" in hlo  # the paged kernels: 128 / 8 x 128, a table of 208 pages
     for kind in ("global", "sliding"):
