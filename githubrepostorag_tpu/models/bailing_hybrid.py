@@ -57,6 +57,7 @@ from githubrepostorag_tpu.ops.latent_attention import (
     latent_prefill_attention,
 )
 from githubrepostorag_tpu.ops.norms import rms_norm, rms_norm_gated
+from githubrepostorag_tpu.ops.pallas_experts import SWIGLU, experts_walk
 from githubrepostorag_tpu.ops.pallas_state import kda_step_in_place
 from githubrepostorag_tpu.ops.rope import rope_cos_sin_interleaved, rope_rotate_interleaved
 from githubrepostorag_tpu.ops.sampling import first_token_tail
@@ -474,8 +475,9 @@ def _moe_ffn(cfg, p: dict, experts: dict, li, x: jnp.ndarray, live):
         return hybrid.swiglu(rows, at(experts["e_wgu"]), at(experts["e_wd"]))
 
     with jax.named_scope("moe_experts"):
-        y, counts = dropless_experts(xf, top_i, top_w, expert_ffn, cfg.n_held,
-                                     lo=cfg.experts_held[0], listed=True)
+        y, counts = dropless_experts(
+            xf, top_i, top_w, expert_ffn, cfg.n_held, lo=cfg.experts_held[0], listed=True,
+            walk=experts_walk(SWIGLU, (experts["e_wgu"], experts["e_wd"]), li, burst=s == 1))
     with jax.named_scope("moe_shared"):  # on x [B, S, d]: its products keep three axes
         y = y.reshape(b, s, d) + hybrid.swiglu(x, p["s_wgu"], p["s_wd"])
     stats = jnp.stack([(counts > 0).sum(), counts.sum(), counts.max()]).astype(jnp.int32)

@@ -51,6 +51,7 @@ from githubrepostorag_tpu.models.quant import embedding_lookup
 from githubrepostorag_tpu.obs import startup
 from githubrepostorag_tpu.ops.latent_attention import einsum_f32
 from githubrepostorag_tpu.ops.norms import rms_norm
+from githubrepostorag_tpu.ops.pallas_experts import SWIGLU, experts_walk
 from githubrepostorag_tpu.ops.prefill_width import at_wave_width
 from githubrepostorag_tpu.ops.rope import rope_cos_sin, rope_rotate, yarn_inv_freq
 from githubrepostorag_tpu.ops.sampling import (
@@ -259,8 +260,9 @@ def _moe_ffn(cfg, p, experts: dict, li, x: jnp.ndarray, live):
         return _swiglu(rows, at(experts["e_wgu"]), at(experts["e_wd"]))
 
     with jax.named_scope("moe_experts"):
-        y, counts = dropless_experts(xf, top_i, top_w, expert_ffn, cfg.n_held,
-                                     lo=cfg.experts_held[0], listed=True)
+        y, counts = dropless_experts(
+            xf, top_i, top_w, expert_ffn, cfg.n_held, lo=cfg.experts_held[0], listed=True,
+            walk=experts_walk(SWIGLU, (experts["e_wgu"], experts["e_wd"]), li, burst=s == 1))
     stats = jnp.stack([(counts > 0).sum(), counts.sum(), counts.max()]).astype(jnp.int32)
     return y.reshape(b, s, d), stats
 

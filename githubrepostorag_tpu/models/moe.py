@@ -13,20 +13,28 @@ static length (the most tiles the shapes allow), so the layer differentiates
 and shards like any other; with the expert axis sharded P("ep", ...) GSPMD
 resolves the per-tile expert index itself.
 
-Which form serves which regime (``listed``; a value of the caller's shapes,
-not a switch a deployment sets):
+Which form serves which regime (``listed`` and ``walk``; values of the caller's
+shapes, not switches a deployment sets):
 
 * FEW LARGE experts, some hit (16 held of 100 MB each, ~6 hit: Command A+;
-  16 of 88 MB: DeepSeek-V3; 32 of 30 MB: Nemotron-H): the scan.  A tile's
-  bookkeeping is nothing beside 30-100 MB of weights, and the scan
-  differentiates.
-* MANY SMALL experts, a third hit (128 held of 6 MB each, ~40 hit: Qwen3-Next):
-  the listed form, whose loop runs the tiles that exist and no others.
-* EVERY expert held, nearly all hit (64 of 64 at 12 MB each, 75-92% hit by a
-  burst's ~19 rows, every one by a wave: Mellum2, PR 54): the listed form too.
-  There is almost nothing to skip, so what either form saves is its own
-  bookkeeping, and the listed form has less of it; measured on the chip in
-  ``PERF.md`` section 6, PR 54.
+  16 of 88 MB: DeepSeek-V3): the scan.  A tile's bookkeeping is nothing beside
+  88-100 MB of weights, and the scan differentiates.
+* MANY SMALL experts, a third hit (128 held of 6 MB each, ~30 hit:
+  Qwen3-Next; 128 of 12 MB: Ling; 32 of 20 MB: Nemotron-H), or EVERY expert
+  held and nearly all hit (64 of 64 at 12 MB each: Mellum2, PR 54), the rows
+  fitting ONE tile (a decode burst's 32 row slots, a one-row wave of 128
+  columns): an expert that was hit runs on all the rows and is weighted by
+  its column of the dense [T, held] weights.  On the chip the hit experts'
+  weights come through one Pallas call whose own DMAs bring the next block in
+  while this one's products run (``walk``, ops/pallas_experts.py: 8.3 us a
+  6 MB expert on a v5e where a loop of XLA products took 13.8, PERF.md,
+  PR 58); the family hands its expert form to it as a ``body``.  Off the chip
+  ``walk`` is None and the loop of XLA products over the hit experts runs: the
+  CPU's path and the kernel's oracle (ROADMAP D26).
+* The same families' waves of more than one tile of rows (256-1,024 columns):
+  the listed form, whose loop runs the tiles that exist and no others, each
+  tile's expert, rows and weights listed once before it (PERF.md, PR 34 and
+  PR 54; a kernel for it needs rows gathered by expert: ROADMAP S21 (a)).
 
 Routers: ``moe_mlp`` is HF ``Qwen2MoeSparseMoeBlock`` (float32 softmax,
 top-k, optional renormalisation, a shared expert behind a sigmoid gate);
@@ -73,7 +81,7 @@ EXPERT_TILE = 128  # rows of one expert a dispatch tile holds, at most
 
 
 def dropless_experts(x: jnp.ndarray, top_i: jnp.ndarray, top_w: jnp.ndarray, expert_ffn,
-                     n_held: int, lo=0, listed: bool = False):
+                     n_held: int, lo=0, listed: bool = False, walk=None):
     """Sum over the held experts of w * E(x) for the pairs routed to them.
 
     ``x`` [T, d]; ``top_i`` [T, K] expert ids over ALL experts; ``top_w``
@@ -82,21 +90,45 @@ def dropless_experts(x: jnp.ndarray, top_i: jnp.ndarray, top_w: jnp.ndarray, exp
     ``lo .. lo + n_held - 1``.  Returns (y [T, d] float32, counts [n_held]
     int32: pairs each held expert received).
 
-    ``listed``: the form for MANY SMALL experts (128 held of 6 MB each, ~40
-    hit by a burst's rows: models/qwen3_next.py).  There the scan's own
-    bookkeeping, a dozen scalar ops a tile and a skipped ``cond`` for each of
-    the ~90 tiles that do not exist, costs more than the experts' bytes
-    (PERF.md, Findings, PR 34).  Every tile's expert, rows and weights are
-    listed once, vectorised, before the loop; the loop runs the tiles that
-    exist and no others (a dynamic trip count: serving only, it does not
-    differentiate in reverse)."""
+    ``listed``: the form for MANY SMALL experts (128 held of 6 MB each:
+    models/qwen3_next.py).  There the scan's own bookkeeping, a dozen scalar
+    ops a tile and a skipped ``cond`` for each of the ~90 tiles that do not
+    exist, costs more than the experts' bytes (PERF.md, Findings, PR 34).
+    Every tile's expert, rows and weights are listed once, vectorised, before
+    the loop; the loop runs the tiles that exist and no others (a dynamic trip
+    count: serving only, it does not differentiate in reverse).
+
+    ``walk(x, w_dense [T, n_held] float32, first_hit [n_held] int32, n_hit) ->
+    y [T, d] float32`` (ops/pallas_experts.experts_walk): where the rows fit
+    one tile, the hit experts (``first_hit[:n_hit]``) as one kernel over the
+    family's expert stacks, and ``expert_ffn`` is not called.  None (off the
+    chip): the ``listed`` form's loop over the hit experts calls it."""
     t, d = x.shape
     k = top_i.shape[1]
     m = min(EXPERT_TILE, -(-t // 8) * 8)  # rows a tile holds
     held = (top_i >= lo) & (top_i < lo + n_held)
     e = jnp.where(held, top_i - lo, n_held).reshape(-1)  # [T*K]; n_held = not here
-    order = jnp.argsort(e, stable=True)  # pairs by expert, the absent last
     counts = (e[:, None] == jnp.arange(n_held)[None, :]).sum(axis=0).astype(jnp.int32)
+    if (listed or walk is not None) and t <= m:
+        # the rows fit one tile: an expert that was hit runs on ALL of them (a
+        # tile is m rows whatever it holds) and its result is weighted by that
+        # expert's column of the dense [T, held] weights, zero where a row did
+        # not choose it: no sort, no gather of rows, no scatter-add a tile; on
+        # the chip the hit experts' weights as one stream (ops/pallas_experts.py)
+        w_dense = jnp.zeros((t, n_held + 1), jnp.float32).at[
+            jnp.arange(t)[:, None], e.reshape(t, k)].add(top_w.astype(jnp.float32))
+        first_hit = jnp.argsort(counts == 0, stable=True).astype(jnp.int32)  # the hit ones first
+        n_hit = (counts > 0).sum()
+        if walk is not None:
+            return walk(x, w_dense[:, :n_held], first_hit, n_hit), counts
+        w_cols = w_dense.T[first_hit]  # [n_held, T].  The loop: the CPU's path and the walk's oracle
+
+        def one(i, y):
+            yt = expert_ffn(first_hit[i], x).astype(jnp.float32)
+            return y + yt * jax.lax.dynamic_index_in_dim(w_cols, i, keepdims=False)[:, None]
+
+        return jax.lax.fori_loop(0, n_hit, one, jnp.zeros((t, d), jnp.float32)), counts
+    order = jnp.argsort(e, stable=True)  # pairs by expert, the absent last
     tiles_of = (counts + m - 1) // m
     tile_end = jnp.cumsum(tiles_of)
     tile_start = tile_end - tiles_of
@@ -106,23 +138,6 @@ def dropless_experts(x: jnp.ndarray, top_i: jnp.ndarray, top_w: jnp.ndarray, exp
     n_tiles = min(t * k // m + n_held, n_held * -(-t // m))
     flat_w = top_w.reshape(-1).astype(jnp.float32)
 
-    if listed and t <= m:
-        # the rows fit one tile: an expert that was hit runs on ALL of them (a
-        # tile is m rows whatever it holds) and its result is weighted by that
-        # expert's column of the dense [T, held] weights, zero where a row did
-        # not choose it: no sort, no gather of rows, no scatter-add a tile
-        local = jnp.where(held, top_i - lo, n_held)  # [T, K]; n_held = not here
-        w_dense = jnp.zeros((t, n_held + 1), jnp.float32).at[
-            jnp.arange(t)[:, None], local].add(top_w.astype(jnp.float32))
-        first_hit = jnp.argsort(counts == 0, stable=True).astype(jnp.int32)  # the hit ones first
-        w_cols = w_dense.T[first_hit]  # [n_held, T]
-
-        def one(i, y):
-            yt = expert_ffn(first_hit[i], x).astype(jnp.float32)
-            return y + yt * jax.lax.dynamic_index_in_dim(w_cols, i, keepdims=False)[:, None]
-
-        y = jax.lax.fori_loop(0, (counts > 0).sum(), one, jnp.zeros((t, d), jnp.float32))
-        return y, counts
     if listed:
         tiles = jnp.arange(n_tiles)
         ex = jnp.minimum(jnp.searchsorted(tile_end, tiles, side="right"), n_held - 1)

@@ -42,6 +42,7 @@ from githubrepostorag_tpu.models.quant import embedding_lookup
 from githubrepostorag_tpu.obs import startup
 from githubrepostorag_tpu.ops.latent_attention import einsum_f32
 from githubrepostorag_tpu.ops.norms import rms_norm
+from githubrepostorag_tpu.ops.pallas_experts import RELU2, experts_walk
 from githubrepostorag_tpu.ops.sampling import first_token_tail
 
 ACT = jnp.bfloat16  # products take bfloat16 operands; the residual stream is float32
@@ -271,12 +272,15 @@ def _moe_ffn(cfg, p, experts: dict, n, x: jnp.ndarray, live):
             w, (n, e, 0, 0), (1, 1, *w.shape[2:]))[0, 0]
         return relu2_ffn(rows, at(experts["e_wu"]), at(experts["e_wd"]))
 
-    # ``listed``: 32 held experts of 20 MB lie between DeepSeek-V3's 16 of 88 MB (the plain scan)
-    # and Qwen3-Next's 128 of 6 MB (listed); on a v5e the listed form's burst takes 59.8 ms
-    # against the scan's 66.4, an eight-row wave 114.6 against 131.6 (PERF.md, PR 41)
+    # 32 held experts of 20 MB lie between DeepSeek-V3's 16 of 88 MB (the plain scan) and
+    # Qwen3-Next's 128 of 6 MB.  The burst's 32 rows and a one-row wave of 128 columns take, on the
+    # chip, the walk over the hit experts (``RELU2``: both stacks read by rows, as stored); a wider
+    # wave the listed tile loop, whose eight-row wave took 114.6 ms on a v5e against the scan's
+    # 131.6 (PERF.md, PR 41 and PR 58)
     with jax.named_scope("moe_experts"):
-        y, counts = dropless_experts(xf, top_i, top_w, expert_ffn, cfg.n_held,
-                                     lo=cfg.experts_held[0], listed=True)
+        y, counts = dropless_experts(
+            xf, top_i, top_w, expert_ffn, cfg.n_held, lo=cfg.experts_held[0], listed=True,
+            walk=experts_walk(RELU2, (experts["e_wu"], experts["e_wd"]), n, burst=s == 1))
     with jax.named_scope("moe_shared"):  # always on, no gate
         y = y.reshape(b, s, d) + relu2_ffn(x, p["s_wu"], p["s_wd"])
     stats = jnp.stack([(counts > 0).sum(), counts.sum()]).astype(jnp.int32)

@@ -34,6 +34,7 @@ from githubrepostorag_tpu.models.quant import embedding_lookup
 from githubrepostorag_tpu.obs import startup
 from githubrepostorag_tpu.ops.latent_attention import einsum_f32
 from githubrepostorag_tpu.ops.norms import rms_norm_zero_centered
+from githubrepostorag_tpu.ops.pallas_experts import SWIGLU, experts_walk
 from githubrepostorag_tpu.ops.rope import rope_cos_sin, rope_rotate_leading
 from githubrepostorag_tpu.ops.sampling import first_token_tail
 
@@ -314,8 +315,9 @@ def _moe_ffn(cfg, p, experts: dict, li, x: jnp.ndarray, live):
         return hybrid.swiglu(rows, at(experts["e_wgu"]), at(experts["e_wd"]))
 
     with jax.named_scope("moe_experts"):
-        y, counts = dropless_experts(xf, top_i, top_w, expert_ffn, cfg.n_held,
-                                     lo=cfg.experts_held[0], listed=True)
+        y, counts = dropless_experts(
+            xf, top_i, top_w, expert_ffn, cfg.n_held, lo=cfg.experts_held[0], listed=True,
+            walk=experts_walk(SWIGLU, (experts["e_wgu"], experts["e_wd"]), li, burst=s == 1))
     with jax.named_scope("moe_shared"):  # on x [B, S, d]: its products keep three axes
         gate = jax.nn.sigmoid(einsum_f32("bsd,de->bse", x, p["s_gate"]))
         y = y.reshape(b, s, d) + gate * hybrid.swiglu(x, p["s_wgu"], p["s_wd"])

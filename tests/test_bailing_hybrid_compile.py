@@ -30,6 +30,7 @@ import pytest
 from tests.test_qwen3_next_compile import timed_lines
 from tests.test_tpu_compile import (  # noqa: F401 - fixtures
     assert_calls_step_pool_in_place,
+    assert_hit_experts_are_one_walk,
     chip,
     pool_movers,
     topo,
@@ -48,8 +49,9 @@ PROGRAMS = [pytest.param("burst", 0, id="burst"), pytest.param("wave", 1, id="wa
 def as_on_chip(monkeypatch):
     import githubrepostorag_tpu.models.bailing_hybrid as model
     import githubrepostorag_tpu.models.hybrid as hybrid
+    import githubrepostorag_tpu.ops.pallas_experts as experts
 
-    for mod in (hybrid, model):
+    for mod in (hybrid, model, experts):
         monkeypatch.setattr(mod, "on_tpu", lambda: True)
 
 
@@ -214,6 +216,17 @@ def test_this_cells_metrics_select_the_ops_under_their_scopes(chip, as_on_chip):
         "dynamic_update_slice.8_bf16_6_96_36864_")
     assert re.compile(spec("ling_latent_pool_move_share")["pattern"]).search(
         "copy.3_bf16_1_1_2560_128_640_")
+
+    # the accepted experts' metric finds the burst's walk over the hit experts, a call an expert
+    # layer (the first layer is dense), and nothing of the wave
+    experts = re.compile(spec("moe_experts_hbm_frac")["op"].format(
+        **family.expert_op_sizes(model, cell.config)))
+    got = _picked(burst, experts)
+    assert set(got) == {"moe_experts"} and len(got["moe_experts"]) == 6
+    assert_hit_experts_are_one_walk(
+        list(timed_ops(burst)), timed_lines(wave, ("custom-call",)), 6, 32, 2560, 1536,
+        ((2560, 1536), (768, 2560)), experts)
+    assert "moe_experts" not in _picked(wave, experts)
 
     # DeepSeek-V3's two latent metrics find this cell's kernels under their scopes
     for name, hlo, scope in (("latent_attn_roofline_frac", burst, "latent_attention"),

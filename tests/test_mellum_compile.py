@@ -30,6 +30,7 @@ import pytest
 from tests.test_qwen3_next_compile import timed_lines
 from tests.test_tpu_compile import (  # noqa: F401 - fixtures
     assert_commits_windows_in_place,
+    assert_hit_experts_are_one_walk,
     chip,
     pool_movers,
     topo,
@@ -48,8 +49,9 @@ HBM_BYTES = 16 * 1024 ** 3
 def as_on_chip(monkeypatch):
     import githubrepostorag_tpu.models.mellum as family
     import githubrepostorag_tpu.ops.fused_decode as fused_decode
+    import githubrepostorag_tpu.ops.pallas_experts as experts
 
-    for mod in (family, fused_decode):
+    for mod in (family, fused_decode, experts):
         monkeypatch.setattr(mod, "on_tpu", lambda: True)
 
 
@@ -220,7 +222,11 @@ def test_this_cells_metrics_select_the_ops_under_their_scopes(chip, as_on_chip):
         **family.expert_op_sizes(model, cell.config)))
     got = _picked(burst, experts)
     print("moe_experts_hbm_frac picks", {k: sorted(v)[:4] for k, v in got.items()})
-    assert "moe_experts" in got
+    assert len(got["moe_experts"]) == cfg.num_layers  # the walk over the hit experts, a call a layer
+    assert_hit_experts_are_one_walk(
+        list(timed_ops(burst)), timed_lines(wave, ("custom-call",)), cfg.num_layers, 32, 2304, 1792,
+        ((2304, 1792), (896, 2304)), experts)
+    assert "moe_experts" not in _picked(wave, experts)
     # the same name and shape ([32, 2304] float32) also ends attention's output projection
     # (2.5% of the experts' bytes a layer): the share's seconds hold it too (PERF.md section 3)
     assert set(got) <= {"moe_experts", "attn_proj", ""}

@@ -3,6 +3,8 @@ expert-parallel sharding parity on the CPU mesh, the dropless dispatch,
 and the full serving engine over a MoE checkpoint.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -202,6 +204,96 @@ def test_two_product_relu2_experts_through_the_dropless_dispatch(listed, tokens)
                * (jnp.square(jax.nn.relu(x @ wu[ex].T)) @ wd[ex]) for ex in range(held))
     np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=2e-5, atol=2e-5)
     assert int(counts.sum()) == int(((top_i >= lo) & (top_i < lo + held)).sum())
+
+
+def _walk_case(form, t, routing, seed=8):
+    """(x, top_i, top_w, the stacks [2 layers, held, ...], a plain float32
+    loop's sum for layer 1) at tiny widths: 16 experts of which 4 .. 11 are
+    held, a hidden width of 320 (two and a half lane tiles)."""
+    rng = np.random.default_rng(seed)
+    d, ff, e, k, lo, held = 16, 320, 16, 4, 4, 8
+    x = jnp.asarray(rng.normal(size=(t, d)), jnp.float32)
+    draw = lambda *shape: jnp.asarray(rng.normal(size=(2, held, *shape)) * 0.3, jnp.float32)  # noqa: E731
+    if form == "swiglu":
+        stacks = (draw(d, 2 * ff), draw(ff, d))
+        ffn = lambda ex: (jax.nn.silu(x @ stacks[0][1, ex][:, :ff])  # noqa: E731
+                          * (x @ stacks[0][1, ex][:, ff:])) @ stacks[1][1, ex]
+    else:
+        stacks = (draw(ff, d), draw(ff, d))
+        ffn = lambda ex: jnp.square(jax.nn.relu(x @ stacks[0][1, ex].T)) @ stacks[1][1, ex]  # noqa: E731
+    scores = jnp.asarray(rng.normal(size=(t, e)), jnp.float32)
+    if routing == "none":  # every pair goes to an expert that is not held
+        scores = scores.at[:, lo:lo + held].add(-20.0)
+    top_w, top_i = jax.lax.top_k(jax.nn.sigmoid(scores), k)
+    if routing == "all":  # row r also chooses held expert r % held
+        top_i = top_i.at[:, 0].set(lo + jnp.arange(t) % held)
+    elif routing == "mixed":  # padding rows, and three held experts that nobody chooses
+        top_i = jnp.where(jnp.isin(top_i, jnp.asarray([lo, lo + 3, lo + 7])), e - 1, top_i)
+        top_i = top_i.at[t // 2:t // 2 + 5].set(-1)
+    want = sum(jnp.where(top_i == lo + ex, top_w, 0).sum(axis=1, keepdims=True) * ffn(ex)
+               for ex in range(held))
+    return x, top_i, top_w, stacks, want, lo, held
+
+
+WALKS = [(form, t, "mixed", "ragged") for form in ("swiglu", "relu2") for t in (24, 32, 128)] + [
+    (form, 32, routing, blocks) for form in ("swiglu", "relu2")
+    for routing, blocks in (("none", "ragged"), ("all", "ragged"), ("mixed", "whole"))]
+
+
+@pytest.mark.parametrize("form,tokens,routing,blocks", WALKS, ids=["-".join(map(str, w)) for w in WALKS])
+def test_the_walk_over_the_hit_experts_equals_a_plain_loop(monkeypatch, form, tokens, routing,
+                                                           blocks):
+    """ops/pallas_experts.walk_experts (interpreted) through the dispatch,
+    as the chip's programs take it in the one-tile loop's place: both expert forms, rows that fill a tile or
+    not, rows routed to ``-1`` (padding) and to experts not held, no expert
+    hit, every expert hit, a hidden width that is two blocks and a half
+    (``ragged``: 128 + 128 + 64 columns) or one block (``whole``: SwiGLU's gate
+    | up as one run), the second layer of the stacks."""
+    from githubrepostorag_tpu.models.moe import dropless_experts
+    from githubrepostorag_tpu.ops import pallas_experts
+
+    x, top_i, top_w, stacks, want, lo, held = _walk_case(form, tokens, routing)
+    if blocks == "ragged":
+        monkeypatch.setattr(pallas_experts, "BLOCK_BYTES", 1)  # a lane tile a block
+    body = pallas_experts.SWIGLU if form == "swiglu" else pallas_experts.RELU2
+
+    def refuse(ex, rows):
+        raise AssertionError("rows that fit one tile take the walk")
+
+    assert pallas_experts.experts_walk(body, stacks, 1, burst=True) is None  # off the chip: the loop
+    walk = functools.partial(pallas_experts.walk_experts, layer=jnp.int32(1), stacks=stacks,
+                             body=body, name="moe_experts",
+                             block_bytes=pallas_experts.BLOCK_BYTES, interpret=True)
+    y, counts = dropless_experts(x, top_i, top_w, refuse, held, lo=lo, listed=True, walk=walk)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=2e-5, atol=2e-5)
+    hit = ((top_i >= lo) & (top_i < lo + held))
+    assert int(counts.sum()) == int(hit.sum())
+    assert {"none": int((counts > 0).sum()) == 0, "all": int((counts > 0).sum()) == held,
+            "mixed": 0 < int((counts > 0).sum()) <= held - 3}[routing]
+
+
+@pytest.mark.parametrize("form", ["swiglu", "relu2"])
+def test_the_walk_waits_for_every_block_it_reads(form):
+    """The same walk under the TPU interpreter, which runs a DMA when it is
+    waited for and watches for races: a block read before its DMA was waited
+    for, or a slot filled again while it is read, shows here."""
+    from jax.experimental.pallas import tpu as pltpu
+    from githubrepostorag_tpu.ops import pallas_experts
+
+    if not hasattr(pltpu, "InterpretParams"):
+        pytest.skip("this jax has no TPU interpreter")
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call as tpu_interpreter
+
+    x, top_i, top_w, stacks, want, lo, held = _walk_case(form, 16, "all", seed=9)
+    w_dense = sum(jnp.where(top_i[:, j:j + 1] == lo + jnp.arange(held)[None], top_w[:, j:j + 1], 0)
+                  for j in range(top_i.shape[1]))
+    y = pallas_experts.walk_experts(
+        x, w_dense, jnp.arange(held, dtype=jnp.int32)[::-1], jnp.int32(held), jnp.int32(1), stacks,
+        body=pallas_experts.SWIGLU if form == "swiglu" else pallas_experts.RELU2,
+        name="moe_experts", block_bytes=1,  # a lane tile a block: two blocks and a half
+        interpret=pltpu.InterpretParams(detect_races=True, dma_execution_mode="on_wait"))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=2e-5, atol=2e-5)
+    assert not tpu_interpreter.races.races_found
 
 
 def test_moe_int8_quantization(tiny_moe):

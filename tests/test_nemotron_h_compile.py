@@ -41,6 +41,7 @@ from tests.test_tpu_compile import (  # noqa: F401 - fixtures
     COMMIT_CASES,
     assert_calls_step_pool_in_place,
     assert_commits_windows_in_place,
+    assert_hit_experts_are_one_walk,
     chip,
     pool_movers,
     topo,
@@ -58,8 +59,9 @@ def as_on_chip(monkeypatch):
     import githubrepostorag_tpu.models.hybrid as hybrid
     import githubrepostorag_tpu.ops.fused_decode as fused_decode
     import githubrepostorag_tpu.ops.latent_attention as latent
+    import githubrepostorag_tpu.ops.pallas_experts as experts
 
-    for mod in (hybrid, fused_decode, latent):
+    for mod in (hybrid, fused_decode, latent, experts):
         monkeypatch.setattr(mod, "on_tpu", lambda: True)
 
 
@@ -265,11 +267,17 @@ def test_this_cells_metrics_select_the_ops_under_their_scopes(chip, as_on_chip):
     assert moves.search("ssm_recurrent.80_f32_8_96_64_64_128_")
     assert not any(moves.search(n) for n in in_scope)
 
-    # the accepted experts' metric finds the burst's two products a layer, and only them
+    # the accepted experts' metric finds the burst's walk over the hit experts, a call a layer,
+    # and only them; both stacks are read by rows, [1856, 2688] as stored, and never copied
     experts = re.compile(manifest.metric_spec("moe_experts_hbm_frac")["args"]["op"].format(
         **family.expert_op_sizes(model, cell.config)))
     got = _picked(burst, experts)
-    assert set(got) == {"moe_experts"} and len(got["moe_experts"]) == 16
+    assert set(got) == {"moe_experts"} and len(got["moe_experts"]) == 8
+    assert_hit_experts_are_one_walk(
+        list(timed_ops(burst)), timed_lines(waves[0], ("custom-call",)), 8, 32, 2688, 1856,
+        ((1856, 2688),), experts)
+    for wave in waves:
+        assert _picked(wave, experts) == {}
     # the burst's attention kernel is named for its scope, where the accepted metric looks
     paged = re.compile(manifest.metric_spec("paged_attn_hbm_frac")["args"]["op"])
     names = {n for n, _ in timed_ops(burst) if paged.search(n)}

@@ -33,6 +33,7 @@ from tests.test_tpu_compile import (  # noqa: F401 - fixtures
     COMMIT_CASES,
     assert_calls_step_pool_in_place,
     assert_commits_windows_in_place,
+    assert_hit_experts_are_one_walk,
     assert_wave_keeps_in_place,
     chip,
     pool_movers,
@@ -56,8 +57,9 @@ def as_on_chip(monkeypatch):
     import githubrepostorag_tpu.models.hybrid as hybrid
     import githubrepostorag_tpu.ops.fused_decode as fused_decode
     import githubrepostorag_tpu.ops.latent_attention as latent
+    import githubrepostorag_tpu.ops.pallas_experts as experts
 
-    for mod in (hybrid, fused_decode, latent):
+    for mod in (hybrid, fused_decode, latent, experts):
         monkeypatch.setattr(mod, "on_tpu", lambda: True)
 
 
@@ -294,7 +296,11 @@ def test_the_metrics_select_the_ops_under_their_scopes(chip, as_on_chip):
     experts = re.compile(spec("moe_experts_hbm_frac")["op"].format(
         **family.expert_op_sizes(model, cell.config)))
     got = _picked(burst, experts)
-    assert set(got) == {"moe_experts"} and len(got["moe_experts"]) == 16  # 2 products x 8 layers
+    assert set(got) == {"moe_experts"} and len(got["moe_experts"]) == 8  # a call a layer
+    assert_hit_experts_are_one_walk(
+        list(timed_ops(burst)), timed_lines(wave, ("custom-call",)), 8, 32, 2048, 1024,
+        ((2048, 1024), (512, 2048)), experts)
+    assert _picked(wave, experts) == {}
     # the shared expert is 512 wide too: its products keep three axes and are not picked
     assert any(re.search(r"_bf16_32_1_1024_$", n) for n, s in timed_ops(burst) if s == "moe_shared")
 
@@ -339,7 +345,11 @@ def test_the_burst_keeps_the_timed_ops_it_has(chip, as_on_chip):
     under ``kv_write`` and for no other (the commit as windows of slots: the two
     row scatters and their indices went), and by PR 56 for the one-token rule and
     for no other (ops/pallas_state.gated_delta_step_in_place on the pool: the
-    rule's two fusions went, the kernel and its small operands came).
+    rule's two fusions went, the kernel and its small operands came), and by
+    PR 58 for the ops under ``moe_experts`` and two of the compiler's own
+    prefetches (ops/pallas_experts.walk_experts in place of the one-tile loop:
+    eleven kinds of op a layer went, among them the two products an expert, and
+    ``moe_experts_f32_32_2048_`` came).
     The accepted ``gdn_decode_roofline_frac`` and ``moe_experts_hbm_frac`` find
     their ops by these names, so an edit made for the other hybrid that renames
     one here reads null on the chip.  A change that MEANS to move this program
@@ -353,3 +363,6 @@ def test_the_burst_keeps_the_timed_ops_it_has(chip, as_on_chip):
     assert want["gdn_recurrent_f32_32_32_128_"] == 6
     assert "fusion_f32_32_32_128_" not in want
     assert "select_dynamic-update-slice_fusion_f32_6_97_32_128_128_" not in want
+    # the hit experts: one walk a layer; the loop's products of one expert are gone
+    assert want["moe_experts_f32_32_2048_"] == 8
+    assert "fusion_bf16_32_1024_" not in want and "fusion_f32_32_2048_" not in want

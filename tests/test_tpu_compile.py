@@ -202,6 +202,29 @@ MOVERS = ("copy", "copy-start", "transpose", "dynamic-slice", "dynamic-update-sl
 _UPDATE = re.compile(r" dynamic-update-slice\(%?[\w.\-]+, %?([\w.\-]+)[,)]")
 
 
+def assert_hit_experts_are_one_walk(burst_ops, wave_lines, layers: int, rows: int, hidden: int,
+                                    gate_up: int, expert_shapes: tuple, pattern) -> None:
+    """A layer's hit experts are ONE call of ops/pallas_experts.walk_experts
+    (PR 58).  ``burst_ops``: (name, scope) of a burst's timed ops: the call is
+    named for the ``moe_experts`` scope and its result, where the accepted
+    ``moe_experts_hbm_frac`` (``pattern``, filled) finds it; XLA's product of
+    one expert (``fusion_bf16_<rows>_<gate_up>_``) is gone with the loop, and
+    nothing under the scope copies or slices an expert or a layer of them out
+    of the stacks.  ``wave_lines``: a one-row wave's timed custom calls: its 128
+    columns take the same walk under a name the pattern does NOT match (the
+    engine counts hit experts for bursts only: a wave's seconds there would
+    read the share low)."""
+    under = [re.sub(r"\.\d+", "", n) for n, scope in burst_ops if scope == "moe_experts"]
+    assert under.count(f"moe_experts_f32_{rows}_{hidden}_") == layers, under
+    assert pattern.search(f"moe_experts.80_f32_{rows}_{hidden}_")
+    shapes = "|".join("_".join(map(str, s)) for s in expert_shapes)
+    assert not [n for n in under if re.search(rf"_bf16_{rows}_{gate_up}_$|_({shapes})_$", n)], under
+    calls = {m.group(1) for ln in wave_lines if "/moe_experts/" in ln and "tpu_custom_call" in ln
+             for m in [re.match(rf"%?([\w\-]+?)(\.\d+)? = f32\[128,{hidden}\]", ln)] if m}
+    assert calls == {"wave_experts"}, calls
+    assert not pattern.search(f"wave_experts.4_f32_128_{hidden}_")
+
+
 def pool_movers(hlo: str, pool_shape: tuple, ops: tuple = MOVERS, windows: bool = True) -> list:
     """Instructions of the optimized HLO, fused computations included (so a
     fusion of a copy counts), that copy, transpose, slice, update-slice or
